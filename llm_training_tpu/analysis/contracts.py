@@ -48,8 +48,20 @@ JAX_FREE_CONTRACTS: dict[str, str] = {
         "like the loadgen — the children own the backend"
     ),
     "bench.py": (
-        "the bench parent orchestrates child stages; a wedged backend must "
-        "cost a stage timeout, not hang the whole bench (the r05 failure)"
+        "the bench parent orchestrates child stages, one after another: a "
+        "parent that touched jax would hold the chip its children need, and "
+        "a hung backend must cost a stage timeout, not the whole bench (the "
+        "r05 failure)"
+    ),
+    "chip_smoke.py": (
+        "the chip smoke's parent runs each phase as a child, strictly one "
+        "at a time: a chip belongs to one process, and a parent that had "
+        "touched jax would hold it"
+    ),
+    "llm_training_tpu/compile_cache.py": (
+        "jax-free parents (bench.py, chip_smoke.py) name the compile cache "
+        "directory through this module; only configure_compile_cache, "
+        "called in their children, imports jax"
     ),
     "scripts/serve_loadgen.py": (
         "the loadgen drives the serve CLI as a subprocess and must keep "

@@ -20,7 +20,11 @@ from pydantic import BaseModel, ConfigDict, Field
 
 logger = logging.getLogger(__name__)
 
-# peak dense bf16 FLOP/s per chip by device_kind substring
+# peak dense bf16 FLOP/s per chip by device_kind substring — the ONE peak
+# table (bench.py reads it too). Source: Google Cloud TPU documentation,
+# the per-generation system-architecture pages ("TPU v5e": 197 TFLOP/s
+# bf16, 16 GB HBM at 819 GB/s). A kind that matches nothing has no peak
+# (None): an unknown device is an error to a measurement, never a default.
 _PEAK_FLOPS = (
     ("v6", 918e12),  # Trillium
     ("v5p", 459e12),

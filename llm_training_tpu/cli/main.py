@@ -46,10 +46,15 @@ def _seed_everything(seed: int) -> None:
 def _apply_extra_config(config: dict) -> None:
     """Top-level runtime flags (the reference ExtraConfig callback,
     `lightning/callbacks/extra_config.py:13-45`): matmul precision (its
-    `float32_matmul_precision`) and a persistent XLA compilation cache (its
-    per-rank TRITON_CACHE_DIR analogue — one dir is safe for all hosts,
-    unlike Triton's)."""
+    `float32_matmul_precision`) and the persistent XLA compilation cache
+    (its per-rank TRITON_CACHE_DIR analogue — one dir is safe for all
+    hosts, unlike Triton's). The cache directory is not a config key: it
+    is `JAX_COMPILATION_CACHE_DIR` where set, else the fixed in-checkout
+    path (`compile_cache.py`) — every command that touches a device passes
+    here, so they all share one cache."""
     import jax
+
+    from llm_training_tpu.compile_cache import ENV_CACHE_DIR, configure_compile_cache
 
     precision = config.get("matmul_precision") or config.get("float32_matmul_precision")
     if precision:
@@ -58,10 +63,13 @@ def _apply_extra_config(config: dict) -> None:
             str(precision), str(precision)
         )
         jax.config.update("jax_default_matmul_precision", precision)
-    cache_dir = config.get("compilation_cache_dir")
-    if cache_dir:
-        jax.config.update("jax_compilation_cache_dir", str(cache_dir))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    if "compilation_cache_dir" in config:
+        raise ValueError(
+            "`compilation_cache_dir` is no longer a config key: place the "
+            f"compile cache with the {ENV_CACHE_DIR} environment variable "
+            "(unset, it lives at <repo>/.jax_cache)"
+        )
+    configure_compile_cache()
 
 
 def _build(config: dict):

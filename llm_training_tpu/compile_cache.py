@@ -1,0 +1,39 @@
+"""One persistent XLA compile cache, placed from outside.
+
+Where `JAX_COMPILATION_CACHE_DIR` is set, JAX reads it itself and the
+program names no other directory. Where it is not, the cache lives at ONE
+fixed path inside the checkout (`<repo>/.jax_cache`, git-ignored). The
+directory is part of what a cache entry is keyed on, so a path built from a
+temporary name, a pid or the time would never hit: every process of the
+repo — `fit`, `serve`, the bench's stage children, `chip_smoke.py` — must
+land in the same place for a second run to compile nothing.
+
+Importing this module does not import jax (bench.py's and chip_smoke.py's
+parents stay off the chip); `configure_compile_cache` does.
+"""
+
+from __future__ import annotations
+
+import os
+from pathlib import Path
+
+ENV_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[1] / ".jax_cache"
+
+
+def compile_cache_dir() -> str:
+    """Where this process's compiles are cached: the environment's choice,
+    else the fixed in-checkout path."""
+    return os.environ.get(ENV_CACHE_DIR) or str(DEFAULT_CACHE_DIR)
+
+
+def configure_compile_cache() -> str:
+    """Point JAX's persistent cache at `compile_cache_dir()`; returns it.
+    With the variable set nothing is set in code — JAX already read it.
+    JAX's own thresholds stay (programs that compile in under a second are
+    not written)."""
+    import jax
+
+    if not os.environ.get(ENV_CACHE_DIR):
+        jax.config.update("jax_compilation_cache_dir", str(DEFAULT_CACHE_DIR))
+    return compile_cache_dir()

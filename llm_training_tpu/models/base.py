@@ -149,7 +149,7 @@ class PagedDecodeState:
     docs/serving.md) — the continuous-batching successor to `DecodeState`'s
     shared-append-index layout.
 
-    `k`/`v` are `[num_layers, num_blocks, block_size, num_kv_heads,
+    `k`/`v` are `[num_layers, num_blocks, num_kv_heads, block_size,
     head_dim]` POOL buffers: fixed-size blocks allocated to requests by the
     host-side `serve.paged_cache.BlockAllocator` (physical block 0 is a
     reserved trash block — idle decode slots and padded chunk positions
@@ -176,7 +176,7 @@ class PagedDecodeState:
 
     @property
     def block_size(self) -> int:
-        return self.k.shape[2]
+        return self.k.shape[3]
 
     @property
     def max_length(self) -> int:

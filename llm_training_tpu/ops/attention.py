@@ -124,6 +124,56 @@ def _xla_attention(
     return checkpoint_name(out, "flash_out")
 
 
+def _flash_under_mesh(q, k, v, segment_ids, q_segment_ids, sinks, **kwargs):
+    """The flash kernel on whatever mesh is active. GSPMD cannot partition a
+    Mosaic kernel ("Mosaic kernels cannot be automatically partitioned" —
+    the TPU compiler's refusal of any sharded step that holds one), so on a
+    multi-device mesh the kernel runs inside a shard_map: batch over the
+    data/fsdp/expert axes, heads over tensor. Attention needs no collective
+    across either, so the shards match what the surrounding layers already
+    hold. A sequence-sharded input is gathered here — ring attention
+    (`parallel/ring_attention.py`) is the path that keeps it sharded."""
+    from llm_training_tpu.ops.pallas.flash_attention import flash_attention
+    from llm_training_tpu.parallel.mesh import active_mesh
+
+    mesh = active_mesh()
+    if mesh is None or mesh.size == 1:
+        return flash_attention(
+            q, k, v, segment_ids=segment_ids, q_segment_ids=q_segment_ids,
+            sinks=sinks, **kwargs,
+        )
+    from jax.sharding import PartitionSpec as P
+
+    from llm_training_tpu.parallel.ring_attention import batch_head_axes
+
+    batch_axes, head_axis = batch_head_axes(mesh, q, k)
+    spec_qkv = P(batch_axes, None, head_axis, None)
+    # optional operands ride as a dict so absent ones need no placeholder
+    optional = {"segment_ids": segment_ids, "q_segment_ids": q_segment_ids, "sinks": sinks}
+    optional = {name: x for name, x in optional.items() if x is not None}
+    optional_specs = {
+        name: P(head_axis) if name == "sinks" else P(batch_axes, None)
+        for name in optional
+    }
+
+    def run(q, k, v, optional):
+        return flash_attention(
+            q, k, v,
+            segment_ids=optional.get("segment_ids"),
+            q_segment_ids=optional.get("q_segment_ids"),
+            sinks=optional.get("sinks"),
+            **kwargs,
+        )
+
+    return jax.shard_map(
+        run,
+        mesh=mesh,
+        in_specs=(spec_qkv, spec_qkv, spec_qkv, optional_specs),
+        out_specs=spec_qkv,
+        check_vma=False,
+    )(q, k, v, optional)
+
+
 def dot_product_attention(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -174,18 +224,13 @@ def dot_product_attention(
 
     use_pallas = impl == "pallas" or (impl == "auto" and jax.default_backend() == "tpu")
     if use_pallas:
-        from llm_training_tpu.ops.pallas.flash_attention import flash_attention
-
-        return flash_attention(
-            q, k, v,
-            segment_ids=segment_ids,
-            q_segment_ids=q_segment_ids,
+        return _flash_under_mesh(
+            q, k, v, segment_ids, q_segment_ids, sinks,
             causal=causal,
             sliding_window=sliding_window,
             logits_soft_cap=logits_soft_cap,
             scale=scale,
             q_offset=q_offset,
-            sinks=sinks,
             block_q=block_q,
             block_k=block_k,
             bwd_block_q=bwd_block_q,

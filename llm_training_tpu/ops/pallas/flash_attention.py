@@ -35,6 +35,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from llm_training_tpu.ops.pallas import resolve_interpret
 from llm_training_tpu.ops.pallas.tuning import (
     SOURCE_ORDER,
     BlockChoice,
@@ -46,12 +47,6 @@ from llm_training_tpu.ops.pallas.tuning import (
 
 _MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 _LANES = 128
-
-# jax renamed pltpu.TPUCompilerParams -> pltpu.CompilerParams; the r04/r05
-# bench machine and this CPU container sit on opposite sides of the rename,
-# so resolve whichever exists (the 17 flash tests were dead-on-arrival in
-# the CPU container on the missing new name alone)
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 # block sizes are resolved at CALL time by ops/pallas/tuning.py (explicit
 # arg > FLASH_BLOCK_* env > config/tuning table > 1024 default) — never at
@@ -668,7 +663,7 @@ def flash_fwd_flat(
     q_offset: int = 0,
     block_q: int | None = None,
     block_k: int | None = None,
-    interpret: bool = False,
+    interpret: bool | None = None,
     sinks: jnp.ndarray | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Forward kernel over flat padded inputs: q [B*Hq, Sq, D], k/v
@@ -678,6 +673,7 @@ def flash_fwd_flat(
     sink mass). Building block for both the public wrapper and ring
     attention (which re-runs the backward with the globally-combined
     lse)."""
+    interpret = resolve_interpret(interpret)
     bh, sq, d = q.shape
     skv = k.shape[1]
     block_q, block_k = _resolve_flat_blocks(
@@ -744,10 +740,11 @@ def flash_fwd_flat(
             jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
             jax.ShapeDtypeStruct((bh, 1, sq), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_fwd",
     )(seg_lo, seg_hi, *inputs)
     # remat tags: under `recompute_granularity='selective'` the model policy
     # saves exactly these two (save_only_these_names), so the backward pass
@@ -777,7 +774,7 @@ def flash_bwd_flat(
     q_offset: int = 0,
     block_q: int | None = None,
     block_k: int | None = None,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Backward kernels over flat padded inputs. `lse`/`delta` are [B*Hq, Sq]
     fp32 — for ring attention they are the globally-combined values, which is
@@ -787,6 +784,7 @@ def flash_bwd_flat(
     `block_q`/`block_k` are the BACKWARD tiles (tuning kind "bwd") — the
     dq/dkv kernels carry different scratch footprints than the forward, so
     their optimal blocks are tuned independently."""
+    interpret = resolve_interpret(interpret)
     bh, sq, d = q.shape
     skv = k.shape[1]
     block_q, block_k = _resolve_flat_blocks(
@@ -842,10 +840,11 @@ def flash_bwd_flat(
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(seg_lo, seg_hi, seg_q[:, None], seg_kv[:, None], q, k, v, do, lse[:, None], delta[:, None])
 
     # q-side refs are indexed by (kv batch-head, group member): the GQA
@@ -895,10 +894,11 @@ def flash_bwd_flat(
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qblk_lo, qblk_hi, seg_q[:, None], seg_kv[:, None], q, k, v, do, lse[:, None], delta[:, None])
     return dq, dk, dv
 
@@ -1006,7 +1006,8 @@ def flash_attention(
     q: [batch, q_len, num_q_heads, head_dim]; k/v: [batch, kv_len,
     num_kv_heads, head_dim]; segment ids as in
     `llm_training_tpu.ops.attention.dot_product_attention` (0 = padding).
-    Runs compiled on TPU, interpreted elsewhere (tests).
+    Runs compiled on TPU, interpreted elsewhere (tests); `interpret=True`
+    on a TPU raises (`resolve_interpret`).
 
     Block sizes left as None resolve at call time through
     `ops/pallas/tuning.py` (env > tuning table > default), independently
@@ -1021,8 +1022,7 @@ def flash_attention(
         )
     if scale is None:
         scale = head_dim**-0.5
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     orig_dtype = q.dtype
     # fold the softmax scale into q: one multiply per q element replaces one
     # per SCORE element in every kernel (fwd + both bwd recomputes) — the

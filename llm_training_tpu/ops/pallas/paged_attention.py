@@ -21,10 +21,14 @@ Design (one page per kv grid step, flash-style online softmax):
 
 The page size IS this kernel's kv tile (the [group, page_size] score tile
 per q-head group), registered with `ops/pallas/tuning.py` under
-kind="paged" (page axis in sublanes, head_dim in lanes — hence 8-aligned,
-not 128). `interpret=True` runs the kernel on CPU for tier-1 tests,
-following the `flash_attention.py` pattern; the XLA gather fallback lives
-in `ops/paged_attention.py`.
+kind="paged". The pool is `[blocks, kv_heads, page, head_dim]`: one kv
+head's page is the (page, head_dim) trailing tile Mosaic requires of a
+block — page in sublanes (8-aligned), head_dim in lanes (128-aligned) —
+so a kv head is selected by the BlockSpec, never by a slice in the kernel.
+Off-TPU the kernel runs interpreted (tier-1 tests), following the
+`flash_attention.py` pattern; on a TPU it always compiles, and a shape
+Mosaic cannot tile raises here instead of being routed elsewhere. The XLA
+gather fallback lives in `ops/paged_attention.py`.
 """
 
 from __future__ import annotations
@@ -37,20 +41,19 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from llm_training_tpu.ops.pallas import resolve_interpret
+
 _MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 _LANES = 128
-
-# see flash_attention.py: resolve whichever side of the
-# TPUCompilerParams -> CompilerParams rename this jax carries
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+_SUBLANES = 8
 
 
 def _decode_kernel(
     tables,  # scalar prefetch: [B, P] physical block per (row, logical page)
     lens,    # scalar prefetch: [B] tokens already written (incl. this one)
     q_ref,   # [1, 1, G, D] this row's q for one kv head's group
-    k_ref,   # [1, page, 1, D] one pool page for this kv head
-    v_ref,   # [1, page, 1, D]
+    k_ref,   # [1, 1, page, D] one pool page for this kv head
+    v_ref,   # [1, 1, page, D]
     o_ref,   # [1, 1, G, D]
     m_ref,   # VMEM [G, lanes] running row max
     l_ref,   # VMEM [G, lanes] running denominator
@@ -77,7 +80,7 @@ def _decode_kernel(
     @pl.when(j * page_size <= q_pos)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)   # [G, D]
-        k = k_ref[0, :, 0].astype(jnp.float32)  # [page, D]
+        k = k_ref[0, 0].astype(jnp.float32)   # [page, D]
         s = lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # [G, page]
@@ -94,7 +97,7 @@ def _decode_kernel(
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.where(mask, jnp.exp(s - m_new), 0.0)  # [G, page]
-        v = v_ref[0, :, 0].astype(jnp.float32)        # [page, D]
+        v = v_ref[0, 0].astype(jnp.float32)           # [page, D]
         acc_ref[...] = acc_ref[...] * alpha + lax.dot_general(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
@@ -125,14 +128,17 @@ def paged_decode_attention(
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """One ragged decode step: q `[B, Hq, D]` (one token per row) against
-    each row's paged cache. `k_pages`/`v_pages` `[N, page, Hkv, D]` are the
+    each row's paged cache. `k_pages`/`v_pages` `[N, Hkv, page, D]` are the
     pool, `block_tables [B, P]` maps logical page -> pool block, and
     `lengths [B]` counts tokens written INCLUDING this step's (the caller
     appends before attending). Rows a scheduler left idle should carry
     length 1 and a trash-block table — they compute one garbage token the
-    caller ignores. Returns `[B, Hq, D]`."""
+    caller ignores. Returns `[B, Hq, D]`.
+
+    `interpret=None` interprets off-TPU and compiles on a TPU; asking for
+    the interpreter on a TPU raises (`resolve_interpret`)."""
     batch, num_q_heads, head_dim = q.shape
-    _, page_size, num_kv_heads, _ = k_pages.shape
+    _, num_kv_heads, page_size, _ = k_pages.shape
     num_pages = block_tables.shape[1]
     if num_q_heads % num_kv_heads:
         raise ValueError(
@@ -142,8 +148,14 @@ def paged_decode_attention(
     group = num_q_heads // num_kv_heads
     if scale is None:
         scale = head_dim**-0.5
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
+    if not interpret and (head_dim % _LANES or page_size % _SUBLANES):
+        raise ValueError(
+            "the compiled paged-decode kernel tiles a (page, head_dim) block "
+            f"as ({_SUBLANES}, {_LANES}): got page {page_size}, head_dim "
+            f"{head_dim}. Serve this model with attention impl 'xla', or "
+            "off-TPU where the kernel is interpreted"
+        )
 
     # q heads are kv-major (head h*G+g serves kv head h) — the same layout
     # _xla_attention's GQA reshape uses
@@ -155,7 +167,7 @@ def paged_decode_attention(
         # pages past the row's last valid page repeat the last valid one:
         # their DMA is elided and their compute is pl.when-skipped
         jc = jnp.minimum(j, jnp.maximum(lens[b] - 1, 0) // page_size)
-        return (tables[b, jc], 0, h, 0)
+        return (tables[b, jc], h, 0, 0)
 
     out = pl.pallas_call(
         functools.partial(
@@ -174,8 +186,8 @@ def paged_decode_attention(
                     (1, 1, group, head_dim),
                     lambda b, h, j, tables, lens: (b, h, 0, 0),
                 ),
-                pl.BlockSpec((1, page_size, 1, head_dim), page_idx),
-                pl.BlockSpec((1, page_size, 1, head_dim), page_idx),
+                pl.BlockSpec((1, 1, page_size, head_dim), page_idx),
+                pl.BlockSpec((1, 1, page_size, head_dim), page_idx),
             ],
             out_specs=pl.BlockSpec(
                 (1, 1, group, head_dim),
@@ -190,9 +202,10 @@ def paged_decode_attention(
         out_shape=jax.ShapeDtypeStruct(
             (batch, num_kv_heads, group, head_dim), q.dtype
         ),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="paged_decode",
     )(tables, lens, qg, k_pages, v_pages)
     return out.reshape(batch, num_q_heads, head_dim)

@@ -43,6 +43,37 @@ from jax import lax
 from llm_training_tpu.parallel.mesh import SEQUENCE_AXIS
 
 
+def batch_head_axes(mesh, q, k):
+    """(batch mesh axes, head mesh axis) an attention shard_map splits
+    q/k/v `[B, S, H, D]` over, degrading to replication on axes the shapes
+    can't fill — the init trace runs with batch 1, and tiny-head configs may
+    not divide the tensor axis. The expert axis joins the batch factors (the
+    batch sharding rule treats EP groups as extra data parallelism), else
+    EP runs would all-gather and redundantly recompute attention across EP
+    ranks."""
+    from llm_training_tpu.parallel.mesh import (
+        DATA_AXIS, EXPERT_AXIS, FSDP_AXIS, TENSOR_AXIS,
+    )
+
+    dp_ways = (
+        mesh.shape[DATA_AXIS]
+        * mesh.shape[FSDP_AXIS]
+        * mesh.shape.get(EXPERT_AXIS, 1)
+    )
+    if q.shape[0] % dp_ways == 0:
+        batch_axes = (DATA_AXIS, FSDP_AXIS, EXPERT_AXIS)
+    elif q.shape[0] % (mesh.shape[DATA_AXIS] * mesh.shape[FSDP_AXIS]) == 0:
+        # degrade only the expert factor, keeping data/fsdp sharding
+        batch_axes = (DATA_AXIS, FSDP_AXIS)
+    else:
+        batch_axes = None
+    tp = mesh.shape[TENSOR_AXIS]
+    head_axis = (
+        TENSOR_AXIS if q.shape[2] % tp == 0 and k.shape[2] % tp == 0 else None
+    )
+    return batch_axes, head_axis
+
+
 def dispatch_ring_attention(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -65,36 +96,14 @@ def dispatch_ring_attention(
     windows + sinks)."""
     from jax.sharding import PartitionSpec as P
 
-    from llm_training_tpu.parallel.mesh import (
-        DATA_AXIS, EXPERT_AXIS, FSDP_AXIS, TENSOR_AXIS, active_mesh,
-    )
+    from llm_training_tpu.parallel.mesh import active_mesh
 
     mesh = active_mesh()
     if mesh is None or mesh.shape.get(SEQUENCE_AXIS, 1) <= 1:
         return None
     if segment_ids is None:
         segment_ids = jnp.ones(q.shape[:2], jnp.int32)
-    # degrade to replication on axes the shapes can't fill — the init trace
-    # runs with batch 1, and tiny-head configs may not divide the tensor
-    # axis. The expert axis joins the batch factors (the batch sharding rule
-    # treats EP groups as extra data parallelism), else EP+ring runs would
-    # all-gather and redundantly recompute attention across EP ranks.
-    dp_ways = (
-        mesh.shape[DATA_AXIS]
-        * mesh.shape[FSDP_AXIS]
-        * mesh.shape.get(EXPERT_AXIS, 1)
-    )
-    if q.shape[0] % dp_ways == 0:
-        batch_axes = (DATA_AXIS, FSDP_AXIS, EXPERT_AXIS)
-    elif q.shape[0] % (mesh.shape[DATA_AXIS] * mesh.shape[FSDP_AXIS]) == 0:
-        # degrade only the expert factor, keeping data/fsdp sharding
-        batch_axes = (DATA_AXIS, FSDP_AXIS)
-    else:
-        batch_axes = None
-    tp = mesh.shape[TENSOR_AXIS]
-    head_axis = (
-        TENSOR_AXIS if q.shape[2] % tp == 0 and k.shape[2] % tp == 0 else None
-    )
+    batch_axes, head_axis = batch_head_axes(mesh, q, k)
     spec_qkv = P(batch_axes, SEQUENCE_AXIS, head_axis, None)
     spec_seg = P(batch_axes, SEQUENCE_AXIS)
     in_specs = [spec_qkv, spec_qkv, spec_qkv, spec_seg]
@@ -292,7 +301,6 @@ def _chunk_fwd(
             logits_soft_cap=logits_soft_cap,
             sliding_window=sliding_window, q_offset=q_offset,
             block_q=block_q, block_k=block_k,
-            interpret=jax.default_backend() != "tpu",
         )
         return _from_flat(o, batch).astype(jnp.float32), lse.reshape(batch, hq, -1)
     return _chunk_fwd_xla(
@@ -319,7 +327,6 @@ def _chunk_bwd(
             logits_soft_cap=logits_soft_cap,
             sliding_window=sliding_window, q_offset=q_offset,
             block_q=block_q, block_k=block_k,
-            interpret=jax.default_backend() != "tpu",
         )
         return _from_flat(dq, batch), _from_flat(dk, batch), _from_flat(dv, batch)
     return _chunk_bwd_xla(

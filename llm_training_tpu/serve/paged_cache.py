@@ -1,8 +1,9 @@
 """Paged KV-cache pool + host-side block allocator (docs/serving.md).
 
-The pool is the device half: `[num_layers, num_blocks, block_size,
-kv_heads, head_dim]` k/v buffers built from the SAME training rule table
-`infer/cache.py` uses (kv heads shard over 'tensor'; the block axis stays
+The pool is the device half: `[num_layers, num_blocks, kv_heads,
+block_size, head_dim]` k/v buffers (one kv head's page is the trailing
+(block_size, head_dim) tile the paged-decode kernel streams), built from
+the SAME training rule table `infer/cache.py` uses (kv heads shard over 'tensor'; the block axis stays
 replicated — each data-parallel serving replica owns its whole pool).
 Physical block 0 is a reserved TRASH block: idle decode slots and padded
 chunk positions write there, so a garbage row can never touch a live
@@ -32,8 +33,8 @@ if TYPE_CHECKING:
     import jax.numpy as jnp
     from jax.sharding import Mesh
 
-# pool layout: [num_layers, num_blocks, block_size, num_kv_heads, head_dim]
-POOL_LOGICAL_AXES = ("layers", None, None, "kv_heads", None)
+# pool layout: [num_layers, num_blocks, num_kv_heads, block_size, head_dim]
+POOL_LOGICAL_AXES = ("layers", None, "kv_heads", None, None)
 
 TRASH_BLOCK = 0  # physical block 0 is never allocated
 
@@ -78,7 +79,7 @@ def init_paged_pool(
 
     num_layers, kv_heads, head_dim = cache_dims(model_config)
     dtype = resolve_cache_dtype(model_config, cache_dtype)
-    shape = (num_layers, num_blocks, block_size, kv_heads, head_dim)
+    shape = (num_layers, num_blocks, kv_heads, block_size, head_dim)
 
     def build():
         return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)

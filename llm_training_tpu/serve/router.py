@@ -981,6 +981,25 @@ def _leg_record(req: RoutedRequest, rid: str, clock=time.monotonic) -> dict:
     return record
 
 
+def require_one_process_per_chip(replicas: int) -> None:
+    """Refuse, at once and by name, a spawn of several `serve` children that
+    would share a chip. A chip belongs to one process at a time, and replica
+    spawns (here and in scripts/serve_loadgen.py) hand every child this
+    process's environment unchanged: none is assigned chips of its own
+    (ROADMAP S3). Wherever JAX's default platform is an accelerator, the
+    second child to reach the backend fails its start-up or hangs. So more
+    than one replica runs only where the environment itself says the CPU
+    test path (`JAX_PLATFORMS=cpu`); this jax-free parent cannot ask JAX
+    what it would find, and does not guess."""
+    if replicas > 1 and os.environ.get("JAX_PLATFORMS", "").strip().lower() != "cpu":
+        raise SystemExit(
+            f"error: {replicas} serve replicas would each initialise the same "
+            "accelerator — a chip belongs to one process, and replica spawns "
+            "do not assign chips yet (ROADMAP S3). Run one replica per host, "
+            "or set JAX_PLATFORMS=cpu for the CPU test path."
+        )
+
+
 def route_main(args) -> int:
     from llm_training_tpu.cli.config import load_config
     from llm_training_tpu.cli.main import _jsonl_run_dir_jaxfree
@@ -1007,6 +1026,7 @@ def route_main(args) -> int:
         format="%(asctime)s %(levelname)s %(name)s: %(message)s",
         force=True,
     )
+    require_one_process_per_chip(max(int(args.replicas), int(args.max_replicas or 0)))
 
     overrides = [a for a in args.serve_args if "=" in a and not a.startswith("-")]
     config = load_config(args.config, overrides)

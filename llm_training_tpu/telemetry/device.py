@@ -27,7 +27,9 @@ family (all-reduce / all-gather / reduce-scatter / collective-permute)
 and per mesh axis — the static comm-fraction estimate the pjit/TPUv4
 paper's scaling methodology is built on, and the compute-vs-collective
 split the pipeline-bubble work needs. It is a STATIC estimate: payload =
-result-shape bytes per collective instruction, with no overlap model.
+result-shape bytes per collective instruction, with no overlap model. The
+same walk counts the Mosaic (Pallas) kernels compiled into the program,
+by name (`attr/kernel/<name>`).
 
 jax is imported lazily so `llm_training_tpu report` (which imports this
 package) stays usable without touching an accelerator backend.
@@ -317,6 +319,13 @@ _GROUPS_LIST_RE = re.compile(r"replica_groups=\{\{([0-9, ]*)\}")
 # `replica_groups=[4,2]<=[8]` (iota form) — [n_groups, group_size]
 _GROUPS_IOTA_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]<=")
 
+# a compiled Pallas kernel: `custom_call_target="tpu_custom_call"`, whose
+# op_name metadata ends `<kernel name>/pallas_call` (the `name=` every
+# pallas_call in ops/pallas/ carries)
+_KERNEL_RE = re.compile(
+    r'custom_call_target="tpu_custom_call".*?op_name="[^"]*?([^/"]+)/pallas_call'
+)
+
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1,
     "f8e4m3": 1, "f8e4m3fn": 1, "f8e4m3b11fnuz": 1, "f8e4m3fnuz": 1,
@@ -369,6 +378,24 @@ def parse_hlo_collectives(hlo_text: str) -> list[dict]:
             "bytes": _shape_bytes(match.group("shape")),
             "group_size": group_size,
         })
+    return out
+
+
+def parse_hlo_kernels(hlo_text: str) -> dict[str, int]:
+    """{kernel name: call sites} of the Mosaic kernels in a compiled
+    program's text. This is how a run OBSERVES that its Pallas kernels are
+    in the program the chip executes (chip_smoke.py asserts on the gauges),
+    rather than trusting the backend-keyed dispatch that should have put
+    them there; an interpreted or XLA-fallback program has none."""
+    out: dict[str, int] = {}
+    for line in hlo_text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        match = _KERNEL_RE.search(line)
+        name = match.group(1) if match else "unnamed"
+        # autodiff wraps the scope: `transpose(jvp(flash_bwd_dq))`
+        name = re.sub(r"^(?:\w+\()+|\)+$", "", name)
+        out[name] = out.get(name, 0) + 1
     return out
 
 
@@ -441,4 +468,8 @@ def compiled_attribution_gauges(
             out[f"attr/mesh/{name}/collective_bytes"] = by_axis.get(name, 0.0)
     if by_axis.get("unattributed"):
         out["attr/mesh/unattributed/collective_bytes"] = by_axis["unattributed"]
+    kernels = parse_hlo_kernels(hlo_text or "")
+    out["attr/pallas_kernels"] = float(sum(kernels.values()))
+    for name, count in kernels.items():
+        out[f"attr/kernel/{name}"] = float(count)
     return out

@@ -82,10 +82,7 @@ def offload_memory_kinds() -> tuple[str, str]:
     (sharding resolution, memory-kind annotation, the blocked step's
     host/device twins) exercisable in CPU containers instead of raising
     'Could not find memory addressable by device cpu'."""
-    try:
-        kinds = {m.kind for m in jax.devices()[0].addressable_memories()}
-    except Exception:  # an exotic backend without the memories API
-        return "device", "pinned_host"
+    kinds = {m.kind for m in jax.devices()[0].addressable_memories()}
     if "pinned_host" in kinds:
         return ("device" if "device" in kinds else "pinned_host", "pinned_host")
     fallback = "unpinned_host" if "unpinned_host" in kinds else "device"
@@ -1013,8 +1010,9 @@ class Trainer:
         # AOT-compile the hot step up front: the compile lands in its own
         # goodput phase (and compile_time_s gauge) instead of skewing the
         # first step, and the Compiled object exposes XLA's cost/memory
-        # analysis — the cross-check for the analytic MFU model. The jitted
-        # callable stays as fallback (same avals/shardings, same semantics).
+        # analysis — the cross-check for the analytic MFU model. A compile
+        # failure (a kernel Mosaic refuses, a step that outgrows HBM) is a
+        # failure of the fit and raises here.
         # With health on EVERY optimizer step (and no accumulation) the
         # plain step would never execute — skip its compile entirely (the
         # health variant compiles on its first call, billed to the compile
@@ -1026,16 +1024,13 @@ class Trainer:
         t_compile = time.perf_counter()
         with self.ledger.measure("compile"), \
                 tracer.measure("train", "compile"):
-            try:
-                if plain_step_used:
-                    aot_step = train_step.lower(state, sample_batch).compile()
-                else:
-                    logger.info(
-                        "health.every_n_steps=1: skipping the plain-step AOT "
-                        "compile (the health step variant runs every step)"
-                    )
-            except Exception as e:
-                logger.info("AOT pre-compile unavailable (%s); compiling on first step", e)
+            if plain_step_used:
+                aot_step = train_step.lower(state, sample_batch).compile()
+            else:
+                logger.info(
+                    "health.every_n_steps=1: skipping the plain-step AOT "
+                    "compile (the health step variant runs every step)"
+                )
         if aot_step is not None:
             self.telemetry.gauge("compile_time_s").set(time.perf_counter() - t_compile)
             for name, value in compiled_cost_gauges(aot_step).items():

@@ -75,6 +75,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 # and the unit tests so format drift fails identically everywhere; the
 # telemetry package surface is jax-free at import time by contract, so
 # this parent stays backend-free
+from llm_training_tpu.serve.router import require_one_process_per_chip  # noqa: E402
 from llm_training_tpu.telemetry.exporter import parse_prometheus_text  # noqa: E402
 
 # the terminal states the protocol may end a request in — anything else
@@ -210,7 +211,7 @@ def build_requests(args) -> list[dict]:
 
 
 def check_misplaced_flags(
-    serve_args: list[str], passthrough: list[str], argv: list[str] | None = None
+    serve_args: list[str], passthrough: list[str], argv: list[str]
 ) -> None:
     """The PR 16 argparse watch-out, made loud: with an otherwise-empty
     `serve_args` positional, `parse_known_args` assigns the token FOLLOWING
@@ -221,19 +222,21 @@ def check_misplaced_flags(
     command line is that swallow; error loudly and demand `--`. Flags after
     genuine positionals (the precommit idiom: `run_root=/x --max-batch 2`)
     keep order and stay legal."""
-    argv = list(sys.argv[1:]) if argv is None else list(argv)
     if "--" in argv:
         return  # explicit separator: everything after it is intentional
-    unknown_positions = [argv.index(tok) for tok in set(passthrough) if tok in argv]
-    if not unknown_positions:
+    # only FLAG tokens locate the unknown region: a passthrough VALUE
+    # (`--prefill-chunk 4`) can repeat a known flag's value (`--requests 4`)
+    # earlier on the line, and its first occurrence is not where it sits
+    unknown_flags = [
+        argv.index(tok) for tok in passthrough
+        if tok.startswith("--") and tok in argv
+    ]
+    if not unknown_flags:
         return
-    first_unknown = min(unknown_positions)
+    first_unknown = min(unknown_flags)
+    after_unknown = argv[first_unknown + 1:]
     for token in serve_args:
-        try:
-            index = argv.index(token)
-        except ValueError:
-            continue
-        if index > first_unknown:
+        if token in after_unknown:
             raise SystemExit(
                 f"error: positional {token!r} follows the unknown flag "
                 f"{argv[first_unknown]!r} — argparse would silently swallow "
@@ -538,7 +541,7 @@ def run_multi(args) -> int:
     return 1 if failures else 0
 
 
-def main() -> int:
+def build_parser() -> argparse.ArgumentParser:
     parser = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     parser.add_argument("--config", required=True)
     parser.add_argument("--requests", type=int, default=4)
@@ -639,12 +642,22 @@ def main() -> int:
         help="config overrides and extra `serve` flags (e.g. run_root=... "
         "--max-batch 2)",
     )
-    # unknown flags (e.g. --max-batch) pass through to the serve child —
-    # but a flag whose value argparse swallowed into the positional slot
-    # must error loudly, not vanish (see check_misplaced_flags)
-    args, passthrough = parser.parse_known_args()
-    check_misplaced_flags(args.serve_args, passthrough)
+    return parser
+
+
+def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
+    """Unknown flags (e.g. --max-batch) pass through to the serve child —
+    but a flag whose value argparse swallowed into the positional slot must
+    error loudly, not vanish (see check_misplaced_flags)."""
+    argv = list(sys.argv[1:]) if argv is None else list(argv)
+    args, passthrough = build_parser().parse_known_args(argv)
+    check_misplaced_flags(args.serve_args, passthrough, argv)
     args.serve_args += passthrough
+    return args
+
+
+def main() -> int:
+    args = parse_args()
 
     if args.router and (args.supervised or args.malformed or args.replicas > 1):
         print(
@@ -652,6 +665,12 @@ def main() -> int:
             "--replicas (the router owns its own fleet)", file=sys.stderr,
         )
         return 2
+    # several children on one accelerator is an immediate, named error —
+    # never a hang at the second child's backend start-up
+    require_one_process_per_chip(
+        max(args.router_replicas, args.router_max_replicas or 0)
+        if args.router else args.replicas
+    )
     if args.replicas > 1:
         return run_multi(args)
 

@@ -1,0 +1,204 @@
+"""The main path's Pallas kernels, compiled for a TPU v5e that is described
+and not attached (guide `on-chip-measurement` §2.3).
+
+The interpreter enforces none of Mosaic's rules — block tiling, VMEM, that a
+kernel cannot be partitioned by GSPMD — so a kernel can pass every
+interpret-mode test and still be refused by the chip's compiler. These
+compiles run what that compiler runs, at the real serving/training widths,
+on the CPU sandbox: a refusal fails here and costs no chip time. Nothing
+executes, so they say nothing about results or speed.
+
+One file, one process: a second process asking for the topology while this
+one holds it aborts on libtpu's multi-process lock file (tier-1 runs
+`-p no:xdist`). Where the topology cannot be described, or the lock is held,
+the compiles skip. The persistent compile cache is off for the whole test
+process (tests/conftest.py): such an entry could be written but never read
+back without a chip.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+from llm_training_tpu.ops.pallas import resolve_interpret
+from llm_training_tpu.ops.pallas.flash_attention import flash_bwd_flat, flash_fwd_flat
+from llm_training_tpu.ops.pallas.paged_attention import paged_decode_attention
+from llm_training_tpu.telemetry.device import parse_hlo_kernels
+
+# Llama-3.1-8B attention at the chip smoke's shape: 32 query / 8 kv heads of
+# 128, sequence 8192, bf16 (config/examples/smoke/chip-smoke.yaml)
+SEQ, Q_HEADS, KV_HEADS, HEAD_DIM = 8192, 32, 8, 128
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu, or its lock file is held
+        pytest.skip(f"no described v5e topology here: {type(e).__name__}: {str(e)[:200]}")
+
+
+@pytest.fixture
+def as_on_tpu(monkeypatch):
+    """Steer the backend-keyed dispatch the chip's way: a described device
+    leaves `jax.default_backend()` at 'cpu', which would pick the
+    interpreter and skip the v5e tuning-table entries."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _kernels(fn, *shapes) -> dict:
+    return parse_hlo_kernels(jax.jit(fn).lower(*shapes).compile().as_text())
+
+
+def _flat_shapes(device):
+    """Flat flash operands: q/do [B*Hq, S, D], k/v [B*Hkv, S, D], seg [B, S],
+    lse/delta [B*Hq, S]."""
+    one = SingleDeviceSharding(device)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    q = shape((Q_HEADS, SEQ, HEAD_DIM), jnp.bfloat16)
+    kv = shape((KV_HEADS, SEQ, HEAD_DIM), jnp.bfloat16)
+    seg = shape((1, SEQ), jnp.int32)
+    row = shape((Q_HEADS, SEQ), jnp.float32)
+    return q, kv, seg, row
+
+
+_HEADS = dict(num_q_heads=Q_HEADS, num_kv_heads=KV_HEADS, scale=1.0, causal=True)
+
+
+def test_flash_forward_compiles_for_v5e(v5e, as_on_tpu):
+    q, kv, seg, _ = _flat_shapes(v5e.devices[0])
+    found = _kernels(
+        lambda q, k, v, sq, sk: flash_fwd_flat(q, k, v, sq, sk, **_HEADS),
+        q, kv, kv, seg, seg,
+    )
+    assert found == {"flash_fwd": 1}, found
+
+
+@pytest.mark.parametrize("kernel,outputs", [
+    ("flash_bwd_dq", slice(0, 1)), ("flash_bwd_dkv", slice(1, 3)),
+])
+def test_flash_backward_compiles_for_v5e(v5e, as_on_tpu, kernel, outputs):
+    """dq and dk/dv are separate pallas_calls; keeping only one's outputs
+    lets the compiler drop the other, so each is compiled (and timed out of
+    the other's way) on its own."""
+    q, kv, seg, row = _flat_shapes(v5e.devices[0])
+    found = _kernels(
+        lambda q, k, v, sq, sk, do, lse, delta: flash_bwd_flat(
+            q, k, v, sq, sk, do, lse, delta, **_HEADS
+        )[outputs],
+        q, kv, kv, seg, seg, q, row, row,
+    )
+    assert found == {kernel: 1}, found
+
+
+@pytest.mark.parametrize("page_size", [16, 128])
+def test_paged_decode_compiles_for_v5e(v5e, page_size):
+    """The serving decode kernel at head_dim 128 / GQA group 4, at the page
+    size `resolve_paged_block_size` picks (16) and one other. The seed's
+    `[blocks, page, kv_heads, head_dim]` pool was refused here: a
+    (1, page, 1, head_dim) block is not an (8, 128) tile of it."""
+    one = SingleDeviceSharding(v5e.devices[0])
+    batch, blocks, pages = 4, 512, 2048 // page_size
+    pool = jax.ShapeDtypeStruct(
+        (blocks, KV_HEADS, page_size, HEAD_DIM), jnp.bfloat16, sharding=one
+    )
+    found = _kernels(
+        lambda q, k, v, tables, lens: paged_decode_attention(
+            q, k, v, tables, lens, interpret=False
+        ),
+        jax.ShapeDtypeStruct((batch, Q_HEADS, HEAD_DIM), jnp.bfloat16, sharding=one),
+        pool, pool,
+        jax.ShapeDtypeStruct((batch, pages), jnp.int32, sharding=one),
+        jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=one),
+    )
+    assert found == {"paged_decode": 1}, found
+
+
+def test_flash_compiles_on_a_sharded_mesh_for_v5e(v5e, as_on_tpu):
+    """GSPMD refuses any sharded program that holds a bare Mosaic kernel
+    ("Mosaic kernels cannot be automatically partitioned") — every fsdp or
+    tensor-parallel fit on a TPU. `dot_product_attention` therefore runs the
+    kernel in a shard_map over the active mesh; this is the compile that
+    refusal came from, on fsdp=2 x tensor=2."""
+    import numpy as np
+
+    from llm_training_tpu.ops.attention import dot_product_attention
+    from llm_training_tpu.parallel.mesh import MESH_AXIS_NAMES
+
+    mesh = Mesh(np.asarray(v5e.devices).reshape(1, 1, 2, 1, 2, 1), MESH_AXIS_NAMES)
+    qkv = NamedSharding(mesh, P("fsdp", None, "tensor", None))
+
+    def shape(heads):
+        return jax.ShapeDtypeStruct((4, 2048, heads, HEAD_DIM), jnp.bfloat16, sharding=qkv)
+
+    seg = jax.ShapeDtypeStruct(
+        (4, 2048), jnp.int32, sharding=NamedSharding(mesh, P("fsdp", None))
+    )
+    with mesh:
+        found = _kernels(
+            lambda q, k, v, seg: dot_product_attention(
+                q, k, v, segment_ids=seg, impl="auto"
+            ),
+            shape(Q_HEADS), shape(KV_HEADS), shape(KV_HEADS), seg,
+        )
+    assert found == {"flash_fwd": 1}, found
+
+
+def test_paged_decode_compiles_on_a_sharded_mesh_for_v5e(v5e, as_on_tpu):
+    """The serving twin of the test above: `paged_cached_attention` with kv
+    heads sharded over `tensor`, as the pool is under a serving mesh."""
+    import numpy as np
+
+    from llm_training_tpu.ops.paged_attention import paged_cached_attention
+    from llm_training_tpu.parallel.mesh import MESH_AXIS_NAMES
+
+    mesh = Mesh(np.asarray(v5e.devices).reshape(1, 1, 2, 1, 2, 1), MESH_AXIS_NAMES)
+
+    def shape(dims, dtype, spec):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=NamedSharding(mesh, spec))
+
+    batch, blocks, page, pages = 4, 512, 16, 128
+    heads = P(None, None, "tensor", None)
+    pool = shape((blocks, KV_HEADS, page, HEAD_DIM), jnp.bfloat16, P(None, "tensor", None, None))
+    with mesh:
+        found = _kernels(
+            lambda q, k, v, pk, pv, lens, tables: paged_cached_attention(
+                q, k, v, (pk, pv), lens, tables, impl="auto"
+            )[0],
+            shape((batch, 1, Q_HEADS, HEAD_DIM), jnp.bfloat16, heads),
+            shape((batch, 1, KV_HEADS, HEAD_DIM), jnp.bfloat16, heads),
+            shape((batch, 1, KV_HEADS, HEAD_DIM), jnp.bfloat16, heads),
+            pool, pool,
+            shape((batch,), jnp.int32, P()), shape((batch, pages), jnp.int32, P()),
+        )
+    assert found == {"paged_decode": 1}, found
+
+
+def test_paged_decode_refuses_untileable_shapes_when_compiled():
+    """Small head_dims (the CPU smoke's 8, 16) need not compile for the
+    chip, but must fail with a clear error there, not be routed elsewhere."""
+    q = jnp.zeros((2, 4, 16))
+    pool = jnp.zeros((5, 2, 8, 16))
+    tables = jnp.zeros((2, 2), jnp.int32)
+    with pytest.raises(ValueError, match="head_dim 16"):
+        paged_decode_attention(q, pool, pool, tables, jnp.ones((2,), jnp.int32),
+                               interpret=False)
+
+
+def test_interpret_is_impossible_on_a_tpu_backend(monkeypatch):
+    assert resolve_interpret(None) is True  # the CPU test path
+    assert resolve_interpret(False) is False  # compiling for a described device
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_interpret(None) is False
+    with pytest.raises(ValueError, match="interpret=True on a TPU"):
+        resolve_interpret(True)

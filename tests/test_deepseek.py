@@ -63,7 +63,7 @@ def _parity(hf_model, hf_config, seed):
     ids = np.random.default_rng(seed).integers(0, 128, (2, 24))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=3e-4, atol=3e-4)
     return cfg, params, model
 
@@ -109,7 +109,7 @@ def test_kimi_k2_routes_as_deepseek_v3():
     ids = np.random.default_rng(31).integers(0, 128, (2, 24))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = Deepseek(cfg).apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(Deepseek(cfg).apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=3e-4, atol=3e-4)
 
 
@@ -169,9 +169,9 @@ def test_ragged_and_dense_impls_agree():
     cfg_r = DeepseekConfig(**TINY, n_group=4, topk_group=2, moe_impl="ragged")
     model_d, model_r = Deepseek(cfg_d), Deepseek(cfg_r)
     ids = jnp.asarray(np.random.default_rng(34).integers(0, 128, (2, 16)))
-    params = model_d.init(jax.random.key(7), ids)
-    out_d = model_d.apply(params, ids).logits
-    out_r = model_r.apply(params, ids).logits
+    params = jax.jit(model_d.init)(jax.random.key(7), ids)
+    out_d = jax.jit(model_d.apply)(params, ids).logits
+    out_r = jax.jit(model_r.apply)(params, ids).logits
     np.testing.assert_allclose(np.asarray(out_d), np.asarray(out_r), rtol=2e-5, atol=2e-5)
 
 
@@ -220,7 +220,7 @@ def test_export_reloads_in_transformers(tmp_path):
     cfg = DeepseekConfig(**TINY, n_group=4, topk_group=2)
     model = Deepseek(cfg)
     ids = jnp.asarray(np.random.default_rng(35).integers(0, 128, (2, 16)))
-    params = model.init(jax.random.key(8), ids)
+    params = jax.jit(model.init)(jax.random.key(8), ids)
     out_dir = save_hf_checkpoint(params, cfg, tmp_path / "export", dtype="float32")
 
     hf_model = AutoModelForCausalLM.from_pretrained(
@@ -229,7 +229,7 @@ def test_export_reloads_in_transformers(tmp_path):
     assert type(hf_model).__name__ == "DeepseekV3ForCausalLM"
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(np.asarray(ids))).logits.numpy()
-    ours = model.apply(params, ids).logits
+    ours = jax.jit(model.apply)(params, ids).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=3e-4, atol=3e-4)
 
 
@@ -282,5 +282,5 @@ def test_hf_causal_lm_loads_deepseek_checkpoint(tmp_path):
     ids = np.random.default_rng(37).integers(0, 128, (2, 16))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(jax.tree.map(jnp.asarray, params), jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(jax.tree.map(jnp.asarray, params), jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=3e-4, atol=3e-4)

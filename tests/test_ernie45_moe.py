@@ -70,7 +70,7 @@ def test_logits_parity_with_hf():
     ids = np.random.default_rng(96).integers(0, 128, (2, 24))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=4e-4, atol=4e-4)
 
 
@@ -113,7 +113,7 @@ def test_clm_fused_loss_applies_tied_head_bias():
     cfg = Ernie45MoeConfig(**TINY)
     model = Ernie45Moe(cfg)
     ids = jnp.asarray(np.random.default_rng(97).integers(1, 128, (2, 16)))
-    params = model.init(jax.random.key(14), ids)
+    params = jax.jit(model.init)(jax.random.key(14), ids)
     # salt the zero-init head bias so it is LIVE
     import flax.linen as fnn
     leaf = params["params"]["lm_head_bias"]
@@ -125,7 +125,7 @@ def test_clm_fused_loss_applies_tied_head_bias():
     objective = CLM(CLMConfig(), model=model)
     loss, _ = objective.loss_and_metrics(params, {"input_ids": ids}, train=False)
 
-    logits = model.apply(params, ids).logits
+    logits = jax.jit(model.apply)(params, ids).logits
     shifted = np.full(ids.shape, -100)
     shifted[:, :-1] = np.asarray(ids)[:, 1:]
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)

@@ -46,9 +46,9 @@ def test_ep_dispatch_matches_dense(ep_mesh):
     cfg_r = LlamaConfig(**TINY_MOE, moe_impl="ragged")
     cfg_d = LlamaConfig(**TINY_MOE, moe_impl="dense")
     model_r, model_d = Llama(cfg_r), Llama(cfg_d)
-    params = model_d.init(jax.random.key(0), ids)
+    params = jax.jit(model_d.init)(jax.random.key(0), ids)
 
-    out_d = model_d.apply(params, ids)  # no mesh: plain dense reference
+    out_d = jax.jit(model_d.apply)(params, ids)  # no mesh: plain dense reference
     with ep_mesh:
         out_ep = jax.jit(lambda p, x: model_r.apply(p, x).logits)(params, ids)
     np.testing.assert_allclose(
@@ -63,11 +63,11 @@ def test_ep_grads_match_dense(ep_mesh):
     cfg_r = LlamaConfig(**TINY_MOE, moe_impl="ragged")
     cfg_d = LlamaConfig(**TINY_MOE, moe_impl="dense")
     model_r, model_d = Llama(cfg_r), Llama(cfg_d)
-    params = model_d.init(jax.random.key(1), ids)
+    params = jax.jit(model_d.init)(jax.random.key(1), ids)
 
     def loss(model):
         def f(p):
-            return jnp.mean(model.apply(p, ids).logits.astype(jnp.float32) ** 2)
+            return jnp.mean(jax.jit(model.apply)(p, ids).logits.astype(jnp.float32) ** 2)
         return f
 
     g_d = jax.grad(loss(model_d))(params)
@@ -124,7 +124,7 @@ def test_ep_dropped_rows_metric_flows_to_output(ep_mesh):
     ids = jnp.asarray(np.random.default_rng(3).integers(0, 128, (4, 16)))
     cfg = LlamaConfig(**TINY_MOE, moe_impl="ragged")
     model = Llama(cfg)
-    params = model.init(jax.random.key(0), ids)
+    params = jax.jit(model.init)(jax.random.key(0), ids)
     with ep_mesh:
         out = jax.jit(lambda p, x: model.apply(p, x))(params, ids)
     assert out.ep_dropped_rows is not None
@@ -141,7 +141,7 @@ def test_ep_dropped_rows_flow_deepseek_scan_route(ep_mesh):
 
     ids = jnp.asarray(np.random.default_rng(4).integers(0, 128, (2, 16)))
     model = Deepseek(DeepseekConfig(**TINY, n_group=4, topk_group=2, moe_impl="ragged"))
-    params = model.init(jax.random.key(0), ids)
+    params = jax.jit(model.init)(jax.random.key(0), ids)
     with ep_mesh:
         out = jax.jit(lambda p, x: model.apply(p, x))(params, ids)
     assert out.ep_dropped_rows is not None
@@ -153,7 +153,7 @@ def test_ep_requires_divisible_experts(ep_mesh):
                       moe_impl="ragged")
     model = Llama(cfg)
     ids = jnp.zeros((2, 16), jnp.int32)
-    params = model.init(jax.random.key(0), ids)
+    params = jax.jit(model.init)(jax.random.key(0), ids)
     with ep_mesh:
         with pytest.raises(ValueError, match="divide"):
             jax.jit(lambda p, x: model.apply(p, x).logits)(params, ids)

@@ -576,17 +576,22 @@ def test_perf_ledger_improvements_never_flag(tmp_path):
 
 
 def test_bench_check_regression_cli(tmp_path):
-    """The real `bench.py --check-regression` entry, exit codes included —
-    and the committed r01..rNN history must gate clean (the acceptance
-    bar: a regressed round exits nonzero, the real board exits 0)."""
+    """The real `bench.py --check-regression` entry, exit codes included
+    (the acceptance bar: a clean board exits 0, a regressed round exits
+    nonzero). The repo commits no rounds, so the clean board is written
+    here."""
     import subprocess
     import sys
     from pathlib import Path
 
     repo = Path(__file__).resolve().parent.parent
     bench = str(repo / "bench.py")
+    clean = tmp_path / "clean"
+    clean.mkdir()
+    _write_round(clean, 1, value=0.5, backend="tpu", model="m")
+    _write_round(clean, 2, wrapped=True, value=0.51, backend="tpu", model="m")
     result = subprocess.run(
-        [sys.executable, bench, "--check-regression", "--bench-dir", str(repo)],
+        [sys.executable, bench, "--check-regression", "--bench-dir", str(clean)],
         capture_output=True, text=True,
     )
     assert result.returncode == 0, result.stdout + result.stderr

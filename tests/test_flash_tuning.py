@@ -210,10 +210,10 @@ def test_table_blocks_reach_kernel_and_telemetry(monkeypatch, tmp_path):
         cot = jnp.asarray(rng.standard_normal((1, 512, 4, 64)), jnp.float32)
 
         def grad_norm():
-            g = jax.grad(
+            g = jax.jit(jax.grad(
                 lambda q, k, v: (flash_attention(q, k, v, causal=True) * cot).sum(),
                 argnums=(0, 1, 2),
-            )(q, k, v)
+            ))(q, k, v)
             return [np.asarray(x) for x in g]
 
         base = grad_norm()
@@ -252,11 +252,11 @@ def test_explicit_fwd_blocks_tile_both_passes():
     previous = set_registry(registry)
     try:
         q = jnp.ones((1, 512, 2, 64), jnp.float32)
-        jax.grad(
+        jax.jit(jax.grad(
             lambda q: flash_attention(
                 q, q, q, causal=True, block_q=256, block_k=128, interpret=True
             ).sum()
-        )(q)
+        ))(q)
         snap = registry.snapshot()
         assert (snap["flash/fwd/block_q"], snap["flash/fwd/block_k"]) == (256, 128)
         assert (snap["flash/bwd/block_q"], snap["flash/bwd/block_k"]) == (256, 128)
@@ -274,11 +274,11 @@ def test_single_explicit_bwd_knob_keeps_env_for_other(monkeypatch):
     previous = set_registry(registry)
     try:
         q = jnp.ones((1, 512, 2, 64), jnp.float32)
-        jax.grad(
+        jax.jit(jax.grad(
             lambda q: flash_attention(
                 q, q, q, causal=True, bwd_block_q=256, interpret=True
             ).sum()
-        )(q)
+        ))(q)
         snap = registry.snapshot()
         assert (snap["flash/bwd/block_q"], snap["flash/bwd/block_k"]) == (256, 128)
     finally:
@@ -296,11 +296,11 @@ def test_explicit_fwd_blocks_respect_bwd_env(monkeypatch):
     previous = set_registry(registry)
     try:
         q = jnp.ones((1, 512, 2, 64), jnp.float32)
-        jax.grad(
+        jax.jit(jax.grad(
             lambda q: flash_attention(
                 q, q, q, causal=True, block_q=256, block_k=256, interpret=True
             ).sum()
-        )(q)
+        ))(q)
         snap = registry.snapshot()
         assert (snap["flash/fwd/block_q"], snap["flash/fwd/block_k"]) == (256, 256)
         assert (snap["flash/bwd/block_q"], snap["flash/bwd/block_k"]) == (128, 128)
@@ -341,11 +341,11 @@ def test_single_explicit_fwd_knob_inherits_per_knob(monkeypatch, tmp_path):
     previous = set_registry(registry)
     try:
         q = jnp.ones((1, 512, 2, 64), jnp.float32)
-        jax.grad(
+        jax.jit(jax.grad(
             lambda q: flash_attention(
                 q, q, q, causal=True, block_q=256, interpret=True
             ).sum()
-        )(q)
+        ))(q)
         snap = registry.snapshot()
         assert (snap["flash/bwd/block_q"], snap["flash/bwd/block_k"]) == (256, 128)
     finally:
@@ -367,11 +367,11 @@ def test_explicit_fwd_blocks_ignore_bwd_table(monkeypatch, tmp_path):
     previous = set_registry(registry)
     try:
         q = jnp.ones((1, 512, 2, 64), jnp.float32)
-        jax.grad(
+        jax.jit(jax.grad(
             lambda q: flash_attention(
                 q, q, q, causal=True, block_q=256, block_k=256, interpret=True
             ).sum()
-        )(q)
+        ))(q)
         snap = registry.snapshot()
         assert (snap["flash/bwd/block_q"], snap["flash/bwd/block_k"]) == (256, 256)
     finally:
@@ -411,8 +411,8 @@ def test_forward_only_trace_records_no_bwd_gauges():
         assert not any(k.startswith("flash/bwd/") for k in snap), snap
         assert snap.get("flash/tuning_table_hit/default", 0) == 1.0  # fwd only
         # ...and the backward records exactly once a grad trace exists
-        jax.grad(lambda q: flash_attention(
-            q, q, q, causal=True, interpret=True).sum())(q)
+        jax.jit(jax.grad(lambda q: flash_attention(
+            q, q, q, causal=True, interpret=True).sum()))(q)
         snap = registry.snapshot()
         assert snap["flash/bwd/block_q"] == 256
     finally:
@@ -529,3 +529,39 @@ def test_bench_chaos_crash_degrades_stage_not_run():
     # dependent stages skipped, not hung
     assert summary["stages"]["train"]["status"] == "skipped"
     assert proc.returncode == 1
+
+
+# ------------------------------------------- bench measures a TPU or nothing
+
+
+def test_bench_refuses_to_measure_without_a_tpu():
+    """Outside --dry a non-TPU backend is an error at backend_init (so no
+    later stage runs); --dry passes and reports what it ran on."""
+    with pytest.raises(SystemExit, match="bench needs a TPU"):
+        bench.stage_backend_init(dry=False)
+    record = bench.stage_backend_init(dry=True)
+    assert record["backend"] == "cpu" and record["n_devices"] >= 1
+
+
+def test_bench_has_one_peak_table_and_no_cpu_peak(monkeypatch):
+    from llm_training_tpu.callbacks import time_estimator
+
+    assert not hasattr(bench, "_PEAK_FLOPS") and not hasattr(bench, "_detect_peak")
+    assert time_estimator.peak_flops_per_device() is None  # this CPU: unknown kind
+
+    class Device:
+        device_kind = "TPU v5 lite"
+
+    monkeypatch.setattr(time_estimator.jax, "devices", lambda: [Device()])
+    assert time_estimator.peak_flops_per_device() == 197e12
+
+
+def test_bench_dry_shrinks_by_flag_not_by_backend(monkeypatch):
+    for name in ("BENCH_MODEL", "BENCH_SEQ", "BENCH_BATCH", "BENCH_LAYERS",
+                 "BENCH_HIDDEN", "BENCH_STEPS", "BENCH_WARMUP", "BENCH_REMAT"):
+        monkeypatch.delenv(name, raising=False)
+    kwargs, seq, batch, steps, warmup = bench._model_setup(dry=True)
+    assert (kwargs["hidden_size"], seq, batch, steps, warmup) == (128, 2048, 4, 3, 1)
+    kwargs, seq, batch, steps, warmup = bench._model_setup(dry=False)
+    assert (kwargs["hidden_size"], kwargs["intermediate_size"], seq, batch) == (
+        4096, 14336, 8192, 3)

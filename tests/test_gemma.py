@@ -55,8 +55,8 @@ def test_forward_shapes():
     cfg = GemmaConfig(**TINY_V1)
     model = Gemma(cfg)
     ids = jnp.ones((2, 10), jnp.int32)
-    params = model.init(jax.random.key(0), ids)
-    out = model.apply(params, ids, return_last_hidden_states=True)
+    params = jax.jit(model.init)(jax.random.key(0), ids)
+    out = jax.jit(model.apply)(params, ids, return_last_hidden_states=True)
     assert out.logits.shape == (2, 10, 128)
     assert out.last_hidden_states.shape == (2, 10, 64)
 
@@ -124,7 +124,7 @@ def test_logits_parity_with_hf_gemma1():
     ids = np.random.default_rng(7).integers(0, 128, (2, 16))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
 
@@ -142,7 +142,7 @@ def test_logits_parity_with_hf_gemma2():
     ids = np.random.default_rng(8).integers(0, 128, (2, 24))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
 
@@ -194,7 +194,7 @@ def test_hf_causal_lm_loads_gemma2_checkpoint(tmp_path):
     ids = np.random.default_rng(10).integers(0, 128, (2, 24))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(jax.tree.map(jnp.asarray, params), jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(jax.tree.map(jnp.asarray, params), jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
 
@@ -249,7 +249,7 @@ def test_logits_parity_with_hf_gemma3():
     ids = np.random.default_rng(9).integers(0, 128, (2, 24))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
 
@@ -289,12 +289,12 @@ def test_clm_fused_loss_applies_final_softcap():
     )
     model = Gemma(cfg)
     ids = jnp.asarray(np.random.default_rng(21).integers(1, 128, (2, 16)))
-    params = model.init(jax.random.key(6), ids)
+    params = jax.jit(model.init)(jax.random.key(6), ids)
 
     objective = CLM(CLMConfig(), model=model)
     loss, _ = objective.loss_and_metrics(params, {"input_ids": ids}, train=False)
 
-    logits = model.apply(params, ids).logits  # capped by compute_logits
+    logits = jax.jit(model.apply)(params, ids).logits  # capped by compute_logits
     shifted = np.full(ids.shape, -100)
     shifted[:, :-1] = np.asarray(ids)[:, 1:]
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)

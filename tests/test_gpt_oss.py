@@ -72,7 +72,7 @@ def test_logits_parity_with_hf():
     ids = np.random.default_rng(50).integers(0, 128, (2, 24))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=3e-4, atol=3e-4)
 
 
@@ -108,9 +108,9 @@ def test_ragged_and_dense_impls_agree():
     cfg_r = GptOssConfig(**TINY, moe_impl="ragged")
     model_d, model_r = GptOss(cfg_d), GptOss(cfg_r)
     ids = jnp.asarray(np.random.default_rng(51).integers(0, 128, (2, 16)))
-    params = model_d.init(jax.random.key(10), ids)
-    out_d = model_d.apply(params, ids).logits
-    out_r = model_r.apply(params, ids).logits
+    params = jax.jit(model_d.init)(jax.random.key(10), ids)
+    out_d = jax.jit(model_d.apply)(params, ids).logits
+    out_r = jax.jit(model_r.apply)(params, ids).logits
     np.testing.assert_allclose(np.asarray(out_d), np.asarray(out_r), rtol=2e-5, atol=2e-5)
 
 
@@ -156,7 +156,7 @@ def test_export_reloads_in_transformers(tmp_path):
     cfg = GptOssConfig(**TINY)
     model = GptOss(cfg)
     ids = jnp.asarray(np.random.default_rng(52).integers(0, 128, (2, 16)))
-    params = model.init(jax.random.key(11), ids)
+    params = jax.jit(model.init)(jax.random.key(11), ids)
     out_dir = save_hf_checkpoint(params, cfg, tmp_path / "export", dtype="float32")
 
     hf_model = AutoModelForCausalLM.from_pretrained(
@@ -165,7 +165,7 @@ def test_export_reloads_in_transformers(tmp_path):
     assert type(hf_model).__name__ == "GptOssForCausalLM"
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(np.asarray(ids))).logits.numpy()
-    ours = model.apply(params, ids).logits
+    ours = jax.jit(model.apply)(params, ids).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=3e-4, atol=3e-4)
 
 

@@ -95,8 +95,8 @@ def test_export_roundtrip_through_transformers(tmp_path):
     )
     model = Llama(cfg)
     ids = jnp.asarray(np.random.default_rng(0).integers(0, 128, (2, 16), np.int32))
-    params = model.init(jax.random.key(0), ids)
-    ours = model.apply(params, ids).logits
+    params = jax.jit(model.init)(jax.random.key(0), ids)
+    ours = jax.jit(model.apply)(params, ids).logits
 
     out = save_hf_checkpoint(params, cfg, tmp_path / "export", dtype="float32")
     hf_model = LlamaForCausalLM.from_pretrained(out, torch_dtype=torch.float32)
@@ -110,7 +110,7 @@ def test_sharded_export(tmp_path):
     """Multiple safetensors shards + index.json when over the shard budget."""
     cfg = LlamaConfig(**TINY_HF, compute_dtype="float32", param_dtype="float32")
     model = Llama(cfg)
-    params = model.init(jax.random.key(0), jnp.ones((1, 4), jnp.int32))
+    params = jax.jit(model.init)(jax.random.key(0), jnp.ones((1, 4), jnp.int32))
     out = save_hf_checkpoint(
         params, cfg, tmp_path / "sharded", dtype="float32", max_shard_bytes=200_000
     )
@@ -200,7 +200,7 @@ def test_convert_to_hf_script(tmp_path):
     ids = np.random.default_rng(1).integers(0, 128, (2, 12), np.int64)
     with torch.no_grad():
         theirs = hf_model(torch.from_numpy(ids)).logits.numpy()
-    ours = objective.model.apply(
+    ours = objective.jax.jit(model.apply)(
         jax.device_get(state.params), jnp.asarray(ids, jnp.int32)
     ).logits
     np.testing.assert_allclose(np.asarray(ours), theirs, atol=2e-4, rtol=2e-3)

@@ -62,7 +62,7 @@ def test_logits_parity_with_hf():
     ids = np.random.default_rng(99).integers(0, 128, (2, 24))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=4e-4, atol=4e-4)
 
 
@@ -74,8 +74,8 @@ def test_scan_and_loop_layers_agree():
     ps = params_from_hf(sd, cfg_s)
     pl = params_from_hf(sd, cfg_l)
     ids = jnp.asarray(np.random.default_rng(100).integers(0, 128, (1, 16)))
-    out_s = HunYuanMoe(cfg_s).apply(ps, ids).logits
-    out_l = HunYuanMoe(cfg_l).apply(pl, ids).logits
+    out_s = jax.jit(HunYuanMoe(cfg_s).apply)(ps, ids).logits
+    out_l = jax.jit(HunYuanMoe(cfg_l).apply)(pl, ids).logits
     np.testing.assert_allclose(np.asarray(out_s), np.asarray(out_l), rtol=2e-5, atol=2e-5)
 
 

@@ -6,6 +6,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import full_forward_greedy as _full_forward_greedy
 
 from llm_training_tpu.infer import (
     GenerateConfig,
@@ -32,16 +33,7 @@ TINY = dict(
 
 
 def _init(model, seed=0):
-    return model.init(jax.random.key(seed), np.zeros((1, 4), np.int32))
-
-
-def _full_forward_greedy(model, variables, prompt, n):
-    """The oracle: n argmax tokens from n FULL forward passes (no cache)."""
-    seq = list(prompt)
-    for _ in range(n):
-        out = model.apply(variables, input_ids=jnp.asarray([seq]))
-        seq.append(int(jnp.argmax(out.logits[0, -1])))
-    return seq[len(prompt):]
+    return jax.jit(model.init)(jax.random.key(seed), np.zeros((1, 4), np.int32))
 
 
 # ------------------------------------------------------------ greedy parity

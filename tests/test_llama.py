@@ -28,8 +28,8 @@ TINY = dict(
 
 def _init_and_run(cfg, ids, **kwargs):
     model = Llama(cfg)
-    params = model.init(jax.random.key(0), ids)
-    return model.apply(params, ids, **kwargs), params
+    params = jax.jit(model.init)(jax.random.key(0), ids)
+    return jax.jit(model.apply)(params, ids, **kwargs), params
 
 
 @pytest.mark.slow
@@ -55,14 +55,14 @@ def test_scan_and_loop_layers_agree():
     ids = jnp.asarray(np.random.default_rng(0).integers(0, 128, (2, 12)))
     cfg_scan = LlamaConfig(**TINY, scan_layers=True)
     model_scan = Llama(cfg_scan)
-    params_scan = model_scan.init(jax.random.key(0), ids)
+    params_scan = jax.jit(model_scan.init)(jax.random.key(0), ids)
 
     # restack scanned params into per-layer trees for the loop model
     hf_sd = params_to_hf(jax.tree.map(lambda x: x, params_scan["params"]), cfg_scan)
     cfg_loop = LlamaConfig(**TINY, scan_layers=False)
     params_loop = params_from_hf(hf_sd, cfg_loop)
 
-    out_scan = model_scan.apply(params_scan, ids)
+    out_scan = jax.jit(model_scan.apply)(params_scan, ids)
     out_loop = Llama(cfg_loop).apply(params_loop, ids)
     np.testing.assert_allclose(out_scan.logits, out_loop.logits, rtol=2e-5, atol=1e-5)
 
@@ -73,7 +73,7 @@ def test_remat_matches_no_remat(granularity):
     ids = jnp.asarray(np.random.default_rng(1).integers(0, 128, (1, 8)))
     cfg = LlamaConfig(**TINY)
     model = Llama(cfg)
-    params = model.init(jax.random.key(0), ids)
+    params = jax.jit(model.init)(jax.random.key(0), ids)
 
     cfg_remat = LlamaConfig(
         **TINY, enable_gradient_checkpointing=True, recompute_granularity=granularity
@@ -96,11 +96,11 @@ def test_tied_embeddings():
     cfg = LlamaConfig(**{**TINY, "tie_word_embeddings": True})
     ids = jnp.ones((1, 4), jnp.int32)
     model = Llama(cfg)
-    params = model.init(jax.random.key(0), ids)
+    params = jax.jit(model.init)(jax.random.key(0), ids)
     flat = jax.tree_util.tree_leaves_with_path(params)
     names = [jax.tree_util.keystr(p) for p, _ in flat]
     assert not any("lm_head" in n for n in names)
-    out = model.apply(params, ids)
+    out = jax.jit(model.apply)(params, ids)
     assert out.logits.shape == (1, 4, 128)
 
 
@@ -116,11 +116,11 @@ def test_packed_forward_matches_separate_docs():
     packed = jnp.asarray(np.concatenate([doc_a, doc_b])[None])
     segment_ids = jnp.asarray([[1] * 5 + [2] * 7])
     position_ids = jnp.asarray([list(range(5)) + list(range(7))])
-    params = model.init(jax.random.key(0), packed)
+    params = jax.jit(model.init)(jax.random.key(0), packed)
 
-    out = model.apply(params, packed, segment_ids=segment_ids, position_ids=position_ids)
-    out_a = model.apply(params, jnp.asarray(doc_a[None]))
-    out_b = model.apply(params, jnp.asarray(doc_b[None]))
+    out = jax.jit(model.apply)(params, packed, segment_ids=segment_ids, position_ids=position_ids)
+    out_a = jax.jit(model.apply)(params, jnp.asarray(doc_a[None]))
+    out_b = jax.jit(model.apply)(params, jnp.asarray(doc_b[None]))
     np.testing.assert_allclose(out.logits[0, :5], out_a.logits[0], rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(out.logits[0, 5:], out_b.logits[0], rtol=1e-4, atol=1e-5)
 
@@ -168,7 +168,7 @@ def test_logits_parity_with_hf(rope_scaling):
     ids = np.random.default_rng(3).integers(0, 128, (2, 16))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
 
@@ -206,7 +206,7 @@ def test_logits_parity_with_hf_mistral():
     ids = np.random.default_rng(4).integers(0, 128, (2, 24))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
 
@@ -235,7 +235,7 @@ def test_logits_parity_with_hf_qwen2():
     ids = np.random.default_rng(5).integers(0, 128, (2, 24))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
 
@@ -265,7 +265,7 @@ def test_logits_parity_with_hf_qwen3():
     ids = np.random.default_rng(6).integers(0, 128, (2, 24))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
 
@@ -299,7 +299,7 @@ def test_logits_parity_with_hf_olmo2():
     ids = np.random.default_rng(12).integers(0, 128, (2, 24))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
 
@@ -315,14 +315,14 @@ def test_qwen3_export_round_trip(tmp_path):
     cfg = LlamaConfig(**TINY, qk_norm=True, head_dim=16)
     model = Llama(cfg)
     ids = jnp.asarray(np.random.default_rng(11).integers(0, 128, (2, 16)))
-    params = model.init(jax.random.key(2), ids)
+    params = jax.jit(model.init)(jax.random.key(2), ids)
     out_dir = save_hf_checkpoint(params, cfg, tmp_path / "export", dtype="float32")
 
     hf_model = AutoModelForCausalLM.from_pretrained(out_dir, attn_implementation="eager").eval()
     assert type(hf_model).__name__ == "Qwen3ForCausalLM"
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(np.asarray(ids))).logits.numpy()
-    ours = model.apply(params, ids).logits
+    ours = jax.jit(model.apply)(params, ids).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
 
@@ -385,7 +385,7 @@ def test_logits_parity_with_hf_granite():
     ids = np.random.default_rng(13).integers(0, 128, (2, 24))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
 
@@ -405,7 +405,7 @@ def test_granite_export_round_trip(tmp_path):
     )
     model = Llama(cfg)
     ids = jnp.asarray(np.random.default_rng(14).integers(0, 128, (2, 16)))
-    params = model.init(jax.random.key(3), ids)
+    params = jax.jit(model.init)(jax.random.key(3), ids)
     out_dir = save_hf_checkpoint(params, cfg, tmp_path / "export", dtype="float32")
 
     hf_model = AutoModelForCausalLM.from_pretrained(
@@ -414,7 +414,7 @@ def test_granite_export_round_trip(tmp_path):
     assert type(hf_model).__name__ == "GraniteForCausalLM"
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(np.asarray(ids))).logits.numpy()
-    ours = model.apply(params, ids).logits
+    ours = jax.jit(model.apply)(params, ids).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
 
@@ -451,7 +451,7 @@ def test_logits_parity_with_hf_starcoder2():
     ids = np.random.default_rng(15).integers(0, 128, (2, 24))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
 
@@ -470,7 +470,7 @@ def test_starcoder2_export_round_trip(tmp_path):
     )
     model = Llama(cfg)
     ids = jnp.asarray(np.random.default_rng(16).integers(0, 128, (2, 16)))
-    params = model.init(jax.random.key(4), ids)
+    params = jax.jit(model.init)(jax.random.key(4), ids)
     out_dir = save_hf_checkpoint(params, cfg, tmp_path / "export", dtype="float32")
 
     hf_model = AutoModelForCausalLM.from_pretrained(
@@ -479,7 +479,7 @@ def test_starcoder2_export_round_trip(tmp_path):
     assert type(hf_model).__name__ == "Starcoder2ForCausalLM"
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(np.asarray(ids))).logits.numpy()
-    ours = model.apply(params, ids).logits
+    ours = jax.jit(model.apply)(params, ids).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
 
@@ -516,7 +516,7 @@ def test_logits_parity_with_hf_cohere(use_qk_norm):
     ids = np.random.default_rng(17).integers(0, 128, (2, 24))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
 
@@ -580,7 +580,7 @@ def test_logits_parity_with_hf_phi():
     ids = np.random.default_rng(18).integers(0, 128, (2, 24))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
 
@@ -601,7 +601,7 @@ def test_phi_export_round_trip(tmp_path):
     )
     model = Llama(cfg)
     ids = jnp.asarray(np.random.default_rng(19).integers(0, 128, (2, 16)))
-    params = model.init(jax.random.key(5), ids)
+    params = jax.jit(model.init)(jax.random.key(5), ids)
     out_dir = save_hf_checkpoint(params, cfg, tmp_path / "export", dtype="float32")
 
     hf_model = AutoModelForCausalLM.from_pretrained(
@@ -610,7 +610,7 @@ def test_phi_export_round_trip(tmp_path):
     assert type(hf_model).__name__ == "PhiForCausalLM"
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(np.asarray(ids))).logits.numpy()
-    ours = model.apply(params, ids).logits
+    ours = jax.jit(model.apply)(params, ids).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
 
@@ -650,7 +650,7 @@ def test_logits_parity_with_hf_glm(cls_name):
     ids = np.random.default_rng(40).integers(0, 128, (2, 24))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
 
@@ -671,7 +671,7 @@ def test_glm4_export_round_trip(tmp_path):
     )
     model = Llama(cfg)
     ids = jnp.asarray(np.random.default_rng(41).integers(0, 128, (2, 16)))
-    params = model.init(jax.random.key(9), ids)
+    params = jax.jit(model.init)(jax.random.key(9), ids)
     # zero-init biases would mask a bias-dropping export: randomize them
     import flax.linen as fnn
 
@@ -695,7 +695,7 @@ def test_glm4_export_round_trip(tmp_path):
     assert type(hf_model).__name__ == "Glm4ForCausalLM"
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(np.asarray(ids))).logits.numpy()
-    ours = model.apply(params, ids).logits
+    ours = jax.jit(model.apply)(params, ids).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
 
@@ -734,7 +734,7 @@ def test_logits_parity_with_hf_nemotron():
     ids = np.random.default_rng(42).integers(0, 128, (2, 24))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
 
@@ -753,7 +753,7 @@ def test_nemotron_export_round_trip(tmp_path):
     )
     model = Llama(cfg)
     ids = jnp.asarray(np.random.default_rng(43).integers(0, 128, (2, 16)))
-    params = model.init(jax.random.key(12), ids)
+    params = jax.jit(model.init)(jax.random.key(12), ids)
     out_dir = save_hf_checkpoint(params, cfg, tmp_path / "export", dtype="float32")
 
     hf_model = AutoModelForCausalLM.from_pretrained(
@@ -762,7 +762,7 @@ def test_nemotron_export_round_trip(tmp_path):
     assert type(hf_model).__name__ == "NemotronForCausalLM"
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(np.asarray(ids))).logits.numpy()
-    ours = model.apply(params, ids).logits
+    ours = jax.jit(model.apply)(params, ids).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
 
@@ -794,7 +794,7 @@ def test_logits_parity_with_hf_ernie45():
     ids = np.random.default_rng(44).integers(0, 128, (2, 24))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
 
@@ -830,7 +830,7 @@ def test_logits_parity_with_hf_hunyuan():
     ids = np.random.default_rng(45).integers(0, 128, (2, 24))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
 
@@ -861,7 +861,7 @@ def test_logits_parity_with_hf_gpt2():
     ids = np.random.default_rng(46).integers(0, 128, (2, 24))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
 
@@ -885,7 +885,7 @@ def test_gpt2_export_round_trip(tmp_path):
     )
     model = Llama(cfg)
     ids = jnp.asarray(np.random.default_rng(47).integers(0, 128, (2, 16)))
-    params = model.init(jax.random.key(13), ids)
+    params = jax.jit(model.init)(jax.random.key(13), ids)
     out_dir = save_hf_checkpoint(params, cfg, tmp_path / "export", dtype="float32")
 
     hf_model = AutoModelForCausalLM.from_pretrained(
@@ -894,7 +894,7 @@ def test_gpt2_export_round_trip(tmp_path):
     assert type(hf_model).__name__ == "GPT2LMHeadModel"
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(np.asarray(ids))).logits.numpy()
-    ours = model.apply(params, ids).logits
+    ours = jax.jit(model.apply)(params, ids).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
 
@@ -923,7 +923,7 @@ def test_logits_parity_with_hf_smollm3():
     ids = np.random.default_rng(48).integers(0, 128, (2, 24))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
 
@@ -959,7 +959,7 @@ def test_logits_parity_with_hf_olmo3():
     ids = np.random.default_rng(49).integers(0, 128, (2, 24))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
 
@@ -989,7 +989,7 @@ def test_logits_parity_with_hf_ministral():
     ids = np.random.default_rng(50).integers(0, 128, (2, 24))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
 
@@ -1025,7 +1025,7 @@ def test_logits_parity_with_hf_helium():
     ids = np.random.default_rng(51).integers(0, 128, (2, 24))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
 
@@ -1055,7 +1055,7 @@ def test_logits_parity_with_hf_arcee():
     ids = np.random.default_rng(52).integers(0, 128, (2, 24))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
 
@@ -1090,7 +1090,7 @@ def test_logits_parity_with_hf_seed_oss():
     ids = np.random.default_rng(53).integers(0, 128, (2, 24))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
     with pytest.raises(ValueError, match="residual_dropout"):
@@ -1133,7 +1133,7 @@ def test_logits_parity_with_hf_stablelm():
     ids = np.random.default_rng(54).integers(0, 128, (2, 24))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
     # export picks stablelm; round trip preserves the graph knobs
@@ -1182,7 +1182,7 @@ def test_logits_parity_with_hf_exaone4():
     ids = np.random.default_rng(55).integers(0, 128, (2, 24))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
     out = config_to_hf(cfg)
@@ -1224,7 +1224,7 @@ def test_logits_parity_with_hf_apertus():
     ids = np.random.default_rng(56).integers(0, 128, (2, 24))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
     out = config_to_hf(cfg)
@@ -1270,7 +1270,7 @@ def test_logits_parity_with_hf_cohere2():
     ids = np.random.default_rng(18).integers(0, 128, (2, 24))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
 
@@ -1294,7 +1294,7 @@ def test_cohere2_export_round_trip(tmp_path):
     )
     model = Llama(cfg)
     ids = jnp.asarray(np.random.default_rng(19).integers(0, 128, (2, 24)))
-    params = model.init(jax.random.key(5), ids)
+    params = jax.jit(model.init)(jax.random.key(5), ids)
     out_dir = save_hf_checkpoint(params, cfg, tmp_path / "export", dtype="float32")
 
     hf_model = AutoModelForCausalLM.from_pretrained(
@@ -1303,7 +1303,7 @@ def test_cohere2_export_round_trip(tmp_path):
     assert type(hf_model).__name__ == "Cohere2ForCausalLM"
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(np.asarray(ids))).logits.numpy()
-    ours = model.apply(params, ids).logits
+    ours = jax.jit(model.apply)(params, ids).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
 
@@ -1343,7 +1343,7 @@ def test_logits_parity_with_hf_phimoe():
     ids = np.random.default_rng(20).integers(0, 128, (2, 24))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
 
@@ -1384,7 +1384,7 @@ def test_phimoe_export_round_trip(tmp_path):
     )
     model = Llama(cfg)
     ids = jnp.asarray(np.random.default_rng(21).integers(0, 128, (2, 16)))
-    params = model.init(jax.random.key(6), ids)
+    params = jax.jit(model.init)(jax.random.key(6), ids)
     out_dir = save_hf_checkpoint(params, cfg, tmp_path / "export", dtype="float32")
 
     hf_model = AutoModelForCausalLM.from_pretrained(
@@ -1393,7 +1393,7 @@ def test_phimoe_export_round_trip(tmp_path):
     assert type(hf_model).__name__ == "PhimoeForCausalLM"
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(np.asarray(ids))).logits.numpy()
-    ours = model.apply(params, ids).logits
+    ours = jax.jit(model.apply)(params, ids).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
 
@@ -1452,7 +1452,7 @@ def test_logits_parity_with_hf_gpt_neox(parallel):
     ids = np.random.default_rng(22).integers(0, 128, (2, 24))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
 
@@ -1471,7 +1471,7 @@ def test_gpt_neox_export_round_trip(tmp_path):
     )
     model = Llama(cfg)
     ids = jnp.asarray(np.random.default_rng(23).integers(0, 128, (2, 16)))
-    params = model.init(jax.random.key(7), ids)
+    params = jax.jit(model.init)(jax.random.key(7), ids)
     out_dir = save_hf_checkpoint(params, cfg, tmp_path / "export", dtype="float32")
 
     hf_model = AutoModelForCausalLM.from_pretrained(
@@ -1480,7 +1480,7 @@ def test_gpt_neox_export_round_trip(tmp_path):
     assert type(hf_model).__name__ == "GPTNeoXForCausalLM"
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(np.asarray(ids))).logits.numpy()
-    ours = model.apply(params, ids).logits
+    ours = jax.jit(model.apply)(params, ids).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
 
@@ -1512,7 +1512,7 @@ def test_logits_parity_with_hf_olmo1():
     ids = np.random.default_rng(24).integers(0, 128, (2, 24))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
 
@@ -1529,7 +1529,7 @@ def test_olmo1_export_round_trip(tmp_path):
     )
     model = Llama(cfg)
     ids = jnp.asarray(np.random.default_rng(25).integers(0, 128, (2, 16)))
-    params = model.init(jax.random.key(8), ids)
+    params = jax.jit(model.init)(jax.random.key(8), ids)
     out_dir = save_hf_checkpoint(params, cfg, tmp_path / "export", dtype="float32")
 
     hf_model = AutoModelForCausalLM.from_pretrained(
@@ -1538,5 +1538,5 @@ def test_olmo1_export_round_trip(tmp_path):
     assert type(hf_model).__name__ == "OlmoForCausalLM"
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(np.asarray(ids))).logits.numpy()
-    ours = model.apply(params, ids).logits
+    ours = jax.jit(model.apply)(params, ids).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)

@@ -74,7 +74,7 @@ def test_logits_parity_with_hf(seq):
     ids = np.random.default_rng(80).integers(0, 128, (2, seq))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=4e-4, atol=4e-4)
 
 
@@ -94,7 +94,7 @@ def test_residual_factors_are_live():
     ids = np.random.default_rng(81).integers(0, 128, (2, 20))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=4e-4, atol=4e-4)
 
 

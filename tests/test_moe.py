@@ -40,9 +40,9 @@ def test_dense_and_ragged_impls_agree():
     cfg_d = LlamaConfig(**TINY_MOE, moe_impl="dense")
     cfg_r = LlamaConfig(**TINY_MOE, moe_impl="ragged")
     model_d, model_r = Llama(cfg_d), Llama(cfg_r)
-    params = model_d.init(jax.random.key(0), ids)
-    out_d = model_d.apply(params, ids)
-    out_r = model_r.apply(params, ids)
+    params = jax.jit(model_d.init)(jax.random.key(0), ids)
+    out_d = jax.jit(model_d.apply)(params, ids)
+    out_r = jax.jit(model_r.apply)(params, ids)
     np.testing.assert_allclose(out_d.logits, out_r.logits, rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(out_d.aux_loss, out_r.aux_loss, rtol=1e-6)
 
@@ -58,9 +58,9 @@ def test_router_stats_parity_dense_vs_ragged():
     ids = jnp.asarray(np.random.default_rng(3).integers(0, 128, (2, 16)))
     model_d = Llama(LlamaConfig(**tiny, moe_impl="dense"))
     model_r = Llama(LlamaConfig(**tiny, moe_impl="ragged"))
-    params = model_d.init(jax.random.key(3), ids)
-    rs_d = model_d.apply(params, ids).router_stats
-    rs_r = model_r.apply(params, ids).router_stats
+    params = jax.jit(model_d.init)(jax.random.key(3), ids)
+    rs_d = jax.jit(model_d.apply)(params, ids).router_stats
+    rs_r = jax.jit(model_r.apply)(params, ids).router_stats
     assert rs_d.layer_ids == rs_r.layer_ids == (0,)
     np.testing.assert_allclose(rs_d.sel_frac, rs_r.sel_frac, rtol=1e-6)
     np.testing.assert_allclose(rs_d.mean_prob, rs_r.mean_prob, rtol=1e-6)
@@ -81,9 +81,9 @@ def test_bucketed_impl_matches_dense_at_full_capacity():
     # factor = num_experts -> capacity == all T*K rows: drops impossible
     cfg_b = LlamaConfig(**TINY_MOE, moe_impl="bucketed", moe_capacity_factor=4.0)
     model_d, model_b = Llama(cfg_d), Llama(cfg_b)
-    params = model_d.init(jax.random.key(1), ids)
-    out_d = model_d.apply(params, ids)
-    out_b = model_b.apply(params, ids)
+    params = jax.jit(model_d.init)(jax.random.key(1), ids)
+    out_d = jax.jit(model_d.apply)(params, ids)
+    out_b = jax.jit(model_b.apply)(params, ids)
     np.testing.assert_allclose(out_d.logits, out_b.logits, rtol=2e-5, atol=2e-5)
     assert float(out_b.ep_dropped_rows) == 0.0
 
@@ -127,8 +127,8 @@ def test_aux_loss_near_topk_at_init():
     ids = jnp.asarray(np.random.default_rng(1).integers(0, 128, (4, 32)))
     cfg = LlamaConfig(**TINY_MOE)
     model = Llama(cfg)
-    params = model.init(jax.random.key(1), ids)
-    aux = float(model.apply(params, ids).aux_loss)
+    params = jax.jit(model.init)(jax.random.key(1), ids)
+    aux = float(jax.jit(model.apply)(params, ids).aux_loss)
     assert np.isfinite(aux)
     top_k = TINY_MOE["num_experts_per_tok"]
     assert 0.9 * top_k < aux < 1.6 * top_k
@@ -146,9 +146,9 @@ def test_aux_loss_excludes_padding():
 
     cfg = LlamaConfig(**TINY_MOE, moe_impl="dense")
     model = Llama(cfg)
-    params = model.init(jax.random.key(2), ids)
-    aux_ref = float(model.apply(params, ids, segment_ids=seg_full).aux_loss)
-    aux_pad = float(model.apply(params, padded_ids, segment_ids=seg_padded).aux_loss)
+    params = jax.jit(model.init)(jax.random.key(2), ids)
+    aux_ref = float(jax.jit(model.apply)(params, ids, segment_ids=seg_full).aux_loss)
+    aux_pad = float(jax.jit(model.apply)(params, padded_ids, segment_ids=seg_padded).aux_loss)
     np.testing.assert_allclose(aux_pad, aux_ref, rtol=1e-5)
 
 
@@ -158,8 +158,8 @@ def test_dense_model_has_no_aux():
                          if not k.startswith(("num_experts", "moe_"))})
     model = Llama(cfg)
     ids = jnp.ones((1, 8), jnp.int32)
-    params = model.init(jax.random.key(0), ids)
-    assert model.apply(params, ids).aux_loss is None
+    params = jax.jit(model.init)(jax.random.key(0), ids)
+    assert jax.jit(model.apply)(params, ids).aux_loss is None
 
 
 # ------------------------------------------------------------ HF parity
@@ -173,7 +173,7 @@ def _parity(hf_model, hf_config, seed):
     ids = np.random.default_rng(seed).integers(0, 128, (2, 24))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=3e-4, atol=3e-4)
     return cfg, params, model
 
@@ -247,7 +247,7 @@ def test_moe_export_round_trip(tmp_path):
     cfg = LlamaConfig(**TINY_MOE, qk_norm=True, head_dim=16, moe_impl="dense")
     model = Llama(cfg)
     ids = jnp.asarray(np.random.default_rng(23).integers(0, 128, (2, 16)))
-    params = model.init(jax.random.key(3), ids)
+    params = jax.jit(model.init)(jax.random.key(3), ids)
     out_dir = save_hf_checkpoint(params, cfg, tmp_path / "export", dtype="float32")
 
     hf_model = AutoModelForCausalLM.from_pretrained(
@@ -256,7 +256,7 @@ def test_moe_export_round_trip(tmp_path):
     assert type(hf_model).__name__ == "Qwen3MoeForCausalLM"
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(np.asarray(ids))).logits.numpy()
-    ours = model.apply(params, ids).logits
+    ours = jax.jit(model.apply)(params, ids).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=3e-4, atol=3e-4)
 
 
@@ -410,7 +410,7 @@ def test_logits_parity_with_hf_flex_olmo():
     ids = np.random.default_rng(61).integers(0, 128, (2, 24))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
     # export picks flex_olmo (post-norm), not olmoe
@@ -466,7 +466,7 @@ def test_logits_parity_with_hf_granitemoe(shared):
     ids = np.random.default_rng(62).integers(0, 128, (2, 24))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=3e-4, atol=3e-4)
 
     out = config_to_hf(cfg)

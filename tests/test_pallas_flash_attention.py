@@ -100,14 +100,14 @@ def test_gradients_smoke_packed_aligned():
     seg = jnp.asarray(np.repeat([1, 2], 128)[None], jnp.int32)
     cot = jnp.asarray(_rand(rng, (batch, seq, h, d)))
 
-    gx = jax.grad(
+    gx = jax.jit(jax.grad(
         lambda q, k, v: (dot_product_attention(q, k, v, segment_ids=seg, impl="xla") * cot).sum(),
         argnums=(0, 1, 2),
-    )(q, k, v)
-    gp = jax.grad(
+    ))(q, k, v)
+    gp = jax.jit(jax.grad(
         lambda q, k, v: (flash_attention(q, k, v, segment_ids=seg, block_q=128, block_k=128) * cot).sum(),
         argnums=(0, 1, 2),
-    )(q, k, v)
+    ))(q, k, v)
     for a, b, name in zip(gx, gp, "qkv"):
         np.testing.assert_allclose(b, a, rtol=2e-3, atol=2e-3, err_msg=f"d{name}")
 
@@ -129,8 +129,8 @@ def test_gradients_match_xla():
     def pallas(q, k, v):
         return flash_attention(q, k, v, segment_ids=seg, block_q=128, block_k=128)
 
-    gx = jax.grad(lambda *a: loss(xla, *a), argnums=(0, 1, 2))(q, k, v)
-    gp = jax.grad(lambda *a: loss(pallas, *a), argnums=(0, 1, 2))(q, k, v)
+    gx = jax.jit(jax.grad(lambda *a: loss(xla, *a), argnums=(0, 1, 2)))(q, k, v)
+    gp = jax.jit(jax.grad(lambda *a: loss(pallas, *a), argnums=(0, 1, 2)))(q, k, v)
     for a, b, name in zip(gx, gp, "qkv"):
         np.testing.assert_allclose(b, a, rtol=2e-3, atol=2e-3, err_msg=f"d{name}")
 
@@ -143,14 +143,14 @@ def test_gradients_match_xla_softcap_window():
     cot = jnp.asarray(_rand(rng, (batch, seq, h, d)))
     kw = dict(sliding_window=33, logits_soft_cap=25.0)
 
-    gx = jax.grad(
+    gx = jax.jit(jax.grad(
         lambda q, k, v: (dot_product_attention(q, k, v, impl="xla", **kw) * cot).sum(),
         argnums=(0, 1, 2),
-    )(q, k, v)
-    gp = jax.grad(
+    ))(q, k, v)
+    gp = jax.jit(jax.grad(
         lambda q, k, v: (flash_attention(q, k, v, block_q=128, block_k=128, **kw) * cot).sum(),
         argnums=(0, 1, 2),
-    )(q, k, v)
+    ))(q, k, v)
     for a, b, name in zip(gx, gp, "qkv"):
         np.testing.assert_allclose(b, a, rtol=2e-3, atol=2e-3, err_msg=f"d{name}")
 
@@ -242,7 +242,7 @@ def test_sinks_match_xla(sliding_window):
             )
             return (out * jnp.arange(d)).sum(), out
 
-        return jax.value_and_grad(lambda *a: f(*a)[0], argnums=(0, 1, 2, 3)), f
+        return jax.jit(jax.value_and_grad(lambda *a: f(*a)[0], argnums=(0, 1, 2, 3))), f
 
     (gx, fx), (gp, fp) = loss("xla"), loss("pallas")
     out_x, out_p = fx(q, k, v, sinks)[1], fp(q, k, v, sinks)[1]
@@ -272,14 +272,14 @@ def test_backward_matches_xla(name, hq, hkv, window, cap, packed):
     cot = jnp.asarray(_rand(rng, (batch, seq, hq, d)))
     kwargs = dict(segment_ids=seg, causal=True, sliding_window=window, logits_soft_cap=cap)
 
-    gx = jax.grad(
+    gx = jax.jit(jax.grad(
         lambda q, k, v: (dot_product_attention(q, k, v, impl="xla", **kwargs) * cot).sum(),
         argnums=(0, 1, 2),
-    )(q, k, v)
-    gp = jax.grad(
+    ))(q, k, v)
+    gp = jax.jit(jax.grad(
         lambda q, k, v: (flash_attention(q, k, v, block_q=128, block_k=128, **kwargs) * cot).sum(),
         argnums=(0, 1, 2),
-    )(q, k, v)
+    ))(q, k, v)
     for a, b, grad_name in zip(gx, gp, "qkv"):
         np.testing.assert_allclose(b, a, rtol=3e-3, atol=3e-3, err_msg=f"d{grad_name}")
 
@@ -292,14 +292,14 @@ def test_backward_traces_with_resolved_blocks():
     q, k, v = _make_qkv(rng, 1, 256, 256, 4, 2, 32)
     cot = jnp.asarray(_rand(rng, (1, 256, 4, 32)))
 
-    gx = jax.grad(
+    gx = jax.jit(jax.grad(
         lambda q, k, v: (dot_product_attention(q, k, v, impl="xla", causal=True) * cot).sum(),
         argnums=(0, 1, 2),
-    )(q, k, v)
-    gp = jax.grad(
+    ))(q, k, v)
+    gp = jax.jit(jax.grad(
         lambda q, k, v: (flash_attention(q, k, v, causal=True) * cot).sum(),
         argnums=(0, 1, 2),
-    )(q, k, v)
+    ))(q, k, v)
     for a, b, grad_name in zip(gx, gp, "qkv"):
         np.testing.assert_allclose(b, a, rtol=3e-3, atol=3e-3, err_msg=f"d{grad_name}")
 
@@ -313,12 +313,12 @@ def test_backward_independent_fwd_bwd_blocks():
     cot = jnp.asarray(_rand(rng, (1, 512, 4, 32)))
 
     def grads(**blocks):
-        return jax.grad(
+        return jax.jit(jax.grad(
             lambda q, k, v: (flash_attention(
                 q, k, v, segment_ids=seg, causal=True, sliding_window=100, **blocks
             ) * cot).sum(),
             argnums=(0, 1, 2),
-        )(q, k, v)
+        ))(q, k, v)
 
     base = grads(block_q=128, block_k=128, bwd_block_q=128, bwd_block_k=128)
     mixed = grads(block_q=256, block_k=128, bwd_block_q=128, bwd_block_k=256)
@@ -361,14 +361,14 @@ def test_backward_gqa_group4_dkv_grid():
     cot = jnp.asarray(_rand(rng, (1, 256, 8, 32)))
     kwargs = dict(causal=True, sliding_window=70)
 
-    gx = jax.grad(
+    gx = jax.jit(jax.grad(
         lambda q, k, v: (dot_product_attention(q, k, v, impl="xla", **kwargs) * cot).sum(),
         argnums=(0, 1, 2),
-    )(q, k, v)
-    gp = jax.grad(
+    ))(q, k, v)
+    gp = jax.jit(jax.grad(
         lambda q, k, v: (flash_attention(q, k, v, block_q=128, block_k=128, **kwargs) * cot).sum(),
         argnums=(0, 1, 2),
-    )(q, k, v)
+    ))(q, k, v)
     for a, b, grad_name in zip(gx, gp, "qkv"):
         np.testing.assert_allclose(b, a, rtol=3e-3, atol=3e-3, err_msg=f"d{grad_name}")
 
@@ -398,14 +398,42 @@ def test_packed_layout_fuzz_fwd_and_grad(case):
     kw = dict(segment_ids=seg, causal=True, sliding_window=case["window"])
 
     def loss(fn):
-        return jax.value_and_grad(
+        return jax.jit(jax.value_and_grad(
             lambda q, k, v: (fn(q, k, v).astype(jnp.float32)
                              * cot.astype(jnp.float32)).sum(),
             argnums=(0, 1, 2),
-        )
+        ))
 
     vx, gx = loss(lambda q, k, v: dot_product_attention(q, k, v, impl="xla", **kw))(q, k, v)
     vp, gp = loss(lambda q, k, v: flash_attention(q, k, v, block_q=blk, block_k=blk, **kw))(q, k, v)
     np.testing.assert_allclose(float(vp), float(vx), rtol=2e-3, atol=1e-2)
     for a, b, name in zip(gx, gp, "qkv"):
         np.testing.assert_allclose(b, a, rtol=3e-3, atol=3e-3, err_msg=f"d{name}")
+
+
+def test_flash_under_a_sharded_mesh_matches_xla(devices):
+    """On a multi-device mesh `dot_product_attention` runs the kernel in a
+    shard_map (batch over data/fsdp, heads over tensor) — GSPMD cannot
+    partition a Mosaic kernel, which the TPU compiler refuses outright
+    (tests/test_chip_compile.py holds that compile). Forward and gradients
+    must equal the einsum path, packed segment ids included."""
+    from llm_training_tpu.parallel.mesh import MeshConfig, build_mesh
+
+    mesh = build_mesh(MeshConfig(fsdp_size=4, tensor_parallel_size=2), devices)
+    rng = np.random.default_rng(7)
+    q, k, v = _make_qkv(rng, 4, 256, 256, 4, 2, 16)
+    seg = _packed_segments(rng, 4, 256)
+
+    def loss(impl):
+        def fn(q, k, v):
+            out = dot_product_attention(q, k, v, segment_ids=seg, impl=impl)
+            return jnp.sum(out * jnp.cos(out)), out
+
+        return jax.jit(jax.value_and_grad(fn, argnums=(0, 1, 2), has_aux=True))
+
+    with mesh:
+        (_, out), grads = loss("pallas")(q, k, v)
+    (_, ref_out), ref_grads = loss("xla")(q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref_out), rtol=2e-5, atol=2e-5)
+    for got, ref in zip(grads, ref_grads):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=1e-4, atol=1e-4)

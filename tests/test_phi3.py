@@ -48,7 +48,7 @@ def test_logits_parity_with_hf():
     ids = np.random.default_rng(0).integers(0, TINY["vocab_size"], (2, 12))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4)
 
 
@@ -69,9 +69,9 @@ def test_sliding_window_changes_output():
     cfg_win = Phi3Config(**TINY, compute_dtype="float32", sliding_window=4)
     ids = jnp.asarray(np.random.default_rng(1).integers(0, 160, (1, 16)))
     model = Phi3(cfg_full)
-    params = model.init(jax.random.key(0), ids)
-    out_full = model.apply(params, ids)
-    out_win = Phi3(cfg_win).apply(params, ids)
+    params = jax.jit(model.init)(jax.random.key(0), ids)
+    out_full = jax.jit(model.apply)(params, ids)
+    out_win = jax.jit(Phi3(cfg_win).apply)(params, ids)
     # early positions (< window) identical, late positions differ
     np.testing.assert_allclose(out_full.logits[:, :4], out_win.logits[:, :4], rtol=1e-5)
     assert np.abs(np.asarray(out_full.logits[:, -1]) - np.asarray(out_win.logits[:, -1])).max() > 1e-3
@@ -126,7 +126,7 @@ def test_longrope_short_long_parity_with_hf():
         ids = np.random.default_rng(seq).integers(0, TINY["vocab_size"], (1, seq))
         with torch.no_grad():
             hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-        ours = model.apply(params, jnp.asarray(ids)).logits
+        ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
         np.testing.assert_allclose(
             np.asarray(ours), hf_logits, rtol=2e-4, atol=2e-4,
             err_msg=f"seq={seq}",
@@ -138,6 +138,6 @@ def test_attention_compute_dtype():
     cfg = Phi3Config(**TINY, compute_dtype="bfloat16", attention_compute_dtype="float32")
     ids = jnp.ones((1, 8), jnp.int32)
     model = Phi3(cfg)
-    params = model.init(jax.random.key(0), ids)
-    out = model.apply(params, ids)
+    params = jax.jit(model.init)(jax.random.key(0), ids)
+    out = jax.jit(model.apply)(params, ids)
     assert out.logits.dtype == jnp.bfloat16  # cast back after attention

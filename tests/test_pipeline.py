@@ -67,11 +67,11 @@ def test_pipeline_matches_scan_forward_and_grad(devices):
 
     m_s, m_p = _models()
     ids, seg, pos = _inputs()
-    p_p = nn.meta.unbox(m_p.init(jax.random.key(0), ids, seg, pos))["params"]
+    p_p = nn.meta.unbox(jax.jit(m_p.init)(jax.random.key(0), ids, seg, pos))["params"]
     p_s = _scan_params_from_pipeline(p_p, KW["num_hidden_layers"])
 
-    out_s = m_s.apply({"params": p_s}, ids, seg, pos)
-    out_p = m_p.apply({"params": p_p}, ids, seg, pos)
+    out_s = jax.jit(m_s.apply)({"params": p_s}, ids, seg, pos)
+    out_p = jax.jit(m_p.apply)({"params": p_p}, ids, seg, pos)
     np.testing.assert_allclose(
         np.asarray(out_p.logits), np.asarray(out_s.logits), atol=1e-5
     )
@@ -81,8 +81,8 @@ def test_pipeline_matches_scan_forward_and_grad(devices):
         logp = jax.nn.log_softmax(out.logits.astype(jnp.float32))
         return jnp.mean(logp[..., 0] ** 2)
 
-    g_s = jax.grad(loss_fn)(p_s, m_s)
-    g_p = jax.grad(loss_fn)(p_p, m_p)
+    g_s = jax.jit(jax.grad(lambda p: loss_fn(p, m_s)))(p_s)
+    g_p = jax.jit(jax.grad(lambda p: loss_fn(p, m_p)))(p_p)
     g_p_as_scan = _scan_params_from_pipeline(g_p, KW["num_hidden_layers"])
     for a, b in zip(jax.tree.leaves(g_s), jax.tree.leaves(g_p_as_scan)):
         np.testing.assert_allclose(np.asarray(b), np.asarray(a), atol=1e-5)
@@ -100,8 +100,8 @@ def test_pipeline_microbatch_counts_agree(devices):
     ref = None
     for micro in (2, 4, 3):  # 3 does not divide batch 8 -> gcd degrades to 1
         m = Llama(LlamaConfig(**KW, pipeline_stages=2, pipeline_microbatches=micro))
-        p = nn.meta.unbox(m.init(jax.random.key(0), ids, seg, pos))["params"]
-        out = np.asarray(m.apply({"params": p}, ids, seg, pos).logits)
+        p = nn.meta.unbox(jax.jit(m.init)(jax.random.key(0), ids, seg, pos))["params"]
+        out = np.asarray(jax.jit(m.apply)({"params": p}, ids, seg, pos).logits)
         if ref is None:
             ref = out
         else:
@@ -218,11 +218,11 @@ def test_pipeline_moe_matches_scan(devices):
     # stats normalize per dispatch by valid-token count, so equal-weight
     # pooling would diverge here — the token-share weighting must not
     seg = seg.at[:2, 10:].set(0)
-    p_p = nn.meta.unbox(m_p.init(jax.random.key(0), ids, seg, pos))["params"]
+    p_p = nn.meta.unbox(jax.jit(m_p.init)(jax.random.key(0), ids, seg, pos))["params"]
     p_s = _scan_params_from_pipeline(p_p, KW["num_hidden_layers"])
 
-    out_s = m_s.apply({"params": p_s}, ids, seg, pos)
-    out_p = m_p.apply({"params": p_p}, ids, seg, pos)
+    out_s = jax.jit(m_s.apply)({"params": p_s}, ids, seg, pos)
+    out_p = jax.jit(m_p.apply)({"params": p_p}, ids, seg, pos)
     np.testing.assert_allclose(
         np.asarray(out_p.logits), np.asarray(out_s.logits), atol=1e-5
     )
@@ -235,9 +235,9 @@ def test_pipeline_moe_matches_scan(devices):
         logp = jax.nn.log_softmax(out.logits.astype(jnp.float32))
         return jnp.mean(logp[..., 0] ** 2) + 0.01 * out.aux_loss
 
-    g_s = jax.grad(loss_fn)(p_s, m_s)
+    g_s = jax.jit(jax.grad(lambda p: loss_fn(p, m_s)))(p_s)
     g_p = _scan_params_from_pipeline(
-        jax.grad(loss_fn)(p_p, m_p), KW["num_hidden_layers"]
+        jax.jit(jax.grad(lambda p: loss_fn(p, m_p)))(p_p), KW["num_hidden_layers"]
     )
     for a, b in zip(jax.tree.leaves(g_s), jax.tree.leaves(g_p)):
         np.testing.assert_allclose(np.asarray(b), np.asarray(a), atol=2e-5)
@@ -283,7 +283,7 @@ def test_pipeline_hf_round_trip(devices):
 
     m_s, m_p = _models()
     ids, seg, pos = _inputs()
-    p_p = nn.meta.unbox(m_p.init(jax.random.key(0), ids, seg, pos))["params"]
+    p_p = nn.meta.unbox(jax.jit(m_p.init)(jax.random.key(0), ids, seg, pos))["params"]
 
     # export the pipelined params to an HF state dict (exercises _pp_as_scan)
     sd = params_to_hf(_pp_as_scan({"params": p_p}, m_p.config), m_p.config)
@@ -291,8 +291,8 @@ def test_pipeline_hf_round_trip(devices):
     p_s2 = load_pretrained_params(m_s.config, sd)["params"]
     p_p2 = load_pretrained_params(m_p.config, sd)["params"]
 
-    out_s = m_s.apply({"params": p_s2}, ids, seg, pos)
-    out_p = m_p.apply({"params": p_p2}, ids, seg, pos)
+    out_s = jax.jit(m_s.apply)({"params": p_s2}, ids, seg, pos)
+    out_p = jax.jit(m_p.apply)({"params": p_p2}, ids, seg, pos)
     np.testing.assert_allclose(
         np.asarray(out_p.logits), np.asarray(out_s.logits), atol=1e-5
     )

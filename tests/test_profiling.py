@@ -562,3 +562,24 @@ def test_profiler_callback_standalone_resolves_default_dir(monkeypatch):
     # back so callers read the actual capture location off the config
     assert cb.config.trace_dir == DEFAULT_TRACE_DIR
     assert calls == [("start", DEFAULT_TRACE_DIR), ("stop",)]
+
+
+def test_parse_hlo_kernels_names_the_mosaic_kernels():
+    """The compiled-program walk that lets a run OBSERVE its Pallas kernels
+    (attr/kernel/<name> gauges; chip_smoke.py asserts on them)."""
+    from llm_training_tpu.telemetry.device import parse_hlo_kernels
+
+    text = (
+        '  %a = bf16[4,8,4,128] custom-call(%t, %l), custom_call_target="tpu_custom_call", '
+        'metadata={op_name="jit(decode_step)/Llama/layers/paged_decode/pallas_call" '
+        'stack_frame_id=4}, backend_config={}\n'
+        '  %b = bf16[32,8192,128] custom-call(%q), custom_call_target="tpu_custom_call", '
+        'metadata={op_name="jit(train_step)/transpose(jvp(Llama))/flash_bwd_dq/pallas_call"}\n'
+        '  %c = bf16[32,8192,128] custom-call(%q), custom_call_target="tpu_custom_call", '
+        'metadata={op_name="jit(fn)/transpose(jvp(flash_bwd_dq))/pallas_call"}\n'
+        '  %d = f32[8] custom-call(%x), custom_call_target="tpu_custom_call", metadata={}\n'
+        '  %e = f32[8] custom-call(%x), custom_call_target="Sharding"\n'
+        '  %f = f32[8] all-reduce(%x), replica_groups={{0,1}}\n'
+    )
+    assert parse_hlo_kernels(text) == {"paged_decode": 1, "flash_bwd_dq": 2, "unnamed": 1}
+    assert parse_hlo_kernels("") == {}

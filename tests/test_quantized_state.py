@@ -201,7 +201,9 @@ def test_blocked_compressed_step_matches_fp32(devices, offload_dtype):
         with trainer.mesh, nn.logical_axis_rules(LOGICAL_AXIS_RULES):
             trainer._blocked_offload = True
             trainer._clip_norm = objective.config.optim.grad_clip_norm
-            params = nn.meta.unbox(objective.init_params(jax.random.key(0), batch))
+            params = jax.jit(
+                lambda rng: nn.meta.unbox(objective.init_params(rng, batch))
+            )(jax.random.key(0))
             blocks = trainer._opt_init(tx, params)
             state = TrainState.create(params, blocks, jax.random.key(7))
             dev = jax.sharding.NamedSharding(trainer.mesh, jax.sharding.PartitionSpec())
@@ -280,7 +282,9 @@ def test_serialized_int8_with_accumulation_matches_fp32(devices):
         with trainer.mesh, nn.logical_axis_rules(LOGICAL_AXIS_RULES):
             tx, _ = trainer._build_tx(objective)
             assert not trainer._blocked_offload  # accumulation -> serialized
-            params = nn.meta.unbox(objective.init_params(jax.random.key(0), b1))
+            params = jax.jit(
+                lambda rng: nn.meta.unbox(objective.init_params(rng, b1))
+            )(jax.random.key(0))
             opt_state = trainer._opt_init(tx, params)
             state = TrainState.create(params, opt_state, jax.random.key(7))
             dev = jax.sharding.NamedSharding(
@@ -332,7 +336,9 @@ def test_checkpoint_roundtrip_int8_state(tmp_path, devices):
     tx, _ = build_optimizer(objective.config.optim, num_total_steps=4)
     trainer._blocked_offload = True
     with trainer.mesh, nn.logical_axis_rules(LOGICAL_AXIS_RULES):
-        params = nn.meta.unbox(objective.init_params(jax.random.key(0), batch))
+        params = jax.jit(
+                lambda rng: nn.meta.unbox(objective.init_params(rng, batch))
+            )(jax.random.key(0))
         state = TrainState.create(
             params, trainer._opt_init(tx, params), jax.random.key(7)
         )

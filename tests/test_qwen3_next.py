@@ -75,7 +75,7 @@ def test_logits_parity_with_hf(seq):
     ids = np.random.default_rng(70).integers(0, 128, (2, seq))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(params, jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(params, jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=4e-4, atol=4e-4)
 
 
@@ -134,5 +134,5 @@ def test_hf_causal_lm_loads_qwen3_next_checkpoint(tmp_path):
     ids = np.random.default_rng(71).integers(0, 128, (2, 24))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply(jax.tree.map(jnp.asarray, params), jnp.asarray(ids)).logits
+    ours = jax.jit(model.apply)(jax.tree.map(jnp.asarray, params), jnp.asarray(ids)).logits
     np.testing.assert_allclose(np.asarray(ours), hf_logits, rtol=4e-4, atol=4e-4)

@@ -35,7 +35,7 @@ TINY = dict(
 
 
 def _init(model, seed=0):
-    return model.init(jax.random.key(seed), np.zeros((1, 4), np.int32))
+    return jax.jit(model.init)(jax.random.key(seed), np.zeros((1, 4), np.int32))
 
 
 def _engine(model, variables, **overrides):
@@ -190,7 +190,7 @@ def _teacher_forced_logprobs(model, variables, prompt, tokens):
     predictor position len(prompt)+j-1 of the raw log-softmax."""
     seq = list(prompt) + list(tokens)
     ids = jnp.asarray([seq], jnp.int32)
-    out = model.apply(variables, input_ids=ids)
+    out = jax.jit(model.apply)(variables, input_ids=ids)
     logps = jax.nn.log_softmax(out.logits[0].astype(jnp.float32), axis=-1)
     return [
         float(logps[len(prompt) + j - 1, token])

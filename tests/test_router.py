@@ -574,3 +574,27 @@ def test_chaos_router_hooks_parse_and_fire_once(monkeypatch):
     inert = Chaos(ChaosConfig())
     assert not inert.maybe_router_kill_replica(10**6)
     assert not inert.maybe_router_blackhole(1)
+
+
+# ------------------------------------------------- one process per chip
+
+
+@pytest.mark.parametrize("platforms,replicas,refused", [
+    ("cpu", 2, False), ("cpu", 1, False), (None, 1, False), ("tpu", 1, False),
+    (None, 2, True), ("tpu", 2, True), ("tpu,cpu", 4, True),
+])
+def test_replica_spawns_never_share_an_accelerator(monkeypatch, platforms, replicas, refused):
+    """Replica spawns assign no chips (ROADMAP S3): more than one serve
+    child is refused at once, by name, unless the environment itself states
+    the CPU test path — never a hang at the second child's backend init."""
+    from llm_training_tpu.serve.router import require_one_process_per_chip
+
+    if platforms is None:
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    else:
+        monkeypatch.setenv("JAX_PLATFORMS", platforms)
+    if refused:
+        with pytest.raises(SystemExit, match="a chip belongs to one process"):
+            require_one_process_per_chip(replicas)
+    else:
+        require_one_process_per_chip(replicas)

@@ -8,6 +8,7 @@ byte-match the loop->HF export (same state dict, different flax trees).
 
 import importlib
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -89,7 +90,9 @@ def test_loop_vs_scan_parity(build):
         assert bool(active) == scan
         params = conv.params_from_hf(sd, cfg)
         ids = np.random.default_rng(7).integers(0, cfg.vocab_size, (2, 16))
-        outs.append(np.asarray(model_cls(cfg).apply(params, jnp.asarray(ids)).logits))
+        # jitted: one compile instead of an eager op-by-op forward
+        logits = jax.jit(model_cls(cfg).apply)(params, jnp.asarray(ids)).logits
+        outs.append(np.asarray(logits))
         cfgs.append(cfg)
         trees.append(params)
 

@@ -34,11 +34,14 @@ def _models(family, reset):
 
 
 def _run(model, params, ids, seg, pos):
-    out = model.apply(
-        params, jnp.asarray(ids), segment_ids=jnp.asarray(seg),
-        position_ids=jnp.asarray(pos),
-    )
-    return np.asarray(out.logits, np.float32)
+    # jitted: one compile per shape instead of an eager op-by-op forward
+    # (the eager scan stacks cost tens of seconds on CPU)
+    logits = jax.jit(
+        lambda params, ids, seg, pos: model.apply(
+            params, ids, segment_ids=seg, position_ids=pos
+        ).logits
+    )(params, jnp.asarray(ids), jnp.asarray(seg), jnp.asarray(pos))
+    return np.asarray(logits, np.float32)
 
 
 @pytest.mark.parametrize("family", ["qwen3_next", "bamba"])
@@ -58,7 +61,7 @@ def test_packed_matches_separate_docs(family):
     )
 
     model, cfg = _models(family, reset=True)
-    params = model.init(jax.random.key(0), jnp.asarray(packed_ids))
+    params = jax.jit(model.init)(jax.random.key(0), jnp.asarray(packed_ids))
 
     packed = _run(model, params, packed_ids, packed_seg, packed_pos)
     solo = _run(
@@ -93,7 +96,7 @@ def test_default_keeps_hf_leak_parity(family):
     )
 
     model, cfg = _models(family, reset=False)
-    params = model.init(jax.random.key(0), jnp.asarray(packed_ids))
+    params = jax.jit(model.init)(jax.random.key(0), jnp.asarray(packed_ids))
     packed = _run(model, params, packed_ids, packed_seg, packed_pos)
     solo = _run(
         model, params, doc2, np.ones((1, l2), np.int32), np.arange(l2)[None]

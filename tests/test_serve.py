@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import full_forward_greedy as _full_forward_greedy
 
 from llm_training_tpu.infer import GenerateConfig, InferenceEngine
 from llm_training_tpu.models import Gemma, GemmaConfig, Llama, LlamaConfig
@@ -32,39 +33,7 @@ TINY = dict(
 
 
 def _init(model, seed=0):
-    return model.init(jax.random.key(seed), np.zeros((1, 4), np.int32))
-
-
-_ORACLE_WIDTH = 32  # static pad width: covers every prompt + n in this file
-_oracle_cache: dict[int, tuple] = {}  # id(model) -> (model, jitted forward)
-
-
-def _full_forward_greedy(model, variables, prompt, n):
-    """The oracle (test_infer.py): n argmax tokens from n full forwards —
-    jitted ONCE per model at a padded static width (length traced, pads
-    masked via segment ids) so each step is a cheap cached call, not an
-    eager CPU forward."""
-    entry = _oracle_cache.get(id(model))
-    if entry is None or entry[0] is not model:
-
-        @jax.jit
-        def fwd(variables, ids, length):
-            seg = (jnp.arange(ids.shape[1]) < length).astype(jnp.int32)[None]
-            out = model.apply(variables, input_ids=ids, segment_ids=seg)
-            logits = jax.lax.dynamic_index_in_dim(
-                out.logits[0], length - 1, axis=0, keepdims=False
-            )
-            return jnp.argmax(logits)
-
-        entry = (model, fwd)  # strong model ref: id() can't be recycled
-        _oracle_cache[id(model)] = entry
-    fwd = entry[1]
-    seq = list(prompt)
-    for _ in range(n):
-        ids = np.zeros((1, _ORACLE_WIDTH), np.int32)
-        ids[0, : len(seq)] = seq
-        seq.append(int(fwd(variables, jnp.asarray(ids), jnp.int32(len(seq)))))
-    return seq[len(prompt):]
+    return jax.jit(model.init)(jax.random.key(seed), np.zeros((1, 4), np.int32))
 
 
 def _serve_all(model, variables, prompts, n, **overrides):
@@ -224,7 +193,7 @@ def test_resolve_block_size_paged_kind(monkeypatch):
 def test_paged_append_pads_go_to_trash():
     from llm_training_tpu.ops.paged_attention import paged_append
 
-    pool = jnp.zeros((4, 8, 1, 4))  # [blocks, page, h, d]
+    pool = jnp.zeros((4, 1, 8, 4))  # [blocks, h, page, d]
     k = jnp.ones((1, 4, 1, 4))
     seg = jnp.asarray([[1, 1, 0, 0]])  # 2 real tokens, 2 pads
     tables = jnp.asarray([[2, 3]])
@@ -232,7 +201,7 @@ def test_paged_append_pads_go_to_trash():
         pool, pool, k, k, jnp.asarray([7]), tables, seg
     )
     # row length 7: real tokens land at block 2 slot 7 then block 3 slot 0
-    assert float(new_k[2, 7, 0, 0]) == 1.0
+    assert float(new_k[2, 0, 7, 0]) == 1.0
     assert float(new_k[3, 0, 0, 0]) == 1.0
     # pads went to the trash block, nowhere else
     assert float(jnp.sum(new_k[1:])) == 2 * 4  # two real tokens x head_dim
@@ -249,7 +218,7 @@ def test_paged_kernel_matches_gather_fallback(window, cap, group):
 
     batch, kv_heads, head_dim, page, pages = 3, 2, 8, 8, 3
     keys = jax.random.split(jax.random.key(0), 4)
-    pool_shape = (1 + batch * pages, page, kv_heads, head_dim)
+    pool_shape = (1 + batch * pages, kv_heads, page, head_dim)
     pool_k = jax.random.normal(keys[0], pool_shape)
     pool_v = jax.random.normal(keys[1], pool_shape)
     q = jax.random.normal(keys[2], (batch, 1, kv_heads * group, head_dim))
@@ -702,3 +671,71 @@ def test_request_sampling_gates_sink_not_ring(tmp_path):
     finally:
         recorder.detach_sink()
         set_tracer(previous)
+
+
+# ------------------------------------------------- loadgen argument guard
+
+
+def _loadgen():
+    import importlib.util
+    from pathlib import Path
+
+    path = Path(__file__).resolve().parent.parent / "scripts" / "serve_loadgen.py"
+    spec = importlib.util.spec_from_file_location("serve_loadgen", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+@pytest.mark.parametrize("argv,swallowed", [
+    # the precommit form: the value of --prefill-chunk repeats the value of
+    # --requests EARLIER on the line, which the old guard took for its place
+    (["--config", "c", "--requests", "4", "--max-new-tokens", "16", "run_root=x",
+      "--max-batch", "2", "--max-model-len", "64", "--prefill-chunk", "4",
+      "--eos-token-id", "-1"], None),
+    # no earlier positional: argparse hands --max-batch's value to serve_args
+    (["--config", "c", "--max-batch", "2", "run_root=x"], "2"),
+    # ... also when that value repeats a known flag's
+    (["--config", "c", "--requests", "2", "--max-batch", "2"], "2"),
+    # the explicit separator makes everything after it intentional
+    (["--config", "c", "--", "--max-batch", "2", "run_root=x"], None),
+])
+def test_loadgen_misplaced_flag_guard(argv, swallowed):
+    loadgen = _loadgen()
+    if swallowed is None:
+        args = loadgen.parse_args(argv)
+        assert "--max-batch" in args.serve_args and "2" in args.serve_args
+    else:
+        with pytest.raises(SystemExit, match=f"positional '{swallowed}' follows the "
+                                             "unknown flag '--max-batch'"):
+            loadgen.parse_args(argv)
+
+
+def test_paged_kernel_under_a_sharded_mesh_matches_gather(devices):
+    """On a multi-device mesh the kernel runs in a shard_map over `tensor`
+    (GSPMD cannot partition a Mosaic kernel — tests/test_chip_compile.py
+    holds the compile); the result must still equal the gather path."""
+    from llm_training_tpu.ops.paged_attention import paged_cached_attention
+    from llm_training_tpu.parallel.mesh import MeshConfig, build_mesh
+
+    mesh = build_mesh(MeshConfig(fsdp_size=4, tensor_parallel_size=2), devices)
+    batch, kv_heads, group, head_dim, page, pages = 3, 2, 2, 8, 8, 3
+    keys = jax.random.split(jax.random.key(1), 5)
+    pool_shape = (1 + batch * pages, kv_heads, page, head_dim)
+    pool_k = jax.random.normal(keys[0], pool_shape)
+    pool_v = jax.random.normal(keys[1], pool_shape)
+    q = jax.random.normal(keys[2], (batch, 1, kv_heads * group, head_dim))
+    k = jax.random.normal(keys[3], (batch, 1, kv_heads, head_dim))
+    v = jax.random.normal(keys[4], (batch, 1, kv_heads, head_dim))
+    tables = jnp.arange(1, 1 + batch * pages, dtype=jnp.int32).reshape(batch, pages)
+    lengths = jnp.asarray([0, 7, 20], jnp.int32)
+
+    def attend(impl):
+        return jax.jit(lambda q, k, v, pk, pv: paged_cached_attention(
+            q, k, v, (pk, pv), lengths, tables, impl=impl
+        )[0])
+
+    with mesh:
+        got = attend("pallas")(q, k, v, pool_k, pool_v)
+    ref = attend("xla")(q, k, v, pool_k, pool_v)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=2e-5, atol=2e-5)

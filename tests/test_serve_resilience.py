@@ -412,7 +412,7 @@ def tiny_model():
     from llm_training_tpu.models import Llama, LlamaConfig
 
     model = Llama(LlamaConfig(**TINY))
-    variables = model.init(jax.random.key(0), np.zeros((1, 4), np.int32))
+    variables = jax.jit(model.init)(jax.random.key(0), np.zeros((1, 4), np.int32))
     return model, variables
 
 

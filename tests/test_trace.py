@@ -326,8 +326,8 @@ def test_report_json_schema(tmp_path, monkeypatch):
         render_report_data,
     )
 
-    # the perf section's cwd fallback would otherwise find the repo's
-    # committed BENCH_r*.json rounds
+    # the perf section's cwd fallback would otherwise find whatever bench
+    # summary sits in the directory the tests run from
     monkeypatch.chdir(tmp_path)
     run_dir = _write_run_dir(tmp_path)
     doc = render_report_data(run_dir)

@@ -162,9 +162,9 @@ def test_blocked_offload_update_matches_whole_tree(devices):
     tx_core, _ = build_optimizer(clip_free, num_total_steps=4)
 
     with trainer.mesh, nn.logical_axis_rules(LOGICAL_AXIS_RULES):
-        params = nn.meta.unbox(
-            objective.init_params(jax.random.key(0), batch)
-        )
+        params = jax.jit(
+            lambda rng: nn.meta.unbox(objective.init_params(rng, batch))
+        )(jax.random.key(0))
         # whole-tree reference step
         trainer._blocked_offload = False
         state_a = TrainState.create(params, tx_full.init(params), jax.random.key(7))
