@@ -8,6 +8,14 @@ temporary name, a pid or the time would never hit: every process of the
 repo — `fit`, `serve`, the bench's stage children, `chip_smoke.py` — must
 land in the same place for a second run to compile nothing.
 
+An entry is also keyed on the program's metadata (`jax.named_scope`s, flax
+module names, source lines), which JAX leaves out by default: a program read
+back from an entry that an older checkout wrote would carry THAT checkout's
+op names, and a device profile would put its time under scopes the running
+code no longer has, or not under the ones it has (seen on the chip, PR 25:
+`moe_*` and `sample` missing from every op of a cached decode step). The
+price is that an edit to a traced source file compiles again.
+
 Importing this module does not import jax (bench.py's and chip_smoke.py's
 parents stay off the chip); `configure_compile_cache` does.
 """
@@ -31,9 +39,10 @@ def configure_compile_cache() -> str:
     """Point JAX's persistent cache at `compile_cache_dir()`; returns it.
     With the variable set nothing is set in code — JAX already read it.
     JAX's own thresholds stay (programs that compile in under a second are
-    not written)."""
+    not written). Keys hold the programs' metadata (module docstring)."""
     import jax
 
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     if not os.environ.get(ENV_CACHE_DIR):
         jax.config.update("jax_compilation_cache_dir", str(DEFAULT_CACHE_DIR))
     return compile_cache_dir()

@@ -124,34 +124,39 @@ def _ep_ragged_apply(
         w_local = jax.tree.unflatten(w_def, w_leaves)
         lo = lax.axis_index(EXPERT_AXIS) * e_local
 
-        flat_e = idx_all.reshape(-1)
-        flat_w = wts_all.reshape(-1)
-        flat_tok = jnp.arange(t_all * top_k) // top_k
-        rel = flat_e - lo
-        local = (rel >= 0) & (rel < e_local)
-        # local rows first (sorted by expert), non-local rows pushed last
-        order = jnp.argsort(jnp.where(local, rel, e_local))
-        sel = order[:capacity]
-        sel_tok = flat_tok[sel]
+        with jax.named_scope("moe_sort"):
+            flat_e = idx_all.reshape(-1)
+            flat_w = wts_all.reshape(-1)
+            flat_tok = jnp.arange(t_all * top_k) // top_k
+            rel = flat_e - lo
+            local = (rel >= 0) & (rel < e_local)
+            # local rows first (sorted by expert), non-local rows pushed last
+            order = jnp.argsort(jnp.where(local, rel, e_local))
+            sel = order[:capacity]
+            sel_tok = flat_tok[sel]
 
-        counts = jnp.bincount(
-            jnp.where(local, rel, e_local), length=e_local + 1
-        )[:e_local]
-        start = jnp.cumsum(counts) - counts
-        # rows are expert-sorted, so clipping to the buffer drops exactly
-        # the rows that did not fit
-        gs = jnp.clip(jnp.minimum(counts, capacity - start), 0)
-        total = gs.sum()
+            counts = jnp.bincount(
+                jnp.where(local, rel, e_local), length=e_local + 1
+            )[:e_local]
+            start = jnp.cumsum(counts) - counts
+            # rows are expert-sorted, so clipping to the buffer drops exactly
+            # the rows that did not fit
+            gs = jnp.clip(jnp.minimum(counts, capacity - start), 0)
+            total = gs.sum()
 
-        ys = ragged_fn(
-            x_all[sel_tok],
-            gs.astype(jnp.int32),
-            jnp.clip(rel[sel], 0, e_local - 1),
-            w_local,
-        )
-        valid = jnp.arange(capacity) < total  # local rows sort first
-        ys = ys * (flat_w[sel] * valid).astype(ys.dtype)[:, None]
-        out_all = jnp.zeros((t_all, hidden), ys.dtype).at[sel_tok].add(ys)
+        with jax.named_scope("moe_gather"):
+            xs = x_all[sel_tok]
+        with jax.named_scope("moe_experts"):
+            ys = ragged_fn(
+                xs,
+                gs.astype(jnp.int32),
+                jnp.clip(rel[sel], 0, e_local - 1),
+                w_local,
+            )
+        with jax.named_scope("moe_scatter"):
+            valid = jnp.arange(capacity) < total  # local rows sort first
+            ys = ys * (flat_w[sel] * valid).astype(ys.dtype)[:, None]
+            out_all = jnp.zeros((t_all, hidden), ys.dtype).at[sel_tok].add(ys)
         # (token, expert) rows routed to this rank's experts that did not
         # fit the capacity buffer — the silent quality hazard of static
         # capacity; summed over the EP group and surfaced as a train metric
@@ -207,6 +212,7 @@ def sparsemixer_topk(logits, jitter_eps: float, top_k: int = 2):
     return jnp.stack([w1, w2], axis=-1), jnp.stack([i1, i2], axis=-1)
 
 
+@jax.named_scope("moe_sort")
 def _sorted_dispatch(topk_idx, topk_weights, num_experts):
     """Shared dispatch prelude: (flat_weight, flat_token, order, gs) for the
     expert-sorted row layout both the ragged and bucketed paths consume."""
@@ -238,21 +244,26 @@ def _bucketed_apply(
     _, flat_weight, flat_token, order, gs = _sorted_dispatch(
         topk_idx, topk_weights, num_experts
     )
-    start = jnp.cumsum(gs) - gs
-    offs = jnp.arange(capacity)
-    # bucket e, slot c -> index into the sorted rows (clamped; invalid
-    # slots masked to zero contribution)
-    src_sorted = jnp.clip(start[:, None] + offs[None, :], 0, rows - 1)
-    valid = offs[None, :] < gs[:, None]  # [E, capacity]
-    src = order[src_sorted.reshape(-1)]  # -> original (token, slot) rows
-    tok = flat_token[src]
-    xb = jnp.where(
-        valid.reshape(-1)[:, None], x[tok], 0
-    ).reshape(num_experts, capacity, hidden)
-    yb = bmm_fn(xb)  # [E, capacity, H]
-    w = (flat_weight[src] * valid.reshape(-1).astype(flat_weight.dtype))
-    ys = yb.reshape(-1, hidden) * w.astype(yb.dtype)[:, None]
-    out = jnp.zeros((n_tokens, hidden), x.dtype).at[tok].add(ys.astype(x.dtype))
+    with jax.named_scope("moe_gather"):
+        start = jnp.cumsum(gs) - gs
+        offs = jnp.arange(capacity)
+        # bucket e, slot c -> index into the sorted rows (clamped; invalid
+        # slots masked to zero contribution)
+        src_sorted = jnp.clip(start[:, None] + offs[None, :], 0, rows - 1)
+        valid = offs[None, :] < gs[:, None]  # [E, capacity]
+        src = order[src_sorted.reshape(-1)]  # -> original (token, slot) rows
+        tok = flat_token[src]
+        xb = jnp.where(
+            valid.reshape(-1)[:, None], x[tok], 0
+        ).reshape(num_experts, capacity, hidden)
+    with jax.named_scope("moe_experts"):
+        yb = bmm_fn(xb)  # [E, capacity, H]
+    with jax.named_scope("moe_scatter"):
+        w = (flat_weight[src] * valid.reshape(-1).astype(flat_weight.dtype))
+        ys = yb.reshape(-1, hidden) * w.astype(yb.dtype)[:, None]
+        out = jnp.zeros((n_tokens, hidden), x.dtype).at[tok].add(
+            ys.astype(x.dtype)
+        )
     dropped = (rows - jnp.minimum(gs, capacity).sum()).astype(jnp.float32)
     return out, dropped
 
@@ -284,6 +295,12 @@ def dropless_moe_apply(
     `bmm_fn(xb [E, C, H]) -> [E, C, H]` (batched dense expert stack) enables
     `impl='bucketed'`; families that do not provide it reject that impl.
 
+    Every path names its phases for a device profile (`jax.named_scope`, op
+    metadata only): `moe_sort` (argsort + group sizes), `moe_gather`,
+    `moe_experts` (the grouped or batched matmuls), `moe_scatter` (weighted
+    scatter-add); the router's `moe_route` is the calling module's
+    (docs/observability.md#tracing; benchmarks read them by name).
+
     Returns (out [T, H], dropped_rows fp32 scalar): dropped_rows counts
     (token, slot) assignments lost to a capacity buffer (expert-parallel
     rank buffer, or the per-expert buckets of impl='bucketed') this call —
@@ -314,12 +331,14 @@ def dropless_moe_apply(
             x, topk_idx, topk_weights, num_experts, bmm_fn, moe_capacity_factor
         )
     if impl == "dense":
-        y = dense_fn(x)
-        combine = jnp.zeros((n_tokens, num_experts), x.dtype)
-        combine = combine.at[
-            jnp.arange(n_tokens)[:, None], topk_idx
-        ].set(topk_weights)
-        return jnp.einsum("teh,te->th", y, combine), no_drops
+        with jax.named_scope("moe_experts"):
+            y = dense_fn(x)
+        with jax.named_scope("moe_scatter"):
+            combine = jnp.zeros((n_tokens, num_experts), x.dtype)
+            combine = combine.at[
+                jnp.arange(n_tokens)[:, None], topk_idx
+            ].set(topk_weights)
+            return jnp.einsum("teh,te->th", y, combine), no_drops
     ep = _ep_group_size()
     if ep > 1:
         if num_experts % ep:
@@ -334,10 +353,14 @@ def dropless_moe_apply(
     flat_expert, flat_weight, flat_token, order, group_sizes = _sorted_dispatch(
         topk_idx, topk_weights, num_experts
     )
-    token_order = flat_token[order]
-    ys = ragged_fn(x[token_order], group_sizes, flat_expert[order], weights)
-    ys = ys * flat_weight[order][:, None]
-    out = jnp.zeros((n_tokens, x.shape[-1]), x.dtype).at[token_order].add(ys)
+    with jax.named_scope("moe_gather"):
+        token_order = flat_token[order]
+        xs, expert_order = x[token_order], flat_expert[order]
+    with jax.named_scope("moe_experts"):
+        ys = ragged_fn(xs, group_sizes, expert_order, weights)
+    with jax.named_scope("moe_scatter"):
+        ys = ys * flat_weight[order][:, None]
+        out = jnp.zeros((n_tokens, x.shape[-1]), x.dtype).at[token_order].add(ys)
     return out, no_drops
 
 
@@ -385,18 +408,21 @@ class MoEMLP(nn.Module):
             ),
             name="gate",
         )
-        logits = router(x).astype(jnp.float32)  # [T, E]
-        probs = jax.nn.softmax(logits, axis=-1)  # full softmax (router stats)
-        if getattr(cfg, "moe_router_impl", "softmax") == "sparsemixer":
-            # Phi-3.5-MoE's deterministic (eval-mode) SparseMixer selection
-            topk_probs, topk_idx = sparsemixer_topk(
-                logits, getattr(cfg, "router_jitter_eps", 0.01), top_k
-            )
-        else:
-            topk_probs, topk_idx = jax.lax.top_k(probs, top_k)  # [T, K]
-            if cfg.norm_topk_prob:
-                topk_probs = topk_probs / topk_probs.sum(axis=-1, keepdims=True)
-        topk_probs = topk_probs.astype(compute_dtype)
+        with jax.named_scope("moe_route"):
+            logits = router(x).astype(jnp.float32)  # [T, E]
+            probs = jax.nn.softmax(logits, axis=-1)  # full softmax (router stats)
+            if getattr(cfg, "moe_router_impl", "softmax") == "sparsemixer":
+                # Phi-3.5-MoE's deterministic (eval-mode) SparseMixer selection
+                topk_probs, topk_idx = sparsemixer_topk(
+                    logits, getattr(cfg, "router_jitter_eps", 0.01), top_k
+                )
+            else:
+                topk_probs, topk_idx = jax.lax.top_k(probs, top_k)  # [T, K]
+                if cfg.norm_topk_prob:
+                    topk_probs = topk_probs / topk_probs.sum(
+                        axis=-1, keepdims=True
+                    )
+            topk_probs = topk_probs.astype(compute_dtype)
 
         # ---- stacked expert weights
         def expert_param(name, shape, axes):

@@ -57,6 +57,7 @@ def cross_entropy(
     raise ValueError(f"unknown reduction {reduction!r}")
 
 
+@jax.named_scope("loss_ce")  # a device profile reads the head + loss by this name
 def fused_linear_cross_entropy(
     hidden: jnp.ndarray,
     weight: jnp.ndarray,

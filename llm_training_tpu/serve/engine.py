@@ -67,6 +67,8 @@ from llm_training_tpu.serve.scheduler import (
     SchedulerConfig,
     ServeRequest,
 )
+from llm_training_tpu.telemetry.profiling import install_trace_annotator
+from llm_training_tpu.telemetry.registry import get_registry
 from llm_training_tpu.telemetry.trace import get_tracer
 
 logger = logging.getLogger(__name__)
@@ -175,6 +177,7 @@ class ServingEngine:
                 mesh=self.mesh, rules=self.rules,
                 cache_dtype=self.config.cache_dtype,
             )
+        self._cache_bytes = pool_bytes(self._pool_k, self._pool_v)  # outlives close()
         self.allocator = BlockAllocator(num_blocks + 1)
         self.scheduler = Scheduler(
             SchedulerConfig(
@@ -188,6 +191,12 @@ class ServingEngine:
             self.allocator,
         )
         self._build_programs()
+        # a profiler capture of this process holds step()'s spans beside the
+        # device's ops (docs/observability.md#tracing)
+        install_trace_annotator()
+        # the running step's counts: filled where the work is decided, closed
+        # into the engine_step span and the serve/* counters by step()
+        self._step_counts: dict[str, int] = {}
         self._rng = jax.random.key(self.config.seed)
         self._call = 0
         self._t0: float | None = None
@@ -252,9 +261,10 @@ class ServingEngine:
             logits = jax.lax.dynamic_index_in_dim(
                 out.logits[0], last_pos, axis=0, keepdims=False
             ).astype(jnp.float32)
-            token, logprob = sample_tokens_with_logprob(
-                logits[None], rng, sampling
-            )
+            with jax.named_scope("sample"):
+                token, logprob = sample_tokens_with_logprob(
+                    logits[None], rng, sampling
+                )
             state = out.decode_state
             return state.k, state.v, token[0], logprob[0]
 
@@ -268,10 +278,15 @@ class ServingEngine:
                 position_ids=lengths[:, None], decode_state=state,
             )
             logits = out.logits[:, -1].astype(jnp.float32)
-            token, logprob = sample_tokens_with_logprob(logits, rng, sampling)
+            with jax.named_scope("sample"):
+                token, logprob = sample_tokens_with_logprob(logits, rng, sampling)
             state = out.decode_state
             return state.k, state.v, token, logprob
 
+        # the function names ARE the programs' names (`jit_prefill_chunk`,
+        # `jit_decode_step` in HLO module names and in a device profile):
+        # docs, chip_smoke.py and the benchmark's trace readers match them.
+        # A contract, pinned by tests/test_serve_spans.py.
         self._prefill_jit = jax.jit(prefill_chunk, donate_argnums=(4, 5))
         self._decode_jit = jax.jit(decode_step, donate_argnums=(2, 3))
 
@@ -445,14 +460,8 @@ class ServingEngine:
             evicted += 1
         self.variables = variables
         self.weights_generation += 1
-        from llm_training_tpu.telemetry import get_registry
-
         get_registry().gauge("serve/weights_generation").set(
             float(self.weights_generation)
-        )
-        get_tracer().instant(
-            "serve", "weights_reload", generation=self.weights_generation,
-            evicted_for_reload=evicted,
         )
         logger.info(
             "weights reloaded: generation %d (%d in-flight request(s) "
@@ -489,6 +498,18 @@ class ServingEngine:
         )
         return summary
 
+    def close(self) -> None:
+        """Wait for the pool's last write, then give the pool's device
+        memory back: for a caller that keeps the weights and needs the room
+        (a reference pass after serving, a reload into a larger pool). The
+        engine cannot step afterwards; `stats()` still answers. Idempotent."""
+        if self._pool_k is None:
+            return
+        jax.block_until_ready((self._pool_k, self._pool_v))
+        self._pool_k.delete()
+        self._pool_v.delete()
+        self._pool_k = self._pool_v = None
+
     # ---------------------------------------------------------------- step
 
     def step(self) -> list[dict]:
@@ -500,49 +521,78 @@ class ServingEngine:
         events: list[dict] = []
         tracer = get_tracer()
         self._step_index += 1
-        # terminals and token chunks returned from the PREVIOUS step have
-        # been delivered by now (the caller emits between steps): retire
-        # finished ids and checkpoint progress/emitted watermarks before
-        # this step can wedge or die. Journaling either at build time
-        # would let a death between build and flush lose a terminal (or
-        # skip re-streaming tokens the client never saw).
-        self._retire_finished()
-        if self.journal is not None and self._step_index % self._journal_every == 0:
-            for request in self.scheduler.running.values():
-                self.journal.progress(request)
-        # chaos serve faults (docs/resilience.md#chaos): a wedged step and
-        # a mid-stream SIGTERM are injected exactly where the real ones
-        # land — the top of an engine step, heartbeat already owed
-        chaos = get_chaos()
-        if chaos is not None:
-            chaos.maybe_serve_stall(self._step_index)
-            chaos.maybe_serve_sigterm_mid_stream(self._step_index)
+        step = self._step_index
+        # one bookkeeping, two readers: the engine_step span's closing args
+        # and the cumulative serve/* counters (docs/observability.md#tracing)
+        counts = self._step_counts = {
+            "prefill_chunks": 0, "prefill_tokens": 0,
+            "decode_rows": 0, "live_tokens": 0,
+        }
+        # every child span below runs on this thread inside engine_step and
+        # carries the step index; nesting by time gives the parent. Children
+        # go to the ring and the profiler only (`write=False`): trace.jsonl
+        # keeps one engine_step a step, and a sampled request's prefill_chunk
+        child = {"step": step, "write": False}
         with tracer.measure(
-            "serve", "engine_step", step=self._step_index,
+            "serve", "engine_step", step=step,
             running=len(self.scheduler.running),
             waiting=len(self.scheduler.waiting),
-        ), self._ctx():
-            before = len(self.scheduler.completed)
-            # deadlines first: expired queued work never costs a FLOP and
-            # an expired decode row frees its blocks before admission looks
-            # at the pool
-            self.scheduler.expire_deadlines()
-            self.scheduler.admit()
-            # the service-time EMA moves with every completion, so the
-            # projected-TTFT shed decision is re-evaluated each step too
-            self.scheduler.shed()
-            # scheduler-side completions (capacity/deadline/overloaded) are
-            # completions — the protocol owes each a done chunk like any
-            # other
-            for request in self.scheduler.completed[before:]:
-                events.append(self._done_event(request))
-            self.peak_running = max(self.peak_running, len(self.scheduler.running))
-            plan = self.scheduler.next_prefill()
+        ) as closing, self._ctx():
+            with tracer.measure("serve", "housekeeping", **child):
+                # terminals and token chunks returned from the PREVIOUS step
+                # have been delivered by now (the caller emits between
+                # steps): retire finished ids and checkpoint progress/emitted
+                # watermarks before this step can wedge or die. Journaling
+                # either at build time would let a death between build and
+                # flush lose a terminal (or skip re-streaming tokens the
+                # client never saw).
+                self._retire_finished()
+                if self.journal is not None and step % self._journal_every == 0:
+                    for request in self.scheduler.running.values():
+                        self.journal.progress(request)
+                # chaos serve faults (docs/resilience.md#chaos): a wedged
+                # step and a mid-stream SIGTERM are injected exactly where
+                # the real ones land — the top of an engine step, heartbeat
+                # already owed
+                chaos = get_chaos()
+                if chaos is not None:
+                    chaos.maybe_serve_stall(step)
+                    chaos.maybe_serve_sigterm_mid_stream(step)
+            with tracer.measure("serve", "schedule", **child):
+                before = len(self.scheduler.completed)
+                # deadlines first: expired queued work never costs a FLOP
+                # and an expired decode row frees its blocks before
+                # admission looks at the pool
+                self.scheduler.expire_deadlines()
+                self.scheduler.admit()
+                # the service-time EMA moves with every completion, so the
+                # projected-TTFT shed decision is re-evaluated each step too
+                self.scheduler.shed()
+                # scheduler-side completions (capacity/deadline/overloaded)
+                # are completions — the protocol owes each a done chunk like
+                # any other
+                for request in self.scheduler.completed[before:]:
+                    events.append(self._done_event(request))
+                self.peak_running = max(
+                    self.peak_running, len(self.scheduler.running)
+                )
+                plan = self.scheduler.next_prefill()
             if plan is not None:
                 events.extend(self._run_prefill(*plan))
-            rows = self.scheduler.decode_rows()
+            # after the prefill: a prompt completed this step decodes this step
+            with tracer.measure("serve", "schedule", **child):
+                rows = self.scheduler.decode_rows()
             if rows:
                 events.extend(self._run_decode(rows))
+            closing.update(counts)
+        registry = get_registry()
+        registry.counter("serve/steps").inc()
+        if counts["prefill_chunks"]:
+            registry.counter("serve/prefill_chunks").inc(counts["prefill_chunks"])
+        if counts["decode_rows"]:
+            registry.counter("serve/decode_steps").inc()
+            registry.counter("serve/decode_rows").inc(counts["decode_rows"])
+            registry.counter("serve/live_tokens").inc(counts["live_tokens"])
         return events
 
     def _emit_token(
@@ -594,83 +644,104 @@ class ServingEngine:
 
     def _run_prefill(self, request: ServeRequest, chunk: list[int], start: int) -> list[dict]:
         events: list[dict] = []
-        t_chunk = time.perf_counter()
-        width = self.config.prefill_chunk
-        ids = np.zeros((1, width), np.int32)
-        seg = np.zeros((1, width), np.int32)
-        ids[0, : len(chunk)] = chunk
-        seg[0, : len(chunk)] = 1
-        pos = np.minimum(
-            start + np.arange(width), self.config.max_model_len - 1
-        ).astype(np.int32)[None, :]
-        tables = self._table_row(request)[None, :]
+        tracer = get_tracer()
+        ids = {"step": self._step_index, "request_id": request.id}
         final = start + len(chunk) >= len(request.prefill_tokens)
-        self._pool_k, self._pool_v, token, logprob = self._prefill_jit(
-            self.variables, jnp.asarray(ids), jnp.asarray(seg),
-            jnp.asarray(pos), self._pool_k, self._pool_v,
-            jnp.asarray(tables), jnp.asarray([start], jnp.int32),
-            jnp.int32(len(chunk) - 1), self._next_rng(),
-        )
-        request.prefilled += len(chunk)
-        request.cache_len += len(chunk)
-        if final:
-            host_token, host_logprob = jax.device_get((token, logprob))
-            self._emit_token(
-                request, int(host_token), events, logprob=float(host_logprob)
-            )
-        now = time.perf_counter()
-        get_tracer().span(
-            "serve", "prefill_chunk", t_chunk, now, write=request.traced,
-            request_id=request.id, start=start, tokens=len(chunk), final=final,
-        )
+        self._step_counts["prefill_chunks"] = 1
+        self._step_counts["prefill_tokens"] = len(chunk)
+        with tracer.measure(
+            "serve", "prefill_chunk", write=request.traced, **ids,
+            start=start, tokens=len(chunk), final=final,
+        ):
+            # inputs and the enqueue; the device's time shows in prefill_fetch
+            # (a final chunk) or in the next decode_fetch
+            with tracer.measure("serve", "prefill_dispatch", write=False, **ids):
+                width = self.config.prefill_chunk
+                ids_row = np.zeros((1, width), np.int32)
+                seg = np.zeros((1, width), np.int32)
+                ids_row[0, : len(chunk)] = chunk
+                seg[0, : len(chunk)] = 1
+                pos = np.minimum(
+                    start + np.arange(width), self.config.max_model_len - 1
+                ).astype(np.int32)[None, :]
+                tables = self._table_row(request)[None, :]
+                self._pool_k, self._pool_v, token, logprob = self._prefill_jit(
+                    self.variables, jnp.asarray(ids_row), jnp.asarray(seg),
+                    jnp.asarray(pos), self._pool_k, self._pool_v,
+                    jnp.asarray(tables), jnp.asarray([start], jnp.int32),
+                    jnp.int32(len(chunk) - 1), self._next_rng(),
+                )
+            request.prefilled += len(chunk)
+            request.cache_len += len(chunk)
+            if final:
+                with tracer.measure("serve", "prefill_fetch", write=False, **ids):
+                    host_token, host_logprob = jax.device_get((token, logprob))
+                self._emit_token(
+                    request, int(host_token), events, logprob=float(host_logprob)
+                )
         if final and not request.done:
             # the first new token landed inside the prefill phase; decode
-            # (one token per engine step) starts here
-            request.advance_phase("decode", now)
+            # (one token per engine step) starts here: at this call's own
+            # clock reading, a few microseconds after prefill_chunk closed
+            request.advance_phase("decode")
         return events
 
     def _run_decode(self, rows: list[ServeRequest]) -> list[dict]:
         events: list[dict] = []
-        # grow each row's blocks for this step's write; under pool pressure
-        # this evicts lowest-priority requests (possibly out of `rows`)
-        survivors = []
-        for request in rows:
-            if request.slot is not None and self.scheduler.ensure_decode_blocks(request):
-                survivors.append(request)
-        # a LATER row's block-pressure eviction can take an EARLIER
-        # survivor (lower priority, mid-page so its own check passed) —
-        # its slot is gone and its blocks may already belong to the
-        # evictor, so it must not decode this step
-        survivors = [r for r in survivors if r.slot is not None]
+        tracer = get_tracer()
+        child = {"step": self._step_index, "write": False}
+        with tracer.measure("serve", "decode_blocks", **child):
+            # grow each row's blocks for this step's write; under pool
+            # pressure this evicts lowest-priority requests (possibly out of
+            # `rows`)
+            survivors = []
+            for request in rows:
+                if request.slot is not None and self.scheduler.ensure_decode_blocks(request):
+                    survivors.append(request)
+            # a LATER row's block-pressure eviction can take an EARLIER
+            # survivor (lower priority, mid-page so its own check passed) —
+            # its slot is gone and its blocks may already belong to the
+            # evictor, so it must not decode this step
+            survivors = [r for r in survivors if r.slot is not None]
         if not survivors:
             return events
-        batch = self.config.max_batch
-        tokens = np.zeros((batch,), np.int32)
-        lengths = np.zeros((batch,), np.int32)
-        tables = np.zeros((batch, self.pages_per_request), np.int32)
-        for request in survivors:
-            tokens[request.slot] = request.generated[-1]
-            lengths[request.slot] = request.cache_len
-            tables[request.slot] = self._table_row(request)
-        step_args = (
-            self.variables, jnp.asarray(tokens), self._pool_k, self._pool_v,
-            jnp.asarray(tables), jnp.asarray(lengths), self._next_rng(),
-        )
+        with tracer.measure("serve", "decode_inputs", **child):
+            batch = self.config.max_batch
+            tokens = np.zeros((batch,), np.int32)
+            lengths = np.zeros((batch,), np.int32)
+            tables = np.zeros((batch, self.pages_per_request), np.int32)
+            for request in survivors:
+                tokens[request.slot] = request.generated[-1]
+                lengths[request.slot] = request.cache_len
+                tables[request.slot] = self._table_row(request)
+            self._step_counts["decode_rows"] = len(survivors)
+            # what the paged kernel reads this call: each row's cache and its
+            # new token
+            self._step_counts["live_tokens"] = int(lengths.sum()) + len(survivors)
+            step_args = (
+                self.variables, jnp.asarray(tokens), self._pool_k, self._pool_v,
+                jnp.asarray(tables), jnp.asarray(lengths), self._next_rng(),
+            )
         if not self._decode_attr_done:
             # before the donating call below: lowering only reads avals,
             # while the jit consumes the pool buffers
             self._decode_attr_done = True
             self._publish_decode_attribution(step_args)
-        self._pool_k, self._pool_v, out, out_lp = self._decode_jit(*step_args)
-        host, host_lp = jax.device_get((out, out_lp))
-        host = np.asarray(host)
-        host_lp = np.asarray(host_lp)
-        for request in survivors:
-            request.cache_len += 1
-            self._emit_token(
-                request, int(host[request.slot]), events,
-                logprob=float(host_lp[request.slot]),
-            )
+        # the enqueue alone, then the wait for the device: a step that reads
+        # far off shows in which of the two its seconds went
+        with tracer.measure("serve", "decode_dispatch", **child):
+            self._pool_k, self._pool_v, out, out_lp = self._decode_jit(*step_args)
+        with tracer.measure("serve", "decode_fetch", **child):
+            host, host_lp = jax.device_get((out, out_lp))
+        with tracer.measure("serve", "decode_emit", **child):
+            host = np.asarray(host)
+            host_lp = np.asarray(host_lp)
+            for request in survivors:
+                request.cache_len += 1
+                self._emit_token(
+                    request, int(host[request.slot]), events,
+                    logprob=float(host_lp[request.slot]),
+                )
         return events
 
     def _publish_decode_attribution(self, step_args) -> None:
@@ -683,7 +754,6 @@ class ServingEngine:
             from llm_training_tpu.telemetry.device import (
                 compiled_attribution_gauges,
             )
-            from llm_training_tpu.telemetry.registry import get_registry
 
             with self._ctx():
                 compiled = self._decode_jit.lower(*step_args).compile()
@@ -827,8 +897,6 @@ class ServingEngine:
     def stats(self) -> dict[str, float]:
         """Engine/latency summary, published as `serve/*` gauges (merged
         into telemetry.jsonl by the CLI; `report` renders `== Serving ==`)."""
-        from llm_training_tpu.telemetry import get_registry
-
         completed_all, completed, ttft, tpot = self._completed_latencies()
         wall = (time.perf_counter() - self._t0) if self._t0 is not None else 0.0
         n_chips = max(1, jax.device_count())
@@ -853,7 +921,7 @@ class ServingEngine:
             "serve/tokens_per_sec": tps,
             "serve/tokens_per_sec_per_chip": tps / n_chips,
             "serve/peak_running": float(self.peak_running),
-            "decode/cache_bytes": float(pool_bytes(self._pool_k, self._pool_v)),
+            "decode/cache_bytes": float(self._cache_bytes),
             "decode/cache_blocks_total": float(self.allocator.num_blocks - 1),
             "decode/cache_blocks_in_use": float(self.allocator.blocks_in_use),
             "decode/cache_peak_blocks_in_use": float(self.allocator.peak_in_use),

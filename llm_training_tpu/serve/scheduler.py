@@ -221,11 +221,6 @@ class Scheduler:
         for request in [r for r in self.running.values() if expired(r)]:
             self.finish(request, "deadline")
             self.deadline_total += 1
-            get_tracer().instant(
-                "serve", "deadline_expired", write=request.traced,
-                request_id=request.id, phase="decode",
-                n_tokens=len(request.generated),
-            )
 
     def shed(self) -> None:
         """Shed lowest-priority queued work (stop_reason='overloaded')
@@ -275,11 +270,6 @@ class Scheduler:
             self.shed_total += 1
         elif stop_reason == "deadline":
             self.deadline_total += 1
-        get_tracer().instant(
-            "serve", "shed" if stop_reason == "overloaded" else "deadline_expired",
-            write=request.traced, request_id=request.id, phase="queue",
-            queue_depth=len(self.waiting), priority=request.priority,
-        )
 
     # --------------------------------------------------------- admission
 

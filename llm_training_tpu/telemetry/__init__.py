@@ -37,6 +37,7 @@ from llm_training_tpu.telemetry.profiling import (
     ProfileTrigger,
     build_profile_trigger,
     get_profile_trigger,
+    install_trace_annotator,
     set_profile_trigger,
 )
 from llm_training_tpu.telemetry.slo import (
@@ -94,6 +95,7 @@ __all__ = [
     "get_registry",
     "get_tracer",
     "hbm_gauges",
+    "install_trace_annotator",
     "set_profile_trigger",
     "layer_health_metrics",
     "moe_router_health",

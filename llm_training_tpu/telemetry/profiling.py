@@ -70,6 +70,22 @@ def _env_int(name: str, default: int) -> int:
     return int(_env_float(name, float(default)))
 
 
+def install_trace_annotator(tracer=None) -> None:
+    """The jax-holding half of `TraceRecorder.measure`: from here on every
+    span the process tracer opens is doubled as a
+    `jax.profiler.TraceAnnotation` (`llmt/<cat>/<name>`, the span's args as
+    keyword arguments), so a profiler capture — a `ProfileTrigger` window,
+    a benchmark's traced run — holds the program's own spans on the device
+    trace's clock. With no capture open an annotation is a flag test.
+    Called where the loops that own the device are built (`ServingEngine`,
+    `Trainer.fit`); idempotent."""
+    import jax
+
+    (tracer or get_tracer()).set_annotator(
+        lambda name, args: jax.profiler.TraceAnnotation(name, **args)
+    )
+
+
 def sanitize_tag(tag: str) -> str:
     """Tags become file/dir names next to the flight dumps; collapse
     anything path-hostile instead of refusing the capture."""

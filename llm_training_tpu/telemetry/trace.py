@@ -17,6 +17,13 @@ correlation ids (`request_id` for serving, `step` for training) — into
   negligible while coarse lifecycle events (compile, checkpoint_save,
   validation, segment boundaries) are always persisted.
 
+Spans opened through `TraceRecorder.measure` can also reach the device
+profiler: a process that holds jax installs an *annotator*
+(`telemetry/profiling.py:install_trace_annotator`), and every such span is
+then doubled as a `jax.profiler.TraceAnnotation` named
+`llmt/<cat>/<name>` — on the device trace's own clock, beside the device's
+ops, whenever a profiler capture is open (docs/observability.md#tracing).
+
 `llm-training-tpu trace <run_dir>` exports the sink as Chrome-trace-format
 JSON viewable in Perfetto (ui.perfetto.dev): one track per request, one
 for the serving engine's steps, one for the trainer's phases.
@@ -35,7 +42,7 @@ import os
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from pathlib import Path
 from typing import Iterator
 
@@ -47,6 +54,11 @@ _FLUSH_EVERY = 64
 
 # serve request-lifecycle phase names, in order (docs/observability.md)
 REQUEST_PHASES = ("queue", "prefill", "decode")
+
+# what tells this program's spans from the profiler's own Python frames in a
+# device profile: an annotated span is named `llmt/<cat>/<name>`. A contract
+# (test-pinned): benchmarks/span_reduce.py and Perfetto queries match it.
+ANNOTATION_PREFIX = "llmt/"
 
 
 def _env_int(name: str, default: int, minimum: int = 1) -> int:
@@ -106,6 +118,7 @@ class TraceRecorder:
         train_steps: bool | None = None,
         enabled: bool | None = None,
         clock=time.perf_counter,
+        annotator=None,
     ):
         # env overlay (docs/observability.md#tracing-env): explicit args win
         self.capacity = capacity or _env_int("LLMT_TRACE_RING", 2048)
@@ -118,6 +131,10 @@ class TraceRecorder:
             enabled if enabled is not None else _env_flag("LLMT_TRACE", True)
         )
         self.clock = clock
+        # `(name, args) -> context manager`, or None: how `measure` doubles a
+        # span into the device profiler without this module importing jax.
+        # Set once, by the loop that owns the device, before it steps.
+        self._annotator = annotator  # guarded by: _lock
         self._ring: deque[dict] = deque(maxlen=self.capacity)  # guarded by: _lock
         self._lock = threading.Lock()
         self._sink = None  # guarded by: _lock
@@ -226,15 +243,37 @@ class TraceRecorder:
             event["args"] = args
         self._record(event, write)
 
+    def set_annotator(self, annotator) -> None:
+        """Install (or, with None, remove) the profiler side of `measure`:
+        a callable `(name, args) -> context manager`. What its `__enter__`
+        returns takes the span's late args through `set_metadata(**args)` —
+        `jax.profiler.TraceAnnotation`'s own shape, so the jax-holding side
+        (`telemetry/profiling.py:install_trace_annotator`) is one lambda."""
+        with self._lock:
+            self._annotator = annotator
+
     @contextmanager
     def measure(
         self, cat: str, name: str, write: bool = True, **args
-    ) -> Iterator[None]:
-        t0 = self.clock()
-        try:
-            yield
-        finally:
-            self.span(cat, name, t0, self.clock(), write=write, **args)
+    ) -> Iterator[dict]:
+        """THE way to open a span: [enter, exit) lands in the ring (and the
+        sink when `write`), and under an installed annotator also in the
+        device profiler as `llmt/<cat>/<name>` with `args` as its keyword
+        arguments. Yields a dict the body may fill with args known only at
+        the end (a step's counts); they join both records."""
+        late: dict = {}
+        annotator = self._annotator if self.enabled else None
+        opened = nullcontext() if annotator is None else annotator(
+            f"{ANNOTATION_PREFIX}{cat}/{name}", args
+        )
+        with opened as annotation:
+            t0 = self.clock()
+            try:
+                yield late
+            finally:
+                self.span(cat, name, t0, self.clock(), write=write, **args, **late)
+                if late and annotation is not None:
+                    annotation.set_metadata(**late)
 
     # ---------------------------------------------------------- sampling
 

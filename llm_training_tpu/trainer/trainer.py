@@ -60,6 +60,7 @@ from llm_training_tpu.telemetry import (
     compiled_cost_gauges,
     get_tracer,
     hbm_gauges,
+    install_trace_annotator,
     layer_health_metrics,
     resolve_run_dir,
     set_profile_trigger,
@@ -209,7 +210,10 @@ class Trainer:
         # callback-visible run state (time/MFU estimator reads these).
         # abstract_state is the jax.eval_shape tree — safe to inspect any
         # time; live TrainState buffers are donated into the next step and
-        # must never be cached by callbacks outside the current hook call
+        # must never be cached by callbacks outside the current hook call:
+        # live_state is the loop's state for the duration of the
+        # on_train_step / on_step_end calls and None outside them
+        self.live_state: TrainState | None = None
         self.should_stop = False
         # callbacks set this when the state must NOT be persisted (e.g. the
         # NaN guard stopping on divergence — saving would poison resume)
@@ -793,13 +797,15 @@ class Trainer:
 
     def _fit_inner(self, objective, datamodule, resume_step, state) -> TrainState:
         cfg = self.config
-        # host-side trace spans mirror the jax.profiler annotation sites
-        # below (docs/observability.md#tracing): coarse lifecycle events
+        # every span below opens through tracer.measure, which also opens
+        # its profiler annotation `llmt/train/<name>` once the annotator is
+        # installed (docs/observability.md#tracing): coarse lifecycle events
         # (compile, validation, checkpoint_save, segment boundaries) always
         # reach the sink; the per-micro-step data_load/train_step spans are
         # written only with LLMT_TRACE_TRAIN=1 — the ring records them
         # regardless, so the flight recorder has context on every crash
         tracer = get_tracer()
+        install_trace_annotator(tracer)
         trace_train = tracer.train_steps
         batches = datamodule.train_batches(start_step=0)
         sample_batch = next(batches)
@@ -1137,7 +1143,6 @@ class Trainer:
                         self._watchdog.beat("train_loop", step=micro)
                     with jax.profiler.StepTraceAnnotation("train", step_num=micro):
                         with self.ledger.measure("data_wait"), \
-                                jax.profiler.TraceAnnotation("data_load"), \
                                 tracer.measure(
                                     "train", "data_load",
                                     write=trace_train, step=micro,
@@ -1162,57 +1167,56 @@ class Trainer:
                         first_compiling = aot_step is None and micro == seg_start
                         phase = "compile" if first_compiling else "step_compute"
                         t_step = time.perf_counter()
-                        if use_health:
-                            health_phase = (
-                                "compile" if not health_compiled else "step_compute"
-                            )
-                            with self.ledger.measure(health_phase), \
-                                    jax.profiler.TraceAnnotation("train_step"):
-                                state, metrics = health_step(state, batch)
-                            if not health_compiled and aot_step is None:
-                                # no plain-step AOT ran: the health compile IS
-                                # the run's train-step compile
+                        with tracer.measure(
+                            "train", "train_step", write=trace_train, step=micro
+                        ):
+                            if use_health:
+                                health_phase = (
+                                    "compile" if not health_compiled
+                                    else "step_compute"
+                                )
+                                with self.ledger.measure(health_phase):
+                                    state, metrics = health_step(state, batch)
+                                if not health_compiled and aot_step is None:
+                                    # no plain-step AOT ran: the health compile
+                                    # IS the run's train-step compile
+                                    self.telemetry.gauge("compile_time_s").set(
+                                        time.perf_counter() - t_step
+                                    )
+                                health_compiled = True
+                                first_compiling = False
+                            else:
+                                try:
+                                    with self.ledger.measure(phase):
+                                        state, metrics = step_fn(state, batch)
+                                except TypeError:
+                                    # the AOT executable is pinned to
+                                    # sample_batch's shapes; pad-to-longest
+                                    # collators emit variable sequence lengths.
+                                    # The mismatch raises BEFORE execution
+                                    # (donated buffers intact), so fall back
+                                    # permanently to the jitted callable, which
+                                    # recompiles per shape like it always did.
+                                    # The retry (jit trace + compile) bills to
+                                    # the compile phase; LATER new-shape
+                                    # recompiles are invisible inside the jit
+                                    # call and land in step_compute — the
+                                    # warning below is the flag that this is
+                                    # happening
+                                    if step_fn is train_step:
+                                        raise
+                                    logger.warning(
+                                        "AOT train step rejected batch shapes at "
+                                        "micro step %d (variable-length batches?); "
+                                        "falling back to jit recompilation", micro,
+                                    )
+                                    step_fn = train_step
+                                    with self.ledger.measure("compile"):
+                                        state, metrics = step_fn(state, batch)
+                            if first_compiling:
                                 self.telemetry.gauge("compile_time_s").set(
                                     time.perf_counter() - t_step
                                 )
-                            health_compiled = True
-                            first_compiling = False
-                        else:
-                            try:
-                                with self.ledger.measure(phase), \
-                                        jax.profiler.TraceAnnotation("train_step"):
-                                    state, metrics = step_fn(state, batch)
-                            except TypeError:
-                                # the AOT executable is pinned to sample_batch's
-                                # shapes; pad-to-longest collators emit variable
-                                # sequence lengths. The mismatch raises BEFORE
-                                # execution (donated buffers intact), so fall back
-                                # permanently to the jitted callable, which
-                                # recompiles per shape like it always did. The
-                                # retry (jit trace + compile) bills to the compile
-                                # phase; LATER new-shape recompiles are invisible
-                                # inside the jit call and land in step_compute —
-                                # the warning below is the flag that this is
-                                # happening
-                                if step_fn is train_step:
-                                    raise
-                                logger.warning(
-                                    "AOT train step rejected batch shapes at "
-                                    "micro step %d (variable-length batches?); "
-                                    "falling back to jit recompilation", micro,
-                                )
-                                step_fn = train_step
-                                with self.ledger.measure("compile"), \
-                                        jax.profiler.TraceAnnotation("train_step"):
-                                    state, metrics = step_fn(state, batch)
-                        if first_compiling:
-                            self.telemetry.gauge("compile_time_s").set(
-                                time.perf_counter() - t_step
-                            )
-                        tracer.span(
-                            "train", "train_step", t_step, time.perf_counter(),
-                            write=trace_train, step=micro,
-                        )
 
                     self._apply_counts(counts)
 
@@ -1253,6 +1257,7 @@ class Trainer:
                         self.last_health = {k: float(v) for k, v in host.items()}
                         for key, value in self.last_health.items():
                             self.telemetry.gauge(key).set(value)
+                    self.live_state = state
                     for cb in self.callbacks:
                         # fires EVERY optimizer step (no metrics, no device sync);
                         # on_step_end below fires only on log steps with host metrics
@@ -1313,6 +1318,7 @@ class Trainer:
                         for cb in self.callbacks:
                             if hasattr(cb, "on_step_end"):
                                 cb.on_step_end(self, step, metrics)
+                    self.live_state = None
 
                     if step == first_process_step:
                         # drop the compile/warmup-laden first step from the next
@@ -1321,7 +1327,6 @@ class Trainer:
 
                     if cfg.val_check_interval and step % cfg.val_check_interval == 0:
                         with self.ledger.measure("validation"), \
-                                jax.profiler.TraceAnnotation("validation"), \
                                 tracer.measure("train", "validation", step=step):
                             self._run_validation(eval_step, state, datamodule, step)
 
@@ -1337,7 +1342,6 @@ class Trainer:
                         and self._loss_finite(metrics, step)
                     ):
                         with self.ledger.measure("checkpoint_save"), \
-                                jax.profiler.TraceAnnotation("checkpoint_save"), \
                                 tracer.measure(
                                     "train", "checkpoint_save", step=step
                                 ):
@@ -1377,6 +1381,8 @@ class Trainer:
                         break
                 return state
             finally:
+                # a callback that raised must not leave the state pinned
+                self.live_state = None
                 if prefetcher is not None:
                     prefetcher.close()
 
@@ -1491,7 +1497,6 @@ class Trainer:
             # label with the step actually reached: an early stop
             # (should_stop) must not masquerade as a completed run
             with self.ledger.measure("checkpoint_save"), \
-                    jax.profiler.TraceAnnotation("checkpoint_save"), \
                     tracer.measure(
                         "train", "checkpoint_save", step=self.last_step
                     ):

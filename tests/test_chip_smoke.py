@@ -57,8 +57,10 @@ def test_serve_requests_are_seeded_and_sized():
 @pytest.fixture
 def restore_cache_dir():
     before = jax.config.jax_compilation_cache_dir
+    keyed_on_metadata = jax.config.jax_compilation_cache_include_metadata_in_key
     yield
     jax.config.update("jax_compilation_cache_dir", before)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", keyed_on_metadata)
 
 
 @pytest.mark.parametrize("from_env", [True, False], ids=["env", "in-checkout"])
@@ -66,11 +68,14 @@ def test_compile_cache_is_placed_from_outside(monkeypatch, tmp_path, restore_cac
     """`_apply_extra_config` — the one place every device-touching command
     passes — keeps the cache where JAX_COMPILATION_CACHE_DIR says and sets
     no other in code; unset, it resolves to the one fixed in-checkout path.
+    Either way entries are keyed on the programs' metadata too, so a cached
+    program never carries an older checkout's scopes into a device profile.
     Pure config assertions: nothing compiles."""
     from llm_training_tpu.cli.main import _apply_extra_config
     from llm_training_tpu.compile_cache import DEFAULT_CACHE_DIR, compile_cache_dir
 
     jax.config.update("jax_compilation_cache_dir", None)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", False)
     if from_env:
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
         _apply_extra_config({})
@@ -83,6 +88,7 @@ def test_compile_cache_is_placed_from_outside(monkeypatch, tmp_path, restore_cac
         assert jax.config.jax_compilation_cache_dir == str(DEFAULT_CACHE_DIR)
         assert DEFAULT_CACHE_DIR == REPO / ".jax_cache"
         assert compile_cache_dir() == str(REPO / ".jax_cache")
+    assert jax.config.jax_compilation_cache_include_metadata_in_key is True
 
 
 def test_compilation_cache_dir_config_key_is_refused():

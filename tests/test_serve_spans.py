@@ -1,0 +1,474 @@
+"""The program reports itself (docs/observability.md#tracing): the tracer's
+optional profiler annotator, the serving engine's step spans and counts on
+the profiler's clock, block scopes in the lowered programs, the program
+names three readers match, and the two hooks the benchmark will use
+(`trainer.live_state`, `ServingEngine.close`)."""
+
+import ast
+import json
+from contextlib import contextmanager
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from llm_training_tpu.analysis.contracts import JAX_FREE_CONTRACTS
+from llm_training_tpu.models import Llama, LlamaConfig
+from llm_training_tpu.serve import ServeConfig, ServingEngine
+from llm_training_tpu.telemetry import get_registry, install_trace_annotator
+from llm_training_tpu.telemetry.registry import TelemetryRegistry, set_registry
+from llm_training_tpu.telemetry.trace import (
+    ANNOTATION_PREFIX,
+    TraceRecorder,
+    read_trace_events,
+    set_tracer,
+)
+
+ROOT = Path(__file__).resolve().parent.parent
+
+TINY = dict(
+    vocab_size=64, hidden_size=32, intermediate_size=64,
+    num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2,
+    max_position_embeddings=64, attention_impl="xla",
+    compute_dtype="float32", param_dtype="float32",
+)
+TINY_MOE = dict(
+    TINY, num_experts=4, num_experts_per_tok=2, moe_intermediate_size=16,
+    moe_impl="ragged",
+)
+SERVE = dict(
+    max_batch=2, max_model_len=48, block_size=8, prefill_chunk=4,
+    eos_token_id=None,
+)
+STEP_CHILDREN = (
+    "housekeeping", "schedule", "prefill_chunk", "decode_blocks",
+    "decode_inputs", "decode_dispatch", "decode_fetch", "decode_emit",
+)
+PROMPTS = [[3, 17, 42, 7, 9, 11], [5, 9, 11], [4, 8, 15, 16, 23]]
+
+
+@pytest.fixture()
+def fresh():
+    """A fresh process tracer and registry, restored afterwards."""
+    tracer = TraceRecorder(capacity=8192, enabled=True)
+    previous_tracer = set_tracer(tracer)
+    previous_registry = set_registry(TelemetryRegistry())
+    try:
+        yield tracer
+    finally:
+        tracer.detach_sink()
+        set_tracer(previous_tracer)
+        set_registry(previous_registry)
+
+
+def _engine(config=TINY, **serve):
+    model = Llama(LlamaConfig(**config))
+    variables = jax.jit(model.init)(jax.random.key(0), np.zeros((1, 4), np.int32))
+    return ServingEngine(model, variables, ServeConfig(**{**SERVE, **serve}))
+
+
+def _requests(n=8):
+    return [
+        {"id": f"r{i}", "prompt": PROMPTS[i % len(PROMPTS)], "max_new_tokens": n}
+        for i in range(len(PROMPTS))
+    ]
+
+
+# ------------------------------------------------------------- annotator
+
+
+def test_measure_without_annotator_yields_late_args():
+    tracer = TraceRecorder(enabled=True)
+    with tracer.measure("serve", "engine_step", step=1) as late:
+        late["decode_rows"] = 2
+    (event,) = tracer.snapshot()
+    assert event["name"] == "engine_step" and event["ph"] == "X"
+    assert event["args"] == {"step": 1, "decode_rows": 2}
+
+
+def test_annotator_sees_prefixed_name_args_and_late_args():
+    seen = []
+
+    class Annotation:
+        def set_metadata(self, **late):
+            seen.append(("late", late))
+
+    @contextmanager
+    def annotator(name, args):
+        seen.append(("open", name, dict(args)))
+        yield Annotation()
+        seen.append(("close", name))
+
+    tracer = TraceRecorder(enabled=True, annotator=annotator)
+    with tracer.measure("serve", "engine_step", step=7) as late:
+        with tracer.measure("serve", "schedule", step=7):
+            pass
+        late["live_tokens"] = 9
+    assert seen == [
+        ("open", "llmt/serve/engine_step", {"step": 7}),
+        ("open", "llmt/serve/schedule", {"step": 7}),
+        ("close", "llmt/serve/schedule"),
+        ("late", {"live_tokens": 9}),
+        ("close", "llmt/serve/engine_step"),
+    ]
+    assert ANNOTATION_PREFIX == "llmt/"
+    # removed again, and a disabled recorder annotates nothing
+    tracer.set_annotator(None)
+    with tracer.measure("serve", "schedule"):
+        pass
+    assert len(seen) == 5
+    off = TraceRecorder(enabled=False, annotator=annotator)
+    with off.measure("serve", "schedule"):
+        pass
+    assert len(seen) == 5 and off.snapshot() == []
+
+
+def test_trace_module_stays_jax_free():
+    """The annotator is how jax reaches the tracer; the module itself and
+    the scheduler still import without it (graftlint holds the contract)."""
+    for module in ("llm_training_tpu/telemetry/trace.py", "llm_training_tpu/serve/scheduler.py"):
+        assert module in JAX_FREE_CONTRACTS
+        tree = ast.parse((ROOT / module).read_text())
+        imported = set()
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Import):
+                imported |= {alias.name.split(".")[0] for alias in node.names}
+            elif isinstance(node, ast.ImportFrom) and node.module:
+                imported.add(node.module.split(".")[0])
+        assert not imported & {"jax", "jaxlib", "flax"}, module
+
+
+# ------------------------------------------------------ the engine's step
+
+
+def _count_from_outside(engine):
+    """The benchmark harness's way (benchmarks/runners/serve_closed.py): wrap
+    the two calls and count, per step, chunks, rows and live tokens."""
+    steps = []
+    run_prefill, run_decode = engine._run_prefill, engine._run_decode
+
+    def counted_prefill(*args):
+        steps[-1]["prefill_chunks"] += 1
+        steps[-1]["prefill_tokens"] += len(args[1])
+        return run_prefill(*args)
+
+    def counted_decode(rows):
+        steps[-1]["decode_rows"] += len(rows)
+        steps[-1]["live_tokens"] += sum(r.cache_len + 1 for r in rows)
+        return run_decode(rows)
+
+    engine._run_prefill, engine._run_decode = counted_prefill, counted_decode
+
+    def step():
+        steps.append(dict.fromkeys(
+            ("prefill_chunks", "prefill_tokens", "decode_rows", "live_tokens"), 0
+        ))
+        return engine.step()
+
+    return steps, step
+
+
+def test_engine_step_spans_nest_and_count_like_the_harness(fresh, tmp_path):
+    assert fresh.attach_sink(tmp_path / "trace.jsonl")
+    engine = _engine()
+    outside, step = _count_from_outside(engine)
+    for request in _requests():
+        engine.submit(**request)
+    while not engine.scheduler.idle:
+        step()
+    ring = [e for e in fresh.snapshot() if e.get("ph") == "X"]
+    parents = [e for e in ring if e["name"] == "engine_step"]
+    assert len(parents) == len(outside) == engine._step_index
+    # step for step: the span's closing args are the outside count
+    for parent, counted in zip(parents, outside):
+        assert {k: parent["args"][k] for k in counted} == counted
+    assert [p["args"]["step"] for p in parents] == list(range(1, len(parents) + 1))
+    # every child lies inside its step, carries its index, and no two overlap
+    children = [e for e in ring if e["name"] in STEP_CHILDREN]
+    assert {e["name"] for e in children} == set(STEP_CHILDREN)
+    by_step = {p["args"]["step"]: p for p in parents}
+    for step_index, parent in by_step.items():
+        mine = sorted(
+            (e for e in children if e["args"]["step"] == step_index),
+            key=lambda e: e["ts"],
+        )
+        assert mine[0]["name"] == "housekeeping"
+        assert mine[0]["ts"] >= parent["ts"]
+        assert mine[-1]["ts"] + mine[-1]["dur"] <= parent["ts"] + parent["dur"] + 1e-9
+        for a, b in zip(mine, mine[1:]):
+            assert a["ts"] + a["dur"] <= b["ts"] + 1e-9, (a["name"], b["name"])
+    # the prefill chunk's own children lie inside it, with the request's id
+    chunks = [e for e in ring if e["name"] == "prefill_chunk"]
+    for name in ("prefill_dispatch", "prefill_fetch"):
+        inner = [e for e in ring if e["name"] == name]
+        assert inner, name
+        for e in inner:
+            chunk = next(
+                c for c in chunks
+                if c["args"]["step"] == e["args"]["step"]
+            )
+            assert e["args"]["request_id"] == chunk["args"]["request_id"]
+            assert chunk["ts"] <= e["ts"]
+            assert e["ts"] + e["dur"] <= chunk["ts"] + chunk["dur"] + 1e-9
+    assert sum(c["args"]["final"] for c in chunks) == len(
+        [e for e in ring if e["name"] == "prefill_fetch"]
+    )
+    # the sink keeps one engine_step a step and a sampled request's chunks:
+    # the step's new children stay in the ring and the profiler
+    fresh.flush()
+    persisted = [e["name"] for e in read_trace_events(tmp_path / "trace.jsonl")]
+    assert persisted.count("engine_step") == len(parents)
+    assert "prefill_chunk" in persisted
+    assert not set(persisted) & (
+        set(STEP_CHILDREN) - {"prefill_chunk"} | {"prefill_dispatch", "prefill_fetch"}
+    )
+    # one bookkeeping, two readers: the registry's counters are the sums
+    registry = get_registry()
+    decode = [c for c in outside if c["decode_rows"]]
+    assert registry.counter("serve/steps").value == len(outside)
+    assert registry.counter("serve/prefill_chunks").value == sum(
+        c["prefill_chunks"] for c in outside
+    )
+    assert registry.counter("serve/decode_steps").value == len(decode)
+    assert registry.counter("serve/decode_rows").value == sum(
+        c["decode_rows"] for c in decode
+    )
+    assert registry.counter("serve/live_tokens").value == sum(
+        c["live_tokens"] for c in decode
+    )
+    # a token comes from a decoding row or from a prompt's last chunk
+    assert engine.tokens_generated == sum(c["decode_rows"] for c in decode) + sum(
+        c["args"]["final"] for c in chunks
+    )
+
+
+def test_profiler_capture_holds_the_engines_spans(fresh, tmp_path):
+    """Under jax.profiler the same spans land in the profiler's host plane,
+    `llmt/`-prefixed, with their args; benchmarks/span_reduce.py reads them
+    back and its counts are the ring's."""
+    from benchmarks import span_reduce
+
+    engine = _engine()
+    for request in _requests(4):
+        engine.submit(**request)
+    engine.step()  # compile outside the capture
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        first = engine._step_index + 1
+        while not engine.scheduler.idle:
+            engine.step()
+    finally:
+        jax.profiler.stop_trace()
+    (xplane,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    trace = span_reduce.load(xplane)
+    ring = [
+        e for e in fresh.snapshot()
+        if e.get("ph") == "X" and e["name"] == "engine_step"
+        and e["args"]["step"] >= first
+    ]
+    steps = span_reduce.spans_named(trace, "serve/engine_step")
+    assert len(steps) == len(ring) > 2
+    for span, event in zip(steps, ring):
+        assert span["args"] == event["args"]
+        assert span["dur"] == pytest.approx(event["dur"] * 1e9, rel=0.2, abs=2e5)
+    counts = span_reduce.step_counts(trace)
+    assert counts["steps"] == len(ring)
+    assert counts["decode_rows"] == sum(e["args"]["decode_rows"] for e in ring)
+    assert counts["live_tokens"] == sum(e["args"]["live_tokens"] for e in ring)
+    assert counts["prefill_steps"] == sum(e["args"]["prefill_chunks"] for e in ring)
+    names = {s["name"] for s in trace["spans"]}
+    assert {f"serve/{n}" for n in STEP_CHILDREN} <= names
+    # self time: a step less what its children cover is what no child names
+    for span in steps:
+        assert 0 <= span_reduce.self_ns(trace, span) <= span["dur"]
+
+
+# ------------------------------------------------- scopes and program names
+
+
+def _decode_args(engine):
+    batch = engine.config.max_batch
+    return (
+        engine.variables, jnp.zeros((batch,), jnp.int32), engine._pool_k,
+        engine._pool_v, jnp.zeros((batch, engine.pages_per_request), jnp.int32),
+        jnp.zeros((batch,), jnp.int32), jax.random.key(0),
+    )
+
+
+def _prefill_args(engine):
+    width = engine.config.prefill_chunk
+    row = jnp.zeros((1, width), jnp.int32)
+    return (
+        engine.variables, row, row, row, engine._pool_k, engine._pool_v,
+        jnp.zeros((1, engine.pages_per_request), jnp.int32),
+        jnp.zeros((1,), jnp.int32), jnp.int32(0), jax.random.key(0),
+    )
+
+
+def test_lowered_programs_carry_block_scopes_and_their_names(fresh):
+    engine = _engine(TINY_MOE)
+    decode = engine._decode_jit.lower(*_decode_args(engine))
+    prefill = engine._prefill_jit.lower(*_prefill_args(engine))
+    # three readers match these (docs, chip_smoke.py, the benchmark's trace
+    # readers): the jitted functions' names are the programs' names
+    assert "jit_decode_step" in decode.as_text()[:200]
+    assert "jit_prefill_chunk" in prefill.as_text()[:200]
+    for lowered in (decode, prefill):
+        text = lowered.as_text(debug_info=True)
+        for scope in (
+            "moe_route", "moe_sort", "moe_gather", "moe_experts", "moe_scatter",
+            "self_attn", "mlp", "sample",
+        ):
+            assert f"/{scope}" in text, scope
+    # the MoE phases sit inside the block's own module scope
+    assert "mlp/moe_sort" in decode.as_text(debug_info=True)
+
+
+def test_lowered_train_loss_carries_loss_ce():
+    from llm_training_tpu.ops.cross_entropy import fused_linear_cross_entropy
+
+    def loss(hidden, weight, labels):
+        total, count = fused_linear_cross_entropy(hidden, weight, labels, chunk_size=4)
+        return total / count
+
+    lowered = jax.jit(jax.grad(loss)).lower(
+        jnp.ones((8, 16)), jnp.ones((16, 32)), jnp.zeros((8,), jnp.int32)
+    )
+    text = lowered.as_text(debug_info=True)
+    assert "loss_ce" in text
+    # forward and backward alike: the transposed ops keep the scope
+    assert any(
+        "transpose" in line and "loss_ce" in line for line in text.splitlines()
+    )
+
+
+# ------------------------------------------------------------ the trainer
+
+
+def _fit(tmp_path, callbacks=(), max_steps=3):
+    from llm_training_tpu.callbacks.loggers import JsonlLogger, JsonlLoggerConfig
+    from llm_training_tpu.data import DummyDataModule, DummyDataModuleConfig
+    from llm_training_tpu.lms import CLM, CLMConfig
+    from llm_training_tpu.lms.base import ModelProvider
+    from llm_training_tpu.parallel import MeshConfig
+    from llm_training_tpu.trainer import Trainer, TrainerConfig
+
+    objective = CLM(CLMConfig(model=ModelProvider(
+        model_class="Llama",
+        model_kwargs=dict(TINY, vocab_size=128, num_hidden_layers=1),
+    )))
+    datamodule = DummyDataModule(DummyDataModuleConfig(
+        batch_size=8, max_length=16, num_samples=64, vocab_size=128,
+    ))
+    jsonl = JsonlLogger(JsonlLoggerConfig(save_dir=str(tmp_path), name="spans"))
+    trainer = Trainer(
+        TrainerConfig(max_steps=max_steps, log_every_n_steps=2, mesh=MeshConfig()),
+        callbacks=[jsonl, *callbacks],
+    )
+    trainer.fit(objective, datamodule)
+    return trainer, jsonl.run_dir
+
+
+def test_trainer_step_spans_reach_the_sink_and_the_annotator(tmp_path, monkeypatch):
+    monkeypatch.setenv("LLMT_TRACE_TRAIN", "1")
+    annotated = []
+
+    @contextmanager
+    def annotator(name, args):
+        annotated.append(name)
+        yield None
+
+    tracer = TraceRecorder(enabled=True)
+    previous = set_tracer(tracer)
+    installs = []
+    monkeypatch.setattr(
+        "llm_training_tpu.trainer.trainer.install_trace_annotator",
+        lambda t: (installs.append(t), t.set_annotator(annotator)),
+    )
+    try:
+        _, run_dir = _fit(tmp_path)
+    finally:
+        tracer.detach_sink()
+        set_tracer(previous)
+    assert installs == [tracer]  # fit installs the profiler side on the process tracer
+    events = read_trace_events(run_dir / "trace.jsonl")
+    spans = [e for e in events if e.get("ph") == "X" and e["cat"] == "train"]
+    for name in ("data_load", "train_step"):
+        mine = [e for e in spans if e["name"] == name]
+        assert [e["args"]["step"] for e in mine] == [0, 1, 2], name
+    assert any(e["name"] == "compile" for e in spans)
+    # one measure each: every span has its annotation, under the prefix
+    assert annotated.count("llmt/train/data_load") == 3
+    assert annotated.count("llmt/train/train_step") == 3
+    assert "llmt/train/compile" in annotated
+    # a step's data_load ends before its train_step begins
+    for step in range(3):
+        load = next(e for e in spans if e["name"] == "data_load" and e["args"]["step"] == step)
+        compute = next(e for e in spans if e["name"] == "train_step" and e["args"]["step"] == step)
+        assert load["ts"] + load["dur"] <= compute["ts"] + 1e-9
+
+
+def test_install_trace_annotator_opens_profiler_annotations():
+    tracer = TraceRecorder(enabled=True)
+    install_trace_annotator(tracer)
+    with tracer.measure("train", "train_step", step=0) as late:
+        late["tokens"] = 4  # no capture open: a flag test, and no error
+    assert tracer.snapshot()[0]["args"] == {"step": 0, "tokens": 4}
+
+
+def test_live_state_is_the_loops_state_inside_the_hooks_only(tmp_path):
+    seen = []
+
+    class Probe:
+        def on_train_step(self, trainer, step):
+            seen.append(("train_step", step, int(trainer.live_state.step)))
+
+        def on_step_end(self, trainer, step, metrics):
+            seen.append(("step_end", step, int(trainer.live_state.step)))
+
+    trainer, _ = _fit(tmp_path, callbacks=[Probe()])
+    assert trainer.live_state is None
+    assert [s for s in seen if s[0] == "train_step"] == [
+        ("train_step", 1, 1), ("train_step", 2, 2), ("train_step", 3, 3),
+    ]
+    assert [s for s in seen if s[0] == "step_end"] == [("step_end", 2, 2), ("step_end", 3, 3)]
+
+
+def test_live_state_is_cleared_when_a_hook_raises(tmp_path):
+    class Boom:
+        def on_train_step(self, trainer, step):
+            assert trainer.live_state is not None
+            raise RuntimeError("boom")
+
+    from llm_training_tpu.trainer import Trainer
+
+    held = {}
+    original = Trainer._fit_inner
+
+    def keep(self, *args, **kwargs):
+        held["trainer"] = self
+        return original(self, *args, **kwargs)
+
+    Trainer._fit_inner = keep
+    try:
+        with pytest.raises(RuntimeError, match="boom"):
+            _fit(tmp_path, callbacks=[Boom()])
+    finally:
+        Trainer._fit_inner = original
+    assert held["trainer"].live_state is None
+
+
+def test_engine_close_frees_the_pool_and_keeps_stats(fresh):
+    engine = _engine()
+    engine.run(_requests(3))
+    pool_k, pool_v = engine._pool_k, engine._pool_v
+    cache_bytes = engine.stats()["decode/cache_bytes"]
+    assert cache_bytes == pool_k.size * pool_k.dtype.itemsize * 2
+    engine.close()
+    assert pool_k.is_deleted() and pool_v.is_deleted()
+    assert engine._pool_k is None and engine._pool_v is None
+    engine.close()  # idempotent
+    assert engine.stats()["decode/cache_bytes"] == cache_bytes
+    assert json.dumps(engine.stats())  # still a plain record
