@@ -8,23 +8,33 @@ successor to the dense `DecodeState` decode path's XLA einsum attention
 (`models/llama/model.py:_cached_attention`), whose whole-cache attention
 bills every row for the longest row's capacity.
 
-Design (one page per kv grid step, flash-style online softmax):
+Design (one grid step per row, hand-made page fetches, flash-style online
+softmax):
 
-  grid (batch, kv_heads, max_pages_per_request), pages innermost
-  ("arbitrary"); the block table and per-row lengths ride as SCALAR
-  PREFETCH operands, so each page's BlockSpec index map resolves the
-  PHYSICAL pool block to stream — the gather happens in the DMA engine,
-  not in compute. Pages past a row's length clamp onto the last valid
-  page (the already-resident block), so Pallas elides their DMA and
-  `pl.when` skips their compute: a row at length L costs ceil(L/page)
-  page visits regardless of the pool size or its neighbours' lengths.
+  grid (batch,); the block table and per-row lengths ride as SCALAR
+  PREFETCH operands and the K/V pools stay where they are
+  (`memory_space=pl.ANY`). The pool is `[blocks, kv_heads, page,
+  head_dim]`, so one physical block `pool[tables[b, p]]` is a single
+  contiguous `[kv_heads, page, head_dim]` region: one async copy brings a
+  page for EVERY kv head the call holds. A row walks its live pages only,
+  `n` consecutive logical pages a trip, in a loop whose trip count comes
+  from `lens[b]`; a trip's copies land in one slot of a double-buffered
+  `[2, kv_heads, n*page, head_dim]` VMEM scratch and are started while the
+  previous trip's slot is being reduced. Every copy that is started is
+  waited for; pages past the row's last live one are never fetched (their
+  V rows in the slot are zeroed, their scores masked), and pages wholly in
+  front of a sliding window are skipped. The online softmax runs in
+  float32 over a `[kv_heads, group, n*page]` score tile, a product
+  batched over kv heads. A row at length L costs ceil(L/page) page copies
+  and ceil(L/(n*page)) trips regardless of the pool size, the table's
+  width or its neighbours' lengths.
 
-The page size IS this kernel's kv tile (the [group, page_size] score tile
-per q-head group), registered with `ops/pallas/tuning.py` under
-kind="paged". The pool is `[blocks, kv_heads, page, head_dim]`: one kv
-head's page is the (page, head_dim) trailing tile Mosaic requires of a
-block — page in sublanes (8-aligned), head_dim in lanes (128-aligned) —
-so a kv head is selected by the BlockSpec, never by a slice in the kernel.
+`n` follows the shapes the call sees (`pages_per_trip`): as many pages as
+keep the double-buffered K and V scratch inside `_KV_SCRATCH_BYTES`, at
+most the table's width. The page size stays the pool's unit (kind="paged"
+in `ops/pallas/tuning.py`), not this kernel's tile. On a TPU a page must
+be whole (8, 128) tiles — page in sublanes, head_dim in lanes — so that a
+copy lands tile-aligned in the slot.
 Off-TPU the kernel runs interpreted (tier-1 tests), following the
 `flash_attention.py` pattern; on a TPU it always compiles, and a shape
 Mosaic cannot tile raises here instead of being routed elsewhere. The XLA
@@ -46,73 +56,139 @@ from llm_training_tpu.ops.pallas import resolve_interpret
 _MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 _LANES = 128
 _SUBLANES = 8
+# VMEM the double-buffered K and V page scratch may take (2 slots x K and V);
+# pages a trip follow from it and from the shapes of the call
+_KV_SCRATCH_BYTES = 2 * 1024 * 1024
+
+
+def pages_per_trip(
+    num_kv_heads: int, page_size: int, head_dim: int, itemsize: int,
+    num_pages: int,
+) -> int:
+    """How many consecutive logical pages of a row one trip fetches: as many
+    as keep `[2 slots] x [K, V] x [kv_heads, n*page, head_dim]` inside
+    `_KV_SCRATCH_BYTES`, at least 1, at most the table's width."""
+    page_bytes = num_kv_heads * page_size * head_dim * itemsize
+    return max(1, min(_KV_SCRATCH_BYTES // (4 * page_bytes), num_pages))
 
 
 def _decode_kernel(
     tables,  # scalar prefetch: [B, P] physical block per (row, logical page)
     lens,    # scalar prefetch: [B] tokens already written (incl. this one)
-    q_ref,   # [1, 1, G, D] this row's q for one kv head's group
-    k_ref,   # [1, 1, page, D] one pool page for this kv head
-    v_ref,   # [1, 1, page, D]
-    o_ref,   # [1, 1, G, D]
-    m_ref,   # VMEM [G, lanes] running row max
-    l_ref,   # VMEM [G, lanes] running denominator
-    acc_ref,  # VMEM [G, D] running numerator
+    q_ref,   # [1, Hkv, G, D] this row's q, kv-head major
+    k_hbm,   # [N, Hkv, page, D] the whole K pool, left in place
+    v_hbm,   # [N, Hkv, page, D]
+    o_ref,   # [1, Hkv, G, D]
+    k_buf,   # VMEM [2, Hkv, n*page, D] double-buffered trip of K pages
+    v_buf,   # VMEM [2, Hkv, n*page, D]
+    sems,    # DMA semaphores [2 (K, V), 2 slots]
     *,
     page_size: int,
+    trip_pages: int,
     scale: float,
     sliding_window: int | None,
     logits_soft_cap: float | None,
-    num_pages: int,
 ):
-    b, j = pl.program_id(0), pl.program_id(2)
+    b = pl.program_id(0)
+    num_kv_heads, group, head_dim = q_ref.shape[1:]
+    trip_tokens = trip_pages * page_size
     # q position of the decoded token == its (0-based) cache slot; the
     # caller appends k/v BEFORE attention, so valid kv slots are 0..q_pos
     q_pos = lens[b] - 1
+    live_pages = pl.cdiv(lens[b], page_size)
+    # pages wholly in front of the window hold nothing this row can see
+    first_page = (
+        0 if sliding_window is None
+        else jnp.maximum(q_pos - sliding_window + 1, 0) // page_size
+    )
+    trips = pl.cdiv(jnp.maximum(live_pages - first_page, 0), trip_pages)
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def trip_span(trip):
+        """(first logical page, live pages) of a trip."""
+        start = first_page + trip * trip_pages
+        return start, jnp.minimum(trip_pages, live_pages - start)
 
-    # pages whose first slot is past q_pos hold nothing this row can see
-    @pl.when(j * page_size <= q_pos)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)   # [G, D]
-        k = k_ref[0, 0].astype(jnp.float32)   # [page, D]
-        s = lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # [G, page]
+    def page_rows(i):
+        """Page i of a trip, as rows of a slot."""
+        return pl.ds(pl.multiple_of(i * page_size, page_size), page_size)
+
+    def for_live_pages(trip, slot, act):
+        """`act` on the K and the V copy of each live page of a trip."""
+        start, live = trip_span(trip)
+
+        def one(i, _):
+            block = tables[b, start + i]
+            for which, (hbm, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+                act(pltpu.make_async_copy(
+                    hbm.at[block], buf.at[slot, :, page_rows(i), :],
+                    sems.at[which, slot],
+                ))
+
+        lax.fori_loop(0, live, one, None)
+
+    for_live_pages(0, 0, lambda copy: copy.start())
+
+    q = q_ref[0]                                   # [Hkv, G, D]
+    # bf16 q against a bf16 pool feeds the matrix unit as is: products of
+    # bf16 values are exact in its float32 accumulator
+    qk_dtype = q.dtype if q.dtype == k_buf.dtype else jnp.float32
+    q = q.astype(qk_dtype)
+
+    def trip_body(trip, carry):
+        m_prev, l_prev, acc = carry
+        slot = trip % 2
+
+        @pl.when(trip + 1 < trips)
+        def _next_fetch():
+            for_live_pages(trip + 1, 1 - slot, lambda copy: copy.start())
+
+        for_live_pages(trip, slot, lambda copy: copy.wait())
+        start, live = trip_span(trip)
+
+        # the slot's pages past the row's last hold an earlier trip's rows
+        # (or nothing yet): masked out of the scores below, zeroed in V so
+        # that 0 * whatever-was-there stays 0
+        def zero_page(i, _):
+            v_buf[slot, :, page_rows(i), :] = jnp.zeros(
+                (num_kv_heads, page_size, head_dim), v_buf.dtype
+            )
+
+        lax.fori_loop(live, trip_pages, zero_page, None)
+
+        k = k_buf[slot].astype(qk_dtype)           # [Hkv, T, D]
+        s = jnp.einsum(
+            "hgd,htd->hgt", q, k, preferred_element_type=jnp.float32
+        ) * scale                                  # [Hkv, G, T]
         if logits_soft_cap is not None:
             s = logits_soft_cap * jnp.tanh(s / logits_soft_cap)
-        kv_pos = j * page_size + lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1
+        kv_pos = start * page_size + lax.broadcasted_iota(
+            jnp.int32, (1, 1, trip_tokens), 2
         )
         mask = kv_pos <= q_pos
         if sliding_window is not None:
             mask &= (q_pos - kv_pos) < sliding_window
         s = jnp.where(mask, s, _MASK_VALUE)
-        m_prev = m_ref[:, :1]                       # [G, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)  # [G, page]
-        v = v_ref[0, 0].astype(jnp.float32)           # [page, D]
-        acc_ref[...] = acc_ref[...] * alpha + lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)  # [Hkv, G, T]
+        v = v_buf[slot].astype(jnp.float32)           # [Hkv, T, D]
+        acc = acc * alpha + jnp.einsum(
+            "hgt,htd->hgd", p, v, preferred_element_type=jnp.float32
         )
-        l_new = l_ref[:, :1] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        l_new = l_prev * alpha + jnp.sum(p, axis=2, keepdims=True)
+        return m_new, l_new, acc
 
-    @pl.when(j == num_pages - 1)
-    def _finish():
-        l = l_ref[:, :1]
-        # a fully-masked row (a sliding window that excludes everything)
-        # emits exactly 0 — the _xla_attention invariant
-        o_ref[0, 0] = (
-            acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
-        ).astype(o_ref.dtype)
+    _, l, acc = lax.fori_loop(
+        0, trips, trip_body,
+        (
+            jnp.full((num_kv_heads, group, 1), -jnp.inf, jnp.float32),
+            jnp.zeros((num_kv_heads, group, 1), jnp.float32),
+            jnp.zeros((num_kv_heads, group, head_dim), jnp.float32),
+        ),
+    )
+    # a fully-masked row (a sliding window that excludes everything)
+    # emits exactly 0 — the _xla_attention invariant
+    o_ref[0] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
 def paged_decode_attention(
@@ -156,54 +232,50 @@ def paged_decode_attention(
             f"{head_dim}. Serve this model with attention impl 'xla', or "
             "off-TPU where the kernel is interpreted"
         )
+    trip_pages = pages_per_trip(
+        num_kv_heads, page_size, head_dim, k_pages.dtype.itemsize, num_pages
+    )
 
     # q heads are kv-major (head h*G+g serves kv head h) — the same layout
     # _xla_attention's GQA reshape uses
     qg = q.reshape(batch, num_kv_heads, group, head_dim)
     tables = block_tables.astype(jnp.int32)
     lens = lengths.astype(jnp.int32)
-
-    def page_idx(b, h, j, tables, lens):
-        # pages past the row's last valid page repeat the last valid one:
-        # their DMA is elided and their compute is pl.when-skipped
-        jc = jnp.minimum(j, jnp.maximum(lens[b] - 1, 0) // page_size)
-        return (tables[b, jc], h, 0, 0)
+    row_block = pl.BlockSpec(
+        (1, num_kv_heads, group, head_dim),
+        lambda b, tables, lens: (b, 0, 0, 0),
+    )
+    kv_slots = pltpu.VMEM(
+        (2, num_kv_heads, trip_pages * page_size, head_dim), k_pages.dtype
+    )
 
     out = pl.pallas_call(
         functools.partial(
             _decode_kernel,
             page_size=page_size,
+            trip_pages=trip_pages,
             scale=scale,
             sliding_window=sliding_window,
             logits_soft_cap=logits_soft_cap,
-            num_pages=num_pages,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(batch, num_kv_heads, num_pages),
+            grid=(batch,),
             in_specs=[
-                pl.BlockSpec(
-                    (1, 1, group, head_dim),
-                    lambda b, h, j, tables, lens: (b, h, 0, 0),
-                ),
-                pl.BlockSpec((1, 1, page_size, head_dim), page_idx),
-                pl.BlockSpec((1, 1, page_size, head_dim), page_idx),
+                row_block,
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec(
-                (1, 1, group, head_dim),
-                lambda b, h, j, tables, lens: (b, h, 0, 0),
-            ),
+            out_specs=row_block,
             scratch_shapes=[
-                pltpu.VMEM((group, _LANES), jnp.float32),
-                pltpu.VMEM((group, _LANES), jnp.float32),
-                pltpu.VMEM((group, head_dim), jnp.float32),
+                kv_slots, kv_slots, pltpu.SemaphoreType.DMA((2, 2)),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct(
             (batch, num_kv_heads, group, head_dim), q.dtype
         ),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel",),
         ),
         interpret=interpret,
         name="paged_decode",

@@ -49,14 +49,15 @@ _SUBLANES = 8
 ENV_FWD = {"block_q": "FLASH_BLOCK_Q", "block_k": "FLASH_BLOCK_K"}
 ENV_BWD = {"block_q": "FLASH_BLOCK_Q_BWD", "block_k": "FLASH_BLOCK_K_BWD"}
 # the ragged paged-decode kernel (ops/pallas/paged_attention.py): block_k is
-# the KV-pool page size — one page IS the kernel's kv tile, so page size is
-# this kernel family's tile knob; block_q is reserved (decode q_len == 1)
+# the KV-pool page size — the pool's unit and what one async copy of the
+# kernel brings (how many pages it reduces at a time follows from the call's
+# shapes, not from here); block_q is reserved (decode q_len == 1)
 ENV_PAGED = {"block_q": "PAGED_BLOCK_Q", "block_k": "PAGED_BLOCK_K"}
 ENV_TABLE = "FLASH_TUNING_TABLE"
 
-# the paged kernel's page axis sits in the SUBLANE dimension of its
-# [group, page] score tile (lanes carry head_dim), so its knobs align to 8,
-# not 128 — and serving pools want small pages (16-64 tokens) anyway
+# a page sits in the SUBLANE dimension of the paged kernel's K/V scratch
+# (lanes carry head_dim), so its knobs align to 8, not 128 — and serving
+# pools want small pages (16-64 tokens) anyway
 _KIND_ALIGN = {"fwd": _LANES, "bwd": _LANES, "paged": _SUBLANES}
 _KIND_DEFAULT = {
     "fwd": (DEFAULT_BLOCK, DEFAULT_BLOCK),
@@ -264,7 +265,7 @@ def resolve_block_sizes(
     default. The reported `source` is the most specific origin that
     contributed either knob (call > env > table > default). `kind="paged"`
     resolves the ragged paged-decode kernel's knobs: block_k is the KV-pool
-    page size (the kernel's kv tile), sublane-aligned (8) instead of
+    page size (the unit the kernel fetches), sublane-aligned (8) instead of
     lane-aligned; block_q is reserved (decode q_len == 1).
     """
     if kind not in ("fwd", "bwd", "paged"):
@@ -351,8 +352,8 @@ def resolve_paged_block_size(
     dtype,
     block_size: int | None = None,
 ) -> BlockChoice:
-    """Resolve the serving pool's KV block (page) size — the paged-decode
-    kernel's tile knob (`block_k` of the "paged" kind): explicit config >
+    """Resolve the serving pool's KV block (page) size — the unit the
+    paged-decode kernel fetches (`block_k` of the "paged" kind): explicit config >
     PAGED_BLOCK_K env > tuning table > 16. Recorded into telemetry like
     every other kernel tile resolution."""
     choice = resolve_block_sizes(

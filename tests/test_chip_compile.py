@@ -101,27 +101,44 @@ def test_flash_backward_compiles_for_v5e(v5e, as_on_tpu, kernel, outputs):
     assert found == {kernel: 1}, found
 
 
-@pytest.mark.parametrize("page_size", [16, 128])
-def test_paged_decode_compiles_for_v5e(v5e, page_size):
-    """The serving decode kernel at head_dim 128 / GQA group 4, at the page
-    size `resolve_paged_block_size` picks (16) and one other. The seed's
+@pytest.mark.parametrize("q_heads,kv_heads,page_size,batch,blocks,max_len", [
+    pytest.param(Q_HEADS, KV_HEADS, 16, 4, 512, 2048, id="llama8b-page16"),
+    pytest.param(Q_HEADS, KV_HEADS, 128, 4, 512, 2048, id="llama8b-page128"),
+    # the two serve cells of BENCHMARK.json as the kernel sees them: 32 rows,
+    # a table of 96 pages over a pool of 3,073 blocks; 10 kv heads in groups
+    # of 4 (not a power of two) and 16 heads of their own
+    pytest.param(40, 10, 16, 32, 3073, 1536, id="phi3-medium-cell"),
+    pytest.param(16, 16, 16, 32, 3073, 1536, id="olmoe-cell"),
+])
+def test_paged_decode_compiles_for_v5e(
+    v5e, q_heads, kv_heads, page_size, batch, blocks, max_len
+):
+    """The serving decode kernel at head_dim 128 in bf16, at the page size
+    `resolve_paged_block_size` picks (16) and one other. The seed's
     `[blocks, page, kv_heads, head_dim]` pool was refused here: a
-    (1, page, 1, head_dim) block is not an (8, 128) tile of it."""
+    (1, page, 1, head_dim) block is not an (8, 128) tile of it. The pools go
+    to the kernel in place, so the program may produce no copy of one."""
     one = SingleDeviceSharding(v5e.devices[0])
-    batch, blocks, pages = 4, 512, 2048 // page_size
     pool = jax.ShapeDtypeStruct(
-        (blocks, KV_HEADS, page_size, HEAD_DIM), jnp.bfloat16, sharding=one
+        (blocks, kv_heads, page_size, HEAD_DIM), jnp.bfloat16, sharding=one
     )
-    found = _kernels(
+    text = jax.jit(
         lambda q, k, v, tables, lens: paged_decode_attention(
             q, k, v, tables, lens, interpret=False
-        ),
-        jax.ShapeDtypeStruct((batch, Q_HEADS, HEAD_DIM), jnp.bfloat16, sharding=one),
+        )
+    ).lower(
+        jax.ShapeDtypeStruct((batch, q_heads, HEAD_DIM), jnp.bfloat16, sharding=one),
         pool, pool,
-        jax.ShapeDtypeStruct((batch, pages), jnp.int32, sharding=one),
+        jax.ShapeDtypeStruct((batch, max_len // page_size), jnp.int32, sharding=one),
         jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=one),
-    )
-    assert found == {"paged_decode": 1}, found
+    ).compile().as_text()
+    assert parse_hlo_kernels(text) == {"paged_decode": 1}
+    pool_shape = f"= bf16[{blocks},{kv_heads},{page_size},{HEAD_DIM}]"
+    produced = [
+        line for line in text.splitlines()
+        if pool_shape in line and " parameter(" not in line
+    ]
+    assert not produced, produced
 
 
 def test_flash_compiles_on_a_sharded_mesh_for_v5e(v5e, as_on_tpu):

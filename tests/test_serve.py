@@ -208,33 +208,116 @@ def test_paged_append_pads_go_to_trash():
     assert float(jnp.sum(new_k[TRASH_BLOCK])) > 0
 
 
-@pytest.mark.parametrize("window,cap,group", [
-    (None, None, 2), (5, None, 2), (None, 4.0, 1), (5, 4.0, 4),
+# One geometry of `test_paged_kernel_matches_gather_fallback`; a case names
+# what it changes. `lengths` are the rows' token counts BEFORE this step's
+# append (the kernel sees one more); `trip` forces that many pages a trip
+# (None: what the shapes give, here the whole table); `idle` rows get a trash
+# table.
+_KERNEL_CASE = dict(
+    lengths=(0, 7, 20), window=None, cap=None, group=2, kv_heads=2, pages=3,
+    trip=None, idle=(), page=8, head_dim=8, dtype="float32",
+)
+
+
+@pytest.mark.parametrize("case", [
+    pytest.param({}, id="plain"),
+    pytest.param(dict(window=5), id="window"),
+    pytest.param(dict(cap=4.0, group=1), id="cap-group1"),
+    pytest.param(dict(window=5, cap=4.0, group=4), id="window-cap-group4"),
+    # rows of 1, 1, 3 and 6 live pages at 2 pages a trip: 1, 1, 2 and 3 trips
+    pytest.param(dict(lengths=(0, 7, 20, 40), pages=8, trip=2), id="several-trips"),
+    # 32 tokens end on the second trip's last slot, 33 open a third, 64 fill
+    # the table, 16 end on a page's
+    pytest.param(dict(lengths=(31, 32, 63, 15), pages=8, trip=2),
+                 id="trip-boundary-and-full-table"),
+    pytest.param(dict(lengths=(47, 0, 30), pages=6, trip=3, idle=(1,)),
+                 id="idle-row-beside-long-rows"),
+    pytest.param(dict(lengths=(3, 9, 12), pages=12, trip=4),
+                 id="table-wider-than-any-row"),
+    # the window opens past the first trips: their pages are never fetched
+    pytest.param(dict(lengths=(60, 41, 5), window=12, cap=4.0, pages=8, trip=2),
+                 id="window-skips-leading-trips"),
+    pytest.param(dict(lengths=(0, 21, 37), kv_heads=10, group=4, pages=5, trip=2),
+                 id="10-kv-heads-group4"),
+    pytest.param(dict(lengths=(0, 21, 37), kv_heads=1, group=4, pages=5, trip=2),
+                 id="1-kv-head-group4"),
+    pytest.param(dict(lengths=(0, 21, 37), kv_heads=1, group=1, pages=5, trip=3),
+                 id="1-kv-head-group1"),
+    # the cells' tile (page 16, head_dim 128, bf16 pool) at Phi-3's heads,
+    # pages a trip from the shapes (the whole table of 6), against the
+    # gather path computed in float32
+    pytest.param(dict(lengths=(0, 50, 95), kv_heads=10, group=4, pages=6,
+                      page=16, head_dim=128, dtype="bfloat16"),
+                 id="bf16-page16-dim128"),
 ])
-def test_paged_kernel_matches_gather_fallback(window, cap, group):
+def test_paged_kernel_matches_gather_fallback(case, monkeypatch):
     """The interpreted Pallas kernel and the XLA gather path must agree on
-    ragged single-token decode — GQA groups, sliding windows, soft cap."""
+    ragged single-token decode — GQA groups, sliding windows, soft cap, and
+    every way a row's live pages can fall across the kernel's trips."""
+    from llm_training_tpu.ops.pallas import paged_attention as kernel
     from llm_training_tpu.ops.paged_attention import paged_cached_attention
 
-    batch, kv_heads, head_dim, page, pages = 3, 2, 8, 8, 3
+    case = {**_KERNEL_CASE, **case}
+    kv_heads, group, head_dim = case["kv_heads"], case["group"], case["head_dim"]
+    page, pages, dtype = case["page"], case["pages"], jnp.dtype(case["dtype"])
+    lengths = jnp.asarray(case["lengths"], jnp.int32)
+    batch = len(case["lengths"])
+    if case["trip"] is not None:
+        page_bytes = kv_heads * page * head_dim * dtype.itemsize
+        monkeypatch.setattr(kernel, "_KV_SCRATCH_BYTES", 4 * page_bytes * case["trip"])
+        assert kernel.pages_per_trip(
+            kv_heads, page, head_dim, dtype.itemsize, pages
+        ) == case["trip"]
     keys = jax.random.split(jax.random.key(0), 4)
     pool_shape = (1 + batch * pages, kv_heads, page, head_dim)
-    pool_k = jax.random.normal(keys[0], pool_shape)
-    pool_v = jax.random.normal(keys[1], pool_shape)
-    q = jax.random.normal(keys[2], (batch, 1, kv_heads * group, head_dim))
-    k = jax.random.normal(keys[3], (batch, 1, kv_heads, head_dim))
-    v = jax.random.normal(keys[3], (batch, 1, kv_heads, head_dim)) + 1.0
+    pool_k = jax.random.normal(keys[0], pool_shape).astype(dtype)
+    pool_v = jax.random.normal(keys[1], pool_shape).astype(dtype)
+    q = jax.random.normal(keys[2], (batch, 1, kv_heads * group, head_dim)).astype(dtype)
+    k = jax.random.normal(keys[3], (batch, 1, kv_heads, head_dim)).astype(dtype)
+    v = (jax.random.normal(keys[3], (batch, 1, kv_heads, head_dim)) + 1.0).astype(dtype)
     tables = jnp.arange(1, 1 + batch * pages, dtype=jnp.int32).reshape(batch, pages)
-    lengths = jnp.asarray([0, 7, 20], jnp.int32)  # ragged: page starts/middles
-    outs = {}
-    for impl in ("pallas", "xla"):
-        outs[impl], _ = paged_cached_attention(
+    for row in case["idle"]:
+        tables = tables.at[row].set(TRASH_BLOCK)
+
+    def attend(impl, *operands):
+        q, k, v, pool_k, pool_v = operands
+        return paged_cached_attention(
             q, k, v, (pool_k, pool_v), lengths, tables,
-            sliding_window=window, logits_soft_cap=cap, impl=impl,
-        )
+            sliding_window=case["window"], logits_soft_cap=case["cap"], impl=impl,
+        )[0]
+
+    operands = (q, k, v, pool_k, pool_v)
+    got = attend("pallas", *operands)
+    ref = attend("xla", *(x.astype(jnp.float32) for x in operands))
+    assert got.dtype == dtype
+    tol = 2e-5 if dtype == jnp.float32 else 1e-2  # the bf16 output's own rounding
     np.testing.assert_allclose(
-        np.asarray(outs["pallas"]), np.asarray(outs["xla"]), rtol=2e-5, atol=2e-5
+        np.asarray(got, np.float32), np.asarray(ref), rtol=tol, atol=tol
     )
+
+
+@pytest.mark.parametrize("kv_heads,page,head_dim,itemsize,table,expect", [
+    (10, 16, 128, 2, 96, 12),   # Phi-3-medium's cache
+    (16, 16, 128, 2, 96, 8),    # OLMoE's
+    (8, 16, 128, 2, 8, 8),      # the table is narrower than the budget
+    (1, 16, 128, 2, 96, 96),    # a tensor shard's single head: the whole table
+    (8, 128, 128, 2, 16, 2),    # pages of 128 tokens
+    (4, 16, 256, 4, 4096, 8),   # float32 at head_dim 256
+    (64, 512, 256, 4, 32, 1),   # one page overflows the budget: still one
+])
+def test_paged_kernel_pages_per_trip_follows_shapes(
+    kv_heads, page, head_dim, itemsize, table, expect
+):
+    from llm_training_tpu.ops.pallas.paged_attention import (
+        _KV_SCRATCH_BYTES,
+        pages_per_trip,
+    )
+
+    n = pages_per_trip(kv_heads, page, head_dim, itemsize, table)
+    assert n == expect
+    assert 1 <= n <= table
+    scratch = 4 * n * kv_heads * page * head_dim * itemsize  # 2 slots x K, V
+    assert scratch <= _KV_SCRATCH_BYTES or n == 1
 
 
 # -------------------------------------------- paged == dense greedy parity
