@@ -14,12 +14,36 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from llm_training_tpu.models.base import DecodeState, resolve_dtype
+from llm_training_tpu.models.base import (
+    DecodeState,
+    KVCacheSpec,
+    RecurrentCacheSpec,
+    resolve_dtype,
+)
 from llm_training_tpu.parallel.sharding import LogicalAxisRules, logical_to_spec
 
 # cache buffer layout: [num_layers, batch, max_length, num_kv_heads, head_dim]
 KV_LOGICAL_AXES = ("layers", "batch", None, "kv_heads", None)
 SEG_LOGICAL_AXES = ("batch", None)
+# recurrent slab: state [layers, slots, heads, key_dim, value_dim] and the
+# conv tail [layers, slots, taps, channels]; a slot is a batch row
+STATE_LOGICAL_AXES = ("layers", "batch", "heads", None, None)
+CONV_LOGICAL_AXES = ("layers", "batch", None, "heads")
+
+
+def cache_specs(config) -> tuple[KVCacheSpec, RecurrentCacheSpec | None]:
+    """What a stack caches, one declaration a layer kind: every pool, dense
+    buffer, slab and sharding below derives from it. A config whose layers
+    are not all of one kind says so itself (`config.cache_specs()`); the
+    shared softmax stacks cache keys and values on every layer."""
+    declared = getattr(config, "cache_specs", None)
+    if declared is not None:
+        return declared()
+    head_dim = getattr(config, "resolved_head_dim", None) or config.head_dim
+    return (
+        KVCacheSpec(config.num_hidden_layers, config.num_key_value_heads, head_dim),
+        None,
+    )
 
 
 def cache_dims(config) -> tuple[int, int, int]:
@@ -27,8 +51,52 @@ def cache_dims(config) -> tuple[int, int, int]:
 
     Gemma carries a mandatory explicit `head_dim`; llama-family configs
     derive it via `resolved_head_dim`."""
-    head_dim = getattr(config, "resolved_head_dim", None) or config.head_dim
-    return config.num_hidden_layers, config.num_key_value_heads, head_dim
+    kv, _ = cache_specs(config)
+    return kv.layers, kv.kv_heads, kv.head_dim
+
+
+def slab_shapes(
+    spec: RecurrentCacheSpec, slots: int
+) -> tuple[tuple[int, ...], tuple[int, ...]]:
+    """(state shape, conv-tail shape) for `slots` decode slots."""
+    return (
+        (spec.layers, slots, spec.heads, spec.key_dim, spec.value_dim),
+        (spec.layers, slots, spec.conv_taps, spec.conv_channels),
+    )
+
+
+def slab_shardings(spec: RecurrentCacheSpec, slots: int, mesh: Mesh, rules):
+    state_shape, conv_shape = slab_shapes(spec, slots)
+    return (
+        NamedSharding(mesh, _divisible_spec(state_shape, STATE_LOGICAL_AXES, mesh, rules)),
+        NamedSharding(mesh, _divisible_spec(conv_shape, CONV_LOGICAL_AXES, mesh, rules)),
+    )
+
+
+def _slab_zeros(spec: RecurrentCacheSpec, slots: int, tail_dtype):
+    state_shape, conv_shape = slab_shapes(spec, slots)
+    return jnp.zeros(state_shape, jnp.float32), jnp.zeros(conv_shape, tail_dtype)
+
+
+def init_state_slab(
+    config, slots: int, mesh: Mesh | None = None, rules=None,
+    cache_dtype: str | None = None,
+) -> tuple[jnp.ndarray, jnp.ndarray] | None:
+    """Fresh all-zeros (state, conv tail) for a stack with linear-attention
+    layers, None for one without. The state is float32 whatever the cache
+    dtype (it is summed into over the whole sequence); the tail holds
+    activations and takes the cache dtype."""
+    _, spec = cache_specs(config)
+    if spec is None:
+        return None
+    dtype = resolve_cache_dtype(config, cache_dtype)
+
+    def build():
+        return _slab_zeros(spec, slots, dtype)
+
+    if mesh is None:
+        return build()
+    return jax.jit(build, out_shardings=slab_shardings(spec, slots, mesh, rules or ()))()
 
 
 def resolve_cache_dtype(config, cache_dtype: str | None) -> jnp.dtype:
@@ -81,9 +149,13 @@ def decode_state_shardings(
         mesh,
         _divisible_spec((batch_size, max_length), SEG_LOGICAL_AXES, mesh, rules),
     )
+    _, recurrent = cache_specs(config)
+    state = conv = None
+    if recurrent is not None:
+        state, conv = slab_shardings(recurrent, batch_size, mesh, rules)
     return DecodeState(
         k=kv, v=kv, index=NamedSharding(mesh, PartitionSpec()), segment_ids=seg,
-        rope_length=rope_length,
+        state=state, conv=conv, rope_length=rope_length,
     )
 
 
@@ -104,13 +176,19 @@ def init_decode_state(
     num_layers, kv_heads, head_dim = cache_dims(config)
     dtype = resolve_cache_dtype(config, cache_dtype)
 
+    _, recurrent = cache_specs(config)
+
     def build() -> DecodeState:
         kv_shape = (num_layers, batch_size, max_length, kv_heads, head_dim)
+        state, conv = (
+            (None, None) if recurrent is None else _slab_zeros(recurrent, batch_size, dtype)
+        )
         return DecodeState(
             k=jnp.zeros(kv_shape, dtype),
             v=jnp.zeros(kv_shape, dtype),
             index=jnp.int32(0),
             segment_ids=jnp.zeros((batch_size, max_length), jnp.int32),
+            state=state, conv=conv,
             rope_length=rope_length,
         )
 
@@ -139,5 +217,7 @@ def cache_bytes(state: DecodeState) -> int:
     """Global HBM footprint of the cache buffers (the `decode/cache_bytes`
     gauge)."""
     return sum(
-        leaf.size * leaf.dtype.itemsize for leaf in (state.k, state.v)
+        leaf.size * leaf.dtype.itemsize
+        for leaf in (state.k, state.v, state.state, state.conv)
+        if leaf is not None
     )

@@ -89,9 +89,11 @@ def mesh_context(mesh: Any, rules: Any = ()) -> contextlib.ExitStack:
 
 def supports_decoding(model: Any) -> bool:
     """A model family opts into KV-cache decoding by accepting a
-    `decode_state` kwarg (the shared llama/gemma stacks do; non-standard
-    mixers — bamba's mamba layers, qwen3-next/minimax linear attention,
-    deepseek MLA — have not been threaded yet)."""
+    `decode_state` kwarg: the shared llama/gemma/phi3 stacks, and
+    solar_open2, whose linear-attention (KDA) layers keep a fixed state slab
+    a decode slot beside the key/value cache (`infer/cache.py:cache_specs`).
+    Not threaded yet: bamba's mamba layers, qwen3-next's and minimax's
+    linear attention, deepseek's MLA."""
     try:
         return "decode_state" in inspect.signature(model.__call__).parameters
     except (TypeError, ValueError):

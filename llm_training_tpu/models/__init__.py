@@ -22,6 +22,7 @@ from llm_training_tpu.models.llama import Llama, LlamaConfig
 from llm_training_tpu.models.minimax import MiniMax, MiniMaxConfig
 from llm_training_tpu.models.phi3 import Phi3, Phi3Config
 from llm_training_tpu.models.qwen3_next import Qwen3Next, Qwen3NextConfig
+from llm_training_tpu.models.solar_open2 import SolarOpen2, SolarOpen2Config
 
 __all__ = [
     "Bamba",
@@ -51,4 +52,6 @@ __all__ = [
     "Phi3Config",
     "Qwen3Next",
     "Qwen3NextConfig",
+    "SolarOpen2",
+    "SolarOpen2Config",
 ]

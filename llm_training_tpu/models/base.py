@@ -8,6 +8,7 @@ dissolve into logical-axis metadata here) and
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Literal
 
 import flax.struct
@@ -98,6 +99,33 @@ class RouterStats:
     layer_ids: tuple[int, ...] = flax.struct.field(pytree_node=False, default=())
 
 
+@dataclasses.dataclass(frozen=True)
+class KVCacheSpec:
+    """The cache of a stack's softmax-attention layers: keys and values a
+    token, in pages (`serve/paged_cache.py`) or a dense buffer
+    (`infer/cache.py`). `layers` counts the layers of THIS kind only."""
+
+    layers: int
+    kv_heads: int
+    head_dim: int
+
+
+@dataclasses.dataclass(frozen=True)
+class RecurrentCacheSpec:
+    """The cache of a stack's linear-attention layers: a fixed slab a decode
+    slot, whatever the request's length: the float32 fast-weight state
+    `[layers, slots, heads, key_dim, value_dim]` and the short convolution's
+    tail `[layers, slots, conv_taps, conv_channels]` (the last pre-conv
+    inputs; `conv_taps` = kernel width - 1)."""
+
+    layers: int
+    heads: int
+    key_dim: int
+    value_dim: int
+    conv_taps: int
+    conv_channels: int
+
+
 @flax.struct.dataclass
 class DecodeState:
     """Static-shape, mesh-sharded KV cache threaded through the decoder
@@ -124,6 +152,10 @@ class DecodeState:
     v: jnp.ndarray
     index: jnp.ndarray
     segment_ids: jnp.ndarray
+    # linear-attention layers' slab (`RecurrentCacheSpec`), one slot a batch
+    # row; None for a stack that has no such layer
+    state: jnp.ndarray | None = None
+    conv: jnp.ndarray | None = None
     # STATIC (not a pytree leaf): the sequence length the generation will
     # actually reach (padded prompt width + max_new_tokens). Length-
     # dependent RoPE variants (longrope short/long factor selection,
@@ -163,12 +195,22 @@ class PagedDecodeState:
     The decoder stacks thread this through the SAME `layer_kv`/`kv_index`/
     `kv_segment_ids` plumbing as `DecodeState` (kv_index carries the [B]
     lengths, kv_segment_ids carries the block tables); attention layers
-    dispatch on `kv_index.ndim` to `ops.paged_attention`."""
+    dispatch on `kv_index.ndim` to `ops.paged_attention`.
+
+    A stack with linear-attention layers carries their slab beside the pool
+    (`state`, `conv`: `RecurrentCacheSpec`), indexed by decode SLOT, not by
+    block. `slots [batch]` names each row's slot (None: row i is slot i, the
+    decode step); `fresh [batch]` marks rows whose request starts here, so
+    the slot's state and tail are read as zeros whatever the slot held."""
 
     k: jnp.ndarray
     v: jnp.ndarray
     block_tables: jnp.ndarray
     lengths: jnp.ndarray
+    state: jnp.ndarray | None = None
+    conv: jnp.ndarray | None = None
+    slots: jnp.ndarray | None = None
+    fresh: jnp.ndarray | None = None
     # STATIC: planned total sequence length for length-dependent RoPE table
     # selection (same contract as DecodeState.rope_length); None = the
     # per-request capacity block_tables can address.

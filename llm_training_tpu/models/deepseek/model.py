@@ -118,7 +118,14 @@ class DeepseekMoE(nn.Module):
 
     Returns (out, (sel_frac [E], mean_prob [E], dropped scalar)) — the
     router health triple (`models.moe.router_block_stats` semantics;
-    `pad_mask` excludes padding tokens like MoEMLP)."""
+    `pad_mask` excludes padding tokens like MoEMLP).
+
+    A config with `experts_held` (and `experts_first`) makes this an
+    expert-parallel SHARE: the router keeps its `n_routed_experts` outputs
+    and its top-k, the stacked expert parameters hold `experts_held` experts,
+    and the output is their part of the routed sum plus the shared experts
+    (`models.moe.dropless_moe_apply(held=...)`); no code stands in for the
+    chips that hold the others."""
 
     config: DeepseekConfig
 
@@ -126,6 +133,8 @@ class DeepseekMoE(nn.Module):
     def __call__(self, hidden, pad_mask=None):
         cfg = self.config
         num_experts = cfg.n_routed_experts
+        held = getattr(cfg, "experts_held", None)
+        num_held = num_experts if held is None else held
         top_k = cfg.num_experts_per_tok
         inter = cfg.moe_intermediate_size
         compute_dtype = cfg.compute_jnp_dtype
@@ -143,46 +152,47 @@ class DeepseekMoE(nn.Module):
             (embed, num_experts),
             param_dtype,
         )
-        logits = x.astype(jnp.float32) @ gate_kernel.astype(jnp.float32)
-        if cfg.version == 3:
-            scores = jax.nn.sigmoid(logits)
-            bias = self.param(
-                "e_score_correction_bias",
-                nn.with_logical_partitioning(nn.initializers.zeros_init(), ("expert",)),
-                (num_experts,),
-                jnp.float32,
-            )
-            # selection sees scores+bias; combine weights use raw scores (the
-            # noaux balancing trick) — no gradient reaches the bias (top_k
-            # indices are non-differentiable), matching its HF buffer role
-            choice = scores + jax.lax.stop_gradient(bias)
-        else:
-            scores = jax.nn.softmax(logits, axis=-1)
-            choice = scores
-
-        group_limited = cfg.n_group and (
-            cfg.version == 3 or cfg.topk_method == "group_limited_greedy"
-        )
-        if group_limited:
-            groups = cfg.n_group
-            per_group = choice.reshape(n_tokens, groups, num_experts // groups)
+        with jax.named_scope("moe_route"):
+            logits = x.astype(jnp.float32) @ gate_kernel.astype(jnp.float32)
             if cfg.version == 3:
-                # group score = sum of its top-2 member scores
-                group_scores = jax.lax.top_k(per_group, 2)[0].sum(axis=-1)
+                scores = jax.nn.sigmoid(logits)
+                bias = self.param(
+                    "e_score_correction_bias",
+                    nn.with_logical_partitioning(nn.initializers.zeros_init(), ("expert",)),
+                    (num_experts,),
+                    jnp.float32,
+                )
+                # selection sees scores+bias; combine weights use raw scores (the
+                # noaux balancing trick) — no gradient reaches the bias (top_k
+                # indices are non-differentiable), matching its HF buffer role
+                choice = scores + jax.lax.stop_gradient(bias)
             else:
-                group_scores = per_group.max(axis=-1)
-            _, group_idx = jax.lax.top_k(group_scores, cfg.topk_group)
-            group_mask = jax.nn.one_hot(group_idx, groups, dtype=jnp.float32).sum(axis=1)
-            mask = jnp.repeat(group_mask, num_experts // groups, axis=-1)
-            choice = jnp.where(mask > 0, choice, 0.0)
+                scores = jax.nn.softmax(logits, axis=-1)
+                choice = scores
 
-        _, topk_idx = jax.lax.top_k(choice, top_k)  # [T, K]
-        topk_weights = jnp.take_along_axis(scores, topk_idx, axis=1)
-        if cfg.version == 3 and cfg.norm_topk_prob:
-            topk_weights = topk_weights / (
-                topk_weights.sum(axis=-1, keepdims=True) + 1e-20
+            group_limited = cfg.n_group and (
+                cfg.version == 3 or cfg.topk_method == "group_limited_greedy"
             )
-        topk_weights = (topk_weights * cfg.routed_scaling_factor).astype(compute_dtype)
+            if group_limited:
+                groups = cfg.n_group
+                per_group = choice.reshape(n_tokens, groups, num_experts // groups)
+                if cfg.version == 3:
+                    # group score = sum of its top-2 member scores
+                    group_scores = jax.lax.top_k(per_group, 2)[0].sum(axis=-1)
+                else:
+                    group_scores = per_group.max(axis=-1)
+                _, group_idx = jax.lax.top_k(group_scores, cfg.topk_group)
+                group_mask = jax.nn.one_hot(group_idx, groups, dtype=jnp.float32).sum(axis=1)
+                mask = jnp.repeat(group_mask, num_experts // groups, axis=-1)
+                choice = jnp.where(mask > 0, choice, 0.0)
+
+            _, topk_idx = jax.lax.top_k(choice, top_k)  # [T, K]
+            topk_weights = jnp.take_along_axis(scores, topk_idx, axis=1)
+            if cfg.version == 3 and cfg.norm_topk_prob:
+                topk_weights = topk_weights / (
+                    topk_weights.sum(axis=-1, keepdims=True) + 1e-20
+                )
+            topk_weights = (topk_weights * cfg.routed_scaling_factor).astype(compute_dtype)
 
         # ---- stacked expert weights
         def expert_param(name, shape, axes):
@@ -196,13 +206,13 @@ class DeepseekMoE(nn.Module):
             ).astype(compute_dtype)
 
         w_gate = expert_param(
-            "experts_gate_proj", (num_experts, embed, inter), ("expert", "embed", "mlp")
+            "experts_gate_proj", (num_held, embed, inter), ("expert", "embed", "mlp")
         )
         w_up = expert_param(
-            "experts_up_proj", (num_experts, embed, inter), ("expert", "embed", "mlp")
+            "experts_up_proj", (num_held, embed, inter), ("expert", "embed", "mlp")
         )
         w_down = expert_param(
-            "experts_down_proj", (num_experts, inter, embed), ("expert", "mlp", "embed")
+            "experts_down_proj", (num_held, inter, embed), ("expert", "mlp", "embed")
         )
 
         def dense_fn(xc):
@@ -223,12 +233,14 @@ class DeepseekMoE(nn.Module):
             cfg.moe_impl, dense_fn, ragged_fn,
             weights=(w_gate, w_up, w_down),
             ep_capacity_factor=getattr(cfg, "ep_capacity_factor", 2.0),
+            held=None if held is None else (cfg.experts_first, held),
         )
         out = out.reshape(batch, seq, embed).astype(hidden.dtype)
-        shared = DeepseekMLP(
-            cfg, cfg.moe_intermediate_size * cfg.n_shared_experts,
-            name="shared_experts",
-        )(hidden)
+        with jax.named_scope("moe_shared"):
+            shared = DeepseekMLP(
+                cfg, cfg.moe_intermediate_size * cfg.n_shared_experts,
+                name="shared_experts",
+            )(hidden)
         # router health stats (telemetry/health.py) — sigmoid scores (v3)
         # normalize per token first so the entropy stays a distribution
         # statistic. DCE'd when unused.

@@ -138,6 +138,47 @@ def _dense(config: LlamaConfig, features: int, logical_axes: tuple[str, str], na
     )
 
 
+def cached_attention(q, k, v, segment_ids, layer_kv, kv_index, kv_segment_ids,
+                     window=None, scale=None):
+    """Append this chunk's k/v at `kv_index` and attend q against the
+    full static-shape cache. The causal term of the mask (q_offset =
+    kv_index) hides slots written after this chunk, and `kv_segment_ids`
+    (0 on unwritten/pad slots) hides garbage — so ONE program serves
+    both prefill (chunk at index 0) and single-token decode steps.
+    Dense-cache attention is always the XLA einsum path: the flash
+    kernel's block tiling assumes q_len ≥ a block and a static q_offset.
+
+    A PAGED cache (`PagedDecodeState`, serve/ subsystem) arrives through
+    the same plumbing with per-ROW lengths in `kv_index` ([B], vs the
+    dense scalar) and the block table in `kv_segment_ids` — dispatched
+    to `ops.paged_attention` (ragged Pallas decode kernel on TPU, XLA
+    gather fallback elsewhere). Shared by every softmax-attention module
+    that decodes (`LlamaAttention`, `solar_open2.GatedAttention`)."""
+    if kv_index.ndim == 1:
+        from llm_training_tpu.ops.paged_attention import paged_cached_attention
+
+        return paged_cached_attention(
+            q, k, v, layer_kv, kv_index, kv_segment_ids,
+            segment_ids=segment_ids,
+            sliding_window=window,
+            scale=scale,
+        )
+    ck, cv = layer_kv
+    ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype), (0, kv_index, 0, 0))
+    cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype), (0, kv_index, 0, 0))
+    out = dot_product_attention(
+        q, ck.astype(k.dtype), cv.astype(v.dtype),
+        segment_ids=kv_segment_ids,
+        q_segment_ids=segment_ids,
+        causal=True,
+        sliding_window=window,
+        scale=scale,
+        q_offset=kv_index,
+        impl="xla",
+    )
+    return out, (ck, cv)
+
+
 class LlamaAttention(nn.Module):
     """GQA attention (reference `llama_model.py:434-663`).
 
@@ -267,48 +308,16 @@ class LlamaAttention(nn.Module):
         return out
 
     def _cached_attention(self, q, k, v, segment_ids, layer_kv, kv_index, kv_segment_ids):
-        """Append this chunk's k/v at `kv_index` and attend q against the
-        full static-shape cache. The causal term of the mask (q_offset =
-        kv_index) hides slots written after this chunk, and `kv_segment_ids`
-        (0 on unwritten/pad slots) hides garbage — so ONE program serves
-        both prefill (chunk at index 0) and single-token decode steps.
-        Dense-cache attention is always the XLA einsum path: the flash
-        kernel's block tiling assumes q_len ≥ a block and a static q_offset.
-
-        A PAGED cache (`PagedDecodeState`, serve/ subsystem) arrives through
-        the same plumbing with per-ROW lengths in `kv_index` ([B], vs the
-        dense scalar) and the block table in `kv_segment_ids` — dispatched
-        to `ops.paged_attention` (ragged Pallas decode kernel on TPU, XLA
-        gather fallback elsewhere)."""
         cfg = self.config
         window = (
             getattr(cfg, "sliding_window", None)
             if self.sliding_window_override == "unset"
             else self.sliding_window_override
         )
-        if kv_index.ndim == 1:
-            from llm_training_tpu.ops.paged_attention import paged_cached_attention
-
-            return paged_cached_attention(
-                q, k, v, layer_kv, kv_index, kv_segment_ids,
-                segment_ids=segment_ids,
-                sliding_window=window,
-                scale=getattr(cfg, "attention_multiplier", None),
-            )
-        ck, cv = layer_kv
-        ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype), (0, kv_index, 0, 0))
-        cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype), (0, kv_index, 0, 0))
-        out = dot_product_attention(
-            q, ck.astype(k.dtype), cv.astype(v.dtype),
-            segment_ids=kv_segment_ids,
-            q_segment_ids=segment_ids,
-            causal=True,
-            sliding_window=window,
-            scale=getattr(cfg, "attention_multiplier", None),
-            q_offset=kv_index,
-            impl="xla",
+        return cached_attention(
+            q, k, v, segment_ids, layer_kv, kv_index, kv_segment_ids,
+            window=window, scale=getattr(cfg, "attention_multiplier", None),
         )
-        return out, (ck, cv)
 
     def _attention(self, q, k, v, segment_ids):
         """Dispatch: ring attention over a sequence-sharded mesh when enabled,

@@ -280,8 +280,16 @@ def dropless_moe_apply(
     ep_capacity_factor: float = 2.0,
     bmm_fn=None,
     moe_capacity_factor: float = 1.25,
+    held: tuple[int, int] | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Shared dropless dispatch/combine for every MoE family.
+
+    `held = (first, count)`: an expert-parallel SHARE run without its
+    exchange. The router chose among `num_experts`; this call holds experts
+    `first .. first + count` only (`weights`, `dense_fn` and `ragged_fn` see
+    `count` experts) and computes their part of the sum. Assignments to
+    experts held elsewhere are left out, their weights having been
+    normalised over all the chosen ones by the caller.
 
     x: [T, H] compute-dtype tokens; topk_idx/topk_weights: [T, K].
     dense_fn(x) -> [T, E, H] (every expert on every token — exact path);
@@ -310,6 +318,20 @@ def dropless_moe_apply(
     no_drops = jnp.float32(0.0)
     if impl == "auto":
         impl = "ragged" if jax.default_backend() == "tpu" else "dense"
+    if held is not None:
+        if impl not in ("dense", "ragged") or _ep_group_size() > 1:
+            raise ValueError(
+                "a held share of the experts runs the dense or ragged path "
+                f"off an expert mesh; got moe_impl={impl!r}"
+            )
+        first, count = held
+        local = topk_idx - first
+        mine = (local >= 0) & (local < count)
+        # what is held elsewhere sorts into one group past the held ones,
+        # with no weight: no expert here computes it
+        topk_idx = jnp.where(mine, local, count)
+        topk_weights = jnp.where(mine, topk_weights, 0)
+        num_experts = count + 1
     if impl not in ("dense", "ragged", "bucketed"):
         # fail loudly: a typo'd impl silently measuring the ragged path
         # would corrupt exactly the A/B comparisons this knob exists for
@@ -338,6 +360,8 @@ def dropless_moe_apply(
             combine = combine.at[
                 jnp.arange(n_tokens)[:, None], topk_idx
             ].set(topk_weights)
+            if held is not None:
+                combine = combine[:, :-1]
             return jnp.einsum("teh,te->th", y, combine), no_drops
     ep = _ep_group_size()
     if ep > 1:
@@ -356,10 +380,16 @@ def dropless_moe_apply(
     with jax.named_scope("moe_gather"):
         token_order = flat_token[order]
         xs, expert_order = x[token_order], flat_expert[order]
+    if held is not None:
+        # the rows past the held groups belong to no group: `ragged_dot`
+        # leaves them zero, and the select below holds whatever it leaves
+        group_sizes = group_sizes[:-1]
     with jax.named_scope("moe_experts"):
         ys = ragged_fn(xs, group_sizes, expert_order, weights)
     with jax.named_scope("moe_scatter"):
         ys = ys * flat_weight[order][:, None]
+        if held is not None:
+            ys = jnp.where((expert_order < num_experts - 1)[:, None], ys, 0)
         out = jnp.zeros((n_tokens, x.shape[-1]), x.dtype).at[token_order].add(ys)
     return out, no_drops
 

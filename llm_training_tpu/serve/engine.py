@@ -11,6 +11,14 @@ both against the paged pool (donated — the cache mutates in place in HBM):
   length, no shared append index, no left padding. Idle slots carry the
   trash-block table and cost one garbage row.
 
+A stack with linear-attention layers (`infer/cache.py:cache_specs`) has a
+second cache beside the pool: the STATE SLAB, a fixed float32 state and a
+short conv tail for every decode slot, donated through both programs like
+the pool. A request's first chunk reads its slot as zeros (admission and
+the fold-in requeue after an eviction alike: `serve/state_resets`), later
+chunks and decode steps carry the slot's state on, and padded positions,
+idle slots and slots still prefilling are left exactly as they were.
+
 The host loop (`step()`) executes what the `Scheduler` decides: admission
 when free blocks suffice, one prefill chunk interleaved between decode
 steps, eviction/requeue under block pressure, slot recycling on eos /
@@ -59,6 +67,7 @@ from llm_training_tpu.resilience.chaos import get_chaos
 from llm_training_tpu.serve.paged_cache import (
     BlockAllocator,
     init_paged_pool,
+    init_state_slab,
     pool_bytes,
     resolve_block_size,
 )
@@ -177,7 +186,13 @@ class ServingEngine:
                 mesh=self.mesh, rules=self.rules,
                 cache_dtype=self.config.cache_dtype,
             )
+            # None for a stack whose layers all cache keys and values
+            self._slab = init_state_slab(
+                model_config, self.config.max_batch, mesh=self.mesh,
+                rules=self.rules, cache_dtype=self.config.cache_dtype,
+            )
         self._cache_bytes = pool_bytes(self._pool_k, self._pool_v)  # outlives close()
+        self._state_bytes = 0 if self._slab is None else pool_bytes(*self._slab)
         self.allocator = BlockAllocator(num_blocks + 1)
         self.scheduler = Scheduler(
             SchedulerConfig(
@@ -236,6 +251,30 @@ class ServingEngine:
             os.environ.get("LLMT_PROFILE_ATTR_DECODE")
         )
 
+    # the pool, as the two attributes every caller knows. A caller that
+    # drops the pool (`close()`, or setting either to None) drops the state
+    # slab with it: the caches live and die together, whatever else still
+    # holds the engine.
+    @property
+    def _pool_k(self):
+        return self._k
+
+    @_pool_k.setter
+    def _pool_k(self, value):
+        self._k = value
+        if value is None:
+            self._slab = None
+
+    @property
+    def _pool_v(self):
+        return self._v
+
+    @_pool_v.setter
+    def _pool_v(self, value):
+        self._v = value
+        if value is None:
+            self._slab = None
+
     # ------------------------------------------------------------ programs
 
     def _ctx(self):
@@ -248,11 +287,21 @@ class ServingEngine:
         sampling = self.config.sampling
         rope_length = self.config.max_model_len
 
+        def slab_fields(slab, **rows):
+            if slab is None:
+                return {}
+            return {"state": slab[0], "conv": slab[1], **rows}
+
+        def slab_of(state):
+            return None if state.state is None else (state.state, state.conv)
+
         def prefill_chunk(variables, ids, seg, pos, pool_k, pool_v,
-                          tables, length, last_pos, rng):
+                          tables, length, last_pos, rng, slab=None, slot=None, fresh=None):
             state = PagedDecodeState(
                 k=pool_k, v=pool_v, block_tables=tables, lengths=length,
                 rope_length=rope_length,
+                # the request's slot of the slab, read as zeros on its first chunk
+                **slab_fields(slab, slots=slot, fresh=fresh),
             )
             out = model.apply(
                 variables, input_ids=ids, segment_ids=seg,
@@ -266,29 +315,40 @@ class ServingEngine:
                     logits[None], rng, sampling
                 )
             state = out.decode_state
-            return state.k, state.v, token[0], logprob[0]
+            return state.k, state.v, token[0], logprob[0], slab_of(state)
 
-        def decode_step(variables, tokens, pool_k, pool_v, tables, lengths, rng):
+        def decode_step(variables, tokens, pool_k, pool_v, tables, lengths, rng, slab=None):
             state = PagedDecodeState(
                 k=pool_k, v=pool_v, block_tables=tables, lengths=lengths,
-                rope_length=rope_length,
+                rope_length=rope_length, **slab_fields(slab),
             )
+            # row i is slot i. A slot that does not decode this step (idle, or
+            # its prompt still prefilling) has length 0 here: segment 0, so
+            # its state and tail come out as they went in
+            rows = {} if slab is None else {
+                "segment_ids": (lengths > 0).astype(jnp.int32)[:, None]
+            }
             out = model.apply(
                 variables, input_ids=tokens[:, None],
-                position_ids=lengths[:, None], decode_state=state,
+                position_ids=lengths[:, None], decode_state=state, **rows,
             )
             logits = out.logits[:, -1].astype(jnp.float32)
             with jax.named_scope("sample"):
                 token, logprob = sample_tokens_with_logprob(logits, rng, sampling)
             state = out.decode_state
-            return state.k, state.v, token, logprob
+            return state.k, state.v, token, logprob, slab_of(state)
 
         # the function names ARE the programs' names (`jit_prefill_chunk`,
         # `jit_decode_step` in HLO module names and in a device profile):
         # docs, chip_smoke.py and the benchmark's trace readers match them.
         # A contract, pinned by tests/test_serve_spans.py.
-        self._prefill_jit = jax.jit(prefill_chunk, donate_argnums=(4, 5))
-        self._decode_jit = jax.jit(decode_step, donate_argnums=(2, 3))
+        # (the slab goes by keyword: a stack without one is called as before)
+        self._prefill_jit = jax.jit(
+            prefill_chunk, donate_argnums=(4, 5), donate_argnames=("slab",)
+        )
+        self._decode_jit = jax.jit(
+            decode_step, donate_argnums=(2, 3), donate_argnames=("slab",)
+        )
 
     def _next_rng(self):
         self._call += 1
@@ -499,15 +559,16 @@ class ServingEngine:
         return summary
 
     def close(self) -> None:
-        """Wait for the pool's last write, then give the pool's device
-        memory back: for a caller that keeps the weights and needs the room
-        (a reference pass after serving, a reload into a larger pool). The
-        engine cannot step afterwards; `stats()` still answers. Idempotent."""
+        """Wait for the pool's last write, then give the pool's (and the
+        state slab's) device memory back: for a caller that keeps the
+        weights and needs the room (a reference pass after serving, a
+        reload into a larger pool). The engine cannot step afterwards;
+        `stats()` still answers. Idempotent."""
         if self._pool_k is None:
             return
-        jax.block_until_ready((self._pool_k, self._pool_v))
-        self._pool_k.delete()
-        self._pool_v.delete()
+        jax.block_until_ready((self._pool_k, self._pool_v, self._slab))
+        for buffer in (self._pool_k, self._pool_v, *(self._slab or ())):
+            buffer.delete()
         self._pool_k = self._pool_v = None
 
     # ---------------------------------------------------------------- step
@@ -528,6 +589,8 @@ class ServingEngine:
             "prefill_chunks": 0, "prefill_tokens": 0,
             "decode_rows": 0, "live_tokens": 0,
         }
+        if self._slab is not None:
+            counts["state_resets"] = 0
         # every child span below runs on this thread inside engine_step and
         # carries the step index; nesting by time gives the parent. Children
         # go to the ring and the profiler only (`write=False`): trace.jsonl
@@ -584,9 +647,16 @@ class ServingEngine:
                 rows = self.scheduler.decode_rows()
             if rows:
                 events.extend(self._run_decode(rows))
+            if self._slab is not None:
+                # slots whose state belongs to a live request after this step
+                counts["state_slots_in_use"] = len(self.scheduler.running)
             closing.update(counts)
         registry = get_registry()
         registry.counter("serve/steps").inc()
+        if self._slab is not None:
+            registry.gauge("decode/state_slots_in_use").set(counts["state_slots_in_use"])
+            if counts["state_resets"]:
+                registry.counter("serve/state_resets").inc(counts["state_resets"])
         if counts["prefill_chunks"]:
             registry.counter("serve/prefill_chunks").inc(counts["prefill_chunks"])
         if counts["decode_rows"]:
@@ -649,6 +719,12 @@ class ServingEngine:
         final = start + len(chunk) >= len(request.prefill_tokens)
         self._step_counts["prefill_chunks"] = 1
         self._step_counts["prefill_tokens"] = len(chunk)
+        # a residency's first chunk (admission, or the requeue after an
+        # eviction: both restart at 0) starts from a zero state, whatever the
+        # slot's last tenant left
+        fresh = start == 0
+        if fresh and self._slab is not None:
+            self._step_counts["state_resets"] = 1
         with tracer.measure(
             "serve", "prefill_chunk", write=request.traced, **ids,
             start=start, tokens=len(chunk), final=final,
@@ -665,11 +741,17 @@ class ServingEngine:
                     start + np.arange(width), self.config.max_model_len - 1
                 ).astype(np.int32)[None, :]
                 tables = self._table_row(request)[None, :]
-                self._pool_k, self._pool_v, token, logprob = self._prefill_jit(
+                # (numpy rows: they travel with the call, not as transfers of their own)
+                slab_row = {} if self._slab is None else {
+                    "slab": self._slab,
+                    "slot": np.asarray([request.slot], np.int32),
+                    "fresh": np.asarray([fresh]),
+                }
+                self._pool_k, self._pool_v, token, logprob, self._slab = self._prefill_jit(
                     self.variables, jnp.asarray(ids_row), jnp.asarray(seg),
                     jnp.asarray(pos), self._pool_k, self._pool_v,
                     jnp.asarray(tables), jnp.asarray([start], jnp.int32),
-                    jnp.int32(len(chunk) - 1), self._next_rng(),
+                    jnp.int32(len(chunk) - 1), self._next_rng(), **slab_row,
                 )
             request.prefilled += len(chunk)
             request.cache_len += len(chunk)
@@ -722,15 +804,18 @@ class ServingEngine:
                 self.variables, jnp.asarray(tokens), self._pool_k, self._pool_v,
                 jnp.asarray(tables), jnp.asarray(lengths), self._next_rng(),
             )
+            step_slab = {} if self._slab is None else {"slab": self._slab}
         if not self._decode_attr_done:
             # before the donating call below: lowering only reads avals,
             # while the jit consumes the pool buffers
             self._decode_attr_done = True
-            self._publish_decode_attribution(step_args)
+            self._publish_decode_attribution(step_args, step_slab)
         # the enqueue alone, then the wait for the device: a step that reads
         # far off shows in which of the two its seconds went
         with tracer.measure("serve", "decode_dispatch", **child):
-            self._pool_k, self._pool_v, out, out_lp = self._decode_jit(*step_args)
+            self._pool_k, self._pool_v, out, out_lp, self._slab = self._decode_jit(
+                *step_args, **step_slab
+            )
         with tracer.measure("serve", "decode_fetch", **child):
             host, host_lp = jax.device_get((out, out_lp))
         with tracer.measure("serve", "decode_emit", **child):
@@ -744,7 +829,7 @@ class ServingEngine:
                 )
         return events
 
-    def _publish_decode_attribution(self, step_args) -> None:
+    def _publish_decode_attribution(self, step_args, step_slab) -> None:
         """AOT-lower the decode step against the first real batch's avals
         and publish its compute/comm split as attr/decode/* gauges
         (docs/observability.md#device-plane). The lowering pays one extra
@@ -756,7 +841,7 @@ class ServingEngine:
             )
 
             with self._ctx():
-                compiled = self._decode_jit.lower(*step_args).compile()
+                compiled = self._decode_jit.lower(*step_args, **step_slab).compile()
             mesh_axes = None
             if self.mesh is not None:
                 mesh_axes = dict(
@@ -922,6 +1007,7 @@ class ServingEngine:
             "serve/tokens_per_sec_per_chip": tps / n_chips,
             "serve/peak_running": float(self.peak_running),
             "decode/cache_bytes": float(self._cache_bytes),
+            "decode/state_bytes": float(self._state_bytes),
             "decode/cache_blocks_total": float(self.allocator.num_blocks - 1),
             "decode/cache_blocks_in_use": float(self.allocator.blocks_in_use),
             "decode/cache_peak_blocks_in_use": float(self.allocator.peak_in_use),

@@ -1,7 +1,9 @@
 """Paged KV-cache pool + host-side block allocator (docs/serving.md).
 
-The pool is the device half: `[num_layers, num_blocks, kv_heads,
-block_size, head_dim]` k/v buffers (one kv head's page is the trailing
+The pool is the device half: `[kv_layers, num_blocks, kv_heads,
+block_size, head_dim]` k/v buffers over the layers that cache keys and
+values (`infer/cache.py:cache_specs`; a stack's linear-attention layers keep
+a fixed slab a decode slot instead, `init_state_slab` below) (one kv head's page is the trailing
 (block_size, head_dim) tile the paged-decode kernel streams), built from
 the SAME training rule table `infer/cache.py` uses (kv heads shard over 'tensor'; the block axis stays
 replicated — each data-parallel serving replica owns its whole pool).
@@ -93,6 +95,23 @@ def init_paged_pool(
         k, v = jax.jit(build, out_shardings=(spec, spec))()
     _publish_pool_gauges(k, v, num_blocks)
     return k, v
+
+
+def init_state_slab(model_config, slots: int, mesh=None, rules=None,
+                    cache_dtype: str | None = None):
+    """The second kind of cache, for a stack with linear-attention layers:
+    `(state, conv tail)` with one slot a decode row (`infer/cache.py:
+    init_state_slab`, from the same `cache_specs` declaration the pool's
+    layer count comes from), or None for a stack without such layers.
+    Publishes its footprint as the `decode/state_bytes` gauge."""
+    from llm_training_tpu.infer import cache
+    from llm_training_tpu.telemetry import get_registry
+
+    slab = cache.init_state_slab(
+        model_config, slots, mesh=mesh, rules=rules, cache_dtype=cache_dtype
+    )
+    get_registry().gauge("decode/state_bytes").set(0 if slab is None else pool_bytes(*slab))
+    return slab
 
 
 def pool_bytes(k: jnp.ndarray, v: jnp.ndarray) -> int:

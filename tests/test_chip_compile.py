@@ -219,3 +219,89 @@ def test_interpret_is_impossible_on_a_tpu_backend(monkeypatch):
     assert resolve_interpret(None) is False
     with pytest.raises(ValueError, match="interpret=True on a TPU"):
         resolve_interpret(True)
+
+
+# ------------------------------------------------- the Solar-Open2 serve cell
+#
+# `solar2-serve-longdoc` (BENCHMARK.json): both serving programs at the
+# configuration's widths, pool and state slab, compiled for the v5e. What is
+# pinned: they fit the chip's 16 GB beside 6.6 GB of weights, the decode step
+# keeps the `paged_decode` kernel for its one GQA layer, the caches are
+# aliased in place, and how many whole-pool and whole-slab arrays each
+# program produces on the way (PERF.md section 5 reports the numbers beside
+# the other cells'; ROADMAP S2 is the item that lowers them).
+
+_SOLAR_POOL = r"bf16\[(?:1,)?6145,8,16,128\]"  # 32 x 192 pages of 16 tokens, and the trash page
+_SOLAR_STATE = r"f32\[(?:1,|3,)?32,64,128,128\]"  # a KDA layer's (or all three layers') states
+# produced in the compiled program's entry computation, views left out
+_SOLAR_PRODUCED = {"decode": {"pool": 8, "state": 4}, "prefill": {"pool": 6, "state": 7}}
+
+
+def _solar_program(v5e, program):
+    import json
+    import re
+    from pathlib import Path
+
+    import flax.linen as nn
+
+    from benchmarks import common
+    from llm_training_tpu.serve.engine import ServeConfig, ServingEngine
+
+    root = Path(__file__).resolve().parent.parent
+    cfg = json.loads((root / "benchmarks/configs/solar-open2-250b-ep8.json").read_text())
+    serve = json.loads((root / "benchmarks/traffic/serve-longdoc-closed.json").read_text())["engine"]
+    model = common.build_model(cfg)
+    one = SingleDeviceSharding(v5e.devices[0])
+    on = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree
+    )
+    shape = lambda dims, dtype=jnp.int32: jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+    variables = on(nn.meta.unbox(jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32))
+    )))
+    engine = ServingEngine(model, None, ServeConfig(**serve))  # its caches give the shapes
+    pool, slab = on(engine._pool_k), on(engine._slab)
+    assert pool.shape == (1, 6145, 8, 16, 128)  # the one GQA layer of the period
+    assert [s.shape for s in slab] == [(3, 32, 64, 128, 128), (3, 32, 3, 24576)]
+    rows, pages, chunk = serve["max_batch"], engine.pages_per_request, serve["prefill_chunk"]
+    key = on(jax.eval_shape(lambda: jax.random.key(0)))
+    if program == "decode":
+        lowered = engine._decode_jit.lower(
+            variables, shape((rows,)), pool, pool, shape((rows, pages)), shape((rows,)), key,
+            slab=slab,
+        )
+    else:
+        lowered = engine._prefill_jit.lower(
+            variables, shape((1, chunk)), shape((1, chunk)), shape((1, chunk)), pool, pool,
+            shape((1, pages)), shape((1,)), shape(()), key,
+            slab=slab, slot=shape((1,)), fresh=shape((1,), jnp.bool_),
+        )
+    engine.close()
+
+    def produced(text, pattern):
+        entry = text[text.index("\nENTRY "):]
+        total = 0
+        for line in entry.splitlines():
+            head, _, rest = line.partition(" = ")
+            kind = re.match(r"(?:\(.*?\)|\S+) ([\w\-]+)\(", rest)
+            if kind and kind.group(1) not in ("parameter", "bitcast", "get-tuple-element", "tuple"):
+                total += len(re.findall(pattern, rest[: kind.start(1)]))
+        return total
+
+    return lowered, produced
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_solar_open2_serve_cell_compiles_for_v5e(v5e, as_on_tpu, program):
+    lowered, produced = _solar_program(v5e, program)
+    compiled = lowered.compile()  # raises what the chip's compiler would: it fits
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    kernels = parse_hlo_kernels(text)
+    assert kernels.get("paged_decode", 0) == (1 if program == "decode" else 0), kernels
+    caches = 2 * 6145 * 8 * 16 * 128 * 2 + 3 * 32 * (64 * 128 * 128 * 4 + 3 * 24576 * 2)
+    assert memory.alias_size_in_bytes >= caches  # pool and slab are written in place
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 12e9
+    counts = {"pool": produced(text, _SOLAR_POOL), "state": produced(text, _SOLAR_STATE)}
+    print(f"solar2-serve-longdoc {program}: produced in the entry computation {counts}, "
+          f"temp {memory.temp_size_in_bytes / 1e9:.2f} GB")
+    assert all(counts[k] <= _SOLAR_PRODUCED[program][k] for k in counts), counts

@@ -326,6 +326,76 @@ def test_lowered_programs_carry_block_scopes_and_their_names(fresh):
     assert "mlp/moe_sort" in decode.as_text(debug_info=True)
 
 
+# ------------------------------------------- a stack with a second cache kind
+
+TINY_SOLAR = dict(
+    vocab_size=64, hidden_size=32, intermediate_size=48, num_hidden_layers=4,
+    num_attention_heads=4, num_key_value_heads=2, head_dim=8,
+    linear_num_heads=2, linear_head_dim=8, n_routed_experts=8, experts_held=4,
+    num_experts_per_tok=2, moe_intermediate_size=16, moe_impl="ragged",
+    attention_impl="xla", compute_dtype="float32", param_dtype="float32",
+)
+
+
+def _solar_engine(**serve):
+    from llm_training_tpu.models import SolarOpen2, SolarOpen2Config
+
+    model = SolarOpen2(SolarOpen2Config(**TINY_SOLAR))
+    variables = jax.jit(model.init)(jax.random.key(0), np.zeros((1, 4), np.int32))
+    return ServingEngine(model, variables, ServeConfig(**{**SERVE, **serve}))
+
+
+def test_linear_attention_stack_names_its_scopes_in_both_programs(fresh):
+    """What the benchmark's per-layer readers match (`/linear_attn/`,
+    `kda_recurrence`, the MoE phases and `moe_shared`), in the lowered
+    programs of a stack that carries the state slab."""
+    engine = _solar_engine()
+    slot = {"slot": jnp.zeros((1,), jnp.int32), "fresh": jnp.ones((1,), bool)}
+    decode = engine._decode_jit.lower(*_decode_args(engine), slab=engine._slab)
+    prefill = engine._prefill_jit.lower(*_prefill_args(engine), slab=engine._slab, **slot)
+    assert "jit_decode_step" in decode.as_text()[:200]
+    assert "jit_prefill_chunk" in prefill.as_text()[:200]
+    shared = (
+        "slot0/self_attn/", "slot1/linear_attn/", "linear_attn/kda_conv", "linear_attn/kda_gates",
+        "mlp/moe_route", "mlp/moe_sort", "mlp/moe_gather", "mlp/moe_experts",
+        "mlp/moe_scatter", "mlp/moe_shared", "/sample",
+    )
+    for lowered, own, other in (
+        (decode, "linear_attn/kda_recurrence", "kda_chunk"),
+        (prefill, "linear_attn/kda_chunk", "kda_recurrence"),
+    ):
+        text = lowered.as_text(debug_info=True)
+        for scope in shared + (own,):
+            assert scope in text, scope
+        assert other not in text
+
+
+def test_state_slab_reports_its_bytes_slots_and_resets(fresh):
+    engine = _solar_engine()
+    engine.run(_requests(6))
+    registry = get_registry()
+    slab_bytes = 3 * 2 * (2 * 8 * 8 * 4 + 3 * 48 * 4)  # 3 KDA layers, 2 slots: state + conv tail
+    assert registry.gauge("decode/state_bytes").value == slab_bytes
+    assert engine.stats()["decode/state_bytes"] == slab_bytes
+    assert registry.gauge("decode/state_slots_in_use").value == 0  # drained
+    # three requests through two slots, no eviction: one first chunk each
+    assert registry.counter("serve/state_resets").value == 3
+    steps = [e["args"] for e in fresh.snapshot() if e.get("ph") == "X" and e["name"] == "engine_step"]
+    assert sum(a["state_resets"] for a in steps) == 3
+    assert max(a["state_slots_in_use"] for a in steps) == 2
+    # a stack without linear-attention layers reports none of it
+    llama_steps = []
+    plain = _engine()
+    plain.run(_requests(2))
+    llama_steps = [
+        e["args"] for e in fresh.snapshot() if e.get("ph") == "X" and e["name"] == "engine_step"
+    ][len(steps):]
+    assert llama_steps and all("state_resets" not in a for a in llama_steps)
+    assert plain._slab is None and plain.stats()["decode/state_bytes"] == 0
+    engine.close()
+    assert engine._slab is None and engine._pool_k is None
+
+
 def test_lowered_train_loss_carries_loss_ce():
     from llm_training_tpu.ops.cross_entropy import fused_linear_cross_entropy
 
