@@ -39,6 +39,7 @@ from llm_training_tpu.models.base import (
 )
 from llm_training_tpu.models.remat import remat_policy as _remat_policy
 from llm_training_tpu.models.gemma.config import GemmaConfig
+from llm_training_tpu.models.llama.model import cached_attention
 from llm_training_tpu.ops import apply_rope, dot_product_attention
 from llm_training_tpu.ops.rope_utils import compute_rope_cos_sin, compute_rope_frequencies
 
@@ -79,16 +80,16 @@ def _dense(config: GemmaConfig, features: int, logical_axes: tuple[str, str], na
 
 
 class GemmaAttention(nn.Module):
-    """KV-cache args (`layer_kv`/`kv_index`/`kv_segment_ids`) follow the
-    shared-stack convention — see `llama/model.py:LlamaAttention`; with a
-    cache the call returns `(out, new_layer_kv)`."""
+    """KV-cache args (`layer_kv`/`kv_index`/`kv_segment_ids`/`layer`) follow
+    the shared-stack convention — see `llama/model.py:cached_attention`;
+    with a cache the call returns `(out, new_layer_kv)`."""
 
     config: GemmaConfig
     sliding_window: int | None
 
     @nn.compact
     def __call__(self, hidden, segment_ids, cos, sin,
-                 layer_kv=None, kv_index=None, kv_segment_ids=None):
+                 layer_kv=None, kv_index=None, kv_segment_ids=None, layer=None):
         cfg = self.config
         batch, seq, _ = hidden.shape
         q = _dense(cfg, cfg.num_attention_heads * cfg.head_dim, ("embed", "heads"), "q_proj")(hidden)
@@ -102,46 +103,17 @@ class GemmaAttention(nn.Module):
             q = GemmaRMSNorm(cfg.rms_norm_eps, cfg.param_jnp_dtype, name="q_norm")(q)
             k = GemmaRMSNorm(cfg.rms_norm_eps, cfg.param_jnp_dtype, name="k_norm")(k)
         q, k = apply_rope(q, k, cos, sin)
-        if layer_kv is not None and kv_index.ndim == 1:
-            # paged cache (serve/): kv_index = per-row lengths,
-            # kv_segment_ids = block table — see LlamaAttention
-            from llm_training_tpu.ops.paged_attention import paged_cached_attention
-
-            out, new_kv = paged_cached_attention(
-                q, k, v, layer_kv, kv_index, kv_segment_ids,
-                segment_ids=segment_ids,
-                sliding_window=self.sliding_window,
-                logits_soft_cap=cfg.attn_logit_softcapping,
+        if layer_kv is not None:
+            out, new_kv = cached_attention(
+                q, k, v, segment_ids, layer_kv, kv_index, kv_segment_ids, layer,
+                window=self.sliding_window,
                 scale=cfg.attention_scale,
+                logits_soft_cap=cfg.attn_logit_softcapping,
             )
             out = out.astype(hidden.dtype).reshape(
                 batch, seq, cfg.num_attention_heads * cfg.head_dim
             )
             return _dense(cfg, cfg.hidden_size, ("heads", "embed"), "o_proj")(out), new_kv
-        if layer_kv is not None:
-            ck, cv = layer_kv
-            ck = jax.lax.dynamic_update_slice(
-                ck, k.astype(ck.dtype), (0, kv_index, 0, 0)
-            )
-            cv = jax.lax.dynamic_update_slice(
-                cv, v.astype(cv.dtype), (0, kv_index, 0, 0)
-            )
-            out = dot_product_attention(
-                q, ck.astype(k.dtype), cv.astype(v.dtype),
-                segment_ids=kv_segment_ids,
-                q_segment_ids=segment_ids,
-                causal=True,
-                sliding_window=self.sliding_window,
-                logits_soft_cap=cfg.attn_logit_softcapping,
-                scale=cfg.attention_scale,
-                q_offset=kv_index,
-                impl="xla",
-            )
-            out = out.astype(hidden.dtype).reshape(
-                batch, seq, cfg.num_attention_heads * cfg.head_dim
-            )
-            out = _dense(cfg, cfg.hidden_size, ("heads", "embed"), "o_proj")(out)
-            return out, (ck, cv)
         out = None
         if getattr(cfg, "ring_attention", False):
             from llm_training_tpu.parallel.ring_attention import (
@@ -188,14 +160,14 @@ class GemmaDecoderLayer(nn.Module):
 
     @nn.compact
     def __call__(self, hidden, segment_ids, cos, sin,
-                 layer_kv=None, kv_index=None, kv_segment_ids=None):
+                 layer_kv=None, kv_index=None, kv_segment_ids=None, layer=None):
         cfg = self.config
         hidden = nn.with_logical_constraint(hidden, ("batch", "act_seq", "act_embed"))
         norm = lambda name: GemmaRMSNorm(cfg.rms_norm_eps, cfg.param_jnp_dtype, name=name)
 
         attn_in = norm("input_layernorm")(hidden)
         attn_out = GemmaAttention(cfg, self.sliding_window, name="self_attn")(
-            attn_in, segment_ids, cos, sin, layer_kv, kv_index, kv_segment_ids
+            attn_in, segment_ids, cos, sin, layer_kv, kv_index, kv_segment_ids, layer
         )
         new_kv = None
         if layer_kv is not None:
@@ -217,29 +189,31 @@ class GemmaDecoderLayer(nn.Module):
 
 class _ScannedBody(nn.Module):
     """Scan body: one layer (gemma 1 / windowless gemma 2) or a
-    (sliding, full) pair (gemma 2 with sliding_window). ys is the updated
-    KV slice when decoding, else None."""
+    (sliding, full) pair (gemma 2 with sliding_window). The carry is
+    `hidden` or, when decoding, `(hidden, the whole stack's KV cache)` with
+    the layer's index as the scanned input, as in the Llama stack."""
 
     config: GemmaConfig
 
     @nn.compact
-    def __call__(self, hidden, segment_ids, cos, sin,
-                 layer_kv=None, kv_index=None, kv_segment_ids=None):
+    def __call__(self, carry, segment_ids, cos, sin,
+                 layer=None, kv_index=None, kv_segment_ids=None):
         cfg = self.config
         if cfg.version == 2 and cfg.sliding_window:
             hidden = GemmaDecoderLayer(cfg, cfg.sliding_window, name="sliding")(
-                hidden, segment_ids, cos, sin
+                carry, segment_ids, cos, sin
             )
             hidden = GemmaDecoderLayer(cfg, None, name="full")(
                 hidden, segment_ids, cos, sin
             )
             return hidden, None
-        out = GemmaDecoderLayer(cfg, None, name="layer")(
-            hidden, segment_ids, cos, sin, layer_kv, kv_index, kv_segment_ids
-        )
-        if layer_kv is not None:
-            return out  # (hidden, new_kv)
-        return out, None
+        block = GemmaDecoderLayer(cfg, None, name="layer")
+        if layer is None:
+            return block(carry, segment_ids, cos, sin), None
+        hidden, decode_kv = carry
+        return block(
+            hidden, segment_ids, cos, sin, decode_kv, kv_index, kv_segment_ids, layer
+        ), None
 
 
 
@@ -286,12 +260,13 @@ class Gemma(nn.Module):
                     length=length,
                     metadata_params={nn.PARTITION_NAME: "layers"},
                 )(cfg, name="layers")
-                hidden, new_kv = scanned(
-                    hidden, segment_ids, cos, sin, decode_kv, kv_index,
-                    kv_segment_ids,
+                # the cache is carried, the layer's index scanned over
+                (hidden, new_kv), _ = scanned(
+                    (hidden, decode_kv), segment_ids, cos, sin,
+                    jnp.arange(length, dtype=jnp.int32), kv_index, kv_segment_ids,
                 )
             return hidden, new_kv
-        kv_slices = []
+        new_kv = decode_kv
         for i in range(cfg.num_hidden_layers):
             layer_cls = GemmaDecoderLayer
             if policy is not None:
@@ -301,18 +276,12 @@ class Gemma(nn.Module):
             lcos, lsin = (
                 (cos_local, sin_local) if cfg.version == 3 and window else (cos, sin)
             )
-            layer_kv = (
-                None if decode_kv is None
-                else jax.tree.map(lambda a: a[i], decode_kv)
-            )
             hidden = layer_cls(
                 cfg, window, name=f"layers_{i}"
-            )(hidden, segment_ids, lcos, lsin, layer_kv, kv_index, kv_segment_ids)
+            )(hidden, segment_ids, lcos, lsin, new_kv, kv_index, kv_segment_ids,
+              None if decode_kv is None else i)
             if decode_kv is not None:
-                hidden, layer_new_kv = hidden
-                kv_slices.append(layer_new_kv)
-        if kv_slices:
-            new_kv = jax.tree.map(lambda *xs: jnp.stack(xs), *kv_slices)
+                hidden, new_kv = hidden
         return hidden, new_kv
 
     @nn.compact

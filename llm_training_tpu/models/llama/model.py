@@ -139,39 +139,55 @@ def _dense(config: LlamaConfig, features: int, logical_axes: tuple[str, str], na
 
 
 def cached_attention(q, k, v, segment_ids, layer_kv, kv_index, kv_segment_ids,
-                     window=None, scale=None):
-    """Append this chunk's k/v at `kv_index` and attend q against the
-    full static-shape cache. The causal term of the mask (q_offset =
-    kv_index) hides slots written after this chunk, and `kv_segment_ids`
-    (0 on unwritten/pad slots) hides garbage — so ONE program serves
-    both prefill (chunk at index 0) and single-token decode steps.
-    Dense-cache attention is always the XLA einsum path: the flash
+                     layer, window=None, scale=None, logits_soft_cap=None):
+    """Append this chunk's k/v to layer `layer` of the whole stack's cache
+    and attend q against that layer's cache; returns `(out, the stack's new
+    cache)`. `layer_kv` is the `(k, v)` pair of EVERY layer, leading axis
+    over layers, as the layer loop carries it (`carry_layers`): a layer
+    writes its new rows into the carried buffers in place and reads its own
+    part of them; its slice is never cut out and put back. `layer` is the
+    loop's index, traced under a scan, a Python int in a Python loop.
+
+    Dense (`DecodeState`: `[L, B, max_length, kv_heads, head_dim]`): the
+    chunk goes in at the shared `kv_index`. The causal term of the mask
+    (q_offset = kv_index) hides slots written after this chunk, and
+    `kv_segment_ids` (0 on unwritten/pad slots) hides garbage — so ONE
+    program serves both prefill (chunk at index 0) and single-token decode
+    steps. Dense-cache attention is always the XLA einsum path: the flash
     kernel's block tiling assumes q_len ≥ a block and a static q_offset.
 
-    A PAGED cache (`PagedDecodeState`, serve/ subsystem) arrives through
-    the same plumbing with per-ROW lengths in `kv_index` ([B], vs the
-    dense scalar) and the block table in `kv_segment_ids` — dispatched
-    to `ops.paged_attention` (ragged Pallas decode kernel on TPU, XLA
-    gather fallback elsewhere). Shared by every softmax-attention module
-    that decodes (`LlamaAttention`, `solar_open2.GatedAttention`)."""
+    A PAGED cache (`PagedDecodeState`, serve/ subsystem: `[L, blocks,
+    kv_heads, page, head_dim]`) arrives through the same plumbing with
+    per-ROW lengths in `kv_index` ([B], vs the dense scalar) and the block
+    table in `kv_segment_ids` — dispatched to `ops.paged_attention` (page
+    writer and ragged Pallas decode kernel on TPU, XLA elsewhere). Shared by
+    every softmax-attention module that decodes (`LlamaAttention`,
+    `GemmaAttention`, `solar_open2.GatedAttention`)."""
     if kv_index.ndim == 1:
         from llm_training_tpu.ops.paged_attention import paged_cached_attention
 
         return paged_cached_attention(
             q, k, v, layer_kv, kv_index, kv_segment_ids,
+            layer=layer,
             segment_ids=segment_ids,
             sliding_window=window,
+            logits_soft_cap=logits_soft_cap,
             scale=scale,
         )
-    ck, cv = layer_kv
-    ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype), (0, kv_index, 0, 0))
-    cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype), (0, kv_index, 0, 0))
+    ck, cv = (
+        jax.lax.dynamic_update_slice(
+            cache, new[None].astype(cache.dtype), (layer, 0, kv_index, 0, 0)
+        )
+        for cache, new in zip(layer_kv, (k, v))
+    )
+    mine = lambda cache: jax.lax.dynamic_index_in_dim(cache, layer, keepdims=False)
     out = dot_product_attention(
-        q, ck.astype(k.dtype), cv.astype(v.dtype),
+        q, mine(ck).astype(k.dtype), mine(cv).astype(v.dtype),
         segment_ids=kv_segment_ids,
         q_segment_ids=segment_ids,
         causal=True,
         sliding_window=window,
+        logits_soft_cap=logits_soft_cap,
         scale=scale,
         q_offset=kv_index,
         impl="xla",
@@ -194,12 +210,13 @@ class LlamaAttention(nn.Module):
     carry `sliding_window` and `attention_compute_dtype` (Phi-3's SDPA
     upcast workaround, `phi3_model.py:172-187`).
 
-    KV-cache decoding (docs/inference.md): `layer_kv` is this layer's
-    `(k, v)` cache buffers `[batch, max_length, kv_heads, head_dim]`;
-    `kv_index` the shared append position and `kv_segment_ids` the cache's
-    filled-slot ids (already including the incoming chunk). When given, the
-    post-RoPE k/v are appended at `kv_index` and attention runs against the
-    whole cache with `q_offset = kv_index`, and the call returns
+    KV-cache decoding (docs/inference.md): `layer_kv` is the WHOLE stack's
+    `(k, v)` cache buffers `[layers, batch, max_length, kv_heads, head_dim]`
+    and `layer` this layer's index in them (`cached_attention`); `kv_index`
+    the shared append position and `kv_segment_ids` the cache's filled-slot
+    ids (already including the incoming chunk). When given, the post-RoPE
+    k/v are appended at `kv_index` of this layer's part and attention runs
+    against that part with `q_offset = kv_index`, and the call returns
     `(out, new_layer_kv)` instead of `out`."""
 
     config: LlamaConfig
@@ -215,6 +232,7 @@ class LlamaAttention(nn.Module):
         layer_kv: tuple[jnp.ndarray, jnp.ndarray] | None = None,
         kv_index: jnp.ndarray | None = None,
         kv_segment_ids: jnp.ndarray | None = None,
+        layer: jnp.ndarray | int | None = None,
     ) -> jnp.ndarray:
         cfg = self.config
         head_dim = cfg.resolved_head_dim
@@ -296,7 +314,7 @@ class LlamaAttention(nn.Module):
         new_layer_kv = None
         if layer_kv is not None:
             out, new_layer_kv = self._cached_attention(
-                q, k, v, segment_ids, layer_kv, kv_index, kv_segment_ids
+                q, k, v, segment_ids, layer_kv, kv_index, kv_segment_ids, layer
             )
         else:
             out = self._attention(q, k, v, segment_ids)
@@ -307,7 +325,8 @@ class LlamaAttention(nn.Module):
             return out, new_layer_kv
         return out
 
-    def _cached_attention(self, q, k, v, segment_ids, layer_kv, kv_index, kv_segment_ids):
+    def _cached_attention(self, q, k, v, segment_ids, layer_kv, kv_index,
+                          kv_segment_ids, layer):
         cfg = self.config
         window = (
             getattr(cfg, "sliding_window", None)
@@ -315,7 +334,7 @@ class LlamaAttention(nn.Module):
             else self.sliding_window_override
         )
         return cached_attention(
-            q, k, v, segment_ids, layer_kv, kv_index, kv_segment_ids,
+            q, k, v, segment_ids, layer_kv, kv_index, kv_segment_ids, layer,
             window=window, scale=getattr(cfg, "attention_multiplier", None),
         )
 
@@ -431,6 +450,7 @@ class LlamaDecoderLayer(nn.Module):
         layer_kv: tuple[jnp.ndarray, jnp.ndarray] | None = None,
         kv_index: jnp.ndarray | None = None,
         kv_segment_ids: jnp.ndarray | None = None,
+        layer: jnp.ndarray | int | None = None,
     ) -> jnp.ndarray:
         cfg = self.config
         hidden = nn.with_logical_constraint(hidden, ("batch", "act_seq", "act_embed"))
@@ -445,7 +465,7 @@ class LlamaDecoderLayer(nn.Module):
                 if layer_kv is None:
                     return module(x, seg, c, s)
                 out, new_kv[0] = module(
-                    x, seg, c, s, layer_kv, kv_index, kv_segment_ids
+                    x, seg, c, s, layer_kv, kv_index, kv_segment_ids, layer
                 )
                 return out
 
@@ -517,21 +537,25 @@ class LlamaDecoderLayer(nn.Module):
 
 class _ScannedLayer(nn.Module):
     """Adapter giving LlamaDecoderLayer the (carry, xs) -> (carry, ys)
-    signature nn.scan expects; ys carries the per-layer MoE aux loss (and,
-    when decoding, this layer's updated KV-cache slice)."""
+    signature nn.scan expects; ys carries the per-layer MoE aux loss. The
+    carry is `hidden` or, when decoding, `(hidden, the whole stack's KV
+    cache)` with the layer's index `layer` as the scanned input: the cache
+    rides the loop and each layer writes its new rows into it in place."""
 
     config: LlamaConfig
     layer_cls: type
 
     @nn.compact
-    def __call__(self, hidden, segment_ids, cos, sin,
-                 layer_kv=None, kv_index=None, kv_segment_ids=None):
-        hidden, ys = self.layer_cls(self.config, name="layer")(
-            hidden, segment_ids, cos, sin, layer_kv, kv_index, kv_segment_ids
+    def __call__(self, carry, segment_ids, cos, sin,
+                 layer=None, kv_index=None, kv_segment_ids=None):
+        block = self.layer_cls(self.config, name="layer")
+        if layer is None:
+            return block(carry, segment_ids, cos, sin)
+        hidden, decode_kv = carry
+        hidden, (aux, decode_kv) = block(
+            hidden, segment_ids, cos, sin, decode_kv, kv_index, kv_segment_ids, layer
         )
-        return hidden, ys
-
-
+        return (hidden, decode_kv), aux
 
 
 class Llama(nn.Module):
@@ -556,8 +580,9 @@ class Llama(nn.Module):
         (None for dense configs).
 
         `decode_kv` is the whole-stack KV cache `(k, v)` with leading layer
-        axis; each layer consumes/produces its slice (the scan axis under
-        scan_layers, an indexed axis on the looped path). `new_kv` is the
+        axis. It rides the layer loop as its carry (beside `hidden` under
+        scan_layers, a Python variable on the looped path) and every layer
+        updates its own part in place (`cached_attention`). `new_kv` is the
         updated stack (None on the training path)."""
         cfg = self.config
         policy = _remat_policy(cfg)
@@ -598,8 +623,9 @@ class Llama(nn.Module):
                 )(cfg, LlamaDecoderLayer, name="layers")
                 hidden, aux = scanned(hidden, segment_ids, cos, sin)
             else:
-                # the cache's layer axis IS the scan axis: each step consumes
-                # its [B, S, H, D] slice and emits the updated slice as ys
+                # the cache is CARRIED and the layer's index scanned over: as
+                # a scanned input and output each step would cut its slice
+                # out of the stack and write a whole slice into a new one
                 # (same param scope as the training-path scan above — only
                 # one of the two traces per call)
                 scanned = nn.scan(
@@ -611,11 +637,11 @@ class Llama(nn.Module):
                     length=cfg.num_hidden_layers,
                     metadata_params={nn.PARTITION_NAME: "layers"},
                 )(cfg, LlamaDecoderLayer, name="layers")
-                hidden, ys = scanned(
-                    hidden, segment_ids, cos, sin, decode_kv, kv_index,
+                (hidden, new_kv), aux = scanned(
+                    (hidden, decode_kv), segment_ids, cos, sin,
+                    jnp.arange(cfg.num_hidden_layers, dtype=jnp.int32), kv_index,
                     kv_segment_ids,
                 )
-                aux, new_kv = ys
         else:
             no_rope = getattr(cfg, "no_rope_layers", None)
             if no_rope is not None and cos is not None:
@@ -625,7 +651,7 @@ class Llama(nn.Module):
                 id_sin = jnp.zeros_like(sin)
             layer_types = getattr(cfg, "layer_types", None)
             stats = []
-            kv_slices = []
+            new_kv = decode_kv
             for i in range(cfg.num_hidden_layers):
                 layer_cls = LlamaDecoderLayer
                 if policy is not None:
@@ -641,21 +667,14 @@ class Llama(nn.Module):
                 elif layer_types is not None and window and local_cos is not None:
                     # OLMo-3: sliding layers rotate with the UNSCALED tables
                     lcos, lsin = local_cos, local_sin
-                layer_kv = (
-                    None if decode_kv is None
-                    else jax.tree.map(lambda a: a[i], decode_kv)
-                )
                 hidden, layer_ys = layer_cls(cfg, window, name=f"layers_{i}")(
-                    hidden, segment_ids, lcos, lsin, layer_kv, kv_index,
-                    kv_segment_ids,
+                    hidden, segment_ids, lcos, lsin, new_kv, kv_index,
+                    kv_segment_ids, None if decode_kv is None else i,
                 )
                 if decode_kv is not None:
-                    layer_ys, layer_new_kv = layer_ys
-                    kv_slices.append(layer_new_kv)
+                    layer_ys, new_kv = layer_ys
                 stats.append(layer_ys)
             aux = jax.tree.map(lambda *xs: jnp.stack(xs), *stats)
-            if kv_slices:
-                new_kv = jax.tree.map(lambda *xs: jnp.stack(xs), *kv_slices)
         if not cfg.num_experts:
             return hidden, jnp.float32(0.0), jnp.float32(0.0), None, new_kv
         sel_frac, mean_prob, dropped = aux  # [L, E], [L, E], [L]

@@ -25,7 +25,9 @@ chunked rule from the slot's state to the slot's state. Positions with
 segment id 0 (padding, idle decode slots) change nothing.
 
 The stack scans over periods of the layer pattern ([GQA, KDA, KDA, KDA] as
-published), the pool's and the slab's leading axes split to match.
+published). When decoding, the pool and the slab ride that loop as its carry
+beside `hidden`, whole: a layer writes its new rows into them in place
+(`llama.model.cached_attention`, `_put_rows`) and reads its own part.
 """
 
 from __future__ import annotations
@@ -165,14 +167,15 @@ class KimiDeltaAttention(nn.Module):
 
 class GatedAttention(nn.Module):
     """Softmax attention with no positional term, gated an output channel.
-    `cache`, `kv_index`, `kv_segment_ids`: as `LlamaAttention`'s `layer_kv`
-    plumbing (`llama.model.cached_attention`), dense or paged."""
+    `cache`, `kv_index`, `kv_segment_ids`, `layer`: as `LlamaAttention`'s
+    `layer_kv` plumbing (`llama.model.cached_attention`), dense or paged:
+    the cache of every GQA layer, and this layer's index in it."""
 
     config: SolarOpen2Config
 
     @nn.compact
     def __call__(self, hidden, segment_ids=None, cache=None, kv_index=None,
-                 kv_segment_ids=None):
+                 kv_segment_ids=None, layer=None):
         cfg = self.config
         batch, seq, _ = hidden.shape
         heads, kv_heads, dim = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
@@ -185,7 +188,7 @@ class GatedAttention(nn.Module):
         new_cache = None
         if cache is not None:
             out, new_cache = cached_attention(
-                q, k, v, segment_ids, cache, kv_index, kv_segment_ids
+                q, k, v, segment_ids, cache, kv_index, kv_segment_ids, layer
             )
         else:
             out = dot_product_attention(
@@ -202,21 +205,23 @@ class GatedAttention(nn.Module):
 
 
 class SolarOpen2DecoderLayer(nn.Module):
-    """Returns (hidden, router stats, this layer's new cache or None)."""
+    """Returns (hidden, router stats, new cache or None). `cache` is, for a
+    GQA layer, the pool (or dense buffers) of every GQA layer with `layer`
+    this one's index in it; for a KDA layer, its rows of the slab."""
 
     config: SolarOpen2Config
     is_gqa: bool
 
     @nn.compact
     def __call__(self, hidden, segment_ids=None, cache=None, kv_index=None,
-                 kv_segment_ids=None):
+                 kv_segment_ids=None, layer=None):
         cfg = self.config
         hidden = nn.with_logical_constraint(hidden, ("batch", "act_seq", "act_embed"))
         norm = lambda name: RMSNorm(cfg.rms_norm_eps, cfg.param_jnp_dtype, name=name)
         normed = norm("input_layernorm")(hidden)
         if self.is_gqa:
             mixed = GatedAttention(cfg, name="self_attn")(
-                normed, segment_ids, cache, kv_index, kv_segment_ids
+                normed, segment_ids, cache, kv_index, kv_segment_ids, layer
             )
         else:
             mixed = KimiDeltaAttention(cfg, name="linear_attn")(normed, segment_ids, cache)
@@ -240,44 +245,71 @@ def _slot_rows(slab, slots, fresh):
     return rows
 
 
+def _layer_rows(slab, layer, slots, fresh):
+    """`_slot_rows` of layer `layer` of the whole slab `[layers, slots, ...]`.
+    Picked slots are gathered out of the slab seen as one run of `layers *
+    slots` rows, so the layer's part is not cut out first."""
+    if slots is None:
+        mine = jax.lax.dynamic_index_in_dim(slab, layer, keepdims=False)
+        return _slot_rows(mine, None, fresh)
+    flat = slab.reshape(-1, *slab.shape[2:])
+    return _slot_rows(flat, layer * slab.shape[1] + slots, fresh)
+
+
+def _put_rows(slab, layer, slots, rows):
+    """The carried slab `[layers, slots, ...]` with layer `layer`'s rows for
+    this batch replaced, in place: the slab is addressed as one run of
+    `layers * slots` rows, not cut up and restacked."""
+    per_layer = slab.shape[1]
+    flat = slab.reshape(-1, *slab.shape[2:])
+    if slots is None:
+        flat = jax.lax.dynamic_update_slice_in_dim(flat, rows, layer * per_layer, axis=0)
+    else:
+        flat = flat.at[layer * per_layer + slots].set(rows)
+    return flat.reshape(slab.shape)
+
+
 class _PeriodBody(nn.Module):
     """Scan body: the layers `first .. first + len(kinds)` of the pattern
-    (one period). `caches` is None or `(pool, slab)` for the period's layers:
-    pool `(k, v)` with a leading axis over its GQA layers, slab `(state,
-    tail)` over its KDA layers; `ctx` holds `kv_index`, `kv_segment_ids` and,
-    for a paged slab, `slots` and `fresh`."""
+    (one period). The carry is `hidden` or, when decoding, `(hidden, pool,
+    slab)`: pool `(k, v)` with a leading axis over ALL the stack's GQA
+    layers, slab `(state, tail)` over all its KDA layers, and `cycle` says
+    which period of them this is. `ctx` holds `kv_index`, `kv_segment_ids`
+    and, for a paged slab, `slots` and `fresh`."""
 
     config: SolarOpen2Config
     kinds: tuple[bool, ...]
 
     @nn.compact
-    def __call__(self, hidden, segment_ids, ctx=None, caches=None):
+    def __call__(self, carry, segment_ids, ctx=None, cycle=None):
         cfg = self.config
         ctx = ctx or {}
         slots, fresh = ctx.get("slots"), ctx.get("fresh")
-        stats, new_pool, new_slab = [], [], []
+        hidden, pool, slab = (carry, None, None) if cycle is None else carry
+        stats = []
         for j, is_gqa in enumerate(self.kinds):
             layer = SolarOpen2DecoderLayer(cfg, is_gqa, name=f"slot{j}")
-            if caches is None:
+            if cycle is None:
                 hidden, layer_stats, _ = layer(hidden, segment_ids)
-            elif is_gqa:
-                mine = jax.tree.map(lambda a: a[len(new_pool)], caches[0])
-                hidden, layer_stats, new = layer(
-                    hidden, segment_ids, mine, ctx["kv_index"], ctx["kv_segment_ids"]
+                stats.append(layer_stats)
+                continue
+            # this layer's index among the stack's layers of its kind
+            index = cycle * self.kinds.count(is_gqa) + self.kinds[:j].count(is_gqa)
+            if is_gqa:
+                hidden, layer_stats, pool = layer(
+                    hidden, segment_ids, pool, ctx["kv_index"], ctx["kv_segment_ids"], index
                 )
-                new_pool.append(new)
             else:
-                mine = jax.tree.map(lambda a: a[len(new_slab)], caches[1])
-                rows = jax.tree.map(lambda a: _slot_rows(a, slots, fresh), mine)
+                rows = jax.tree.map(lambda a: _layer_rows(a, index, slots, fresh), slab)
                 hidden, layer_stats, new = layer(hidden, segment_ids, rows)
-                if slots is not None:
-                    new = jax.tree.map(lambda a, n: a.at[slots].set(n), mine, new)
-                new_slab.append(new)
+                # the new rows are whole before they go in: fused into the
+                # update, their computation reads the slab it writes, and the
+                # compiler then copies the whole slab first, once a layer
+                new = jax.lax.optimization_barrier(new)
+                slab = jax.tree.map(lambda a, n: _put_rows(a, index, slots, n), slab, new)
             stats.append(layer_stats)
-        stack = lambda xs: jax.tree.map(lambda *leaves: jnp.stack(leaves), *xs)
-        if caches is None:
-            return hidden, stack(stats)
-        return hidden, (stack(stats), stack(new_pool), stack(new_slab))
+        stats = jax.tree.map(lambda *leaves: jnp.stack(leaves), *stats)
+        return (hidden if cycle is None else (hidden, pool, slab)), stats
 
 
 class SolarOpen2(nn.Module):
@@ -296,30 +328,29 @@ class SolarOpen2(nn.Module):
         policy = _remat_policy(cfg)
         if policy is not None:
             body = nn.remat(_PeriodBody, policy=policy, prevent_cse=False)
-        split = lambda a: a.reshape(cycles, a.shape[0] // cycles, *a.shape[1:])
+        carry = hidden if caches is None else (hidden, *caches)
         if not cfg.scan_period:
             # the loop: the whole stack is one body, under the scan's names
-            out = body(cfg, tuple(kinds), name="layers")(hidden, segment_ids, ctx, caches)
-            hidden, ys = out
-            return (hidden, ys, None) if caches is None else (hidden, ys[0], ys[1:])
-        if caches is None:
+            carry, stats = body(cfg, tuple(kinds), name="layers")(
+                carry, segment_ids, ctx, None if caches is None else 0
+            )
+        else:
+            # the caches are CARRIED, whole, and the period's index scanned over
             scanned = nn.scan(
                 body, variable_axes={"params": 0}, split_rngs={"params": True},
-                in_axes=(nn.broadcast,), length=cycles,
-                metadata_params={nn.PARTITION_NAME: "layers"},
+                in_axes=(nn.broadcast,) if caches is None else (nn.broadcast, nn.broadcast, 0),
+                length=cycles, metadata_params={nn.PARTITION_NAME: "layers"},
             )(cfg, tuple(kinds[:period]), name="layers")
-            hidden, stats = scanned(hidden, segment_ids)
-            return hidden, jax.tree.map(lambda x: x.reshape(-1, *x.shape[2:]), stats), None
-        # the caches' layer axes ARE the scan axis: [cycles, layers a period, ...]
-        scanned = nn.scan(
-            body, variable_axes={"params": 0}, split_rngs={"params": True},
-            in_axes=(nn.broadcast, nn.broadcast, 0), length=cycles,
-            metadata_params={nn.PARTITION_NAME: "layers"},
-        )(cfg, tuple(kinds[:period]), name="layers")
-        hidden, ys = scanned(hidden, segment_ids, ctx, jax.tree.map(split, caches))
-        merge = lambda x: x.reshape(-1, *x.shape[2:])
-        stats, pool, slab = jax.tree.map(merge, ys)
-        return hidden, stats, (pool, slab)
+            if caches is None:
+                carry, stats = scanned(carry, segment_ids)
+            else:
+                carry, stats = scanned(
+                    carry, segment_ids, ctx, jnp.arange(cycles, dtype=jnp.int32)
+                )
+            stats = jax.tree.map(lambda x: x.reshape(-1, *x.shape[2:]), stats)
+        if caches is None:
+            return carry, stats, None
+        return carry[0], stats, carry[1:]
 
     @nn.compact
     def __call__(

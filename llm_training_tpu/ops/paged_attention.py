@@ -1,12 +1,23 @@
-"""Paged KV-cache attention: scatter-append + ragged attention dispatch.
+"""Paged KV-cache attention: in-place page append + ragged attention dispatch.
 
 The serving counterpart of the dense cached-attention path in the shared
 decoder stacks (docs/serving.md). The cache is a POOL of fixed-size blocks
 (`[num_blocks, kv_heads, block_size, head_dim]` per layer) owned by
 `serve/paged_cache.py`; each row addresses it through a block table and
-its own length — so this module does per-row scatter writes and per-row
+its own length — so this module does per-row page writes and per-row
 ragged reads where the dense path does one `dynamic_update_slice` at a
 shared index.
+
+The append (`paged_append`) never rewrites the pool: it gathers the few
+pages a chunk's positions lie in, lays the new rows over them and writes
+each page back whole to its own block — on a TPU with the Pallas page
+writer (`ops/pallas/paged_attention.py:write_pages`, pools aliased and left
+in HBM, one copy a page, the layout the decode kernel reads), elsewhere
+with one XLA scatter over whole blocks. A decoder stack carries the pools
+of ALL its layers through its layer loop and passes `layer`: the stack is
+then addressed as one pool of `layers * blocks` blocks through a shifted
+block table (docs/serving.md, "How the cache is carried and appended"), so
+a step produces nothing of a pool's shape.
 
 Two attention paths behind one call:
 
@@ -29,6 +40,33 @@ import jax.numpy as jnp
 from llm_training_tpu.ops.attention import _xla_attention
 
 
+def _chunk_pages(lengths, block_tables, segment_ids, batch, seq, page_size):
+    """Where a chunk of `seq` tokens a row lands, page by page. A chunk that
+    starts anywhere touches at most `T = ceil((seq - 1) / page) + 1` pages of
+    its row (one for a single token). Returns, for row b's t-th touched page:
+    `blocks [B, T]` its pool block, `token [B, T * page]` which chunk token
+    each slot takes (clipped into the chunk) and `valid [B, T, page]` whether
+    it takes one; and `stray [B, seq]`, the chunk positions that have no slot:
+    padded ones (segment id 0) and those past the table."""
+    num_pages = block_tables.shape[1]
+    touched = (seq - 1 + page_size - 1) // page_size + 1
+    logical = (lengths // page_size)[:, None] + jnp.arange(touched, dtype=jnp.int32)
+    pos = logical[:, :, None] * page_size + jnp.arange(page_size, dtype=jnp.int32)
+    token = (pos - lengths[:, None, None]).reshape(batch, touched * page_size)
+    in_chunk = (token >= 0) & (token < seq)
+    token = jnp.clip(token, 0, seq - 1)
+    real = jnp.ones((batch, seq), bool) if segment_ids is None else segment_ids > 0
+    chunk_pos = lengths[:, None] + jnp.arange(seq, dtype=jnp.int32)
+    real &= chunk_pos < num_pages * page_size
+    valid = (in_chunk & jnp.take_along_axis(real, token, axis=1)).reshape(
+        batch, touched, page_size
+    )
+    blocks = jnp.take_along_axis(
+        block_tables, jnp.minimum(logical, num_pages - 1), axis=1
+    )
+    return blocks, token, valid, ~real
+
+
 def paged_append(
     pool_k: jnp.ndarray,
     pool_v: jnp.ndarray,
@@ -37,29 +75,67 @@ def paged_append(
     lengths: jnp.ndarray,
     block_tables: jnp.ndarray,
     segment_ids: jnp.ndarray | None,
+    impl: str = "auto",
+    trash: jnp.ndarray | int = 0,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Scatter this chunk's k/v `[B, S, H, D]` into the pool at each row's
-    next positions (`lengths[b] + i`). Padded chunk positions (segment id
-    0) and any out-of-table position are redirected to the reserved trash
-    block 0 — garbage can land there but never in a live block."""
+    """Write this chunk's k/v `[B, S, H, D]` into the pool at each row's next
+    positions (`lengths[b] + i`), touching only the pages those positions lie
+    in: each such page is read, the chunk's rows are laid over it, and the
+    page is written back whole to its own block, in the pool's own layout
+    (`_write_pages`: one copy a page on a TPU, in place on a donated or
+    carried pool). Nothing of the pool's shape is produced on the way, and
+    the chunk may start anywhere in a page. Padded chunk positions (segment
+    id 0) and any out-of-table position are redirected to slot 0 of the
+    reserved trash block `trash` — garbage can land there but never in a
+    live block."""
     batch, seq = k.shape[:2]
-    page_size = pool_k.shape[2]
-    num_pages = block_tables.shape[1]
-    pos = lengths[:, None] + jnp.arange(seq, dtype=jnp.int32)[None, :]  # [B, S]
-    valid = (
-        jnp.ones((batch, seq), bool) if segment_ids is None else segment_ids > 0
+    _, kv_heads, page_size, head_dim = pool_k.shape
+    blocks, token, valid, stray = _chunk_pages(
+        lengths, block_tables, segment_ids, batch, seq, page_size
     )
-    valid &= pos < num_pages * page_size
-    page = jnp.take_along_axis(
-        block_tables, jnp.minimum(pos // page_size, num_pages - 1), axis=1
+    # one more page for the strays: the trash block with one of them on slot 0
+    blocks = jnp.append(blocks.reshape(-1), jnp.asarray(trash, blocks.dtype))
+    live = jnp.append(valid.any(axis=-1).reshape(-1), stray.any())
+    a_stray = jnp.argmax(stray.reshape(-1))
+    first_slot = jnp.arange(page_size) == 0
+
+    def pages(pool, x):
+        # the chunk's rows in page shape [B * T, H, page, D], over what the
+        # touched pages hold
+        rows = (
+            jnp.broadcast_to(x, (batch, token.shape[1], kv_heads, head_dim))
+            if seq == 1 else jnp.take_along_axis(x, token[:, :, None, None], axis=1)
+        )
+        rows = rows.reshape(-1, page_size, kv_heads, head_dim).swapaxes(1, 2)
+        rows = jnp.append(
+            rows, jnp.broadcast_to(
+                x.reshape(-1, kv_heads, 1, head_dim)[a_stray], (1, *rows.shape[1:])
+            ), axis=0,
+        )
+        take = jnp.append(valid.reshape(-1, page_size), first_slot[None], axis=0)
+        return jnp.where(take[:, None, :, None], rows.astype(pool.dtype), pool[blocks])
+
+    return _write_pages(
+        pool_k, pool_v, pages(pool_k, k), pages(pool_v, v), blocks, live, impl
     )
-    page = jnp.where(valid, page, 0)
-    offset = jnp.where(valid, pos % page_size, 0)
-    # advanced indices split by the head slice: the [B, S] index dims lead
-    # the update window, which is k/v's own [B, S, H, D]
+
+
+def _write_pages(pool_k, pool_v, pages_k, pages_v, blocks, live, impl):
+    """`pool[blocks[i]] = pages[i]` for every live i, for K and for V. On a
+    TPU (or `impl='pallas'`) the Pallas page writer, whose pools are aliased
+    to its outputs and never leave HBM; elsewhere one XLA scatter over whole
+    blocks, rows that are not live dropped."""
+    if impl == "pallas" or (impl == "auto" and jax.default_backend() == "tpu"):
+        from llm_training_tpu.ops.pallas.paged_attention import write_pages
+
+        return _over_heads(
+            write_pages, (pool_k, pool_v, pages_k, pages_v, blocks, live),
+            (1, 1, 1, 1, None, None), (0, 1),
+        )
+    at = jnp.where(live, blocks, pool_k.shape[0])  # out of range: dropped
     return (
-        pool_k.at[page, :, offset].set(k.astype(pool_k.dtype)),
-        pool_v.at[page, :, offset].set(v.astype(pool_v.dtype)),
+        pool_k.at[at].set(pages_k, mode="drop"),
+        pool_v.at[at].set(pages_v, mode="drop"),
     )
 
 
@@ -96,38 +172,38 @@ def _gather_attention(
     )
 
 
-def _kernel_under_mesh(q, pool_k, pool_v, block_tables, lengths, **kwargs):
-    """The paged-decode kernel on whatever mesh is active. Like the flash
-    kernel (`ops/attention.py:_flash_under_mesh`), a Mosaic kernel cannot be
+def _over_heads(fn, args, head_axes, like):
+    """A Mosaic call on whatever mesh is active. Like the flash kernel
+    (`ops/attention.py:_flash_under_mesh`), a Mosaic kernel cannot be
     partitioned by GSPMD, so on a multi-device mesh it runs in a shard_map:
     heads over `tensor` — how the pool's kv heads and q's heads are already
-    sharded — and every other axis replicated, as the pool's block axis is
-    (each data-parallel rank owns its whole pool)."""
-    from llm_training_tpu.ops.pallas.paged_attention import paged_decode_attention
+    sharded, so neither kernel gathers them — and every other axis
+    replicated, as the pool's block axis is (each data-parallel rank owns
+    its whole pool). `head_axes[i]` is the axis of `args[i]` that counts
+    heads (None: no such axis); the outputs are sharded as the args that
+    `like` indexes are."""
     from llm_training_tpu.parallel.mesh import TENSOR_AXIS, active_mesh
 
     mesh = active_mesh()
     if mesh is None or mesh.size == 1:
-        return paged_decode_attention(
-            q, pool_k, pool_v, block_tables, lengths, **kwargs
-        )
+        return fn(*args)
     from jax.sharding import PartitionSpec as P
 
     tp = mesh.shape[TENSOR_AXIS]
-    head_axis = (
-        TENSOR_AXIS if q.shape[1] % tp == 0 and pool_k.shape[1] % tp == 0 else None
+    split = all(
+        arg.shape[axis] % tp == 0
+        for arg, axis in zip(args, head_axes) if axis is not None
     )
-    spec_q = P(None, head_axis, None)
-    spec_pool = P(None, head_axis, None, None)
+    specs = tuple(
+        P(*(TENSOR_AXIS if split and i == axis else None for i in range(arg.ndim)))
+        for arg, axis in zip(args, head_axes)
+    )
+    out_specs = (
+        tuple(specs[i] for i in like) if isinstance(like, tuple) else specs[like]
+    )
     return jax.shard_map(
-        lambda q, pk, pv, tables, lens: paged_decode_attention(
-            q, pk, pv, tables, lens, **kwargs
-        ),
-        mesh=mesh,
-        in_specs=(spec_q, spec_pool, spec_pool, P(), P()),
-        out_specs=spec_q,
-        check_vma=False,
-    )(q, pool_k, pool_v, block_tables, lengths)
+        fn, mesh=mesh, in_specs=specs, out_specs=out_specs, check_vma=False,
+    )(*args)
 
 
 def paged_cached_attention(
@@ -138,6 +214,7 @@ def paged_cached_attention(
     lengths: jnp.ndarray,
     block_tables: jnp.ndarray,
     *,
+    layer: jnp.ndarray | int | None = None,
     segment_ids: jnp.ndarray | None = None,
     sliding_window: int | None = None,
     logits_soft_cap: float | None = None,
@@ -146,32 +223,52 @@ def paged_cached_attention(
 ) -> tuple[jnp.ndarray, tuple[jnp.ndarray, jnp.ndarray]]:
     """Append this chunk's k/v through the block table, then attend each
     row against its own cache. q/k/v `[B, S, H*, D]` (S == 1 on the decode
-    hot path, S == chunk width during chunked prefill); `layer_kv` is this
-    layer's pool pair; `lengths [B]` counts tokens already in each row's
-    cache BEFORE this chunk. Returns `(out [B, S, Hq, D], new pool pair)`.
+    hot path, S == chunk width during chunked prefill); `lengths [B]` counts
+    tokens already in each row's cache BEFORE this chunk. Returns `(out [B,
+    S, Hq, D], new pool pair)`.
 
-    impl: 'auto' (Pallas kernel for single-token decode on TPU, XLA gather
-    otherwise) | 'pallas' (kernel forced — interpreted off-TPU) | 'xla'.
+    `layer_kv` is one layer's pool pair `[N, Hkv, page, D]`, or, with
+    `layer`, the pools of the whole stack `[L, N, Hkv, page, D]` as the
+    layer loop carries them: the stack is viewed as one pool of `L * N`
+    blocks and `layer * N` is added to the table, so this layer appends to
+    and reads ITS blocks of the carried buffer (its trash block is `layer *
+    N`) and the layer's pool is never cut out of the stack.
+
+    impl: 'auto' (Pallas kernels on TPU — the page writer, and the decode
+    kernel for single-token decode — XLA elsewhere) | 'pallas' (kernels
+    forced, interpreted off-TPU) | 'xla'.
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
     pool_k, pool_v = layer_kv
+    stack_shape, trash = pool_k.shape, 0
+    if layer is not None:
+        # this layer's first block in the stack seen as one pool: its trash block
+        trash = jnp.asarray(layer * stack_shape[1], block_tables.dtype)
+        block_tables = block_tables + trash
+        pool_k, pool_v = (pool.reshape(-1, *stack_shape[2:]) for pool in layer_kv)
     lengths = lengths.astype(jnp.int32)
-    ck, cv = paged_append(pool_k, pool_v, k, v, lengths, block_tables, segment_ids)
+    ck, cv = paged_append(
+        pool_k, pool_v, k, v, lengths, block_tables, segment_ids, impl, trash
+    )
 
     seq = q.shape[1]
     use_kernel = seq == 1 and (
         impl == "pallas" or (impl == "auto" and jax.default_backend() == "tpu")
     )
     if use_kernel:
-        out = _kernel_under_mesh(
-            q[:, 0], ck, cv, block_tables, lengths + 1,
-            scale=scale, sliding_window=sliding_window,
-            logits_soft_cap=logits_soft_cap,
+        from llm_training_tpu.ops.pallas.paged_attention import paged_decode_attention
+
+        out = _over_heads(
+            lambda q, pk, pv, tables, lens: paged_decode_attention(
+                q, pk, pv, tables, lens, scale=scale,
+                sliding_window=sliding_window, logits_soft_cap=logits_soft_cap,
+            ),
+            (q[:, 0], ck, cv, block_tables, lengths + 1), (1, 1, 1, None, None), 0,
         )[:, None]
     else:
         out = _gather_attention(
             q, ck, cv, lengths, block_tables, segment_ids,
             sliding_window, logits_soft_cap, scale,
         )
-    return out, (ck, cv)
+    return out, (ck.reshape(stack_shape), cv.reshape(stack_shape))

@@ -39,6 +39,11 @@ Off-TPU the kernel runs interpreted (tier-1 tests), following the
 `flash_attention.py` pattern; on a TPU it always compiles, and a shape
 Mosaic cannot tile raises here instead of being routed elsewhere. The XLA
 gather fallback lives in `ops/paged_attention.py`.
+
+Beside it lives the append's writer (`write_pages`, kernel name
+`kv_page_write`): whole pages copied HBM to HBM into a pool that is aliased
+to the call's output, so the pool is written where it lies and in the
+layout the decode kernel reads.
 """
 
 from __future__ import annotations
@@ -281,3 +286,76 @@ def paged_decode_attention(
         name="paged_decode",
     )(tables, lens, qg, k_pages, v_pages)
     return out.reshape(batch, num_q_heads, head_dim)
+
+
+def _write_pages_kernel(
+    blocks,      # scalar prefetch: [M] pool block of each page
+    live,        # scalar prefetch: [M] nonzero where the page is to be written
+    k_pages,     # [M, Hkv, page, D] in HBM
+    v_pages,
+    k_in, v_in,  # the pools, aliased to the outputs below: the same memory
+    k_pool,      # [N, Hkv, page, D], left in place
+    v_pool,
+    sems,        # DMA semaphores [2 (K, V)]
+):
+    del k_in, v_in
+
+    def for_live_pages(act):
+        def one(i, _):
+            @pl.when(live[i] != 0)
+            def _():
+                for which, (pages, pool) in enumerate(((k_pages, k_pool), (v_pages, v_pool))):
+                    act(pltpu.make_async_copy(
+                        pages.at[i], pool.at[blocks[i]], sems.at[which]
+                    ))
+
+        lax.fori_loop(0, blocks.shape[0], one, None)
+
+    # every copy is in flight before the first is waited for: they are of one
+    # size, so a wait a copy drains each semaphore whatever order they end in
+    for_live_pages(lambda copy: copy.start())
+    for_live_pages(lambda copy: copy.wait())
+
+
+def write_pages(
+    k_pool: jnp.ndarray,
+    v_pool: jnp.ndarray,
+    k_pages: jnp.ndarray,
+    v_pages: jnp.ndarray,
+    blocks: jnp.ndarray,
+    live: jnp.ndarray,
+    *,
+    interpret: bool | None = None,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The append's write: `pool[blocks[i]] = pages[i]` wherever `live[i]`,
+    for the K and the V pool `[N, Hkv, page, D]` and pages `[M, Hkv, page,
+    D]` of the pool's dtype. The pools are aliased to the outputs and stay
+    in HBM (`memory_space=pl.ANY`): a page is one contiguous block of the
+    pool in the layout `paged_decode_attention` reads, so a write is one
+    HBM-to-HBM copy, the pool's other blocks are not touched, and nothing of
+    the pool's shape is produced. Live pages go to blocks of their own (a
+    block belongs to one row); which copy wins a block named twice is not
+    defined."""
+    any_space = pl.BlockSpec(memory_space=pl.ANY)
+    new_k, new_v = pl.pallas_call(
+        _write_pages_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(1,),
+            in_specs=[any_space] * 4,
+            out_specs=[any_space] * 2,
+            scratch_shapes=[pltpu.SemaphoreType.DMA((2,))],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
+            jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype),
+        ],
+        # operands count the two scalar-prefetch arrays
+        input_output_aliases={4: 0, 5: 1},
+        interpret=resolve_interpret(interpret),
+        name="kv_page_write",
+    )(
+        blocks.astype(jnp.int32), live.astype(jnp.int32),
+        k_pages, v_pages, k_pool, v_pool,
+    )
+    return new_k, new_v

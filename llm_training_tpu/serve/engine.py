@@ -1,7 +1,10 @@
 """Continuous-batching serving engine (docs/serving.md).
 
 Two jitted programs over the SAME sharded decoder stack the trainer runs,
-both against the paged pool (donated — the cache mutates in place in HBM):
+both against the paged pool (donated — the cache mutates in place in HBM:
+the stack carries the pools through its layer loop and the append writes
+only the pages the new tokens lie in, so neither program produces an array
+of a pool's shape; docs/serving.md, "How the cache is carried and appended"):
 
 - `prefill_chunk`: ONE request's next prompt chunk (batch 1, static chunk
   width) written into its own blocks; samples the first new token when the

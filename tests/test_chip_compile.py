@@ -198,7 +198,8 @@ def test_paged_decode_compiles_on_a_sharded_mesh_for_v5e(v5e, as_on_tpu):
             pool, pool,
             shape((batch,), jnp.int32, P()), shape((batch, pages), jnp.int32, P()),
         )
-    assert found == {"paged_decode": 1}, found
+    # the append's page writer and the decode kernel, each in its shard_map
+    assert found == {"kv_page_write": 1, "paged_decode": 1}, found
 
 
 def test_paged_decode_refuses_untileable_shapes_when_compiled():
@@ -221,25 +222,89 @@ def test_interpret_is_impossible_on_a_tpu_backend(monkeypatch):
         resolve_interpret(True)
 
 
-# ------------------------------------------------- the Solar-Open2 serve cell
+# ------------------------------------------------------- the serve cells
 #
-# `solar2-serve-longdoc` (BENCHMARK.json): both serving programs at the
-# configuration's widths, pool and state slab, compiled for the v5e. What is
-# pinned: they fit the chip's 16 GB beside 6.6 GB of weights, the decode step
-# keeps the `paged_decode` kernel for its one GQA layer, the caches are
-# aliased in place, and how many whole-pool and whole-slab arrays each
-# program produces on the way (PERF.md section 5 reports the numbers beside
-# the other cells'; ROADMAP S2 is the item that lowers them).
+# Both serving programs of every serve cell (BENCHMARK.json), at the cell's
+# own configuration and traffic files, compiled for the v5e. What is pinned:
+# they fit the chip's 16 GB beside the weights, the decode step keeps one
+# `paged_decode` call a pool layer, the caches are aliased in place, and how
+# many arrays of a pool's, the stacked pools' or a state slab's shape each
+# program PRODUCES on the way: in every computation it runs (the entry, the
+# layer loop's body), views and in-place writes left out. Since PR 28 the
+# caches ride the layer loop as its carry and the append writes whole pages
+# in place, so no program produces a pool at all; the parent's counts are in
+# CHANGES.md (PR 28).
 
-_SOLAR_POOL = r"bf16\[(?:1,)?6145,8,16,128\]"  # 32 x 192 pages of 16 tokens, and the trash page
-_SOLAR_STATE = r"f32\[(?:1,|3,)?32,64,128,128\]"  # a KDA layer's (or all three layers') states
-# produced in the compiled program's entry computation, views left out
-_SOLAR_PRODUCED = {"decode": {"pool": 8, "state": 4}, "prefill": {"pool": 6, "state": 7}}
+# program -> {shape kind: arrays produced}; "state" is a KDA layer's (or all
+# three layers') float32 states. A decode step writes each layer's new state
+# (134 MB) and then XLA's in-place update copies it into the carried slab:
+# two instructions a layer where the parent had one and a restack of all
+# three (4 -> 6 by count, the same 805 MB written; a Pallas recurrence that
+# writes in place is ROADMAP R10). A chunk scatters one slot a layer in place
+# (7 -> 3).
+_PRODUCED = {
+    "phi3m-serve-rollout": {"decode": {"pool": 0, "stack": 0}, "prefill": {"pool": 0, "stack": 0}},
+    "olmoe-serve-rollout": {"decode": {"pool": 0, "stack": 0}, "prefill": {"pool": 0, "stack": 0}},
+    "solar2-serve-longdoc": {
+        "decode": {"pool": 0, "stack": 0, "state": 6},
+        "prefill": {"pool": 0, "stack": 0, "state": 3},
+    },
+}
+# the programs' temporaries, GB, which hold that Solar's slab updates ARE in
+# place (one more copy of a layer's states is 0.13 GB, of a pool as much).
+# Phi-3's chunk holds its 0.126 GB of attention scores, OLMoE one layer's
+# expert weights cut out of the stack (0.27 GB, ROADMAP S4), Solar's step
+# one layer's new state (0.134 GB) and its chunk the scores (0.40 GB)
+_TEMP_GB = {
+    "phi3m-serve-rollout": {"decode": 0.01, "prefill": 0.15},
+    "olmoe-serve-rollout": {"decode": 0.3, "prefill": 0.3},
+    "solar2-serve-longdoc": {"decode": 0.2, "prefill": 0.45},
+}
+_NOT_PRODUCED = (
+    "parameter", "bitcast", "get-tuple-element", "tuple", "while", "conditional", "call",
+)
 
 
-def _solar_program(v5e, program):
-    import json
+def _run_computations(text):
+    """{name: lines} of the computations a compiled program runs instruction
+    by instruction: the entry, loop bodies and conditions, branches. A fused
+    computation is one device op, counted where it is called."""
     import re
+
+    computations, fused, name = {}, set(), None
+    for line in text.splitlines():
+        opening = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if opening and not line.startswith(" "):
+            name = opening.group(1)
+            computations[name] = []
+        elif name is not None:
+            computations[name].append(line)
+            if " fusion(" in line or "to_apply=" in line:
+                fused.update(re.findall(r"(?:calls|to_apply)=%?([\w.\-]+)", line))
+    return {k: v for k, v in computations.items() if k not in fused}
+
+
+def _produced(text, pattern):
+    """Arrays whose shape matches `pattern` that some instruction of the
+    program produces. Left out: views (`_NOT_PRODUCED`), and a custom call
+    whose outputs alias its operands (the page writer: the same memory)."""
+    import re
+
+    total = 0
+    for lines in _run_computations(text).values():
+        for line in lines:
+            _, found, rest = line.partition(" = ")
+            kind = re.match(r"(?:\(.*?\)|\S+) ([\w\-]+)\(", rest)
+            if not found or not kind or kind.group(1) in _NOT_PRODUCED:
+                continue
+            if kind.group(1) == "custom-call" and "output_to_operand_aliasing=" in rest:
+                continue
+            total += len(re.findall(pattern, rest[: kind.start(1)]))
+    return total
+
+
+def _serve_program(v5e, cell, program):
+    """(lowered program, the engine's cache shapes) of a serve cell."""
     from pathlib import Path
 
     import flax.linen as nn
@@ -247,10 +312,9 @@ def _solar_program(v5e, program):
     from benchmarks import common
     from llm_training_tpu.serve.engine import ServeConfig, ServingEngine
 
-    root = Path(__file__).resolve().parent.parent
-    cfg = json.loads((root / "benchmarks/configs/solar-open2-250b-ep8.json").read_text())
-    serve = json.loads((root / "benchmarks/traffic/serve-longdoc-closed.json").read_text())["engine"]
-    model = common.build_model(cfg)
+    found = common.Cell(Path(__file__).resolve().parent.parent, cell)
+    serve = found.traffic["engine"]
+    model = common.build_model(found.config)
     one = SingleDeviceSharding(v5e.devices[0])
     on = lambda tree: jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree
@@ -261,47 +325,75 @@ def _solar_program(v5e, program):
     )))
     engine = ServingEngine(model, None, ServeConfig(**serve))  # its caches give the shapes
     pool, slab = on(engine._pool_k), on(engine._slab)
-    assert pool.shape == (1, 6145, 8, 16, 128)  # the one GQA layer of the period
-    assert [s.shape for s in slab] == [(3, 32, 64, 128, 128), (3, 32, 3, 24576)]
     rows, pages, chunk = serve["max_batch"], engine.pages_per_request, serve["prefill_chunk"]
     key = on(jax.eval_shape(lambda: jax.random.key(0)))
+    slab_rows = {} if slab is None else {"slab": slab}
     if program == "decode":
         lowered = engine._decode_jit.lower(
             variables, shape((rows,)), pool, pool, shape((rows, pages)), shape((rows,)), key,
-            slab=slab,
+            **slab_rows,
         )
     else:
+        if slab is not None:
+            slab_rows.update(slot=shape((1,)), fresh=shape((1,), jnp.bool_))
         lowered = engine._prefill_jit.lower(
             variables, shape((1, chunk)), shape((1, chunk)), shape((1, chunk)), pool, pool,
-            shape((1, pages)), shape((1,)), shape(()), key,
-            slab=slab, slot=shape((1,)), fresh=shape((1,), jnp.bool_),
+            shape((1, pages)), shape((1,)), shape(()), key, **slab_rows,
         )
     engine.close()
+    return lowered, pool, slab
 
-    def produced(text, pattern):
-        entry = text[text.index("\nENTRY "):]
-        total = 0
-        for line in entry.splitlines():
-            head, _, rest = line.partition(" = ")
-            kind = re.match(r"(?:\(.*?\)|\S+) ([\w\-]+)\(", rest)
-            if kind and kind.group(1) not in ("parameter", "bitcast", "get-tuple-element", "tuple"):
-                total += len(re.findall(pattern, rest[: kind.start(1)]))
-        return total
 
-    return lowered, produced
+def _check_serve_program(v5e, cell, program):
+    import re
+
+    lowered, pool, slab = _serve_program(v5e, cell, program)
+    compiled = lowered.compile()  # raises what the chip's compiler would: it fits
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    layers, blocks, *page = pool.shape
+    dims = ",".join(str(d) for d in page)
+    patterns = {
+        "pool": rf"bf16\[(?:1,)?{blocks},{dims}\]",
+        # the stack, as it is declared and as the append sees it (one run of blocks)
+        "stack": rf"bf16\[(?:{layers},{blocks}|{layers * blocks}),{dims}\]" if layers > 1 else None,
+    }
+    caches = 2 * pool.size * 2
+    if slab is not None:
+        state, tail = slab
+        # a layer's states, every layer's, and every layer's as one run of slots
+        layers_slots = f"{state.shape[0]},{state.shape[1]}|{state.shape[0] * state.shape[1]}"
+        patterns["state"] = (
+            rf"f32\[(?:(?:1,)?{state.shape[1]}|{layers_slots}),"
+            + ",".join(str(d) for d in state.shape[2:]) + r"\]"
+        )
+        caches += state.size * 4 + tail.size * 2
+    assert memory.alias_size_in_bytes >= caches  # pools and slab are written in place
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 14e9
+    assert memory.temp_size_in_bytes < _TEMP_GB[cell][program] * 1e9
+
+    # one `paged_decode` call a pool layer: a call site in the layer loop's
+    # body runs once a layer; an unrolled loop has a call site a layer
+    sites = {
+        name: sum("paged_decode" in line and "tpu_custom_call" in line for line in lines)
+        for name, lines in _run_computations(text).items()
+    }
+    bodies = set(re.findall(r"body=%?([\w.\-]+)", text))
+    calls = sum(n * (layers if name in bodies else 1) for name, n in sites.items())
+    assert calls == (layers if program == "decode" else 0), sites
+    assert parse_hlo_kernels(text).get("kv_page_write", 0) >= 1  # the append's writer
+
+    counts = {k: _produced(text, p) for k, p in patterns.items() if p}
+    counts.setdefault("stack", 0)
+    print(f"{cell} {program}: produced {counts}, temp {memory.temp_size_in_bytes / 1e9:.3f} GB")
+    assert counts == _PRODUCED[cell][program], counts
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
 def test_solar_open2_serve_cell_compiles_for_v5e(v5e, as_on_tpu, program):
-    lowered, produced = _solar_program(v5e, program)
-    compiled = lowered.compile()  # raises what the chip's compiler would: it fits
-    text, memory = compiled.as_text(), compiled.memory_analysis()
-    kernels = parse_hlo_kernels(text)
-    assert kernels.get("paged_decode", 0) == (1 if program == "decode" else 0), kernels
-    caches = 2 * 6145 * 8 * 16 * 128 * 2 + 3 * 32 * (64 * 128 * 128 * 4 + 3 * 24576 * 2)
-    assert memory.alias_size_in_bytes >= caches  # pool and slab are written in place
-    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 12e9
-    counts = {"pool": produced(text, _SOLAR_POOL), "state": produced(text, _SOLAR_STATE)}
-    print(f"solar2-serve-longdoc {program}: produced in the entry computation {counts}, "
-          f"temp {memory.temp_size_in_bytes / 1e9:.2f} GB")
-    assert all(counts[k] <= _SOLAR_PRODUCED[program][k] for k in counts), counts
+    _check_serve_program(v5e, "solar2-serve-longdoc", program)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("cell", ["phi3m-serve-rollout", "olmoe-serve-rollout"])
+def test_rollout_cells_update_the_pool_in_place_for_v5e(v5e, as_on_tpu, cell, program):
+    _check_serve_program(v5e, cell, program)
