@@ -97,6 +97,14 @@ class FamilySpec:
     batch: int = 1  # sample batch width for init (pipeline needs >= stages)
     seq: int = 16
 
+    def build(self):
+        """The family's module at this spec's config (`model.config`)."""
+        import importlib
+
+        module = importlib.import_module(self.module)
+        config = getattr(module, self.model_class + "Config")(**self.config)
+        return getattr(module, self.model_class)(config)
+
 
 def _llama_tiny(**extra) -> dict:
     base = dict(
@@ -224,6 +232,16 @@ FAMILY_REGISTRY: tuple[FamilySpec, ...] = (
              num_experts_per_tok=2, moe_intermediate_size=32,
              shared_expert_intermediate_size=48),
     ),
+    FamilySpec(
+        "solar_open2", "llm_training_tpu.models.solar_open2", "SolarOpen2",
+        "llm_training_tpu/models/solar_open2/model.py",
+        dict(vocab_size=128, hidden_size=64, intermediate_size=112,
+             moe_intermediate_size=32, num_hidden_layers=4,
+             num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+             linear_num_heads=4, linear_head_dim=16,
+             max_position_embeddings=128, n_routed_experts=8,
+             num_experts_per_tok=2, n_shared_experts=1),
+    ),
 )
 
 
@@ -250,7 +268,7 @@ class AuditResult:
 
 @dataclass(frozen=True)
 class _Leaf:
-    """One audited tensor: a Partitioned param leaf or the KV-cache proxy."""
+    """One audited tensor: a Partitioned param leaf or a cache proxy."""
 
     path: str
     names: tuple[str | None, ...]
@@ -275,18 +293,13 @@ def _family_leaves(spec: FamilySpec) -> tuple[list[_Leaf], int, Any]:
     """(audited leaves, abstract opt-state bytes BEFORE sharding is known,
     model config). jax/flax/optax imports live here — `--audit` is the only
     CLI path that pays them."""
-    import importlib
-
     import flax.linen as nn
     import jax
     import jax.numpy as jnp
     import optax
 
-    module = importlib.import_module(spec.module)
-    model_cls = getattr(module, spec.model_class)
-    config_cls = getattr(module, spec.model_class + "Config")
-    config = config_cls(**spec.config)
-    model = model_cls(config)
+    model = spec.build()
+    config = model.config
 
     sample = jax.ShapeDtypeStruct((spec.batch, spec.seq), jnp.int32)
     variables = jax.eval_shape(model.init, jax.random.key(0), sample)
@@ -344,35 +357,42 @@ def _family_leaves(spec: FamilySpec) -> tuple[list[_Leaf], int, Any]:
             "optimizer; update shard_audit's opt accounting"
         )
 
-    # KV cache under infer/cache's layout, when the config carries the
-    # shared-stack cache dims (every family does today; degrade to zero
-    # rather than fail if a future family diverges)
-    try:
+    # the caches a decoding family declares (`BaseModelConfig.cache_specs`),
+    # under infer/cache's layouts: the key/value buffers, k and v both, and a
+    # linear-attention stack's slab. The batch (= slots) dimension is a
+    # placeholder; run_audit fills it from AuditConfig. A family that
+    # declares none has none to audit
+    declared = config.cache_specs()
+    if declared is not None:
         import numpy as np
 
-        from llm_training_tpu.infer.cache import KV_LOGICAL_AXES, cache_dims
-
-        num_layers, kv_heads, head_dim = cache_dims(config)
-        kv_full = (
-            num_layers,
-            0,  # placeholder batch; run_audit fills it from AuditConfig
-            spec.config.get("max_position_embeddings", 64),
-            kv_heads,
-            head_dim,
+        from llm_training_tpu.infer.cache import (
+            CONV_LOGICAL_AXES,
+            KV_LOGICAL_AXES,
+            STATE_LOGICAL_AXES,
+            slab_shapes,
         )
-        # ONE buffer's shape; k and v both exist, so count it twice
-        for kv_name in ("<kv-cache k>", "<kv-cache v>"):
-            leaves.append(
-                _Leaf(
-                    path=kv_name,
-                    names=tuple(KV_LOGICAL_AXES),
-                    shape=kv_full,
-                    itemsize=np.dtype(config.param_jnp_dtype).itemsize,
-                    kind="kv",
-                )
-            )
-    except (AttributeError, ImportError):
-        pass
+
+        kv, recurrent = declared
+        kv_full = (
+            kv.layers, 0, spec.config.get("max_position_embeddings", 64),
+            kv.kv_heads, kv.head_dim,
+        )
+        itemsize = np.dtype(config.param_jnp_dtype).itemsize
+        caches = [
+            ("<kv-cache k>", KV_LOGICAL_AXES, kv_full, itemsize),
+            ("<kv-cache v>", KV_LOGICAL_AXES, kv_full, itemsize),
+        ]
+        if recurrent is not None:
+            state_shape, conv_shape = slab_shapes(recurrent, 0)
+            caches += [
+                ("<state-slab state>", STATE_LOGICAL_AXES, state_shape, 4),
+                ("<state-slab conv>", CONV_LOGICAL_AXES, conv_shape, itemsize),
+            ]
+        leaves += [
+            _Leaf(path=path, names=tuple(names), shape=shape, itemsize=size, kind="kv")
+            for path, names, shape, size in caches
+        ]
 
     return leaves, opt_scalar_bytes, config
 
@@ -428,9 +448,7 @@ def run_audit(root: Path, config: AuditConfig | None = None) -> AuditResult:
         for leaf in leaves:
             shape = leaf.shape
             if leaf.kind == "kv":
-                shape = (
-                    shape[0], cfg.decode_batch, shape[2], shape[3], shape[4]
-                )
+                shape = (shape[0], cfg.decode_batch, *shape[2:])
                 leaf = _Leaf(leaf.path, leaf.names, shape, leaf.itemsize, "kv")
             unknown = [
                 axis for axis in leaf.names

@@ -32,25 +32,20 @@ CONV_LOGICAL_AXES = ("layers", "batch", None, "heads")
 
 
 def cache_specs(config) -> tuple[KVCacheSpec, RecurrentCacheSpec | None]:
-    """What a stack caches, one declaration a layer kind: every pool, dense
-    buffer, slab and sharding below derives from it. A config whose layers
-    are not all of one kind says so itself (`config.cache_specs()`); the
-    shared softmax stacks cache keys and values on every layer."""
-    declared = getattr(config, "cache_specs", None)
-    if declared is not None:
-        return declared()
-    head_dim = getattr(config, "resolved_head_dim", None) or config.head_dim
-    return (
-        KVCacheSpec(config.num_hidden_layers, config.num_key_value_heads, head_dim),
-        None,
-    )
+    """What a stack caches, as its config declares it
+    (`BaseModelConfig.cache_specs`): every pool, dense buffer, slab and
+    sharding below derives from it."""
+    declared = config.cache_specs()
+    if declared is None:
+        raise NotImplementedError(
+            f"{type(config).__name__} declares no cache (cache_specs() is None): "
+            "the family does not decode"
+        )
+    return declared
 
 
 def cache_dims(config) -> tuple[int, int, int]:
-    """(num_layers, num_kv_heads, head_dim) for any shared-stack config.
-
-    Gemma carries a mandatory explicit `head_dim`; llama-family configs
-    derive it via `resolved_head_dim`."""
+    """(layers, num_kv_heads, head_dim) of the key/value cache."""
     kv, _ = cache_specs(config)
     return kv.layers, kv.kv_heads, kv.head_dim
 

@@ -24,7 +24,6 @@ through the process registry, so the `generate` CLI lands it in
 from __future__ import annotations
 
 import contextlib
-import inspect
 import logging
 import time
 from typing import Any, Sequence
@@ -88,16 +87,13 @@ def mesh_context(mesh: Any, rules: Any = ()) -> contextlib.ExitStack:
 
 
 def supports_decoding(model: Any) -> bool:
-    """A model family opts into KV-cache decoding by accepting a
-    `decode_state` kwarg: the shared llama/gemma/phi3 stacks, and
+    """A family decodes when its config declares what its stack caches
+    (`BaseModelConfig.cache_specs`): the shared llama/gemma/phi3 stacks, and
     solar_open2, whose linear-attention (KDA) layers keep a fixed state slab
-    a decode slot beside the key/value cache (`infer/cache.py:cache_specs`).
-    Not threaded yet: bamba's mamba layers, qwen3-next's and minimax's
-    linear attention, deepseek's MLA."""
-    try:
-        return "decode_state" in inspect.signature(model.__call__).parameters
-    except (TypeError, ValueError):
-        return False
+    a decode slot beside the key/value cache. Not declared yet: bamba's mamba
+    layers, qwen3-next's and minimax's linear attention, deepseek's MLA."""
+    declared = getattr(model.config, "cache_specs", None)
+    return declared is not None and declared() is not None
 
 
 def _left_pad(prompts: Sequence[Sequence[int]], pad_id: int) -> tuple[np.ndarray, np.ndarray]:
@@ -132,9 +128,9 @@ class InferenceEngine:
         if not supports_decoding(model):
             raise NotImplementedError(
                 f"{type(model).__name__} does not support KV-cache decoding "
-                "yet: its __call__ takes no decode_state (non-standard "
-                "sequence mixers need their own cache layout — see "
-                "docs/inference.md)"
+                "yet: its config declares no cache and its __call__ takes no "
+                "decode_state (non-standard sequence mixers need their own "
+                "cache layout — see docs/inference.md)"
             )
         self.model = model
         self.variables = variables

@@ -26,9 +26,10 @@ class CausalLM(Protocol):
     (reference `lms/protos/clm_proto.py:9-26`).
 
     `decode_state` (a `models.base.DecodeState` KV cache) is OPTIONAL for
-    implementations: families that accept it opt into the inference
-    engine's prefill/decode programs; `infer.engine.supports_decoding`
-    checks for it and raises NotImplementedError otherwise."""
+    implementations: a family whose config declares what its stack caches
+    (`BaseModelConfig.cache_specs`) accepts it and runs under the inference
+    engine's prefill/decode programs; `infer.engine.supports_decoding` asks
+    for the declaration and the engines raise NotImplementedError without."""
 
     def __call__(
         self,

@@ -74,6 +74,13 @@ class BaseModelConfig(BaseModel):
     def param_jnp_dtype(self) -> jnp.dtype:
         return resolve_dtype(self.param_dtype)
 
+    def cache_specs(self) -> "tuple[KVCacheSpec, RecurrentCacheSpec | None] | None":
+        """What this family's stack caches when it decodes, one spec a layer
+        kind; every pool, dense buffer, slab and sharding derives from it
+        (`infer/cache.py`). None, the default, says the family does not
+        decode: its `__call__` takes no `decode_state`."""
+        return None
+
 
 @flax.struct.dataclass
 class RouterStats:
@@ -192,10 +199,8 @@ class PagedDecodeState:
     which is what lets a finished request's blocks be recycled and a new
     request join mid-flight without left-padding anyone.
 
-    The decoder stacks thread this through the SAME `layer_kv`/`kv_index`/
-    `kv_segment_ids` plumbing as `DecodeState` (kv_index carries the [B]
-    lengths, kv_segment_ids carries the block tables); attention layers
-    dispatch on `kv_index.ndim` to `ops.paged_attention`.
+    The decoder stacks open either state into the same `LayerCache`
+    (`models/cache.py`), which is where the two kinds part.
 
     A stack with linear-attention layers carries their slab beside the pool
     (`state`, `conv`: `RecurrentCacheSpec`), indexed by decode SLOT, not by

@@ -1,0 +1,249 @@
+"""How a decoder stack meets its cache: the one module of `models/` that
+knows what a cache kind is (docs/inference.md, "How a layer meets its cache").
+
+A family's `__call__` opens its `decode_state` once (`open_cache`), hands the
+`LayerCache` to its layer loop, and closes it (`close_cache`). A layer asks
+the cache to append its chunk's keys and values and attend against its own
+part (`attend`), or for its recurrent rows and to take them back
+(`recurrent_rows`, `put_recurrent_rows`); it never sees which kind it was
+given. The kind is decided here, once, from the state's type:
+
+- dense (`DecodeState`, `infer/`): buffers `[L, B, max_length, kv_heads,
+  head_dim]`, one append position shared by the batch, and the filled-slot
+  ids every layer masks against;
+- paged (`PagedDecodeState`, `serve/`): pools `[L, blocks, kv_heads, page,
+  head_dim]` addressed through per-row lengths and block tables
+  (`ops/paged_attention.py`);
+- beside either, where the stack has linear-attention layers, their slab
+  (`RecurrentCacheSpec`): `state`, `conv` `[layers, slots, ...]`.
+
+The buffers hold EVERY layer of their kind, leading axis over layers, and
+ride the layer loop as its carry (`scan_layers`; a Python variable on a
+looped path): a layer writes its new rows into them in place and reads its
+own part, and its slice is never cut out of the stack and put back. What is
+the same for every layer is closed over by the loop, not carried.
+"""
+
+from __future__ import annotations
+
+import flax.linen as nn
+import flax.struct
+import jax
+import jax.numpy as jnp
+
+from llm_training_tpu.models.base import PagedDecodeState
+from llm_training_tpu.ops import dot_product_attention
+
+_BUFFERS = ("k", "v", "state", "conv")
+
+
+def _slot_rows(slab, slots, fresh):
+    """A slab layer's rows for this batch: `slots` picks them (None: row i is
+    slot i), and a row whose request starts here reads zeros."""
+    rows = slab if slots is None else slab[slots]
+    if fresh is not None:
+        rows = jnp.where(fresh.reshape((-1,) + (1,) * (rows.ndim - 1)), 0, rows)
+    return rows
+
+
+def _layer_rows(slab, layer, slots, fresh, read):
+    """`read` (`_slot_rows`) of layer `layer` of the whole slab `[layers,
+    slots, ...]`. Picked slots are gathered out of the slab seen as one run
+    of `layers * slots` rows, so the layer's part is not cut out first."""
+    if slots is None:
+        mine = jax.lax.dynamic_index_in_dim(slab, layer, keepdims=False)
+        return read(mine, None, fresh)
+    flat = slab.reshape(-1, *slab.shape[2:])
+    return read(flat, layer * slab.shape[1] + slots, fresh)
+
+
+def _put_rows(slab, layer, slots, rows):
+    """The carried slab `[layers, slots, ...]` with layer `layer`'s rows for
+    this batch replaced, in place: the slab is addressed as one run of
+    `layers * slots` rows, not cut up and restacked."""
+    per_layer = slab.shape[1]
+    flat = slab.reshape(-1, *slab.shape[2:])
+    if slots is None:
+        flat = jax.lax.dynamic_update_slice_in_dim(flat, rows, layer * per_layer, axis=0)
+    else:
+        flat = flat.at[layer * per_layer + slots].set(rows)
+    return flat.reshape(slab.shape)
+
+
+@flax.struct.dataclass
+class LayerCache:
+    """An opened `DecodeState` / `PagedDecodeState` as the layers see it.
+    `k`, `v`, `state`, `conv` are the carried buffers (`buffers`); the other
+    fields are the same for every layer. Dense: `index` is the shared append
+    position and `kv_segment_ids` the cache's filled-slot ids with the
+    incoming chunk already merged in, so every layer masks against the same
+    view. Paged: `lengths [B]` counts each row's tokens before this chunk,
+    `block_tables` maps its pages; `slots`, `fresh` address the slab
+    (`PagedDecodeState`)."""
+
+    k: jnp.ndarray | None
+    v: jnp.ndarray | None
+    state: jnp.ndarray | None = None
+    conv: jnp.ndarray | None = None
+    fresh: jnp.ndarray | None = None
+    index: jnp.ndarray | None = None
+    kv_segment_ids: jnp.ndarray | None = None
+    lengths: jnp.ndarray | None = None
+    block_tables: jnp.ndarray | None = None
+    slots: jnp.ndarray | None = None
+    paged: bool = flax.struct.field(pytree_node=False, default=False)
+
+    @property
+    def buffers(self) -> tuple:
+        return tuple(getattr(self, name) for name in _BUFFERS)
+
+    def holding(self, buffers: tuple) -> "LayerCache":
+        return self.replace(**dict(zip(_BUFFERS, buffers)))
+
+    def attend(self, layer, q, k, v, segment_ids, *, window=None, scale=None,
+               logits_soft_cap=None):
+        """Append this chunk's post-RoPE k/v `[B, S, H*, D]` to layer
+        `layer`'s part of the cache and attend q against that part; returns
+        `(out, the cache holding the new buffers)`. `layer` is the loop's
+        index among the stack's softmax-attention layers: traced under a
+        scan, a Python int in a Python loop.
+
+        Dense: the chunk goes in at the shared `index`. The causal term of
+        the mask (q_offset = index) hides slots written after this chunk and
+        `kv_segment_ids` (0 on unwritten/pad slots) hides garbage, so ONE
+        program serves both prefill (chunk at index 0) and single-token
+        decode steps. Always the XLA einsum path: the flash kernel's block
+        tiling assumes q_len >= a block and a static q_offset.
+
+        Paged: page writer and ragged Pallas decode kernel on a TPU, XLA
+        elsewhere; padded chunk positions (segment id 0) go to the trash
+        block."""
+        if self.paged:
+            from llm_training_tpu.ops.paged_attention import paged_cached_attention
+
+            out, (ck, cv) = paged_cached_attention(
+                q, k, v, (self.k, self.v), self.lengths, self.block_tables,
+                layer=layer,
+                segment_ids=segment_ids,
+                sliding_window=window,
+                logits_soft_cap=logits_soft_cap,
+                scale=scale,
+            )
+            return out, self.replace(k=ck, v=cv)
+        ck, cv = (
+            jax.lax.dynamic_update_slice(
+                cache, new[None].astype(cache.dtype), (layer, 0, self.index, 0, 0)
+            )
+            for cache, new in zip((self.k, self.v), (k, v))
+        )
+        mine = lambda cache: jax.lax.dynamic_index_in_dim(cache, layer, keepdims=False)
+        out = dot_product_attention(
+            q, mine(ck).astype(k.dtype), mine(cv).astype(v.dtype),
+            segment_ids=self.kv_segment_ids,
+            q_segment_ids=segment_ids,
+            causal=True,
+            sliding_window=window,
+            logits_soft_cap=logits_soft_cap,
+            scale=scale,
+            q_offset=self.index,
+            impl="xla",
+        )
+        return out, self.replace(k=ck, v=cv)
+
+    def recurrent_rows(self, layer, read=_slot_rows):
+        """`(state [B, ...] float32, conv tail [B, ...])` of recurrent layer
+        `layer` for this batch's rows. (`read`: the benchmark's planted fault,
+        `benchmarks/tests/test_solar_open2.py`, swaps the slot read by its
+        name in the family's module.)"""
+        return tuple(
+            _layer_rows(slab, layer, self.slots, self.fresh, read)
+            for slab in (self.state, self.conv)
+        )
+
+    def put_recurrent_rows(self, layer, rows) -> "LayerCache":
+        # the new rows are whole before they go in: fused into the update,
+        # their computation reads the slab it writes, and the compiler then
+        # copies the whole slab first, once a layer
+        rows = jax.lax.optimization_barrier(rows)
+        state, conv = (
+            _put_rows(slab, layer, self.slots, new)
+            for slab, new in zip((self.state, self.conv), rows)
+        )
+        return self.replace(state=state, conv=conv)
+
+
+def open_cache(decode_state, segment_ids, batch: int, seq: int):
+    """-> `(LayerCache or None, segment_ids)`, before the layer loop. With a
+    state the chunk's q-side segment ids (pads 0, real tokens 1; all ones
+    when not given) are part of the cache's bookkeeping: dense, they double
+    as the ids of the slots the chunk writes and are merged into the
+    filled-slot map here, BEFORE the layers; paged, they mark the padded
+    chunk positions the append sends to the trash block."""
+    if decode_state is None:
+        return None, segment_ids
+    if segment_ids is None:
+        segment_ids = jnp.ones((batch, seq), jnp.int32)
+    held = {name: getattr(decode_state, name) for name in _BUFFERS}
+    if isinstance(decode_state, PagedDecodeState):
+        return LayerCache(
+            **held, paged=True, lengths=decode_state.lengths,
+            block_tables=decode_state.block_tables,
+            slots=decode_state.slots, fresh=decode_state.fresh,
+        ), segment_ids
+    return LayerCache(
+        **held, index=decode_state.index,
+        kv_segment_ids=jax.lax.dynamic_update_slice(
+            decode_state.segment_ids, segment_ids.astype(jnp.int32),
+            (0, decode_state.index),
+        ),
+    ), segment_ids
+
+
+def close_cache(cache: LayerCache | None, decode_state, segment_ids):
+    """The state after the call (None on the training path): buffers
+    replaced; paged rows advance by the chunk's REAL token count (padded
+    tail positions of a final prefill chunk occupy no slot), the dense index
+    by the chunk's width."""
+    if cache is None:
+        return None
+    held = dict(zip(_BUFFERS, cache.buffers))
+    if cache.paged:
+        return decode_state.replace(
+            **held,
+            lengths=decode_state.lengths
+            + jnp.sum(segment_ids > 0, axis=1).astype(jnp.int32),
+        )
+    return decode_state.replace(
+        **held, index=decode_state.index + segment_ids.shape[1],
+        segment_ids=cache.kv_segment_ids,
+    )
+
+
+def scan_layers(body, args: tuple, length: int, hidden, inputs: tuple,
+                cache: LayerCache | None = None):
+    """`body(*args, name="layers")` run `length` times by `nn.scan`, params
+    stacked on axis 0: `-> (hidden, stacked ys, cache)`. Training: the body
+    is called `(hidden, *inputs) -> (hidden, ys)`. Decoding: the buffers are
+    CARRIED beside `hidden` and the step's index scanned over (as a scanned
+    input and output each step would cut its slice out of the stack and
+    write a whole slice into a new one), so the body is called `((hidden,
+    buffers), *inputs, cache=<the rest of the cache>, layer=<step>)` and
+    puts them together with `cache.holding(buffers)`. Same param scope
+    either way: only one of the two traces per call."""
+    decoding = cache is not None
+    scanned = nn.scan(
+        body,
+        variable_axes={"params": 0},
+        split_rngs={"params": True},
+        in_axes=(nn.broadcast,) * len(inputs) + ((nn.broadcast, 0) if decoding else ()),
+        length=length,
+        metadata_params={nn.PARTITION_NAME: "layers"},
+    )(*args, name="layers")
+    if not decoding:
+        hidden, ys = scanned(hidden, *inputs)
+        return hidden, ys, None
+    (hidden, buffers), ys = scanned(
+        (hidden, cache.buffers), *inputs, cache.holding((None,) * len(_BUFFERS)),
+        jnp.arange(length, dtype=jnp.int32),
+    )
+    return hidden, ys, cache.holding(buffers)

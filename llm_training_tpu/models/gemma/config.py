@@ -17,7 +17,7 @@ from typing import Literal
 
 from pydantic import model_validator
 
-from llm_training_tpu.models.base import BaseModelConfig
+from llm_training_tpu.models.base import BaseModelConfig, KVCacheSpec
 
 
 class GemmaConfig(BaseModelConfig):
@@ -122,6 +122,10 @@ class GemmaConfig(BaseModelConfig):
     def attention_scale(self) -> float:
         base = self.query_pre_attn_scalar if self.query_pre_attn_scalar else self.head_dim
         return float(base) ** -0.5
+
+    def cache_specs(self) -> tuple[KVCacheSpec, None]:
+        """Every layer caches keys and values (`BaseModelConfig.cache_specs`)."""
+        return KVCacheSpec(self.num_hidden_layers, self.num_key_value_heads, self.head_dim), None
 
     def layer_sliding_window(self, layer_idx: int) -> int | None:
         """HF Gemma2: 'sliding_attention' on even indices; Gemma3: explicit

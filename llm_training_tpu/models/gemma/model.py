@@ -32,14 +32,10 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from llm_training_tpu.models.base import (
-    CausalLMOutput,
-    DecodeState,
-    PagedDecodeState,
-)
+from llm_training_tpu.models.base import CausalLMOutput, DecodeState
+from llm_training_tpu.models.cache import close_cache, open_cache, scan_layers
 from llm_training_tpu.models.remat import remat_policy as _remat_policy
 from llm_training_tpu.models.gemma.config import GemmaConfig
-from llm_training_tpu.models.llama.model import cached_attention
 from llm_training_tpu.ops import apply_rope, dot_product_attention
 from llm_training_tpu.ops.rope_utils import compute_rope_cos_sin, compute_rope_frequencies
 
@@ -80,16 +76,15 @@ def _dense(config: GemmaConfig, features: int, logical_axes: tuple[str, str], na
 
 
 class GemmaAttention(nn.Module):
-    """KV-cache args (`layer_kv`/`kv_index`/`kv_segment_ids`/`layer`) follow
-    the shared-stack convention — see `llama/model.py:cached_attention`;
-    with a cache the call returns `(out, new_layer_kv)`."""
+    """Returns `(out, cache)`: with a `cache` (`models/cache.py`) the k/v are
+    appended to layer `layer`'s part of it and attention runs against that
+    part; `cache` is None on the training path."""
 
     config: GemmaConfig
     sliding_window: int | None
 
     @nn.compact
-    def __call__(self, hidden, segment_ids, cos, sin,
-                 layer_kv=None, kv_index=None, kv_segment_ids=None, layer=None):
+    def __call__(self, hidden, segment_ids, cos, sin, cache=None, layer=None):
         cfg = self.config
         batch, seq, _ = hidden.shape
         q = _dense(cfg, cfg.num_attention_heads * cfg.head_dim, ("embed", "heads"), "q_proj")(hidden)
@@ -103,19 +98,15 @@ class GemmaAttention(nn.Module):
             q = GemmaRMSNorm(cfg.rms_norm_eps, cfg.param_jnp_dtype, name="q_norm")(q)
             k = GemmaRMSNorm(cfg.rms_norm_eps, cfg.param_jnp_dtype, name="k_norm")(k)
         q, k = apply_rope(q, k, cos, sin)
-        if layer_kv is not None:
-            out, new_kv = cached_attention(
-                q, k, v, segment_ids, layer_kv, kv_index, kv_segment_ids, layer,
+        out = None
+        if cache is not None:
+            out, cache = cache.attend(
+                layer, q, k, v, segment_ids,
                 window=self.sliding_window,
                 scale=cfg.attention_scale,
                 logits_soft_cap=cfg.attn_logit_softcapping,
             )
-            out = out.astype(hidden.dtype).reshape(
-                batch, seq, cfg.num_attention_heads * cfg.head_dim
-            )
-            return _dense(cfg, cfg.hidden_size, ("heads", "embed"), "o_proj")(out), new_kv
-        out = None
-        if getattr(cfg, "ring_attention", False):
+        elif getattr(cfg, "ring_attention", False):
             from llm_training_tpu.parallel.ring_attention import (
                 dispatch_ring_attention,
             )
@@ -138,7 +129,7 @@ class GemmaAttention(nn.Module):
                 impl=cfg.attention_impl,
             )
         out = out.astype(hidden.dtype).reshape(batch, seq, cfg.num_attention_heads * cfg.head_dim)
-        return _dense(cfg, cfg.hidden_size, ("heads", "embed"), "o_proj")(out)
+        return _dense(cfg, cfg.hidden_size, ("heads", "embed"), "o_proj")(out), cache
 
 
 class GemmaMLP(nn.Module):
@@ -155,23 +146,21 @@ class GemmaMLP(nn.Module):
 
 
 class GemmaDecoderLayer(nn.Module):
+    """Returns `(hidden, cache)` (`GemmaAttention`)."""
+
     config: GemmaConfig
     sliding_window: int | None
 
     @nn.compact
-    def __call__(self, hidden, segment_ids, cos, sin,
-                 layer_kv=None, kv_index=None, kv_segment_ids=None, layer=None):
+    def __call__(self, hidden, segment_ids, cos, sin, cache=None, layer=None):
         cfg = self.config
         hidden = nn.with_logical_constraint(hidden, ("batch", "act_seq", "act_embed"))
         norm = lambda name: GemmaRMSNorm(cfg.rms_norm_eps, cfg.param_jnp_dtype, name=name)
 
         attn_in = norm("input_layernorm")(hidden)
-        attn_out = GemmaAttention(cfg, self.sliding_window, name="self_attn")(
-            attn_in, segment_ids, cos, sin, layer_kv, kv_index, kv_segment_ids, layer
+        attn_out, cache = GemmaAttention(cfg, self.sliding_window, name="self_attn")(
+            attn_in, segment_ids, cos, sin, cache, layer
         )
-        new_kv = None
-        if layer_kv is not None:
-            attn_out, new_kv = attn_out
         if cfg.version in (2, 3):
             attn_out = norm("post_attention_layernorm")(attn_out)
             hidden = hidden + attn_out
@@ -182,40 +171,34 @@ class GemmaDecoderLayer(nn.Module):
             hidden = hidden + attn_out
             mlp_in = norm("post_attention_layernorm")(hidden)
             hidden = hidden + GemmaMLP(cfg, name="mlp")(mlp_in)
-        if layer_kv is not None:
-            return hidden, new_kv
-        return hidden
+        return hidden, cache
 
 
 class _ScannedBody(nn.Module):
     """Scan body: one layer (gemma 1 / windowless gemma 2) or a
     (sliding, full) pair (gemma 2 with sliding_window). The carry is
-    `hidden` or, when decoding, `(hidden, the whole stack's KV cache)` with
-    the layer's index as the scanned input, as in the Llama stack."""
+    `hidden` or, when decoding, `(hidden, the cache's buffers)` with the
+    layer's index as the scanned input (`models/cache.py:scan_layers`)."""
 
     config: GemmaConfig
 
     @nn.compact
-    def __call__(self, carry, segment_ids, cos, sin,
-                 layer=None, kv_index=None, kv_segment_ids=None):
+    def __call__(self, carry, segment_ids, cos, sin, cache=None, layer=None):
         cfg = self.config
         if cfg.version == 2 and cfg.sliding_window:
-            hidden = GemmaDecoderLayer(cfg, cfg.sliding_window, name="sliding")(
+            hidden, _ = GemmaDecoderLayer(cfg, cfg.sliding_window, name="sliding")(
                 carry, segment_ids, cos, sin
             )
-            hidden = GemmaDecoderLayer(cfg, None, name="full")(
+            hidden, _ = GemmaDecoderLayer(cfg, None, name="full")(
                 hidden, segment_ids, cos, sin
             )
             return hidden, None
         block = GemmaDecoderLayer(cfg, None, name="layer")
-        if layer is None:
-            return block(carry, segment_ids, cos, sin), None
-        hidden, decode_kv = carry
-        return block(
-            hidden, segment_ids, cos, sin, decode_kv, kv_index, kv_segment_ids, layer
-        ), None
-
-
+        if cache is None:
+            return block(carry, segment_ids, cos, sin)
+        hidden, buffers = carry
+        hidden, cache = block(hidden, segment_ids, cos, sin, cache.holding(buffers), layer)
+        return (hidden, cache.buffers), None
 
 
 class Gemma(nn.Module):
@@ -223,14 +206,13 @@ class Gemma(nn.Module):
 
     config: GemmaConfig
 
-    def _layers(self, hidden, segment_ids, cos, sin, cos_local, sin_local,
-                decode_kv=None, kv_index=None, kv_segment_ids=None):
+    def _layers(self, hidden, segment_ids, cos, sin, cos_local, sin_local, cache=None):
+        """-> (hidden, the cache as the layers left it, None when training)."""
         cfg = self.config
         policy = _remat_policy(cfg)
         paired = cfg.version == 2 and cfg.sliding_window
-        new_kv = None
         if cfg.scan_layers:
-            if decode_kv is not None and paired:
+            if cache is not None and paired:
                 raise NotImplementedError(
                     "KV-cache decoding of gemma-2's paired (sliding, full) "
                     "scan body is not supported; its cache layer axis would "
@@ -240,33 +222,10 @@ class Gemma(nn.Module):
             if policy is not None:
                 body = nn.remat(_ScannedBody, policy=policy, prevent_cse=False)
             length = cfg.num_hidden_layers // 2 if paired else cfg.num_hidden_layers
-            if decode_kv is None:
-                scanned = nn.scan(
-                    body,
-                    variable_axes={"params": 0},
-                    split_rngs={"params": True},
-                    in_axes=(nn.broadcast, nn.broadcast, nn.broadcast),
-                    length=length,
-                    metadata_params={nn.PARTITION_NAME: "layers"},
-                )(cfg, name="layers")
-                hidden, _ = scanned(hidden, segment_ids, cos, sin)
-            else:
-                scanned = nn.scan(
-                    body,
-                    variable_axes={"params": 0},
-                    split_rngs={"params": True},
-                    in_axes=(nn.broadcast, nn.broadcast, nn.broadcast, 0,
-                             nn.broadcast, nn.broadcast),
-                    length=length,
-                    metadata_params={nn.PARTITION_NAME: "layers"},
-                )(cfg, name="layers")
-                # the cache is carried, the layer's index scanned over
-                (hidden, new_kv), _ = scanned(
-                    (hidden, decode_kv), segment_ids, cos, sin,
-                    jnp.arange(length, dtype=jnp.int32), kv_index, kv_segment_ids,
-                )
-            return hidden, new_kv
-        new_kv = decode_kv
+            hidden, _, cache = scan_layers(
+                body, (cfg,), length, hidden, (segment_ids, cos, sin), cache
+            )
+            return hidden, cache
         for i in range(cfg.num_hidden_layers):
             layer_cls = GemmaDecoderLayer
             if policy is not None:
@@ -276,13 +235,10 @@ class Gemma(nn.Module):
             lcos, lsin = (
                 (cos_local, sin_local) if cfg.version == 3 and window else (cos, sin)
             )
-            hidden = layer_cls(
-                cfg, window, name=f"layers_{i}"
-            )(hidden, segment_ids, lcos, lsin, new_kv, kv_index, kv_segment_ids,
-              None if decode_kv is None else i)
-            if decode_kv is not None:
-                hidden, new_kv = hidden
-        return hidden, new_kv
+            hidden, cache = layer_cls(cfg, window, name=f"layers_{i}")(
+                hidden, segment_ids, lcos, lsin, cache, i
+            )
+        return hidden, cache
 
     @nn.compact
     def __call__(
@@ -315,23 +271,7 @@ class Gemma(nn.Module):
         hidden = inputs_embeds * normalizer
         seq = hidden.shape[1]
 
-        paged = isinstance(decode_state, PagedDecodeState)
-        kv_segment_ids = None
-        if decode_state is not None and not paged:
-            # shared-stack KV-cache convention (llama/model.py): merge the
-            # chunk's segment ids into the cache's filled-slot map up front
-            if segment_ids is None:
-                segment_ids = jnp.ones((hidden.shape[0], seq), jnp.int32)
-            kv_segment_ids = jax.lax.dynamic_update_slice(
-                decode_state.segment_ids, segment_ids.astype(jnp.int32),
-                (0, decode_state.index),
-            )
-        elif paged:
-            # paged plumbing (llama/model.py): kv_index carries the per-row
-            # lengths, kv_segment_ids the block table
-            if segment_ids is None:
-                segment_ids = jnp.ones((hidden.shape[0], seq), jnp.int32)
-            kv_segment_ids = decode_state.block_tables
+        cache, segment_ids = open_cache(decode_state, segment_ids, hidden.shape[0], seq)
 
         if position_ids is None:
             position_ids = jnp.arange(seq)[None, :]
@@ -349,32 +289,10 @@ class Gemma(nn.Module):
                 inv_freq_l, position_ids, scaling_l
             )
 
-        hidden, new_kv = self._layers(
-            hidden, segment_ids, cos, sin, cos_local, sin_local,
-            decode_kv=(
-                None if decode_state is None
-                else (decode_state.k, decode_state.v)
-            ),
-            kv_index=(
-                None if decode_state is None
-                else decode_state.lengths if paged
-                else decode_state.index
-            ),
-            kv_segment_ids=kv_segment_ids,
+        hidden, cache = self._layers(
+            hidden, segment_ids, cos, sin, cos_local, sin_local, cache
         )
-        new_decode_state = None
-        if paged:
-            new_decode_state = decode_state.replace(
-                k=new_kv[0], v=new_kv[1],
-                lengths=decode_state.lengths
-                + jnp.sum(segment_ids > 0, axis=1).astype(jnp.int32),
-            )
-        elif decode_state is not None:
-            new_decode_state = decode_state.replace(
-                k=new_kv[0], v=new_kv[1],
-                index=decode_state.index + seq,
-                segment_ids=kv_segment_ids,
-            )
+        new_decode_state = close_cache(cache, decode_state, segment_ids)
         hidden = GemmaRMSNorm(cfg.rms_norm_eps, cfg.param_jnp_dtype, name="norm")(hidden)
         hidden = nn.with_logical_constraint(hidden, ("batch", "act_seq", "act_embed"))
 

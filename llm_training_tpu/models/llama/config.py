@@ -12,7 +12,7 @@ from typing import Any, Literal
 
 from pydantic import model_validator
 
-from llm_training_tpu.models.base import BaseModelConfig
+from llm_training_tpu.models.base import BaseModelConfig, KVCacheSpec
 from llm_training_tpu.ops.rope_utils import RoPEConfig
 
 
@@ -280,6 +280,13 @@ class LlamaConfig(BaseModelConfig):
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or self.hidden_size // self.num_attention_heads
+
+    def cache_specs(self) -> tuple[KVCacheSpec, None]:
+        """Every layer caches keys and values (`BaseModelConfig.cache_specs`)."""
+        return (
+            KVCacheSpec(self.num_hidden_layers, self.num_key_value_heads, self.resolved_head_dim),
+            None,
+        )
 
     @property
     def rope_config(self) -> RoPEConfig:

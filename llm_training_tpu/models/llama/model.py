@@ -28,12 +28,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from llm_training_tpu.models.base import (
-    CausalLMOutput,
-    DecodeState,
-    PagedDecodeState,
-    RouterStats,
-)
+from llm_training_tpu.models.base import CausalLMOutput, DecodeState, RouterStats
+from llm_training_tpu.models.cache import LayerCache, close_cache, open_cache, scan_layers
 from llm_training_tpu.models.remat import remat_policy as _remat_policy
 from llm_training_tpu.models.llama.config import LlamaConfig
 from llm_training_tpu.ops import apply_rope, dot_product_attention, rms_norm
@@ -138,63 +134,6 @@ def _dense(config: LlamaConfig, features: int, logical_axes: tuple[str, str], na
     )
 
 
-def cached_attention(q, k, v, segment_ids, layer_kv, kv_index, kv_segment_ids,
-                     layer, window=None, scale=None, logits_soft_cap=None):
-    """Append this chunk's k/v to layer `layer` of the whole stack's cache
-    and attend q against that layer's cache; returns `(out, the stack's new
-    cache)`. `layer_kv` is the `(k, v)` pair of EVERY layer, leading axis
-    over layers, as the layer loop carries it (`carry_layers`): a layer
-    writes its new rows into the carried buffers in place and reads its own
-    part of them; its slice is never cut out and put back. `layer` is the
-    loop's index, traced under a scan, a Python int in a Python loop.
-
-    Dense (`DecodeState`: `[L, B, max_length, kv_heads, head_dim]`): the
-    chunk goes in at the shared `kv_index`. The causal term of the mask
-    (q_offset = kv_index) hides slots written after this chunk, and
-    `kv_segment_ids` (0 on unwritten/pad slots) hides garbage — so ONE
-    program serves both prefill (chunk at index 0) and single-token decode
-    steps. Dense-cache attention is always the XLA einsum path: the flash
-    kernel's block tiling assumes q_len ≥ a block and a static q_offset.
-
-    A PAGED cache (`PagedDecodeState`, serve/ subsystem: `[L, blocks,
-    kv_heads, page, head_dim]`) arrives through the same plumbing with
-    per-ROW lengths in `kv_index` ([B], vs the dense scalar) and the block
-    table in `kv_segment_ids` — dispatched to `ops.paged_attention` (page
-    writer and ragged Pallas decode kernel on TPU, XLA elsewhere). Shared by
-    every softmax-attention module that decodes (`LlamaAttention`,
-    `GemmaAttention`, `solar_open2.GatedAttention`)."""
-    if kv_index.ndim == 1:
-        from llm_training_tpu.ops.paged_attention import paged_cached_attention
-
-        return paged_cached_attention(
-            q, k, v, layer_kv, kv_index, kv_segment_ids,
-            layer=layer,
-            segment_ids=segment_ids,
-            sliding_window=window,
-            logits_soft_cap=logits_soft_cap,
-            scale=scale,
-        )
-    ck, cv = (
-        jax.lax.dynamic_update_slice(
-            cache, new[None].astype(cache.dtype), (layer, 0, kv_index, 0, 0)
-        )
-        for cache, new in zip(layer_kv, (k, v))
-    )
-    mine = lambda cache: jax.lax.dynamic_index_in_dim(cache, layer, keepdims=False)
-    out = dot_product_attention(
-        q, mine(ck).astype(k.dtype), mine(cv).astype(v.dtype),
-        segment_ids=kv_segment_ids,
-        q_segment_ids=segment_ids,
-        causal=True,
-        sliding_window=window,
-        logits_soft_cap=logits_soft_cap,
-        scale=scale,
-        q_offset=kv_index,
-        impl="xla",
-    )
-    return out, (ck, cv)
-
-
 class LlamaAttention(nn.Module):
     """GQA attention (reference `llama_model.py:434-663`).
 
@@ -210,14 +149,10 @@ class LlamaAttention(nn.Module):
     carry `sliding_window` and `attention_compute_dtype` (Phi-3's SDPA
     upcast workaround, `phi3_model.py:172-187`).
 
-    KV-cache decoding (docs/inference.md): `layer_kv` is the WHOLE stack's
-    `(k, v)` cache buffers `[layers, batch, max_length, kv_heads, head_dim]`
-    and `layer` this layer's index in them (`cached_attention`); `kv_index`
-    the shared append position and `kv_segment_ids` the cache's filled-slot
-    ids (already including the incoming chunk). When given, the post-RoPE
-    k/v are appended at `kv_index` of this layer's part and attention runs
-    against that part with `q_offset = kv_index`, and the call returns
-    `(out, new_layer_kv)` instead of `out`."""
+    KV-cache decoding (docs/inference.md): with a `cache` (`models/cache.py`)
+    the post-RoPE k/v are appended to layer `layer`'s part of it and
+    attention runs against that part. Returns `(out, cache)`, the cache as
+    this layer left it (None when none came in)."""
 
     config: LlamaConfig
     sliding_window_override: int | None | str = "unset"
@@ -229,11 +164,9 @@ class LlamaAttention(nn.Module):
         segment_ids: jnp.ndarray | None,
         cos: jnp.ndarray,
         sin: jnp.ndarray,
-        layer_kv: tuple[jnp.ndarray, jnp.ndarray] | None = None,
-        kv_index: jnp.ndarray | None = None,
-        kv_segment_ids: jnp.ndarray | None = None,
+        cache: LayerCache | None = None,
         layer: jnp.ndarray | int | None = None,
-    ) -> jnp.ndarray:
+    ) -> tuple[jnp.ndarray, LayerCache | None]:
         cfg = self.config
         head_dim = cfg.resolved_head_dim
         batch, seq, _ = hidden.shape
@@ -311,43 +244,29 @@ class LlamaAttention(nn.Module):
             dtype = resolve_dtype(attention_dtype)
             q, k, v = q.astype(dtype), k.astype(dtype), v.astype(dtype)
 
-        new_layer_kv = None
-        if layer_kv is not None:
-            out, new_layer_kv = self._cached_attention(
-                q, k, v, segment_ids, layer_kv, kv_index, kv_segment_ids, layer
-            )
-        else:
-            out = self._attention(q, k, v, segment_ids)
-        out = out.astype(hidden.dtype)
-        out = out.reshape(batch, seq, cfg.num_attention_heads * head_dim)
-        out = _dense(cfg, cfg.hidden_size, ("heads", "embed"), "o_proj", cfg.attention_out_bias)(out)
-        if layer_kv is not None:
-            return out, new_layer_kv
-        return out
-
-    def _cached_attention(self, q, k, v, segment_ids, layer_kv, kv_index,
-                          kv_segment_ids, layer):
-        cfg = self.config
         window = (
             getattr(cfg, "sliding_window", None)
             if self.sliding_window_override == "unset"
             else self.sliding_window_override
         )
-        return cached_attention(
-            q, k, v, segment_ids, layer_kv, kv_index, kv_segment_ids, layer,
-            window=window, scale=getattr(cfg, "attention_multiplier", None),
-        )
+        # Granite replaces 1/sqrt(head_dim) with a config scalar
+        scale = getattr(cfg, "attention_multiplier", None)
+        if cache is not None:
+            out, cache = cache.attend(
+                layer, q, k, v, segment_ids, window=window, scale=scale
+            )
+        else:
+            out = self._attention(q, k, v, segment_ids, window, scale)
+        out = out.astype(hidden.dtype)
+        out = out.reshape(batch, seq, cfg.num_attention_heads * head_dim)
+        out = _dense(cfg, cfg.hidden_size, ("heads", "embed"), "o_proj", cfg.attention_out_bias)(out)
+        return out, cache
 
-    def _attention(self, q, k, v, segment_ids):
+    def _attention(self, q, k, v, segment_ids, window, scale):
         """Dispatch: ring attention over a sequence-sharded mesh when enabled,
         otherwise the single-device flash/XLA path (GSPMD handles any other
         sharding by inserting collectives itself)."""
         cfg = self.config
-        window = (
-            getattr(cfg, "sliding_window", None)
-            if self.sliding_window_override == "unset"
-            else self.sliding_window_override
-        )
         if getattr(cfg, "ring_attention", False):
             from llm_training_tpu.parallel.ring_attention import (
                 dispatch_ring_attention,
@@ -356,7 +275,7 @@ class LlamaAttention(nn.Module):
             out = dispatch_ring_attention(
                 q, k, v, segment_ids,
                 sliding_window=window,
-                scale=getattr(cfg, "attention_multiplier", None),
+                scale=scale,
                 impl=cfg.attention_impl,
             )
             if out is not None:
@@ -366,8 +285,7 @@ class LlamaAttention(nn.Module):
             segment_ids=segment_ids,
             causal=True,
             sliding_window=window,
-            # Granite replaces 1/sqrt(head_dim) with a config scalar
-            scale=getattr(cfg, "attention_multiplier", None),
+            scale=scale,
             impl=cfg.attention_impl,
         )
 
@@ -430,12 +348,9 @@ class LlamaMLP(nn.Module):
 
 
 class LlamaDecoderLayer(nn.Module):
-    """Pre-norm block (reference `llama_model.py:747-789`).
-
-    With a KV cache (`layer_kv` et al. — see `LlamaAttention`) the layer
-    returns `(hidden, (aux, new_layer_kv))`; without one the return stays
-    `(hidden, aux)` and the traced graph is identical to before the cache
-    existed."""
+    """Pre-norm block (reference `llama_model.py:747-789`). Returns `(hidden,
+    aux, cache)`: `cache` (`LlamaAttention`) is None without one, and the
+    traced graph is then identical to before the cache existed."""
 
     config: LlamaConfig
     sliding_window_override: int | None | str = "unset"
@@ -447,34 +362,17 @@ class LlamaDecoderLayer(nn.Module):
         segment_ids: jnp.ndarray | None,
         cos: jnp.ndarray,
         sin: jnp.ndarray,
-        layer_kv: tuple[jnp.ndarray, jnp.ndarray] | None = None,
-        kv_index: jnp.ndarray | None = None,
-        kv_segment_ids: jnp.ndarray | None = None,
+        cache: LayerCache | None = None,
         layer: jnp.ndarray | int | None = None,
-    ) -> jnp.ndarray:
+    ) -> tuple[jnp.ndarray, jnp.ndarray, LayerCache | None]:
         cfg = self.config
         hidden = nn.with_logical_constraint(hidden, ("batch", "act_seq", "act_embed"))
         norm = lambda name: _norm_cls(cfg)(cfg.rms_norm_eps, cfg.param_jnp_dtype, name=name)
 
-        new_kv = [None]  # box: written by whichever branch runs attention
-
-        def attention(name):
-            module = LlamaAttention(cfg, self.sliding_window_override, name=name)
-
-            def run(x, seg, c, s):
-                if layer_kv is None:
-                    return module(x, seg, c, s)
-                out, new_kv[0] = module(
-                    x, seg, c, s, layer_kv, kv_index, kv_segment_ids, layer
-                )
-                return out
-
-            return run
-
-        def pack(hidden, aux):
-            if layer_kv is None:
-                return hidden, aux
-            return hidden, (aux, new_kv[0])
+        def attention(x):
+            return LlamaAttention(cfg, self.sliding_window_override, name="self_attn")(
+                x, segment_ids, cos, sin, cache, layer
+            )
 
         def mlp(x):
             """(out, aux): MoE block returns per-layer router stats
@@ -497,65 +395,57 @@ class LlamaDecoderLayer(nn.Module):
             # Cohere: ONE input norm feeds attention and mlp; both outputs
             # join the residual in a single add
             normed = norm("input_layernorm")(hidden)
-            attn = attention("self_attn")(normed, segment_ids, cos, sin)
+            attn, cache = attention(normed)
             mlp_out, aux = mlp(normed)
-            hidden = hidden + join(attn) + join(mlp_out)
-            return pack(hidden, aux)
+            return hidden + join(attn) + join(mlp_out), aux, cache
         if cfg.norm_scheme == "parallel2":
             # GPT-NeoX: TWO norms over the SAME block input feed attention
             # and mlp in parallel; one residual join
-            attn = attention("self_attn")(
-                norm("input_layernorm")(hidden), segment_ids, cos, sin
-            )
+            attn, cache = attention(norm("input_layernorm")(hidden))
             mlp_out, aux = mlp(norm("post_attention_layernorm")(hidden))
-            hidden = hidden + join(attn) + join(mlp_out)
-            return pack(hidden, aux)
+            return hidden + join(attn) + join(mlp_out), aux, cache
         if cfg.norm_scheme == "sandwich":
             # GLM-4: pre-norm AND output-norm around both blocks
-            normed = norm("input_layernorm")(hidden)
-            attn = attention("self_attn")(normed, segment_ids, cos, sin)
+            attn, cache = attention(norm("input_layernorm")(hidden))
             hidden = hidden + join(norm("post_self_attn_layernorm")(attn))
             normed = norm("post_attention_layernorm")(hidden)
             mlp_out, aux = mlp(normed)
-            hidden = hidden + join(norm("post_mlp_layernorm")(mlp_out))
-            return pack(hidden, aux)
+            return hidden + join(norm("post_mlp_layernorm")(mlp_out)), aux, cache
         if cfg.norm_scheme == "post":
             # OLMo-2 reordering: no input norms; normalize each block's
             # OUTPUT before it joins the residual stream
-            attn = attention("self_attn")(hidden, segment_ids, cos, sin)
+            attn, cache = attention(hidden)
             hidden = hidden + join(norm("post_attention_layernorm")(attn))
             mlp_out, aux = mlp(hidden)
-            hidden = hidden + join(norm("post_feedforward_layernorm")(mlp_out))
-            return pack(hidden, aux)
-        normed = norm("input_layernorm")(hidden)
-        hidden = hidden + join(attention("self_attn")(normed, segment_ids, cos, sin))
+            return hidden + join(norm("post_feedforward_layernorm")(mlp_out)), aux, cache
+        attn, cache = attention(norm("input_layernorm")(hidden))
+        hidden = hidden + join(attn)
         normed = norm("post_attention_layernorm")(hidden)
         mlp_out, aux = mlp(normed)
-        hidden = hidden + join(mlp_out)
-        return pack(hidden, aux)
+        return hidden + join(mlp_out), aux, cache
 
 
 class _ScannedLayer(nn.Module):
     """Adapter giving LlamaDecoderLayer the (carry, xs) -> (carry, ys)
     signature nn.scan expects; ys carries the per-layer MoE aux loss. The
-    carry is `hidden` or, when decoding, `(hidden, the whole stack's KV
-    cache)` with the layer's index `layer` as the scanned input: the cache
-    rides the loop and each layer writes its new rows into it in place."""
+    carry is `hidden` or, when decoding, `(hidden, the cache's buffers)`
+    with the layer's index `layer` as the scanned input
+    (`models/cache.py:scan_layers`)."""
 
     config: LlamaConfig
     layer_cls: type
 
     @nn.compact
-    def __call__(self, carry, segment_ids, cos, sin,
-                 layer=None, kv_index=None, kv_segment_ids=None):
+    def __call__(self, carry, segment_ids, cos, sin, cache=None, layer=None):
         block = self.layer_cls(self.config, name="layer")
-        if layer is None:
-            return block(carry, segment_ids, cos, sin)
-        hidden, decode_kv = carry
-        hidden, (aux, decode_kv) = block(
-            hidden, segment_ids, cos, sin, decode_kv, kv_index, kv_segment_ids, layer
+        if cache is None:
+            hidden, aux, _ = block(carry, segment_ids, cos, sin)
+            return hidden, aux
+        hidden, buffers = carry
+        hidden, aux, cache = block(
+            hidden, segment_ids, cos, sin, cache.holding(buffers), layer
         )
-        return (hidden, decode_kv), aux
+        return (hidden, cache.buffers), aux
 
 
 class Llama(nn.Module):
@@ -569,8 +459,8 @@ class Llama(nn.Module):
     config: LlamaConfig
 
     def _layers(self, hidden, segment_ids, cos, sin, local_cos=None, local_sin=None,
-                decode_kv=None, kv_index=None, kv_segment_ids=None):
-        """Returns (hidden, aux_loss, ep_dropped_rows, layer_stats, new_kv).
+                cache=None):
+        """Returns (hidden, aux_loss, ep_dropped_rows, layer_stats, cache).
         For MoE configs the per-layer router stats (sel_frac, mean_prob,
         dropped) are pooled across depth BEFORE the E * sum(f * P) product —
         matching HF `load_balancing_loss_func`, which concatenates all
@@ -579,18 +469,16 @@ class Llama(nn.Module):
         (sel_frac [L, E], mean_prob [L, E]) pair for the health layer
         (None for dense configs).
 
-        `decode_kv` is the whole-stack KV cache `(k, v)` with leading layer
-        axis. It rides the layer loop as its carry (beside `hidden` under
-        scan_layers, a Python variable on the looped path) and every layer
-        updates its own part in place (`cached_attention`). `new_kv` is the
-        updated stack (None on the training path)."""
+        `cache` (`models/cache.py`) rides the layer loop: its buffers as the
+        carry beside `hidden` under scan_layers, a Python variable on the
+        looped path. It comes back as the layers left it (None on the
+        training path)."""
         cfg = self.config
         policy = _remat_policy(cfg)
-        new_kv = None
         if getattr(cfg, "pipeline_stages", 1) > 1:
             from llm_training_tpu.models.pipeline import PipelinedLayers
 
-            if decode_kv is not None:
+            if cache is not None:
                 raise NotImplementedError(
                     "KV-cache decoding does not compose with "
                     "pipeline_stages > 1; restore the checkpoint with "
@@ -612,36 +500,10 @@ class Llama(nn.Module):
                 layer_cls = nn.remat(
                     _ScannedLayer, policy=policy, prevent_cse=False,
                 )
-            if decode_kv is None:
-                scanned = nn.scan(
-                    layer_cls,
-                    variable_axes={"params": 0},
-                    split_rngs={"params": True},
-                    in_axes=(nn.broadcast, nn.broadcast, nn.broadcast),
-                    length=cfg.num_hidden_layers,
-                    metadata_params={nn.PARTITION_NAME: "layers"},
-                )(cfg, LlamaDecoderLayer, name="layers")
-                hidden, aux = scanned(hidden, segment_ids, cos, sin)
-            else:
-                # the cache is CARRIED and the layer's index scanned over: as
-                # a scanned input and output each step would cut its slice
-                # out of the stack and write a whole slice into a new one
-                # (same param scope as the training-path scan above — only
-                # one of the two traces per call)
-                scanned = nn.scan(
-                    layer_cls,
-                    variable_axes={"params": 0},
-                    split_rngs={"params": True},
-                    in_axes=(nn.broadcast, nn.broadcast, nn.broadcast, 0,
-                             nn.broadcast, nn.broadcast),
-                    length=cfg.num_hidden_layers,
-                    metadata_params={nn.PARTITION_NAME: "layers"},
-                )(cfg, LlamaDecoderLayer, name="layers")
-                (hidden, new_kv), aux = scanned(
-                    (hidden, decode_kv), segment_ids, cos, sin,
-                    jnp.arange(cfg.num_hidden_layers, dtype=jnp.int32), kv_index,
-                    kv_segment_ids,
-                )
+            hidden, aux, cache = scan_layers(
+                layer_cls, (cfg, LlamaDecoderLayer), cfg.num_hidden_layers,
+                hidden, (segment_ids, cos, sin), cache,
+            )
         else:
             no_rope = getattr(cfg, "no_rope_layers", None)
             if no_rope is not None and cos is not None:
@@ -651,7 +513,6 @@ class Llama(nn.Module):
                 id_sin = jnp.zeros_like(sin)
             layer_types = getattr(cfg, "layer_types", None)
             stats = []
-            new_kv = decode_kv
             for i in range(cfg.num_hidden_layers):
                 layer_cls = LlamaDecoderLayer
                 if policy is not None:
@@ -667,21 +528,18 @@ class Llama(nn.Module):
                 elif layer_types is not None and window and local_cos is not None:
                     # OLMo-3: sliding layers rotate with the UNSCALED tables
                     lcos, lsin = local_cos, local_sin
-                hidden, layer_ys = layer_cls(cfg, window, name=f"layers_{i}")(
-                    hidden, segment_ids, lcos, lsin, new_kv, kv_index,
-                    kv_segment_ids, None if decode_kv is None else i,
+                hidden, layer_ys, cache = layer_cls(cfg, window, name=f"layers_{i}")(
+                    hidden, segment_ids, lcos, lsin, cache, i
                 )
-                if decode_kv is not None:
-                    layer_ys, new_kv = layer_ys
                 stats.append(layer_ys)
             aux = jax.tree.map(lambda *xs: jnp.stack(xs), *stats)
         if not cfg.num_experts:
-            return hidden, jnp.float32(0.0), jnp.float32(0.0), None, new_kv
+            return hidden, jnp.float32(0.0), jnp.float32(0.0), None, cache
         sel_frac, mean_prob, dropped = aux  # [L, E], [L, E], [L]
         aux_loss = cfg.num_experts * jnp.sum(
             sel_frac.mean(axis=0) * mean_prob.mean(axis=0)
         )
-        return hidden, aux_loss, dropped.sum(), (sel_frac, mean_prob), new_kv
+        return hidden, aux_loss, dropped.sum(), (sel_frac, mean_prob), cache
 
     @nn.compact
     def __call__(
@@ -715,28 +573,7 @@ class Llama(nn.Module):
             hidden = hidden * jnp.asarray(em, hidden.dtype)
         seq = hidden.shape[1]
 
-        paged = isinstance(decode_state, PagedDecodeState)
-        kv_segment_ids = None
-        if decode_state is not None and not paged:
-            # the chunk's q-side segment ids (pads 0, real tokens 1) double
-            # as the cache-slot ids for the slots it writes; merge them into
-            # the cache's filled-slot map BEFORE the layers so every layer
-            # masks against the same updated view
-            if segment_ids is None:
-                segment_ids = jnp.ones((hidden.shape[0], seq), jnp.int32)
-            kv_segment_ids = jax.lax.dynamic_update_slice(
-                decode_state.segment_ids, segment_ids.astype(jnp.int32),
-                (0, decode_state.index),
-            )
-        elif paged:
-            # paged plumbing reuses the dense arg slots: kv_index carries
-            # the per-row lengths, kv_segment_ids the block table (see
-            # LlamaAttention._cached_attention); q-side segment ids mark
-            # padded chunk positions, which the paged append redirects to
-            # the trash block
-            if segment_ids is None:
-                segment_ids = jnp.ones((hidden.shape[0], seq), jnp.int32)
-            kv_segment_ids = decode_state.block_tables
+        cache, segment_ids = open_cache(decode_state, segment_ids, hidden.shape[0], seq)
 
         if position_ids is None:
             position_ids = jnp.arange(seq)[None, :]
@@ -799,34 +636,10 @@ class Llama(nn.Module):
                 half = local_cos.shape[-1] // 2
                 local_cos = jnp.repeat(local_cos[..., :half], 2, axis=-1)
                 local_sin = jnp.repeat(local_sin[..., :half], 2, axis=-1)
-        hidden, aux_loss, ep_dropped, layer_stats, new_kv = self._layers(
-            hidden, segment_ids, cos, sin, local_cos, local_sin,
-            decode_kv=(
-                None if decode_state is None
-                else (decode_state.k, decode_state.v)
-            ),
-            kv_index=(
-                None if decode_state is None
-                else decode_state.lengths if paged
-                else decode_state.index
-            ),
-            kv_segment_ids=kv_segment_ids,
+        hidden, aux_loss, ep_dropped, layer_stats, cache = self._layers(
+            hidden, segment_ids, cos, sin, local_cos, local_sin, cache
         )
-        new_decode_state = None
-        if paged:
-            # per-row advance by the chunk's REAL token count (padded tail
-            # positions of a final prefill chunk don't occupy cache slots)
-            new_decode_state = decode_state.replace(
-                k=new_kv[0], v=new_kv[1],
-                lengths=decode_state.lengths
-                + jnp.sum(segment_ids > 0, axis=1).astype(jnp.int32),
-            )
-        elif decode_state is not None:
-            new_decode_state = decode_state.replace(
-                k=new_kv[0], v=new_kv[1],
-                index=decode_state.index + seq,
-                segment_ids=kv_segment_ids,
-            )
+        new_decode_state = close_cache(cache, decode_state, segment_ids)
         hidden = _norm_cls(cfg)(cfg.rms_norm_eps, cfg.param_jnp_dtype, name="norm")(hidden)
         mult = getattr(cfg, "logit_scale", None)
         if mult is not None:

@@ -26,8 +26,8 @@ segment id 0 (padding, idle decode slots) change nothing.
 
 The stack scans over periods of the layer pattern ([GQA, KDA, KDA, KDA] as
 published). When decoding, the pool and the slab ride that loop as its carry
-beside `hidden`, whole: a layer writes its new rows into them in place
-(`llama.model.cached_attention`, `_put_rows`) and reads its own part.
+beside `hidden`, whole: a layer writes its new rows into them in place and
+reads its own part (`models/cache.py`).
 """
 
 from __future__ import annotations
@@ -42,8 +42,9 @@ from llm_training_tpu.models.base import (
     PagedDecodeState,
     RouterStats,
 )
+from llm_training_tpu.models.cache import _slot_rows, close_cache, open_cache, scan_layers
 from llm_training_tpu.models.deepseek.model import DeepseekMoE
-from llm_training_tpu.models.llama.model import RMSNorm, _dense, cached_attention
+from llm_training_tpu.models.llama.model import RMSNorm, _dense
 from llm_training_tpu.models.remat import remat_policy as _remat_policy
 from llm_training_tpu.models.solar_open2.config import SolarOpen2Config
 from llm_training_tpu.models.solar_open2.kda import kda_chunked, kda_step
@@ -55,14 +56,14 @@ def _l2norm(x: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
 
 
 class KimiDeltaAttention(nn.Module):
-    """`cache` is this layer's `(state [B, H, dk, dv] float32, tail
+    """`rows` is this layer's `(state [B, H, dk, dv] float32, tail
     [B, taps, 3 * H * dk])` for the batch's rows, or None (training: zero
-    state, zero tail). With a cache the call returns `(out, new_cache)`."""
+    state, zero tail). Returns `(out, new rows)`, the rows None without any."""
 
     config: SolarOpen2Config
 
     @nn.compact
-    def __call__(self, hidden, segment_ids=None, cache=None):
+    def __call__(self, hidden, segment_ids=None, rows=None):
         cfg = self.config
         batch, seq, _ = hidden.shape
         heads, dim = cfg.linear_num_heads, cfg.linear_head_dim
@@ -90,8 +91,8 @@ class KimiDeltaAttention(nn.Module):
             # a padded position feeds nothing: not the conv, not the tail
             mixed = jnp.where(valid[..., None], mixed, 0)
             tail = (
-                jnp.zeros((batch, taps, 3 * width), mixed.dtype) if cache is None
-                else cache[1].astype(mixed.dtype)
+                jnp.zeros((batch, taps, 3 * width), mixed.dtype) if rows is None
+                else rows[1].astype(mixed.dtype)
             )
             padded = jnp.concatenate([tail, mixed], axis=1)
             if cut:
@@ -142,9 +143,9 @@ class KimiDeltaAttention(nn.Module):
             beta = jnp.where(valid[..., None], beta, 0.0)
 
         state = (
-            jnp.zeros((batch, heads, dim, dim), jnp.float32) if cache is None else cache[0]
+            jnp.zeros((batch, heads, dim, dim), jnp.float32) if rows is None else rows[0]
         )
-        if cache is not None and seq == 1:
+        if rows is not None and seq == 1:
             with jax.named_scope("kda_recurrence"):
                 state, out = kda_step(
                     state, q[:, 0], k[:, 0], v[:, 0], log_alpha[:, 0], beta[:, 0]
@@ -160,22 +161,19 @@ class KimiDeltaAttention(nn.Module):
         out = RMSNorm(cfg.rms_norm_eps, cfg.param_jnp_dtype, name="o_norm")(out)
         out = (out.reshape(batch, seq, width) * gate).astype(hidden.dtype)
         out = _dense(cfg, cfg.hidden_size, ("heads", "embed"), "o_proj", False)(out)
-        if cache is None:
-            return out
-        return out, (state, new_tail.astype(cache[1].dtype))
+        return out, None if rows is None else (state, new_tail.astype(rows[1].dtype))
 
 
 class GatedAttention(nn.Module):
     """Softmax attention with no positional term, gated an output channel.
-    `cache`, `kv_index`, `kv_segment_ids`, `layer`: as `LlamaAttention`'s
-    `layer_kv` plumbing (`llama.model.cached_attention`), dense or paged:
-    the cache of every GQA layer, and this layer's index in it."""
+    Returns `(out, cache)`: with a `cache` (`models/cache.py`) k/v are
+    appended to part `layer` of it (this layer's index among the stack's GQA
+    layers) and attention runs against that part."""
 
     config: SolarOpen2Config
 
     @nn.compact
-    def __call__(self, hidden, segment_ids=None, cache=None, kv_index=None,
-                 kv_segment_ids=None, layer=None):
+    def __call__(self, hidden, segment_ids=None, cache=None, layer=None):
         cfg = self.config
         batch, seq, _ = hidden.shape
         heads, kv_heads, dim = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
@@ -185,11 +183,8 @@ class GatedAttention(nn.Module):
         q = q.reshape(batch, seq, heads, dim)
         k = k.reshape(batch, seq, kv_heads, dim)
         v = v.reshape(batch, seq, kv_heads, dim)
-        new_cache = None
         if cache is not None:
-            out, new_cache = cached_attention(
-                q, k, v, segment_ids, cache, kv_index, kv_segment_ids, layer
-            )
+            out, cache = cache.attend(layer, q, k, v, segment_ids)
         else:
             out = dot_product_attention(
                 q, k, v, segment_ids=segment_ids, causal=True, impl=cfg.attention_impl
@@ -198,118 +193,75 @@ class GatedAttention(nn.Module):
         if cfg.use_gqa_gate:
             gate = _dense(cfg, heads * dim, ("embed", "heads"), "g_proj", False)(hidden)
             out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(out.dtype)
-        out = _dense(cfg, cfg.hidden_size, ("heads", "embed"), "o_proj", False)(out)
-        if cache is None:
-            return out
-        return out, new_cache
+        return _dense(cfg, cfg.hidden_size, ("heads", "embed"), "o_proj", False)(out), cache
 
 
 class SolarOpen2DecoderLayer(nn.Module):
-    """Returns (hidden, router stats, new cache or None). `cache` is, for a
-    GQA layer, the pool (or dense buffers) of every GQA layer with `layer`
-    this one's index in it; for a KDA layer, its rows of the slab."""
+    """Returns (hidden, router stats, cache). `layer` is this layer's index
+    among the stack's layers of its kind: a GQA layer's part of the cache's
+    pool (or dense buffers), a KDA layer's rows of its slab."""
 
     config: SolarOpen2Config
     is_gqa: bool
 
     @nn.compact
-    def __call__(self, hidden, segment_ids=None, cache=None, kv_index=None,
-                 kv_segment_ids=None, layer=None):
+    def __call__(self, hidden, segment_ids=None, cache=None, layer=None):
         cfg = self.config
         hidden = nn.with_logical_constraint(hidden, ("batch", "act_seq", "act_embed"))
         norm = lambda name: RMSNorm(cfg.rms_norm_eps, cfg.param_jnp_dtype, name=name)
-        normed = norm("input_layernorm")(hidden)
+        rows = None
         if self.is_gqa:
-            mixed = GatedAttention(cfg, name="self_attn")(
-                normed, segment_ids, cache, kv_index, kv_segment_ids, layer
+            mixed, cache = GatedAttention(cfg, name="self_attn")(
+                norm("input_layernorm")(hidden), segment_ids, cache, layer
             )
         else:
-            mixed = KimiDeltaAttention(cfg, name="linear_attn")(normed, segment_ids, cache)
-        new_cache = None
-        if cache is not None:
-            mixed, new_cache = mixed
+            if cache is not None:
+                # `_slot_rows` by this module's name: where the benchmark's
+                # tests plant their fault (`LayerCache.recurrent_rows`)
+                rows = cache.recurrent_rows(layer, _slot_rows)
+            mixed, rows = KimiDeltaAttention(cfg, name="linear_attn")(
+                norm("input_layernorm")(hidden), segment_ids, rows
+            )
         hidden = hidden + mixed
         pad_mask = None if segment_ids is None else segment_ids > 0
         mlp_out, stats = DeepseekMoE(cfg, name="mlp")(
             norm("post_attention_layernorm")(hidden), pad_mask
         )
-        return hidden + mlp_out, stats, new_cache
-
-
-def _slot_rows(slab, slots, fresh):
-    """A slab layer's rows for this batch: `slots` picks them (None: row i is
-    slot i), and a row whose request starts here reads zeros."""
-    rows = slab if slots is None else slab[slots]
-    if fresh is not None:
-        rows = jnp.where(fresh.reshape((-1,) + (1,) * (rows.ndim - 1)), 0, rows)
-    return rows
-
-
-def _layer_rows(slab, layer, slots, fresh):
-    """`_slot_rows` of layer `layer` of the whole slab `[layers, slots, ...]`.
-    Picked slots are gathered out of the slab seen as one run of `layers *
-    slots` rows, so the layer's part is not cut out first."""
-    if slots is None:
-        mine = jax.lax.dynamic_index_in_dim(slab, layer, keepdims=False)
-        return _slot_rows(mine, None, fresh)
-    flat = slab.reshape(-1, *slab.shape[2:])
-    return _slot_rows(flat, layer * slab.shape[1] + slots, fresh)
-
-
-def _put_rows(slab, layer, slots, rows):
-    """The carried slab `[layers, slots, ...]` with layer `layer`'s rows for
-    this batch replaced, in place: the slab is addressed as one run of
-    `layers * slots` rows, not cut up and restacked."""
-    per_layer = slab.shape[1]
-    flat = slab.reshape(-1, *slab.shape[2:])
-    if slots is None:
-        flat = jax.lax.dynamic_update_slice_in_dim(flat, rows, layer * per_layer, axis=0)
-    else:
-        flat = flat.at[layer * per_layer + slots].set(rows)
-    return flat.reshape(slab.shape)
+        hidden = hidden + mlp_out
+        if rows is not None:
+            cache = cache.put_recurrent_rows(layer, rows)
+        return hidden, stats, cache
 
 
 class _PeriodBody(nn.Module):
     """Scan body: the layers `first .. first + len(kinds)` of the pattern
-    (one period). The carry is `hidden` or, when decoding, `(hidden, pool,
-    slab)`: pool `(k, v)` with a leading axis over ALL the stack's GQA
-    layers, slab `(state, tail)` over all its KDA layers, and `cycle` says
-    which period of them this is. `ctx` holds `kv_index`, `kv_segment_ids`
-    and, for a paged slab, `slots` and `fresh`."""
+    (one period). The carry is `hidden` or, when decoding, `(hidden, the
+    cache's buffers)`: the pool with a leading axis over ALL the stack's GQA
+    layers, the slab over all its KDA layers, and `cycle` says which period
+    of them this is (`models/cache.py:scan_layers`)."""
 
     config: SolarOpen2Config
     kinds: tuple[bool, ...]
 
     @nn.compact
-    def __call__(self, carry, segment_ids, ctx=None, cycle=None):
+    def __call__(self, carry, segment_ids, cache=None, cycle=None):
         cfg = self.config
-        ctx = ctx or {}
-        slots, fresh = ctx.get("slots"), ctx.get("fresh")
-        hidden, pool, slab = (carry, None, None) if cycle is None else carry
+        hidden = carry
+        if cache is not None:
+            hidden, buffers = carry
+            cache = cache.holding(buffers)
         stats = []
         for j, is_gqa in enumerate(self.kinds):
-            layer = SolarOpen2DecoderLayer(cfg, is_gqa, name=f"slot{j}")
-            if cycle is None:
-                hidden, layer_stats, _ = layer(hidden, segment_ids)
-                stats.append(layer_stats)
-                continue
             # this layer's index among the stack's layers of its kind
-            index = cycle * self.kinds.count(is_gqa) + self.kinds[:j].count(is_gqa)
-            if is_gqa:
-                hidden, layer_stats, pool = layer(
-                    hidden, segment_ids, pool, ctx["kv_index"], ctx["kv_segment_ids"], index
-                )
-            else:
-                rows = jax.tree.map(lambda a: _layer_rows(a, index, slots, fresh), slab)
-                hidden, layer_stats, new = layer(hidden, segment_ids, rows)
-                # the new rows are whole before they go in: fused into the
-                # update, their computation reads the slab it writes, and the
-                # compiler then copies the whole slab first, once a layer
-                new = jax.lax.optimization_barrier(new)
-                slab = jax.tree.map(lambda a, n: _put_rows(a, index, slots, n), slab, new)
+            index = None if cache is None else (
+                cycle * self.kinds.count(is_gqa) + self.kinds[:j].count(is_gqa)
+            )
+            hidden, layer_stats, cache = SolarOpen2DecoderLayer(cfg, is_gqa, name=f"slot{j}")(
+                hidden, segment_ids, cache, index
+            )
             stats.append(layer_stats)
         stats = jax.tree.map(lambda *leaves: jnp.stack(leaves), *stats)
-        return (hidden if cycle is None else (hidden, pool, slab)), stats
+        return (hidden if cache is None else (hidden, cache.buffers)), stats
 
 
 class SolarOpen2(nn.Module):
@@ -318,39 +270,29 @@ class SolarOpen2(nn.Module):
 
     config: SolarOpen2Config
 
-    def _layers(self, hidden, segment_ids, ctx, caches):
-        """-> (hidden, pooled router stats [L, ...], new (pool, slab) or None)."""
+    def _layers(self, hidden, segment_ids, cache):
+        """-> (hidden, pooled router stats [L, ...], cache or None)."""
         cfg = self.config
         kinds = cfg.layer_kinds
         period = cfg.scan_period or cfg.num_hidden_layers
-        cycles = cfg.num_hidden_layers // period
         body = _PeriodBody
         policy = _remat_policy(cfg)
         if policy is not None:
             body = nn.remat(_PeriodBody, policy=policy, prevent_cse=False)
-        carry = hidden if caches is None else (hidden, *caches)
-        if not cfg.scan_period:
-            # the loop: the whole stack is one body, under the scan's names
-            carry, stats = body(cfg, tuple(kinds), name="layers")(
-                carry, segment_ids, ctx, None if caches is None else 0
+        if cfg.scan_period:
+            hidden, stats, cache = scan_layers(
+                body, (cfg, tuple(kinds[:period])), cfg.num_hidden_layers // period,
+                hidden, (segment_ids,), cache,
             )
-        else:
-            # the caches are CARRIED, whole, and the period's index scanned over
-            scanned = nn.scan(
-                body, variable_axes={"params": 0}, split_rngs={"params": True},
-                in_axes=(nn.broadcast,) if caches is None else (nn.broadcast, nn.broadcast, 0),
-                length=cycles, metadata_params={nn.PARTITION_NAME: "layers"},
-            )(cfg, tuple(kinds[:period]), name="layers")
-            if caches is None:
-                carry, stats = scanned(carry, segment_ids)
-            else:
-                carry, stats = scanned(
-                    carry, segment_ids, ctx, jnp.arange(cycles, dtype=jnp.int32)
-                )
-            stats = jax.tree.map(lambda x: x.reshape(-1, *x.shape[2:]), stats)
-        if caches is None:
-            return carry, stats, None
-        return carry[0], stats, carry[1:]
+            return hidden, jax.tree.map(lambda x: x.reshape(-1, *x.shape[2:]), stats), cache
+        # the loop: the whole stack is one body, under the scan's names
+        if cache is None:
+            hidden, stats = body(cfg, tuple(kinds), name="layers")(hidden, segment_ids)
+            return hidden, stats, None
+        (hidden, buffers), stats = body(cfg, tuple(kinds), name="layers")(
+            (hidden, cache.buffers), segment_ids, cache, 0
+        )
+        return hidden, stats, cache.holding(buffers)
 
     @nn.compact
     def __call__(
@@ -381,50 +323,9 @@ class SolarOpen2(nn.Module):
         hidden = inputs_embeds
         seq = hidden.shape[1]
 
-        ctx = caches = None
-        paged = isinstance(decode_state, PagedDecodeState)
-        if decode_state is not None:
-            if segment_ids is None:
-                segment_ids = jnp.ones((hidden.shape[0], seq), jnp.int32)
-            caches = ((decode_state.k, decode_state.v), (decode_state.state, decode_state.conv))
-            if paged:
-                # the paged plumbing of the Llama stack: per-row lengths for
-                # the append position, the block table where the dense cache
-                # has its filled-slot map
-                ctx = {"kv_index": decode_state.lengths,
-                       "kv_segment_ids": decode_state.block_tables}
-                if decode_state.slots is not None:
-                    ctx["slots"] = decode_state.slots
-                if decode_state.fresh is not None:
-                    ctx["fresh"] = decode_state.fresh
-            else:
-                ctx = {
-                    "kv_index": decode_state.index,
-                    "kv_segment_ids": jax.lax.dynamic_update_slice(
-                        decode_state.segment_ids, segment_ids.astype(jnp.int32),
-                        (0, decode_state.index),
-                    ),
-                }
-
-        hidden, (sel_frac, mean_prob, dropped), new_caches = self._layers(
-            hidden, segment_ids, ctx, caches
-        )
-
-        new_decode_state = None
-        if decode_state is not None:
-            (new_k, new_v), (new_state, new_conv) = new_caches
-            new_decode_state = decode_state.replace(
-                k=new_k, v=new_v, state=new_state, conv=new_conv
-            )
-            if paged:
-                new_decode_state = new_decode_state.replace(
-                    lengths=decode_state.lengths
-                    + jnp.sum(segment_ids > 0, axis=1).astype(jnp.int32),
-                )
-            else:
-                new_decode_state = new_decode_state.replace(
-                    index=decode_state.index + seq, segment_ids=ctx["kv_segment_ids"],
-                )
+        cache, segment_ids = open_cache(decode_state, segment_ids, hidden.shape[0], seq)
+        hidden, (sel_frac, mean_prob, dropped), cache = self._layers(hidden, segment_ids, cache)
+        new_decode_state = close_cache(cache, decode_state, segment_ids)
 
         hidden = RMSNorm(cfg.rms_norm_eps, cfg.param_jnp_dtype, name="norm")(hidden)
         hidden = nn.with_logical_constraint(hidden, ("batch", "act_seq", "act_embed"))

@@ -5,7 +5,7 @@ PAPERS.md): each decode row attends over ITS OWN cache length, gathering
 K/V pages through its block table — no shared append index, no left
 padding, no FLOPs on another row's history. This is the designated
 successor to the dense `DecodeState` decode path's XLA einsum attention
-(`models/llama/model.py:_cached_attention`), whose whole-cache attention
+(`models/cache.py:LayerCache.attend`), whose whole-cache attention
 bills every row for the longest row's capacity.
 
 Design (one grid step per row, hand-made page fetches, flash-style online

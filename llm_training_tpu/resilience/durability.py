@@ -658,10 +658,11 @@ class MirrorDaemon:
             self.mirror, self.keep_last, self.keep_every, protected
         )
         if victims:
+            # a victim stays in `_mirrored` (attempted): forgotten, a step the
+            # primary keeps longer than the mirror does would be mirrored and
+            # deleted again every pass, and drain() would wait on it
             registry.counter("ckpt/gc_deleted").inc(len(victims))
             logger.info("mirror retention GC deleted steps %s", victims)
-            with self._lock:
-                self._mirrored.difference_update(victims)
         gc_orphan_manifests(self.primary)
         mirrored_now = committed_steps(self.mirror)
         lag = len([s for s in committed if s not in mirrored_now])

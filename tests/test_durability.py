@@ -239,26 +239,31 @@ def test_mirror_daemon_mirrors_gcs_and_scrubs(tmp_path, registry):
         primary, mirror, interval_s=0.05, keep_last=2,
         scrub_interval_s=0.0,  # exercised separately below
         registry=registry,
-    ).start()
-    try:
-        assert daemon.drain(timeout_s=30.0)
-        stats = daemon.stats()
-        assert stats["mirrored"] and not stats["failed"]
-        # retention keeps the newest keep_last on the mirror side; drain()
-        # only barriers the mirroring attempts, so wait out the GC pass
-        import time
-
-        deadline = time.monotonic() + 30.0
-        while (durability.committed_steps(mirror) != [2, 3]
-               and time.monotonic() < deadline):
-            time.sleep(0.02)
-        assert durability.committed_steps(mirror) == [2, 3]
-    finally:
-        daemon.stop()
+    )
+    # the mirror-and-GC pass, driven from here: no thread, no wall clock
+    daemon._pass()
+    stats = daemon.stats()
+    assert stats["mirrored"] and not stats["failed"]
+    # retention keeps the newest keep_last on the mirror side
+    assert durability.committed_steps(mirror) == [2, 3]
     snap, _ = registry.snapshot_with_kinds()
     assert snap["ckpt/mirrored_steps"] == 2
     assert snap["ckpt/mirror_lag_steps"] == 1  # step 1 GC'd mirror-side
     assert snap["ckpt/gc_deleted"] >= 1
+    # the thread itself: every later pass finds nothing to do (a step the
+    # mirror's retention dropped is not mirrored again), so the barrier
+    # returns on the state the direct pass left
+    daemon.start()
+    try:
+        assert daemon.drain(timeout_s=30.0)
+        assert daemon.stats()["mirrored"] == [1, 2, 3]
+    finally:
+        thread = daemon._thread
+        daemon.stop()
+    assert not thread.is_alive()
+    assert durability.committed_steps(mirror) == [2, 3]
+    snap, _ = registry.snapshot_with_kinds()
+    assert snap["ckpt/gc_deleted"] == 1  # and step 1 was not deleted twice
     # scrubber: drive _maybe_scrub directly with a fake clock
     clock = iter([100.0, 200.0]).__next__
     scrubber = MirrorDaemon(
@@ -585,7 +590,11 @@ def test_targeted_chaos_exercises_mirror_reject_then_fallback(tmp_path,
     fall back primary(2 corrupt) -> mirror(2 absent) -> older step 1."""
     primary, mirror = tmp_path / "p", tmp_path / "m"
     install_chaos(ChaosConfig(ckpt_corrupt="truncate:2"), registry=registry)
-    ckpt = _checkpointer(primary, mirror_dir=str(mirror), verify="fast")
+    # the mirror wakes on the checkpointer's notify() alone, which comes after
+    # the chaos hook: at the helper's 0.05 s poll it now and then copied step
+    # 2 between its manifest and its corruption (1 of 30 runs on a busy machine)
+    ckpt = _checkpointer(primary, mirror_dir=str(mirror), verify="fast",
+                         mirror_interval_s=3600.0)
     ckpt.save(1, _tiny_state(1.0))
     ckpt.save(2, _tiny_state(2.0))  # corrupted right after its manifest
     ckpt.wait()

@@ -178,6 +178,52 @@ def test_engine_rejects_unthreaded_families():
         InferenceEngine(NoCacheModel(), {})
 
 
+# what `models/__init__.py` exports, by the shard audit's tiny configs (its
+# registry holds every concrete family; llama three times)
+_DECODING_FAMILIES = {"llama", "llama_moe", "llama_pp", "phi3", "gemma", "solar_open2"}
+
+
+def _family_names():
+    from llm_training_tpu.analysis.shard_audit import FAMILY_REGISTRY
+
+    return [spec.name for spec in FAMILY_REGISTRY]
+
+
+@pytest.mark.parametrize("family", _family_names())
+def test_supports_decoding_is_the_configs_declaration(family):
+    """A family decodes when its config declares a cache, and those are the
+    families whose `__call__` takes a `decode_state`."""
+    import inspect
+
+    from llm_training_tpu.analysis.shard_audit import FAMILY_REGISTRY
+    from llm_training_tpu.infer.engine import supports_decoding
+
+    model = next(s for s in FAMILY_REGISTRY if s.name == family).build()
+    assert supports_decoding(model) is (family in _DECODING_FAMILIES)
+    takes_state = "decode_state" in inspect.signature(type(model).__call__).parameters
+    assert takes_state is (family in _DECODING_FAMILIES)
+    if family not in _DECODING_FAMILIES:
+        with pytest.raises(NotImplementedError, match="declares no cache"):
+            init_decode_state(model.config, 1, 8)
+
+
+def test_a_signature_alone_does_not_make_a_family_decode():
+    """A `__call__` that takes `decode_state` under a config that declares
+    no cache: no pool or buffer could be sized for it."""
+    from llm_training_tpu.infer.engine import supports_decoding
+    from llm_training_tpu.models.base import BaseModelConfig
+
+    class TakesAState:
+        config = BaseModelConfig()
+
+        def __call__(self, input_ids=None, decode_state=None):
+            raise AssertionError("never applied")
+
+    assert not supports_decoding(TakesAState())
+    with pytest.raises(NotImplementedError, match="decode_state"):
+        InferenceEngine(TakesAState(), {})
+
+
 def test_engine_eos_truncation():
     model = Llama(LlamaConfig(**TINY))
     variables = _init(model)

@@ -323,12 +323,18 @@ def test_paged_kernel_pages_per_trip_follows_shapes(
 # -------------------------------------------- paged == dense greedy parity
 
 
-@pytest.mark.parametrize("scan_layers", [True, False], ids=["scan", "looped"])
-def test_paged_greedy_matches_dense_and_oracle(scan_layers):
+@pytest.mark.parametrize("stack", [
+    dict(scan_layers=True),
+    dict(scan_layers=False),
+    # the looped path on which every layer has its own window
+    dict(scan_layers=False, sliding_window=4,
+         layer_types=["sliding_attention", "full_attention"]),
+], ids=["scan", "looped", "layer_types"])
+def test_paged_greedy_matches_dense_and_oracle(stack):
     """Continuous-batching greedy decode through the paged pool must be
     token-identical to BOTH the dense `DecodeState` engine and the full-
     forward oracle, with ragged prompts spanning page boundaries."""
-    model = Llama(LlamaConfig(**TINY, scan_layers=scan_layers))
+    model = Llama(LlamaConfig(**TINY, **stack))
     variables = _init(model)
     prompts = [[3, 17, 42, 7, 11], [5, 9], [1, 2, 3]]
     n = 8

@@ -3,7 +3,7 @@
 Three layers, cheapest first: pure-math hbm_budget units, the strict-mode /
 structured-drop regression pins on `parallel/sharding.py`, then the real
 family × mesh audit matrix — `jax.eval_shape` only, zero FLOPs, so the full
-13-family × 6-mesh sweep costs single-digit seconds on CPU. The capstone is
+14-family × 6-mesh sweep costs single-digit seconds on CPU. The capstone is
 the copied-tree acceptance test: a seeded one-character typo in a family's
 logical-axis metadata must fail `--audit` with a finding naming the leaf
 path, the bad axis, and the affected mesh configs.
@@ -169,7 +169,7 @@ def test_audit_matrix_all_families_all_meshes_clean():
     HEAD, well inside the acceptance budget."""
     result = run_audit(REPO_ROOT)
     assert result.findings == [], [f.render() for f in result.findings]
-    assert len(result.families_run) == 13
+    assert len(result.families_run) == 14
     assert set(result.meshes_run) == set(MESH_MATRIX)
     assert result.elapsed_s < 60.0
     # every cell produced an estimate and fits the default budget
@@ -380,8 +380,8 @@ def test_baseline_keys_are_mesh_selection_stable():
     assert result.meshes_run == ("fsdp8",)
 
 
-def test_registry_covers_thirteen_families():
+def test_registry_covers_fourteen_families():
     names = [f.name for f in FAMILY_REGISTRY]
-    assert len(names) == len(set(names)) == 13
+    assert len(names) == len(set(names)) == 14
     # the registry must exercise scan stacks, MoE, and pipeline layouts
     assert {"llama", "llama_moe", "llama_pp"} <= set(names)
