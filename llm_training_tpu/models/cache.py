@@ -21,7 +21,9 @@ The buffers hold EVERY layer of their kind, leading axis over layers, and
 ride the layer loop as its carry (`scan_layers`; a Python variable on a
 looped path): a layer writes its new rows into them in place and reads its
 own part, and its slice is never cut out of the stack and put back. What is
-the same for every layer is closed over by the loop, not carried.
+the same for every layer is closed over by the loop, not carried: the rest
+of the cache, and the stacked weights a layer must not cut out either
+(`scan_layers`, `whole=`).
 """
 
 from __future__ import annotations
@@ -30,6 +32,7 @@ import flax.linen as nn
 import flax.struct
 import jax
 import jax.numpy as jnp
+from flax.traverse_util import flatten_dict, unflatten_dict
 
 from llm_training_tpu.models.base import PagedDecodeState
 from llm_training_tpu.ops import dot_product_attention
@@ -219,8 +222,19 @@ def close_cache(cache: LayerCache | None, decode_state, segment_ids):
     )
 
 
+def _whole_leaves(params, names):
+    """The part of a scanned body's stacked `params` that holds the leaves
+    called one of `names`, each `[L, ...]`, under their modules' names; None
+    where there is none (so also while the parameters are being made)."""
+    found = {
+        path: nn.meta.unbox(leaf)
+        for path, leaf in flatten_dict(params).items() if path[-1] in names
+    }
+    return unflatten_dict(found) or None
+
+
 def scan_layers(body, args: tuple, length: int, hidden, inputs: tuple,
-                cache: LayerCache | None = None):
+                cache: LayerCache | None = None, whole: tuple[str, ...] = ()):
     """`body(*args, name="layers")` run `length` times by `nn.scan`, params
     stacked on axis 0: `-> (hidden, stacked ys, cache)`. Training: the body
     is called `(hidden, *inputs) -> (hidden, ys)`. Decoding: the buffers are
@@ -229,21 +243,36 @@ def scan_layers(body, args: tuple, length: int, hidden, inputs: tuple,
     write a whole slice into a new one), so the body is called `((hidden,
     buffers), *inputs, cache=<the rest of the cache>, layer=<step>)` and
     puts them together with `cache.holding(buffers)`. Same param scope
-    either way: only one of the two traces per call."""
+    either way: only one of the two traces per call.
+
+    `whole` names parameter leaves that a decoding layer must not have cut
+    out of the stack for it (the scan's slice of a leaf is a copy wherever
+    its reader wants a buffer of its own: `models/moe.py:grouped_matmul`).
+    A body that names some is called with one more argument, `stack=`:
+    those leaves whole, `[L, ...]`, nested under the body's module names as
+    its params are and closed over like the rest of the cache; None where
+    the stack has no such leaf, or one layer only (a length-1 slice is a
+    view already). The body still owns its slices of them; a layer that
+    reads the stack leaves them unread, and the compiler drops the cut."""
     decoding = cache is not None
+    extra = ((nn.broadcast, 0) + (nn.broadcast,) * bool(whole)) if decoding else ()
     scanned = nn.scan(
         body,
         variable_axes={"params": 0},
         split_rngs={"params": True},
-        in_axes=(nn.broadcast,) * len(inputs) + ((nn.broadcast, 0) if decoding else ()),
+        in_axes=(nn.broadcast,) * len(inputs) + extra,
         length=length,
         metadata_params={nn.PARTITION_NAME: "layers"},
     )(*args, name="layers")
     if not decoding:
         hidden, ys = scanned(hidden, *inputs)
         return hidden, ys, None
+    stack = ()
+    if whole:
+        found = scanned.variables.get("params", {}) if length > 1 else {}
+        stack = (_whole_leaves(found, whole),)
     (hidden, buffers), ys = scanned(
         (hidden, cache.buffers), *inputs, cache.holding((None,) * len(_BUFFERS)),
-        jnp.arange(length, dtype=jnp.int32),
+        jnp.arange(length, dtype=jnp.int32), *stack,
     )
     return hidden, ys, cache.holding(buffers)

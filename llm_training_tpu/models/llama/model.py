@@ -30,6 +30,7 @@ import numpy as np
 
 from llm_training_tpu.models.base import CausalLMOutput, DecodeState, RouterStats
 from llm_training_tpu.models.cache import LayerCache, close_cache, open_cache, scan_layers
+from llm_training_tpu.models.moe import EXPERT_LEAVES
 from llm_training_tpu.models.remat import remat_policy as _remat_policy
 from llm_training_tpu.models.llama.config import LlamaConfig
 from llm_training_tpu.ops import apply_rope, dot_product_attention, rms_norm
@@ -350,7 +351,9 @@ class LlamaMLP(nn.Module):
 class LlamaDecoderLayer(nn.Module):
     """Pre-norm block (reference `llama_model.py:747-789`). Returns `(hidden,
     aux, cache)`: `cache` (`LlamaAttention`) is None without one, and the
-    traced graph is then identical to before the cache existed."""
+    traced graph is then identical to before the cache existed. `stack`: the
+    layer loop's stacked leaves that this layer must not have cut out for it
+    (`models/cache.py:scan_layers`), under this block's module names."""
 
     config: LlamaConfig
     sliding_window_override: int | None | str = "unset"
@@ -364,6 +367,7 @@ class LlamaDecoderLayer(nn.Module):
         sin: jnp.ndarray,
         cache: LayerCache | None = None,
         layer: jnp.ndarray | int | None = None,
+        stack=None,
     ) -> tuple[jnp.ndarray, jnp.ndarray, LayerCache | None]:
         cfg = self.config
         hidden = nn.with_logical_constraint(hidden, ("batch", "act_seq", "act_embed"))
@@ -383,7 +387,9 @@ class LlamaDecoderLayer(nn.Module):
                 from llm_training_tpu.models.moe import MoEMLP
 
                 pad_mask = None if segment_ids is None else segment_ids > 0
-                return MoEMLP(cfg, name="mlp")(x, pad_mask)
+                return MoEMLP(cfg, name="mlp")(
+                    x, pad_mask, None if stack is None else (stack["mlp"], layer)
+                )
             return LlamaMLP(cfg, name="mlp")(x), jnp.float32(0.0)
 
         # Granite scales every block output before the residual add;
@@ -429,21 +435,23 @@ class _ScannedLayer(nn.Module):
     """Adapter giving LlamaDecoderLayer the (carry, xs) -> (carry, ys)
     signature nn.scan expects; ys carries the per-layer MoE aux loss. The
     carry is `hidden` or, when decoding, `(hidden, the cache's buffers)`
-    with the layer's index `layer` as the scanned input
+    with the layer's index `layer` as the scanned input and, for a sparse
+    MLP, the experts' stacked leaves `stack` closed over
     (`models/cache.py:scan_layers`)."""
 
     config: LlamaConfig
     layer_cls: type
 
     @nn.compact
-    def __call__(self, carry, segment_ids, cos, sin, cache=None, layer=None):
+    def __call__(self, carry, segment_ids, cos, sin, cache=None, layer=None, stack=None):
         block = self.layer_cls(self.config, name="layer")
         if cache is None:
             hidden, aux, _ = block(carry, segment_ids, cos, sin)
             return hidden, aux
         hidden, buffers = carry
         hidden, aux, cache = block(
-            hidden, segment_ids, cos, sin, cache.holding(buffers), layer
+            hidden, segment_ids, cos, sin, cache.holding(buffers), layer,
+            None if stack is None else stack["layer"],
         )
         return (hidden, cache.buffers), aux
 
@@ -503,6 +511,7 @@ class Llama(nn.Module):
             hidden, aux, cache = scan_layers(
                 layer_cls, (cfg, LlamaDecoderLayer), cfg.num_hidden_layers,
                 hidden, (segment_ids, cos, sin), cache,
+                whole=EXPERT_LEAVES if cfg.num_experts else (),
             )
         else:
             no_rope = getattr(cfg, "no_rope_layers", None)

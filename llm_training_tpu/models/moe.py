@@ -18,6 +18,11 @@ Qwen2/3-MoE — only through `HFCausalLM`'s torch wrapping,
 - 'dense' impl (parity/debug): run every expert on every token and combine
   with the routing weights — exact, E/K-times the FLOPs; default off-TPU
   where tiny parity tests run.
+- decoding under a layer scan (`grouped_matmul(..., layer=i)`): the ragged
+  path reads layer i's experts INSIDE the layer-stacked parameter
+  [L, E, ...], which reaches the block whole (`MoEMLP(..., stack=)`), so the
+  stack is never cut up by copy (docs/inference.md, "How a layer meets a stacked
+  weight").
 - optional shared expert + sigmoid gate (Qwen2-MoE).
 - load-balancing auxiliary loss (Switch/Mixtral form): E * sum_e f_e * P_e
   with f_e the fraction of (token, slot) assignments routed to e and P_e
@@ -35,7 +40,17 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from llm_training_tpu.ops.pallas import resolve_interpret
 from llm_training_tpu.parallel.mesh import EXPERT_AXIS, active_mesh
+from llm_training_tpu.telemetry.registry import get_registry
+
+# the stacked expert leaves [E, K, N] of `MoEMLP`, in `ragged_fn`'s order:
+# what a decoding layer loop hands the block whole (`models/cache.py:
+# scan_layers`, `whole=`)
+EXPERT_LEAVES = ("experts_gate_proj", "experts_up_proj", "experts_down_proj")
+# how many layers' expert weights the serving programs traced last read in
+# place; `serve/engine.py` zeroes it before it builds its programs
+IN_PLACE_GAUGE = "decode/experts_in_place_layers"
 
 
 def router_block_stats(topk_idx, probs, num_experts: int, pad_mask=None):
@@ -69,6 +84,73 @@ def _ep_group_size() -> int:
     if mesh is None or EXPERT_AXIS not in mesh.shape:
         return 1
     return mesh.shape[EXPERT_AXIS]
+
+
+def _resolved_impl(impl: str) -> str:
+    if impl == "auto":
+        return "ragged" if jax.default_backend() == "tpu" else "dense"
+    return impl
+
+
+# rows of `xs` one grid step of the in-place grouped matmul multiplies, and
+# the bytes of one expert's matrix it brings: two such tiles, the rows, the
+# output tile and its float32 accumulator fit the kernel's default VMEM.
+# Tried on the chip (PR 31) at OLMoE's [9, 64, 2048, 1024], nine layers of
+# three matmuls: 128 rows and the whole 4 MiB matrix a step were the fastest
+# at 256 rows (10.0 ms; 10.0 to 10.9 for 32 to 128 rows and tiles of 1 to 4
+# MiB, up to 12.5 at 256 rows a step) and at 4,096 (13.3 ms; up to 15.9)
+_GMM_ROWS = 128
+_GMM_WEIGHT_TILE = 4 << 20
+
+
+def _gmm_tiling(rows: int, k: int, n: int, itemsize: int) -> tuple[int, int, int]:
+    """(tm, tk, tn) of the in-place grouped matmul. The whole of K where
+    it fits the tile, so a row's sum is ONE float32 accumulation, as
+    `ragged_dot`'s is; N, then K, halved until a weight tile does."""
+    tm = min(_GMM_ROWS, -(-rows // 16) * 16)
+    tk, tn = k, n
+    while tk * tn * itemsize > _GMM_WEIGHT_TILE and tn % 256 == 0:
+        tn //= 2
+    while tk * tn * itemsize > _GMM_WEIGHT_TILE and tk % 256 == 0:
+        tk //= 2
+    return tm, tk, tn
+
+
+def grouped_matmul(xs, w, group_sizes, layer=None):
+    """`xs [rows, K]`, sorted by expert, times the experts' matrices `w [E,
+    K, N]`, `group_sizes [E]` rows each -> `[rows, N]`: `jax.lax.ragged_dot`.
+
+    With `layer`, `w` is the layer-stacked parameter `[L, E, K, N]` and the
+    product is with its layer `layer`, read where it lies: `ragged_dot`
+    wants its operand as a buffer of its own, so layer i's slice of the
+    stack is a 268 MB copy at OLMoE's widths, three a layer. Instead the
+    stack is seen as `[L*E, K, N]` (a free reshape) with the sizes written
+    into a zero `[L*E]` at `layer*E`, and given to jax's Pallas grouped
+    matmul (megablox `gmm`), which picks each grid step's weight block
+    through scalar prefetch and walks the row tiles of the NON-EMPTY groups
+    only: what it fetches is layer i's experts that have rows. The
+    arithmetic is `ragged_dot`'s: operands as they come, float32
+    accumulation, `xs`'s dtype out. Rows past the last group (a held share
+    leaves such rows) come out zero, as `ragged_dot` leaves them."""
+    if layer is None:
+        return jax.lax.ragged_dot(xs, w, group_sizes)
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    num_layers, num_experts, k, n = w.shape
+    rows = xs.shape[0]
+    tm, tk, tn = _gmm_tiling(rows, k, n, w.dtype.itemsize)
+    sizes = lax.dynamic_update_slice(
+        jnp.zeros((num_layers * num_experts,), jnp.int32),
+        group_sizes.astype(jnp.int32), (layer * num_experts,),
+    )
+    out = gmm(
+        jnp.pad(xs, ((0, -rows % tm), (0, 0))), w.reshape(-1, k, n), sizes,
+        preferred_element_type=xs.dtype, tiling=(tm, tk, tn),
+        interpret=resolve_interpret(),
+    )
+    # the kernel stores a group's rows and nothing else
+    in_a_group = jnp.arange(rows) < group_sizes.sum()
+    return jnp.where(in_a_group[:, None], out[:rows], 0)
 
 
 def _ep_ragged_apply(
@@ -316,8 +398,7 @@ def dropless_moe_apply(
     """
     n_tokens, top_k = topk_idx.shape
     no_drops = jnp.float32(0.0)
-    if impl == "auto":
-        impl = "ragged" if jax.default_backend() == "tpu" else "dense"
+    impl = _resolved_impl(impl)
     if held is not None:
         if impl not in ("dense", "ragged") or _ep_group_size() > 1:
             raise ValueError(
@@ -400,6 +481,11 @@ class MoEMLP(nn.Module):
     __call__(hidden [B, S, H], pad_mask [B, S] bool | None) ->
     (out [B, S, H], (sel_frac [E], mean_prob [E], dropped scalar) fp32
     router stats — `dropped` counts EP capacity-buffer losses, 0 off-EP).
+    `stack = (leaves, layer)`, from a decoding layer scan: this module's
+    `EXPERT_LEAVES` with every layer's experts, `[L, E, ...]`, and which
+    layer this is. The ragged path on one device then multiplies with
+    the stack in place (`grouped_matmul`) and this layer's slices of those
+    three parameters go unread, so the compiler drops the cut.
     The caller pools the per-layer stats across depth and applies the
     Switch/Mixtral formula E * sum(f * P) — pooling BEFORE the product is
     what HF's `load_balancing_loss_func` does (it concatenates every
@@ -416,6 +502,7 @@ class MoEMLP(nn.Module):
         self,
         hidden: jnp.ndarray,
         pad_mask: jnp.ndarray | None = None,
+        stack: tuple | None = None,
     ) -> tuple[jnp.ndarray, tuple[jnp.ndarray, jnp.ndarray]]:
         cfg = self.config
         num_experts = cfg.num_experts
@@ -480,11 +567,25 @@ class MoEMLP(nn.Module):
             up = jnp.einsum("th,ehi->tei", xc, w_up)
             return jnp.einsum("tei,eih->teh", nn.silu(gate) * up, w_down)
 
+        weights, layer = (w_gate, w_up, w_down), None
+        if (
+            stack is not None
+            and _resolved_impl(cfg.moe_impl) == "ragged"
+            # an expert mesh shards its slices (`_ep_ragged_apply`); any
+            # other would have to partition a Mosaic kernel, and cannot
+            and (active_mesh() is None or active_mesh().size == 1)
+        ):
+            whole = tuple(stack[0][name] for name in EXPERT_LEAVES)
+            # a cast would copy the whole stack
+            if all(w.dtype == compute_dtype for w in whole):
+                weights, layer = whole, stack[1]
+                get_registry().gauge(IN_PLACE_GAUGE).set(whole[0].shape[0])
+
         def ragged_fn(xs, group_sizes, expert_order, w):
             wg, wu, wd = w
-            gate = jax.lax.ragged_dot(xs, wg, group_sizes)
-            up = jax.lax.ragged_dot(xs, wu, group_sizes)
-            return jax.lax.ragged_dot(nn.silu(gate) * up, wd, group_sizes)
+            gate = grouped_matmul(xs, wg, group_sizes, layer)
+            up = grouped_matmul(xs, wu, group_sizes, layer)
+            return grouped_matmul(nn.silu(gate) * up, wd, group_sizes, layer)
 
         def bmm_fn(xb):  # [E, C, H] dense bucket stack (moe_impl='bucketed')
             gate = jnp.einsum(
@@ -501,7 +602,7 @@ class MoEMLP(nn.Module):
         out, dropped = dropless_moe_apply(
             x.astype(compute_dtype), topk_idx, topk_probs, num_experts,
             cfg.moe_impl, dense_fn, ragged_fn,
-            weights=(w_gate, w_up, w_down),
+            weights=weights,
             ep_capacity_factor=getattr(cfg, "ep_capacity_factor", 2.0),
             bmm_fn=bmm_fn,
             moe_capacity_factor=getattr(cfg, "moe_capacity_factor", 1.25),

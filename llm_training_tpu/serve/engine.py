@@ -66,6 +66,7 @@ from llm_training_tpu.infer.sampling import (
     sample_tokens_with_logprob,
 )
 from llm_training_tpu.models.base import PagedDecodeState
+from llm_training_tpu.models.moe import IN_PLACE_GAUGE
 from llm_training_tpu.resilience.chaos import get_chaos
 from llm_training_tpu.serve.paged_cache import (
     BlockAllocator,
@@ -287,6 +288,9 @@ class ServingEngine:
 
     def _build_programs(self) -> None:
         model = self.model
+        # a sparse MLP that reads its stacked experts in place says in how
+        # many layers, when a program below is traced (models/moe.py)
+        get_registry().gauge(IN_PLACE_GAUGE).set(0)
         sampling = self.config.sampling
         rope_length = self.config.max_model_len
 
@@ -1011,6 +1015,7 @@ class ServingEngine:
             "serve/peak_running": float(self.peak_running),
             "decode/cache_bytes": float(self._cache_bytes),
             "decode/state_bytes": float(self._state_bytes),
+            IN_PLACE_GAUGE: get_registry().gauge(IN_PLACE_GAUGE).value or 0.0,
             "decode/cache_blocks_total": float(self.allocator.num_blocks - 1),
             "decode/cache_blocks_in_use": float(self.allocator.blocks_in_use),
             "decode/cache_peak_blocks_in_use": float(self.allocator.peak_in_use),

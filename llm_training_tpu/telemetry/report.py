@@ -256,6 +256,9 @@ def _serving_section(telemetry: dict) -> list[str]:
         if leaked:
             line += f" — {int(leaked)} still held at exit (leak?)"
         lines.append(line)
+    in_place = num("decode/experts_in_place_layers")
+    if in_place:
+        lines.append(f"expert weights: read in place in {int(in_place)} layers")
     return lines
 
 

@@ -244,7 +244,13 @@ def test_interpret_is_impossible_on_a_tpu_backend(monkeypatch):
 # (7 -> 3).
 _PRODUCED = {
     "phi3m-serve-rollout": {"decode": {"pool": 0, "stack": 0}, "prefill": {"pool": 0, "stack": 0}},
-    "olmoe-serve-rollout": {"decode": {"pool": 0, "stack": 0}, "prefill": {"pool": 0, "stack": 0}},
+    # "experts": one layer's expert matrices `[E, in, out]`. Until PR 31 the
+    # scan cut each of the three out of the stacked parameter by copy, once a
+    # layer; the grouped matmul now reads them inside the stack
+    "olmoe-serve-rollout": {
+        "decode": {"pool": 0, "stack": 0, "experts": 0},
+        "prefill": {"pool": 0, "stack": 0, "experts": 0},
+    },
     "solar2-serve-longdoc": {
         "decode": {"pool": 0, "stack": 0, "state": 6},
         "prefill": {"pool": 0, "stack": 0, "state": 3},
@@ -252,12 +258,13 @@ _PRODUCED = {
 }
 # the programs' temporaries, GB, which hold that Solar's slab updates ARE in
 # place (one more copy of a layer's states is 0.13 GB, of a pool as much).
-# Phi-3's chunk holds its 0.126 GB of attention scores, OLMoE one layer's
-# expert weights cut out of the stack (0.27 GB, ROADMAP S4), Solar's step
-# one layer's new state (0.134 GB) and its chunk the scores (0.40 GB)
+# Phi-3's chunk holds its 0.126 GB of attention scores, OLMoE a chunk's
+# expert activations (0.013 GB; one layer's expert weights cut out of the
+# stack, 0.27 GB, until PR 31), Solar's step one layer's new state (0.134 GB)
+# and its chunk the scores (0.40 GB)
 _TEMP_GB = {
     "phi3m-serve-rollout": {"decode": 0.01, "prefill": 0.15},
-    "olmoe-serve-rollout": {"decode": 0.3, "prefill": 0.3},
+    "olmoe-serve-rollout": {"decode": 0.01, "prefill": 0.03},
     "solar2-serve-longdoc": {"decode": 0.2, "prefill": 0.45},
 }
 _NOT_PRODUCED = (
@@ -346,6 +353,9 @@ def _serve_program(v5e, cell, program):
 
 def _check_serve_program(v5e, cell, program):
     import re
+    from pathlib import Path
+
+    from benchmarks import common
 
     lowered, pool, slab = _serve_program(v5e, cell, program)
     compiled = lowered.compile()  # raises what the chip's compiler would: it fits
@@ -357,6 +367,11 @@ def _check_serve_program(v5e, cell, program):
         # the stack, as it is declared and as the append sees it (one run of blocks)
         "stack": rf"bf16\[(?:{layers},{blocks}|{layers * blocks}),{dims}\]" if layers > 1 else None,
     }
+    if "experts" in _PRODUCED[cell][program]:
+        config = common.Cell(Path(__file__).resolve().parent.parent, cell).config
+        experts, wide = config["num_experts"], config["hidden_size"]
+        narrow = config["program"]["model_kwargs"]["moe_intermediate_size"]
+        patterns["experts"] = rf"bf16\[(?:1,)?{experts},(?:{wide},{narrow}|{narrow},{wide})\]"
     caches = 2 * pool.size * 2
     if slab is not None:
         state, tail = slab
