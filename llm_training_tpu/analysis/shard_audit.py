@@ -242,6 +242,15 @@ FAMILY_REGISTRY: tuple[FamilySpec, ...] = (
              max_position_embeddings=128, n_routed_experts=8,
              num_experts_per_tok=2, n_shared_experts=1),
     ),
+    FamilySpec(
+        "longcat_flash", "llm_training_tpu.models.longcat_flash", "LongcatFlash",
+        "llm_training_tpu/models/longcat_flash/model.py",
+        dict(vocab_size=128, hidden_size=64, ffn_hidden_size=112,
+             expert_ffn_hidden_size=32, num_layers=2, num_attention_heads=4,
+             kv_lora_rank=32, q_lora_rank=48, qk_rope_head_dim=8,
+             qk_nope_head_dim=16, v_head_dim=16, n_routed_experts=8,
+             zero_expert_num=4, moe_topk=3, max_position_embeddings=128),
+    ),
 )
 
 
@@ -358,8 +367,8 @@ def _family_leaves(spec: FamilySpec) -> tuple[list[_Leaf], int, Any]:
         )
 
     # the caches a decoding family declares (`BaseModelConfig.cache_specs`),
-    # under infer/cache's layouts: the key/value buffers, k and v both, and a
-    # linear-attention stack's slab. The batch (= slots) dimension is a
+    # under infer/cache's layouts: the key/value buffers, k and v both (or the
+    # one buffer of latent rows), and a linear-attention stack's slab. The batch (= slots) dimension is a
     # placeholder; run_audit fills it from AuditConfig. A family that
     # declares none has none to audit
     declared = config.cache_specs()
@@ -368,20 +377,19 @@ def _family_leaves(spec: FamilySpec) -> tuple[list[_Leaf], int, Any]:
 
         from llm_training_tpu.infer.cache import (
             CONV_LOGICAL_AXES,
-            KV_LOGICAL_AXES,
             STATE_LOGICAL_AXES,
+            dense_cache_axes,
             slab_shapes,
+            token_rows,
         )
 
-        kv, recurrent = declared
-        kv_full = (
-            kv.layers, 0, spec.config.get("max_position_embeddings", 64),
-            kv.kv_heads, kv.head_dim,
-        )
+        _, recurrent = declared
+        buffers, layers, heads, width = token_rows(config)
+        kv_full = (layers, 0, spec.config.get("max_position_embeddings", 64), heads, width)
         itemsize = np.dtype(config.param_jnp_dtype).itemsize
         caches = [
-            ("<kv-cache k>", KV_LOGICAL_AXES, kv_full, itemsize),
-            ("<kv-cache v>", KV_LOGICAL_AXES, kv_full, itemsize),
+            (f"<kv-cache {name}>", dense_cache_axes(config), kv_full, itemsize)
+            for name in ("k", "v")[:buffers]
         ]
         if recurrent is not None:
             state_shape, conv_shape = slab_shapes(recurrent, 0)

@@ -17,6 +17,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from llm_training_tpu.models.base import (
     DecodeState,
     KVCacheSpec,
+    LatentCacheSpec,
     RecurrentCacheSpec,
     resolve_dtype,
 )
@@ -24,6 +25,8 @@ from llm_training_tpu.parallel.sharding import LogicalAxisRules, logical_to_spec
 
 # cache buffer layout: [num_layers, batch, max_length, num_kv_heads, head_dim]
 KV_LOGICAL_AXES = ("layers", "batch", None, "kv_heads", None)
+# a latent cache's one row a token is shared by the heads: nothing to shard there
+LATENT_LOGICAL_AXES = ("layers", "batch", None, None, None)
 SEG_LOGICAL_AXES = ("batch", None)
 # recurrent slab: state [layers, slots, heads, key_dim, value_dim] and the
 # conv tail [layers, slots, taps, channels]; a slot is a batch row
@@ -31,7 +34,9 @@ STATE_LOGICAL_AXES = ("layers", "batch", "heads", None, None)
 CONV_LOGICAL_AXES = ("layers", "batch", None, "heads")
 
 
-def cache_specs(config) -> tuple[KVCacheSpec, RecurrentCacheSpec | None]:
+def cache_specs(
+    config,
+) -> tuple[KVCacheSpec | LatentCacheSpec, RecurrentCacheSpec | None]:
     """What a stack caches, as its config declares it
     (`BaseModelConfig.cache_specs`): every pool, dense buffer, slab and
     sharding below derives from it."""
@@ -44,10 +49,21 @@ def cache_specs(config) -> tuple[KVCacheSpec, RecurrentCacheSpec | None]:
     return declared
 
 
-def cache_dims(config) -> tuple[int, int, int]:
-    """(layers, num_kv_heads, head_dim) of the key/value cache."""
-    kv, _ = cache_specs(config)
-    return kv.layers, kv.kv_heads, kv.head_dim
+def token_rows(config) -> tuple[int, int, int, int]:
+    """(buffers, layers, heads, width): what a token leaves in each layer of
+    the attention cache, as the pools and dense buffers are shaped
+    `[layers, ..., heads, ..., width]`. Keys and values: two buffers of
+    `kv_heads` rows of `head_dim`. Latent rows: ONE buffer of one row."""
+    spec, _ = cache_specs(config)
+    if isinstance(spec, LatentCacheSpec):
+        return 1, spec.layers, 1, spec.width
+    return 2, spec.layers, spec.kv_heads, spec.head_dim
+
+
+def dense_cache_axes(config) -> tuple[str | None, ...]:
+    """The logical axes of the dense attention buffers `token_rows` shapes."""
+    latent = isinstance(cache_specs(config)[0], LatentCacheSpec)
+    return LATENT_LOGICAL_AXES if latent else KV_LOGICAL_AXES
 
 
 def slab_shapes(
@@ -137,9 +153,9 @@ def decode_state_shardings(
     """A DecodeState-shaped tree of NamedShardings for jit in/out.
     `rope_length` must match the state the shardings are used with — it is
     static pytree metadata, so a mismatch is a structure mismatch."""
-    num_layers, kv_heads, head_dim = cache_dims(config)
+    buffers, num_layers, kv_heads, head_dim = token_rows(config)
     kv_shape = (num_layers, batch_size, max_length, kv_heads, head_dim)
-    kv = NamedSharding(mesh, _divisible_spec(kv_shape, KV_LOGICAL_AXES, mesh, rules))
+    kv = NamedSharding(mesh, _divisible_spec(kv_shape, dense_cache_axes(config), mesh, rules))
     seg = NamedSharding(
         mesh,
         _divisible_spec((batch_size, max_length), SEG_LOGICAL_AXES, mesh, rules),
@@ -149,7 +165,8 @@ def decode_state_shardings(
     if recurrent is not None:
         state, conv = slab_shardings(recurrent, batch_size, mesh, rules)
     return DecodeState(
-        k=kv, v=kv, index=NamedSharding(mesh, PartitionSpec()), segment_ids=seg,
+        k=kv, v=kv if buffers == 2 else None,
+        index=NamedSharding(mesh, PartitionSpec()), segment_ids=seg,
         state=state, conv=conv, rope_length=rope_length,
     )
 
@@ -168,7 +185,7 @@ def init_decode_state(
     first prefill never materializes a replicated cache. `rope_length` is
     the planned total sequence length when it is shorter than the cache
     capacity (length-dependent RoPE variants select tables from it)."""
-    num_layers, kv_heads, head_dim = cache_dims(config)
+    buffers, num_layers, kv_heads, head_dim = token_rows(config)
     dtype = resolve_cache_dtype(config, cache_dtype)
 
     _, recurrent = cache_specs(config)
@@ -180,7 +197,7 @@ def init_decode_state(
         )
         return DecodeState(
             k=jnp.zeros(kv_shape, dtype),
-            v=jnp.zeros(kv_shape, dtype),
+            v=jnp.zeros(kv_shape, dtype) if buffers == 2 else None,
             index=jnp.int32(0),
             segment_ids=jnp.zeros((batch_size, max_length), jnp.int32),
             state=state, conv=conv,

@@ -19,6 +19,7 @@ from llm_training_tpu.models.gpt_oss import GptOss, GptOssConfig
 from llm_training_tpu.models.hf_causal_lm import HFCausalLM, HFCausalLMConfig
 from llm_training_tpu.models.hunyuan_moe import HunYuanMoe, HunYuanMoeConfig
 from llm_training_tpu.models.llama import Llama, LlamaConfig
+from llm_training_tpu.models.longcat_flash import LongcatFlash, LongcatFlashConfig
 from llm_training_tpu.models.minimax import MiniMax, MiniMaxConfig
 from llm_training_tpu.models.phi3 import Phi3, Phi3Config
 from llm_training_tpu.models.qwen3_next import Qwen3Next, Qwen3NextConfig
@@ -46,6 +47,8 @@ __all__ = [
     "HunYuanMoeConfig",
     "Llama",
     "LlamaConfig",
+    "LongcatFlash",
+    "LongcatFlashConfig",
     "MiniMax",
     "MiniMaxConfig",
     "Phi3",

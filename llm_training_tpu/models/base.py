@@ -74,10 +74,15 @@ class BaseModelConfig(BaseModel):
     def param_jnp_dtype(self) -> jnp.dtype:
         return resolve_dtype(self.param_dtype)
 
-    def cache_specs(self) -> "tuple[KVCacheSpec, RecurrentCacheSpec | None] | None":
-        """What this family's stack caches when it decodes, one spec a layer
-        kind; every pool, dense buffer, slab and sharding derives from it
-        (`infer/cache.py`). None, the default, says the family does not
+    def cache_specs(
+        self,
+    ) -> "tuple[KVCacheSpec | LatentCacheSpec, RecurrentCacheSpec | None] | None":
+        """What this family's stack caches when it decodes: first what a
+        token leaves behind in the attention layers (keys and values a head,
+        `KVCacheSpec`, or one latent row shared by the heads,
+        `LatentCacheSpec`), then the slab of its linear-attention layers, if
+        it has any. Every pool, dense buffer, slab and sharding derives from
+        it (`infer/cache.py`). None, the default, says the family does not
         decode: its `__call__` takes no `decode_state`."""
         return None
 
@@ -115,6 +120,28 @@ class KVCacheSpec:
     layers: int
     kv_heads: int
     head_dim: int
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentCacheSpec:
+    """The cache of a stack's latent-attention (MLA) blocks: ONE row a token,
+    shared by every head: the compressed key/value latent (`latent_dim`,
+    normalised and scaled as the block's `kv_b` projection reads it) and the
+    rotated positional key (`rope_dim`). It is read once, as keys and, its
+    first `latent_dim` values, as values: there is no second buffer. `layers`
+    counts the MLA blocks (two a double layer)."""
+
+    layers: int
+    latent_dim: int
+    rope_dim: int
+
+    @property
+    def width(self) -> int:
+        """The row as it is stored: whole 128-lane tiles, zeros past
+        `latent_dim + rope_dim` (the chip lays an array out in such tiles
+        whatever it is declared as, and Mosaic refuses a page copy that is
+        not whole ones: 576 values are stored as 640)."""
+        return -(-(self.latent_dim + self.rope_dim) // 128) * 128
 
 
 @dataclasses.dataclass(frozen=True)
@@ -156,7 +183,9 @@ class DecodeState:
     keeps the chunk from seeing slots written after it."""
 
     k: jnp.ndarray
-    v: jnp.ndarray
+    # None for a latent cache (`LatentCacheSpec`): `k` is then `[mla_blocks,
+    # batch, max_length, 1, width]`, the one row a token
+    v: jnp.ndarray | None
     index: jnp.ndarray
     segment_ids: jnp.ndarray
     # linear-attention layers' slab (`RecurrentCacheSpec`), one slot a batch
@@ -209,7 +238,9 @@ class PagedDecodeState:
     the slot's state and tail are read as zeros whatever the slot held."""
 
     k: jnp.ndarray
-    v: jnp.ndarray
+    # None for a latent cache (`LatentCacheSpec`): `k` is then the latent
+    # pool `[mla_blocks, num_blocks, 1, block_size, width]`
+    v: jnp.ndarray | None
     block_tables: jnp.ndarray
     lengths: jnp.ndarray
     state: jnp.ndarray | None = None
@@ -250,7 +281,11 @@ class CausalLMOutput:
     `router_stats` carries the pre-pooled per-layer router statistics
     (None for dense models) for the health-metric layer. `decode_state` is
     the updated KV cache when the forward was called with one (None on the
-    training path)."""
+    training path). `moe_assignments` is `[3]` int32 from a stack that holds
+    a SHARE of its experts and has zero-compute ones (longcat_flash): the
+    call's (token, slot) assignments to experts held here, to zero-compute
+    experts, and to experts held elsewhere, over all layers, padding left
+    out; None from every other family."""
 
     logits: jnp.ndarray | None = None
     last_hidden_states: jnp.ndarray | None = None
@@ -258,3 +293,4 @@ class CausalLMOutput:
     ep_dropped_rows: jnp.ndarray | None = None
     router_stats: RouterStats | None = None
     decode_state: DecodeState | None = None
+    moe_assignments: jnp.ndarray | None = None

@@ -4,16 +4,26 @@ knows what a cache kind is (docs/inference.md, "How a layer meets its cache").
 A family's `__call__` opens its `decode_state` once (`open_cache`), hands the
 `LayerCache` to its layer loop, and closes it (`close_cache`). A layer asks
 the cache to append its chunk's keys and values and attend against its own
-part (`attend`), or for its recurrent rows and to take them back
-(`recurrent_rows`, `put_recurrent_rows`); it never sees which kind it was
-given. The kind is decided here, once, from the state's type:
+part (`attend`), to append its chunk's latent rows and attend against them
+(`attend_latent`), or for its recurrent rows and to take them back
+(`recurrent_rows`, `put_recurrent_rows`); it never sees whether the cache is
+dense or paged. That is decided here, once, from the state's type:
 
 - dense (`DecodeState`, `infer/`): buffers `[L, B, max_length, kv_heads,
   head_dim]`, one append position shared by the batch, and the filled-slot
   ids every layer masks against;
 - paged (`PagedDecodeState`, `serve/`): pools `[L, blocks, kv_heads, page,
   head_dim]` addressed through per-row lengths and block tables
-  (`ops/paged_attention.py`);
+  (`ops/paged_attention.py`).
+
+What a token leaves in those buffers is the stack's declaration
+(`BaseModelConfig.cache_specs`), of three kinds:
+
+- keys and values a head (`KVCacheSpec`): `k` and `v`, as above;
+- one latent row shared by the heads (`LatentCacheSpec`, MLA): `k` alone,
+  `[mla_blocks, ..., 1, width]` in either layout, `v` None; the row is read
+  as keys and, its first `latent_dim` values, as values
+  (`ops/latent_attention.py`);
 - beside either, where the stack has linear-attention layers, their slab
   (`RecurrentCacheSpec`): `state`, `conv` `[layers, slots, ...]`.
 
@@ -85,7 +95,7 @@ class LayerCache:
     (`PagedDecodeState`)."""
 
     k: jnp.ndarray | None
-    v: jnp.ndarray | None
+    v: jnp.ndarray | None  # None beside a latent buffer in `k`
     state: jnp.ndarray | None = None
     conv: jnp.ndarray | None = None
     fresh: jnp.ndarray | None = None
@@ -152,6 +162,40 @@ class LayerCache:
             impl="xla",
         )
         return out, self.replace(k=ck, v=cv)
+
+    def attend_latent(self, layer, q_nope, q_rope, row, w_kvb, segment_ids, *, scale):
+        """A latent-attention (MLA) block's turn: append this chunk's rows
+        `row [B, S, latent + rope]` (`[c_kv | rotated k_r]`, ONE a token) to
+        part `layer` of the latent buffer, zeros up to the stored width, and
+        attend `q_nope [B, S, H, nope]`, `q_rope [B, S, H, rope]` against that
+        part; `w_kvb [latent, H, nope + v]` is the block's up-projection.
+        Returns `(out [B, S, H, v], the cache holding the new buffer)`. One
+        token a row attends in the absorbed form (the paged kernel's), a
+        chunk in the expanded one (`ops/latent_attention.py`). `layer` counts
+        MLA blocks."""
+        from llm_training_tpu.ops import latent_attention
+
+        row = jnp.pad(row, ((0, 0), (0, 0), (0, self.k.shape[-1] - row.shape[-1])))
+        if self.paged:
+            out, pool = latent_attention.paged_latent_attention(
+                q_nope, q_rope, row, w_kvb, self.k, self.lengths, self.block_tables,
+                layer=layer, segment_ids=segment_ids, scale=scale,
+            )
+            return out, self.replace(k=pool)
+        buffer = jax.lax.dynamic_update_slice(
+            self.k, row[None, :, :, None, :].astype(self.k.dtype), (layer, 0, self.index, 0, 0)
+        )
+        mine = jax.lax.dynamic_index_in_dim(buffer, layer, keepdims=False)[:, :, 0]
+        seq = row.shape[1]
+        out = latent_attention.attend_rows(
+            q_nope, q_rope, w_kvb,
+            # one trip: the whole buffer; unwritten and padded slots have id 0
+            lambda _: (mine, jnp.arange(mine.shape[1])[None], self.kv_segment_ids > 0),
+            1, jnp.broadcast_to(self.index + jnp.arange(seq), segment_ids.shape),
+            segment_ids > 0,
+            scale=scale, absorbed=seq == 1,
+        )
+        return out, self.replace(k=buffer)
 
     def recurrent_rows(self, layer, read=_slot_rows):
         """`(state [B, ...] float32, conv tail [B, ...])` of recurrent layer

@@ -116,6 +116,27 @@ def _gmm_tiling(rows: int, k: int, n: int, itemsize: int) -> tuple[int, int, int
     return tm, tk, tn
 
 
+def experts_in_place(stack, local, impl: str, compute_dtype):
+    """`(weights, layer)` for a layer's grouped products (`grouped_matmul`).
+    `stack = (leaves, layer)` from a decoding layer scan (`models/cache.py:
+    scan_layers(whole=EXPERT_LEAVES)`): where the products can read layer
+    `layer` inside the stacked leaves `[L, E, ...]`, those and the index;
+    otherwise `local`, this layer's own three matrices, and None."""
+    if (
+        stack is not None
+        and _resolved_impl(impl) == "ragged"
+        # an expert mesh shards its slices (`_ep_ragged_apply`); any
+        # other would have to partition a Mosaic kernel, and cannot
+        and (active_mesh() is None or active_mesh().size == 1)
+    ):
+        whole = tuple(stack[0][name] for name in EXPERT_LEAVES)
+        # a cast would copy the whole stack
+        if all(w.dtype == compute_dtype for w in whole):
+            get_registry().gauge(IN_PLACE_GAUGE).set(whole[0].shape[0])
+            return whole, stack[1]
+    return local, None
+
+
 def grouped_matmul(xs, w, group_sizes, layer=None):
     """`xs [rows, K]`, sorted by expert, times the experts' matrices `w [E,
     K, N]`, `group_sizes [E]` rows each -> `[rows, N]`: `jax.lax.ragged_dot`.
@@ -567,19 +588,9 @@ class MoEMLP(nn.Module):
             up = jnp.einsum("th,ehi->tei", xc, w_up)
             return jnp.einsum("tei,eih->teh", nn.silu(gate) * up, w_down)
 
-        weights, layer = (w_gate, w_up, w_down), None
-        if (
-            stack is not None
-            and _resolved_impl(cfg.moe_impl) == "ragged"
-            # an expert mesh shards its slices (`_ep_ragged_apply`); any
-            # other would have to partition a Mosaic kernel, and cannot
-            and (active_mesh() is None or active_mesh().size == 1)
-        ):
-            whole = tuple(stack[0][name] for name in EXPERT_LEAVES)
-            # a cast would copy the whole stack
-            if all(w.dtype == compute_dtype for w in whole):
-                weights, layer = whole, stack[1]
-                get_registry().gauge(IN_PLACE_GAUGE).set(whole[0].shape[0])
+        weights, layer = experts_in_place(
+            stack, (w_gate, w_up, w_down), cfg.moe_impl, compute_dtype
+        )
 
         def ragged_fn(xs, group_sizes, expert_order, w):
             wg, wu, wd = w

@@ -78,6 +78,19 @@ def paged_append(
     impl: str = "auto",
     trash: jnp.ndarray | int = 0,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """`_append` for a key pool and a value pool."""
+    return _append(
+        (pool_k, pool_v), (k, v), lengths, block_tables, segment_ids, impl, trash
+    )
+
+
+def latent_append(pool, rows, lengths, block_tables, segment_ids, impl="auto", trash=0):
+    """`_append` for the one pool of a latent cache: `rows [B, S, 1, W]` into
+    `pool [N, 1, page, W]`."""
+    return _append((pool,), (rows,), lengths, block_tables, segment_ids, impl, trash)[0]
+
+
+def _append(pools, xs, lengths, block_tables, segment_ids, impl, trash):
     """Write this chunk's k/v `[B, S, H, D]` into the pool at each row's next
     positions (`lengths[b] + i`), touching only the pages those positions lie
     in: each such page is read, the chunk's rows are laid over it, and the
@@ -88,8 +101,8 @@ def paged_append(
     id 0) and any out-of-table position are redirected to slot 0 of the
     reserved trash block `trash` — garbage can land there but never in a
     live block."""
-    batch, seq = k.shape[:2]
-    _, kv_heads, page_size, head_dim = pool_k.shape
+    batch, seq = xs[0].shape[:2]
+    _, kv_heads, page_size, head_dim = pools[0].shape
     blocks, token, valid, stray = _chunk_pages(
         lengths, block_tables, segment_ids, batch, seq, page_size
     )
@@ -116,26 +129,33 @@ def paged_append(
         return jnp.where(take[:, None, :, None], rows.astype(pool.dtype), pool[blocks])
 
     return _write_pages(
-        pool_k, pool_v, pages(pool_k, k), pages(pool_v, v), blocks, live, impl
+        pools, tuple(pages(pool, x) for pool, x in zip(pools, xs)), blocks, live, impl
     )
 
 
-def _write_pages(pool_k, pool_v, pages_k, pages_v, blocks, live, impl):
-    """`pool[blocks[i]] = pages[i]` for every live i, for K and for V. On a
-    TPU (or `impl='pallas'`) the Pallas page writer, whose pools are aliased
-    to its outputs and never leave HBM; elsewhere one XLA scatter over whole
-    blocks, rows that are not live dropped."""
+def _write_pages(pools, pages, blocks, live, impl):
+    """`pool[blocks[i]] = pages[i]` for every live i, for each pool (K and V,
+    or the one latent pool). On a TPU (or `impl='pallas'`) the Pallas page
+    writers, whose pools are aliased to their outputs and never leave HBM;
+    elsewhere one XLA scatter over whole blocks, rows that are not live
+    dropped."""
     if impl == "pallas" or (impl == "auto" and jax.default_backend() == "tpu"):
+        if len(pools) == 1:
+            from llm_training_tpu.ops.pallas.mla_decode import write_latent_pages
+
+            # one row a token, shared by the heads: nothing to split over them
+            return (_over_heads(
+                write_latent_pages, (*pools, *pages, blocks, live), (None,) * 4, 0
+            ),)
         from llm_training_tpu.ops.pallas.paged_attention import write_pages
 
         return _over_heads(
-            write_pages, (pool_k, pool_v, pages_k, pages_v, blocks, live),
+            write_pages, (*pools, *pages, blocks, live),
             (1, 1, 1, 1, None, None), (0, 1),
         )
-    at = jnp.where(live, blocks, pool_k.shape[0])  # out of range: dropped
-    return (
-        pool_k.at[at].set(pages_k, mode="drop"),
-        pool_v.at[at].set(pages_v, mode="drop"),
+    at = jnp.where(live, blocks, pools[0].shape[0])  # out of range: dropped
+    return tuple(
+        pool.at[at].set(new, mode="drop") for pool, new in zip(pools, pages)
     )
 
 

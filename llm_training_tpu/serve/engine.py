@@ -14,6 +14,15 @@ of a pool's shape; docs/serving.md, "How the cache is carried and appended"):
   length, no shared append index, no left padding. Idle slots carry the
   trash-block table and cost one garbage row.
 
+A stack with latent attention (`LatentCacheSpec`) has ONE pool, a latent row
+a token: `_pool_k` is that pool and `_pool_v` is None, through both programs
+(`decode/latent_pool_bytes`). Such a stack, holding a share of its experts,
+also counts each call's expert assignments on the device (`CausalLMOutput.
+moe_assignments`): the programs add them to a three-number int32 carry that
+stays on the device, and a decode step returns the carry as an output of its
+own, which the host reads in the `device_get` that fetches the step's tokens
+(no sync of its own) and feeds to `serve/moe_{held,zero,elsewhere}_assignments`.
+
 A stack with linear-attention layers (`infer/cache.py:cache_specs`) has a
 second cache beside the pool: the STATE SLAB, a fixed float32 state and a
 short conv tail for every decode slot, donated through both programs like
@@ -196,6 +205,7 @@ class ServingEngine:
                 rules=self.rules, cache_dtype=self.config.cache_dtype,
             )
         self._cache_bytes = pool_bytes(self._pool_k, self._pool_v)  # outlives close()
+        self._latent_pool = self._pool_v is None
         self._state_bytes = 0 if self._slab is None else pool_bytes(*self._slab)
         self.allocator = BlockAllocator(num_blocks + 1)
         self.scheduler = Scheduler(
@@ -216,6 +226,14 @@ class ServingEngine:
         # the running step's counts: filled where the work is decided, closed
         # into the engine_step span and the serve/* counters by step()
         self._step_counts: dict[str, int] = {}
+        # for a stack that counts its expert assignments (`CausalLMOutput.
+        # moe_assignments`: held here, zero-compute, held elsewhere): what the
+        # calls since the last decode fetch counted, carried on the device
+        # (zeros from the host after a fetch). None for every other stack
+        self._moe_carry = (
+            np.zeros((3,), np.int32)
+            if getattr(model_config, "counts_expert_assignments", False) else None
+        )
         self._rng = jax.random.key(self.config.seed)
         self._call = 0
         self._t0: float | None = None
@@ -302,8 +320,8 @@ class ServingEngine:
         def slab_of(state):
             return None if state.state is None else (state.state, state.conv)
 
-        def prefill_chunk(variables, ids, seg, pos, pool_k, pool_v,
-                          tables, length, last_pos, rng, slab=None, slot=None, fresh=None):
+        def prefill_chunk(variables, ids, seg, pos, pool_k, pool_v, tables, length,
+                          last_pos, rng, slab=None, slot=None, fresh=None, moe=None):
             state = PagedDecodeState(
                 k=pool_k, v=pool_v, block_tables=tables, lengths=length,
                 rope_length=rope_length,
@@ -322,17 +340,21 @@ class ServingEngine:
                     logits[None], rng, sampling
                 )
             state = out.decode_state
-            return state.k, state.v, token[0], logprob[0], slab_of(state)
+            # the chunk's expert assignments join the carry, on the device
+            moe = None if moe is None else moe + out.moe_assignments
+            return state.k, state.v, token[0], logprob[0], slab_of(state), moe
 
-        def decode_step(variables, tokens, pool_k, pool_v, tables, lengths, rng, slab=None):
+        def decode_step(variables, tokens, pool_k, pool_v, tables, lengths, rng, slab=None,
+                        moe=None):
             state = PagedDecodeState(
                 k=pool_k, v=pool_v, block_tables=tables, lengths=lengths,
                 rope_length=rope_length, **slab_fields(slab),
             )
             # row i is slot i. A slot that does not decode this step (idle, or
             # its prompt still prefilling) has length 0 here: segment 0, so
-            # its state and tail come out as they went in
-            rows = {} if slab is None else {
+            # its state and tail come out as they went in, and a stack that
+            # counts its expert assignments leaves it out
+            rows = {} if slab is None and moe is None else {
                 "segment_ids": (lengths > 0).astype(jnp.int32)[:, None]
             }
             out = model.apply(
@@ -343,7 +365,9 @@ class ServingEngine:
             with jax.named_scope("sample"):
                 token, logprob = sample_tokens_with_logprob(logits, rng, sampling)
             state = out.decode_state
-            return state.k, state.v, token, logprob, slab_of(state)
+            # the carry and this step's assignments: fetched with the tokens
+            moe = None if moe is None else moe + out.moe_assignments
+            return state.k, state.v, token, logprob, slab_of(state), moe
 
         # the function names ARE the programs' names (`jit_prefill_chunk`,
         # `jit_decode_step` in HLO module names and in a device profile):
@@ -575,7 +599,8 @@ class ServingEngine:
             return
         jax.block_until_ready((self._pool_k, self._pool_v, self._slab))
         for buffer in (self._pool_k, self._pool_v, *(self._slab or ())):
-            buffer.delete()
+            if buffer is not None:
+                buffer.delete()
         self._pool_k = self._pool_v = None
 
     # ---------------------------------------------------------------- step
@@ -664,6 +689,9 @@ class ServingEngine:
             registry.gauge("decode/state_slots_in_use").set(counts["state_slots_in_use"])
             if counts["state_resets"]:
                 registry.counter("serve/state_resets").inc(counts["state_resets"])
+        for kind in ("held", "zero", "elsewhere"):
+            if counts.get(f"moe_{kind}"):
+                registry.counter(f"serve/moe_{kind}_assignments").inc(counts[f"moe_{kind}"])
         if counts["prefill_chunks"]:
             registry.counter("serve/prefill_chunks").inc(counts["prefill_chunks"])
         if counts["decode_rows"]:
@@ -754,7 +782,10 @@ class ServingEngine:
                     "slot": np.asarray([request.slot], np.int32),
                     "fresh": np.asarray([fresh]),
                 }
-                self._pool_k, self._pool_v, token, logprob, self._slab = self._prefill_jit(
+                if self._moe_carry is not None:
+                    slab_row["moe"] = self._moe_carry
+                (self._pool_k, self._pool_v, token, logprob, self._slab,
+                 self._moe_carry) = self._prefill_jit(
                     self.variables, jnp.asarray(ids_row), jnp.asarray(seg),
                     jnp.asarray(pos), self._pool_k, self._pool_v,
                     jnp.asarray(tables), jnp.asarray([start], jnp.int32),
@@ -812,6 +843,8 @@ class ServingEngine:
                 jnp.asarray(tables), jnp.asarray(lengths), self._next_rng(),
             )
             step_slab = {} if self._slab is None else {"slab": self._slab}
+            if self._moe_carry is not None:
+                step_slab["moe"] = self._moe_carry
         if not self._decode_attr_done:
             # before the donating call below: lowering only reads avals,
             # while the jit consumes the pool buffers
@@ -820,14 +853,19 @@ class ServingEngine:
         # the enqueue alone, then the wait for the device: a step that reads
         # far off shows in which of the two its seconds went
         with tracer.measure("serve", "decode_dispatch", **child):
-            self._pool_k, self._pool_v, out, out_lp, self._slab = self._decode_jit(
+            self._pool_k, self._pool_v, out, out_lp, self._slab, moe = self._decode_jit(
                 *step_args, **step_slab
             )
         with tracer.measure("serve", "decode_fetch", **child):
-            host, host_lp = jax.device_get((out, out_lp))
+            host, host_lp, moe = jax.device_get((out, out_lp, moe))
         with tracer.measure("serve", "decode_emit", **child):
             host = np.asarray(host)
             host_lp = np.asarray(host_lp)
+            if moe is not None:
+                # the assignments counted since the last fetch
+                for kind, n in zip(("held", "zero", "elsewhere"), moe):
+                    self._step_counts[f"moe_{kind}"] = int(n)
+                self._moe_carry = np.zeros((3,), np.int32)
             for request in survivors:
                 request.cache_len += 1
                 self._emit_token(
@@ -1015,6 +1053,9 @@ class ServingEngine:
             "serve/peak_running": float(self.peak_running),
             "decode/cache_bytes": float(self._cache_bytes),
             "decode/state_bytes": float(self._state_bytes),
+            "decode/latent_pool_bytes": float(
+                self._cache_bytes if self._latent_pool else 0
+            ),
             IN_PLACE_GAUGE: get_registry().gauge(IN_PLACE_GAUGE).value or 0.0,
             "decode/cache_blocks_total": float(self.allocator.num_blocks - 1),
             "decode/cache_blocks_in_use": float(self.allocator.blocks_in_use),
@@ -1033,6 +1074,11 @@ class ServingEngine:
         registry = get_registry()
         for key, value in stats.items():
             registry.gauge(key).set(value)
+        if self._moe_carry is not None:
+            # counters already (step()): read into the summary, not published twice
+            for kind in ("held", "zero", "elsewhere"):
+                key = f"serve/moe_{kind}_assignments"
+                stats[key] = float(registry.counter(key).value)
         logger.info(
             "serve: %d completed (%d evictions) | %.1f tokens/s (%.1f/chip)",
             len(completed), self.scheduler.evictions, tps, stats["serve/tokens_per_sec_per_chip"],

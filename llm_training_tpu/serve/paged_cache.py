@@ -46,10 +46,10 @@ def resolve_block_size(
     cache_dtype: str | None = None,
 ) -> int:
     """The pool's tokens-per-block, via the tuning layer (kind='paged')."""
-    from llm_training_tpu.infer.cache import cache_dims, resolve_cache_dtype
+    from llm_training_tpu.infer.cache import resolve_cache_dtype, token_rows
     from llm_training_tpu.ops.pallas.tuning import resolve_paged_block_size
 
-    _, _, head_dim = cache_dims(model_config)
+    _, _, _, head_dim = token_rows(model_config)
     choice = resolve_paged_block_size(
         max_model_len=max_model_len, head_dim=head_dim,
         dtype=resolve_cache_dtype(model_config, cache_dtype),
@@ -67,32 +67,38 @@ def init_paged_pool(
     cache_dtype: str | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Fresh all-zeros (k, v) pool, created ALREADY sharded under a mesh
-    (kv heads over 'tensor', like the dense cache). Publishes the pool
-    footprint as the `decode/cache_bytes` gauge."""
+    (kv heads over 'tensor', like the dense cache). A stack that caches
+    latent rows (`LatentCacheSpec`) has ONE pool, `[mla_blocks, num_blocks, 1,
+    block_size, width]`: it comes back as `k`, and `v` is None. Publishes the
+    pool footprint as the `decode/cache_bytes` gauge, and a latent pool's as
+    `decode/latent_pool_bytes` too."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding
 
     from llm_training_tpu.infer.cache import (
         _divisible_spec,
-        cache_dims,
         resolve_cache_dtype,
+        token_rows,
     )
 
-    num_layers, kv_heads, head_dim = cache_dims(model_config)
+    buffers, num_layers, kv_heads, head_dim = token_rows(model_config)
     dtype = resolve_cache_dtype(model_config, cache_dtype)
     shape = (num_layers, num_blocks, kv_heads, block_size, head_dim)
 
     def build():
-        return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+        return tuple(jnp.zeros(shape, dtype) for _ in range(buffers))
 
     if mesh is None:
-        k, v = build()
+        pools = build()
     else:
+        # (a latent pool's one row a token is shared by the heads: it has no
+        # head axis to shard, and `_divisible_spec` drops the rule)
         spec = NamedSharding(
             mesh, _divisible_spec(shape, POOL_LOGICAL_AXES, mesh, rules or ())
         )
-        k, v = jax.jit(build, out_shardings=(spec, spec))()
+        pools = jax.jit(build, out_shardings=(spec,) * buffers)()
+    k, v = pools if buffers == 2 else (pools[0], None)
     _publish_pool_gauges(k, v, num_blocks)
     return k, v
 
@@ -114,8 +120,8 @@ def init_state_slab(model_config, slots: int, mesh=None, rules=None,
     return slab
 
 
-def pool_bytes(k: jnp.ndarray, v: jnp.ndarray) -> int:
-    return sum(leaf.size * leaf.dtype.itemsize for leaf in (k, v))
+def pool_bytes(k: jnp.ndarray, v: jnp.ndarray | None) -> int:
+    return sum(leaf.size * leaf.dtype.itemsize for leaf in (k, v) if leaf is not None)
 
 
 def _publish_pool_gauges(k, v, num_blocks: int) -> None:
@@ -123,6 +129,7 @@ def _publish_pool_gauges(k, v, num_blocks: int) -> None:
 
     registry = get_registry()
     registry.gauge("decode/cache_bytes").set(pool_bytes(k, v))
+    registry.gauge("decode/latent_pool_bytes").set(0 if v is not None else pool_bytes(k, v))
     registry.gauge("decode/cache_blocks_total").set(num_blocks - 1)  # minus trash
 
 

@@ -259,6 +259,15 @@ def _serving_section(telemetry: dict) -> list[str]:
     in_place = num("decode/experts_in_place_layers")
     if in_place:
         lines.append(f"expert weights: read in place in {int(in_place)} layers")
+    latent = num("decode/latent_pool_bytes")
+    if latent:
+        lines.append(f"latent (MLA) pool: {latent / 2**20:.1f} MiB, one row a token a block")
+    held, zero, elsewhere = (num(f"serve/moe_{k}_assignments") for k in ("held", "zero", "elsewhere"))
+    if held or zero or elsewhere:
+        lines.append(
+            f"expert assignments: {int(held or 0)} held here, {int(zero or 0)} zero-compute, "
+            f"{int(elsewhere or 0)} held elsewhere"
+        )
     return lines
 
 

@@ -332,19 +332,22 @@ def _serve_program(v5e, cell, program):
     )))
     engine = ServingEngine(model, None, ServeConfig(**serve))  # its caches give the shapes
     pool, slab = on(engine._pool_k), on(engine._slab)
+    pool_v = None if engine._pool_v is None else pool  # a latent stack has ONE pool
     rows, pages, chunk = serve["max_batch"], engine.pages_per_request, serve["prefill_chunk"]
     key = on(jax.eval_shape(lambda: jax.random.key(0)))
     slab_rows = {} if slab is None else {"slab": slab}
+    if engine._moe_carry is not None:  # a share of the experts counts its assignments
+        slab_rows["moe"] = shape((3,))
     if program == "decode":
         lowered = engine._decode_jit.lower(
-            variables, shape((rows,)), pool, pool, shape((rows, pages)), shape((rows,)), key,
+            variables, shape((rows,)), pool, pool_v, shape((rows, pages)), shape((rows,)), key,
             **slab_rows,
         )
     else:
         if slab is not None:
             slab_rows.update(slot=shape((1,)), fresh=shape((1,), jnp.bool_))
         lowered = engine._prefill_jit.lower(
-            variables, shape((1, chunk)), shape((1, chunk)), shape((1, chunk)), pool, pool,
+            variables, shape((1, chunk)), shape((1, chunk)), shape((1, chunk)), pool, pool_v,
             shape((1, pages)), shape((1,)), shape(()), key, **slab_rows,
         )
     engine.close()
@@ -412,3 +415,83 @@ def test_solar_open2_serve_cell_compiles_for_v5e(v5e, as_on_tpu, program):
 @pytest.mark.parametrize("cell", ["phi3m-serve-rollout", "olmoe-serve-rollout"])
 def test_rollout_cells_update_the_pool_in_place_for_v5e(v5e, as_on_tpu, cell, program):
     _check_serve_program(v5e, cell, program)
+
+
+# ------------------------------------------- the cell with a latent (MLA) pool
+#
+# `longcat-serve-longctx`: ONE pool (a latent row a token for each of the 8 MLA
+# blocks, `_pool_v` None), the held experts' stacked weights read in place by
+# the grouped product. Pinned: both programs fit beside 10.3 GB of weights,
+# the decode step holds its `mla_decode` kernel (two call sites in the layer
+# loop's body: 8 calls a step), the append is the in-place page writer, and
+# neither program produces an array of the pool's shape, of the stacked
+# pools', or of one layer's expert matrices.
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_longcat_serve_cell_compiles_for_v5e(v5e, as_on_tpu, program):
+    import re
+    from pathlib import Path
+
+    from benchmarks import common
+
+    lowered, pool, slab = _serve_program(v5e, "longcat-serve-longctx", program)
+    assert slab is None
+    config = common.Cell(Path(__file__).resolve().parent.parent, "longcat-serve-longctx").config
+    compiled = lowered.compile()  # raises what the chip's compiler would: it fits
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    layers, blocks, *page = pool.shape
+    assert (layers, blocks, page) == (8, 32 * 352 + 1, [1, 16, 640])
+    dims = ",".join(str(d) for d in page)
+    wide, narrow = config["hidden_size"], config["expert_ffn_hidden_size"]
+    patterns = {
+        "pool": rf"bf16\[(?:1,)?{blocks},{dims}\]",
+        "stack": rf"bf16\[(?:{layers},{blocks}|{layers * blocks}),{dims}\]",
+        "experts": rf"bf16\[(?:1,)?16,(?:{wide},{narrow}|{narrow},{wide})\]",
+    }
+    counts = {k: _produced(text, p) for k, p in patterns.items()}
+    print(f"longcat-serve-longctx {program}: produced {counts}, "
+          f"temp {memory.temp_size_in_bytes / 1e9:.3f} GB, "
+          f"arguments {memory.argument_size_in_bytes / 1e9:.3f} GB")
+    assert counts == {"pool": 0, "stack": 0, "experts": 0}, counts
+    assert memory.alias_size_in_bytes >= pool.size * 2  # the pool is written in place
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 14e9
+    # a step's temporaries are its rows' activations; a chunk holds a trip's
+    # [64, 512, 512] float32 scores and the expanded keys and values
+    assert memory.temp_size_in_bytes < {"decode": 0.02, "prefill": 0.2}[program] * 1e9
+
+    sites = {
+        name: sum("mla_decode" in line and "tpu_custom_call" in line for line in lines)
+        for name, lines in _run_computations(text).items()
+    }
+    bodies = set(re.findall(r"body=%?([\w.\-]+)", text))
+    calls = sum(n * (layers // 2 if name in bodies else 1) for name, n in sites.items())
+    assert calls == (layers if program == "decode" else 0), sites
+    kernels = parse_hlo_kernels(text)
+    assert kernels.get("latent_page_write", 0) >= 1 and kernels.get("gmm", 0) >= 3
+    assert "kv_page_write" not in kernels and "paged_decode" not in kernels
+
+
+def test_mla_decode_refuses_a_row_that_is_not_whole_lanes(v5e):
+    """576 values a row is what the mathematics needs and not what the chip
+    stores: the array is laid out in tiles of 128 lanes (as 640) and Mosaic
+    refuses a page copy that is not whole tiles, so the pool is declared 640
+    wide (`LatentCacheSpec.width`) and the zeros are real."""
+    from llm_training_tpu.ops.pallas.mla_decode import mla_decode_attention
+
+    one = SingleDeviceSharding(v5e.devices[0])
+    shape = lambda dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    def compile_at(width):
+        return jax.jit(
+            lambda q, pool, tables, lens: mla_decode_attention(
+                q, pool, tables, lens, latent_dim=512, scale=192 ** -0.5, interpret=False
+            )
+        ).lower(
+            shape((32, 64, width)), shape((4096, 1, 16, width)),
+            shape((32, 352), jnp.int32), shape((32,), jnp.int32),
+        ).compile()
+
+    assert "mla_decode" in parse_hlo_kernels(compile_at(640).as_text())
+    with pytest.raises(Exception, match="aligned to tiling"):
+        compile_at(576)

@@ -6,6 +6,7 @@ names three readers match, and the two hooks the benchmark will use
 
 import ast
 import json
+import re
 from contextlib import contextmanager
 from pathlib import Path
 
@@ -394,6 +395,98 @@ def test_state_slab_reports_its_bytes_slots_and_resets(fresh):
     assert plain._slab is None and plain.stats()["decode/state_bytes"] == 0
     engine.close()
     assert engine._slab is None and engine._pool_k is None
+
+
+# --------------------------------------------- a stack with a latent cache
+
+TINY_LONGCAT = dict(
+    vocab_size=64, hidden_size=32, ffn_hidden_size=48, expert_ffn_hidden_size=16, num_layers=2,
+    num_attention_heads=4, kv_lora_rank=16, q_lora_rank=24, qk_rope_head_dim=8, qk_nope_head_dim=8,
+    v_head_dim=8, n_routed_experts=8, zero_expert_num=4, moe_topk=3, experts_held=4,
+    moe_impl="ragged", attention_impl="xla", compute_dtype="float32", param_dtype="float32",
+)
+
+
+def _longcat_engine(**serve):
+    from llm_training_tpu.models import LongcatFlash, LongcatFlashConfig
+
+    model = LongcatFlash(LongcatFlashConfig(**TINY_LONGCAT))
+    variables = jax.jit(model.init)(jax.random.key(0), np.zeros((1, 4), np.int32))
+    return ServingEngine(model, variables, ServeConfig(**{**SERVE, **serve}))
+
+
+def test_latent_attention_stack_names_its_scopes_in_both_programs(fresh):
+    """What the benchmark's readers match: every op of an MLA block under
+    `/self_attn/` with its parts named, the dense FFNs and the MoE under
+    `/mlp/`, the MoE branch whole under `scmoe` with `moe_zero`; the decode
+    step attends absorbed, the chunk expanded."""
+    engine = _longcat_engine()
+    assert engine._pool_v is None and engine._pool_k.shape == (4, 13, 1, 8, 128)
+    decode = engine._decode_jit.lower(*_decode_args(engine), moe=engine._moe_carry)
+    prefill = engine._prefill_jit.lower(*_prefill_args(engine), moe=engine._moe_carry)
+    assert "jit_decode_step" in decode.as_text()[:200]
+    assert "jit_prefill_chunk" in prefill.as_text()[:200]
+    shared = (
+        "sub_0/self_attn/mla_q", "sub_1/self_attn/mla_kv", "self_attn/mla_attend", "self_attn/mla_out",
+        "sub_0/mlp/", "sub_1/mlp/", "layer/scmoe/mlp/moe_route", "scmoe/mlp/moe_sort",
+        "scmoe/mlp/moe_gather", "scmoe/mlp/moe_experts", "scmoe/mlp/moe_scatter", "scmoe/mlp/moe_zero",
+        "/sample",
+    )
+    for lowered, own, other in (
+        # (a chunk walks its row's pages in a loop, whose body's scopes follow `while/body`)
+        (decode, "self_attn/mla_absorb", "mla_expand"), (prefill, "/mla_expand/", "mla_absorb"),
+    ):
+        text = lowered.as_text(debug_info=True)
+        for scope in shared + (own,):
+            assert scope in text, scope
+        assert other not in text
+        # no op of an MLA block lies outside the block's module scope (a name that
+        # starts at a loop's body is the location of a call's wrapper there, not an op's)
+        named = re.findall(r'loc\("([^"]*mla_[^"]*)"', text)
+        assert named and all("/self_attn/" in name for name in named if not name.startswith("while/body/"))
+
+
+def test_latent_pool_and_expert_assignments_are_counted_with_the_tokens(fresh):
+    """`decode/latent_pool_bytes`, and the step's expert assignments (held
+    here, zero-compute, held elsewhere): counted on the device, carried there
+    until a decode step returns them as an int32 output of its own, beside
+    log-probabilities of the batch's own length (read in the `device_get`
+    that fetches the tokens), closed into `engine_step` and summed by the
+    counters."""
+    from llm_training_tpu.telemetry.report import _serving_section
+
+    engine = _longcat_engine()
+    *_, token, logprob, _, counts = jax.eval_shape(
+        engine._decode_jit, *_decode_args(engine), moe=engine._moe_carry
+    )
+    assert token.shape == logprob.shape == (SERVE["max_batch"],)
+    assert (counts.shape, counts.dtype) == ((3,), np.int32)
+    engine.run(_requests(6))
+    registry = get_registry()
+    pool_bytes = 4 * 13 * 8 * 128 * 4  # 4 MLA blocks, 12 blocks and the trash one, pages of 8 rows stored 128 wide
+    assert registry.gauge("decode/latent_pool_bytes").value == pool_bytes
+    stats = engine.stats()
+    assert stats["decode/latent_pool_bytes"] == stats["decode/cache_bytes"] == pool_bytes
+    steps = [e["args"] for e in fresh.snapshot() if e.get("ph") == "X" and e["name"] == "engine_step"]
+    kinds = ("held", "zero", "elsewhere")
+    totals = {k: sum(a.get(f"moe_{k}", 0) for a in steps) for k in kinds}
+    # every real token of every call, twice (2 layers), three choices each:
+    # 14 prompt tokens and 3 x 5 decoded ones (the last token is not fed back)
+    assert sum(totals.values()) == (14 + 15) * 2 * 3
+    assert all(totals.values())
+    for kind in kinds:
+        assert registry.counter(f"serve/moe_{kind}_assignments").value == totals[kind]
+        assert stats[f"serve/moe_{kind}_assignments"] == totals[kind]
+    said = "\n".join(_serving_section(stats))
+    assert "latent (MLA) pool" in said and f"{totals['zero']} zero-compute" in said
+    # a stack with keys and values reports none of it
+    plain = _engine()
+    plain.run(_requests(2))
+    assert plain.stats()["decode/latent_pool_bytes"] == 0
+    assert "serve/moe_held_assignments" not in plain.stats()
+    assert registry.gauge("decode/latent_pool_bytes").value == 0
+    engine.close()
+    assert engine._pool_k is None
 
 
 @pytest.mark.parametrize("config,layers", [
