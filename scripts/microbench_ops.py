@@ -9,6 +9,11 @@ and is reported as ns/token and achieved HBM GB/s against the chip's ~819
 GB/s peak (all four ops are bandwidth-bound — roofline says a fused
 implementation can only win by moving fewer bytes).
 
+One device, no mesh: the fused CE here is the one scan over all the tokens.
+What it does on a mesh that splits the tokens (each device scanning its own,
+`ops/cross_entropy.py:_on_own_tokens`) is not exercised by this script: the
+benchmark's `phi3m-train-4k-fsdp4` cell and `tests/test_ce_sharding.py` are.
+
 Usage: python scripts/microbench_ops.py  (prints a markdown table)
 """
 

@@ -1,4 +1,4 @@
-"""HLO-level proof of the vocab-sharded fused CE (VERDICT r3 #4).
+"""HLO-level proof of how the fused CE is partitioned (VERDICT r3 #4).
 
 `lms/clm.py` claims the chunked `fused_linear_cross_entropy` lowers to a
 vocab-sharded lm-head matmul + psum under tensor parallelism — i.e. the
@@ -7,6 +7,14 @@ tests compile the op on a tensor-sharded mesh and inspect the partitioned
 HLO: no full-vocab logits buffer may materialize per device, and the head
 must never be all-gathered. They FAIL if the sharding regresses (e.g. a
 future change constrains the logits to replicated).
+
+Under fsdp (the tokens split over the batch axes, the head over its
+CONTRACTING dimension) the op runs its scan inside a shard_map over the
+batch axes: each device scans its own tokens against the whole head. The
+fsdp cases hold that nothing crosses devices inside the scan (GSPMD alone
+all-reduces `f32[chunk, vocab]` partial logits every chunk, forward and
+recomputed backward), that the head is gathered once and its gradient
+reduced once, and that the numbers are the single-device function's.
 """
 
 import re
@@ -17,7 +25,11 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from llm_training_tpu.ops.cross_entropy import fused_linear_cross_entropy
+from llm_training_tpu.ops.cross_entropy import (
+    fused_linear_cross_entropy,
+    fused_linear_log_probs,
+    fused_linear_token_log_probs,
+)
 from llm_training_tpu.parallel.mesh import MeshConfig, build_mesh
 
 TOKENS, HIDDEN, VOCAB, CHUNK = 4096, 256, 32000, 1024
@@ -101,3 +113,206 @@ def test_ce_sharded_numerics_match_replicated(tp_mesh):
     total, count = fused_linear_cross_entropy(hidden, head, labels, chunk_size=CHUNK)
     expected = total / jnp.maximum(count, 1).astype(jnp.float32)
     np.testing.assert_allclose(float(sharded), float(expected), rtol=1e-5)
+
+
+# ---------------------------------------------------------------- fsdp
+
+F_BATCH, F_SEQ, F_HIDDEN, F_VOCAB, F_CHUNK = 16, 512, 256, 4096, 1024
+COLLECTIVES = ("all-reduce", "all-gather", "all-to-all", "reduce-scatter", "collective-permute")
+
+
+def _mean_nll(op, hidden, head, labels, **kwargs):
+    """One scalar out of each chunked op (the CE's two sums; DPO/ORPO's
+    per-row and GRPO's per-token log-probs): the mean NLL, and the count."""
+    first, second = op(hidden, head, labels, **kwargs)
+    count = second.sum()  # a count, per-row counts, or the validity mask
+    return jnp.abs(first).sum() / jnp.maximum(count, 1).astype(jnp.float32), count
+
+
+OPS = {
+    "ce": fused_linear_cross_entropy,
+    "logps": fused_linear_log_probs,
+    "token_logps": fused_linear_token_log_probs,
+}
+
+
+def _mesh(fsdp: int, tensor: int = 1, sequence: int = 1):
+    return build_mesh(
+        MeshConfig(fsdp_size=fsdp, tensor_parallel_size=tensor, sequence_parallel_size=sequence),
+        devices=jax.devices()[: fsdp * tensor * sequence],
+    )
+
+
+def _compile_fsdp(mesh, grad: bool, vocab_axis=None, op="ce"):
+    """The loss as `lms/clm.py` calls it: hidden `[batch, seq, embed]` and
+    labels split over the batch axes, the head `("embed", "vocab")` by the
+    rule table, its gradient wanted back in the head's own sharding."""
+    hidden_sh = NamedSharding(mesh, P("fsdp", None, None))
+    labels_sh = NamedSharding(mesh, P("fsdp", None))
+    head_sh = NamedSharding(mesh, P("fsdp", vocab_axis))
+
+    def loss(hidden, head, labels):
+        # DPO/ORPO/GRPO chunk the sequence with the batch kept: a chunk of
+        # F_CHUNK // rows-a-device tokens gives the same [F_CHUNK, vocab] logits
+        chunk = F_CHUNK if op == "ce" else F_CHUNK // (F_BATCH // 4)
+        return _mean_nll(OPS[op], hidden, head, labels, chunk_size=chunk)[0]
+
+    fn = jax.grad(loss, argnums=(0, 1)) if grad else loss
+    out_sh = (hidden_sh, head_sh) if grad else None
+    with mesh:
+        return (
+            jax.jit(fn, out_shardings=out_sh)
+            .lower(
+                jax.ShapeDtypeStruct((F_BATCH, F_SEQ, F_HIDDEN), jnp.bfloat16, sharding=hidden_sh),
+                jax.ShapeDtypeStruct((F_HIDDEN, F_VOCAB), jnp.bfloat16, sharding=head_sh),
+                jax.ShapeDtypeStruct((F_BATCH, F_SEQ), jnp.int32, sharding=labels_sh),
+            )
+            .compile()
+        )
+
+
+def _collectives(txt: str, scope: str = "") -> list[tuple[str, set[tuple[int, ...]], bool]]:
+    """(op, result shapes, inside a while body?) of every collective of a
+    partitioned module whose `op_name` holds `scope`: a computation is
+    inside a loop when a while's body or condition reaches it through
+    calls, fusions or reducers."""
+    computations: dict[str, list[str]] = {}
+    current = None
+    for line in txt.splitlines():
+        m = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{\s*$", line)
+        if m:
+            current = computations.setdefault(m.group(1), [])
+        elif current is not None:
+            current.append(line)
+
+    def callees(lines):
+        names = set()
+        for line in lines:
+            names.update(re.findall(r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)", line))
+            for group in re.findall(r"branch_computations=\{([^}]*)\}", line):
+                names.update(n.strip().lstrip("%") for n in group.split(","))
+        return names
+
+    stack = [
+        name
+        for lines in computations.values()
+        for line in lines
+        if " while(" in line
+        for name in callees([line])
+    ]
+    in_loop: set[str] = set()
+    while stack:
+        name = stack.pop()
+        if name in computations and name not in in_loop:
+            in_loop.add(name)
+            stack.extend(callees(computations[name]))
+
+    found = []
+    for name, lines in computations.items():
+        for line in lines:
+            m = re.search(r"= (.*?) (%s)(?:-start)?\(" % "|".join(COLLECTIVES), line)
+            if m and scope in line:
+                found.append((m.group(2), _shapes_in(m.group(1)), name in in_loop))
+    return found
+
+
+@pytest.mark.parametrize("op", list(OPS))
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
+def test_ce_scans_each_devices_own_tokens_under_fsdp(devices, grad, op):
+    found = _collectives(_compile_fsdp(_mesh(4), grad, op=op).as_text())
+    assert found, "no collective parsed out of the partitioned module"
+
+    # 1. inside the scan nothing crosses devices: no partial-logits
+    #    all-reduce [chunk, vocab], no head traffic, no re-split of the
+    #    hidden chunk from tokens to embed (the parent's all-to-all)
+    assert [(op, shapes) for op, shapes, looped in found if looped] == []
+    assert not any(op == "all-to-all" for op, _, _ in found)
+
+    # 2. the whole head exists once: one all-gather of [embed, vocab]
+    head = (F_HIDDEN, F_VOCAB)
+    gathers = [shapes for op, shapes, _ in found if op == "all-gather" and head in shapes]
+    assert len(gathers) == 1, found
+    # no [chunk, vocab]-shaped collective anywhere
+    assert not any(s[-1] == F_VOCAB and s != head for _, shapes, _ in found for s in shapes)
+
+    # 3. its gradient is reduced once, after the backward scan
+    reductions = [
+        op for op, shapes, _ in found
+        if op in ("all-reduce", "reduce-scatter")
+        and (head in shapes or (F_HIDDEN // 4, F_VOCAB) in shapes)
+    ]
+    assert len(reductions) == (1 if grad else 0), found
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
+def test_ce_stays_vocab_sharded_under_fsdp_x_tensor(devices, grad):
+    """fsdp=2 x tensor=2 takes GSPMD's program, not the local scan: the
+    vocabulary would have to stay an automatic axis inside the shard_map,
+    and XLA:CPU (jax 0.9.0) aborts on bf16 crossing a partial-auto
+    shard_map boundary (`models/moe.py`), which would take a tier-1 worker
+    down with it. So this mesh keeps the tensor-parallel properties (the
+    head never gathered over `tensor`, no full-vocab buffer) and still
+    all-reduces its partial logits over fsdp inside the scan (ROADMAP S3b)."""
+    txt = _compile_fsdp(_mesh(2, 2), grad, vocab_axis="tensor").as_text()
+    shapes = _shapes_in(txt)
+    assert not any(s and s[-1] == F_VOCAB for s in shapes), "full vocab axis materialized"
+    assert any(s[-2:] == (F_CHUNK, F_VOCAB // 2) for s in shapes if len(s) >= 2)
+    looped = [(op, sh) for op, sh, inside in _collectives(txt) if inside]
+    assert any(op == "all-reduce" for op, _ in looped), "expected GSPMD's in-scan reductions"
+
+
+def _value_case(batch: int):
+    """Inputs that take every branch of the chunk body: ignored rows, a
+    token count a chunk does not divide (padding), the bias, the soft cap."""
+    seq, embed, vocab = 24, 32, 96
+    rng = np.random.default_rng(batch)
+    hidden = jnp.asarray(rng.standard_normal((batch, seq, embed)), jnp.float32)
+    head = jnp.asarray(rng.standard_normal((embed, vocab)) * 0.2, jnp.float32)
+    bias = jnp.asarray(rng.standard_normal((vocab,)) * 0.1, jnp.float32)
+    labels = rng.integers(0, vocab, (batch, seq))
+    labels[rng.random((batch, seq)) < 0.3] = -100
+    labels[1] = -100  # a whole row ignored
+    return hidden, head, bias, jnp.asarray(labels, jnp.int32)
+
+
+@pytest.mark.parametrize("op", list(OPS))
+@pytest.mark.parametrize(
+    "batch,sequence",
+    [(8, 1), (6, 1), (8, 2)],
+    ids=["batch_divides:local_scan", "batch_does_not:falls_through", "fsdp2_x_sequence2:local_scan"],
+)
+def test_ce_fsdp_values_match_single_device(devices, batch, sequence, op):
+    """Loss, count, dh, dW and db on the 4-device fsdp mesh equal the
+    single-device function's to fp32 rounding. 8 rows: 2 a device, 48
+    tokens scanned in chunks of 20 (padded to 60; the per-row ops pad a
+    row's 24 to 40). 6 rows: the axes do not divide the batch and the op
+    falls through to the one scan. fsdp=2 x sequence=2: a device holds half
+    of four rows, and a row's sum crosses the sequence axis."""
+    hidden, head, bias, labels = _value_case(batch)
+
+    def loss(hidden, head, bias):
+        return _mean_nll(
+            OPS[op], hidden, head, labels, chunk_size=20, logits_soft_cap=5.0, bias=bias
+        )
+
+    grad = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True))
+    (want, want_count), want_grads = grad(hidden, head, bias)
+
+    mesh = _mesh(4 // sequence, sequence=sequence)
+    divides = batch % 4 == 0
+    with mesh:
+        placed = (
+            jax.device_put(
+                hidden, NamedSharding(mesh, P("fsdp", "sequence") if divides else P())
+            ),
+            jax.device_put(head, NamedSharding(mesh, P("fsdp", None))),
+            jax.device_put(bias, NamedSharding(mesh, P())),
+        )
+        lowered = grad.lower(*placed)
+        (got, got_count), got_grads = lowered.compile()(*placed)
+    assert ("shard_map" in lowered.as_text(debug_info=True)) == divides
+
+    assert int(got_count) == int(want_count) == int((np.asarray(labels) != -100).sum())
+    np.testing.assert_allclose(float(got), float(want), rtol=2e-6)
+    for g, w in zip(got_grads, want_grads):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-5, atol=1e-7)

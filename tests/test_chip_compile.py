@@ -495,3 +495,74 @@ def test_mla_decode_refuses_a_row_that_is_not_whole_lanes(v5e):
     assert "mla_decode" in parse_hlo_kernels(compile_at(640).as_text())
     with pytest.raises(Exception, match="aligned to tiling"):
         compile_at(576)
+
+
+# --------------------------------------------------- the four-chip train cell
+#
+# `phi3m-train-4k-fsdp4`'s whole train step, partitioned for the 2x2. Pinned:
+# it fits, and the chunked loss keeps each chip's tokens on that chip: under
+# `loss_ce` no collective sits inside a while body (GSPMD alone all-reduced
+# `f32[2048,32064]` partial logits every chunk, forward and recomputed
+# backward, and re-split each chunk with an all-to-all), the head is gathered
+# ONCE and in bf16 (328 MB: the cast comes before the gather), and its
+# gradient is reduced once, after the backward scan.
+
+
+def test_train_cell_keeps_the_loss_on_its_chip_for_v5e(v5e, as_on_tpu):
+    import re
+    from pathlib import Path
+
+    import flax.linen as nn
+    import numpy as np
+
+    from benchmarks import common
+    from benchmarks.runners import train_fit
+    from llm_training_tpu.parallel.mesh import MeshConfig, build_mesh
+    from llm_training_tpu.trainer import Trainer, TrainerConfig
+    from llm_training_tpu.trainer.trainer import LOGICAL_AXIS_RULES, _batch_shardings
+    from tests.test_ce_sharding import _collectives
+
+    cell = common.Cell(Path(__file__).resolve().parent.parent, "phi3m-train-4k-fsdp4")
+    objective = train_fit.seeded_objective(cell, 1)
+    devices = list(v5e.devices)
+    trainer = Trainer(
+        TrainerConfig(mesh=MeshConfig(**cell.config["train"]["mesh"])), devices=devices
+    )
+    mesh = trainer.mesh = build_mesh(trainer.config.mesh, devices)
+    rows, seq = cell.traffic["global_batch_rows"], cell.traffic["seq_len"]
+    keys = ("input_ids", "labels", "segment_ids", "position_ids")
+    sample = {k: np.zeros((rows, seq), np.int32) for k in keys}
+    with mesh, nn.logical_axis_rules(LOGICAL_AXIS_RULES):
+        tx, _ = trainer._build_tx(objective)
+        boxed = trainer._abstract_state(objective, sample, tx)
+        trainer.state_shardings = trainer._state_shardings(boxed)
+        step = jax.jit(
+            trainer._build_step(objective, tx),
+            in_shardings=(trainer.state_shardings, _batch_shardings(sample, mesh)),
+            out_shardings=(trainer.state_shardings, None),
+            donate_argnums=0,
+        )
+        compiled = step.lower(
+            nn.meta.unbox(boxed),
+            {k: jax.ShapeDtypeStruct((rows, seq), jnp.int32) for k in keys},
+        ).compile()  # raises what the chip's compiler would: it fits
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    print(f"phi3m-train-4k-fsdp4 step: arguments {memory.argument_size_in_bytes / 1e9:.3f} GB, "
+          f"temp {memory.temp_size_in_bytes / 1e9:.3f} GB")
+    # the parent's step: 13.653 GB of temporaries (donated state counted in)
+    assert memory.temp_size_in_bytes < 13.7e9
+
+    embed, vocab = cell.config["hidden_size"], cell.config["vocab_size"]
+    chunk = cell.config["train"]["ce_chunk_size"]
+    of_loss = _collectives(text, scope="loss_ce")
+    assert [c for c in of_loss if c[2]] == [], "a collective of the loss inside a scan"
+    assert not any(op == "all-to-all" for op, _, _ in of_loss)
+    everywhere = _collectives(text)
+    assert not any(s[-2:] == (chunk, vocab) for _, shapes, _ in everywhere for s in shapes)
+    head = [(op, looped) for op, shapes, looped in everywhere if (embed, vocab) in shapes]
+    assert sorted(head) == [("all-gather", False), ("all-reduce", False)], head
+    gather = next(
+        line for line in text.splitlines()
+        if re.search(rf"= \S*\[{embed},{vocab}\]\S* all-gather", line)
+    )
+    assert gather.split("= ")[1].startswith("bf16["), gather[:200]
