@@ -251,6 +251,15 @@ FAMILY_REGISTRY: tuple[FamilySpec, ...] = (
              qk_nope_head_dim=16, v_head_dim=16, n_routed_experts=8,
              zero_expert_num=4, moe_topk=3, max_position_embeddings=128),
     ),
+    FamilySpec(
+        "afmoe", "llm_training_tpu.models.afmoe", "Afmoe",
+        "llm_training_tpu/models/afmoe/model.py",
+        dict(vocab_size=128, hidden_size=64, intermediate_size=112,
+             moe_intermediate_size=32, num_hidden_layers=8, num_dense_layers=2,
+             num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+             sliding_window=16, num_experts=8, num_experts_per_tok=2,
+             max_position_embeddings=128),
+    ),
 )
 
 
@@ -379,6 +388,7 @@ def _family_leaves(spec: FamilySpec) -> tuple[list[_Leaf], int, Any]:
             CONV_LOGICAL_AXES,
             STATE_LOGICAL_AXES,
             dense_cache_axes,
+            kv_groups,
             slab_shapes,
             token_rows,
         )
@@ -391,6 +401,13 @@ def _family_leaves(spec: FamilySpec) -> tuple[list[_Leaf], int, Any]:
             (f"<kv-cache {name}>", dense_cache_axes(config), kv_full, itemsize)
             for name in ("k", "v")[:buffers]
         ]
+        if (window := kv_groups(config)[1]) is not None:
+            # the layers that keep a window: a second group of the same buffers
+            caches += [
+                (f"<kv-cache window {name}>", dense_cache_axes(config),
+                 (window.layers, *kv_full[1:]), itemsize)
+                for name in ("k", "v")
+            ]
         if recurrent is not None:
             state_shape, conv_shape = slab_shapes(recurrent, 0)
             caches += [

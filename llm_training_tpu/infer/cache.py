@@ -36,7 +36,10 @@ CONV_LOGICAL_AXES = ("layers", "batch", None, "heads")
 
 def cache_specs(
     config,
-) -> tuple[KVCacheSpec | LatentCacheSpec, RecurrentCacheSpec | None]:
+) -> tuple[
+    KVCacheSpec | LatentCacheSpec | tuple[KVCacheSpec, KVCacheSpec],
+    RecurrentCacheSpec | None,
+]:
     """What a stack caches, as its config declares it
     (`BaseModelConfig.cache_specs`): every pool, dense buffer, slab and
     sharding below derives from it."""
@@ -49,12 +52,23 @@ def cache_specs(
     return declared
 
 
+def kv_groups(config) -> tuple[KVCacheSpec | LatentCacheSpec, KVCacheSpec | None]:
+    """(the layers that keep every token, the layers that keep a window or
+    None): the attention part of the declaration. A stack declares the second
+    group only where its layers differ in how much of the past they keep; a
+    lone spec is the first group whatever its `window` (a window every layer
+    shares is a mask over one pool, as it always was)."""
+    spec, _ = cache_specs(config)
+    return spec if isinstance(spec, tuple) else (spec, None)
+
+
 def token_rows(config) -> tuple[int, int, int, int]:
     """(buffers, layers, heads, width): what a token leaves in each layer of
     the attention cache, as the pools and dense buffers are shaped
     `[layers, ..., heads, ..., width]`. Keys and values: two buffers of
-    `kv_heads` rows of `head_dim`. Latent rows: ONE buffer of one row."""
-    spec, _ = cache_specs(config)
+    `kv_heads` rows of `head_dim`. Latent rows: ONE buffer of one row. Of a
+    stack with two groups (`kv_groups`) these are the first group's layers."""
+    spec, _ = kv_groups(config)
     if isinstance(spec, LatentCacheSpec):
         return 1, spec.layers, 1, spec.width
     return 2, spec.layers, spec.kv_heads, spec.head_dim
@@ -62,7 +76,7 @@ def token_rows(config) -> tuple[int, int, int, int]:
 
 def dense_cache_axes(config) -> tuple[str | None, ...]:
     """The logical axes of the dense attention buffers `token_rows` shapes."""
-    latent = isinstance(cache_specs(config)[0], LatentCacheSpec)
+    latent = isinstance(kv_groups(config)[0], LatentCacheSpec)
     return LATENT_LOGICAL_AXES if latent else KV_LOGICAL_AXES
 
 
@@ -164,10 +178,16 @@ def decode_state_shardings(
     state = conv = None
     if recurrent is not None:
         state, conv = slab_shardings(recurrent, batch_size, mesh, rules)
+    window = None
+    if (group := kv_groups(config)[1]) is not None:
+        window = NamedSharding(mesh, _divisible_spec(
+            (group.layers, *kv_shape[1:]), KV_LOGICAL_AXES, mesh, rules
+        ))
     return DecodeState(
         k=kv, v=kv if buffers == 2 else None,
         index=NamedSharding(mesh, PartitionSpec()), segment_ids=seg,
-        state=state, conv=conv, rope_length=rope_length,
+        state=state, conv=conv, window_k=window, window_v=window,
+        rope_length=rope_length,
     )
 
 
@@ -189,18 +209,23 @@ def init_decode_state(
     dtype = resolve_cache_dtype(config, cache_dtype)
 
     _, recurrent = cache_specs(config)
+    window = kv_groups(config)[1]
 
     def build() -> DecodeState:
         kv_shape = (num_layers, batch_size, max_length, kv_heads, head_dim)
         state, conv = (
             (None, None) if recurrent is None else _slab_zeros(recurrent, batch_size, dtype)
         )
+        # the window group's layers at full length: the dense path masks
+        window_k, window_v = (None, None) if window is None else (
+            jnp.zeros((window.layers, *kv_shape[1:]), dtype) for _ in range(2)
+        )
         return DecodeState(
             k=jnp.zeros(kv_shape, dtype),
             v=jnp.zeros(kv_shape, dtype) if buffers == 2 else None,
             index=jnp.int32(0),
             segment_ids=jnp.zeros((batch_size, max_length), jnp.int32),
-            state=state, conv=conv,
+            state=state, conv=conv, window_k=window_k, window_v=window_v,
             rope_length=rope_length,
         )
 
@@ -230,6 +255,6 @@ def cache_bytes(state: DecodeState) -> int:
     gauge)."""
     return sum(
         leaf.size * leaf.dtype.itemsize
-        for leaf in (state.k, state.v, state.state, state.conv)
+        for leaf in (state.k, state.v, state.state, state.conv, state.window_k, state.window_v)
         if leaf is not None
     )

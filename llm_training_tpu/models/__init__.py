@@ -9,6 +9,7 @@ families. Here, models are flax.linen Modules whose parameters carry
 `llm_training_tpu.parallel.sharding`.
 """
 
+from llm_training_tpu.models.afmoe import Afmoe, AfmoeConfig
 from llm_training_tpu.models.bamba import Bamba, BambaConfig
 from llm_training_tpu.models.base import BaseModelConfig, CausalLMOutput, RouterStats
 from llm_training_tpu.models.deepseek import Deepseek, DeepseekConfig
@@ -26,6 +27,8 @@ from llm_training_tpu.models.qwen3_next import Qwen3Next, Qwen3NextConfig
 from llm_training_tpu.models.solar_open2 import SolarOpen2, SolarOpen2Config
 
 __all__ = [
+    "Afmoe",
+    "AfmoeConfig",
     "Bamba",
     "BambaConfig",
     "BaseModelConfig",

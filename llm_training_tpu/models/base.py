@@ -76,14 +76,24 @@ class BaseModelConfig(BaseModel):
 
     def cache_specs(
         self,
-    ) -> "tuple[KVCacheSpec | LatentCacheSpec, RecurrentCacheSpec | None] | None":
+    ) -> (
+        tuple[
+            KVCacheSpec | LatentCacheSpec | tuple[KVCacheSpec, KVCacheSpec],
+            RecurrentCacheSpec | None,
+        ]
+        | None
+    ):
         """What this family's stack caches when it decodes: first what a
         token leaves behind in the attention layers (keys and values a head,
         `KVCacheSpec`, or one latent row shared by the heads,
         `LatentCacheSpec`), then the slab of its linear-attention layers, if
-        it has any. Every pool, dense buffer, slab and sharding derives from
-        it (`infer/cache.py`). None, the default, says the family does not
-        decode: its `__call__` takes no `decode_state`."""
+        it has any. A stack whose layers differ in how much of the past they
+        keep declares its key/value layers in TWO groups, `(those that keep
+        every token, those that keep a window)`: a pool, a block table and a
+        page budget each (`infer/cache.py:kv_groups`). Every pool, dense
+        buffer, slab and sharding derives from it (`infer/cache.py`). None,
+        the default, says the family does not decode: its `__call__` takes no
+        `decode_state`."""
         return None
 
 
@@ -115,11 +125,15 @@ class RouterStats:
 class KVCacheSpec:
     """The cache of a stack's softmax-attention layers: keys and values a
     token, in pages (`serve/paged_cache.py`) or a dense buffer
-    (`infer/cache.py`). `layers` counts the layers of THIS kind only."""
+    (`infer/cache.py`). `layers` counts the layers of THIS kind only. `window`
+    is how many of a row's newest tokens these layers ever read: None, all of
+    them; a number, and the layers are a group of their own whose pages in
+    front of the window go back to their pool (`serve/scheduler.py`)."""
 
     layers: int
     kv_heads: int
     head_dim: int
+    window: int | None = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -192,6 +206,11 @@ class DecodeState:
     # row; None for a stack that has no such layer
     state: jnp.ndarray | None = None
     conv: jnp.ndarray | None = None
+    # the window group's layers (`KVCacheSpec.window`), `[window_layers,
+    # batch, max_length, num_kv_heads, head_dim]`: held at full length here
+    # and masked; None for a stack with one group
+    window_k: jnp.ndarray | None = None
+    window_v: jnp.ndarray | None = None
     # STATIC (not a pytree leaf): the sequence length the generation will
     # actually reach (padded prompt width + max_new_tokens). Length-
     # dependent RoPE variants (longrope short/long factor selection,
@@ -235,7 +254,15 @@ class PagedDecodeState:
     (`state`, `conv`: `RecurrentCacheSpec`), indexed by decode SLOT, not by
     block. `slots [batch]` names each row's slot (None: row i is slot i, the
     decode step); `fresh [batch]` marks rows whose request starts here, so
-    the slot's state and tail are read as zeros whatever the slot held."""
+    the slot's state and tail are read as zeros whatever the slot held.
+
+    A stack that keeps a window in some of its layers has a SECOND pool for
+    them (`window_k`, `window_v`: `[window_layers, window_blocks, kv_heads,
+    block_size, head_dim]`, block 0 its own trash block) with its own table,
+    `window_tables [batch, window_pages]`, as short as the window's page
+    budget: a row's logical page `p` is at `window_tables[b, p % window_pages]`
+    (a ring; `ops/paged_attention.py`), and a slot whose page went back to
+    the pool names the trash block."""
 
     k: jnp.ndarray
     # None for a latent cache (`LatentCacheSpec`): `k` is then the latent
@@ -247,6 +274,9 @@ class PagedDecodeState:
     conv: jnp.ndarray | None = None
     slots: jnp.ndarray | None = None
     fresh: jnp.ndarray | None = None
+    window_k: jnp.ndarray | None = None
+    window_v: jnp.ndarray | None = None
+    window_tables: jnp.ndarray | None = None
     # STATIC: planned total sequence length for length-dependent RoPE table
     # selection (same contract as DecodeState.rope_length); None = the
     # per-request capacity block_tables can address.

@@ -25,7 +25,13 @@ What a token leaves in those buffers is the stack's declaration
   as keys and, its first `latent_dim` values, as values
   (`ops/latent_attention.py`);
 - beside either, where the stack has linear-attention layers, their slab
-  (`RecurrentCacheSpec`): `state`, `conv` `[layers, slots, ...]`.
+  (`RecurrentCacheSpec`): `state`, `conv` `[layers, slots, ...]`;
+- beside keys and values, where some of the stack's layers keep a window of
+  the past and the others all of it, the window layers' own buffers
+  (`KVCacheSpec.window`, the second GROUP: `window_k`, `window_v`). Paged,
+  they are a pool of their own behind a table as short as the window's page
+  budget (`window_tables`, a ring); dense, buffers at full length, masked. A
+  layer says which group it is of by the `window` it attends with.
 
 The buffers hold EVERY layer of their kind, leading axis over layers, and
 ride the layer loop as its carry (`scan_layers`; a Python variable on a
@@ -47,7 +53,7 @@ from flax.traverse_util import flatten_dict, unflatten_dict
 from llm_training_tpu.models.base import PagedDecodeState
 from llm_training_tpu.ops import dot_product_attention
 
-_BUFFERS = ("k", "v", "state", "conv")
+_BUFFERS = ("k", "v", "state", "conv", "window_k", "window_v")
 
 
 def _slot_rows(slab, slots, fresh):
@@ -92,12 +98,17 @@ class LayerCache:
     incoming chunk already merged in, so every layer masks against the same
     view. Paged: `lengths [B]` counts each row's tokens before this chunk,
     `block_tables` maps its pages; `slots`, `fresh` address the slab
-    (`PagedDecodeState`)."""
+    (`PagedDecodeState`); `window_tables` maps the window group's pages."""
 
     k: jnp.ndarray | None
     v: jnp.ndarray | None  # None beside a latent buffer in `k`
     state: jnp.ndarray | None = None
     conv: jnp.ndarray | None = None
+    # the buffers of the layers that keep a window, where the stack has such
+    # a group beside the layers that keep everything
+    window_k: jnp.ndarray | None = None
+    window_v: jnp.ndarray | None = None
+    window_tables: jnp.ndarray | None = None
     fresh: jnp.ndarray | None = None
     index: jnp.ndarray | None = None
     kv_segment_ids: jnp.ndarray | None = None
@@ -119,7 +130,10 @@ class LayerCache:
         `layer`'s part of the cache and attend q against that part; returns
         `(out, the cache holding the new buffers)`. `layer` is the loop's
         index among the stack's softmax-attention layers: traced under a
-        scan, a Python int in a Python loop.
+        scan, a Python int in a Python loop. In a stack with two groups
+        (`window_k` is there) a layer that attends with a `window` is of the
+        window group and `layer` counts that group's layers; any other layer
+        is of the group that keeps everything.
 
         Dense: the chunk goes in at the shared `index`. The causal term of
         the mask (q_offset = index) hides slots written after this chunk and
@@ -130,24 +144,31 @@ class LayerCache:
 
         Paged: page writer and ragged Pallas decode kernel on a TPU, XLA
         elsewhere; padded chunk positions (segment id 0) go to the trash
-        block."""
+        block. The window group's table is a ring as wide as its page
+        budget, so neither the append nor the read reaches past it."""
+        windowed = window is not None and self.window_k is not None
+        names = ("window_k", "window_v") if windowed else ("k", "v")
+        mine_k, mine_v = (getattr(self, name) for name in names)
+        held = lambda ck, cv: self.replace(**dict(zip(names, (ck, cv))))
         if self.paged:
             from llm_training_tpu.ops.paged_attention import paged_cached_attention
 
             out, (ck, cv) = paged_cached_attention(
-                q, k, v, (self.k, self.v), self.lengths, self.block_tables,
+                q, k, v, (mine_k, mine_v), self.lengths,
+                self.window_tables if windowed else self.block_tables,
                 layer=layer,
                 segment_ids=segment_ids,
                 sliding_window=window,
                 logits_soft_cap=logits_soft_cap,
                 scale=scale,
+                ring=windowed,
             )
-            return out, self.replace(k=ck, v=cv)
+            return out, held(ck, cv)
         ck, cv = (
             jax.lax.dynamic_update_slice(
                 cache, new[None].astype(cache.dtype), (layer, 0, self.index, 0, 0)
             )
-            for cache, new in zip((self.k, self.v), (k, v))
+            for cache, new in zip((mine_k, mine_v), (k, v))
         )
         mine = lambda cache: jax.lax.dynamic_index_in_dim(cache, layer, keepdims=False)
         out = dot_product_attention(
@@ -161,7 +182,7 @@ class LayerCache:
             q_offset=self.index,
             impl="xla",
         )
-        return out, self.replace(k=ck, v=cv)
+        return out, held(ck, cv)
 
     def attend_latent(self, layer, q_nope, q_rope, row, w_kvb, segment_ids, *, scale):
         """A latent-attention (MLA) block's turn: append this chunk's rows
@@ -235,6 +256,7 @@ def open_cache(decode_state, segment_ids, batch: int, seq: int):
         return LayerCache(
             **held, paged=True, lengths=decode_state.lengths,
             block_tables=decode_state.block_tables,
+            window_tables=decode_state.window_tables,
             slots=decode_state.slots, fresh=decode_state.fresh,
         ), segment_ids
     return LayerCache(

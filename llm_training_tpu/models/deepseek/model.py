@@ -125,12 +125,15 @@ class DeepseekMoE(nn.Module):
     and its top-k, the stacked expert parameters hold `experts_held` experts,
     and the output is their part of the routed sum plus the shared experts
     (`models.moe.dropless_moe_apply(held=...)`); no code stands in for the
-    chips that hold the others."""
+    chips that hold the others. `stack = (leaves, layer)` from a decoding
+    layer scan: the experts' `EXPERT_LEAVES` whole, `[L, E, ...]`, read in
+    place (`models/moe.py:experts_in_place`); None, and the grouped products
+    are `jax.lax.ragged_dot` on this layer's own matrices."""
 
     config: DeepseekConfig
 
     @nn.compact
-    def __call__(self, hidden, pad_mask=None):
+    def __call__(self, hidden, pad_mask=None, stack=None):
         cfg = self.config
         num_experts = cfg.n_routed_experts
         held = getattr(cfg, "experts_held", None)
@@ -220,18 +223,26 @@ class DeepseekMoE(nn.Module):
             up = jnp.einsum("th,ehi->tei", xc, w_up)
             return jnp.einsum("tei,eih->teh", nn.silu(gate) * up, w_down)
 
+        from llm_training_tpu.models.moe import (
+            dropless_moe_apply,
+            experts_in_place,
+            grouped_matmul,
+        )
+
+        weights, layer = experts_in_place(
+            stack, (w_gate, w_up, w_down), cfg.moe_impl, compute_dtype
+        )
+
         def ragged_fn(xs, group_sizes, expert_order, w):
             wg, wu, wd = w
-            gate = jax.lax.ragged_dot(xs, wg, group_sizes)
-            up = jax.lax.ragged_dot(xs, wu, group_sizes)
-            return jax.lax.ragged_dot(nn.silu(gate) * up, wd, group_sizes)
-
-        from llm_training_tpu.models.moe import dropless_moe_apply
+            gate = grouped_matmul(xs, wg, group_sizes, layer)
+            up = grouped_matmul(xs, wu, group_sizes, layer)
+            return grouped_matmul(nn.silu(gate) * up, wd, group_sizes, layer)
 
         out, dropped = dropless_moe_apply(
             x.astype(compute_dtype), topk_idx, topk_weights, num_experts,
             cfg.moe_impl, dense_fn, ragged_fn,
-            weights=(w_gate, w_up, w_down),
+            weights=weights,
             ep_capacity_factor=getattr(cfg, "ep_capacity_factor", 2.0),
             held=None if held is None else (cfg.experts_first, held),
         )

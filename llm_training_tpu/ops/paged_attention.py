@@ -19,6 +19,18 @@ then addressed as one pool of `layers * blocks` blocks through a shifted
 block table (docs/serving.md, "How the cache is carried and appended"), so
 a step produces nothing of a pool's shape.
 
+ONE rule says where a row's logical page `p` is in its table: slot `p %
+width`. A table as wide as the row can grow (`max_model_len` in pages) never
+wraps, so for it the rule is `p` itself and the remainder is left out; the
+table of a group of layers that keep a WINDOW of the past is as wide as that
+group's page budget (`serve/paged_cache.py:window_page_budget`) and does
+wrap: `ring=True`. The append, the gather path and the decode kernel all
+follow it, and none reads or writes past the table's width, whatever
+`max_model_len` is. What a slot held before it was given to a newer page, and
+what a slot whose page went back to the pool points at (the trash block), is
+masked by POSITION: the slot stands for the newest page congruent to it, and
+the window's own mask hides every position that page no longer holds.
+
 Two attention paths behind one call:
 
 - single-token decode on TPU (or `impl='pallas'`): the Pallas ragged
@@ -40,14 +52,14 @@ import jax.numpy as jnp
 from llm_training_tpu.ops.attention import _xla_attention
 
 
-def _chunk_pages(lengths, block_tables, segment_ids, batch, seq, page_size):
+def _chunk_pages(lengths, block_tables, segment_ids, batch, seq, page_size, ring=False):
     """Where a chunk of `seq` tokens a row lands, page by page. A chunk that
     starts anywhere touches at most `T = ceil((seq - 1) / page) + 1` pages of
     its row (one for a single token). Returns, for row b's t-th touched page:
     `blocks [B, T]` its pool block, `token [B, T * page]` which chunk token
     each slot takes (clipped into the chunk) and `valid [B, T, page]` whether
     it takes one; and `stray [B, seq]`, the chunk positions that have no slot:
-    padded ones (segment id 0) and those past the table."""
+    padded ones (segment id 0) and those past the table (a ring has no end)."""
     num_pages = block_tables.shape[1]
     touched = (seq - 1 + page_size - 1) // page_size + 1
     logical = (lengths // page_size)[:, None] + jnp.arange(touched, dtype=jnp.int32)
@@ -56,13 +68,16 @@ def _chunk_pages(lengths, block_tables, segment_ids, batch, seq, page_size):
     in_chunk = (token >= 0) & (token < seq)
     token = jnp.clip(token, 0, seq - 1)
     real = jnp.ones((batch, seq), bool) if segment_ids is None else segment_ids > 0
-    chunk_pos = lengths[:, None] + jnp.arange(seq, dtype=jnp.int32)
-    real &= chunk_pos < num_pages * page_size
+    if not ring:
+        chunk_pos = lengths[:, None] + jnp.arange(seq, dtype=jnp.int32)
+        real &= chunk_pos < num_pages * page_size
     valid = (in_chunk & jnp.take_along_axis(real, token, axis=1)).reshape(
         batch, touched, page_size
     )
     blocks = jnp.take_along_axis(
-        block_tables, jnp.minimum(logical, num_pages - 1), axis=1
+        block_tables,
+        logical % num_pages if ring else jnp.minimum(logical, num_pages - 1),
+        axis=1,
     )
     return blocks, token, valid, ~real
 
@@ -77,10 +92,11 @@ def paged_append(
     segment_ids: jnp.ndarray | None,
     impl: str = "auto",
     trash: jnp.ndarray | int = 0,
+    ring: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """`_append` for a key pool and a value pool."""
     return _append(
-        (pool_k, pool_v), (k, v), lengths, block_tables, segment_ids, impl, trash
+        (pool_k, pool_v), (k, v), lengths, block_tables, segment_ids, impl, trash, ring
     )
 
 
@@ -90,7 +106,7 @@ def latent_append(pool, rows, lengths, block_tables, segment_ids, impl="auto", t
     return _append((pool,), (rows,), lengths, block_tables, segment_ids, impl, trash)[0]
 
 
-def _append(pools, xs, lengths, block_tables, segment_ids, impl, trash):
+def _append(pools, xs, lengths, block_tables, segment_ids, impl, trash, ring=False):
     """Write this chunk's k/v `[B, S, H, D]` into the pool at each row's next
     positions (`lengths[b] + i`), touching only the pages those positions lie
     in: each such page is read, the chunk's rows are laid over it, and the
@@ -104,7 +120,7 @@ def _append(pools, xs, lengths, block_tables, segment_ids, impl, trash):
     batch, seq = xs[0].shape[:2]
     _, kv_heads, page_size, head_dim = pools[0].shape
     blocks, token, valid, stray = _chunk_pages(
-        lengths, block_tables, segment_ids, batch, seq, page_size
+        lengths, block_tables, segment_ids, batch, seq, page_size, ring
     )
     # one more page for the strays: the trash block with one of them on slot 0
     blocks = jnp.append(blocks.reshape(-1), jnp.asarray(trash, blocks.dtype))
@@ -161,11 +177,13 @@ def _write_pages(pools, pages, blocks, live, impl):
 
 def _gather_attention(
     q, pool_k, pool_v, lengths, block_tables, segment_ids,
-    sliding_window, logits_soft_cap, scale,
+    sliding_window, logits_soft_cap, scale, ring=False,
 ):
     """XLA fallback: dense gather of each row's pages + per-row causal
     mask. `lengths` here is the PRE-append count, so q position i of row b
-    sits at absolute slot lengths[b] + i."""
+    sits at absolute slot lengths[b] + i. The gather is as wide as the table:
+    a ring's scores are `[B, heads, S, budget * page]`, whatever the row's
+    length."""
     batch, seq = q.shape[:2]
     _, kv_heads, page_size, head_dim = pool_k.shape
     num_pages = block_tables.shape[1]
@@ -179,12 +197,24 @@ def _gather_attention(
     gk, gv = gather(pool_k), gather(pool_v)
     q_pos = lengths[:, None] + jnp.arange(seq, dtype=jnp.int32)[None, :]
     kv_pos = jnp.arange(num_pages * page_size, dtype=jnp.int32)
+    keys = lambda: kv_pos[None, None, None, :]
+    if ring:
+        # slot s stands for the newest page congruent to it that the chunk's
+        # last position has reached; one that never held a page is before 0
+        newest = (lengths + seq - 1) // page_size
+        page_of = newest[:, None] - (
+            newest[:, None] - jnp.arange(num_pages, dtype=jnp.int32)
+        ) % num_pages
+        kv_pos = jnp.repeat(page_of, page_size, axis=1) * page_size + kv_pos % page_size
+        keys = lambda: kv_pos[:, None, None, :]
     # [B, 1, S, KV] — True = attend; the causal term alone hides unwritten
     # slots (their position is ahead of every query) and other requests'
     # blocks never appear in this row's table
-    mask = kv_pos[None, None, None, :] <= q_pos[:, None, :, None]
+    mask = keys() <= q_pos[:, None, :, None]
+    if ring:
+        mask &= keys() >= 0
     if sliding_window is not None:
-        mask &= q_pos[:, None, :, None] - kv_pos[None, None, None, :] < sliding_window
+        mask &= q_pos[:, None, :, None] - keys() < sliding_window
     if segment_ids is not None:
         mask &= (segment_ids > 0)[:, None, :, None]
     return _xla_attention(
@@ -240,6 +270,7 @@ def paged_cached_attention(
     logits_soft_cap: float | None = None,
     scale: float | None = None,
     impl: str = "auto",
+    ring: bool = False,
 ) -> tuple[jnp.ndarray, tuple[jnp.ndarray, jnp.ndarray]]:
     """Append this chunk's k/v through the block table, then attend each
     row against its own cache. q/k/v `[B, S, H*, D]` (S == 1 on the decode
@@ -253,6 +284,9 @@ def paged_cached_attention(
     blocks and `layer * N` is added to the table, so this layer appends to
     and reads ITS blocks of the carried buffer (its trash block is `layer *
     N`) and the layer's pool is never cut out of the stack.
+
+    `ring`: the table is a window group's, as wide as its page budget, and
+    logical page `p` is in slot `p % width` (the module docstring).
 
     impl: 'auto' (Pallas kernels on TPU — the page writer, and the decode
     kernel for single-token decode — XLA elsewhere) | 'pallas' (kernels
@@ -269,7 +303,7 @@ def paged_cached_attention(
         pool_k, pool_v = (pool.reshape(-1, *stack_shape[2:]) for pool in layer_kv)
     lengths = lengths.astype(jnp.int32)
     ck, cv = paged_append(
-        pool_k, pool_v, k, v, lengths, block_tables, segment_ids, impl, trash
+        pool_k, pool_v, k, v, lengths, block_tables, segment_ids, impl, trash, ring
     )
 
     seq = q.shape[1]
@@ -283,12 +317,13 @@ def paged_cached_attention(
             lambda q, pk, pv, tables, lens: paged_decode_attention(
                 q, pk, pv, tables, lens, scale=scale,
                 sliding_window=sliding_window, logits_soft_cap=logits_soft_cap,
+                ring=ring,
             ),
             (q[:, 0], ck, cv, block_tables, lengths + 1), (1, 1, 1, None, None), 0,
         )[:, None]
     else:
         out = _gather_attention(
             q, ck, cv, lengths, block_tables, segment_ids,
-            sliding_window, logits_soft_cap, scale,
+            sliding_window, logits_soft_cap, scale, ring,
         )
     return out, (ck.reshape(stack_shape), cv.reshape(stack_shape))

@@ -93,6 +93,7 @@ def _decode_kernel(
     scale: float,
     sliding_window: int | None,
     logits_soft_cap: float | None,
+    ring: bool,
 ):
     b = pl.program_id(0)
     num_kv_heads, group, head_dim = q_ref.shape[1:]
@@ -122,7 +123,9 @@ def _decode_kernel(
         start, live = trip_span(trip)
 
         def one(i, _):
-            block = tables[b, start + i]
+            page = start + i
+            # a window group's table is a ring as wide as its page budget
+            block = tables[b, lax.rem(page, tables.shape[1]) if ring else page]
             for which, (hbm, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
                 act(pltpu.make_async_copy(
                     hbm.at[block], buf.at[slot, :, page_rows(i), :],
@@ -206,6 +209,7 @@ def paged_decode_attention(
     scale: float | None = None,
     sliding_window: int | None = None,
     logits_soft_cap: float | None = None,
+    ring: bool = False,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """One ragged decode step: q `[B, Hq, D]` (one token per row) against
@@ -214,7 +218,9 @@ def paged_decode_attention(
     `lengths [B]` counts tokens written INCLUDING this step's (the caller
     appends before attending). Rows a scheduler left idle should carry
     length 1 and a trash-block table — they compute one garbage token the
-    caller ignores. Returns `[B, Hq, D]`.
+    caller ignores. Returns `[B, Hq, D]`. With `ring` (a sliding window's
+    own short table) logical page `p` of a row is at `block_tables[b, p % P]`:
+    the row walks the pages its window reaches, whatever its length.
 
     `interpret=None` interprets off-TPU and compiles on a TPU; asking for
     the interpreter on a TPU raises (`resolve_interpret`)."""
@@ -262,6 +268,7 @@ def paged_decode_attention(
             scale=scale,
             sliding_window=sliding_window,
             logits_soft_cap=logits_soft_cap,
+            ring=ring,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,

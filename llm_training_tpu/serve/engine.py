@@ -31,6 +31,16 @@ the fold-in requeue after an eviction alike: `serve/state_resets`), later
 chunks and decode steps carry the slot's state on, and padded positions,
 idle slots and slots still prefilling are left exactly as they were.
 
+A stack whose layers differ in how much of the past they keep
+(`infer/cache.py:kv_groups`: some keep a sliding window, the others every
+token) has a pool, an allocator and a block table a GROUP. The window group's
+table is `window_pages` wide (`serve/paged_cache.py:window_page_budget`:
+window + one chunk, in pages, + 1) whatever `max_model_len` is, a ring the
+programs address by `page % window_pages`; after every chunk and every decode
+step a request gives back the pages that fell wholly in front of its window
+(`Scheduler.release_window`; `serve/window_pages_released`), and the slot of
+a page given back names the trash block.
+
 The host loop (`step()`) executes what the `Scheduler` decides: admission
 when free blocks suffice, one prefill chunk interleaved between decode
 steps, eviction/requeue under block pressure, slot recycling on eos /
@@ -70,6 +80,7 @@ import jax.numpy as jnp
 import numpy as np
 from pydantic import BaseModel, ConfigDict, model_validator
 
+from llm_training_tpu.infer.cache import kv_groups
 from llm_training_tpu.infer.sampling import (
     SamplingConfig,
     sample_tokens_with_logprob,
@@ -81,13 +92,16 @@ from llm_training_tpu.serve.paged_cache import (
     BlockAllocator,
     init_paged_pool,
     init_state_slab,
+    init_window_pool,
     pool_bytes,
     resolve_block_size,
+    window_page_budget,
 )
 from llm_training_tpu.serve.scheduler import (
     Scheduler,
     SchedulerConfig,
     ServeRequest,
+    WindowGroup,
 )
 from llm_training_tpu.telemetry.profiling import install_trace_annotator
 from llm_training_tpu.telemetry.registry import get_registry
@@ -193,9 +207,28 @@ class ServingEngine:
         num_blocks = self.config.num_blocks
         if num_blocks is None:
             num_blocks = self.config.max_batch * self.pages_per_request
+        # the layers that keep a window, where the stack has such a group:
+        # the window, the pages of it a request may hold, the pool's blocks
+        # (as many as every row at its budget needs, at most `num_blocks`)
+        window_group = kv_groups(model_config)[1]
+        self.sliding_window = self.window_pages = None
+        window_blocks = 0
+        if window_group is not None:
+            self.sliding_window = window_group.window
+            self.window_pages = window_page_budget(
+                self.sliding_window, self.config.prefill_chunk, self.block_size,
+                self.pages_per_request,
+            )
+            window_blocks = min(num_blocks, self.config.max_batch * self.window_pages)
         with self._ctx():
             self._pool_k, self._pool_v = init_paged_pool(
                 model_config, num_blocks + 1, self.block_size,
+                mesh=self.mesh, rules=self.rules,
+                cache_dtype=self.config.cache_dtype,
+            )
+            # None for a stack with one group
+            self._window_pool = init_window_pool(
+                model_config, window_blocks + 1, self.block_size,
                 mesh=self.mesh, rules=self.rules,
                 cache_dtype=self.config.cache_dtype,
             )
@@ -207,7 +240,14 @@ class ServingEngine:
         self._cache_bytes = pool_bytes(self._pool_k, self._pool_v)  # outlives close()
         self._latent_pool = self._pool_v is None
         self._state_bytes = 0 if self._slab is None else pool_bytes(*self._slab)
+        self._window_bytes = (
+            0 if self._window_pool is None else pool_bytes(*self._window_pool)
+        )
         self.allocator = BlockAllocator(num_blocks + 1)
+        self.window_allocator = (
+            None if self._window_pool is None
+            else BlockAllocator(window_blocks + 1, group="window")
+        )
         self.scheduler = Scheduler(
             SchedulerConfig(
                 max_batch=self.config.max_batch,
@@ -218,6 +258,9 @@ class ServingEngine:
                 shed_ttft_ms=self.config.shed_ttft_ms,
             ),
             self.allocator,
+            None if self.window_allocator is None else WindowGroup(
+                self.window_allocator, self.sliding_window, self.window_pages
+            ),
         )
         self._build_programs()
         # a profiler capture of this process holds step()'s spans beside the
@@ -275,8 +318,8 @@ class ServingEngine:
 
     # the pool, as the two attributes every caller knows. A caller that
     # drops the pool (`close()`, or setting either to None) drops the state
-    # slab with it: the caches live and die together, whatever else still
-    # holds the engine.
+    # slab and the window group's pool with it: the caches live and die
+    # together, whatever else still holds the engine.
     @property
     def _pool_k(self):
         return self._k
@@ -285,7 +328,7 @@ class ServingEngine:
     def _pool_k(self, value):
         self._k = value
         if value is None:
-            self._slab = None
+            self._slab = self._window_pool = None
 
     @property
     def _pool_v(self):
@@ -295,7 +338,7 @@ class ServingEngine:
     def _pool_v(self, value):
         self._v = value
         if value is None:
-            self._slab = None
+            self._slab = self._window_pool = None
 
     # ------------------------------------------------------------ programs
 
@@ -320,13 +363,27 @@ class ServingEngine:
         def slab_of(state):
             return None if state.state is None else (state.state, state.conv)
 
+        def window_fields(pool, tables):
+            if pool is None:
+                return {}
+            return {"window_k": pool[0], "window_v": pool[1], "window_tables": tables}
+
+        def with_window(outs, state):
+            """A program's outputs, and last the window group's pool where the
+            stack has one: any other stack returns what it always did."""
+            if state.window_k is None:
+                return outs
+            return (*outs, (state.window_k, state.window_v))
+
         def prefill_chunk(variables, ids, seg, pos, pool_k, pool_v, tables, length,
-                          last_pos, rng, slab=None, slot=None, fresh=None, moe=None):
+                          last_pos, rng, slab=None, slot=None, fresh=None, moe=None,
+                          window_pool=None, window_tables=None):
             state = PagedDecodeState(
                 k=pool_k, v=pool_v, block_tables=tables, lengths=length,
                 rope_length=rope_length,
                 # the request's slot of the slab, read as zeros on its first chunk
                 **slab_fields(slab, slots=slot, fresh=fresh),
+                **window_fields(window_pool, window_tables),
             )
             out = model.apply(
                 variables, input_ids=ids, segment_ids=seg,
@@ -342,13 +399,16 @@ class ServingEngine:
             state = out.decode_state
             # the chunk's expert assignments join the carry, on the device
             moe = None if moe is None else moe + out.moe_assignments
-            return state.k, state.v, token[0], logprob[0], slab_of(state), moe
+            return with_window(
+                (state.k, state.v, token[0], logprob[0], slab_of(state), moe), state
+            )
 
         def decode_step(variables, tokens, pool_k, pool_v, tables, lengths, rng, slab=None,
-                        moe=None):
+                        moe=None, window_pool=None, window_tables=None):
             state = PagedDecodeState(
                 k=pool_k, v=pool_v, block_tables=tables, lengths=lengths,
                 rope_length=rope_length, **slab_fields(slab),
+                **window_fields(window_pool, window_tables),
             )
             # row i is slot i. A slot that does not decode this step (idle, or
             # its prompt still prefilling) has length 0 here: segment 0, so
@@ -367,27 +427,36 @@ class ServingEngine:
             state = out.decode_state
             # the carry and this step's assignments: fetched with the tokens
             moe = None if moe is None else moe + out.moe_assignments
-            return state.k, state.v, token, logprob, slab_of(state), moe
+            return with_window((state.k, state.v, token, logprob, slab_of(state), moe), state)
 
         # the function names ARE the programs' names (`jit_prefill_chunk`,
         # `jit_decode_step` in HLO module names and in a device profile):
         # docs, chip_smoke.py and the benchmark's trace readers match them.
         # A contract, pinned by tests/test_serve_spans.py.
-        # (the slab goes by keyword: a stack without one is called as before)
+        # (the slab and the window group's pool go by keyword: a stack
+        # without one is called as before)
         self._prefill_jit = jax.jit(
-            prefill_chunk, donate_argnums=(4, 5), donate_argnames=("slab",)
+            prefill_chunk, donate_argnums=(4, 5), donate_argnames=("slab", "window_pool")
         )
         self._decode_jit = jax.jit(
-            decode_step, donate_argnums=(2, 3), donate_argnames=("slab",)
+            decode_step, donate_argnums=(2, 3), donate_argnames=("slab", "window_pool")
         )
 
     def _next_rng(self):
         self._call += 1
         return jax.random.fold_in(self._rng, self._call)
 
-    def _table_row(self, request: ServeRequest) -> np.ndarray:
-        row = np.zeros((self.pages_per_request,), np.int32)
-        row[: len(request.blocks)] = request.blocks
+    def _table_row(self, request: ServeRequest, window: bool = False) -> np.ndarray:
+        """The request's row of a group's block table. The window group's is
+        a ring: logical page `p` in slot `p % window_pages`, and a slot whose
+        page was given back (or never taken) names the trash block."""
+        if not window:
+            row = np.zeros((self.pages_per_request,), np.int32)
+            row[: len(request.blocks)] = request.blocks
+            return row
+        row = np.zeros((self.window_pages,), np.int32)
+        pages = request.window_first + np.arange(len(request.window_blocks))
+        row[pages % self.window_pages] = request.window_blocks
         return row
 
     # -------------------------------------------------------------- intake
@@ -577,15 +646,14 @@ class ServingEngine:
             if self.journal is not None:
                 self.journal.progress(request)
                 journaled += 1
-        summary = {
-            "journaled": journaled,
-            "blocks_in_use": self.allocator.blocks_in_use,
-            "step": self._step_index,
-        }
+        in_use = self.allocator.blocks_in_use + (
+            0 if self.window_allocator is None else self.window_allocator.blocks_in_use
+        )
+        summary = {"journaled": journaled, "blocks_in_use": in_use, "step": self._step_index}
         get_tracer().instant("serve", "drain", **summary)
         logger.warning(
             "drain: %d unfinished request(s) journaled for replay "
-            "(%d pool blocks in use)", journaled, self.allocator.blocks_in_use,
+            "(%d pool blocks in use)", journaled, in_use,
         )
         return summary
 
@@ -597,8 +665,9 @@ class ServingEngine:
         `stats()` still answers. Idempotent."""
         if self._pool_k is None:
             return
-        jax.block_until_ready((self._pool_k, self._pool_v, self._slab))
-        for buffer in (self._pool_k, self._pool_v, *(self._slab or ())):
+        jax.block_until_ready((self._pool_k, self._pool_v, self._slab, self._window_pool))
+        for buffer in (self._pool_k, self._pool_v, *(self._slab or ()),
+                       *(self._window_pool or ())):
             if buffer is not None:
                 buffer.delete()
         self._pool_k = self._pool_v = None
@@ -623,6 +692,8 @@ class ServingEngine:
         }
         if self._slab is not None:
             counts["state_resets"] = 0
+        if self.window_allocator is not None:
+            counts["window_live_tokens"] = counts["window_pages_released"] = 0
         # every child span below runs on this thread inside engine_step and
         # carries the step index; nesting by time gives the parent. Children
         # go to the ring and the profiler only (`write=False`): trace.jsonl
@@ -692,6 +763,9 @@ class ServingEngine:
         for kind in ("held", "zero", "elsewhere"):
             if counts.get(f"moe_{kind}"):
                 registry.counter(f"serve/moe_{kind}_assignments").inc(counts[f"moe_{kind}"])
+        for name in ("window_live_tokens", "window_pages_released"):
+            if counts.get(name):
+                registry.counter(f"serve/{name}").inc(counts[name])
         if counts["prefill_chunks"]:
             registry.counter("serve/prefill_chunks").inc(counts["prefill_chunks"])
         if counts["decode_rows"]:
@@ -784,15 +858,19 @@ class ServingEngine:
                 }
                 if self._moe_carry is not None:
                     slab_row["moe"] = self._moe_carry
+                if self._window_pool is not None:
+                    slab_row["window_pool"] = self._window_pool
+                    slab_row["window_tables"] = self._table_row(request, window=True)[None, :]
                 (self._pool_k, self._pool_v, token, logprob, self._slab,
-                 self._moe_carry) = self._prefill_jit(
+                 self._moe_carry) = self._take_window_pool(self._prefill_jit(
                     self.variables, jnp.asarray(ids_row), jnp.asarray(seg),
                     jnp.asarray(pos), self._pool_k, self._pool_v,
                     jnp.asarray(tables), jnp.asarray([start], jnp.int32),
                     jnp.int32(len(chunk) - 1), self._next_rng(), **slab_row,
-                )
+                ))
             request.prefilled += len(chunk)
             request.cache_len += len(chunk)
+            self._release_window(request)
             if final:
                 with tracer.measure("serve", "prefill_fetch", write=False, **ids):
                     host_token, host_logprob = jax.device_get((token, logprob))
@@ -805,6 +883,22 @@ class ServingEngine:
             # clock reading, a few microseconds after prefill_chunk closed
             request.advance_phase("decode")
         return events
+
+    def _take_window_pool(self, outs: tuple) -> tuple:
+        """A program's outputs less the window group's pool, which a stack
+        with such a group returns last: kept, as the program left it."""
+        if self._window_pool is None:
+            return outs
+        *outs, self._window_pool = outs
+        return outs
+
+    def _release_window(self, request: ServeRequest) -> None:
+        """After a chunk or a decode step: the request's pages of the window
+        group that no later token reads go back to that group's allocator."""
+        if self.window_allocator is not None:
+            self._step_counts["window_pages_released"] += (
+                self.scheduler.release_window(request)
+            )
 
     def _run_decode(self, rows: list[ServeRequest]) -> list[dict]:
         events: list[dict] = []
@@ -830,10 +924,16 @@ class ServingEngine:
             tokens = np.zeros((batch,), np.int32)
             lengths = np.zeros((batch,), np.int32)
             tables = np.zeros((batch, self.pages_per_request), np.int32)
+            window_tables = (
+                None if self._window_pool is None
+                else np.zeros((batch, self.window_pages), np.int32)
+            )
             for request in survivors:
                 tokens[request.slot] = request.generated[-1]
                 lengths[request.slot] = request.cache_len
                 tables[request.slot] = self._table_row(request)
+                if window_tables is not None:
+                    window_tables[request.slot] = self._table_row(request, window=True)
             self._step_counts["decode_rows"] = len(survivors)
             # what the paged kernel reads this call: each row's cache and its
             # new token
@@ -845,6 +945,13 @@ class ServingEngine:
             step_slab = {} if self._slab is None else {"slab": self._slab}
             if self._moe_carry is not None:
                 step_slab["moe"] = self._moe_carry
+            if window_tables is not None:
+                step_slab["window_pool"] = self._window_pool
+                step_slab["window_tables"] = window_tables
+                # what a window layer's call reads: of each row, its window
+                self._step_counts["window_live_tokens"] = sum(
+                    min(r.cache_len + 1, self.sliding_window) for r in survivors
+                )
         if not self._decode_attr_done:
             # before the donating call below: lowering only reads avals,
             # while the jit consumes the pool buffers
@@ -853,8 +960,8 @@ class ServingEngine:
         # the enqueue alone, then the wait for the device: a step that reads
         # far off shows in which of the two its seconds went
         with tracer.measure("serve", "decode_dispatch", **child):
-            self._pool_k, self._pool_v, out, out_lp, self._slab, moe = self._decode_jit(
-                *step_args, **step_slab
+            self._pool_k, self._pool_v, out, out_lp, self._slab, moe = self._take_window_pool(
+                self._decode_jit(*step_args, **step_slab)
             )
         with tracer.measure("serve", "decode_fetch", **child):
             host, host_lp, moe = jax.device_get((out, out_lp, moe))
@@ -868,6 +975,7 @@ class ServingEngine:
                 self._moe_carry = np.zeros((3,), np.int32)
             for request in survivors:
                 request.cache_len += 1
+                self._release_window(request)
                 self._emit_token(
                     request, int(host[request.slot]), events,
                     logprob=float(host_lp[request.slot]),
@@ -1052,6 +1160,8 @@ class ServingEngine:
             "serve/tokens_per_sec_per_chip": tps / n_chips,
             "serve/peak_running": float(self.peak_running),
             "decode/cache_bytes": float(self._cache_bytes),
+            "decode/global_pool_bytes": float(self._cache_bytes),
+            "decode/window_pool_bytes": float(self._window_bytes),
             "decode/state_bytes": float(self._state_bytes),
             "decode/latent_pool_bytes": float(
                 self._cache_bytes if self._latent_pool else 0
@@ -1078,6 +1188,14 @@ class ServingEngine:
             # counters already (step()): read into the summary, not published twice
             for kind in ("held", "zero", "elsewhere"):
                 key = f"serve/moe_{kind}_assignments"
+                stats[key] = float(registry.counter(key).value)
+        if self.window_allocator is not None:
+            # the window group's allocator publishes its own gauges; the two
+            # sums are counters already
+            stats["decode/window_blocks_total"] = float(self.window_allocator.num_blocks - 1)
+            stats["decode/window_blocks_in_use"] = float(self.window_allocator.blocks_in_use)
+            stats["decode/window_peak_blocks_in_use"] = float(self.window_allocator.peak_in_use)
+            for key in ("serve/window_live_tokens", "serve/window_pages_released"):
                 stats[key] = float(registry.counter(key).value)
         logger.info(
             "serve: %d completed (%d evictions) | %.1f tokens/s (%.1f/chip)",

@@ -11,6 +11,12 @@ Physical block 0 is a reserved TRASH block: idle decode slots and padded
 chunk positions write there, so a garbage row can never touch a live
 request's cache.
 
+A stack whose layers differ in how much of the past they keep (`infer/cache.py:
+kv_groups`) has a pool a GROUP: the one above for the layers that keep every
+token, and `init_window_pool`'s for the layers that keep a window, with its
+own trash block 0, its own allocator and a page budget a request that does not
+grow with `max_model_len` (`window_page_budget`).
+
 The `BlockAllocator` is the host half: a free list handing fixed-size
 blocks to requests and taking them back on completion/eviction, publishing
 pool occupancy as `decode/cache_blocks_total` / `decode/cache_blocks_in_use`
@@ -58,6 +64,28 @@ def resolve_block_size(
     return choice.block_k
 
 
+def _zero_pools(shape, dtype, buffers: int, mesh, rules) -> tuple:
+    """`buffers` all-zeros pools of `shape`, created already sharded under a
+    mesh (kv heads over 'tensor', like the dense cache; a latent pool's one
+    row a token is shared by the heads: it has no head axis to shard, and
+    `_divisible_spec` drops the rule)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding
+
+    from llm_training_tpu.infer.cache import _divisible_spec
+
+    def build():
+        return tuple(jnp.zeros(shape, dtype) for _ in range(buffers))
+
+    if mesh is None:
+        return build()
+    spec = NamedSharding(
+        mesh, _divisible_spec(shape, POOL_LOGICAL_AXES, mesh, rules or ())
+    )
+    return jax.jit(build, out_shardings=(spec,) * buffers)()
+
+
 def init_paged_pool(
     model_config,
     num_blocks: int,
@@ -70,37 +98,50 @@ def init_paged_pool(
     (kv heads over 'tensor', like the dense cache). A stack that caches
     latent rows (`LatentCacheSpec`) has ONE pool, `[mla_blocks, num_blocks, 1,
     block_size, width]`: it comes back as `k`, and `v` is None. Publishes the
-    pool footprint as the `decode/cache_bytes` gauge, and a latent pool's as
+    pool footprint as the `decode/cache_bytes` gauge (and as
+    `decode/global_pool_bytes`: of a stack with two groups this is the pool of
+    the layers that keep every token), and a latent pool's as
     `decode/latent_pool_bytes` too."""
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import NamedSharding
-
-    from llm_training_tpu.infer.cache import (
-        _divisible_spec,
-        resolve_cache_dtype,
-        token_rows,
-    )
+    from llm_training_tpu.infer.cache import resolve_cache_dtype, token_rows
 
     buffers, num_layers, kv_heads, head_dim = token_rows(model_config)
-    dtype = resolve_cache_dtype(model_config, cache_dtype)
-    shape = (num_layers, num_blocks, kv_heads, block_size, head_dim)
-
-    def build():
-        return tuple(jnp.zeros(shape, dtype) for _ in range(buffers))
-
-    if mesh is None:
-        pools = build()
-    else:
-        # (a latent pool's one row a token is shared by the heads: it has no
-        # head axis to shard, and `_divisible_spec` drops the rule)
-        spec = NamedSharding(
-            mesh, _divisible_spec(shape, POOL_LOGICAL_AXES, mesh, rules or ())
-        )
-        pools = jax.jit(build, out_shardings=(spec,) * buffers)()
+    pools = _zero_pools(
+        (num_layers, num_blocks, kv_heads, block_size, head_dim),
+        resolve_cache_dtype(model_config, cache_dtype), buffers, mesh, rules,
+    )
     k, v = pools if buffers == 2 else (pools[0], None)
     _publish_pool_gauges(k, v, num_blocks)
     return k, v
+
+
+def window_page_budget(
+    sliding_window: int, prefill_chunk: int, block_size: int, pages_per_request: int
+) -> int:
+    """Pages of the window group a request holds at most, so also the width
+    of its table there: the window and one prefill chunk, rounded up to pages,
+    and one more for a chunk that starts inside a page; never more than the
+    other group's. A row's logical page `p` lives in slot `p % budget`."""
+    return min(-(-(sliding_window + prefill_chunk) // block_size) + 1, pages_per_request)
+
+
+def init_window_pool(model_config, num_blocks: int, block_size: int, mesh=None,
+                     rules=None, cache_dtype: str | None = None):
+    """The (k, v) pool of the layers that keep a window (`infer/cache.py:
+    kv_groups`), shaped and sharded as `init_paged_pool`'s, or None for a stack
+    with one group. Publishes `decode/window_pool_bytes` and
+    `decode/window_blocks_total`."""
+    from llm_training_tpu.infer.cache import kv_groups, resolve_cache_dtype
+    from llm_training_tpu.telemetry import get_registry
+
+    registry = get_registry()
+    _, group = kv_groups(model_config)
+    pools = None if group is None else _zero_pools(
+        (group.layers, num_blocks, group.kv_heads, block_size, group.head_dim),
+        resolve_cache_dtype(model_config, cache_dtype), 2, mesh, rules,
+    )
+    registry.gauge("decode/window_pool_bytes").set(0 if pools is None else pool_bytes(*pools))
+    registry.gauge("decode/window_blocks_total").set(0 if pools is None else num_blocks - 1)
+    return pools
 
 
 def init_state_slab(model_config, slots: int, mesh=None, rules=None,
@@ -129,6 +170,7 @@ def _publish_pool_gauges(k, v, num_blocks: int) -> None:
 
     registry = get_registry()
     registry.gauge("decode/cache_bytes").set(pool_bytes(k, v))
+    registry.gauge("decode/global_pool_bytes").set(pool_bytes(k, v))
     registry.gauge("decode/latent_pool_bytes").set(0 if v is not None else pool_bytes(k, v))
     registry.gauge("decode/cache_blocks_total").set(num_blocks - 1)  # minus trash
 
@@ -136,9 +178,13 @@ def _publish_pool_gauges(k, v, num_blocks: int) -> None:
 class BlockAllocator:
     """Host-side free list over the pool's physical blocks (block 0
     reserved as trash). All-or-nothing `alloc`, idempotence-free `free`
-    (double-free is a bug and raises), occupancy gauges on every change."""
+    (double-free is a bug and raises), occupancy gauges on every change:
+    `decode/<group>_blocks_in_use` and `decode/<group>_peak_blocks_in_use`
+    (`cache`: the pool of the layers that keep every token; `window`: the
+    window group's)."""
 
-    def __init__(self, num_blocks: int):
+    def __init__(self, num_blocks: int, group: str = "cache"):
+        self.group = group
         if num_blocks < 2:
             raise ValueError(
                 f"need >= 2 blocks (1 usable + trash), got {num_blocks}"
@@ -181,5 +227,5 @@ class BlockAllocator:
         from llm_training_tpu.telemetry import get_registry
 
         registry = get_registry()
-        registry.gauge("decode/cache_blocks_in_use").set(len(self._in_use))
-        registry.gauge("decode/cache_peak_blocks_in_use").set(self.peak_in_use)
+        registry.gauge(f"decode/{self.group}_blocks_in_use").set(len(self._in_use))
+        registry.gauge(f"decode/{self.group}_peak_blocks_in_use").set(self.peak_in_use)

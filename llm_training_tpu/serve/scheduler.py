@@ -18,6 +18,18 @@ Per engine step the scheduler decides three things:
   so on re-admission it re-prefills and CONTINUES; greedy decode makes the
   continuation token-identical to an uninterrupted run.
 
+A stack whose layers differ in how much of the past they keep has a pool a
+GROUP (`serve/paged_cache.py`), and the scheduler an allocator a group:
+`allocator` for the layers that keep every token, and `window` (a
+`WindowGroup`: the allocator of the layers that keep `sliding_window`, that
+window and the page budget, which the engine derives together). A request takes pages of both or of
+neither; its pages of the first group it holds from admission on, as ever;
+of the window group it holds the pages its window and its next chunk reach,
+taken chunk by chunk (`next_prefill`) and token by token
+(`ensure_decode_blocks`) and GIVEN BACK (`release_window`) as soon as they lie
+wholly in front of `cache_len - sliding_window + 1`: at most `window.pages`
+at a time, however long the request.
+
 Two admission-control policies ride the same machinery
 (docs/serving.md#resilience):
 
@@ -55,6 +67,7 @@ import math
 import time
 from collections import deque
 from dataclasses import dataclass, field
+from typing import Any, NamedTuple
 
 from llm_training_tpu.telemetry.trace import get_tracer
 
@@ -83,6 +96,10 @@ class ServeRequest:
     emitted: int = 0  # tokens already streamed (an evict/resume never re-emits)
     slot: int | None = None
     blocks: list[int] = field(default_factory=list)
+    # the window group's pages this request holds: its logical pages
+    # `window_first ..`, the ones in front given back (`release_window`)
+    window_blocks: list[int] = field(default_factory=list)
+    window_first: int = 0
     prefill_tokens: list[int] = field(default_factory=list)  # this residency's prefill
     prefilled: int = 0  # prefill_tokens positions already written
     cache_len: int = 0  # tokens whose KV is in the pool
@@ -146,16 +163,31 @@ class SchedulerConfig:
     shed_ttft_ms: float | None = None
 
 
+class WindowGroup(NamedTuple):
+    """The window group as the engine derives it from the model's
+    declaration: its allocator, how many of a row's newest tokens its layers
+    read, and the pages of it a request may hold at once
+    (`serve/paged_cache.py:window_page_budget`). Not an option of a
+    deployment: it comes with the allocator or not at all."""
+
+    allocator: Any
+    sliding_window: int
+    pages: int
+
+
 class Scheduler:
     """Owns the waiting queue, the slot map, and the block accounting
     policy; the `ServingEngine` executes what `admit`/`next_prefill`/
     `ensure_decode_blocks` decide."""
 
-    def __init__(self, config: SchedulerConfig, allocator):
+    def __init__(self, config: SchedulerConfig, allocator, window: WindowGroup | None = None):
         if config.prefill_chunk < 1:
             raise ValueError("prefill_chunk must be >= 1")
         self.config = config
         self.allocator = allocator
+        self.window = window
+        self.window_allocator = None if window is None else window.allocator
+        self.window_pages_released = 0
         self.waiting: deque[ServeRequest] = deque()
         self.running: dict[int, ServeRequest] = {}  # slot -> request
         self._free_slots = list(range(config.max_batch - 1, -1, -1))
@@ -273,20 +305,39 @@ class Scheduler:
 
     # --------------------------------------------------------- admission
 
+    def _take(self, pages: int, window_pages: int) -> tuple[list[int], list[int]] | None:
+        """`pages` of the first group and `window_pages` of the window group,
+        or None and nothing taken."""
+        blocks = self.allocator.alloc(pages)
+        if blocks is None or not window_pages:
+            return None if blocks is None else (blocks, [])
+        window = self.window_allocator.alloc(window_pages)
+        if window is None:
+            self.allocator.free(blocks)
+            return None
+        return blocks, window
+
     def admit(self) -> list[ServeRequest]:
         """Admit waiting requests while a slot is free and the pool covers
-        each one's (re)prefill + one decode-step write. A head-of-queue
-        request the pool can NEVER satisfy (even with everything else
-        drained) fails with stop_reason='capacity' rather than starving
-        the queue behind it."""
+        each one's (re)prefill + one decode-step write: of the window group,
+        its first chunk's pages. A head-of-queue request the pool can NEVER
+        satisfy (even with everything else drained) fails with
+        stop_reason='capacity' rather than starving the queue behind it; so
+        does one whose window would not fit the window pool when alone."""
         admitted = []
         while self.waiting and self._free_slots:
             request = self.waiting[0]
             resident = request.prompt + request.generated
             needed = self._blocks_for(len(resident) + 1)
-            blocks = self.allocator.alloc(needed)
-            if blocks is None:
-                if not self.running and not admitted:
+            window_needed, fits = 0, True
+            if self.window_allocator is not None:
+                window_needed = self._blocks_for(
+                    min(len(resident) + 1, self.config.prefill_chunk)
+                )
+                fits = min(needed, self.window.pages) < self.window_allocator.num_blocks
+            taken = self._take(needed, window_needed) if fits else None
+            if taken is None:
+                if not fits or (not self.running and not admitted):
                     # nothing left to drain — this request cannot ever fit
                     self.waiting.popleft()
                     request.stop_reason = "capacity"
@@ -296,7 +347,8 @@ class Scheduler:
                 break
             self.waiting.popleft()
             request.slot = self._free_slots.pop()
-            request.blocks = blocks
+            request.blocks, request.window_blocks = taken
+            request.window_first = 0
             request.prefill_tokens = resident
             request.prefilled = 0
             request.cache_len = 0
@@ -309,17 +361,21 @@ class Scheduler:
 
     def next_prefill(self) -> tuple[ServeRequest, list[int], int] | None:
         """(request, chunk_tokens, chunk_start) for the oldest running
-        request with prompt left to prefill, or None."""
-        pending = [
-            r for r in self.running.values()
-            if r.prefilled < len(r.prefill_tokens)
-        ]
-        if not pending:
-            return None
-        request = min(pending, key=lambda r: r.arrival_s)
-        start = request.prefilled
-        chunk = request.prefill_tokens[start:start + self.config.prefill_chunk]
-        return request, chunk, start
+        request with prompt left to prefill, or None. The chunk's pages of
+        the window group are taken here, evicting under pressure as a decode
+        row's growth does; a request that got evicted itself is passed over."""
+        while True:
+            pending = [
+                r for r in self.running.values()
+                if r.prefilled < len(r.prefill_tokens)
+            ]
+            if not pending:
+                return None
+            request = min(pending, key=lambda r: r.arrival_s)
+            start = request.prefilled
+            chunk = request.prefill_tokens[start:start + self.config.prefill_chunk]
+            if self._grow(request, start + len(chunk)):
+                return request, chunk, start
 
     # ------------------------------------------------------------ decode
 
@@ -327,18 +383,51 @@ class Scheduler:
         return [r for r in self.running.values() if r.decoding]
 
     def ensure_decode_blocks(self, request: ServeRequest) -> bool:
-        """Guarantee the row's next token has a cache slot, evicting under
-        block pressure. False when the request itself got evicted."""
-        while self._blocks_for(request.cache_len + 1) > len(request.blocks):
-            grown = self.allocator.alloc(1)
-            if grown is not None:
-                request.blocks.extend(grown)
+        """Guarantee the row's next token has a cache slot in every group,
+        evicting under block pressure. False when the request itself got
+        evicted."""
+        return self._grow(request, request.cache_len + 1)
+
+    def _grow(self, request: ServeRequest, tokens: int) -> bool:
+        """Pages for the request's first `tokens` positions: of the first
+        group all of them, of the window group those from `window_first` on.
+        Both groups' or neither's; under pressure the eviction victim goes.
+        False when that was the request itself."""
+        pages = self._blocks_for(tokens)
+        while True:
+            short = max(0, pages - len(request.blocks))
+            window_short = 0
+            if self.window_allocator is not None:
+                window_short = max(
+                    0, pages - request.window_first - len(request.window_blocks)
+                )
+            if not short and not window_short:
+                return True
+            taken = self._take(short, window_short)
+            if taken is not None:
+                request.blocks.extend(taken[0])
+                request.window_blocks.extend(taken[1])
                 return True
             victim = self._eviction_victim()
             self.evict(victim)
             if victim is request:
                 return False
-        return True
+
+    def release_window(self, request: ServeRequest) -> int:
+        """Give back the request's pages of the window group that lie wholly
+        in front of `cache_len - sliding_window + 1`: no later token reads
+        them. After every chunk and every decode step; returns how many."""
+        if self.window_allocator is None or request.slot is None:
+            return 0
+        keep_from = max(0, request.cache_len - self.window.sliding_window + 1)
+        gone = keep_from // self.config.block_size - request.window_first
+        if gone <= 0:
+            return 0
+        self.window_allocator.free(request.window_blocks[:gone])
+        del request.window_blocks[:gone]
+        request.window_first += gone
+        self.window_pages_released += gone
+        return gone
 
     def _eviction_victim(self) -> ServeRequest:
         return min(
@@ -383,5 +472,9 @@ class Scheduler:
         del self.running[request.slot]
         self._free_slots.append(request.slot)
         self.allocator.free(request.blocks)
+        if self.window_allocator is not None:
+            self.window_allocator.free(request.window_blocks)
         request.slot = None
         request.blocks = []
+        request.window_blocks = []
+        request.window_first = 0

@@ -256,6 +256,21 @@ def _serving_section(telemetry: dict) -> list[str]:
         if leaked:
             line += f" — {int(leaked)} still held at exit (leak?)"
         lines.append(line)
+    window_total = num("decode/window_blocks_total")
+    if window_total:
+        # the second page group: the layers that keep a sliding window
+        window_peak = num("decode/window_peak_blocks_in_use") or 0
+        line = (
+            f"window page group: {int(window_total)} blocks "
+            f"({(num('decode/window_pool_bytes') or 0) / 2**20:.1f} MiB beside "
+            f"{(num('decode/global_pool_bytes') or 0) / 2**20:.1f} MiB), peak {int(window_peak)} in use"
+            f" ({100.0 * window_peak / window_total:.0f}%), "
+            f"{int(num('serve/window_pages_released') or 0)} pages given back"
+        )
+        leaked = num("decode/window_blocks_in_use")
+        if leaked:
+            line += f" — {int(leaked)} still held at exit (leak?)"
+        lines.append(line)
     in_place = num("decode/experts_in_place_layers")
     if in_place:
         lines.append(f"expert weights: read in place in {int(in_place)} layers")

@@ -338,6 +338,9 @@ def _serve_program(v5e, cell, program):
     slab_rows = {} if slab is None else {"slab": slab}
     if engine._moe_carry is not None:  # a share of the experts counts its assignments
         slab_rows["moe"] = shape((3,))
+    if engine._window_pool is not None:  # the layers that keep a window: a pool and a short table
+        slab_rows["window_pool"] = on(engine._window_pool)
+        slab_rows["window_tables"] = shape((rows if program == "decode" else 1, engine.window_pages))
     if program == "decode":
         lowered = engine._decode_jit.lower(
             variables, shape((rows,)), pool, pool_v, shape((rows, pages)), shape((rows,)), key,
@@ -470,6 +473,62 @@ def test_longcat_serve_cell_compiles_for_v5e(v5e, as_on_tpu, program):
     kernels = parse_hlo_kernels(text)
     assert kernels.get("latent_page_write", 0) >= 1 and kernels.get("gmm", 0) >= 3
     assert "kv_page_write" not in kernels and "paged_decode" not in kernels
+
+
+# --------------------------------------------- the cell with two page groups
+#
+# `trinity-serve-mixedlen`: 4 layers keep every token of 16 requests of 12,800
+# (a pool of 16 x 800 + 1 pages), 12 keep a window of 2,048 (a pool of 16 x 161
+# + 1: window + one chunk of 512, in pages of 16, + 1). Pinned: both programs
+# fit beside 4.23 GB of weights; both pools are written in place; a window
+# layer never reads past its group's budget, whatever `max_model_len` is: no
+# array computed under `attn_window` is 12,800 (or 800 pages) wide, its scores
+# and gathered keys are 2,576 wide, while the global layers' are 12,800; the
+# decode step calls `paged_decode` once a layer (16), the scanned periods' held
+# experts go through `gmm` in place.
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_trinity_serve_cell_keeps_window_layers_inside_their_budget_for_v5e(v5e, as_on_tpu, program):
+    import re
+
+    from llm_training_tpu.telemetry import get_registry
+
+    lowered, pool, slab = _serve_program(v5e, "trinity-serve-mixedlen", program)
+    assert slab is None and pool.shape == (4, 16 * 800 + 1, 4, 16, 128)
+    registry = get_registry()
+    assert round(registry.gauge("decode/global_pool_bytes").value / 1e9, 2) == 1.68
+    assert round(registry.gauge("decode/window_pool_bytes").value / 1e9, 2) == 1.01
+    assert registry.gauge("decode/window_blocks_total").value == 16 * 161
+    window_pool = "bf16[12,2577,4,16,128]"  # [12, 16 x 161 + 1, 4, 16, 128]
+    assert lowered.as_text().count(window_pool.replace("bf16[", "tensor<").replace(",", "x").replace("]", "xbf16>")) >= 2
+    compiled = lowered.compile()  # raises what the chip's compiler would: it fits
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    print(f"trinity-serve-mixedlen {program}: arguments {memory.argument_size_in_bytes / 1e9:.3f} GB, "
+          f"temp {memory.temp_size_in_bytes / 1e9:.3f} GB")
+    assert memory.alias_size_in_bytes >= 2 * (pool.size + 12 * 2577 * 4 * 16 * 128) * 2  # both pools in place
+    assert 6.9e9 < memory.argument_size_in_bytes < 6.95e9
+    # a step's temporaries are its rows' activations; a chunk holds a global layer's
+    # [1, 4, 8, 512, 12800] float32 scores (0.84 GB)
+    assert memory.temp_size_in_bytes < {"decode": 0.15, "prefill": 1.0}[program] * 1e9
+    wide = re.compile(r"\w+\[[\d,]*\b(?:12800|800)\b[\d,]*\]")
+    window_lines = [line for line in text.splitlines() if "attn_window" in line]
+    global_lines = [line for line in text.splitlines() if "attn_global" in line]
+    assert window_lines and global_lines
+    assert not [line for line in window_lines if wide.search(line.split(" metadata=")[0])]
+    assert any(wide.search(line.split(" metadata=")[0]) for line in global_lines)
+    if program == "prefill":
+        assert any("f32[1,4,8,512,2576]" in line for line in window_lines)  # scores: the ring's width
+        assert any("f32[1,4,8,512,12800]" in line for line in global_lines)
+    sites = {
+        name: sum("paged_decode" in line and "tpu_custom_call" in line for line in lines)
+        for name, lines in _run_computations(text).items()
+    }
+    bodies = set(re.findall(r"body=%?([\w.\-]+)", text))
+    calls = sum(n * (3 if name in bodies else 1) for name, n in sites.items())  # three scanned periods
+    assert calls == (16 if program == "decode" else 0), sites
+    kernels = parse_hlo_kernels(text)
+    assert kernels.get("kv_page_write", 0) >= 2 and kernels.get("gmm", 0) >= 3
 
 
 def test_mla_decode_refuses_a_row_that_is_not_whole_lanes(v5e):

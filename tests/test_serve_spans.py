@@ -489,6 +489,101 @@ def test_latent_pool_and_expert_assignments_are_counted_with_the_tokens(fresh):
     assert engine._pool_k is None
 
 
+# ------------------------------------------------ a stack with two page groups
+
+TINY_AFMOE = dict(
+    vocab_size=64, hidden_size=32, intermediate_size=48, moe_intermediate_size=16,
+    num_hidden_layers=8, num_dense_layers=2, num_attention_heads=4, num_key_value_heads=2,
+    head_dim=8, sliding_window=8, num_experts=8, num_experts_per_tok=2, experts_held=4,
+    moe_impl="ragged", attention_impl="xla", compute_dtype="float32", param_dtype="float32",
+)
+
+
+def _afmoe_engine(**serve):
+    from llm_training_tpu.models import Afmoe, AfmoeConfig
+
+    model = Afmoe(AfmoeConfig(**TINY_AFMOE))
+    variables = jax.jit(model.init)(jax.random.key(0), np.zeros((1, 4), np.int32))
+    return ServingEngine(model, variables, ServeConfig(**{**SERVE, **serve}))
+
+
+def _window_args(engine, rows):
+    return {
+        "window_pool": engine._window_pool,
+        "window_tables": jnp.zeros((rows, engine.window_pages), jnp.int32),
+    }
+
+
+def test_window_and_global_layers_name_their_scopes_in_both_programs(fresh):
+    """What the benchmark's readers match: a layer's append and attention
+    under `attn_window` or `attn_global` inside `/self_attn/`, the output gate
+    under `attn_gate`, the MoE phases and `moe_shared` under `/mlp/`, in the
+    looped layers in front and in the scanned periods alike."""
+    engine = _afmoe_engine()
+    decode = engine._decode_jit.lower(*_decode_args(engine), **_window_args(engine, SERVE["max_batch"]))
+    prefill = engine._prefill_jit.lower(*_prefill_args(engine), **_window_args(engine, 1))
+    assert "jit_decode_step" in decode.as_text()[:200]
+    assert "jit_prefill_chunk" in prefill.as_text()[:200]
+    for lowered in (decode, prefill):
+        text = lowered.as_text(debug_info=True)
+        for scope in (
+            "front/slot0/self_attn/attn_window", "front/slot3/self_attn/attn_global",
+            "slot0/self_attn/attn_window", "slot3/self_attn/attn_global", "self_attn/attn_gate",
+            "front/slot0/mlp/", "slot3/mlp/moe_route", "mlp/moe_sort", "mlp/moe_gather",
+            "mlp/moe_experts", "mlp/moe_scatter", "mlp/moe_shared", "/sample",
+        ):
+            assert scope in text, scope
+        # no attention group's op lies outside the block's module scope
+        named = re.findall(r'loc\("([^"]*attn_(?:window|global)[^"]*)"', text)
+        assert named and all("/self_attn/" in name for name in named if not name.startswith("while/body/"))
+
+
+def test_window_group_reports_its_pool_its_pages_and_what_it_reads(fresh):
+    """`decode/global_pool_bytes` and `decode/window_pool_bytes`, the window
+    group's own block gauges, `window_live_tokens` (of each decoding row, its
+    window at most) beside `live_tokens` in `engine_step`'s closing args, and
+    the pages given back: all in `stats()`, the counters and `report`."""
+    from llm_training_tpu.telemetry.report import _serving_section
+
+    engine = _afmoe_engine()
+    # 2 layers keep everything (a row of 6 pages), 6 keep a window of 8: 8 + a chunk of 4, in pages of 8, + 1
+    assert engine._pool_k.shape == (2, 2 * 6 + 1, 2, 8, 8)
+    assert engine.window_pages == 3 and engine._window_pool[0].shape == (6, 2 * 3 + 1, 2, 8, 8)
+    *_, window = jax.eval_shape(
+        engine._decode_jit, *_decode_args(engine), **_window_args(engine, SERVE["max_batch"]))
+    assert [leaf.shape for leaf in window] == [engine._window_pool[0].shape] * 2
+    engine.run(_requests(14))  # rows of 20, 17 and 19 tokens: each gives its first page back
+    registry = get_registry()
+    stats = engine.stats()
+    full, window = 2 * 2 * 13 * 2 * 8 * 8 * 4, 2 * 6 * 7 * 2 * 8 * 8 * 4
+    assert stats["decode/global_pool_bytes"] == stats["decode/cache_bytes"] == full
+    assert stats["decode/window_pool_bytes"] == registry.gauge("decode/window_pool_bytes").value == window
+    assert stats["decode/window_blocks_total"] == registry.gauge("decode/window_blocks_total").value == 6
+    assert stats["decode/window_blocks_in_use"] == registry.gauge("decode/window_blocks_in_use").value == 0
+    assert 0 < stats["decode/window_peak_blocks_in_use"] <= 6
+    assert registry.gauge("decode/window_peak_blocks_in_use").value == stats["decode/window_peak_blocks_in_use"]
+    assert stats["decode/cache_blocks_in_use"] == 0
+    steps = [e["args"] for e in fresh.snapshot() if e.get("ph") == "X" and e["name"] == "engine_step"]
+    decode = [a for a in steps if a["decode_rows"]]
+    assert decode and all(0 < a["window_live_tokens"] <= a["live_tokens"] for a in decode)
+    assert all(a["window_live_tokens"] <= 8 * a["decode_rows"] for a in decode)
+    assert any(a["window_live_tokens"] < a["live_tokens"] for a in decode)  # a row past its window
+    for name in ("window_live_tokens", "window_pages_released"):
+        total = sum(a[name] for a in steps)
+        assert total > 0 and registry.counter(f"serve/{name}").value == stats[f"serve/{name}"] == total
+    said = "\n".join(_serving_section(stats))
+    assert "window page group: 6 blocks" in said and f"{int(stats['serve/window_pages_released'])} pages given back" in said
+    # a stack with one group reports none of it
+    plain = _engine()
+    plain.run(_requests(2))
+    assert plain.stats()["decode/window_pool_bytes"] == 0 == registry.gauge("decode/window_pool_bytes").value
+    assert plain.stats()["decode/global_pool_bytes"] == plain.stats()["decode/cache_bytes"]
+    assert "serve/window_live_tokens" not in plain.stats() and plain.window_allocator is None
+    assert "window page group" not in "\n".join(_serving_section(plain.stats()))
+    engine.close()
+    assert engine._pool_k is None and engine._window_pool is None
+
+
 @pytest.mark.parametrize("config,layers", [
     pytest.param(dict(TINY_MOE, num_hidden_layers=3), 3, id="engaged"),
     pytest.param(dict(TINY_MOE, num_hidden_layers=3, moe_impl="dense"), 0, id="dense-impl"),
