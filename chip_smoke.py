@@ -59,6 +59,7 @@ PLATFORM = "tpu"
 CONFIG_OVERRIDES: list[str] = []
 FLASH_SHAPE = dict(batch=1, seq=2048, q_heads=32, kv_heads=8, head_dim=128)
 PAGED_SHAPE = dict(batch=4, q_heads=32, kv_heads=8, head_dim=128, max_len=512)
+PAGED_CHUNK = 128  # queries a row of the chunk the prefill kernel is checked on
 SERVE_PROMPT_LENS = (200, 320, 480, 700, 1024, 1400, 1800, 2000)
 SERVE_NEW_TOKENS = 32
 SERVE_FLAGS = [
@@ -230,7 +231,8 @@ def kernels_in(compiled) -> dict:
 def phase_kernels(chips: int) -> dict:
     """The compiled Pallas kernels against their XLA references, at the
     serving/training widths: flash forward + gradients with packed segment
-    ids, and the paged-decode kernel on a ragged batch."""
+    ids, and the paged kernels (a decoded token, a chunk of queries) on a
+    ragged batch."""
     phase = "kernels"
     log = CompileLog()
     device = check_device(phase, chips)
@@ -307,44 +309,48 @@ def phase_kernels(chips: int) -> dict:
         pool_shape = (n_blocks, shape["kv_heads"], page, d)
         pool_k = jax.random.normal(keys[0], pool_shape, dtype)
         pool_v = jax.random.normal(keys[1], pool_shape, dtype)
-        q = jax.random.normal(keys[2], (batch, 1, shape["q_heads"], d), jnp.bfloat16)
-        k = jax.random.normal(keys[3], (batch, 1, shape["kv_heads"], d), jnp.bfloat16)
-        v = jax.random.normal(keys[4], (batch, 1, shape["kv_heads"], d), jnp.bfloat16)
         tables = jnp.asarray(
             1 + np.random.default_rng(page).permutation(batch * pages)
             .reshape(batch, pages).astype(np.int32)
         )
+        # a decoded token a row (`paged_decode`), then a chunk of queries a
+        # row (`paged_prefill`), started where the chunk still fits the table
+        for seq, kernel in ((1, "paged_decode"), (PAGED_CHUNK, "paged_prefill")):
+            starts = jnp.asarray(np.minimum(lengths, shape["max_len"] - seq))
+            q = jax.random.normal(keys[2], (batch, seq, shape["q_heads"], d), jnp.bfloat16)
+            k = jax.random.normal(keys[3], (batch, seq, shape["kv_heads"], d), jnp.bfloat16)
+            v = jax.random.normal(keys[4], (batch, seq, shape["kv_heads"], d), jnp.bfloat16)
 
-        def attend(impl):
-            return jax.jit(
-                lambda q, k, v, pk, pv: paged_cached_attention(
-                    q, k, v, (pk, pv), jnp.asarray(lengths), tables, impl=impl
-                )[0]
-            )
+            def attend(impl):
+                return jax.jit(
+                    lambda q, k, v, pk, pv: paged_cached_attention(
+                        q, k, v, (pk, pv), starts, tables, impl=impl
+                    )[0]
+                )
 
-        if page == 16 and dtype == jnp.bfloat16:
-            found = kernels_in(attend("auto").lower(q, k, v, pool_k, pool_v).compile())
-            say(
-                phase,
-                f"paged: impl='auto' single-token decode compiles to "
-                f"[{describe(device)}]: {found}",
-            )
+            if page == 16 and dtype == jnp.bfloat16:
+                found = kernels_in(attend("auto").lower(q, k, v, pool_k, pool_v).compile())
+                say(
+                    phase,
+                    f"paged: impl='auto' with {seq} quer{'y' if seq == 1 else 'ies'} a row "
+                    f"compiles to [{describe(device)}]: {found}",
+                )
+                check(
+                    PLATFORM != "tpu" or kernel in found,
+                    f"paged: impl='auto' did not put {kernel} in the program: {found}",
+                )
+            got = attend("pallas")(q, k, v, pool_k, pool_v)
+            ref = attend("xla")(q, k, v, pool_k, pool_v)
+            tag = f"{kernel}/page{page}/{jnp.dtype(dtype).name}"
+            paged_errors[tag] = relative_error(got, ref)
             check(
-                PLATFORM != "tpu" or "paged_decode" in found,
-                f"paged: impl='auto' did not put the kernel in the program: {found}",
+                math.isfinite(paged_errors[tag]) and paged_errors[tag] <= KERNEL_TOL,
+                f"paged: {tag} differs from the gather reference by "
+                f"{paged_errors[tag]:.3e} (> {KERNEL_TOL})",
             )
-        got = attend("pallas")(q, k, v, pool_k, pool_v)
-        ref = attend("xla")(q, k, v, pool_k, pool_v)
-        tag = f"page{page}/{jnp.dtype(dtype).name}"
-        paged_errors[tag] = relative_error(got, ref)
-        check(
-            math.isfinite(paged_errors[tag]) and paged_errors[tag] <= KERNEL_TOL,
-            f"paged: {tag} differs from the gather reference by "
-            f"{paged_errors[tag]:.3e} (> {KERNEL_TOL})",
-        )
     say(
         phase,
-        f"paged-decode kernel vs gather at ({shape['q_heads']}/{shape['kv_heads']} "
+        f"paged kernels vs gather at ({shape['q_heads']}/{shape['kv_heads']} "
         f"heads, d{d}), ragged lengths {lengths.tolist()}, max error / max |ref| "
         f"[{describe(device)}]: "
         + ", ".join(f"{n} {e:.2e}" for n, e in paged_errors.items()),

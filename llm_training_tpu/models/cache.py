@@ -142,9 +142,9 @@ class LayerCache:
         decode steps. Always the XLA einsum path: the flash kernel's block
         tiling assumes q_len >= a block and a static q_offset.
 
-        Paged: page writer and ragged Pallas decode kernel on a TPU, XLA
-        elsewhere; padded chunk positions (segment id 0) go to the trash
-        block. The window group's table is a ring as wide as its page
+        Paged: page writer and the ragged Pallas kernels on a TPU (one for
+        a decoded token, one for a chunk), XLA elsewhere; padded chunk
+        positions (segment id 0) go to the trash block. The window group's table is a ring as wide as its page
         budget, so neither the append nor the read reaches past it."""
         windowed = window is not None and self.window_k is not None
         names = ("window_k", "window_v") if windowed else ("k", "v")

@@ -27,7 +27,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from llm_training_tpu.ops.paged_attention import _over_heads, latent_append
+from llm_training_tpu.ops.paged_attention import _on_kernels, _over_heads, latent_append
 
 _MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 # cached tokens a trip of the paged XLA path brings: a chunk of 512 queries
@@ -148,8 +148,7 @@ def paged_latent_attention(
     )
     if absorbed is None:
         absorbed = seq == 1
-    on_chip = impl == "pallas" or (impl == "auto" and jax.default_backend() == "tpu")
-    if seq == 1 and absorbed and on_chip:
+    if seq == 1 and absorbed and _on_kernels(impl):
         from llm_training_tpu.ops.pallas.mla_decode import mla_decode_attention
 
         q = absorb_queries(q_nope, q_rope, w_kvb[..., :nope], width)

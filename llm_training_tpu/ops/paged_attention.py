@@ -33,15 +33,22 @@ the window's own mask hides every position that page no longer holds.
 
 Two attention paths behind one call:
 
-- single-token decode on TPU (or `impl='pallas'`): the Pallas ragged
-  paged-decode kernel (`ops/pallas/paged_attention.py`) — per-row lengths,
-  block-table gathers in the DMA engine. On a TPU the kernel always
-  compiles; a head_dim/page Mosaic cannot tile raises there;
-- everything else (chunked prefill q_len > 1, CPU tier-1): an XLA gather
-  path — block-table gather to a dense `[B, P*page, H, D]` view plus a
-  per-row position mask into the reference einsum attention. Same math,
-  shape-static, differentiable-free (decode only), and the oracle the
-  kernel is tested against.
+- on a TPU (or `impl='pallas'`): the Pallas kernels of
+  `ops/pallas/paged_attention.py`, which leave the pools in HBM and fetch a
+  row's pages through its block table in the DMA engine, several a trip,
+  double buffered. Single-token decode runs `paged_decode` (one grid step a
+  row); a chunk (q_len > 1, chunked prefill) runs `paged_prefill` (one grid
+  step a block of queries, the GQA group folded into the tile's rows, the
+  softmax state in VMEM across the trips): a chunk walks only the pages its
+  row holds, from its window's first to its last query's, and produces
+  nothing as wide as the table. On a TPU the kernels always compile; a
+  head_dim/page Mosaic cannot tile raises there;
+- everything else (the CPU, `impl='xla'`): an XLA gather path — block-table
+  gather to a dense `[B, P*page, H, D]` view plus a per-row position mask
+  into the reference einsum attention, float32 scores `[B, heads, S,
+  P*page]` whatever the row's length. Same math, shape-static,
+  differentiable-free (decode only), and the oracle both kernels are tested
+  against.
 """
 
 from __future__ import annotations
@@ -50,6 +57,31 @@ import jax
 import jax.numpy as jnp
 
 from llm_training_tpu.ops.attention import _xla_attention
+from llm_training_tpu.telemetry.registry import get_registry
+
+
+# how many key/value layers' chunk attention the serving programs traced last
+# run in the `paged_prefill` kernel; `serve/engine.py` zeroes it before it
+# builds its programs
+CHUNK_KERNEL_GAUGE = "decode/chunk_attention_kernel_layers"
+_chunk_kernel_layers: dict[bool, int] = {}
+
+
+def reset_chunk_kernel_layers() -> None:
+    _chunk_kernel_layers.clear()
+    get_registry().gauge(CHUNK_KERNEL_GAUGE).set(0)
+
+
+def _count_chunk_kernel_layers(layers: int, ring: bool) -> None:
+    """Said when a chunk's attention is traced into the kernel: the call
+    stands for every layer of the stack it addresses, and a stack with two
+    page groups (one behind a ring table) makes such calls for each."""
+    _chunk_kernel_layers[ring] = layers
+    get_registry().gauge(CHUNK_KERNEL_GAUGE).set(sum(_chunk_kernel_layers.values()))
+
+
+def _on_kernels(impl: str) -> bool:
+    return impl == "pallas" or (impl == "auto" and jax.default_backend() == "tpu")
 
 
 def _chunk_pages(lengths, block_tables, segment_ids, batch, seq, page_size, ring=False):
@@ -155,7 +187,7 @@ def _write_pages(pools, pages, blocks, live, impl):
     writers, whose pools are aliased to their outputs and never leave HBM;
     elsewhere one XLA scatter over whole blocks, rows that are not live
     dropped."""
-    if impl == "pallas" or (impl == "auto" and jax.default_backend() == "tpu"):
+    if _on_kernels(impl):
         if len(pools) == 1:
             from llm_training_tpu.ops.pallas.mla_decode import write_latent_pages
 
@@ -179,11 +211,11 @@ def _gather_attention(
     q, pool_k, pool_v, lengths, block_tables, segment_ids,
     sliding_window, logits_soft_cap, scale, ring=False,
 ):
-    """XLA fallback: dense gather of each row's pages + per-row causal
-    mask. `lengths` here is the PRE-append count, so q position i of row b
-    sits at absolute slot lengths[b] + i. The gather is as wide as the table:
-    a ring's scores are `[B, heads, S, budget * page]`, whatever the row's
-    length."""
+    """The XLA path, and the kernels' oracle: dense gather of each row's
+    pages + per-row causal mask. `lengths` here is the PRE-append count, so
+    q position i of row b sits at absolute slot lengths[b] + i. The gather is
+    as wide as the table: a ring's scores are `[B, heads, S, budget * page]`,
+    whatever the row's length."""
     batch, seq = q.shape[:2]
     _, kv_heads, page_size, head_dim = pool_k.shape
     num_pages = block_tables.shape[1]
@@ -288,9 +320,9 @@ def paged_cached_attention(
     `ring`: the table is a window group's, as wide as its page budget, and
     logical page `p` is in slot `p % width` (the module docstring).
 
-    impl: 'auto' (Pallas kernels on TPU — the page writer, and the decode
-    kernel for single-token decode — XLA elsewhere) | 'pallas' (kernels
-    forced, interpreted off-TPU) | 'xla'.
+    impl: 'auto' (Pallas kernels on TPU — the page writer, the decode kernel
+    for single-token decode, the prefill kernel for a chunk — XLA elsewhere)
+    | 'pallas' (kernels forced, interpreted off-TPU) | 'xla'.
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
@@ -307,10 +339,12 @@ def paged_cached_attention(
     )
 
     seq = q.shape[1]
-    use_kernel = seq == 1 and (
-        impl == "pallas" or (impl == "auto" and jax.default_backend() == "tpu")
-    )
-    if use_kernel:
+    if not _on_kernels(impl):
+        out = _gather_attention(
+            q, ck, cv, lengths, block_tables, segment_ids,
+            sliding_window, logits_soft_cap, scale, ring,
+        )
+    elif seq == 1:
         from llm_training_tpu.ops.pallas.paged_attention import paged_decode_attention
 
         out = _over_heads(
@@ -322,8 +356,18 @@ def paged_cached_attention(
             (q[:, 0], ck, cv, block_tables, lengths + 1), (1, 1, 1, None, None), 0,
         )[:, None]
     else:
-        out = _gather_attention(
-            q, ck, cv, lengths, block_tables, segment_ids,
-            sliding_window, logits_soft_cap, scale, ring,
+        from llm_training_tpu.ops.pallas.paged_attention import paged_prefill_attention
+
+        out = _over_heads(
+            lambda q, pk, pv, tables, lens: paged_prefill_attention(
+                q, pk, pv, tables, lens, scale=scale,
+                sliding_window=sliding_window, logits_soft_cap=logits_soft_cap,
+                ring=ring,
+            ),
+            (q, ck, cv, block_tables, lengths), (2, 1, 1, None, None), 0,
         )
+        if segment_ids is not None:
+            # a padded query emits exactly 0, as on the gather path
+            out = jnp.where((segment_ids > 0)[:, :, None, None], out, 0)
+        _count_chunk_kernel_layers(1 if layer is None else stack_shape[0], ring)
     return out, (ck.reshape(stack_shape), cv.reshape(stack_shape))

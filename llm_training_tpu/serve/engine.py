@@ -87,6 +87,7 @@ from llm_training_tpu.infer.sampling import (
 )
 from llm_training_tpu.models.base import PagedDecodeState
 from llm_training_tpu.models.moe import IN_PLACE_GAUGE
+from llm_training_tpu.ops.paged_attention import CHUNK_KERNEL_GAUGE, reset_chunk_kernel_layers
 from llm_training_tpu.resilience.chaos import get_chaos
 from llm_training_tpu.serve.paged_cache import (
     BlockAllocator,
@@ -352,6 +353,9 @@ class ServingEngine:
         # a sparse MLP that reads its stacked experts in place says in how
         # many layers, when a program below is traced (models/moe.py)
         get_registry().gauge(IN_PLACE_GAUGE).set(0)
+        # and so does a chunk's attention that runs in the kernel
+        # (ops/paged_attention.py)
+        reset_chunk_kernel_layers()
         sampling = self.config.sampling
         rope_length = self.config.max_model_len
 
@@ -687,7 +691,8 @@ class ServingEngine:
         # one bookkeeping, two readers: the engine_step span's closing args
         # and the cumulative serve/* counters (docs/observability.md#tracing)
         counts = self._step_counts = {
-            "prefill_chunks": 0, "prefill_tokens": 0,
+            # `prefill_start`: the tokens the chunk's row held before the chunk
+            "prefill_chunks": 0, "prefill_tokens": 0, "prefill_start": 0,
             "decode_rows": 0, "live_tokens": 0,
         }
         if self._slab is not None:
@@ -828,6 +833,7 @@ class ServingEngine:
         final = start + len(chunk) >= len(request.prefill_tokens)
         self._step_counts["prefill_chunks"] = 1
         self._step_counts["prefill_tokens"] = len(chunk)
+        self._step_counts["prefill_start"] = start
         # a residency's first chunk (admission, or the requeue after an
         # eviction: both restart at 0) starts from a zero state, whatever the
         # slot's last tenant left
@@ -1167,6 +1173,7 @@ class ServingEngine:
                 self._cache_bytes if self._latent_pool else 0
             ),
             IN_PLACE_GAUGE: get_registry().gauge(IN_PLACE_GAUGE).value or 0.0,
+            CHUNK_KERNEL_GAUGE: get_registry().gauge(CHUNK_KERNEL_GAUGE).value or 0.0,
             "decode/cache_blocks_total": float(self.allocator.num_blocks - 1),
             "decode/cache_blocks_in_use": float(self.allocator.blocks_in_use),
             "decode/cache_peak_blocks_in_use": float(self.allocator.peak_in_use),

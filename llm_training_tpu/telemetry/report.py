@@ -274,6 +274,9 @@ def _serving_section(telemetry: dict) -> list[str]:
     in_place = num("decode/experts_in_place_layers")
     if in_place:
         lines.append(f"expert weights: read in place in {int(in_place)} layers")
+    in_kernel = num("decode/chunk_attention_kernel_layers")
+    if in_kernel:
+        lines.append(f"chunk attention: in the paged_prefill kernel in {int(in_kernel)} layers")
     latent = num("decode/latent_pool_bytes")
     if latent:
         lines.append(f"latent (MLA) pool: {latent / 2**20:.1f} MiB, one row a token a block")

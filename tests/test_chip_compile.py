@@ -26,7 +26,10 @@ from jax.sharding import PartitionSpec as P
 
 from llm_training_tpu.ops.pallas import resolve_interpret
 from llm_training_tpu.ops.pallas.flash_attention import flash_bwd_flat, flash_fwd_flat
-from llm_training_tpu.ops.pallas.paged_attention import paged_decode_attention
+from llm_training_tpu.ops.pallas.paged_attention import (
+    paged_decode_attention,
+    paged_prefill_attention,
+)
 from llm_training_tpu.telemetry.device import parse_hlo_kernels
 
 # Llama-3.1-8B attention at the chip smoke's shape: 32 query / 8 kv heads of
@@ -213,6 +216,88 @@ def test_paged_decode_refuses_untileable_shapes_when_compiled():
                                interpret=False)
 
 
+@pytest.mark.parametrize("q_heads,kv_heads,blocks,pages,window,ring", [
+    # the serve cells of BENCHMARK.json as the chunk kernel sees them: one row,
+    # 512 queries, kv heads x group x table width
+    pytest.param(32, 4, 4 * 12801, 800, None, False, id="trinity-global-4x8x12800"),
+    pytest.param(32, 4, 12 * 2577, 161, 2048, True, id="trinity-window-ring-2576"),
+    pytest.param(40, 10, 13 * 3073, 96, 2047, False, id="phi3-10x4x1536"),
+    pytest.param(16, 16, 9 * 3073, 96, None, False, id="olmoe-16x1x1536"),
+    pytest.param(64, 8, 3073, 192, None, False, id="solar-8x8x3072"),
+])
+def test_paged_prefill_compiles_for_v5e(v5e, q_heads, kv_heads, blocks, pages, window, ring):
+    """The chunk attention kernel at head_dim 128, pages of 16, bf16: the
+    pools go to it in place (a layer stack seen as one pool), and nothing as
+    wide as the table comes out of the program, in float32 or gathered."""
+    import re
+
+    one = SingleDeviceSharding(v5e.devices[0])
+    pool = jax.ShapeDtypeStruct((blocks, kv_heads, 16, HEAD_DIM), jnp.bfloat16, sharding=one)
+    compiled = jax.jit(
+        lambda q, k, v, tables, lens: paged_prefill_attention(
+            q, k, v, tables, lens, sliding_window=window, ring=ring, interpret=False
+        )
+    ).lower(
+        jax.ShapeDtypeStruct((1, 512, q_heads, HEAD_DIM), jnp.bfloat16, sharding=one),
+        pool, pool,
+        jax.ShapeDtypeStruct((1, pages), jnp.int32, sharding=one),
+        jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one),
+    ).compile()
+    text = compiled.as_text()
+    assert parse_hlo_kernels(text) == {"paged_prefill": 1}
+    assert compiled.memory_analysis().temp_size_in_bytes < 20e6  # q and the output, regrouped
+    produced = [
+        line for line in text.splitlines()
+        if " parameter(" not in line and (
+            f"= bf16[{blocks},{kv_heads},16,{HEAD_DIM}]" in line
+            or re.search(rf"= \w+\[[\d,]*\b{pages * 16}\b[\d,]*\]", line)
+        )
+    ]
+    assert not produced, produced
+
+
+def test_paged_prefill_compiles_on_a_sharded_mesh_for_v5e(v5e, as_on_tpu):
+    """A chunk through `paged_cached_attention` with kv heads sharded over
+    `tensor`: the page writer and the chunk kernel, each in its shard_map
+    (`_over_heads`), as the decode kernel above."""
+    import numpy as np
+
+    from llm_training_tpu.ops.paged_attention import paged_cached_attention
+    from llm_training_tpu.parallel.mesh import MESH_AXIS_NAMES
+
+    mesh = Mesh(np.asarray(v5e.devices).reshape(1, 1, 2, 1, 2, 1), MESH_AXIS_NAMES)
+
+    def shape(dims, dtype, spec):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=NamedSharding(mesh, spec))
+
+    batch, seq, blocks, page, pages = 2, 512, 512, 16, 128
+    heads = P(None, None, "tensor", None)
+    pool = shape((blocks, KV_HEADS, page, HEAD_DIM), jnp.bfloat16, P(None, "tensor", None, None))
+    with mesh:
+        found = _kernels(
+            lambda q, k, v, pk, pv, lens, tables, seg: paged_cached_attention(
+                q, k, v, (pk, pv), lens, tables, segment_ids=seg, impl="auto"
+            )[0],
+            shape((batch, seq, Q_HEADS, HEAD_DIM), jnp.bfloat16, heads),
+            shape((batch, seq, KV_HEADS, HEAD_DIM), jnp.bfloat16, heads),
+            shape((batch, seq, KV_HEADS, HEAD_DIM), jnp.bfloat16, heads),
+            pool, pool,
+            shape((batch,), jnp.int32, P()), shape((batch, pages), jnp.int32, P()),
+            shape((batch, seq), jnp.int32, P()),
+        )
+    assert found == {"kv_page_write": 1, "paged_prefill": 1}, found
+
+
+def test_paged_prefill_refuses_untileable_shapes_when_compiled():
+    """As the decode kernel: a head_dim or page Mosaic cannot tile raises on
+    the chip's path, and is not routed to the gather path."""
+    q = jnp.zeros((2, 8, 4, 16))
+    pool = jnp.zeros((5, 2, 8, 16))
+    tables = jnp.zeros((2, 2), jnp.int32)
+    with pytest.raises(ValueError, match="paged-prefill kernel .* head_dim 16"):
+        paged_prefill_attention(q, pool, pool, tables, jnp.ones((2,), jnp.int32), interpret=False)
+
+
 def test_interpret_is_impossible_on_a_tpu_backend(monkeypatch):
     assert resolve_interpret(None) is True  # the CPU test path
     assert resolve_interpret(False) is False  # compiling for a described device
@@ -258,14 +343,14 @@ _PRODUCED = {
 }
 # the programs' temporaries, GB, which hold that Solar's slab updates ARE in
 # place (one more copy of a layer's states is 0.13 GB, of a pool as much).
-# Phi-3's chunk holds its 0.126 GB of attention scores, OLMoE a chunk's
-# expert activations (0.013 GB; one layer's expert weights cut out of the
-# stack, 0.27 GB, until PR 31), Solar's step one layer's new state (0.134 GB)
-# and its chunk the scores (0.40 GB)
+# OLMoE's chunk holds its expert activations (0.013 GB; one layer's expert
+# weights cut out of the stack, 0.27 GB, until PR 31), Solar's step and chunk
+# one layer's new state (0.134 GB). No chunk holds attention scores since
+# PR 35 (`paged_prefill`; before it Phi-3's held 0.126 GB, Solar's 0.40)
 _TEMP_GB = {
-    "phi3m-serve-rollout": {"decode": 0.01, "prefill": 0.15},
+    "phi3m-serve-rollout": {"decode": 0.01, "prefill": 0.02},
     "olmoe-serve-rollout": {"decode": 0.01, "prefill": 0.03},
-    "solar2-serve-longdoc": {"decode": 0.2, "prefill": 0.45},
+    "solar2-serve-longdoc": {"decode": 0.2, "prefill": 0.2},
 }
 _NOT_PRODUCED = (
     "parameter", "bitcast", "get-tuple-element", "tuple", "while", "conditional", "call",
@@ -308,6 +393,20 @@ def _produced(text, pattern):
                 continue
             total += len(re.findall(pattern, rest[: kind.start(1)]))
     return total
+
+
+def _kernel_calls(text, kernel, repeats, under=""):
+    """How often a compiled program calls a Mosaic kernel (under a named
+    scope, where given): a call site in a loop's body runs `repeats` times, any
+    other once."""
+    import re
+
+    sites = {
+        name: sum(kernel in line and "tpu_custom_call" in line and under in line for line in lines)
+        for name, lines in _run_computations(text).items()
+    }
+    bodies = set(re.findall(r"body=%?([\w.\-]+)", text))
+    return sum(n * (repeats if name in bodies else 1) for name, n in sites.items())
 
 
 def _serve_program(v5e, cell, program):
@@ -358,7 +457,6 @@ def _serve_program(v5e, cell, program):
 
 
 def _check_serve_program(v5e, cell, program):
-    import re
     from pathlib import Path
 
     from benchmarks import common
@@ -394,13 +492,9 @@ def _check_serve_program(v5e, cell, program):
 
     # one `paged_decode` call a pool layer: a call site in the layer loop's
     # body runs once a layer; an unrolled loop has a call site a layer
-    sites = {
-        name: sum("paged_decode" in line and "tpu_custom_call" in line for line in lines)
-        for name, lines in _run_computations(text).items()
-    }
-    bodies = set(re.findall(r"body=%?([\w.\-]+)", text))
-    calls = sum(n * (layers if name in bodies else 1) for name, n in sites.items())
-    assert calls == (layers if program == "decode" else 0), sites
+    assert _kernel_calls(text, "paged_decode", layers) == (layers if program == "decode" else 0)
+    # and a chunk attends in `paged_prefill`, once a pool layer
+    assert _kernel_calls(text, "paged_prefill", layers) == (0 if program == "decode" else layers)
     assert parse_hlo_kernels(text).get("kv_page_write", 0) >= 1  # the append's writer
 
     counts = {k: _produced(text, p) for k, p in patterns.items() if p}
@@ -433,7 +527,6 @@ def test_rollout_cells_update_the_pool_in_place_for_v5e(v5e, as_on_tpu, cell, pr
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
 def test_longcat_serve_cell_compiles_for_v5e(v5e, as_on_tpu, program):
-    import re
     from pathlib import Path
 
     from benchmarks import common
@@ -463,16 +556,11 @@ def test_longcat_serve_cell_compiles_for_v5e(v5e, as_on_tpu, program):
     # [64, 512, 512] float32 scores and the expanded keys and values
     assert memory.temp_size_in_bytes < {"decode": 0.02, "prefill": 0.2}[program] * 1e9
 
-    sites = {
-        name: sum("mla_decode" in line and "tpu_custom_call" in line for line in lines)
-        for name, lines in _run_computations(text).items()
-    }
-    bodies = set(re.findall(r"body=%?([\w.\-]+)", text))
-    calls = sum(n * (layers // 2 if name in bodies else 1) for name, n in sites.items())
-    assert calls == (layers if program == "decode" else 0), sites
+    # two call sites in the layer loop's body: 8 calls a step
+    assert _kernel_calls(text, "mla_decode", layers // 2) == (layers if program == "decode" else 0)
     kernels = parse_hlo_kernels(text)
     assert kernels.get("latent_page_write", 0) >= 1 and kernels.get("gmm", 0) >= 3
-    assert "kv_page_write" not in kernels and "paged_decode" not in kernels
+    assert not {"kv_page_write", "paged_decode", "paged_prefill"} & set(kernels)
 
 
 # --------------------------------------------- the cell with two page groups
@@ -482,10 +570,12 @@ def test_longcat_serve_cell_compiles_for_v5e(v5e, as_on_tpu, program):
 # + 1: window + one chunk of 512, in pages of 16, + 1). Pinned: both programs
 # fit beside 4.23 GB of weights; both pools are written in place; a window
 # layer never reads past its group's budget, whatever `max_model_len` is: no
-# array computed under `attn_window` is 12,800 (or 800 pages) wide, its scores
-# and gathered keys are 2,576 wide, while the global layers' are 12,800; the
-# decode step calls `paged_decode` once a layer (16), the scanned periods' held
-# experts go through `gmm` in place.
+# array computed under `attn_window` is 12,800 (or 800 pages) wide; the decode
+# step calls `paged_decode` once a layer (16: 12 under `attn_window`, 4 under
+# `attn_global`) and a chunk `paged_prefill` as often, so the chunk program
+# holds no float32 scores 2,576 or 12,800 wide (until PR 35 it gathered each
+# table whole: 0.89 GB of temporaries); the scanned periods' held experts go
+# through `gmm` in place.
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
@@ -508,25 +598,26 @@ def test_trinity_serve_cell_keeps_window_layers_inside_their_budget_for_v5e(v5e,
           f"temp {memory.temp_size_in_bytes / 1e9:.3f} GB")
     assert memory.alias_size_in_bytes >= 2 * (pool.size + 12 * 2577 * 4 * 16 * 128) * 2  # both pools in place
     assert 6.9e9 < memory.argument_size_in_bytes < 6.95e9
-    # a step's temporaries are its rows' activations; a chunk holds a global layer's
-    # [1, 4, 8, 512, 12800] float32 scores (0.84 GB)
-    assert memory.temp_size_in_bytes < {"decode": 0.15, "prefill": 1.0}[program] * 1e9
+    # a step's temporaries are its rows' activations, and so are a chunk's since
+    # its attention runs in `paged_prefill` (0.89 GB until PR 35: a global layer's
+    # [1, 4, 8, 512, 12800] float32 scores)
+    assert memory.temp_size_in_bytes < {"decode": 0.15, "prefill": 0.2}[program] * 1e9
     wide = re.compile(r"\w+\[[\d,]*\b(?:12800|800)\b[\d,]*\]")
     window_lines = [line for line in text.splitlines() if "attn_window" in line]
     global_lines = [line for line in text.splitlines() if "attn_global" in line]
     assert window_lines and global_lines
     assert not [line for line in window_lines if wide.search(line.split(" metadata=")[0])]
-    assert any(wide.search(line.split(" metadata=")[0]) for line in global_lines)
+    assert any(wide.search(line.split(" metadata=")[0]) for line in global_lines)  # its table
     if program == "prefill":
-        assert any("f32[1,4,8,512,2576]" in line for line in window_lines)  # scores: the ring's width
-        assert any("f32[1,4,8,512,12800]" in line for line in global_lines)
-    sites = {
-        name: sum("paged_decode" in line and "tpu_custom_call" in line for line in lines)
-        for name, lines in _run_computations(text).items()
-    }
-    bodies = set(re.findall(r"body=%?([\w.\-]+)", text))
-    calls = sum(n * (3 if name in bodies else 1) for name, n in sites.items())  # three scanned periods
-    assert calls == (16 if program == "decode" else 0), sites
+        # no scores as wide as a table, the ring's or the global group's, anywhere
+        scores = re.compile(r"f32\[[\d,]*\b(?:12800|2576)\b[\d,]*\]")
+        assert not [line for line in text.splitlines() if scores.search(line.split(" metadata=")[0])]
+    # one attention kernel a layer, three scanned periods behind the looped one:
+    # a decode step's `paged_decode`, a chunk's `paged_prefill`
+    mine, other = ("paged_decode", "paged_prefill") if program == "decode" else ("paged_prefill", "paged_decode")
+    assert _kernel_calls(text, mine, 3) == 16 and _kernel_calls(text, other, 3) == 0
+    assert _kernel_calls(text, mine, 3, under="attn_window") == 12
+    assert _kernel_calls(text, mine, 3, under="attn_global") == 4
     kernels = parse_hlo_kernels(text)
     assert kernels.get("kv_page_write", 0) >= 2 and kernels.get("gmm", 0) >= 3
 

@@ -608,6 +608,55 @@ def test_gauge_counts_the_layers_whose_experts_are_read_in_place(fresh, config, 
     assert said == bool(layers)
 
 
+def test_engine_step_says_where_its_chunk_starts(fresh):
+    """`prefill_start` beside `prefill_tokens` in `engine_step`'s closing
+    args: the tokens the chunk's row held before the chunk, so a reader can
+    count the (query, key) pairs the chunk's attention may see."""
+    engine = _engine()
+    engine.run(_requests())
+    ring = [e for e in fresh.snapshot() if e.get("ph") == "X"]
+    steps = {e["args"]["step"]: e["args"] for e in ring if e["name"] == "engine_step"}
+    chunks = {e["args"]["step"]: e["args"] for e in ring if e["name"] == "prefill_chunk"}
+    assert chunks and all("prefill_start" in args for args in steps.values())
+    for step, args in steps.items():
+        chunk = chunks.get(step, {"start": 0, "tokens": 0})
+        assert (args["prefill_start"], args["prefill_tokens"]) == (chunk["start"], chunk["tokens"])
+    # a prompt of 6 in chunks of 4: its second chunk starts at 4
+    assert sorted({args["prefill_start"] for args in steps.values()}) == [0, 4]
+
+
+@pytest.mark.parametrize("build,impl,layers", [
+    pytest.param("llama", None, 0, id="xla-off-the-chip"),
+    pytest.param("llama", "pallas", 2, id="kernel"),
+    pytest.param("afmoe", "pallas", 8, id="kernel-two-page-groups"),
+])
+def test_gauge_counts_the_layers_whose_chunk_attention_runs_in_the_kernel(
+    fresh, monkeypatch, build, impl, layers
+):
+    """`decode/chunk_attention_kernel_layers`: set when the prefill program is
+    traced, every key/value layer of the stack (both page groups' where it has
+    two) when a chunk attends in `paged_prefill`, 0 on the CPU's gather path;
+    `stats()` holds it and `report` says it."""
+    from llm_training_tpu.ops import paged_attention
+    from llm_training_tpu.telemetry.report import _serving_section
+
+    if impl is not None:
+        attend = paged_attention.paged_cached_attention
+        monkeypatch.setattr(
+            paged_attention, "paged_cached_attention",
+            lambda *args, **kwargs: attend(*args, **{**kwargs, "impl": impl}),
+        )
+    get_registry().gauge("decode/chunk_attention_kernel_layers").set(5)  # another engine's
+    engine = {"llama": _engine, "afmoe": _afmoe_engine}[build]()
+    assert get_registry().gauge("decode/chunk_attention_kernel_layers").value == 0  # nothing traced yet
+    engine.run(_requests(2))
+    assert get_registry().gauge("decode/chunk_attention_kernel_layers").value == layers
+    stats = engine.stats()
+    assert stats["decode/chunk_attention_kernel_layers"] == layers
+    said = f"chunk attention: in the paged_prefill kernel in {layers} layers" in _serving_section(stats)
+    assert said == bool(layers)
+
+
 def test_lowered_train_loss_carries_loss_ce():
     from llm_training_tpu.ops.cross_entropy import fused_linear_cross_entropy
 
