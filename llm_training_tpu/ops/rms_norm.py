@@ -2,15 +2,23 @@
 
 Capability parity: reference `src/llm_training/ops/rms_norm_op.py:4-14` (fp32
 upcast, variance over last dim) and the Triton-fused
-`ops/liger_kernel/rms_norm_op.py`. On TPU the fused version is just this
-function under XLA fusion — the normalization fuses into the surrounding
-elementwise/matmul HLO, so no hand-written kernel is needed for parity.
+`ops/liger_kernel/rms_norm_op.py`. On TPU this function is left to XLA: the
+scale and the multiplies fuse into the neighbouring matmuls, the statistic
+(a reduction over the hidden width) cannot, and stays a device op of its own
+or rides out of a neighbour's fusion as a second output. That costs little
+but not nothing, and `rms_norm` runs under a named scope of that name so a
+device profile says how much: 0.96% of the benchmark's train step (most of it
+the backward's two passes over the activation), 0.008 to 0.07 ms of a decode
+step and 0.03 to 0.3 ms of a 512-token chunk (chip runs, PR 37: PERF.md
+section 5; `train_norm_device_pct`, `decode_norm_device_ms`,
+`prefill_norm_device_ms`; docs/observability.md lists the readers).
 """
 
 import jax
 import jax.numpy as jnp
 
 
+@jax.named_scope("rms_norm")  # a device profile reads the norms by this name
 def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
     """y = weight * (x / rms(x)).
 

@@ -464,18 +464,21 @@ class Trainer:
             grads, metrics = _grads_and_metrics(
                 objective, state, batch, objective_health
             )
-            opt_state = state.opt_state
-            if offload:
-                opt_state = self._decode(
-                    jax.tree.map(jax.device_put, opt_state, opt_device)
-                )
-            updates, opt_state = tx.update(grads, opt_state, state.params)
-            if offload:
-                opt_state = jax.tree.map(
-                    jax.device_put, self._encode(opt_state), opt_host
-                )
-            params = optax.apply_updates(state.params, updates)
-            metrics["grad_norm"] = optax.global_norm(grads)
+            # a device profile reads the update, the offload's copies around
+            # it and the gradient norm by this name (docs/observability.md)
+            with jax.named_scope("optimizer"):
+                opt_state = state.opt_state
+                if offload:
+                    opt_state = self._decode(
+                        jax.tree.map(jax.device_put, opt_state, opt_device)
+                    )
+                updates, opt_state = tx.update(grads, opt_state, state.params)
+                if offload:
+                    opt_state = jax.tree.map(
+                        jax.device_put, self._encode(opt_state), opt_host
+                    )
+                params = optax.apply_updates(state.params, updates)
+                metrics["grad_norm"] = optax.global_norm(grads)
             if with_health:
                 metrics.update(
                     layer_health_metrics(
@@ -511,30 +514,33 @@ class Trainer:
             grads, metrics = _grads_and_metrics(
                 objective, state, batch, objective_health
             )
-            gnorm = optax.global_norm(grads)
-            metrics["grad_norm"] = gnorm
-            # health reads the PRE-clip gradients (same semantics as the
-            # non-offload step): the clip rescale is global, so a single
-            # NaN leaf would smear NaN over every group and destroy the
-            # per-layer provenance this exists for
-            raw_grads = grads
-            if clip_norm is not None:
-                scale = clip_norm / jnp.maximum(gnorm, clip_norm)
-                grads = jax.tree.map(lambda g: g * scale.astype(g.dtype), grads)
+            # the same scope as the plain step's: norm, clip, per-leaf update
+            # and the copies around it
+            with jax.named_scope("optimizer"):
+                gnorm = optax.global_norm(grads)
+                metrics["grad_norm"] = gnorm
+                # health reads the PRE-clip gradients (same semantics as the
+                # non-offload step): the clip rescale is global, so a single
+                # NaN leaf would smear NaN over every group and destroy the
+                # per-layer provenance this exists for
+                raw_grads = grads
+                if clip_norm is not None:
+                    scale = clip_norm / jnp.maximum(gnorm, clip_norm)
+                    grads = jax.tree.map(lambda g: g * scale.astype(g.dtype), grads)
 
-            p_leaves, p_def = jax.tree.flatten(state.params)
-            g_leaves = jax.tree.flatten(grads)[0]
-            new_params, new_opt, upd_leaves = [], [], []
-            for p, g, o_host, sh_dev, sh_host in zip(
-                p_leaves, g_leaves, state.opt_state, opt_device, opt_host
-            ):
-                o_dev = jax.tree.map(jax.device_put, o_host, sh_dev)
-                upd, o_fp = tx.update(g, self._decode(o_dev), p)
-                new_opt.append(
-                    jax.tree.map(jax.device_put, self._encode(o_fp), sh_host)
-                )
-                upd_leaves.append(upd)
-                new_params.append(optax.apply_updates(p, upd))
+                p_leaves, p_def = jax.tree.flatten(state.params)
+                g_leaves = jax.tree.flatten(grads)[0]
+                new_params, new_opt, upd_leaves = [], [], []
+                for p, g, o_host, sh_dev, sh_host in zip(
+                    p_leaves, g_leaves, state.opt_state, opt_device, opt_host
+                ):
+                    o_dev = jax.tree.map(jax.device_put, o_host, sh_dev)
+                    upd, o_fp = tx.update(g, self._decode(o_dev), p)
+                    new_opt.append(
+                        jax.tree.map(jax.device_put, self._encode(o_fp), sh_host)
+                    )
+                    upd_leaves.append(upd)
+                    new_params.append(optax.apply_updates(p, upd))
             if with_health:
                 metrics.update(
                     layer_health_metrics(
