@@ -73,6 +73,7 @@ import logging
 import math
 import os
 import time
+from dataclasses import dataclass
 from typing import Any, Sequence
 
 import jax
@@ -90,6 +91,7 @@ from llm_training_tpu.models.moe import IN_PLACE_GAUGE
 from llm_training_tpu.ops.paged_attention import CHUNK_KERNEL_GAUGE, reset_chunk_kernel_layers
 from llm_training_tpu.resilience.chaos import get_chaos
 from llm_training_tpu.serve.paged_cache import (
+    TRASH_BLOCK,
     BlockAllocator,
     init_paged_pool,
     init_state_slab,
@@ -118,6 +120,33 @@ _LIVE_WINDOW = 512
 # terminals that are the engine SHEDDING load to protect its SLO, not
 # request failures: counted as serve/requests_shed, never requests_failed
 _SHED_REASONS = ("deadline", "overloaded")
+
+
+def _split(packed, fields: dict[str, tuple[int, ...]]) -> dict:
+    """The named fields of a call's packed int32 inputs, in the order and
+    shapes of `fields`: views of the host's staging buffer, which the engine
+    writes through, and slices of the traced array, which the program reads.
+    One layout for both sides, one transfer a call."""
+    out, at = {}, 0
+    for name, shape in fields.items():
+        size = math.prod(shape)
+        out[name] = packed[at:at + size].reshape(shape)
+        at += size
+    return out
+
+
+@dataclass(slots=True)
+class _KeptRow:
+    """What the engine last wrote into a decode slot's rows of the kept block
+    tables: whose pages (a request's residency is the request and its
+    `evictions`), how many of the first group, and of the window group's ring
+    the first page and how many."""
+
+    request: ServeRequest
+    residency: int
+    blocks: int
+    window_first: int
+    window_blocks: int
 
 
 class ServeConfig(BaseModel):
@@ -263,6 +292,7 @@ class ServingEngine:
                 self.window_allocator, self.sliding_window, self.window_pages
             ),
         )
+        self._build_tables()
         self._build_programs()
         # a profiler capture of this process holds step()'s spans beside the
         # device's ops (docs/observability.md#tracing)
@@ -273,9 +303,10 @@ class ServingEngine:
         # for a stack that counts its expert assignments (`CausalLMOutput.
         # moe_assignments`: held here, zero-compute, held elsewhere): what the
         # calls since the last decode fetch counted, carried on the device
-        # (zeros from the host after a fetch). None for every other stack
-        self._moe_carry = (
-            np.zeros((3,), np.int32)
+        # (zeros again after a fetch: one array made here, never donated).
+        # None for every other stack
+        self._moe_zero = self._moe_carry = (
+            jnp.zeros((3,), jnp.int32)
             if getattr(model_config, "counts_expert_assignments", False) else None
         )
         self._rng = jax.random.key(self.config.seed)
@@ -379,26 +410,35 @@ class ServingEngine:
                 return outs
             return (*outs, (state.window_k, state.window_v))
 
-        def prefill_chunk(variables, ids, seg, pos, pool_k, pool_v, tables, length,
-                          last_pos, rng, slab=None, slot=None, fresh=None, moe=None,
-                          window_pool=None, window_tables=None):
+        prefill_fields, decode_fields = self._prefill_fields, self._decode_fields
+        last_position = self.config.max_model_len - 1
+
+        def prefill_chunk(variables, packed, pool_k, pool_v, rng, slab=None, moe=None,
+                          window_pool=None):
+            sent = _split(packed, prefill_fields)
+            tokens, start = sent["tokens"], sent["start"]
+            # the chunk's first `tokens` columns are the row's positions
+            # `start ..`; the others are padding (segment 0)
+            column = jnp.arange(prefill_fields["ids"][1], dtype=jnp.int32)[None, :]
             state = PagedDecodeState(
-                k=pool_k, v=pool_v, block_tables=tables, lengths=length,
+                k=pool_k, v=pool_v, block_tables=sent["tables"], lengths=start[None],
                 rope_length=rope_length,
                 # the request's slot of the slab, read as zeros on its first chunk
-                **slab_fields(slab, slots=slot, fresh=fresh),
-                **window_fields(window_pool, window_tables),
+                **slab_fields(slab, slots=sent["slot"][None], fresh=sent["fresh"][None] != 0),
+                **window_fields(window_pool, sent.get("window_tables")),
             )
             out = model.apply(
-                variables, input_ids=ids, segment_ids=seg,
-                position_ids=pos, decode_state=state,
+                variables, input_ids=sent["ids"],
+                segment_ids=(column < tokens).astype(jnp.int32),
+                position_ids=jnp.minimum(start + column, last_position),
+                decode_state=state,
             )
             logits = jax.lax.dynamic_index_in_dim(
-                out.logits[0], last_pos, axis=0, keepdims=False
+                out.logits[0], tokens - 1, axis=0, keepdims=False
             ).astype(jnp.float32)
             with jax.named_scope("sample"):
                 token, logprob = sample_tokens_with_logprob(
-                    logits[None], rng, sampling
+                    logits[None], jax.random.fold_in(rng, sent["call"]), sampling
                 )
             state = out.decode_state
             # the chunk's expert assignments join the carry, on the device
@@ -407,12 +447,14 @@ class ServingEngine:
                 (state.k, state.v, token[0], logprob[0], slab_of(state), moe), state
             )
 
-        def decode_step(variables, tokens, pool_k, pool_v, tables, lengths, rng, slab=None,
-                        moe=None, window_pool=None, window_tables=None):
+        def decode_step(variables, packed, pool_k, pool_v, rng, slab=None, moe=None,
+                        window_pool=None):
+            sent = _split(packed, decode_fields)
+            tokens, lengths = sent["tokens"], sent["lengths"]
             state = PagedDecodeState(
-                k=pool_k, v=pool_v, block_tables=tables, lengths=lengths,
+                k=pool_k, v=pool_v, block_tables=sent["tables"], lengths=lengths,
                 rope_length=rope_length, **slab_fields(slab),
-                **window_fields(window_pool, window_tables),
+                **window_fields(window_pool, sent.get("window_tables")),
             )
             # row i is slot i. A slot that does not decode this step (idle, or
             # its prompt still prefilling) has length 0 here: segment 0, so
@@ -427,7 +469,9 @@ class ServingEngine:
             )
             logits = out.logits[:, -1].astype(jnp.float32)
             with jax.named_scope("sample"):
-                token, logprob = sample_tokens_with_logprob(logits, rng, sampling)
+                token, logprob = sample_tokens_with_logprob(
+                    logits, jax.random.fold_in(rng, sent["call"]), sampling
+                )
             state = out.decode_state
             # the carry and this step's assignments: fetched with the tokens
             moe = None if moe is None else moe + out.moe_assignments
@@ -440,20 +484,45 @@ class ServingEngine:
         # (the slab and the window group's pool go by keyword: a stack
         # without one is called as before)
         self._prefill_jit = jax.jit(
-            prefill_chunk, donate_argnums=(4, 5), donate_argnames=("slab", "window_pool")
+            prefill_chunk, donate_argnums=(2, 3), donate_argnames=("slab", "window_pool")
         )
         self._decode_jit = jax.jit(
             decode_step, donate_argnums=(2, 3), donate_argnames=("slab", "window_pool")
         )
 
-    def _next_rng(self):
-        self._call += 1
-        return jax.random.fold_in(self._rng, self._call)
+    def _build_tables(self) -> None:
+        """What the engine keeps a decode slot for its lifetime and edits
+        where it changes, instead of rebuilding it from the requests every
+        step: the block tables (a group), and the staging buffer of each
+        program's packed inputs with the views the host writes through."""
+        batch, pages, window = self.config.max_batch, self.pages_per_request, self.window_pages
+        self._tables = np.zeros((batch, pages), np.int32)
+        self._window_tables = None if window is None else np.zeros((batch, window), np.int32)
+        self._held: list[_KeptRow | None] = [None] * batch
+        self._alive = np.zeros((batch, 1), bool)
+        self._decode_fields = {
+            "tokens": (batch,), "lengths": (batch,), "call": (), "tables": (batch, pages),
+        }
+        self._prefill_fields = {
+            "ids": (1, self.config.prefill_chunk), "tokens": (), "start": (), "call": (),
+            "slot": (), "fresh": (), "tables": (1, pages),
+        }
+        if window is not None:
+            self._decode_fields["window_tables"] = (batch, window)
+            self._prefill_fields["window_tables"] = (1, window)
+        self._decode_packed, self._prefill_packed = (
+            np.zeros((sum(math.prod(shape) for shape in fields.values()),), np.int32)
+            for fields in (self._decode_fields, self._prefill_fields)
+        )
+        self._decode_sent = _split(self._decode_packed, self._decode_fields)
+        self._prefill_sent = _split(self._prefill_packed, self._prefill_fields)
 
     def _table_row(self, request: ServeRequest, window: bool = False) -> np.ndarray:
-        """The request's row of a group's block table. The window group's is
-        a ring: logical page `p` in slot `p % window_pages`, and a slot whose
-        page was given back (or never taken) names the trash block."""
+        """The request's row of a group's block table, built whole: what a
+        slot's kept row starts from when a residency begins, and what it must
+        equal after every edit (tests/test_serve_tables.py). The window
+        group's is a ring: logical page `p` in slot `p % window_pages`, and a
+        slot whose page was given back (or never taken) names the trash block."""
         if not window:
             row = np.zeros((self.pages_per_request,), np.int32)
             row[: len(request.blocks)] = request.blocks
@@ -462,6 +531,41 @@ class ServingEngine:
         pages = request.window_first + np.arange(len(request.window_blocks))
         row[pages % self.window_pages] = request.window_blocks
         return row
+
+    def _sync_row(self, request: ServeRequest) -> None:
+        """Bring the kept rows of the request's slot up to its pages. A
+        residency's first sight (a new tenant, or the same request back after
+        an eviction) builds them whole; after that only what changed is
+        written: the pages `_grow` appended, and of the window group's ring
+        the slots whose pages `release_window` gave back (the trash block
+        again). The scheduler does not know the tables: the engine compares
+        what it wrote last with what the request holds."""
+        slot, held = request.slot, self._held[request.slot]
+        count, first = len(request.blocks), request.window_first
+        window_count = len(request.window_blocks)
+        if held is None or held.request is not request or held.residency != request.evictions:
+            self._tables[slot] = self._table_row(request)
+            if self._window_tables is not None:
+                self._window_tables[slot] = self._table_row(request, window=True)
+            self._held[slot] = _KeptRow(request, request.evictions, count, first, window_count)
+            self._step_counts["table_writes"] += count + window_count
+            return
+        if count != held.blocks:
+            self._tables[slot, held.blocks:count] = request.blocks[held.blocks:]
+            self._step_counts["table_writes"] += count - held.blocks
+            held.blocks = count
+        if first != held.window_first or window_count != held.window_blocks:
+            row, ring = self._window_tables[slot], self.window_pages
+            written = held.window_first + held.window_blocks
+            given_back = range(held.window_first, min(written, first))
+            taken = range(max(written, first), first + window_count)
+            # given back first: a page taken since may lie in the same slot
+            for page in given_back:
+                row[page % ring] = TRASH_BLOCK
+            for page in taken:
+                row[page % ring] = request.window_blocks[page - first]
+            self._step_counts["table_writes"] += len(given_back) + len(taken)
+            held.window_first, held.window_blocks = first, window_count
 
     # -------------------------------------------------------------- intake
 
@@ -694,6 +798,8 @@ class ServingEngine:
             # `prefill_start`: the tokens the chunk's row held before the chunk
             "prefill_chunks": 0, "prefill_tokens": 0, "prefill_start": 0,
             "decode_rows": 0, "live_tokens": 0,
+            # block-table entries the host wrote, both groups (`_sync_row`)
+            "table_writes": 0,
         }
         if self._slab is not None:
             counts["state_resets"] = 0
@@ -761,6 +867,11 @@ class ServingEngine:
             closing.update(counts)
         registry = get_registry()
         registry.counter("serve/steps").inc()
+        self.allocator.publish()
+        if self.window_allocator is not None:
+            self.window_allocator.publish()
+        if counts["table_writes"]:
+            registry.counter("serve/table_writes").inc(counts["table_writes"])
         if self._slab is not None:
             registry.gauge("decode/state_slots_in_use").set(counts["state_slots_in_use"])
             if counts["state_resets"]:
@@ -847,32 +958,32 @@ class ServingEngine:
             # inputs and the enqueue; the device's time shows in prefill_fetch
             # (a final chunk) or in the next decode_fetch
             with tracer.measure("serve", "prefill_dispatch", write=False, **ids):
-                width = self.config.prefill_chunk
-                ids_row = np.zeros((1, width), np.int32)
-                seg = np.zeros((1, width), np.int32)
-                ids_row[0, : len(chunk)] = chunk
-                seg[0, : len(chunk)] = 1
-                pos = np.minimum(
-                    start + np.arange(width), self.config.max_model_len - 1
-                ).astype(np.int32)[None, :]
-                tables = self._table_row(request)[None, :]
-                # (numpy rows: they travel with the call, not as transfers of their own)
-                slab_row = {} if self._slab is None else {
-                    "slab": self._slab,
-                    "slot": np.asarray([request.slot], np.int32),
-                    "fresh": np.asarray([fresh]),
-                }
+                self._sync_row(request)
+                sent = self._prefill_sent
+                sent["ids"][0, : len(chunk)] = chunk
+                sent["ids"][0, len(chunk):] = 0
+                sent["tokens"][...] = len(chunk)
+                sent["start"][...] = start
+                self._call += 1
+                sent["call"][...] = self._call
+                sent["slot"][...] = request.slot
+                sent["fresh"][...] = fresh
+                sent["tables"][0] = self._tables[request.slot]
+                caches = {} if self._slab is None else {"slab": self._slab}
                 if self._moe_carry is not None:
-                    slab_row["moe"] = self._moe_carry
+                    caches["moe"] = self._moe_carry
                 if self._window_pool is not None:
-                    slab_row["window_pool"] = self._window_pool
-                    slab_row["window_tables"] = self._table_row(request, window=True)[None, :]
+                    caches["window_pool"] = self._window_pool
+                    sent["window_tables"][0] = self._window_tables[request.slot]
+                # a COPY of the staging buffer travels with the call, the one
+                # transfer: the runtime may read a numpy argument after the
+                # call returns (the CPU backend aliases an aligned one
+                # outright), and the next chunk fills the buffer before this
+                # one is fetched
                 (self._pool_k, self._pool_v, token, logprob, self._slab,
                  self._moe_carry) = self._take_window_pool(self._prefill_jit(
-                    self.variables, jnp.asarray(ids_row), jnp.asarray(seg),
-                    jnp.asarray(pos), self._pool_k, self._pool_v,
-                    jnp.asarray(tables), jnp.asarray([start], jnp.int32),
-                    jnp.int32(len(chunk) - 1), self._next_rng(), **slab_row,
+                    self.variables, self._prefill_packed.copy(), self._pool_k, self._pool_v,
+                    self._rng, **caches,
                 ))
             request.prefilled += len(chunk)
             request.cache_len += len(chunk)
@@ -926,38 +1037,41 @@ class ServingEngine:
         if not survivors:
             return events
         with tracer.measure("serve", "decode_inputs", **child):
-            batch = self.config.max_batch
-            tokens = np.zeros((batch,), np.int32)
-            lengths = np.zeros((batch,), np.int32)
-            tables = np.zeros((batch, self.pages_per_request), np.int32)
-            window_tables = (
-                None if self._window_pool is None
-                else np.zeros((batch, self.window_pages), np.int32)
-            )
+            sent, alive = self._decode_sent, self._alive
+            tokens, lengths = sent["tokens"], sent["lengths"]
+            tokens[:] = 0
+            lengths[:] = 0
+            alive[:] = False
             for request in survivors:
+                self._sync_row(request)
                 tokens[request.slot] = request.generated[-1]
                 lengths[request.slot] = request.cache_len
-                tables[request.slot] = self._table_row(request)
-                if window_tables is not None:
-                    window_tables[request.slot] = self._table_row(request, window=True)
+                alive[request.slot] = True
+            # a slot that does not decode this step (idle, its prompt still
+            # prefilling, evicted a moment ago) is handed a row of zeros, as
+            # its length: its append lands in the trash block, not in position
+            # 0 of a page its kept row names
+            np.multiply(self._tables, alive, out=sent["tables"])
+            self._call += 1
+            sent["call"][...] = self._call
             self._step_counts["decode_rows"] = len(survivors)
             # what the paged kernel reads this call: each row's cache and its
             # new token
             self._step_counts["live_tokens"] = int(lengths.sum()) + len(survivors)
-            step_args = (
-                self.variables, jnp.asarray(tokens), self._pool_k, self._pool_v,
-                jnp.asarray(tables), jnp.asarray(lengths), self._next_rng(),
-            )
             step_slab = {} if self._slab is None else {"slab": self._slab}
             if self._moe_carry is not None:
                 step_slab["moe"] = self._moe_carry
-            if window_tables is not None:
+            if self._window_pool is not None:
                 step_slab["window_pool"] = self._window_pool
-                step_slab["window_tables"] = window_tables
+                np.multiply(self._window_tables, alive, out=sent["window_tables"])
                 # what a window layer's call reads: of each row, its window
                 self._step_counts["window_live_tokens"] = sum(
                     min(r.cache_len + 1, self.sliding_window) for r in survivors
                 )
+            # (a copy, as a chunk's: what a call was handed is never written again)
+            step_args = (
+                self.variables, self._decode_packed.copy(), self._pool_k, self._pool_v, self._rng,
+            )
         if not self._decode_attr_done:
             # before the donating call below: lowering only reads avals,
             # while the jit consumes the pool buffers
@@ -978,7 +1092,7 @@ class ServingEngine:
                 # the assignments counted since the last fetch
                 for kind, n in zip(("held", "zero", "elsewhere"), moe):
                     self._step_counts[f"moe_{kind}"] = int(n)
-                self._moe_carry = np.zeros((3,), np.int32)
+                self._moe_carry = self._moe_zero
             for request in survivors:
                 request.cache_len += 1
                 self._release_window(request)
@@ -1191,19 +1305,22 @@ class ServingEngine:
         registry = get_registry()
         for key, value in stats.items():
             registry.gauge(key).set(value)
+        # what step() counted (counters already: read into the summary, not
+        # published twice): the rows decoded and the block-table entries the
+        # host wrote for them, `rows / block_size` where nothing else happens
+        counted = ["serve/steps", "serve/decode_rows", "serve/table_writes"]
         if self._moe_carry is not None:
-            # counters already (step()): read into the summary, not published twice
-            for kind in ("held", "zero", "elsewhere"):
-                key = f"serve/moe_{kind}_assignments"
-                stats[key] = float(registry.counter(key).value)
+            counted += [f"serve/moe_{kind}_assignments" for kind in ("held", "zero", "elsewhere")]
+        self.allocator.publish()
         if self.window_allocator is not None:
-            # the window group's allocator publishes its own gauges; the two
-            # sums are counters already
+            # the window group's allocator publishes its own gauges
+            self.window_allocator.publish()
             stats["decode/window_blocks_total"] = float(self.window_allocator.num_blocks - 1)
             stats["decode/window_blocks_in_use"] = float(self.window_allocator.blocks_in_use)
             stats["decode/window_peak_blocks_in_use"] = float(self.window_allocator.peak_in_use)
-            for key in ("serve/window_live_tokens", "serve/window_pages_released"):
-                stats[key] = float(registry.counter(key).value)
+            counted += ["serve/window_live_tokens", "serve/window_pages_released"]
+        for key in counted:
+            stats[key] = float(registry.counter(key).value)
         logger.info(
             "serve: %d completed (%d evictions) | %.1f tokens/s (%.1f/chip)",
             len(completed), self.scheduler.evictions, tps, stats["serve/tokens_per_sec_per_chip"],

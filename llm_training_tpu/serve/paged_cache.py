@@ -19,7 +19,7 @@ grow with `max_model_len` (`window_page_budget`).
 
 The `BlockAllocator` is the host half: a free list handing fixed-size
 blocks to requests and taking them back on completion/eviction, publishing
-pool occupancy as `decode/cache_blocks_total` / `decode/cache_blocks_in_use`
+(once an engine step: `publish`) pool occupancy as `decode/cache_blocks_total` / `decode/cache_blocks_in_use`
 / `decode/cache_peak_blocks_in_use` gauges so telemetry.jsonl and `report`
 show block pressure (and the serve-smoke gate can assert leak-freedom).
 
@@ -178,7 +178,8 @@ def _publish_pool_gauges(k, v, num_blocks: int) -> None:
 class BlockAllocator:
     """Host-side free list over the pool's physical blocks (block 0
     reserved as trash). All-or-nothing `alloc`, idempotence-free `free`
-    (double-free is a bug and raises), occupancy gauges on every change:
+    (double-free is a bug and raises), occupancy gauges where the owner asks
+    (`publish`; the engine does once a step):
     `decode/<group>_blocks_in_use` and `decode/<group>_peak_blocks_in_use`
     (`cache`: the pool of the layers that keep every token; `window`: the
     window group's)."""
@@ -193,7 +194,7 @@ class BlockAllocator:
         self._free = list(range(num_blocks - 1, TRASH_BLOCK, -1))  # pop() -> low ids first
         self._in_use: set[int] = set()
         self.peak_in_use = 0
-        self._publish()
+        self.publish()
 
     @property
     def free_blocks(self) -> int:
@@ -212,7 +213,6 @@ class BlockAllocator:
         blocks = [self._free.pop() for _ in range(n)]
         self._in_use.update(blocks)
         self.peak_in_use = max(self.peak_in_use, len(self._in_use))
-        self._publish()
         return blocks
 
     def free(self, blocks: list[int]) -> None:
@@ -221,9 +221,11 @@ class BlockAllocator:
                 raise ValueError(f"free of unallocated block {block}")
             self._in_use.remove(block)
             self._free.append(block)
-        self._publish()
 
-    def _publish(self) -> None:
+    def publish(self) -> None:
+        """Set the two occupancy gauges from the allocator's own counts: once
+        an engine step (and when its summary is taken), not on every `alloc`
+        and `free` of the step."""
         from llm_training_tpu.telemetry import get_registry
 
         registry = get_registry()

@@ -385,8 +385,16 @@ class Scheduler:
     def ensure_decode_blocks(self, request: ServeRequest) -> bool:
         """Guarantee the row's next token has a cache slot in every group,
         evicting under block pressure. False when the request itself got
-        evicted."""
-        return self._grow(request, request.cache_len + 1)
+        evicted. A row takes a page once in `block_size` tokens: every
+        other step the pages it holds reach, which is one compare a group."""
+        tokens = request.cache_len + 1
+        size = self.config.block_size
+        if tokens <= len(request.blocks) * size and (
+            self.window_allocator is None
+            or tokens <= (request.window_first + len(request.window_blocks)) * size
+        ):
+            return True
+        return self._grow(request, tokens)
 
     def _grow(self, request: ServeRequest, tokens: int) -> bool:
         """Pages for the request's first `tokens` positions: of the first

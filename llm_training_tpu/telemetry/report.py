@@ -247,6 +247,14 @@ def _serving_section(telemetry: dict) -> list[str]:
             if p99 is not None:
                 line += f"  p99 {p99:,.1f} ms"
             lines.append(line)
+    rows = num("serve/decode_rows")
+    if rows:
+        # what the host did for them: block-table entries written, where a
+        # rebuild a step would write rows x the table's width
+        lines.append(
+            f"engine: {int(num('serve/steps') or 0):,} steps, {int(rows):,} rows decoded, "
+            f"{int(num('serve/table_writes') or 0):,} block-table entries written"
+        )
     total = num("decode/cache_blocks_total")
     peak_blocks = num("decode/cache_peak_blocks_in_use")
     if total:

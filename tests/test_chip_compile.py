@@ -432,26 +432,18 @@ def _serve_program(v5e, cell, program):
     engine = ServingEngine(model, None, ServeConfig(**serve))  # its caches give the shapes
     pool, slab = on(engine._pool_k), on(engine._slab)
     pool_v = None if engine._pool_v is None else pool  # a latent stack has ONE pool
-    rows, pages, chunk = serve["max_batch"], engine.pages_per_request, serve["prefill_chunk"]
     key = on(jax.eval_shape(lambda: jax.random.key(0)))
     slab_rows = {} if slab is None else {"slab": slab}
     if engine._moe_carry is not None:  # a share of the experts counts its assignments
         slab_rows["moe"] = shape((3,))
-    if engine._window_pool is not None:  # the layers that keep a window: a pool and a short table
+    if engine._window_pool is not None:  # the layers that keep a window: a pool (its short table is packed)
         slab_rows["window_pool"] = on(engine._window_pool)
-        slab_rows["window_tables"] = shape((rows if program == "decode" else 1, engine.window_pages))
-    if program == "decode":
-        lowered = engine._decode_jit.lower(
-            variables, shape((rows,)), pool, pool_v, shape((rows, pages)), shape((rows,)), key,
-            **slab_rows,
-        )
-    else:
-        if slab is not None:
-            slab_rows.update(slot=shape((1,)), fresh=shape((1,), jnp.bool_))
-        lowered = engine._prefill_jit.lower(
-            variables, shape((1, chunk)), shape((1, chunk)), shape((1, chunk)), pool, pool_v,
-            shape((1, pages)), shape((1,)), shape(()), key, **slab_rows,
-        )
+    # a call's int32 inputs travel packed (tokens, lengths, tables, the call index: `_decode_fields`)
+    jitted, packed = (
+        (engine._decode_jit, engine._decode_packed) if program == "decode"
+        else (engine._prefill_jit, engine._prefill_packed)
+    )
+    lowered = jitted.lower(variables, shape(packed.shape), pool, pool_v, key, **slab_rows)
     engine.close()
     return lowered, pool, slab
 

@@ -73,11 +73,16 @@ def test_allocator_alloc_free_roundtrip():
 
 
 def test_allocator_occupancy_gauges():
+    """Set where the owner asks (`publish`: the engine does once a step),
+    not on every `alloc` and `free`."""
     allocator = BlockAllocator(num_blocks=4)
     blocks = allocator.alloc(2)
     registry = get_registry()
+    assert registry.gauge("decode/cache_blocks_in_use").value == 0
+    allocator.publish()
     assert registry.gauge("decode/cache_blocks_in_use").value == 2
     allocator.free(blocks)
+    allocator.publish()
     assert registry.gauge("decode/cache_blocks_in_use").value == 0
     assert registry.gauge("decode/cache_peak_blocks_in_use").value == 2
 
@@ -405,6 +410,97 @@ def test_eos_recycles_slot_and_reports_stop_reason():
         [prompt], GenerateConfig(max_new_tokens=6, eos_token_id=None)
     )
     assert full["stop_reasons"] == ["max_tokens"] and full["lengths"] == [6]
+
+
+# ------------------------------------- the parent's rebuild path, recorded
+
+# sha256 of the event stream of `_event_stream` for each family's tiny
+# config, recorded at PR 37's tree (b15c5df): the engine that rebuilt every
+# row, transfer and key a step from the requests. To record anew, print
+# `_stream_hash(_event_stream(build(**STREAM_SERVE)))` under the tree to trust.
+RECORDED = {
+    "llama": "1e3ceb80af323c92",
+    "olmoe": "e393c7c6238fb690",
+    "solar": "e80e81a66ef70fab",
+    "longcat": "49c2aacf8cc3ecc2",
+    "afmoe": "003f5ac484ecc69d",
+    "llama-sampled": "c8948767af2e47dc",
+}
+STREAM_SERVE = dict(max_batch=3, max_model_len=48, block_size=8, prefill_chunk=4, num_blocks=10)
+
+
+def _stream_builders():
+    from test_serve_spans import (
+        TINY_MOE, _afmoe_engine, _engine, _longcat_engine, _solar_engine,
+    )
+
+    from llm_training_tpu.infer.sampling import SamplingConfig
+
+    return {
+        "llama": _engine,
+        "olmoe": lambda **serve: _engine(TINY_MOE, **serve),
+        "solar": _solar_engine,
+        "longcat": _longcat_engine,
+        "afmoe": _afmoe_engine,
+        "llama-sampled": lambda **serve: _engine(
+            seed=7, sampling=SamplingConfig(temperature=0.8, top_p=0.9), **serve
+        ),
+    }
+
+
+def _event_stream(engine, steps=200, seed=0):
+    """Seeded arrivals over `steps` engine steps of a pool too small for its
+    rows (evictions, requeues, every slot taken again and again): each token
+    with its logprob, each done event, and what the run counted."""
+    import random
+
+    rng = random.Random(seed)
+    stream, submitted = [], 0
+    for _ in range(steps):
+        events = []
+        if len(engine.scheduler.waiting) < 2 and rng.random() < 0.45:
+            prompt = [rng.randrange(1, 64) for _ in range(rng.choice((3, 6, 10, 15)))]
+            events += engine.submit(f"r{submitted}", prompt, max_new_tokens=rng.choice((5, 12, 24)))
+            submitted += 1
+        events += engine.step()
+        for e in events:
+            if e["type"] == "token":
+                stream.append([e["id"], e["token"], round(e["logprob"], 4)])
+            else:
+                stream.append([e["id"], e["stop_reason"], e["tokens"], e["evictions"]])
+    counters = get_registry().counter
+    stream.append([
+        engine.scheduler.evictions, counters("serve/state_resets").value,
+        counters("serve/prefill_chunks").value, counters("serve/decode_rows").value,
+    ])
+    return stream
+
+
+def _stream_hash(stream):
+    import hashlib
+    import json
+
+    return hashlib.sha256(json.dumps(stream).encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("case", sorted(RECORDED))
+def test_event_stream_equals_the_rebuild_paths_recording(case):
+    """Tables kept a slot, the key folded inside the program and one packed
+    transfer a call change no token, logprob or done event of 200 steps: for
+    every family's stack (Solar's with its slots reused, so `fresh` and
+    `serve/state_resets` are in it), and for a SAMPLED run, which draws what
+    `fold_in(rng, call)` outside the program drew."""
+    from llm_training_tpu.telemetry.registry import TelemetryRegistry, set_registry
+
+    previous = set_registry(TelemetryRegistry())
+    try:
+        stream = _event_stream(_stream_builders()[case](**STREAM_SERVE))
+    finally:
+        set_registry(previous)
+    assert stream[-1][0] > 0 and stream[-1][3] > 200, stream[-1]  # evictions; rows decoded
+    if case == "solar":
+        assert stream[-1][1] > STREAM_SERVE["max_batch"]  # a slot's second tenant started fresh
+    assert _stream_hash(stream) == RECORDED[case], stream[:5] + stream[-3:]
 
 
 # ------------------------------------------------- continuous batching

@@ -245,6 +245,67 @@ def test_engine_step_spans_nest_and_count_like_the_harness(fresh, tmp_path):
     )
 
 
+@pytest.mark.parametrize("build", ["llama", "afmoe"])
+def test_table_writes_count_pages_taken_not_rows_rebuilt(fresh, build):
+    """`table_writes` closes `serve/engine_step` and `serve/table_writes` is
+    its sum: the block-table entries the host wrote, which on a stretch of
+    decode steps is a page a row every `block_size` tokens (and, of a window
+    group's ring, the slot of each page given back), not rows x pages."""
+    engine = {"llama": _engine, "afmoe": _afmoe_engine}[build](max_batch=3, max_model_len=64)
+    for i, prompt in enumerate(PROMPTS):
+        engine.submit(f"r{i}", prompt, max_new_tokens=40)
+    while any(not r.decoding for r in engine.scheduler.running.values()) or engine.scheduler.waiting:
+        engine.step()
+    prefill_steps = engine._step_index
+    for _ in range(32):  # 3 rows x 32 tokens: 4 pages of 8 a row
+        engine.step()
+    steps = [e["args"] for e in fresh.snapshot() if e.get("ph") == "X" and e["name"] == "engine_step"]
+    assert all("table_writes" in args for args in steps)
+    stretch = steps[prefill_steps:]
+    assert all(args["decode_rows"] == 3 and not args["prefill_chunks"] for args in stretch)
+    rows, pages = sum(args["decode_rows"] for args in stretch), engine.pages_per_request
+    taken = rows // SERVE["block_size"]  # 12
+    given_back = sum(args.get("window_pages_released", 0) for args in stretch)
+    assert (given_back > 0) == (build == "afmoe")
+    # the window group writes its own taken page, and the slot of each page given back
+    groups = 2 if build == "afmoe" else 1
+    assert sum(args["table_writes"] for args in stretch) == taken * groups + given_back
+    assert max(args["table_writes"] for args in stretch) < rows * pages / len(stretch)
+    # admission wrote each row once, whole: its pages of each group
+    assert sum(args["table_writes"] for args in steps[:prefill_steps]) >= len(PROMPTS)
+    registry = get_registry()
+    assert registry.counter("serve/table_writes").value == sum(args["table_writes"] for args in steps)
+    stats = engine.stats()
+    assert stats["serve/table_writes"] == registry.counter("serve/table_writes").value
+    assert stats["serve/decode_rows"] == registry.counter("serve/decode_rows").value
+    from llm_training_tpu.telemetry.report import _serving_section
+
+    assert f"{int(stats['serve/table_writes']):,} block-table entries written" in "\n".join(
+        _serving_section(stats)
+    )
+
+
+def test_allocator_gauges_are_set_once_a_step_from_its_counts(fresh):
+    """`alloc` and `free` no longer touch the registry: after every step the
+    gauges of each group read what its allocator counts."""
+    engine = _afmoe_engine()
+    for request in _requests(14):
+        engine.submit(**request)
+    registry = get_registry()
+    seen = set()
+    while not engine.scheduler.idle:
+        engine.step()
+        for allocator in (engine.allocator, engine.window_allocator):
+            in_use = registry.gauge(f"decode/{allocator.group}_blocks_in_use").value
+            peak = registry.gauge(f"decode/{allocator.group}_peak_blocks_in_use").value
+            assert (in_use, peak) == (allocator.blocks_in_use, allocator.peak_in_use)
+            seen.add((allocator.group, in_use))
+    assert len(seen) > 4  # they moved
+    blocks = engine.allocator.alloc(2)
+    assert registry.gauge("decode/cache_blocks_in_use").value == 0  # until someone asks
+    engine.allocator.free(blocks)
+
+
 def test_profiler_capture_holds_the_engines_spans(fresh, tmp_path):
     """Under jax.profiler the same spans land in the profiler's host plane,
     `llmt/`-prefixed, with their args; benchmarks/span_reduce.py reads them
@@ -290,21 +351,19 @@ def test_profiler_capture_holds_the_engines_spans(fresh, tmp_path):
 
 
 def _decode_args(engine):
-    batch = engine.config.max_batch
+    """A decode call's arguments: the packed int32 inputs (tokens, lengths,
+    the call index, the block tables: `engine._decode_fields`), the pool, the
+    engine's one key."""
     return (
-        engine.variables, jnp.zeros((batch,), jnp.int32), engine._pool_k,
-        engine._pool_v, jnp.zeros((batch, engine.pages_per_request), jnp.int32),
-        jnp.zeros((batch,), jnp.int32), jax.random.key(0),
+        engine.variables, jnp.asarray(engine._decode_packed), engine._pool_k,
+        engine._pool_v, engine._rng,
     )
 
 
 def _prefill_args(engine):
-    width = engine.config.prefill_chunk
-    row = jnp.zeros((1, width), jnp.int32)
     return (
-        engine.variables, row, row, row, engine._pool_k, engine._pool_v,
-        jnp.zeros((1, engine.pages_per_request), jnp.int32),
-        jnp.zeros((1,), jnp.int32), jnp.int32(0), jax.random.key(0),
+        engine.variables, jnp.asarray(engine._prefill_packed), engine._pool_k,
+        engine._pool_v, engine._rng,
     )
 
 
@@ -351,9 +410,8 @@ def test_linear_attention_stack_names_its_scopes_in_both_programs(fresh):
     `kda_recurrence`, the MoE phases and `moe_shared`), in the lowered
     programs of a stack that carries the state slab."""
     engine = _solar_engine()
-    slot = {"slot": jnp.zeros((1,), jnp.int32), "fresh": jnp.ones((1,), bool)}
     decode = engine._decode_jit.lower(*_decode_args(engine), slab=engine._slab)
-    prefill = engine._prefill_jit.lower(*_prefill_args(engine), slab=engine._slab, **slot)
+    prefill = engine._prefill_jit.lower(*_prefill_args(engine), slab=engine._slab)
     assert "jit_decode_step" in decode.as_text()[:200]
     assert "jit_prefill_chunk" in prefill.as_text()[:200]
     shared = (
@@ -507,11 +565,8 @@ def _afmoe_engine(**serve):
     return ServingEngine(model, variables, ServeConfig(**{**SERVE, **serve}))
 
 
-def _window_args(engine, rows):
-    return {
-        "window_pool": engine._window_pool,
-        "window_tables": jnp.zeros((rows, engine.window_pages), jnp.int32),
-    }
+def _window_args(engine):
+    return {"window_pool": engine._window_pool}  # its tables travel in the packed inputs
 
 
 def test_window_and_global_layers_name_their_scopes_in_both_programs(fresh):
@@ -520,8 +575,8 @@ def test_window_and_global_layers_name_their_scopes_in_both_programs(fresh):
     under `attn_gate`, the MoE phases and `moe_shared` under `/mlp/`, in the
     looped layers in front and in the scanned periods alike."""
     engine = _afmoe_engine()
-    decode = engine._decode_jit.lower(*_decode_args(engine), **_window_args(engine, SERVE["max_batch"]))
-    prefill = engine._prefill_jit.lower(*_prefill_args(engine), **_window_args(engine, 1))
+    decode = engine._decode_jit.lower(*_decode_args(engine), **_window_args(engine))
+    prefill = engine._prefill_jit.lower(*_prefill_args(engine), **_window_args(engine))
     assert "jit_decode_step" in decode.as_text()[:200]
     assert "jit_prefill_chunk" in prefill.as_text()[:200]
     for lowered in (decode, prefill):
@@ -550,7 +605,7 @@ def test_window_group_reports_its_pool_its_pages_and_what_it_reads(fresh):
     assert engine._pool_k.shape == (2, 2 * 6 + 1, 2, 8, 8)
     assert engine.window_pages == 3 and engine._window_pool[0].shape == (6, 2 * 3 + 1, 2, 8, 8)
     *_, window = jax.eval_shape(
-        engine._decode_jit, *_decode_args(engine), **_window_args(engine, SERVE["max_batch"]))
+        engine._decode_jit, *_decode_args(engine), **_window_args(engine))
     assert [leaf.shape for leaf in window] == [engine._window_pool[0].shape] * 2
     engine.run(_requests(14))  # rows of 20, 17 and 19 tokens: each gives its first page back
     registry = get_registry()

@@ -1,0 +1,237 @@
+"""The serving engine keeps a step's inputs and edits them where they change
+(docs/serving.md, "What a step sends"): block tables a decode slot, written
+where a page is taken or given back, and one packed int32 array a call.
+
+The invariant: what a program is handed equals, entry for entry, what a plain
+rebuild from the requests (`ServingEngine._table_row`, zeros for every slot
+that does not decode this step) would hand it. Checked at every call of both
+programs over a seeded schedule that holds every event that moves a row, on a
+stack with one page group and on one with a window group's ring."""
+
+import random
+import time
+
+import numpy as np
+import pytest
+from test_serve_spans import _afmoe_engine, _engine
+
+from llm_training_tpu.serve.engine import _split
+from llm_training_tpu.telemetry.registry import TelemetryRegistry, set_registry
+
+SERVE = dict(max_batch=3, max_model_len=48, block_size=8, prefill_chunk=4, num_blocks=9)
+BUILD = {"llama": _engine, "afmoe": _afmoe_engine}
+EVENTS = (
+    "chunk_beside_decode", "finish_by_length", "finish_by_eos", "eviction_in_decode_blocks",
+    "deadline_expiry", "submit_resumed", "second_tenant", "ring_pages_given_back",
+)
+EOS_PROMPT = [3, 17, 42, 7, 9]
+STEPS = 150
+
+
+class Watch:
+    """Both programs of an engine, each call's packed inputs compared with the
+    rebuild AT the call: a step later the rows have moved on."""
+
+    def __init__(self, engine):
+        self.engine, self.violations, self.plan = engine, [], None
+        self.calls = {"prefill_chunk": 0, "decode_step": 0}
+        self.aliased = dict(self.calls)  # calls handed the staging buffer itself
+        self.tenants = [set() for _ in range(engine.config.max_batch)]
+        run_prefill, decode_jit, prefill_jit = engine._run_prefill, engine._decode_jit, engine._prefill_jit
+
+        def watched_prefill(request, chunk, start):
+            self.plan = (request, chunk, start)
+            return run_prefill(request, chunk, start)
+
+        def watched_decode_call(variables, packed, *args, **caches):
+            self.calls["decode_step"] += 1
+            self.aliased["decode_step"] += np.shares_memory(packed, engine._decode_packed)
+            self.check_decode(np.array(packed))
+            return decode_jit(variables, packed, *args, **caches)
+
+        def watched_prefill_call(variables, packed, *args, **caches):
+            self.calls["prefill_chunk"] += 1
+            self.aliased["prefill_chunk"] += np.shares_memory(packed, engine._prefill_packed)
+            self.check_prefill(np.array(packed))
+            return prefill_jit(variables, packed, *args, **caches)
+
+        engine._run_prefill = watched_prefill
+        engine._decode_jit, engine._prefill_jit = watched_decode_call, watched_prefill_call
+
+    def expect(self, what, got, want):
+        if not np.array_equal(got, want):
+            self.violations.append(
+                f"step {self.engine._step_index} {what}: handed {np.asarray(got).tolist()}, "
+                f"the rebuild gives {np.asarray(want).tolist()}"
+            )
+
+    def check_decode(self, packed):
+        engine = self.engine
+        sent = _split(packed, engine._decode_fields)
+        for slot in range(engine.config.max_batch):
+            request = engine.scheduler.running.get(slot)
+            if request is not None and request.decoding:
+                self.tenants[slot].add(request.id)
+                want = (request.generated[-1], request.cache_len, engine._table_row(request))
+            else:  # idle, its prompt still prefilling, or evicted a moment ago
+                want = (0, 0, np.zeros_like(sent["tables"][slot]))
+            self.expect(f"decode slot {slot} token", sent["tokens"][slot], want[0])
+            self.expect(f"decode slot {slot} length", sent["lengths"][slot], want[1])
+            self.expect(f"decode slot {slot} table", sent["tables"][slot], want[2])
+            if "window_tables" in sent:
+                row = (
+                    engine._table_row(request, window=True) if want[1]
+                    else np.zeros_like(sent["window_tables"][slot])
+                )
+                self.expect(f"decode slot {slot} window table", sent["window_tables"][slot], row)
+        self.expect("decode call index", sent["call"], engine._call)
+
+    def check_prefill(self, packed):
+        engine = self.engine
+        request, chunk, start = self.plan
+        sent = _split(packed, engine._prefill_fields)
+        ids = np.zeros_like(sent["ids"])
+        ids[0, : len(chunk)] = chunk
+        self.expect("chunk ids", sent["ids"], ids)
+        self.expect(
+            "chunk tokens, start, slot, fresh, call",
+            [sent[k] for k in ("tokens", "start", "slot", "fresh", "call")],
+            [len(chunk), start, request.slot, start == 0, engine._call],
+        )
+        self.expect("chunk table", sent["tables"][0], engine._table_row(request))
+        if "window_tables" in sent:
+            self.expect("chunk window table", sent["window_tables"][0], engine._table_row(request, window=True))
+
+
+def _first_tokens(engine, n):
+    """What `EOS_PROMPT` decodes to, alone: its second token then stops it."""
+    events = engine.run([{"id": "probe", "prompt": EOS_PROMPT, "max_new_tokens": n}])
+    return [e["token"] for e in events if e["type"] == "token"]
+
+
+def _schedule(engine, steps=STEPS, seed=0):
+    """Seeded arrivals over `steps` engine steps, with the events no arrival
+    brings planted at fixed steps. -> (events, what happened)."""
+    rng = random.Random(seed)
+    happened = dict.fromkeys(EVENTS, 0)
+    scheduler = engine.scheduler
+    ensure = scheduler.ensure_decode_blocks
+
+    def counted_ensure(request):
+        before = scheduler.evictions
+        kept = ensure(request)
+        happened["eviction_in_decode_blocks"] += scheduler.evictions - before
+        return kept
+
+    scheduler.ensure_decode_blocks = counted_ensure
+    events, submitted = [], 0
+    for step in range(steps):
+        if step == 5:  # stops at its second token
+            events += engine.submit("eos", EOS_PROMPT, max_new_tokens=12)
+        elif step == 40:  # a journal's entry after a relaunch: prompt and progress folded in
+            events += engine.submit_resumed({
+                "id": "resumed", "prompt": [5, 9, 11, 2], "generated": [7, 7, 1],
+                "logprobs": [-1.0, -1.5, -2.0], "emitted": 3, "max_new_tokens": 10,
+            })
+        elif step == 60:
+            events += engine.submit("late", [8, 1, 13, 21, 34, 55], max_new_tokens=30, deadline_ms=3.6e6)
+        elif len(scheduler.waiting) < 2 and rng.random() < 0.45:
+            prompt = [rng.randrange(1, 64) for _ in range(rng.choice((3, 6, 10, 15)))]
+            events += engine.submit(f"r{submitted}", prompt, max_new_tokens=rng.choice((5, 12, 24)))
+            submitted += 1
+        for request in scheduler.running.values():
+            if request.id == "late" and len(request.generated) >= 3:
+                request.deadline_s = time.perf_counter() - 1.0  # expires mid-decode, at this step's top
+        events += engine.step()
+        counts = engine._step_counts
+        happened["chunk_beside_decode"] += bool(counts["prefill_chunks"] and counts["decode_rows"])
+        happened["ring_pages_given_back"] += counts.get("window_pages_released", 0)
+    for event in events:
+        if event["type"] == "done":
+            reason = event["stop_reason"]
+            happened["finish_by_length"] += reason == "max_tokens"
+            happened["finish_by_eos"] += reason == "eos"
+            happened["deadline_expiry"] += reason == "deadline" and event["n_tokens"] >= 3
+            happened["submit_resumed"] += event["id"] == "resumed" and reason in ("max_tokens", "eos")
+    return events, happened
+
+
+@pytest.fixture(scope="module", params=sorted(BUILD))
+def watched(request):
+    previous = set_registry(TelemetryRegistry())  # this run's counters alone
+    try:
+        engine = BUILD[request.param](**SERVE)
+        engine.config.eos_token_id = _first_tokens(engine, 3)[1]
+        watch = Watch(engine)
+        events, happened = _schedule(engine)
+        happened["second_tenant"] = sum(len(ids) > 1 for ids in watch.tenants)
+        yield request.param, engine, watch, happened, engine.stats()
+    finally:
+        set_registry(previous)
+
+
+@pytest.mark.parametrize("event", EVENTS)
+def test_handed_tables_equal_the_rebuild_through(watched, event):
+    """Every call of both programs was handed the rebuild's inputs, in a run
+    where `event` happened: zeros for every slot that did not decode."""
+    config, engine, watch, happened, _ = watched
+    if event == "ring_pages_given_back" and engine.window_allocator is None:
+        pytest.skip("one page group: no ring")
+    assert happened[event] > 0, happened
+    assert not watch.violations, watch.violations[:5]
+
+
+def test_rows_are_edited_not_rebuilt(watched):
+    """The counter says so: far fewer entries written than rows decoded x the
+    table's width, which is what a rebuild a step writes."""
+    _, engine, _, _, stats = watched
+    width = engine.pages_per_request + (engine.window_pages or 0)
+    assert 0 < stats["serve/table_writes"] < stats["serve/decode_rows"] * width / 4
+
+
+@pytest.mark.parametrize("program", ["prefill_chunk", "decode_step"])
+def test_a_call_is_handed_a_copy_of_the_staging_buffer(watched, program):
+    """The runtime may read a numpy argument after the call returns (the CPU
+    backend aliases a 64-byte-aligned one outright), and a chunk that is not a
+    prompt's last is not fetched before the next one fills the buffer: handed
+    the buffer itself, one engine in four served garbage under load."""
+    _, _, watch, _, _ = watched
+    assert watch.calls[program] > 20 and watch.aliased[program] == 0
+
+
+def _tokens(events):
+    return [(e["id"], e["token"]) for e in events if e["type"] == "token"]
+
+
+@pytest.mark.parametrize("config", sorted(BUILD))
+def test_a_prefilling_slots_pages_shown_to_decode_change_served_tokens(config):
+    """The planted fault: the kept table, unmasked. A slot whose prompt is
+    still prefilling goes into `decode_step` with length 0, so its append
+    overwrites position 0 of the request's first page: wrong tokens, no crash."""
+    def serve(fault):
+        engine = BUILD[config](**{**SERVE, "num_blocks": None})
+        decode_jit = engine._decode_jit
+
+        def unmasked(variables, packed, *args, **caches):
+            sent = _split(packed, engine._decode_fields)
+            for slot, request in engine.scheduler.running.items():
+                if not request.decoding:
+                    engine._sync_row(request)
+                    sent["tables"][slot] = engine._tables[slot]
+                    if "window_tables" in sent:
+                        sent["window_tables"][slot] = engine._window_tables[slot]
+            return decode_jit(variables, packed, *args, **caches)
+
+        if fault:
+            engine._decode_jit = unmasked
+        events = engine.submit("first", [3, 17, 42], max_new_tokens=24)
+        for step in range(40):
+            if step == 2:  # five chunks beside the first request's decode steps
+                events += engine.submit("long", list(range(11, 29)), max_new_tokens=12)
+            events += engine.step()
+        return _tokens(events)
+
+    sound, faulty = serve(False), serve(True)
+    assert len(sound) == len(faulty) == 36
+    assert [t for t in sound if t[0] == "first"] == [t for t in faulty if t[0] == "first"]
+    assert [t for t in sound if t[0] == "long"] != [t for t in faulty if t[0] == "long"]

@@ -65,19 +65,11 @@ def _serve_program(program):
     model = Llama(LlamaConfig(**TINY))
     variables = jax.jit(model.init)(jax.random.key(0), np.zeros((1, 4), np.int32))
     engine = ServingEngine(model, variables, ServeConfig(**SERVE))
-    pools = (engine._pool_k, engine._pool_v)
-    pages, key = engine.pages_per_request, jax.random.key(0)
-    if program == "decode_step":
-        rows = SERVE["max_batch"]
-        return engine._decode_jit.lower(
-            variables, jnp.zeros((rows,), jnp.int32), *pools,
-            jnp.zeros((rows, pages), jnp.int32), jnp.zeros((rows,), jnp.int32), key,
-        )
-    row = jnp.zeros((1, SERVE["prefill_chunk"]), jnp.int32)
-    return engine._prefill_jit.lower(
-        variables, row, row, row, *pools, jnp.zeros((1, pages), jnp.int32),
-        jnp.zeros((1,), jnp.int32), jnp.int32(0), key,
+    jitted, packed = (
+        (engine._decode_jit, engine._decode_packed) if program == "decode_step"
+        else (engine._prefill_jit, engine._prefill_packed)
     )
+    return jitted.lower(variables, packed, engine._pool_k, engine._pool_v, engine._rng)
 
 
 def _lowered(program):
