@@ -260,6 +260,15 @@ FAMILY_REGISTRY: tuple[FamilySpec, ...] = (
              sliding_window=16, num_experts=8, num_experts_per_tok=2,
              max_position_embeddings=128),
     ),
+    FamilySpec(
+        "olmo_hybrid", "llm_training_tpu.models.olmo_hybrid", "OlmoHybrid",
+        "llm_training_tpu/models/olmo_hybrid/model.py",
+        dict(vocab_size=128, hidden_size=64, intermediate_size=112,
+             num_hidden_layers=4, num_attention_heads=4, num_key_value_heads=4,
+             linear_num_key_heads=4, linear_num_value_heads=4,
+             linear_key_head_dim=8, linear_value_head_dim=16,
+             max_position_embeddings=128),
+    ),
 )
 
 

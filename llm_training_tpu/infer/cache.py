@@ -28,8 +28,9 @@ KV_LOGICAL_AXES = ("layers", "batch", None, "kv_heads", None)
 # a latent cache's one row a token is shared by the heads: nothing to shard there
 LATENT_LOGICAL_AXES = ("layers", "batch", None, None, None)
 SEG_LOGICAL_AXES = ("batch", None)
-# recurrent slab: state [layers, slots, heads, key_dim, value_dim] and the
-# conv tail [layers, slots, taps, channels]; a slot is a batch row
+# recurrent slab: state [layers, slots, *RecurrentCacheSpec.stored] (heads,
+# key_dim, value_dim; some heads side by side where that fills the chip's
+# tiles) and the conv tail [layers, slots, taps, channels]; a slot is a batch row
 STATE_LOGICAL_AXES = ("layers", "batch", "heads", None, None)
 CONV_LOGICAL_AXES = ("layers", "batch", None, "heads")
 
@@ -85,9 +86,19 @@ def slab_shapes(
 ) -> tuple[tuple[int, ...], tuple[int, ...]]:
     """(state shape, conv-tail shape) for `slots` decode slots."""
     return (
-        (spec.layers, slots, spec.heads, spec.key_dim, spec.value_dim),
+        (spec.layers, slots, *spec.stored),
         (spec.layers, slots, spec.conv_taps, spec.conv_channels),
     )
+
+
+def slab_logical_bytes(spec: RecurrentCacheSpec, slots: int, tail_dtype) -> int:
+    """What the slab HOLDS for `slots` slots: the float32 states as declared
+    (`heads x key_dim x value_dim`) and the tails. What it occupies is its
+    arrays' size, the `decode/state_bytes` gauge: the same where the stored
+    layout fills the chip's tiles."""
+    state = spec.heads * spec.key_dim * spec.value_dim * 4
+    tail = spec.conv_taps * spec.conv_channels * jnp.dtype(tail_dtype).itemsize
+    return spec.layers * slots * (state + tail)
 
 
 def slab_shardings(spec: RecurrentCacheSpec, slots: int, mesh: Mesh, rules):

@@ -22,6 +22,7 @@ from llm_training_tpu.models.hunyuan_moe import HunYuanMoe, HunYuanMoeConfig
 from llm_training_tpu.models.llama import Llama, LlamaConfig
 from llm_training_tpu.models.longcat_flash import LongcatFlash, LongcatFlashConfig
 from llm_training_tpu.models.minimax import MiniMax, MiniMaxConfig
+from llm_training_tpu.models.olmo_hybrid import OlmoHybrid, OlmoHybridConfig
 from llm_training_tpu.models.phi3 import Phi3, Phi3Config
 from llm_training_tpu.models.qwen3_next import Qwen3Next, Qwen3NextConfig
 from llm_training_tpu.models.solar_open2 import SolarOpen2, SolarOpen2Config
@@ -54,6 +55,8 @@ __all__ = [
     "LongcatFlashConfig",
     "MiniMax",
     "MiniMaxConfig",
+    "OlmoHybrid",
+    "OlmoHybridConfig",
     "Phi3",
     "Phi3Config",
     "Qwen3Next",

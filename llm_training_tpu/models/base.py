@@ -161,10 +161,11 @@ class LatentCacheSpec:
 @dataclasses.dataclass(frozen=True)
 class RecurrentCacheSpec:
     """The cache of a stack's linear-attention layers: a fixed slab a decode
-    slot, whatever the request's length: the float32 fast-weight state
-    `[layers, slots, heads, key_dim, value_dim]` and the short convolution's
-    tail `[layers, slots, conv_taps, conv_channels]` (the last pre-conv
-    inputs; `conv_taps` = kernel width - 1)."""
+    slot, whatever the request's length: the float32 fast-weight state, a
+    head `[key_dim, value_dim]`, and the short convolution's tail `[layers,
+    slots, conv_taps, conv_channels]` (the last pre-conv inputs; `conv_taps`
+    = kernel width - 1). The state is STORED `[layers, slots, *stored]`
+    (below), which is what every slab, sharding and gauge is sized from."""
 
     layers: int
     heads: int
@@ -172,6 +173,26 @@ class RecurrentCacheSpec:
     value_dim: int
     conv_taps: int
     conv_channels: int
+
+    @property
+    def abreast(self) -> int:
+        """Heads side by side on the stored state's value axis: as many as
+        make its rows whole 128-lane tiles (the chip lays a float32 array
+        out in tiles of 8 x 128 whatever it is declared as: 192 values a row
+        would occupy 256 lanes, two heads' 384 occupy 384), 1 where the rows
+        are whole already or the head count does not divide by it
+        (`ops/delta_rule.py:pack_heads` is the layout)."""
+        n = 1
+        while (n * self.value_dim) % 128:
+            n += 1
+        return n if self.heads % n == 0 else 1
+
+    @property
+    def stored(self) -> tuple[int, int, int]:
+        """A slot's state of one layer as it is stored: `[heads / abreast,
+        key_dim, abreast * value_dim]`."""
+        n = self.abreast
+        return self.heads // n, self.key_dim, n * self.value_dim
 
 
 @flax.struct.dataclass

@@ -65,10 +65,19 @@ def _slot_rows(slab, slots, fresh):
     return rows
 
 
-def _layer_rows(slab, layer, slots, fresh, read):
+def _layer_rows(slab, layer, slots, fresh, read, in_place=False):
     """`read` (`_slot_rows`) of layer `layer` of the whole slab `[layers,
     slots, ...]`. Picked slots are gathered out of the slab seen as one run
-    of `layers * slots` rows, so the layer's part is not cut out first."""
+    of `layers * slots` rows, so the layer's part is not cut out first.
+    `in_place`: every slot's rows are read through that same view, the one
+    `_put_rows` writes through, so that an elementwise update of them can be
+    written where they lie (the compiler copies the WHOLE slab first when the
+    rows it reads and the rows it writes reach it as two views)."""
+    if slots is None and in_place:
+        per_layer = slab.shape[1]
+        flat = slab.reshape(-1, *slab.shape[2:])
+        mine = jax.lax.dynamic_slice_in_dim(flat, layer * per_layer, per_layer, axis=0)
+        return read(mine, None, fresh)
     if slots is None:
         mine = jax.lax.dynamic_index_in_dim(slab, layer, keepdims=False)
         return read(mine, None, fresh)
@@ -218,21 +227,31 @@ class LayerCache:
         )
         return out, self.replace(k=buffer)
 
-    def recurrent_rows(self, layer, read=_slot_rows):
+    def recurrent_rows(self, layer, read=_slot_rows, in_place=False):
         """`(state [B, ...] float32, conv tail [B, ...])` of recurrent layer
         `layer` for this batch's rows. (`read`: the benchmark's planted fault,
         `benchmarks/tests/test_solar_open2.py`, swaps the slot read by its
-        name in the family's module.)"""
-        return tuple(
-            _layer_rows(slab, layer, self.slots, self.fresh, read)
-            for slab in (self.state, self.conv)
+        name in the family's module.) `in_place`: the caller's new state is
+        an elementwise update of the state it is handed here, and goes back
+        through `put_recurrent_rows(..., in_place=True)`."""
+        return (
+            _layer_rows(self.state, layer, self.slots, self.fresh, read, in_place),
+            _layer_rows(self.conv, layer, self.slots, self.fresh, read),
         )
 
-    def put_recurrent_rows(self, layer, rows) -> "LayerCache":
+    def put_recurrent_rows(self, layer, rows, in_place=False) -> "LayerCache":
+        """`in_place` (a decode step, where row i is slot i): the new state is
+        NOT made whole first; its update fuses with the write into the
+        carried slab, which reads and writes the slab through one view, so
+        the state is written where it lay and no array of a layer's states
+        is made (`tests/test_chip_compile.py` holds that for the v5e)."""
         # the new rows are whole before they go in: fused into the update,
         # their computation reads the slab it writes, and the compiler then
         # copies the whole slab first, once a layer
-        rows = jax.lax.optimization_barrier(rows)
+        if in_place and self.slots is None:
+            rows = (rows[0], jax.lax.optimization_barrier(rows[1]))
+        else:
+            rows = jax.lax.optimization_barrier(rows)
         state, conv = (
             _put_rows(slab, layer, self.slots, new)
             for slab, new in zip((self.state, self.conv), rows)

@@ -44,6 +44,7 @@ _FAMILIES: dict[str, str] = {
     "SolarOpen2Config": "llm_training_tpu.models.solar_open2.hf_conversion",
     "LongcatFlashConfig": "llm_training_tpu.models.longcat_flash.hf_conversion",
     "AfmoeConfig": "llm_training_tpu.models.afmoe.hf_conversion",
+    "OlmoHybridConfig": "llm_training_tpu.models.olmo_hybrid.hf_conversion",
     "MiniMaxConfig": "llm_training_tpu.models.minimax.hf_conversion",
     "BambaConfig": "llm_training_tpu.models.bamba.hf_conversion",
     "Glm4MoeConfig": "llm_training_tpu.models.glm4_moe.hf_conversion",
@@ -370,6 +371,7 @@ _ARCH_TO_FAMILY = {
     "solar_open2": "llm_training_tpu.models.SolarOpen2",  # KDA + gated NoPE GQA, config only
     "longcat_flash": "llm_training_tpu.models.LongcatFlash",  # MLA double layers + zero-compute experts, config only
     "afmoe": "llm_training_tpu.models.Afmoe",  # window and full layers in two page groups, config only
+    "olmo_hybrid": "llm_training_tpu.models.OlmoHybrid",  # gated delta rule (96 x 192 state) + NoPE MHA, config only
     "minimax": "llm_training_tpu.models.MiniMax",  # hybrid lightning attention
     "bamba": "llm_training_tpu.models.Bamba",  # Mamba-2 SSD + attention hybrid
     # sparse MoE variants: stacked-expert MoEMLP block (models/moe.py)

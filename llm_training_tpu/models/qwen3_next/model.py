@@ -41,6 +41,7 @@ from llm_training_tpu.models.qwen3_next.config import Qwen3NextConfig
 from llm_training_tpu.models.remat import remat_policy as _remat_policy
 from llm_training_tpu.models.llama.model import _dense
 from llm_training_tpu.ops import apply_rope, dot_product_attention
+from llm_training_tpu.ops.delta_rule import gated_delta_chunked, l2norm as _l2norm
 from llm_training_tpu.ops.rope_utils import compute_rope_cos_sin, compute_rope_frequencies
 
 
@@ -91,10 +92,6 @@ class GatedRMSNorm(nn.Module):
         ).astype(x.dtype)
 
 
-def _l2norm(x: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
-    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
-
-
 _RESET_LOG_DECAY = -1e4  # exp() underflows to exactly 0.0 in fp32
 
 
@@ -123,96 +120,22 @@ def chunk_gated_delta_rule(
     chunk_size: int = 64,
     reset_decay: jnp.ndarray | None = None,  # [B, S] from segment_reset_decay
 ) -> jnp.ndarray:
-    """Chunked gated delta rule (HF `torch_chunk_gated_delta_rule`), fp32.
-
-    Within each chunk the delta-rule corrections solve a unit-lower-
-    triangular system (the reference's forward-substitution loop); across
-    chunks a `lax.scan` carries the [dk, dv] fast-weight state.
+    """Chunked gated delta rule (HF `torch_chunk_gated_delta_rule`), fp32:
+    q and k L2-normalised a head, q scaled, then the shared rule
+    (`ops/delta_rule.py:gated_delta_chunked`) from a zero state.
     """
     in_dtype = q.dtype
-    q = _l2norm(q.astype(jnp.float32))
+    q = _l2norm(q.astype(jnp.float32)) * (q.shape[-1] ** -0.5)
     k = _l2norm(k.astype(jnp.float32))
-    v = v.astype(jnp.float32)
     g = g.astype(jnp.float32)
-    beta = beta.astype(jnp.float32)
     if reset_decay is not None:
         g = g + reset_decay.astype(jnp.float32)[..., None]
-
-    batch, seq, heads, dk = q.shape
-    dv = v.shape[-1]
-    pad = (-seq) % chunk_size
-    if pad:
-        q, k, v = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0))) for x in (q, k, v))
-        g, beta = (jnp.pad(x, ((0, 0), (0, pad), (0, 0))) for x in (g, beta))
-    nc = (seq + pad) // chunk_size
-    c = chunk_size
-
-    # -> [B, H, nc, c, d]
-    def chunked(x):
-        return x.reshape(batch, nc, c, heads, -1).transpose(0, 3, 1, 2, 4)
-
-    q = chunked(q) * (dk ** -0.5)
-    k = chunked(k)
-    v = chunked(v)
-    g = g.reshape(batch, nc, c, heads).transpose(0, 3, 1, 2)  # [B, H, nc, c]
-    beta = beta.reshape(batch, nc, c, heads).transpose(0, 3, 1, 2)
-
-    v_beta = v * beta[..., None]
-    k_beta = k * beta[..., None]
-
-    g = jnp.cumsum(g, axis=-1)
-    # decay_ij = exp(g_i - g_j) on the lower triangle (i >= j), else 0
-    tril = jnp.tril(jnp.ones((c, c), bool))
-    decay = jnp.where(tril, jnp.exp(g[..., :, None] - g[..., None, :]), 0.0)
-
-    # strictly-lower correction matrix, then T = (I - A)^{-1} via a
-    # triangular solve — the reference computes this with a per-row loop
-    strict = jnp.tril(jnp.ones((c, c), bool), -1)
-    a_mat = jnp.where(
-        strict,
-        -jnp.einsum("bhncd,bhnmd->bhncm", k_beta, k) * decay,
-        0.0,
+    batch, _, heads, dk = q.shape
+    out, _ = gated_delta_chunked(
+        q, k, v.astype(jnp.float32), g, beta.astype(jnp.float32),
+        jnp.zeros((batch, heads, dk, v.shape[-1]), jnp.float32),
+        chunk_size=chunk_size, precision=None,
     )
-    eye = jnp.eye(c, dtype=jnp.float32)
-    t_mat = jax.scipy.linalg.solve_triangular(
-        eye - a_mat, jnp.broadcast_to(eye, a_mat.shape), lower=True, unit_diagonal=True
-    )
-    v_corr = jnp.einsum("bhncm,bhnmd->bhncd", t_mat, v_beta)
-    k_cumdecay = jnp.einsum(
-        "bhncm,bhnmd->bhncd", t_mat, k_beta * jnp.exp(g)[..., None]
-    )
-
-    # [nc, B, H, ...] for the scan over chunks
-    def lead(x):
-        return jnp.moveaxis(x, 2, 0)
-
-    q_s, k_s, v_s, kc_s = lead(q), lead(k), lead(v_corr), lead(k_cumdecay)
-    g_s, decay_s = lead(g), lead(decay)
-
-    def step(state, xs):
-        q_i, k_i, v_i, kc_i, g_i, decay_i = xs
-        attn = jnp.where(
-            tril,
-            jnp.einsum("bhcd,bhmd->bhcm", q_i, k_i) * decay_i,
-            0.0,
-        )
-        v_prime = jnp.einsum("bhcd,bhdv->bhcv", kc_i, state)
-        v_new = v_i - v_prime
-        inter = jnp.einsum("bhcd,bhdv->bhcv", q_i * jnp.exp(g_i)[..., None], state)
-        out_i = inter + jnp.einsum("bhcm,bhmv->bhcv", attn, v_new)
-        g_last = g_i[..., -1]
-        state = state * jnp.exp(g_last)[..., None, None] + jnp.einsum(
-            "bhcd,bhcv->bhdv",
-            k_i * jnp.exp(g_last[..., None] - g_i)[..., None],
-            v_new,
-        )
-        return state, out_i
-
-    init = jnp.zeros((batch, heads, dk, dv), jnp.float32)
-    _, out = jax.lax.scan(step, init, (q_s, k_s, v_s, kc_s, g_s, decay_s))
-    # [nc, B, H, c, dv] -> [B, S, H, dv]
-    out = jnp.moveaxis(out, 0, 2).reshape(batch, heads, nc * c, dv)
-    out = out.transpose(0, 2, 1, 3)[:, :seq]
     return out.astype(in_dtype)
 
 

@@ -49,10 +49,7 @@ from llm_training_tpu.models.remat import remat_policy as _remat_policy
 from llm_training_tpu.models.solar_open2.config import SolarOpen2Config
 from llm_training_tpu.models.solar_open2.kda import kda_chunked, kda_step
 from llm_training_tpu.ops import dot_product_attention
-
-
-def _l2norm(x: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
-    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
+from llm_training_tpu.ops.delta_rule import l2norm as _l2norm, short_conv
 
 
 class KimiDeltaAttention(nn.Module):
@@ -88,31 +85,9 @@ class KimiDeltaAttention(nn.Module):
                 (taps + 1, 3 * width),
                 cfg.param_jnp_dtype,
             ).astype(jnp.float32)
-            # a padded position feeds nothing: not the conv, not the tail
-            mixed = jnp.where(valid[..., None], mixed, 0)
-            tail = (
-                jnp.zeros((batch, taps, 3 * width), mixed.dtype) if rows is None
-                else rows[1].astype(mixed.dtype)
+            (q, k, v), new_tail = short_conv(
+                mixed, conv_w, None if rows is None else rows[1], segment_ids, valid, 3
             )
-            padded = jnp.concatenate([tail, mixed], axis=1)
-            if cut:
-                # a tap never crosses a document boundary; the tail is the
-                # first position's own document (a request's earlier chunk)
-                seg_p = jnp.concatenate(
-                    [jnp.broadcast_to(segment_ids[:, :1], (batch, taps)), segment_ids], axis=1
-                )
-            conv = 0.0
-            for i in range(taps + 1):
-                tap = padded[:, i:i + seq].astype(jnp.float32) * conv_w[i]
-                if cut:
-                    tap = jnp.where((seg_p[:, i:i + seq] == segment_ids)[..., None], tap, 0.0)
-                conv = conv + tap
-            q, k, v = jnp.split(jax.nn.silu(conv), 3, axis=-1)
-            # the last `taps` inputs up to the last real position
-            end = jnp.max(jnp.where(valid, jnp.arange(1, seq + 1), 0), axis=1)
-            new_tail = jax.vmap(
-                lambda row, at: jax.lax.dynamic_slice_in_dim(row, at, taps, axis=0)
-            )(padded, end)
 
         by_head = lambda x: x.reshape(batch, seq, heads, dim)
         q = _l2norm(by_head(q)) * dim ** -0.5

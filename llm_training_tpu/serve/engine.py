@@ -81,7 +81,7 @@ import jax.numpy as jnp
 import numpy as np
 from pydantic import BaseModel, ConfigDict, model_validator
 
-from llm_training_tpu.infer.cache import kv_groups
+from llm_training_tpu.infer.cache import cache_specs, kv_groups, slab_logical_bytes
 from llm_training_tpu.infer.sampling import (
     SamplingConfig,
     sample_tokens_with_logprob,
@@ -270,6 +270,8 @@ class ServingEngine:
         self._cache_bytes = pool_bytes(self._pool_k, self._pool_v)  # outlives close()
         self._latent_pool = self._pool_v is None
         self._state_bytes = 0 if self._slab is None else pool_bytes(*self._slab)
+        self._state_logical_bytes = 0 if self._slab is None else slab_logical_bytes(
+            cache_specs(model_config)[1], self.config.max_batch, self._slab[1].dtype)
         self._window_bytes = (
             0 if self._window_pool is None else pool_bytes(*self._window_pool)
         )
@@ -1283,6 +1285,7 @@ class ServingEngine:
             "decode/global_pool_bytes": float(self._cache_bytes),
             "decode/window_pool_bytes": float(self._window_bytes),
             "decode/state_bytes": float(self._state_bytes),
+            "decode/state_logical_bytes": float(self._state_logical_bytes),
             "decode/latent_pool_bytes": float(
                 self._cache_bytes if self._latent_pool else 0
             ),

@@ -150,14 +150,20 @@ def init_state_slab(model_config, slots: int, mesh=None, rules=None,
     `(state, conv tail)` with one slot a decode row (`infer/cache.py:
     init_state_slab`, from the same `cache_specs` declaration the pool's
     layer count comes from), or None for a stack without such layers.
-    Publishes its footprint as the `decode/state_bytes` gauge."""
+    Publishes its footprint as the `decode/state_bytes` gauge and what it
+    holds as `decode/state_logical_bytes`."""
     from llm_training_tpu.infer import cache
     from llm_training_tpu.telemetry import get_registry
 
     slab = cache.init_state_slab(
         model_config, slots, mesh=mesh, rules=rules, cache_dtype=cache_dtype
     )
-    get_registry().gauge("decode/state_bytes").set(0 if slab is None else pool_bytes(*slab))
+    registry = get_registry()
+    registry.gauge("decode/state_bytes").set(0 if slab is None else pool_bytes(*slab))
+    # what it holds beside what it occupies: the stored layout's padding, if any
+    registry.gauge("decode/state_logical_bytes").set(0 if slab is None else cache.slab_logical_bytes(
+        cache.cache_specs(model_config)[1], slots, slab[1].dtype
+    ))
     return slab
 
 

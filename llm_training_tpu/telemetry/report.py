@@ -279,6 +279,15 @@ def _serving_section(telemetry: dict) -> list[str]:
         if leaked:
             line += f" — {int(leaked)} still held at exit (leak?)"
         lines.append(line)
+    slab = num("decode/state_bytes")
+    if slab:
+        # the second cache kind: a fixed state a decode slot, beside the pages
+        holds = num("decode/state_logical_bytes") or slab
+        lines.append(
+            f"state slab: {slab / 2**20:.1f} MiB stored ({holds / 2**20:.1f} MiB of states and conv tails) "
+            f"beside {(num('decode/cache_bytes') or 0) / 2**20:.1f} MiB of pages, "
+            f"{int(num('serve/state_resets') or 0)} resets"
+        )
     in_place = num("decode/experts_in_place_layers")
     if in_place:
         lines.append(f"expert weights: read in place in {int(in_place)} layers")

@@ -340,7 +340,19 @@ _PRODUCED = {
         "decode": {"pool": 0, "stack": 0, "state": 6},
         "prefill": {"pool": 0, "stack": 0, "state": 3},
     },
+    # six delta-rule layers in two periods: a call site a layer of the
+    # period, each an update of the WHOLE carried slab in place (`_IN_PLACE`
+    # below holds that they are, and that no layer's states are made)
+    "olmoh-serve-longgen": {
+        "decode": {"pool": 0, "stack": 0, "state": 3},
+        "prefill": {"pool": 0, "stack": 0, "state": 3},
+    },
 }
+# cells whose decode step updates a slot's state where it lies
+# (`LayerCache.put_recurrent_rows(in_place=True)`): every array of the slab's
+# shape a program produces is a fusion rooted in a dynamic-update-slice of its
+# own operand, and none has the shape of one layer's states
+_IN_PLACE = {"olmoh-serve-longgen"}
 # the programs' temporaries, GB, which hold that Solar's slab updates ARE in
 # place (one more copy of a layer's states is 0.13 GB, of a pool as much).
 # OLMoE's chunk holds its expert activations (0.013 GB; one layer's expert
@@ -351,6 +363,9 @@ _TEMP_GB = {
     "phi3m-serve-rollout": {"decode": 0.01, "prefill": 0.02},
     "olmoe-serve-rollout": {"decode": 0.01, "prefill": 0.03},
     "solar2-serve-longdoc": {"decode": 0.2, "prefill": 0.2},
+    # one layer's states are 0.071 GB: the step holds none. The chunk's
+    # temporaries are its float32 logits' and the chunked rule's
+    "olmoh-serve-longgen": {"decode": 0.03, "prefill": 1.0},
 }
 _NOT_PRODUCED = (
     "parameter", "bitcast", "get-tuple-element", "tuple", "while", "conditional", "call",
@@ -393,6 +408,27 @@ def _produced(text, pattern):
                 continue
             total += len(re.findall(pattern, rest[: kind.start(1)]))
     return total
+
+
+def _updates_in_place(text, pattern):
+    """Whether every array matching `pattern` that the program produces is
+    the result of a fusion whose root is a dynamic-update-slice: an update of
+    its operand's own memory."""
+    import re
+
+    roots = dict(re.findall(r"^%?([\w.\-]+) \([^\n]*\{\n(?:[^\n]*\n)*?\s*ROOT [^\n]*? ([\w\-]+)\(", text, re.M))
+    for lines in _run_computations(text).values():
+        for line in lines:
+            _, found, rest = line.partition(" = ")
+            kind = re.match(r"(?:\(.*?\)|\S+) ([\w\-]+)\(", rest)
+            if not found or not kind or kind.group(1) in _NOT_PRODUCED:
+                continue
+            if not re.search(pattern, rest[: kind.start(1)]):
+                continue
+            called = re.search(r"calls=%?([\w.\-]+)", rest)
+            if kind.group(1) != "fusion" or not called or roots.get(called.group(1)) != "dynamic-update-slice":
+                return False
+    return True
 
 
 def _kernel_calls(text, kernel, repeats, under=""):
@@ -493,11 +529,27 @@ def _check_serve_program(v5e, cell, program):
     counts.setdefault("stack", 0)
     print(f"{cell} {program}: produced {counts}, temp {memory.temp_size_in_bytes / 1e9:.3f} GB")
     assert counts == _PRODUCED[cell][program], counts
+    if cell in _IN_PLACE:
+        state = slab[0]
+        rest = ",".join(str(d) for d in state.shape[2:])
+        assert _produced(text, rf"f32\[(?:1,)?{state.shape[1]},{rest}\]") == 0  # no layer's states
+        assert _updates_in_place(text, patterns["state"])
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
 def test_solar_open2_serve_cell_compiles_for_v5e(v5e, as_on_tpu, program):
     _check_serve_program(v5e, "solar2-serve-longdoc", program)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_olmo_hybrid_serve_cell_writes_its_state_in_place_for_v5e(v5e, as_on_tpu, program):
+    """`olmoh-serve-longgen`: 8 layers at the published widths fit the chip (12
+    do too; it is the check's reference that stops the cell at 8: PERF.md),
+    `paged_decode` and `paged_prefill` take 30 key/value heads with a group of
+    ONE query head, and the slab (`[6, 32, 15, 96, 384]` float32, two heads
+    abreast) is carried and written in place: the step holds no second array
+    of a layer's states."""
+    _check_serve_program(v5e, "olmoh-serve-longgen", program)
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])

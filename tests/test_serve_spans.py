@@ -455,6 +455,81 @@ def test_state_slab_reports_its_bytes_slots_and_resets(fresh):
     assert engine._slab is None and engine._pool_k is None
 
 
+# ------------------------- a stack whose slab is the larger cache of its layers
+
+TINY_OLMOH = dict(
+    vocab_size=64, hidden_size=36, intermediate_size=48, num_hidden_layers=4,
+    num_attention_heads=6, num_key_value_heads=6,
+    linear_num_key_heads=6, linear_num_value_heads=6,
+    linear_key_head_dim=4, linear_value_head_dim=64, delta_chunk_size=4,
+    attention_impl="xla", compute_dtype="float32", param_dtype="float32",
+)
+
+
+def _olmoh_engine(**serve):
+    from llm_training_tpu.models import OlmoHybrid, OlmoHybridConfig
+
+    model = OlmoHybrid(OlmoHybridConfig(**TINY_OLMOH))
+    variables = jax.jit(model.init)(jax.random.key(0), np.zeros((1, 4), np.int32))
+    return ServingEngine(model, variables, ServeConfig(**{**SERVE, **serve}))
+
+
+def test_gated_delta_stack_names_its_scopes_in_both_programs(fresh):
+    """What the benchmark's per-layer readers match (`/linear_attn/` and
+    inside it `gdn_conv`, `gdn_gates`, `gdn_recurrence` or `gdn_chunk`,
+    `gdn_out`; `/self_attn/`, `/mlp/`, `rms_norm`, `sample`), in the lowered
+    programs of a stack with three delta-rule layers to one softmax layer."""
+    engine = _olmoh_engine()
+    decode = engine._decode_jit.lower(*_decode_args(engine), slab=engine._slab)
+    prefill = engine._prefill_jit.lower(*_prefill_args(engine), slab=engine._slab)
+    assert "jit_decode_step" in decode.as_text()[:200]
+    assert "jit_prefill_chunk" in prefill.as_text()[:200]
+    shared = (
+        "slot3/self_attn/", "slot0/linear_attn/", "slot2/linear_attn/", "linear_attn/gdn_conv",
+        "linear_attn/gdn_gates", "linear_attn/gdn_out", "gdn_out/o_norm/rms_norm", "slot1/mlp/",
+        "self_attn/q_norm/rms_norm", "post_feedforward_layernorm/rms_norm", "/sample",
+    )
+    # the slab's write carries the recurrence's scope: a decode step's update
+    # of the state fuses into it, and a fusion lands where its root does
+    for lowered, own, write, other in (
+        (decode, "linear_attn/gdn_recurrence", "gdn_recurrence/dynamic_update_slice", "gdn_chunk"),
+        (prefill, "linear_attn/gdn_chunk", "gdn_chunk/scatter", "gdn_recurrence"),
+    ):
+        text = lowered.as_text(debug_info=True)
+        for scope in shared + (own, write):
+            assert scope in text, scope
+        assert other not in text
+    # the state rides the programs as it is STORED: two heads abreast, whole tiles
+    assert engine._slab[0].shape == (3, 2, 3, 4, 128)
+    assert "tensor<3x2x3x4x128xf32>" in decode.as_text() and "6x4x64xf32" not in decode.as_text()
+
+
+def test_stored_slab_reports_what_it_occupies_and_what_it_holds(fresh):
+    engine = _olmoh_engine()
+    engine.run(_requests(6))
+    registry = get_registry()
+    # 3 linear layers, 2 slots: 6 heads of 4 x 64 floats (3 rows of 4 x 128) + the conv tail
+    slab_bytes = 3 * 2 * (6 * 4 * 64 * 4 + 3 * 6 * (4 + 4 + 64) * 4)
+    for name in ("decode/state_bytes", "decode/state_logical_bytes"):
+        assert registry.gauge(name).value == slab_bytes
+        assert engine.stats()[name] == slab_bytes
+    assert registry.gauge("decode/state_slots_in_use").value == 0  # drained
+    assert registry.counter("serve/state_resets").value == 3
+    steps = [e["args"] for e in fresh.snapshot() if e.get("ph") == "X" and e["name"] == "engine_step"]
+    assert sum(a["state_resets"] for a in steps) == 3
+    assert max(a["state_slots_in_use"] for a in steps) == 2
+    # `report` prints the slab beside the pool
+    from llm_training_tpu.telemetry.report import _serving_section
+
+    lines = _serving_section({k: v for k, v in engine.stats().items()} | {
+        "serve/state_resets": registry.counter("serve/state_resets").value,
+    })
+    slab_line = next(line for line in lines if line.startswith("state slab:"))
+    assert f"{slab_bytes / 2**20:.1f} MiB" in slab_line and "beside" in slab_line and "3 resets" in slab_line
+    engine.close()
+    assert engine._slab is None and engine._pool_k is None
+
+
 # --------------------------------------------- a stack with a latent cache
 
 TINY_LONGCAT = dict(
