@@ -585,10 +585,12 @@ def _run_serve(args, config: dict) -> int:
 
     open_stdin = True
     rc = 0
-    while open_stdin or not engine.scheduler.idle:
+    # (`engine.idle`, not the scheduler's: the engine runs a call ahead of
+    # what it has returned, and the step after the last one reads its tokens)
+    while open_stdin or not engine.idle:
         if shutdown.requested:
             break
-        if engine.scheduler.idle:
+        if engine.idle:
             if watchdog is not None:
                 # a quiet server is healthy, not hung: the engine-step
                 # beat only moves under traffic
@@ -617,7 +619,7 @@ def _run_serve(args, config: dict) -> int:
         deadline = _time.monotonic() + args.drain_timeout_s
         while True:
             flush_delivered()
-            if engine.scheduler.idle or _time.monotonic() >= deadline:
+            if engine.idle or _time.monotonic() >= deadline:
                 break
             emit(engine.step())
             profile_trigger.poll(engine._step_index)
@@ -625,6 +627,9 @@ def _run_serve(args, config: dict) -> int:
                 watchdog.beat(step=engine._step_index)
         _time.sleep(0.05)  # let a mid-read reader line land in the queue
         flush_delivered()
+        # the last call's tokens go to their clients; what drain() would read
+        # itself it could only journal as not streamed
+        emit(engine.flush())
         engine.drain()
         rc = RESUMABLE_EXIT_CODE
 

@@ -274,6 +274,9 @@ class RolloutCollector:
                             prompts=len(prompts), group=self.group_size):
             for step in range(max_steps):
                 if should_stop is not None and should_stop():
+                    # the last call's tokens: the caller drains next, and
+                    # what it reads itself nobody is handed
+                    self._route(self.engine.flush())
                     break
                 while queue and not self._slo_gate():
                     prompt_idx, prompt = queue.pop(0)

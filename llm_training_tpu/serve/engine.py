@@ -14,14 +14,25 @@ of a pool's shape; docs/serving.md, "How the cache is carried and appended"):
   length, no shared append index, no left padding. Idle slots carry the
   trash-block table and cost one garbage row.
 
+The token a row feeds to its next decode step never visits the host on the
+way: both programs return `_last_tokens` (`[max_batch]` int32, on the device,
+never donated) updated, and `decode_step` reads its input ids from it. So the
+engine runs ONE STEP AHEAD OF ITS FETCHES (docs/serving.md, "A step ahead of
+its fetches"): `step()` n enqueues its chunk and its decode step, THEN reads
+and emits what step n-1 enqueued, while the chip runs on. What needs no
+token's value (`cache_len`, the window's pages, the finish by length) is
+booked when a call is enqueued, so the scheduler sees what it would have seen;
+what needs one reads first (`flush()`: an eviction, a running row's deadline,
+`reload_weights`, `drain`, `close`), and an `eos` is seen a call late (its
+row's extra step is dropped and counted).
+
 A stack with latent attention (`LatentCacheSpec`) has ONE pool, a latent row
 a token: `_pool_k` is that pool and `_pool_v` is None, through both programs
 (`decode/latent_pool_bytes`). Such a stack, holding a share of its experts,
 also counts each call's expert assignments on the device (`CausalLMOutput.
-moe_assignments`): the programs add them to a three-number int32 carry that
-stays on the device, and a decode step returns the carry as an output of its
-own, which the host reads in the `device_get` that fetches the step's tokens
-(no sync of its own) and feeds to `serve/moe_{held,zero,elsewhere}_assignments`.
+moe_assignments`): each call returns its three int32 counts as an output of
+its own, which the host reads in the `device_get` that fetches the call's
+tokens (no sync of its own) and adds to `serve/moe_{held,zero,elsewhere}_assignments`.
 
 A stack with linear-attention layers (`infer/cache.py:cache_specs`) has a
 second cache beside the pool: the STATE SLAB, a fixed float32 state and a
@@ -43,8 +54,9 @@ a page given back names the trash block.
 
 The host loop (`step()`) executes what the `Scheduler` decides: admission
 when free blocks suffice, one prefill chunk interleaved between decode
-steps, eviction/requeue under block pressure, slot recycling on eos /
-max-tokens. Per-request TTFT/TPOT and engine throughput publish as
+steps, eviction/requeue under block pressure, slot recycling on max-tokens
+(at the enqueue of a request's last call) / eos (when the host reads it).
+Per-request TTFT/TPOT and engine throughput publish as
 `serve/*` gauges (rendered by `report`'s `== Serving ==` section).
 
 Resilience seams (docs/serving.md#resilience):
@@ -133,6 +145,23 @@ def _split(packed, fields: dict[str, tuple[int, ...]]) -> dict:
         out[name] = packed[at:at + size].reshape(shape)
         at += size
     return out
+
+
+@dataclass(slots=True)
+class _InFlight:
+    """One enqueued call's outputs that the host has not read: the step that
+    enqueued it, the rows it makes a token for as (request, slot) in the order
+    they are emitted (a decode step's rows; a prompt's last chunk's one;
+    none for any other chunk, which is kept only for its counts), and the
+    device arrays: the engine's token carry as the call left it, the chosen
+    tokens' log-probabilities ([max_batch], or a chunk's scalar) and a
+    counting stack's three expert-assignment counts."""
+
+    step: int
+    rows: list[tuple[ServeRequest, int]]
+    tokens: Any
+    logprobs: Any
+    moe: Any
 
 
 @dataclass(slots=True)
@@ -294,6 +323,11 @@ class ServingEngine:
                 self.window_allocator, self.sliding_window, self.window_pages
             ),
         )
+        # a stack that counts its expert assignments (`CausalLMOutput.
+        # moe_assignments`: held here, zero-compute, held elsewhere): every
+        # call returns its own three counts, read with the call's tokens
+        self._counts_experts = bool(getattr(model_config, "counts_expert_assignments", False))
+        self.scheduler.before_evict = lambda: self._flush("eviction")
         self._build_tables()
         self._build_programs()
         # a profiler capture of this process holds step()'s spans beside the
@@ -302,15 +336,20 @@ class ServingEngine:
         # the running step's counts: filled where the work is decided, closed
         # into the engine_step span and the serve/* counters by step()
         self._step_counts: dict[str, int] = {}
-        # for a stack that counts its expert assignments (`CausalLMOutput.
-        # moe_assignments`: held here, zero-compute, held elsewhere): what the
-        # calls since the last decode fetch counted, carried on the device
-        # (zeros again after a fetch: one array made here, never donated).
-        # None for every other stack
-        self._moe_zero = self._moe_carry = (
-            jnp.zeros((3,), jnp.int32)
-            if getattr(model_config, "counts_expert_assignments", False) else None
-        )
+        # the token each decode slot feeds to its next decode step, on the
+        # device: both programs return it updated (a decode step the sampled
+        # tokens of the rows that decoded, a chunk its token at its slot), so
+        # a token never visits the host on its way into the next call. Never
+        # donated: the array a call returned is what the host fetches later,
+        # and it must outlive the next call
+        self._last_tokens = jnp.zeros((self.config.max_batch,), jnp.int32)
+        # enqueued calls whose outputs the host has not read, oldest first:
+        # when a step begins, at most the calls of the step before
+        self._in_flight: list[_InFlight] = []
+        # events made and not yet returned: what the running step has made so
+        # far, and what a flush inside `reload_weights`, `drain` or `close`
+        # left for the next `step()` or `flush()` to return
+        self._events: list[dict] = []
         self._rng = jax.random.key(self.config.seed)
         self._call = 0
         self._t0: float | None = None
@@ -414,8 +453,9 @@ class ServingEngine:
 
         prefill_fields, decode_fields = self._prefill_fields, self._decode_fields
         last_position = self.config.max_model_len - 1
+        counts_experts = self._counts_experts
 
-        def prefill_chunk(variables, packed, pool_k, pool_v, rng, slab=None, moe=None,
+        def prefill_chunk(variables, packed, pool_k, pool_v, rng, last_tokens, slab=None,
                           window_pool=None):
             sent = _split(packed, prefill_fields)
             tokens, start = sent["tokens"], sent["start"]
@@ -443,30 +483,33 @@ class ServingEngine:
                     logits[None], jax.random.fold_in(rng, sent["call"]), sampling
                 )
             state = out.decode_state
-            # the chunk's expert assignments join the carry, on the device
-            moe = None if moe is None else moe + out.moe_assignments
+            # the slot's next decode step reads the token from here: only a
+            # prompt's LAST chunk is followed by one, and it writes last
+            last_tokens = last_tokens.at[sent["slot"]].set(token[0])
+            moe = out.moe_assignments if counts_experts else None
             return with_window(
-                (state.k, state.v, token[0], logprob[0], slab_of(state), moe), state
+                (state.k, state.v, last_tokens, logprob[0], slab_of(state), moe), state
             )
 
-        def decode_step(variables, packed, pool_k, pool_v, rng, slab=None, moe=None,
+        def decode_step(variables, packed, pool_k, pool_v, rng, last_tokens, slab=None,
                         window_pool=None):
             sent = _split(packed, decode_fields)
-            tokens, lengths = sent["tokens"], sent["lengths"]
+            lengths = sent["lengths"]
             state = PagedDecodeState(
                 k=pool_k, v=pool_v, block_tables=sent["tables"], lengths=lengths,
                 rope_length=rope_length, **slab_fields(slab),
                 **window_fields(window_pool, sent.get("window_tables")),
             )
             # row i is slot i. A slot that does not decode this step (idle, or
-            # its prompt still prefilling) has length 0 here: segment 0, so
-            # its state and tail come out as they went in, and a stack that
-            # counts its expert assignments leaves it out
-            rows = {} if slab is None and moe is None else {
-                "segment_ids": (lengths > 0).astype(jnp.int32)[:, None]
+            # its prompt still prefilling) has length 0 here: token 0 and
+            # segment 0, so its state and tail come out as they went in, and a
+            # stack that counts its expert assignments leaves it out
+            alive = lengths > 0
+            rows = {} if slab is None and not counts_experts else {
+                "segment_ids": alive.astype(jnp.int32)[:, None]
             }
             out = model.apply(
-                variables, input_ids=tokens[:, None],
+                variables, input_ids=jnp.where(alive, last_tokens, 0)[:, None],
                 position_ids=lengths[:, None], decode_state=state, **rows,
             )
             logits = out.logits[:, -1].astype(jnp.float32)
@@ -475,9 +518,11 @@ class ServingEngine:
                     logits, jax.random.fold_in(rng, sent["call"]), sampling
                 )
             state = out.decode_state
-            # the carry and this step's assignments: fetched with the tokens
-            moe = None if moe is None else moe + out.moe_assignments
-            return with_window((state.k, state.v, token, logprob, slab_of(state), moe), state)
+            last_tokens = jnp.where(alive, token, last_tokens)
+            moe = out.moe_assignments if counts_experts else None
+            return with_window(
+                (state.k, state.v, last_tokens, logprob, slab_of(state), moe), state
+            )
 
         # the function names ARE the programs' names (`jit_prefill_chunk`,
         # `jit_decode_step` in HLO module names and in a device profile):
@@ -502,9 +547,7 @@ class ServingEngine:
         self._window_tables = None if window is None else np.zeros((batch, window), np.int32)
         self._held: list[_KeptRow | None] = [None] * batch
         self._alive = np.zeros((batch, 1), bool)
-        self._decode_fields = {
-            "tokens": (batch,), "lengths": (batch,), "call": (), "tables": (batch, pages),
-        }
+        self._decode_fields = {"lengths": (batch,), "call": (), "tables": (batch, pages)}
         self._prefill_fields = {
             "ids": (1, self.config.prefill_chunk), "tokens": (), "start": (), "call": (),
             "slot": (), "fresh": (), "tables": (1, pages),
@@ -682,6 +725,16 @@ class ServingEngine:
         self.journal = journal
         self._journal_every = max(1, int(every))
 
+    def _journal_in_flight(self) -> None:
+        """Checkpoint the requests an unread call makes a token for, as their
+        clients have them: among them the ones that finished by length when
+        that call was enqueued, which are in no queue any more and whose
+        terminal waits for it."""
+        if self.journal is not None:
+            for call in self._in_flight:
+                for request, _ in call.rows:
+                    self.journal.progress(request)
+
     def _retire_finished(self) -> None:
         """Write the deferred `done` records for terminals the caller has
         had a chance to deliver (everything built before this step)."""
@@ -724,6 +777,9 @@ class ServingEngine:
                     f"{getattr(old_leaf, 'shape', None)}/"
                     f"{getattr(old_leaf, 'dtype', None)})"
                 )
+        # the tokens in flight were made under the old weights: read first, so
+        # they carry the old generation and fold into the requeued prompts
+        self._flush("reload_weights")
         evicted = 0
         for request in list(self.scheduler.running.values()):
             self.scheduler.evict(request)
@@ -749,6 +805,12 @@ class ServingEngine:
         summary for the drain trace event."""
         # the drain caller has emitted every returned event by now
         self._retire_finished()
+        # what the last call made is journaled with the rest. A caller that
+        # wants those tokens streamed takes them with `flush()` BEFORE it
+        # drains; what is read here has nobody to go to, so it is journaled
+        # as generated and not streamed, and the relaunch streams it
+        self._journal_in_flight()
+        self._flush("drain", stream=False)
         for request in list(self.scheduler.running.values()):
             self.scheduler.evict(request)
         journaled = 0
@@ -775,6 +837,7 @@ class ServingEngine:
         `stats()` still answers. Idempotent."""
         if self._pool_k is None:
             return
+        self._flush("close")
         jax.block_until_ready((self._pool_k, self._pool_v, self._slab, self._window_pool))
         for buffer in (self._pool_k, self._pool_v, *(self._slab or ()),
                        *(self._window_pool or ())):
@@ -786,11 +849,13 @@ class ServingEngine:
 
     def step(self) -> list[dict]:
         """One scheduler round: deadline expiry, admissions, shedding, at
-        most one prefill chunk, one decode step over every decoding row.
-        Returns the streamed events ({'type': 'token', ...} per new token,
-        {'type': 'done', ...} per completion — deadline/overloaded
-        terminations included)."""
-        events: list[dict] = []
+        most one prefill chunk and one decode step over every decoding row,
+        both ENQUEUED; then the outputs of the calls the step before enqueued
+        are read and emitted, while this step's run. Returns the streamed
+        events ({'type': 'token', ...} per new token, {'type': 'done', ...}
+        per completion — deadline/overloaded terminations included): a token
+        leaves in the step after the one whose call made it, and `flush()`
+        gives a caller that stops stepping the last ones."""
         tracer = get_tracer()
         self._step_index += 1
         step = self._step_index
@@ -802,6 +867,11 @@ class ServingEngine:
             "decode_rows": 0, "live_tokens": 0,
             # block-table entries the host wrote, both groups (`_sync_row`)
             "table_writes": 0,
+            # the step ahead of its fetches: 1 where a call was enqueued while
+            # an earlier step's outputs were unread, the times the host had to
+            # read before it could go on, and the rows decoded for nothing (a
+            # request that had stopped at `eos` a call earlier)
+            "steps_ahead": 0, "pipeline_flushes": 0, "discarded_row_steps": 0,
         }
         if self._slab is not None:
             counts["state_resets"] = 0
@@ -824,11 +894,15 @@ class ServingEngine:
                 # watermarks before this step can wedge or die. Journaling
                 # either at build time would let a death between build and
                 # flush lose a terminal (or skip re-streaming tokens the
-                # client never saw).
-                self._retire_finished()
-                if self.journal is not None and step % self._journal_every == 0:
-                    for request in self.scheduler.running.values():
-                        self.journal.progress(request)
+                # client never saw): so not while events that a flush between
+                # two steps left behind wait to be returned. A token in flight
+                # is in no record: a replay makes it again.
+                if not self._events:
+                    self._retire_finished()
+                    if self.journal is not None and step % self._journal_every == 0:
+                        for request in self.scheduler.running.values():
+                            self.journal.progress(request)
+                        self._journal_in_flight()
                 # chaos serve faults (docs/resilience.md#chaos): a wedged
                 # step and a mid-stream SIGTERM are injected exactly where
                 # the real ones land — the top of an engine step, heartbeat
@@ -838,11 +912,18 @@ class ServingEngine:
                     chaos.maybe_serve_stall(step)
                     chaos.maybe_serve_sigterm_mid_stream(step)
             with tracer.measure("serve", "schedule", **child):
-                before = len(self.scheduler.completed)
                 # deadlines first: expired queued work never costs a FLOP
                 # and an expired decode row frees its blocks before
-                # admission looks at the pool
-                self.scheduler.expire_deadlines()
+                # admission looks at the pool. Its terminal carries its
+                # tokens: the ones in flight are read first
+                now = time.perf_counter()
+                if any(
+                    r.in_flight and r.deadline_s is not None and now >= r.deadline_s
+                    for r in self.scheduler.running.values()
+                ):
+                    self._flush("deadline")
+                before = len(self.scheduler.completed)
+                self.scheduler.expire_deadlines(now)
                 self.scheduler.admit()
                 # the service-time EMA moves with every completion, so the
                 # projected-TTFT shed decision is re-evaluated each step too
@@ -851,18 +932,21 @@ class ServingEngine:
                 # are completions — the protocol owes each a done chunk like
                 # any other
                 for request in self.scheduler.completed[before:]:
-                    events.append(self._done_event(request))
+                    self._events.append(self._done_event(request))
                 self.peak_running = max(
                     self.peak_running, len(self.scheduler.running)
                 )
                 plan = self.scheduler.next_prefill()
             if plan is not None:
-                events.extend(self._run_prefill(*plan))
+                self._run_prefill(*plan)
             # after the prefill: a prompt completed this step decodes this step
             with tracer.measure("serve", "schedule", **child):
                 rows = self.scheduler.decode_rows()
             if rows:
-                events.extend(self._run_decode(rows))
+                self._run_decode(rows)
+            # the chip has this step's calls to run: now read the step
+            # before's, which are done or nearly
+            self._read([call for call in self._in_flight if call.step < step])
             if self._slab is not None:
                 # slots whose state belongs to a live request after this step
                 counts["state_slots_in_use"] = len(self.scheduler.running)
@@ -878,9 +962,6 @@ class ServingEngine:
             registry.gauge("decode/state_slots_in_use").set(counts["state_slots_in_use"])
             if counts["state_resets"]:
                 registry.counter("serve/state_resets").inc(counts["state_resets"])
-        for kind in ("held", "zero", "elsewhere"):
-            if counts.get(f"moe_{kind}"):
-                registry.counter(f"serve/moe_{kind}_assignments").inc(counts[f"moe_{kind}"])
         for name in ("window_live_tokens", "window_pages_released"):
             if counts.get(name):
                 registry.counter(f"serve/{name}").inc(counts[name])
@@ -890,15 +971,98 @@ class ServingEngine:
             registry.counter("serve/decode_steps").inc()
             registry.counter("serve/decode_rows").inc(counts["decode_rows"])
             registry.counter("serve/live_tokens").inc(counts["live_tokens"])
+        return self._take_events()
+
+    # ------------------------------------------------- a step ahead of its fetches
+
+    def flush(self) -> list[dict]:
+        """Read what the enqueued calls made and return every event not yet
+        returned: for a caller that stops stepping (its last tokens are one
+        call behind), and after `reload_weights`, `drain` or `close`, which
+        read for themselves and leave their events here. After it the
+        engine's books are those of an engine that read every call at once;
+        `flush()` after every `step()` IS that engine."""
+        self._flush("caller")
+        return self._take_events()
+
+    @property
+    def idle(self) -> bool:
+        """Nothing queued, nothing running, and no token or event still
+        owed: what a loop that steps until the work is done waits for (the
+        scheduler alone is idle one call before the last tokens are read)."""
+        return self.scheduler.idle and not self._in_flight and not self._events
+
+    def _take_events(self) -> list[dict]:
+        events, self._events = self._events, []
         return events
 
+    def _flush(self, reason: str, stream: bool = True) -> bool:
+        """The host needs a token's value (or stops): read every call in
+        flight, the running step's too. False when there was none."""
+        if not self._in_flight:
+            return False
+        self._count("pipeline_flushes")
+        with get_tracer().measure(
+            "serve", "pipeline_flush", step=self._step_index, write=False, reason=reason,
+        ):
+            self._read(list(self._in_flight), stream)
+        return True
+
+    def _count(self, name: str) -> None:
+        """One of the step ahead's three counts, which can move outside a
+        step (a flush between two): into the registry at once, and into the
+        closing args of the step that is running or ran last."""
+        get_registry().counter(f"serve/{name}").inc()
+        self._step_counts[name] = self._step_counts.get(name, 0) + 1
+
+    def _enqueued(self, rows: list[tuple[ServeRequest, int]], tokens, logprobs, moe) -> None:
+        """The books of a call just enqueued, which need no token's value:
+        each row's token is in flight, and a request whose last token it is
+        gives its slot and pages back now (what the device still does with
+        them lies in front of any later call in its queue). The done EVENT
+        waits for the tokens."""
+        if (self._in_flight and self._in_flight[0].step < self._step_index
+                and not self._step_counts["steps_ahead"]):
+            self._count("steps_ahead")
+        if rows or moe is not None:
+            self._in_flight.append(_InFlight(self._step_index, rows, tokens, logprobs, moe))
+        for request, _ in rows:
+            request.in_flight += 1
+            if len(request.generated) + request.in_flight >= request.max_new_tokens:
+                self.scheduler.finish(request, "max_tokens")
+
+    def _read(self, calls: list[_InFlight], stream: bool = True) -> None:
+        """Fetch the given calls' outputs (the oldest in flight) in one
+        `device_get` and emit their tokens in the order they were enqueued
+        (`stream` False: into `generated` only, no event made: `drain`)."""
+        if not calls:
+            return
+        child = {"step": self._step_index, "write": False}
+        del self._in_flight[: len(calls)]
+        # the wait for the device, where the newest of them still runs
+        with get_tracer().measure("serve", "decode_fetch", **child):
+            fetched = jax.device_get([(c.tokens, c.logprobs, c.moe) for c in calls])
+        with get_tracer().measure("serve", "decode_emit", **child):
+            for call, (tokens, logprobs, moe) in zip(calls, fetched):
+                if moe is not None:
+                    for kind, n in zip(("held", "zero", "elsewhere"), moe):
+                        get_registry().counter(f"serve/moe_{kind}_assignments").inc(int(n))
+                for request, slot in call.rows:
+                    self._emit_token(
+                        request, int(tokens[slot]),
+                        float(logprobs[slot] if logprobs.ndim else logprobs), stream,
+                    )
+
     def _emit_token(
-        self,
-        request: ServeRequest,
-        token: int,
-        events: list[dict],
-        logprob: float | None = None,
+        self, request: ServeRequest, token: int, logprob: float, stream: bool = True
     ) -> None:
+        request.in_flight -= 1
+        if request.stop_reason == "eos":
+            # it had stopped a call earlier, which the host saw only after
+            # this row was enqueued: a row-step for nothing, its write in a
+            # page (and slot) the request still held then
+            self._count("discarded_row_steps")
+            return
         now = time.perf_counter()
         request.generated.append(token)
         # parallel to `generated`: the chosen token's logprob under the
@@ -920,8 +1084,8 @@ class ServingEngine:
         # an evicted-then-resumed request regenerates nothing (its progress
         # rode along in the re-prefill), so every append past `emitted` is
         # genuinely new — emit it
-        while request.emitted < len(request.generated):
-            events.append({
+        while stream and request.emitted < len(request.generated):
+            self._events.append({
                 "type": "token", "id": request.id,
                 "token": request.generated[request.emitted],
                 "logprob": request.logprobs[request.emitted],
@@ -933,14 +1097,20 @@ class ServingEngine:
             request.emitted += 1
         eos = self.config.eos_token_id
         if eos is not None and token == eos:
-            self.scheduler.finish(request, "eos")
-            events.append(self._done_event(request))
-        elif len(request.generated) >= request.max_new_tokens:
-            self.scheduler.finish(request, "max_tokens")
-            events.append(self._done_event(request))
+            if request.done:
+                # its length ran out at the enqueue of a later call, whose
+                # token is dropped when it comes
+                request.stop_reason = "eos"
+            else:
+                self.scheduler.finish(request, "eos")
+            finished = True
+        else:
+            # by length: it finished when its last call was enqueued
+            finished = len(request.generated) >= request.max_new_tokens
+        if finished and stream:
+            self._events.append(self._done_event(request))
 
-    def _run_prefill(self, request: ServeRequest, chunk: list[int], start: int) -> list[dict]:
-        events: list[dict] = []
+    def _run_prefill(self, request: ServeRequest, chunk: list[int], start: int) -> None:
         tracer = get_tracer()
         ids = {"step": self._step_index, "request_id": request.id}
         final = start + len(chunk) >= len(request.prefill_tokens)
@@ -957,8 +1127,8 @@ class ServingEngine:
             "serve", "prefill_chunk", write=request.traced, **ids,
             start=start, tokens=len(chunk), final=final,
         ):
-            # inputs and the enqueue; the device's time shows in prefill_fetch
-            # (a final chunk) or in the next decode_fetch
+            # inputs and the enqueue; the device's time shows in a later
+            # decode_fetch
             with tracer.measure("serve", "prefill_dispatch", write=False, **ids):
                 self._sync_row(request)
                 sent = self._prefill_sent
@@ -972,36 +1142,30 @@ class ServingEngine:
                 sent["fresh"][...] = fresh
                 sent["tables"][0] = self._tables[request.slot]
                 caches = {} if self._slab is None else {"slab": self._slab}
-                if self._moe_carry is not None:
-                    caches["moe"] = self._moe_carry
                 if self._window_pool is not None:
                     caches["window_pool"] = self._window_pool
                     sent["window_tables"][0] = self._window_tables[request.slot]
                 # a COPY of the staging buffer travels with the call, the one
                 # transfer: the runtime may read a numpy argument after the
                 # call returns (the CPU backend aliases an aligned one
-                # outright), and the next chunk fills the buffer before this
-                # one is fetched
-                (self._pool_k, self._pool_v, token, logprob, self._slab,
-                 self._moe_carry) = self._take_window_pool(self._prefill_jit(
+                # outright), and the next chunk fills the buffer while this
+                # one may still be in the device's queue
+                (self._pool_k, self._pool_v, self._last_tokens, logprob, self._slab,
+                 moe) = self._take_window_pool(self._prefill_jit(
                     self.variables, self._prefill_packed.copy(), self._pool_k, self._pool_v,
-                    self._rng, **caches,
+                    self._rng, self._last_tokens, **caches,
                 ))
             request.prefilled += len(chunk)
             request.cache_len += len(chunk)
             self._release_window(request)
-            if final:
-                with tracer.measure("serve", "prefill_fetch", write=False, **ids):
-                    host_token, host_logprob = jax.device_get((token, logprob))
-                self._emit_token(
-                    request, int(host_token), events, logprob=float(host_logprob)
-                )
+            # the prompt's last chunk makes the first new token: in flight
+            self._enqueued(
+                [(request, request.slot)] if final else [], self._last_tokens, logprob, moe
+            )
         if final and not request.done:
-            # the first new token landed inside the prefill phase; decode
-            # (one token per engine step) starts here: at this call's own
-            # clock reading, a few microseconds after prefill_chunk closed
+            # decode (one token per engine step) starts here: at this call's
+            # own clock reading, a few microseconds after prefill_chunk closed
             request.advance_phase("decode")
-        return events
 
     def _take_window_pool(self, outs: tuple) -> tuple:
         """A program's outputs less the window group's pool, which a stack
@@ -1012,21 +1176,21 @@ class ServingEngine:
         return outs
 
     def _release_window(self, request: ServeRequest) -> None:
-        """After a chunk or a decode step: the request's pages of the window
-        group that no later token reads go back to that group's allocator."""
+        """After a chunk or a decode step is enqueued: the request's pages of
+        the window group that no later token reads go back to that group's
+        allocator."""
         if self.window_allocator is not None:
             self._step_counts["window_pages_released"] += (
                 self.scheduler.release_window(request)
             )
 
-    def _run_decode(self, rows: list[ServeRequest]) -> list[dict]:
-        events: list[dict] = []
+    def _run_decode(self, rows: list[ServeRequest]) -> None:
         tracer = get_tracer()
         child = {"step": self._step_index, "write": False}
         with tracer.measure("serve", "decode_blocks", **child):
             # grow each row's blocks for this step's write; under pool
             # pressure this evicts lowest-priority requests (possibly out of
-            # `rows`)
+            # `rows`), after the tokens in flight are read
             survivors = []
             for request in rows:
                 if request.slot is not None and self.scheduler.ensure_decode_blocks(request):
@@ -1037,16 +1201,14 @@ class ServingEngine:
             # evictor, so it must not decode this step
             survivors = [r for r in survivors if r.slot is not None]
         if not survivors:
-            return events
+            return
         with tracer.measure("serve", "decode_inputs", **child):
             sent, alive = self._decode_sent, self._alive
-            tokens, lengths = sent["tokens"], sent["lengths"]
-            tokens[:] = 0
+            lengths = sent["lengths"]
             lengths[:] = 0
             alive[:] = False
             for request in survivors:
                 self._sync_row(request)
-                tokens[request.slot] = request.generated[-1]
                 lengths[request.slot] = request.cache_len
                 alive[request.slot] = True
             # a slot that does not decode this step (idle, its prompt still
@@ -1061,8 +1223,6 @@ class ServingEngine:
             # new token
             self._step_counts["live_tokens"] = int(lengths.sum()) + len(survivors)
             step_slab = {} if self._slab is None else {"slab": self._slab}
-            if self._moe_carry is not None:
-                step_slab["moe"] = self._moe_carry
             if self._window_pool is not None:
                 step_slab["window_pool"] = self._window_pool
                 np.multiply(self._window_tables, alive, out=sent["window_tables"])
@@ -1070,39 +1230,26 @@ class ServingEngine:
                 self._step_counts["window_live_tokens"] = sum(
                     min(r.cache_len + 1, self.sliding_window) for r in survivors
                 )
-            # (a copy, as a chunk's: what a call was handed is never written again)
+            # (a copy, as a chunk's: what a call was handed is never written
+            # again; each row's token is on the device already)
             step_args = (
                 self.variables, self._decode_packed.copy(), self._pool_k, self._pool_v, self._rng,
+                self._last_tokens,
             )
         if not self._decode_attr_done:
             # before the donating call below: lowering only reads avals,
             # while the jit consumes the pool buffers
             self._decode_attr_done = True
             self._publish_decode_attribution(step_args, step_slab)
-        # the enqueue alone, then the wait for the device: a step that reads
-        # far off shows in which of the two its seconds went
+        # the enqueue and the books that need no token's value: a step that
+        # reads far off shows whether its seconds went here or in the wait
         with tracer.measure("serve", "decode_dispatch", **child):
-            self._pool_k, self._pool_v, out, out_lp, self._slab, moe = self._take_window_pool(
-                self._decode_jit(*step_args, **step_slab)
-            )
-        with tracer.measure("serve", "decode_fetch", **child):
-            host, host_lp, moe = jax.device_get((out, out_lp, moe))
-        with tracer.measure("serve", "decode_emit", **child):
-            host = np.asarray(host)
-            host_lp = np.asarray(host_lp)
-            if moe is not None:
-                # the assignments counted since the last fetch
-                for kind, n in zip(("held", "zero", "elsewhere"), moe):
-                    self._step_counts[f"moe_{kind}"] = int(n)
-                self._moe_carry = self._moe_zero
+            (self._pool_k, self._pool_v, self._last_tokens, logprobs, self._slab,
+             moe) = self._take_window_pool(self._decode_jit(*step_args, **step_slab))
             for request in survivors:
                 request.cache_len += 1
                 self._release_window(request)
-                self._emit_token(
-                    request, int(host[request.slot]), events,
-                    logprob=float(host_lp[request.slot]),
-                )
-        return events
+            self._enqueued([(r, r.slot) for r in survivors], self._last_tokens, logprobs, moe)
 
     def _publish_decode_attribution(self, step_args, step_slab) -> None:
         """AOT-lower the decode step against the first real batch's avals
@@ -1183,6 +1330,7 @@ class ServingEngine:
             events.extend(self.step())
         else:
             raise RuntimeError(f"serve loop not drained after {max_steps} steps")
+        events.extend(self.flush())  # the last call's tokens, a call behind
         return events
 
     # --------------------------------------------------------------- stats
@@ -1311,8 +1459,12 @@ class ServingEngine:
         # what step() counted (counters already: read into the summary, not
         # published twice): the rows decoded and the block-table entries the
         # host wrote for them, `rows / block_size` where nothing else happens
-        counted = ["serve/steps", "serve/decode_rows", "serve/table_writes"]
-        if self._moe_carry is not None:
+        counted = [
+            "serve/steps", "serve/decode_rows", "serve/table_writes",
+            # the step ahead of its fetches (docs/serving.md, "A step ahead")
+            "serve/steps_ahead", "serve/pipeline_flushes", "serve/discarded_row_steps",
+        ]
+        if self._counts_experts:
             counted += [f"serve/moe_{kind}_assignments" for kind in ("held", "zero", "elsewhere")]
         self.allocator.publish()
         if self.window_allocator is not None:

@@ -67,7 +67,7 @@ import math
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Any, NamedTuple
+from typing import Any, Callable, NamedTuple
 
 from llm_training_tpu.telemetry.trace import get_tracer
 
@@ -94,6 +94,12 @@ class ServeRequest:
     # with `generated` so a fold-in requeue loses nothing.
     logprobs: list[float | None] = field(default_factory=list)
     emitted: int = 0  # tokens already streamed (an evict/resume never re-emits)
+    # tokens an enqueued call is making whose values the host has not read
+    # yet (the engine runs a step ahead of its fetches: at most a final
+    # chunk's and the same step's decode, two). Everything that does not
+    # need a token's VALUE counts them at the enqueue: `cache_len`, the
+    # window's pages, the finish by length
+    in_flight: int = 0
     slot: int | None = None
     blocks: list[int] = field(default_factory=list)
     # the window group's pages this request holds: its logical pages
@@ -188,6 +194,11 @@ class Scheduler:
         self.window = window
         self.window_allocator = None if window is None else window.allocator
         self.window_pages_released = 0
+        # called before a request is evicted under block pressure: the engine
+        # reads the tokens it has in flight (an eviction folds `generated`
+        # into the requeued prompt). True when it read any: a finish seen
+        # late (`eos`) may have freed the pages, so the taking is tried again
+        self.before_evict: Callable[[], bool] | None = None
         self.waiting: deque[ServeRequest] = deque()
         self.running: dict[int, ServeRequest] = {}  # slot -> request
         self._free_slots = list(range(config.max_batch - 1, -1, -1))
@@ -416,6 +427,10 @@ class Scheduler:
                 request.blocks.extend(taken[0])
                 request.window_blocks.extend(taken[1])
                 return True
+            if self.before_evict is not None and self.before_evict():
+                if request.slot is None:  # it finished among the tokens read
+                    return False
+                continue
             victim = self._eviction_victim()
             self.evict(victim)
             if victim is request:

@@ -91,7 +91,7 @@ def run_engine(model, variables, between_steps=None, **serve):
         events += step()
     for r in requests[2:]:
         events += engine.submit(**r)
-    while not engine.scheduler.idle:
+    while not engine.idle:
         events += step()
     done = {e["id"]: e for e in events if e["type"] == "done"}
     return engine, requests, done
@@ -355,7 +355,7 @@ def test_drain_and_replay_through_the_engine_leave_both_pools_empty(tiny, tmp_pa
         second = ServingEngine(model, variables, ServeConfig(**SERVE))
         for entry in replay_journal(tmp_path / "journal.jsonl"):
             events += second.submit_resumed(entry)
-        while not second.scheduler.idle:
+        while not second.idle:
             events += second.step()
     assert second.allocator.blocks_in_use == 0 and second.window_allocator.blocks_in_use == 0
     done = {e["id"]: e for e in events if e["type"] == "done"}

@@ -470,16 +470,17 @@ def _serve_program(v5e, cell, program):
     pool_v = None if engine._pool_v is None else pool  # a latent stack has ONE pool
     key = on(jax.eval_shape(lambda: jax.random.key(0)))
     slab_rows = {} if slab is None else {"slab": slab}
-    if engine._moe_carry is not None:  # a share of the experts counts its assignments
-        slab_rows["moe"] = shape((3,))
     if engine._window_pool is not None:  # the layers that keep a window: a pool (its short table is packed)
         slab_rows["window_pool"] = on(engine._window_pool)
-    # a call's int32 inputs travel packed (tokens, lengths, tables, the call index: `_decode_fields`)
+    # a call's int32 inputs travel packed (lengths, tables, the call index: `_decode_fields`);
+    # each slot's last token stays on the device, an array of its own
     jitted, packed = (
         (engine._decode_jit, engine._decode_packed) if program == "decode"
         else (engine._prefill_jit, engine._prefill_packed)
     )
-    lowered = jitted.lower(variables, shape(packed.shape), pool, pool_v, key, **slab_rows)
+    lowered = jitted.lower(
+        variables, shape(packed.shape), pool, pool_v, key, shape(engine._last_tokens.shape), **slab_rows
+    )
     engine.close()
     return lowered, pool, slab
 

@@ -398,7 +398,7 @@ def run_engine(model, variables, **serve):
         events += engine.step()
     for r in requests[2:]:
         events += engine.submit(**r)
-    while not engine.scheduler.idle:
+    while not engine.idle:
         events += engine.step()
     done = {e["id"]: e for e in events if e["type"] == "done"}
     return engine, requests, done
@@ -448,7 +448,7 @@ def test_a_wrong_rotary_position_is_caught(tiny):
         for r in requests:
             events += engine.submit(**r)
         with jax.default_matmul_precision("highest"):
-            while not engine.scheduler.idle:
+            while not engine.idle:
                 events += engine.step()
     finally:
         object.__delattr__(model, "apply")

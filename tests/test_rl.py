@@ -299,6 +299,9 @@ def test_weight_sync_stream_equivalence_fused_vs_host_vs_fresh():
             before += [
                 e["token"] for e in engine.step() if e.get("type") == "token"
             ]
+        # the engine is a call ahead of what it has returned: one more token
+        # was made under w0 and is what the sync would read first
+        before += [e["token"] for e in engine.flush() if e.get("type") == "token"]
         summary = sync_weights(engine, w1, mode=mode)
         assert summary["generation"] == engine.weights_generation
         done = None

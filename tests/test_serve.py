@@ -448,6 +448,12 @@ def _stream_builders():
     }
 
 
+def _stream_entry(e):
+    if e["type"] == "token":
+        return [e["id"], e["token"], round(e["logprob"], 4)]
+    return [e["id"], e["stop_reason"], e["tokens"], e["evictions"]]
+
+
 def _event_stream(engine, steps=200, seed=0):
     """Seeded arrivals over `steps` engine steps of a pool too small for its
     rows (evictions, requeues, every slot taken again and again): each token
@@ -463,11 +469,9 @@ def _event_stream(engine, steps=200, seed=0):
             events += engine.submit(f"r{submitted}", prompt, max_new_tokens=rng.choice((5, 12, 24)))
             submitted += 1
         events += engine.step()
-        for e in events:
-            if e["type"] == "token":
-                stream.append([e["id"], e["token"], round(e["logprob"], 4)])
-            else:
-                stream.append([e["id"], e["stop_reason"], e["tokens"], e["evictions"]])
+        stream += [_stream_entry(e) for e in events]
+    # a token leaves a call after the one that made it: the last step's, here
+    stream += [_stream_entry(e) for e in engine.flush()]
     counters = get_registry().counter
     stream.append([
         engine.scheduler.evictions, counters("serve/state_resets").value,
@@ -523,7 +527,7 @@ def test_mid_stream_admission_is_token_identical():
     events.extend(engine.submit("second", second, max_new_tokens=n))
     events.extend(engine.step())
     assert len(engine.scheduler.running) == 2, "second not admitted mid-flight"
-    while not engine.scheduler.idle:
+    while not engine.idle:
         events.extend(engine.step())
     done = {e["id"]: e for e in events if e["type"] == "done"}
     assert done["first"]["tokens"] == _full_forward_greedy(model, variables, first, n)

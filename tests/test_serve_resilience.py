@@ -451,6 +451,7 @@ def test_drain_then_replay_is_token_identical_exactly_once(tiny_model, tmp_path)
         events += first.submit(rid, prompt, max_new_tokens=n)
     while sum(e["type"] == "token" for e in events) < 6:
         events += first.step()
+    events += first.flush()  # the last call's tokens, to their clients before the drain
     first.drain()
     first.journal.close()
     assert first.allocator.blocks_in_use == 0, "drain leaked pool blocks"
@@ -468,7 +469,7 @@ def test_drain_then_replay_is_token_identical_exactly_once(tiny_model, tmp_path)
     replay_events = []
     for entry in entries:
         replay_events += second.submit_resumed(entry)
-    while not second.scheduler.idle:
+    while not second.idle:
         replay_events += second.step()
     done = {e["id"]: e for e in replay_events if e["type"] == "done"}
     assert second.replayed_requests == 2
@@ -507,12 +508,22 @@ def test_reload_weights_mid_stream_token_identity_and_tags(tiny_model):
     events = list(engine.submit("r", prompt, max_new_tokens=n))
     while sum(e["type"] == "token" for e in events) < 4:
         events += engine.step()
-    pre_reload = [e["token"] for e in events if e["type"] == "token"]
+    seen = sum(e["type"] == "token" for e in events)
+    # the engine is a call ahead of what it has returned: one token made
+    # under v1 is in flight. The reload reads it first, so it folds into the
+    # requeued prompt and leaves (in the next step) tagged generation 0
+    from llm_training_tpu.telemetry.registry import get_registry
+
+    flushes = get_registry().counter("serve/pipeline_flushes")
+    flushed = flushes.value
+    assert engine._in_flight and engine.scheduler.running[0].in_flight == 1
     assert engine.reload_weights(v2) == 1
-    while not engine.scheduler.idle:
+    assert not engine._in_flight and flushes.value == flushed + 1
+    while not engine.idle:
         events += engine.step()
     token_events = [e for e in events if e["type"] == "token"]
     done = [e for e in events if e["type"] == "done"][0]
+    pre_reload = [e["token"] for e in token_events[: seen + 1]]
 
     fresh = _engine(model, v2)
     fresh_done = [
@@ -575,7 +586,7 @@ def test_engine_sheds_over_bounded_queue(tiny_model):
     engine = _engine(model, variables, max_batch=1, max_queue=0)
     events = list(engine.submit("first", [3, 17], max_new_tokens=4))
     events += list(engine.submit("second", [5, 9], max_new_tokens=4))
-    while not engine.scheduler.idle:
+    while not engine.idle:
         events += engine.step()
     done = {e["id"]: e for e in events if e["type"] == "done"}
     assert done["second"]["stop_reason"] == "overloaded"

@@ -177,7 +177,7 @@ def test_engine_step_spans_nest_and_count_like_the_harness(fresh, tmp_path):
     outside, step = _count_from_outside(engine)
     for request in _requests():
         engine.submit(**request)
-    while not engine.scheduler.idle:
+    while not engine.idle:
         step()
     ring = [e for e in fresh.snapshot() if e.get("ph") == "X"]
     parents = [e for e in ring if e["name"] == "engine_step"]
@@ -200,22 +200,31 @@ def test_engine_step_spans_nest_and_count_like_the_harness(fresh, tmp_path):
         assert mine[-1]["ts"] + mine[-1]["dur"] <= parent["ts"] + parent["dur"] + 1e-9
         for a, b in zip(mine, mine[1:]):
             assert a["ts"] + a["dur"] <= b["ts"] + 1e-9, (a["name"], b["name"])
-    # the prefill chunk's own children lie inside it, with the request's id
+    # the prefill chunk's own child lies inside it, with the request's id
     chunks = [e for e in ring if e["name"] == "prefill_chunk"]
-    for name in ("prefill_dispatch", "prefill_fetch"):
-        inner = [e for e in ring if e["name"] == name]
-        assert inner, name
-        for e in inner:
-            chunk = next(
-                c for c in chunks
-                if c["args"]["step"] == e["args"]["step"]
-            )
-            assert e["args"]["request_id"] == chunk["args"]["request_id"]
-            assert chunk["ts"] <= e["ts"]
-            assert e["ts"] + e["dur"] <= chunk["ts"] + chunk["dur"] + 1e-9
-    assert sum(c["args"]["final"] for c in chunks) == len(
-        [e for e in ring if e["name"] == "prefill_fetch"]
-    )
+    inner = [e for e in ring if e["name"] == "prefill_dispatch"]
+    assert len(inner) == len(chunks) > 0
+    for e in inner:
+        chunk = next(
+            c for c in chunks
+            if c["args"]["step"] == e["args"]["step"]
+        )
+        assert e["args"]["request_id"] == chunk["args"]["request_id"]
+        assert chunk["ts"] <= e["ts"]
+        assert e["ts"] + e["dur"] <= chunk["ts"] + chunk["dur"] + 1e-9
+    # no chunk waits for its token: a prompt's last chunk is read with the
+    # decode step beside it, by the NEXT step's decode_fetch
+    assert not [e for e in ring if e["name"] == "prefill_fetch"]
+    fetches = {e["args"]["step"] for e in ring if e["name"] == "decode_fetch"}
+    assert all(c["args"]["step"] + 1 in fetches for c in chunks if c["args"]["final"])
+    # a step ahead: it enqueued a call while the step before's tokens (its
+    # decode rows', or its prompt's last chunk's) were unread
+    final = {c["args"]["step"] for c in chunks if c["args"]["final"]}
+    made_tokens = [bool(p["args"]["decode_rows"]) or p["args"]["step"] in final for p in parents]
+    enqueues = [bool(p["args"]["decode_rows"] or p["args"]["prefill_chunks"]) for p in parents]
+    ahead = [int(a and b) for a, b in zip([False] + made_tokens, enqueues)]
+    assert [p["args"]["steps_ahead"] for p in parents] == ahead and sum(ahead) >= len(parents) - 3
+    assert not any(p["args"]["pipeline_flushes"] or p["args"]["discarded_row_steps"] for p in parents)
     # the sink keeps one engine_step a step and a sampled request's chunks:
     # the step's new children stay in the ring and the profiler
     fresh.flush()
@@ -293,7 +302,7 @@ def test_allocator_gauges_are_set_once_a_step_from_its_counts(fresh):
         engine.submit(**request)
     registry = get_registry()
     seen = set()
-    while not engine.scheduler.idle:
+    while not engine.idle:
         engine.step()
         for allocator in (engine.allocator, engine.window_allocator):
             in_use = registry.gauge(f"decode/{allocator.group}_blocks_in_use").value
@@ -319,7 +328,7 @@ def test_profiler_capture_holds_the_engines_spans(fresh, tmp_path):
     jax.profiler.start_trace(str(tmp_path))
     try:
         first = engine._step_index + 1
-        while not engine.scheduler.idle:
+        while not engine.idle:
             engine.step()
     finally:
         jax.profiler.stop_trace()
@@ -351,19 +360,19 @@ def test_profiler_capture_holds_the_engines_spans(fresh, tmp_path):
 
 
 def _decode_args(engine):
-    """A decode call's arguments: the packed int32 inputs (tokens, lengths,
-    the call index, the block tables: `engine._decode_fields`), the pool, the
-    engine's one key."""
+    """A decode call's arguments: the packed int32 inputs (lengths, the call
+    index, the block tables: `engine._decode_fields`), the pool, the engine's
+    one key, the slots' last tokens (which never leave the device)."""
     return (
         engine.variables, jnp.asarray(engine._decode_packed), engine._pool_k,
-        engine._pool_v, engine._rng,
+        engine._pool_v, engine._rng, engine._last_tokens,
     )
 
 
 def _prefill_args(engine):
     return (
         engine.variables, jnp.asarray(engine._prefill_packed), engine._pool_k,
-        engine._pool_v, engine._rng,
+        engine._pool_v, engine._rng, engine._last_tokens,
     )
 
 
@@ -555,8 +564,8 @@ def test_latent_attention_stack_names_its_scopes_in_both_programs(fresh):
     step attends absorbed, the chunk expanded."""
     engine = _longcat_engine()
     assert engine._pool_v is None and engine._pool_k.shape == (4, 13, 1, 8, 128)
-    decode = engine._decode_jit.lower(*_decode_args(engine), moe=engine._moe_carry)
-    prefill = engine._prefill_jit.lower(*_prefill_args(engine), moe=engine._moe_carry)
+    decode = engine._decode_jit.lower(*_decode_args(engine))
+    prefill = engine._prefill_jit.lower(*_prefill_args(engine))
     assert "jit_decode_step" in decode.as_text()[:200]
     assert "jit_prefill_chunk" in prefill.as_text()[:200]
     shared = (
@@ -580,17 +589,16 @@ def test_latent_attention_stack_names_its_scopes_in_both_programs(fresh):
 
 
 def test_latent_pool_and_expert_assignments_are_counted_with_the_tokens(fresh):
-    """`decode/latent_pool_bytes`, and the step's expert assignments (held
-    here, zero-compute, held elsewhere): counted on the device, carried there
-    until a decode step returns them as an int32 output of its own, beside
-    log-probabilities of the batch's own length (read in the `device_get`
-    that fetches the tokens), closed into `engine_step` and summed by the
-    counters."""
+    """`decode/latent_pool_bytes`, and each call's expert assignments (held
+    here, zero-compute, held elsewhere): counted on the device, returned by
+    the call as an int32 output of its own beside log-probabilities of the
+    batch's own length, read in the `device_get` that fetches the call's
+    tokens (a step later: no sync of their own) and summed by the counters."""
     from llm_training_tpu.telemetry.report import _serving_section
 
     engine = _longcat_engine()
     *_, token, logprob, _, counts = jax.eval_shape(
-        engine._decode_jit, *_decode_args(engine), moe=engine._moe_carry
+        engine._decode_jit, *_decode_args(engine)
     )
     assert token.shape == logprob.shape == (SERVE["max_batch"],)
     assert (counts.shape, counts.dtype) == ((3,), np.int32)
@@ -600,15 +608,13 @@ def test_latent_pool_and_expert_assignments_are_counted_with_the_tokens(fresh):
     assert registry.gauge("decode/latent_pool_bytes").value == pool_bytes
     stats = engine.stats()
     assert stats["decode/latent_pool_bytes"] == stats["decode/cache_bytes"] == pool_bytes
-    steps = [e["args"] for e in fresh.snapshot() if e.get("ph") == "X" and e["name"] == "engine_step"]
     kinds = ("held", "zero", "elsewhere")
-    totals = {k: sum(a.get(f"moe_{k}", 0) for a in steps) for k in kinds}
+    totals = {k: int(registry.counter(f"serve/moe_{k}_assignments").value) for k in kinds}
     # every real token of every call, twice (2 layers), three choices each:
     # 14 prompt tokens and 3 x 5 decoded ones (the last token is not fed back)
     assert sum(totals.values()) == (14 + 15) * 2 * 3
     assert all(totals.values())
     for kind in kinds:
-        assert registry.counter(f"serve/moe_{kind}_assignments").value == totals[kind]
         assert stats[f"serve/moe_{kind}_assignments"] == totals[kind]
     said = "\n".join(_serving_section(stats))
     assert "latent (MLA) pool" in said and f"{totals['zero']} zero-compute" in said

@@ -6,7 +6,13 @@ The invariant: what a program is handed equals, entry for entry, what a plain
 rebuild from the requests (`ServingEngine._table_row`, zeros for every slot
 that does not decode this step) would hand it. Checked at every call of both
 programs over a seeded schedule that holds every event that moves a row, on a
-stack with one page group and on one with a window group's ring."""
+stack with one page group and on one with a window group's ring.
+
+The engine runs a step ahead of its fetches (docs/serving.md, "A step ahead of
+its fetches"), so at a call the tokens of the call before are still in flight:
+a row's LENGTH is checked against `cache_len` as `_run_decode` found it (what
+the benchmark's harness counts there), and the token a row feeds is checked
+where it can be, against the device's carry once every call has been read."""
 
 import random
 import time
@@ -38,13 +44,23 @@ class Watch:
         self.aliased = dict(self.calls)  # calls handed the staging buffer itself
         self.tenants = [set() for _ in range(engine.config.max_batch)]
         run_prefill, decode_jit, prefill_jit = engine._run_prefill, engine._decode_jit, engine._prefill_jit
+        run_decode = engine._run_decode
+        self.lengths_at_run_decode = {}  # slot -> cache_len, as the harness's wrapper reads it
+        self.decode_calls_behind = 0  # decode calls enqueued with an earlier step's outputs unread
 
         def watched_prefill(request, chunk, start):
             self.plan = (request, chunk, start)
             return run_prefill(request, chunk, start)
 
+        def watched_decode(rows):
+            self.lengths_at_run_decode = {r.slot: r.cache_len for r in rows}
+            return run_decode(rows)
+
         def watched_decode_call(variables, packed, *args, **caches):
             self.calls["decode_step"] += 1
+            self.decode_calls_behind += bool(engine._in_flight) and (
+                engine._in_flight[0].step < engine._step_index
+            )
             self.aliased["decode_step"] += np.shares_memory(packed, engine._decode_packed)
             self.check_decode(np.array(packed))
             return decode_jit(variables, packed, *args, **caches)
@@ -55,7 +71,7 @@ class Watch:
             self.check_prefill(np.array(packed))
             return prefill_jit(variables, packed, *args, **caches)
 
-        engine._run_prefill = watched_prefill
+        engine._run_prefill, engine._run_decode = watched_prefill, watched_decode
         engine._decode_jit, engine._prefill_jit = watched_decode_call, watched_prefill_call
 
     def expect(self, what, got, want):
@@ -72,15 +88,19 @@ class Watch:
             request = engine.scheduler.running.get(slot)
             if request is not None and request.decoding:
                 self.tenants[slot].add(request.id)
-                want = (request.generated[-1], request.cache_len, engine._table_row(request))
+                want = (request.cache_len, engine._table_row(request))
+                # the books moved when the call before was enqueued, so the
+                # length `_run_decode` was called with IS the one handed
+                self.expect(
+                    f"decode slot {slot} length at _run_decode", self.lengths_at_run_decode.get(slot), want[0]
+                )
             else:  # idle, its prompt still prefilling, or evicted a moment ago
-                want = (0, 0, np.zeros_like(sent["tables"][slot]))
-            self.expect(f"decode slot {slot} token", sent["tokens"][slot], want[0])
-            self.expect(f"decode slot {slot} length", sent["lengths"][slot], want[1])
-            self.expect(f"decode slot {slot} table", sent["tables"][slot], want[2])
+                want = (0, np.zeros_like(sent["tables"][slot]))
+            self.expect(f"decode slot {slot} length", sent["lengths"][slot], want[0])
+            self.expect(f"decode slot {slot} table", sent["tables"][slot], want[1])
             if "window_tables" in sent:
                 row = (
-                    engine._table_row(request, window=True) if want[1]
+                    engine._table_row(request, window=True) if want[0]
                     else np.zeros_like(sent["window_tables"][slot])
                 )
                 self.expect(f"decode slot {slot} window table", sent["window_tables"][slot], row)
@@ -179,6 +199,24 @@ def test_handed_tables_equal_the_rebuild_through(watched, event):
         pytest.skip("one page group: no ring")
     assert happened[event] > 0, happened
     assert not watch.violations, watch.violations[:5]
+
+
+def test_cache_len_at_run_decode_is_the_length_the_call_is_handed(watched):
+    """What the benchmark's harness counts (`sum(cache_len + 1)` at
+    `_run_decode`'s call, the bytes `paged_decode_roofline_pct` divides by):
+    the books moved when the call before was enqueued, so at every call each
+    decoding row's `cache_len` there was the length the program was handed
+    (the watcher records a violation otherwise), a step ahead of the fetches:
+    nearly every decode call was enqueued with the step before's unread."""
+    _, engine, watch, happened, stats = watched
+    assert not [v for v in watch.violations if "length" in v]
+    assert watch.decode_calls_behind >= watch.calls["decode_step"] - 8  # flushes: evictions, the deadline
+    assert stats["serve/steps_ahead"] >= watch.decode_calls_behind
+    # eos set: a finish by eos cost a row-step (two where the eos was a residency's
+    # first token: the decode step beside its last chunk AND the next were enqueued
+    # before it was read; none where the length ran out with it), and nothing else did
+    assert 0 < stats["serve/discarded_row_steps"] <= 2 * happened["finish_by_eos"]
+    assert stats["serve/pipeline_flushes"] >= happened["eviction_in_decode_blocks"] + 1
 
 
 def test_rows_are_edited_not_rebuilt(watched):

@@ -360,7 +360,7 @@ def run_engine(model, variables, **serve):
         events += engine.step()
     for r in requests[2:]:
         events += engine.submit(**r)
-    while not engine.scheduler.idle:
+    while not engine.idle:
         events += engine.step()
     done = {e["id"]: e for e in events if e["type"] == "done"}
     return engine, requests, done

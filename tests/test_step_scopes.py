@@ -69,7 +69,9 @@ def _serve_program(program):
         (engine._decode_jit, engine._decode_packed) if program == "decode_step"
         else (engine._prefill_jit, engine._prefill_packed)
     )
-    return jitted.lower(variables, packed, engine._pool_k, engine._pool_v, engine._rng)
+    return jitted.lower(
+        variables, packed, engine._pool_k, engine._pool_v, engine._rng, engine._last_tokens
+    )
 
 
 def _lowered(program):
