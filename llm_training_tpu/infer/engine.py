@@ -90,10 +90,10 @@ def supports_decoding(model: Any) -> bool:
     """A family decodes when its config declares what its stack caches
     (`BaseModelConfig.cache_specs`): the shared llama/gemma/phi3 stacks,
     solar_open2, whose linear-attention (KDA) layers keep a fixed state slab
-    a decode slot beside the key/value cache, and longcat_flash, whose MLA
-    blocks cache one latent row a token. Not declared yet: bamba's mamba
-    layers, qwen3-next's and minimax's linear attention, deepseek's MLA
-    (`models/deepseek` trains only)."""
+    a decode slot beside the key/value cache, and longcat_flash and deepseek
+    (V2, V3, pangu_ultra_moe), whose MLA blocks cache one latent row a token.
+    Not declared yet: bamba's mamba layers, qwen3-next's and minimax's linear
+    attention."""
     declared = getattr(model.config, "cache_specs", None)
     return declared is not None and declared() is not None
 

@@ -36,6 +36,12 @@ class CLMConfig(BaseLMConfig):
     neftune_alpha: float | None = None
     log_perplexity: bool = True
     ce_chunk_size: int = 1024
+    # what the multi-token-prediction loss of a model that has the module
+    # (`num_nextn_predict_layers`, models/deepseek) weighs in the total:
+    # `loss = CE + mtp_loss_weight * CE_mtp`. 0.3 is DeepSeek-V3's first
+    # value (its report lowers it to 0.1 late in training); the module's
+    # publishers give none
+    mtp_loss_weight: float = 0.3
 
 
 def _get_path(tree: Any, path: str) -> jnp.ndarray:
@@ -133,6 +139,19 @@ class CLM:
             valid = (segment_ids > 0) & (segment_ids == next_seg)
             labels = jnp.where(valid, labels, cfg.ignore_index)
 
+        # a model with a multi-token-prediction module: position i also
+        # predicts the token at i + 2, where that lies in its own document
+        with_mtp = bool(getattr(model.config, "num_nextn_predict_layers", 0))
+        if with_mtp:
+            mtp_labels = shift_labels(labels, cfg.ignore_index)
+            if segment_ids is not None:
+                ahead = jnp.concatenate(
+                    [segment_ids[:, 2:], jnp.zeros_like(segment_ids[:, :2])], axis=1
+                )
+                mtp_labels = jnp.where(
+                    (segment_ids > 0) & (segment_ids == ahead), mtp_labels, cfg.ignore_index
+                )
+
         p = params["params"] if "params" in params else params
 
         inputs_embeds = None
@@ -151,26 +170,32 @@ class CLM:
 
         out = model.apply(
             params,
-            input_ids=None if inputs_embeds is not None else input_ids,
+            # the module embeds the tokens itself, noise or no noise
+            input_ids=None if inputs_embeds is not None and not with_mtp else input_ids,
             segment_ids=segment_ids,
             position_ids=position_ids,
             inputs_embeds=inputs_embeds,
             compute_logits=False,
             return_last_hidden_states=True,
+            **({"return_mtp": True} if with_mtp else {}),
         )
         head, head_bias = head_and_bias(model, p)
-        total, count = fused_linear_cross_entropy(
-            out.last_hidden_states,
-            head.astype(out.last_hidden_states.dtype),
-            labels,
-            ignore_index=cfg.ignore_index,
-            chunk_size=cfg.ce_chunk_size,
-            bias=head_bias,
-            # Gemma-2 caps the final logits; the fused path must apply the
-            # same cap or training loss diverges from the compute_logits path
-            logits_soft_cap=getattr(model.config, "final_logit_softcapping", None),
-        )
-        loss = total / jnp.maximum(count, 1).astype(jnp.float32)
+
+        def token_loss(hidden, targets):
+            total, count = fused_linear_cross_entropy(
+                hidden,
+                head.astype(hidden.dtype),
+                targets,
+                ignore_index=cfg.ignore_index,
+                chunk_size=cfg.ce_chunk_size,
+                bias=head_bias,
+                # Gemma-2 caps the final logits; the fused path must apply the
+                # same cap or training loss diverges from the compute_logits path
+                logits_soft_cap=getattr(model.config, "final_logit_softcapping", None),
+            )
+            return total / jnp.maximum(count, 1).astype(jnp.float32), count
+
+        loss, count = token_loss(out.last_hidden_states, labels)
 
         metrics = {
             "loss": loss,
@@ -180,6 +205,14 @@ class CLM:
             # exp of the TOKEN-LEVEL cross entropy only — never the MoE
             # balancing penalty, so curves stay comparable to dense/HF evals
             metrics["perplexity"] = jnp.exp(loss)
+        if with_mtp:
+            # (its ops land under `loss_ce` beside the main loss's: the fused
+            # cross entropy is a custom_vjp, whose ops keep no outer scope; the
+            # module itself runs under `mtp`, docs/observability.md)
+            mtp_loss, _ = token_loss(out.mtp_hidden_states, mtp_labels)
+            metrics["mtp_loss"] = mtp_loss
+            loss = loss + cfg.mtp_loss_weight * mtp_loss
+            metrics["loss"] = loss
         if out.aux_loss is not None:
             # MoE load-balancing loss (HF load_balancing_loss_func analogue):
             # the model returns it unscaled; the coefficient lives in the

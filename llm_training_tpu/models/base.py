@@ -336,7 +336,11 @@ class CausalLMOutput:
     a SHARE of its experts and has zero-compute ones (longcat_flash): the
     call's (token, slot) assignments to experts held here, to zero-compute
     experts, and to experts held elsewhere, over all layers, padding left
-    out; None from every other family."""
+    out; a `Deepseek` share counts the same three (no zero-compute ones);
+    None from every other family. `mtp_hidden_states` (and, under
+    `compute_logits`, `mtp_logits`) are a multi-token-prediction module's
+    final-normed output, position i for the token at i + 2, only from a call
+    that asked for them (`Deepseek.__call__(return_mtp=True)`)."""
 
     logits: jnp.ndarray | None = None
     last_hidden_states: jnp.ndarray | None = None
@@ -345,3 +349,5 @@ class CausalLMOutput:
     router_stats: RouterStats | None = None
     decode_state: DecodeState | None = None
     moe_assignments: jnp.ndarray | None = None
+    mtp_hidden_states: jnp.ndarray | None = None
+    mtp_logits: jnp.ndarray | None = None

@@ -319,8 +319,9 @@ def _whole_leaves(params, names):
 
 
 def scan_layers(body, args: tuple, length: int, hidden, inputs: tuple,
-                cache: LayerCache | None = None, whole: tuple[str, ...] = ()):
-    """`body(*args, name="layers")` run `length` times by `nn.scan`, params
+                cache: LayerCache | None = None, whole: tuple[str, ...] = (),
+                name: str = "layers"):
+    """`body(*args, name=name)` run `length` times by `nn.scan`, params
     stacked on axis 0: `-> (hidden, stacked ys, cache)`. Training: the body
     is called `(hidden, *inputs) -> (hidden, ys)`. Decoding: the buffers are
     CARRIED beside `hidden` and the step's index scanned over (as a scanned
@@ -348,7 +349,7 @@ def scan_layers(body, args: tuple, length: int, hidden, inputs: tuple,
         in_axes=(nn.broadcast,) * len(inputs) + extra,
         length=length,
         metadata_params={nn.PARTITION_NAME: "layers"},
-    )(*args, name="layers")
+    )(*args, name=name)
     if not decoding:
         hidden, ys = scanned(hidden, *inputs)
         return hidden, ys, None

@@ -7,6 +7,14 @@ grouped-MoE computation graph is native. `version=2` mirrors HF
 `DeepseekV2Config` (softmax routing, greedy / group-limited-greedy top-k);
 `version=3` mirrors `DeepseekV3Config` (sigmoid routing with the noaux
 e_score_correction_bias and top-2-sum group selection).
+
+`model_type: pangu_ultra_moe` (openPangu-Ultra-MoE) is `version=3` without
+expert groups plus two things: `sandwich_norm` (a norm after the attention
+and after the MLP as well as before each) and `num_nextn_predict_layers`
+(the multi-token-prediction module, `model.py:MTPModule`).
+
+The stack decodes (docs/inference.md, docs/serving.md): every layer's MLA
+block leaves ONE latent row a token, declared by `cache_specs()`.
 """
 
 from __future__ import annotations
@@ -15,7 +23,7 @@ from typing import Any, Literal
 
 from pydantic import model_validator
 
-from llm_training_tpu.models.base import BaseModelConfig
+from llm_training_tpu.models.base import BaseModelConfig, LatentCacheSpec
 
 
 class DeepseekConfig(BaseModelConfig):
@@ -63,10 +71,25 @@ class DeepseekConfig(BaseModelConfig):
     topk_method: Literal["greedy", "group_limited_greedy"] = "greedy"
     # 'ragged' = dropless grouped matmul; 'dense' = exact every-expert path
     moe_impl: Literal["auto", "dense", "ragged"] = "auto"
+    # an expert-parallel share: this many of the routed experts, from
+    # `experts_first` on, are held (and computed) here; the router still
+    # scores all `n_routed_experts`, and the shared experts are every
+    # chip's own. None = all.
+    experts_held: int | None = None
+    experts_first: int = 0
     # per-rank buffer slack for the expert-parallel dispatch: capacity =
     # ceil(T*K/ep * factor) rows (clamped to T*K); routing beyond it is
     # dropped, so raise this if EP training shows imbalance-driven drops
     ep_capacity_factor: float = 2.0
+
+    # pangu_ultra_moe: `a = N(MLA(N x))`, `h = x + a`, `y = h + N(MLP(N h))`,
+    # four norms a layer (`pre_mlp_layernorm`, `post_mlp_layernorm` beside
+    # the two every layer has)
+    sandwich_norm: bool = False
+    # multi-token-prediction modules after the stack (0 or 1): a training
+    # loss of their own (`lms/clm.py`); a forward without `return_mtp` never
+    # runs them, and the cache holds no row for them
+    num_nextn_predict_layers: int = 0
 
     enable_gradient_checkpointing: bool = False
     recompute_granularity: Literal["full", "selective"] = "full"
@@ -87,6 +110,19 @@ class DeepseekConfig(BaseModelConfig):
                     raise ValueError("n_routed_experts must divide into n_group groups")
                 if self.topk_group is None:
                     raise ValueError("n_group requires topk_group")
+            held = self.num_experts_held
+            if not 0 <= self.experts_first <= self.n_routed_experts - held:
+                raise ValueError(
+                    f"experts {self.experts_first}..{self.experts_first + held} are not "
+                    f"among the {self.n_routed_experts} routed experts"
+                )
+        elif self.experts_held is not None:
+            raise ValueError("experts_held needs n_routed_experts")
+        if self.num_nextn_predict_layers not in (0, 1):
+            raise ValueError(
+                "num_nextn_predict_layers: one multi-token-prediction module is implemented, "
+                f"not {self.num_nextn_predict_layers}"
+            )
         self.rope_config  # trigger validation
         return self
 
@@ -117,6 +153,28 @@ class DeepseekConfig(BaseModelConfig):
                 mscale = 0.1 * mscale_all_dim * math.log(factor) + 1.0
                 scale = scale * mscale * mscale
         return scale
+
+    @property
+    def num_experts_held(self) -> int | None:
+        return self.n_routed_experts if self.experts_held is None else self.experts_held
+
+    @property
+    def counts_expert_assignments(self) -> bool:
+        """A share of the experts counts where its tokens' choices went
+        (`CausalLMOutput.moe_assignments`; `serve/engine.py` reads this)."""
+        return self.experts_held is not None
+
+    def cache_specs(self) -> tuple[LatentCacheSpec, None]:
+        """The one declaration the latent pool, the dense latent buffer and
+        their shardings derive from (`infer/cache.py`): one row a token for
+        each layer's MLA block, dense prefix and MoE suffix alike."""
+        return (
+            LatentCacheSpec(
+                layers=self.num_hidden_layers, latent_dim=self.kv_lora_rank,
+                rope_dim=self.qk_rope_head_dim,
+            ),
+            None,
+        )
 
     def layer_is_moe(self, layer_idx: int) -> bool:
         return (

@@ -6,6 +6,15 @@ torch wrapping, `hf_causal_lm.py:22`). The dense prefix is looped
 (`layers_{i}` keys); the uniform MoE suffix is scanned (`moe_layers/layer`
 keys with a leading depth axis). Per-expert HF weights stack into ONE
 [E, in, out] parameter per projection ([L_s, E, in, out] under the scan).
+
+`model_type: pangu_ultra_moe` (openPangu-Ultra-MoE) is the V3 layout with two
+more norms a layer (`pre_mlp_layernorm`, `post_mlp_layernorm`), which convert.
+Its multi-token-prediction layer (index `num_hidden_layers`: `eh_proj`,
+`enorm`, `hnorm`, a decoder layer, a `shared_head`) is NOT mapped: this repo
+shares the final norm and the head with the stack where the checkpoint keeps
+a copy, and which copy a load should trust is not this module's to guess. A
+config with `num_nextn_predict_layers`, or one that holds a share of the
+experts, refuses a state dict with a message.
 """
 
 from __future__ import annotations
@@ -62,8 +71,30 @@ _ATTN_BIASES = [
 _Q_LORA_BIAS = [(("self_attn", "q_a_proj", "bias"), "self_attn.q_a_proj.bias", False)]
 
 
+_SANDWICH_NORMS = [
+    (("pre_mlp_layernorm", "weight"), "pre_mlp_layernorm.weight", False),
+    (("post_mlp_layernorm", "weight"), "post_mlp_layernorm.weight", False),
+]
+
+
+def _refuse_unmapped(config: DeepseekConfig) -> None:
+    if config.num_nextn_predict_layers:
+        raise NotImplementedError(
+            "deepseek: the multi-token-prediction layer's tensors (layer "
+            f"{config.num_hidden_layers}: eh_proj, enorm, hnorm, shared_head) are not mapped; "
+            "convert with num_nextn_predict_layers=0"
+        )
+    if config.experts_held is not None:
+        raise NotImplementedError(
+            "deepseek: a share of the experts (experts_held) is no whole checkpoint; "
+            "the config converts, a state dict does not"
+        )
+
+
 def _layer_params(config: DeepseekConfig, i: int) -> list:
     params = list(_ATTN_COMMON)
+    if config.sandwich_norm:
+        params += _SANDWICH_NORMS
     params += _Q_FULL if config.q_lora_rank is None else _Q_LORA
     if config.attention_bias:
         params += _ATTN_BIASES
@@ -84,6 +115,7 @@ def _layer_params(config: DeepseekConfig, i: int) -> list:
 def params_from_hf(
     state_dict: Mapping[str, Any], config: DeepseekConfig, leaf_fn: Any = None
 ) -> dict:
+    _refuse_unmapped(config)
     params: dict = {}
     sd = {k.removeprefix("model."): v for k, v in state_dict.items()}
 
@@ -111,6 +143,7 @@ def params_from_hf(
 def params_to_hf(params: Mapping, config: DeepseekConfig) -> dict[str, np.ndarray]:
     import flax.linen as nn
 
+    _refuse_unmapped(config)
     p = params.get("params", params)
     p = nn.meta.unbox(p)
     out: dict[str, np.ndarray] = {}
@@ -131,6 +164,11 @@ def params_to_hf(params: Mapping, config: DeepseekConfig) -> dict[str, np.ndarra
 
 def config_to_hf(config: DeepseekConfig, torch_dtype: str = "bfloat16") -> dict[str, Any]:
     v3 = config.version == 3
+    # pangu_ultra_moe is the one model_type with the four norms
+    pangu = {
+        "architectures": ["PanguUltraMoEForCausalLM"], "model_type": "pangu_ultra_moe",
+        "sandwich_norm": True, "num_nextn_predict_layers": config.num_nextn_predict_layers,
+    } if config.sandwich_norm else {}
     return {
         "architectures": ["DeepseekV3ForCausalLM" if v3 else "DeepseekV2ForCausalLM"],
         "model_type": "deepseek_v3" if v3 else "deepseek_v2",
@@ -173,6 +211,7 @@ def config_to_hf(config: DeepseekConfig, torch_dtype: str = "bfloat16") -> dict[
             if v3
             else {"topk_method": config.topk_method}
         ),
+        **pangu,
     }
 
 
@@ -183,7 +222,7 @@ def config_from_hf(hf_config: Any, **overrides: Any) -> DeepseekConfig:
     model_type = get("model_type")
     # kimi_k2 (Moonshot Kimi-K2) ships the DeepSeek-V3 graph and key layout
     # verbatim under its own model_type
-    version = 3 if model_type in ("deepseek_v3", "kimi_k2") else 2
+    version = 3 if model_type in ("deepseek_v3", "kimi_k2", "pangu_ultra_moe") else 2
     if version == 2 and get("topk_method", "greedy") not in (
         "greedy", "group_limited_greedy"
     ):
@@ -224,4 +263,10 @@ def config_from_hf(hf_config: Any, **overrides: Any) -> DeepseekConfig:
         n_group=get("n_group"),
         topk_group=get("topk_group"),
         topk_method=get("topk_method", "greedy") if version == 2 else "greedy",
+        sandwich_norm=bool(get("sandwich_norm", False)),
+        # DeepSeek-V3's and Kimi-K2's configs name a module too; their
+        # checkpoints' extra layer was never loaded here, and is not now
+        num_nextn_predict_layers=(
+            get("num_nextn_predict_layers", 0) if model_type == "pangu_ultra_moe" else 0
+        ),
     ), **overrides})

@@ -1,100 +1,196 @@
-"""DeepSeek V2/V3 decoder, TPU-native.
+"""DeepSeek V2/V3 decoder, TPU-native; openPangu-Ultra-MoE (`model_type:
+pangu_ultra_moe`) is the V3 graph without expert groups plus `sandwich_norm`
+and a multi-token-prediction module.
 
 Graph verified against HF `modeling_deepseek_v2.py` / `modeling_deepseek_v3.py`:
 
-- MLA (multi-head latent attention): q via optional LoRA factorization
-  (q_a_proj -> RMSNorm -> q_b_proj), kv via a shared compressed latent
-  (kv_a_proj_with_mqa -> split latent + rope part -> RMSNorm -> kv_b_proj).
-  Per head, q/k are [nope | rope] concatenations; the rope part of k is
-  MQA-style (one head, broadcast). Rotation uses the interleaved
-  (complex-pair) layout the HF checkpoints store (`rope_interleave`).
-  v (v_head_dim) is zero-padded to qk_head_dim for the attention kernel and
-  sliced back — padding columns receive zero weight, exactly HF's FA2 trick.
+- MLA (multi-head latent attention, `MLAttention`, which `LongcatFlash`
+  shares): q via optional LoRA factorization (q_a_proj -> RMSNorm ->
+  q_b_proj), kv via a shared compressed latent (kv_a_proj_with_mqa -> split
+  latent + rope part -> RMSNorm -> kv_b_proj). Per head, q/k are [nope |
+  rope] concatenations; the rope part of k is MQA-style (one head,
+  broadcast). Rotation uses the interleaved (complex-pair) layout the HF
+  checkpoints store (`rope_interleave`).
 - attention scale 1/sqrt(qk_head_dim) with DeepSeek-yarn's squared-mscale
   correction (config.attention_scale).
 - MoE: fp32 router (sigmoid + e_score_correction_bias + top-2-sum group
   selection for v3; softmax + greedy / group-limited max for v2), dropless
   `lax.ragged_dot` grouped matmuls over ONE stacked parameter per
   projection, always-on shared experts, routed_scaling_factor. No aux loss:
-  v3 balances via the noaux bias; the HF v2 port computes none either.
+  v3 balances via the noaux bias; the HF v2 port computes none either. With
+  `experts_held` the block is an expert-parallel SHARE (`DeepseekMoE`).
 - dense prefix: layers [0, first_k_dense_replace) use the full-width MLP and
   are looped; the uniform MoE suffix scans (`nn.scan`) so compile time stays
   ~flat in depth.
+- `sandwich_norm` (pangu_ultra_moe): `h = x + N(MLA(N x))`, `y = h + N(MLP(N
+  h))`, four norms a layer.
+- `num_nextn_predict_layers` (`MTPModule`): `h'_i = W_eh [N(Emb(t_{i+1})) ;
+  N(h_i)]`, one more decoder layer, the stack's own final norm and head: a
+  second training loss (`lms/clm.py`), run only by a call that asks for it.
+
+Decoding (docs/inference.md, docs/serving.md): a token leaves ONE row in the
+cache for each layer's MLA block, `[c_kv | rotated k_r]`, declared by
+`DeepseekConfig.cache_specs()`; the block appends it and attends through
+`LayerCache.attend_latent`: absorbed against the paged latent pool for one
+token a row, expanded through `W_kvb` for a chunk. The looped prefix threads
+the cache as a Python variable; the scanned suffix carries the latent buffer
+of ALL the stack's blocks as its carry and reads the held experts' stacked
+weights where they lie (`models/cache.py:scan_layers`, `whole=`).
 """
 
 from __future__ import annotations
+
+from typing import Any
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from llm_training_tpu.models.base import CausalLMOutput, RouterStats
+from llm_training_tpu.models.base import (
+    CausalLMOutput,
+    DecodeState,
+    PagedDecodeState,
+    RouterStats,
+)
+from llm_training_tpu.models.cache import close_cache, open_cache, scan_layers
 from llm_training_tpu.models.deepseek.config import DeepseekConfig
 from llm_training_tpu.models.llama.model import RMSNorm, _dense
+from llm_training_tpu.models.moe import (
+    EXPERT_LEAVES,
+    assignment_counts,
+    dropless_moe_apply,
+    experts_in_place,
+    grouped_matmul,
+    router_block_stats,
+)
 from llm_training_tpu.models.remat import remat_policy as _remat_policy
 from llm_training_tpu.ops import apply_rope, dot_product_attention
 from llm_training_tpu.ops.rope_utils import compute_rope_cos_sin, compute_rope_frequencies
 from llm_training_tpu.ops.swiglu import silu_mul
 
 
-class MLAttention(nn.Module):
-    config: DeepseekConfig
+class _UpProjection(nn.Module):
+    """`kv_b_proj` as the HuggingFace checkpoints keep it, a `kernel` of
+    `[latent, heads * (nope + v)]`, handed out a head: `[latent, heads, nope +
+    v]` in the compute dtype. The module multiplies nothing: the expanded
+    route and the absorbed one (`ops/latent_attention.py`) each take the
+    parts of it they need."""
+
+    config: Any
+    heads: int
+    width: int
 
     @nn.compact
-    def __call__(self, hidden, segment_ids, cos, sin):
+    def __call__(self):
+        cfg = self.config
+        kernel = self.param(
+            "kernel",
+            nn.with_logical_partitioning(
+                nn.initializers.normal(cfg.initializer_range), (None, "heads")
+            ),
+            (cfg.kv_lora_rank, self.heads * self.width),
+            cfg.param_jnp_dtype,
+        )
+        return kernel.astype(cfg.compute_jnp_dtype).reshape(
+            cfg.kv_lora_rank, self.heads, self.width
+        )
+
+
+class MLAttention(nn.Module):
+    """Multi-head latent attention, for every family that has it (`Deepseek`
+    V2 / V3 / pangu_ultra_moe, `LongcatFlash`). Returns `(out, cache)`: with
+    a `cache` (`models/cache.py`) the token's latent row `[c_kv | rotated
+    k_r]` is appended to part `block` of it (this block's index among the
+    stack's MLA blocks) and attention runs against that part, absorbed for
+    one token a row and expanded for a chunk; without one, the chunk's own
+    latents are expanded and attended with the training kernels.
+
+    `config` gives the widths (`num_attention_heads`, `q_lora_rank` (None: a
+    full-rank `q_proj`), `kv_lora_rank`, `qk_nope_head_dim`,
+    `qk_rope_head_dim`, `v_head_dim`, `attention_bias`). `q_scale`, `kv_scale`:
+    LongCat's two low-rank scale factors, on the up-projected query and on
+    the normalised latent. `scale`: the softmax scale (None: `1 /
+    sqrt(qk_head_dim)`). `interleaved`: rotary pairs (2i, 2i+1), as the
+    checkpoints store them. `kv_b_stacked`: `kv_b_proj` is one parameter
+    `[latent, heads, nope + v]` (LongCat's tree) and not a `kernel` in the
+    HuggingFace layout."""
+
+    config: Any
+    q_scale: float = 1.0
+    kv_scale: float = 1.0
+    scale: float | None = None
+    interleaved: bool = True
+    kv_b_stacked: bool = False
+
+    @nn.compact
+    def __call__(self, hidden, segment_ids, cos, sin, cache=None, block=None):
         cfg = self.config
         batch, seq, _ = hidden.shape
-        heads = cfg.num_attention_heads
-        qk_dim, rope_dim, nope_dim = (
-            cfg.qk_head_dim, cfg.qk_rope_head_dim, cfg.qk_nope_head_dim
-        )
+        heads, nope, rope = cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+        latent, v_dim = cfg.kv_lora_rank, cfg.v_head_dim
+        bias = cfg.attention_bias
+        norm = lambda name: RMSNorm(cfg.rms_norm_eps, cfg.param_jnp_dtype, name=name)
 
-        if cfg.q_lora_rank is None:
-            q = _dense(cfg, heads * qk_dim, ("embed", "heads"), "q_proj", False)(hidden)
+        with jax.named_scope("mla_q"):
+            if cfg.q_lora_rank is None:
+                q = _dense(cfg, heads * (nope + rope), ("embed", "heads"), "q_proj", False)(hidden)
+            else:
+                c_q = norm("q_a_layernorm")(
+                    _dense(cfg, cfg.q_lora_rank, ("embed", None), "q_a_proj", bias)(hidden)
+                )
+                q = _dense(cfg, heads * (nope + rope), (None, "heads"), "q_b_proj", False)(c_q)
+            if self.q_scale != 1.0:
+                q = q * jnp.asarray(self.q_scale, q.dtype)
+            q = q.reshape(batch, seq, heads, nope + rope)
+            q_nope, q_rope = q[..., :nope], q[..., nope:]
+        with jax.named_scope("mla_kv"):
+            compressed = _dense(
+                cfg, latent + rope, ("embed", None), "kv_a_proj_with_mqa", bias
+            )(hidden)
+            c_kv = norm("kv_a_layernorm")(compressed[..., :latent])
+            if self.kv_scale != 1.0:
+                c_kv = c_kv * jnp.asarray(self.kv_scale, c_kv.dtype)
+            # one rotated key a token, shared by the heads; it is not scaled
+            q_rope, k_rope = apply_rope(
+                q_rope, compressed[..., None, latent:], cos, sin, interleaved=self.interleaved
+            )
+            if self.kv_b_stacked:
+                w_kvb = self.param(
+                    "kv_b_proj",
+                    nn.with_logical_partitioning(
+                        nn.initializers.normal(cfg.initializer_range), (None, "heads", None)
+                    ),
+                    (latent, heads, nope + v_dim),
+                    cfg.param_jnp_dtype,
+                ).astype(cfg.compute_jnp_dtype)
+            else:
+                w_kvb = _UpProjection(cfg, heads, nope + v_dim, name="kv_b_proj")()
+
+        scale = (nope + rope) ** -0.5 if self.scale is None else self.scale
+        if cache is not None:
+            row = jnp.concatenate([c_kv, k_rope[:, :, 0]], axis=-1)
+            out, cache = cache.attend_latent(
+                block, q_nope, q_rope, row, w_kvb, segment_ids, scale=scale
+            )
         else:
-            q = _dense(cfg, cfg.q_lora_rank, ("embed", None), "q_a_proj",
-                       cfg.attention_bias)(hidden)
-            q = RMSNorm(cfg.rms_norm_eps, cfg.param_jnp_dtype, name="q_a_layernorm")(q)
-            q = _dense(cfg, heads * qk_dim, (None, "heads"), "q_b_proj", False)(q)
-        q = q.reshape(batch, seq, heads, qk_dim)
-        q_nope, q_rot = q[..., :nope_dim], q[..., nope_dim:]
-
-        compressed = _dense(
-            cfg, cfg.kv_lora_rank + rope_dim, ("embed", None),
-            "kv_a_proj_with_mqa", cfg.attention_bias,
-        )(hidden)
-        kv_latent, k_rot = compressed[..., : cfg.kv_lora_rank], compressed[..., cfg.kv_lora_rank:]
-        kv_latent = RMSNorm(
-            cfg.rms_norm_eps, cfg.param_jnp_dtype, name="kv_a_layernorm"
-        )(kv_latent)
-        kv = _dense(
-            cfg, heads * (nope_dim + cfg.v_head_dim), (None, "heads"), "kv_b_proj", False
-        )(kv_latent).reshape(batch, seq, heads, nope_dim + cfg.v_head_dim)
-        k_nope, v = kv[..., :nope_dim], kv[..., nope_dim:]
-
-        # MQA rope head: one k head, rotated, broadcast across query heads
-        k_rot = k_rot[:, :, None, :]
-        q_rot, k_rot = apply_rope(
-            q_rot, k_rot, cos, sin, interleaved=cfg.rope_interleave
-        )
-        k_rot = jnp.broadcast_to(k_rot, (batch, seq, heads, rope_dim))
-
-        q = jnp.concatenate([q_nope, q_rot], axis=-1)
-        k = jnp.concatenate([k_nope, k_rot], axis=-1)
-        # pad v to the qk head dim for the kernel; the padded columns get
-        # zero attention weight mass and are sliced off after
-        v_pad = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, qk_dim - cfg.v_head_dim)))
-
-        out = dot_product_attention(
-            q, k, v_pad,
-            segment_ids=segment_ids,
-            causal=True,
-            scale=cfg.attention_scale,
-            impl=cfg.attention_impl,
-        )[..., : cfg.v_head_dim]
-        out = out.astype(hidden.dtype).reshape(batch, seq, heads * cfg.v_head_dim)
-        return _dense(cfg, cfg.hidden_size, ("heads", "embed"), "o_proj",
-                      cfg.attention_bias)(out)
+            with jax.named_scope("mla_expand"):
+                kv = jnp.einsum(
+                    "bsl,lhe->bshe", c_kv, w_kvb, preferred_element_type=jnp.float32
+                ).astype(c_kv.dtype)
+            with jax.named_scope("mla_attend"):
+                k = jnp.concatenate(
+                    [kv[..., :nope], jnp.broadcast_to(k_rope, (batch, seq, heads, rope))], axis=-1
+                )
+                # the kernels want one head size: v zero-padded to the keys'
+                # (the padded columns get no weight and are sliced off after)
+                v = jnp.pad(kv[..., nope:], ((0, 0),) * 3 + ((0, nope + rope - v_dim),))
+                out = dot_product_attention(
+                    jnp.concatenate([q_nope, q_rope], axis=-1), k, v,
+                    segment_ids=segment_ids, causal=True, scale=scale, impl=cfg.attention_impl,
+                )[..., :v_dim]
+        with jax.named_scope("mla_out"):
+            out = out.astype(hidden.dtype).reshape(batch, seq, heads * v_dim)
+            return _dense(cfg, cfg.hidden_size, ("heads", "embed"), "o_proj", bias)(out), cache
 
 
 class DeepseekMLP(nn.Module):
@@ -131,6 +227,11 @@ class DeepseekMoE(nn.Module):
     are `jax.lax.ragged_dot` on this layer's own matrices."""
 
     config: DeepseekConfig
+    # a third result, `counts [3]` int32: this call's assignments to experts
+    # held here, to zero-compute experts (this router has none) and to
+    # experts held elsewhere, padding left out (`CausalLMOutput.
+    # moe_assignments`)
+    count_assignments: bool = False
 
     @nn.compact
     def __call__(self, hidden, pad_mask=None, stack=None):
@@ -223,12 +324,6 @@ class DeepseekMoE(nn.Module):
             up = jnp.einsum("th,ehi->tei", xc, w_up)
             return jnp.einsum("tei,eih->teh", nn.silu(gate) * up, w_down)
 
-        from llm_training_tpu.models.moe import (
-            dropless_moe_apply,
-            experts_in_place,
-            grouped_matmul,
-        )
-
         weights, layer = experts_in_place(
             stack, (w_gate, w_up, w_down), cfg.moe_impl, compute_dtype
         )
@@ -261,63 +356,148 @@ class DeepseekMoE(nn.Module):
             )
         else:
             norm_scores = scores
-        from llm_training_tpu.models.moe import router_block_stats
-
         sel_frac, mean_prob = router_block_stats(
             topk_idx, norm_scores, num_experts, pad_mask
         )
-        return out + shared, (sel_frac, mean_prob, dropped)
+        stats = (sel_frac, mean_prob, dropped)
+        if not self.count_assignments:
+            return out + shared, stats
+        counts = assignment_counts(topk_idx, getattr(cfg, "experts_first", 0), num_held, pad_mask)
+        return out + shared, stats, counts
 
 
 class DeepseekDecoderLayer(nn.Module):
-    """Pre-norm block (HF DeepseekV2/V3DecoderLayer). Returns
-    (hidden, stats) — DeepSeek computes no aux loss (the noaux bias
-    balances instead), so the layer ys channel carries the router health
-    triple (sel_frac [E], mean_prob [E], dropped scalar) on MoE layers and
-    None on dense layers (`is_moe` is static, so the structures are
-    trace-time constants)."""
+    """One block (HF DeepseekV2/V3DecoderLayer; with `sandwich_norm`,
+    pangu_ultra_moe's): pre-norm, `h = x + MLA(N x)`, `y = h + MLP(N h)`, or
+    sandwiched, `h = x + N(MLA(N x))`, `y = h + N(MLP(N h))`. Returns
+    `(hidden, ys, cache)`. DeepSeek computes no aux loss (the noaux bias
+    balances instead), so `ys` carries, on a MoE layer, `(the router health
+    triple (sel_frac [E], mean_prob [E], dropped scalar), the assignment
+    counts [3] of a share or None)`, and None on a dense layer (`is_moe` is
+    static, so the structures are trace-time constants). `layer` is this
+    layer's index in the stack, its MLA block's part of the cache; `stack =
+    (leaves, index)` the scanned suffix's expert leaves whole, for a
+    decoding layer of it."""
 
     config: DeepseekConfig
     is_moe: bool
 
     @nn.compact
-    def __call__(self, hidden, segment_ids, cos, sin):
+    def __call__(self, hidden, segment_ids, cos, sin, cache=None, layer=None, stack=None):
         cfg = self.config
         hidden = nn.with_logical_constraint(hidden, ("batch", "act_seq", "act_embed"))
         norm = lambda name: RMSNorm(cfg.rms_norm_eps, cfg.param_jnp_dtype, name=name)
 
-        normed = norm("input_layernorm")(hidden)
-        hidden = hidden + MLAttention(cfg, name="self_attn")(normed, segment_ids, cos, sin)
-        normed = norm("post_attention_layernorm")(hidden)
+        attn, cache = MLAttention(
+            cfg, scale=cfg.attention_scale, interleaved=cfg.rope_interleave, name="self_attn"
+        )(norm("input_layernorm")(hidden), segment_ids, cos, sin, cache, layer)
+        if cfg.sandwich_norm:
+            hidden = hidden + norm("post_attention_layernorm")(attn)
+            normed = norm("pre_mlp_layernorm")(hidden)
+        else:
+            hidden = hidden + attn
+            normed = norm("post_attention_layernorm")(hidden)
+        ys = None
         if self.is_moe:
             pad_mask = None if segment_ids is None else segment_ids > 0
-            mlp_out, stats = DeepseekMoE(cfg, name="mlp")(normed, pad_mask)
+            counts = None
+            if cfg.counts_expert_assignments:
+                mlp_out, stats, counts = DeepseekMoE(cfg, True, name="mlp")(normed, pad_mask, stack)
+            else:
+                mlp_out, stats = DeepseekMoE(cfg, name="mlp")(normed, pad_mask, stack)
+            ys = (stats, counts)
         else:
             mlp_out = DeepseekMLP(cfg, cfg.intermediate_size, name="mlp")(normed)
-            stats = None
-        return hidden + mlp_out, stats
+        if cfg.sandwich_norm:
+            mlp_out = norm("post_mlp_layernorm")(mlp_out)
+        return hidden + mlp_out, ys, cache
 
 
 class _MoEScanBody(nn.Module):
     """Scan body: one MoE layer. The dense prefix is non-uniform with the
     suffix, so it is looped; everything from `first_k_dense_replace` on is
     the SAME graph and scans — compile time stays ~flat in depth (DeepSeek-V3
-    is 61 layers; a looped stack would compile 58 copies of this body)."""
+    is 61 layers; a looped stack would compile 58 copies of this body).
+    `(carry, xs) -> (carry, ys)` for `nn.scan`; the carry is `hidden` or,
+    when decoding, `(hidden, the cache's buffers)`, and `layer` then counts
+    the suffix's layers (`models/cache.py:scan_layers`)."""
 
     config: DeepseekConfig
 
     @nn.compact
-    def __call__(self, hidden, segment_ids, cos, sin):
-        hidden, stats = DeepseekDecoderLayer(self.config, True, name="layer")(
-            hidden, segment_ids, cos, sin
+    def __call__(self, carry, segment_ids, cos, sin, cache=None, layer=None, stack=None):
+        cfg = self.config
+        block = DeepseekDecoderLayer(cfg, True, name="layer")
+        if cache is None:
+            hidden, ys, _ = block(carry, segment_ids, cos, sin)
+            return hidden, ys
+        hidden, buffers = carry
+        hidden, ys, cache = block(
+            hidden, segment_ids, cos, sin, cache.holding(buffers),
+            cfg.first_k_dense_replace + layer,
+            None if stack is None else (stack["layer"]["mlp"], layer),
         )
-        return hidden, stats
+        return (hidden, cache.buffers), ys
+
+
+class MTPModule(nn.Module):
+    """One multi-token-prediction module (DeepSeek-V3's, which
+    pangu_ultra_moe publishes one of): `h'_i = W_eh [N_e(Emb(t_{i+1})) ;
+    N_h(h_i)]`, then one decoder layer of the stack's last kind. `h_i` is the
+    stack's output at position i BEFORE the final norm, `next_embeds` the
+    shared embedding of the token after it. The caller applies the model's
+    own final norm and head to what comes back: its logits at i are for
+    `t_{i+2}`. Returns `(hidden, dropped)`."""
+
+    config: DeepseekConfig
+
+    @nn.compact
+    def __call__(self, hidden, next_embeds, segment_ids, cos, sin):
+        cfg = self.config
+        norm = lambda name: RMSNorm(cfg.rms_norm_eps, cfg.param_jnp_dtype, name=name)
+        joined = jnp.concatenate([norm("enorm")(next_embeds), norm("hnorm")(hidden)], axis=-1)
+        merged = _dense(cfg, cfg.hidden_size, (None, "embed"), "eh_proj", False)(joined)
+        is_moe = cfg.layer_is_moe(cfg.num_hidden_layers)
+        layer_cls = DeepseekDecoderLayer
+        policy = _remat_policy(cfg)
+        if policy is not None:
+            layer_cls = nn.remat(DeepseekDecoderLayer, policy=policy)
+        out, ys, _ = layer_cls(cfg, is_moe, name="layer")(merged, segment_ids, cos, sin)
+        return out, (ys[0][2] if is_moe else jnp.float32(0.0))
 
 
 class Deepseek(nn.Module):
-    """DeepSeek V2/V3 causal LM with the `CausalLMProto` surface."""
+    """DeepSeek V2/V3 causal LM with the `CausalLMProto` surface, decoding
+    through `decode_state` (dense or paged) like the Llama stack."""
 
     config: DeepseekConfig
+
+    def _layers(self, hidden, segment_ids, cos, sin, cache):
+        """-> (hidden, [(layer index, router stats, counts)] of the looped MoE
+        layers, the scanned suffix's stacked (stats, counts) or None, cache)."""
+        cfg = self.config
+        policy = _remat_policy(cfg)
+        n_scanned = cfg.num_scanned_layers
+        looped = []
+        for i in range(cfg.num_hidden_layers - n_scanned):
+            layer_cls = DeepseekDecoderLayer
+            if policy is not None:
+                layer_cls = nn.remat(DeepseekDecoderLayer, policy=policy)
+            hidden, ys, cache = layer_cls(cfg, cfg.layer_is_moe(i), name=f"layers_{i}")(
+                hidden, segment_ids, cos, sin, cache, i
+            )
+            if ys is not None:
+                looped.append((i, *ys))
+        scanned = None
+        if n_scanned:
+            body = _MoEScanBody
+            if policy is not None:
+                body = nn.remat(_MoEScanBody, policy=policy, prevent_cse=False)
+            hidden, scanned, cache = scan_layers(
+                body, (cfg,), n_scanned, hidden, (segment_ids, cos, sin), cache,
+                whole=EXPERT_LEAVES, name="moe_layers",
+            )
+        return hidden, looped, scanned, cache
 
     @nn.compact
     def __call__(
@@ -328,8 +508,17 @@ class Deepseek(nn.Module):
         inputs_embeds: jnp.ndarray | None = None,
         compute_logits: bool = True,
         return_last_hidden_states: bool = False,
+        decode_state: DecodeState | PagedDecodeState | None = None,
+        return_mtp: bool = False,
     ) -> CausalLMOutput:
+        """`return_mtp` (a config with `num_nextn_predict_layers`; needs
+        `input_ids`, also beside `inputs_embeds`): the multi-token-prediction
+        module runs too and its final-normed hidden states come back as
+        `mtp_hidden_states`, with `mtp_logits` beside them under
+        `compute_logits`. No other call runs the module."""
         cfg = self.config
+        if return_mtp and not cfg.num_nextn_predict_layers:
+            raise ValueError("return_mtp needs a config with num_nextn_predict_layers")
         embed_tokens = nn.Embed(
             num_embeddings=cfg.vocab_size,
             features=cfg.hidden_size,
@@ -345,12 +534,17 @@ class Deepseek(nn.Module):
                 raise ValueError("one of input_ids / inputs_embeds is required")
             inputs_embeds = embed_tokens(input_ids)
         hidden = inputs_embeds
-        seq = hidden.shape[1]
+        batch, seq = hidden.shape[:2]
 
         if position_ids is None:
+            if decode_state is not None:
+                raise ValueError("decoding needs position_ids: a chunk's place in its row")
             position_ids = jnp.arange(seq)[None, :]
+        # a cache sets the length the rotary tables are chosen for (yarn's
+        # long/short factors), not the chunk in hand
+        table_length = seq if decode_state is None else decode_state.table_length
         inv_freq, attention_scaling = compute_rope_frequencies(
-            cfg.rope_config, seq_len=seq
+            cfg.rope_config, seq_len=table_length
         )
         cos, sin = compute_rope_cos_sin(inv_freq, position_ids, attention_scaling)
         if cfg.rope_interleave:
@@ -358,73 +552,72 @@ class Deepseek(nn.Module):
             cos = jnp.repeat(cos[..., :half], 2, axis=-1)
             sin = jnp.repeat(sin[..., :half], 2, axis=-1)
 
-        policy = _remat_policy(cfg)
-        n_scanned = cfg.num_scanned_layers
-        ep_dropped = jnp.float32(0.0)
-        moe_sel, moe_prob, moe_ids = [], [], []
-        for i in range(cfg.num_hidden_layers - n_scanned):
-            layer_cls = DeepseekDecoderLayer
-            if policy is not None:
-                layer_cls = nn.remat(DeepseekDecoderLayer, policy=policy)
-            hidden, stats = layer_cls(cfg, cfg.layer_is_moe(i), name=f"layers_{i}")(
-                hidden, segment_ids, cos, sin
-            )
-            if stats is not None:
-                moe_sel.append(stats[0])
-                moe_prob.append(stats[1])
-                moe_ids.append(i)
-                ep_dropped = ep_dropped + stats[2]
-        if n_scanned:
-            body = _MoEScanBody
-            if policy is not None:
-                body = nn.remat(_MoEScanBody, policy=policy, prevent_cse=False)
-            scanned = nn.scan(
-                body,
-                variable_axes={"params": 0},
-                split_rngs={"params": True},
-                in_axes=(nn.broadcast, nn.broadcast, nn.broadcast),
-                length=n_scanned,
-                metadata_params={nn.PARTITION_NAME: "layers"},
-            )(cfg, name="moe_layers")
-            hidden, (sel, prob, dropped) = scanned(hidden, segment_ids, cos, sin)
-            ep_dropped = ep_dropped + dropped.sum()
+        cache, segment_ids = open_cache(decode_state, segment_ids, batch, seq)
+        hidden, looped, scanned, cache = self._layers(hidden, segment_ids, cos, sin, cache)
+        new_decode_state = close_cache(cache, decode_state, segment_ids)
+        ep_dropped = sum((stats[2] for _, stats, _ in looped), jnp.float32(0.0))
+        if scanned is not None:
+            ep_dropped = ep_dropped + scanned[0][2].sum()
 
-        hidden = RMSNorm(cfg.rms_norm_eps, cfg.param_jnp_dtype, name="norm")(hidden)
+        final_norm = RMSNorm(cfg.rms_norm_eps, cfg.param_jnp_dtype, name="norm")
+        mtp_hidden = None
+        # `init` makes the module's parameters whatever it was asked to return
+        if return_mtp or (self.is_initializing() and cfg.num_nextn_predict_layers):
+            if input_ids is None or decode_state is not None:
+                raise ValueError("the MTP module reads input_ids, and is no part of decoding")
+            with jax.named_scope("mtp"):
+                # position i is given the token after it; the row's last
+                # position has none, and predicts nothing (`lms/clm.py`)
+                next_embeds = embed_tokens(jnp.roll(input_ids, -1, axis=1))
+                mtp_hidden, mtp_dropped = MTPModule(cfg, name="mtp_0")(
+                    hidden, next_embeds, segment_ids, cos, sin
+                )
+                mtp_hidden = final_norm(mtp_hidden)
+            ep_dropped = ep_dropped + mtp_dropped
+        hidden = final_norm(hidden)
         hidden = nn.with_logical_constraint(hidden, ("batch", "act_seq", "act_embed"))
 
-        # assemble per-MoE-layer router stats in layer order (dense prefix
-        # layers carry none); DeepSeek optimizes no aux loss, but the health
-        # layer still wants the balance signal per layer
-        sel_parts = [jnp.stack(moe_sel)] if moe_sel else []
-        prob_parts = [jnp.stack(moe_prob)] if moe_prob else []
-        if n_scanned:
-            sel_parts.append(sel)
-            prob_parts.append(prob)
+        # per-MoE-layer router stats in layer order (dense prefix layers
+        # carry none); DeepSeek optimizes no aux loss, but the health layer
+        # still wants the balance signal per layer
+        stats = [jax.tree.map(lambda *leaves: jnp.stack(leaves), *(s for _, s, _ in looped))] if looped else []
+        counts = [jnp.stack([c for _, _, c in looped])] if looped and looped[0][2] is not None else []
+        moe_ids = [i for i, _, _ in looped]
+        if scanned is not None:
+            stats.append(scanned[0])
+            if scanned[1] is not None:
+                counts.append(scanned[1])
             moe_ids.extend(
-                range(cfg.num_hidden_layers - n_scanned, cfg.num_hidden_layers)
+                range(cfg.num_hidden_layers - cfg.num_scanned_layers, cfg.num_hidden_layers)
             )
         router_stats = None
-        if sel_parts:
+        if stats:
+            sel_frac, mean_prob, _ = jax.tree.map(lambda *leaves: jnp.concatenate(leaves), *stats)
             router_stats = RouterStats(
-                sel_frac=jnp.concatenate(sel_parts),
-                mean_prob=jnp.concatenate(prob_parts),
-                dropped=ep_dropped,
+                sel_frac=sel_frac, mean_prob=mean_prob, dropped=ep_dropped,
                 layer_ids=tuple(moe_ids),
             )
 
-        logits = None
+        logits = mtp_logits = None
         if compute_logits:
             if cfg.tie_word_embeddings:
-                logits = embed_tokens.attend(hidden)
+                head = embed_tokens.attend
             else:
-                logits = _dense(cfg, cfg.vocab_size, ("embed", "vocab"), "lm_head", False)(hidden)
-            logits = nn.with_logical_constraint(logits, ("batch", "act_seq", "act_vocab"))
+                head = _dense(cfg, cfg.vocab_size, ("embed", "vocab"), "lm_head", False)
+            logits = nn.with_logical_constraint(head(hidden), ("batch", "act_seq", "act_vocab"))
+            if mtp_hidden is not None:
+                mtp_logits = head(mtp_hidden)
 
         return CausalLMOutput(
             logits=logits,
             last_hidden_states=hidden if return_last_hidden_states else None,
             ep_dropped_rows=ep_dropped,
             router_stats=router_stats,
+            decode_state=new_decode_state,
+            # only a share of the experts has assignments held elsewhere to count
+            moe_assignments=jnp.concatenate(counts).sum(axis=0) if counts else None,
+            mtp_hidden_states=mtp_hidden,
+            mtp_logits=mtp_logits,
         )
 
     def get_input_embeddings_path(self) -> str:

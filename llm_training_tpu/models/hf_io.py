@@ -366,6 +366,7 @@ _ARCH_TO_FAMILY = {
     "deepseek_v2": "llm_training_tpu.models.Deepseek",  # MLA + grouped MoE
     "deepseek_v3": "llm_training_tpu.models.Deepseek",  # + sigmoid noaux routing
     "kimi_k2": "llm_training_tpu.models.Deepseek",  # Kimi-K2: V3 graph verbatim
+    "pangu_ultra_moe": "llm_training_tpu.models.Deepseek",  # openPangu: V3 without groups + sandwich norms + MTP
     "gpt_oss": "llm_training_tpu.models.GptOss",  # sink attention + clamped-swiglu MoE
     "qwen3_next": "llm_training_tpu.models.Qwen3Next",  # hybrid gated DeltaNet
     "solar_open2": "llm_training_tpu.models.SolarOpen2",  # KDA + gated NoPE GQA, config only

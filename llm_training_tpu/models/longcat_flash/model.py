@@ -46,86 +46,19 @@ from llm_training_tpu.models.base import (
     RouterStats,
 )
 from llm_training_tpu.models.cache import close_cache, open_cache, scan_layers
+from llm_training_tpu.models.deepseek.model import MLAttention
 from llm_training_tpu.models.llama.model import RMSNorm, _dense
 from llm_training_tpu.models.longcat_flash.config import LongcatFlashConfig
 from llm_training_tpu.models.moe import (
     EXPERT_LEAVES,
+    assignment_counts,
     dropless_moe_apply,
     experts_in_place,
     grouped_matmul,
     router_block_stats,
 )
 from llm_training_tpu.models.remat import remat_policy as _remat_policy
-from llm_training_tpu.ops import apply_rope, dot_product_attention
 from llm_training_tpu.ops.swiglu import silu_mul
-
-
-class LongcatMLA(nn.Module):
-    """Multi-head latent attention with the two low-rank scale factors.
-    Returns `(out, cache)`: with a `cache` (`models/cache.py`) the token's
-    latent row is appended to part `block` of it (this block's index among
-    the stack's MLA blocks) and attention runs against that part."""
-
-    config: LongcatFlashConfig
-
-    @nn.compact
-    def __call__(self, hidden, segment_ids, cos, sin, cache=None, block=None):
-        cfg = self.config
-        batch, seq, _ = hidden.shape
-        heads, nope, rope = cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-        latent, v_dim = cfg.kv_lora_rank, cfg.v_head_dim
-        norm = lambda name: RMSNorm(cfg.rms_norm_eps, cfg.param_jnp_dtype, name=name)
-
-        with jax.named_scope("mla_q"):
-            c_q = norm("q_a_layernorm")(
-                _dense(cfg, cfg.q_lora_rank, ("embed", None), "q_a_proj", False)(hidden)
-            )
-            q = _dense(cfg, heads * cfg.qk_head_dim, (None, "heads"), "q_b_proj", False)(c_q)
-            q = (q * jnp.asarray(cfg.q_scale, q.dtype)).reshape(batch, seq, heads, nope + rope)
-            q_nope, q_rope = q[..., :nope], q[..., nope:]
-        with jax.named_scope("mla_kv"):
-            compressed = _dense(
-                cfg, latent + rope, ("embed", None), "kv_a_proj_with_mqa", False
-            )(hidden)
-            c_kv = norm("kv_a_layernorm")(compressed[..., :latent])
-            c_kv = c_kv * jnp.asarray(cfg.kv_scale, c_kv.dtype)
-            # one rotated key a token, shared by the heads; it is not scaled
-            q_rope, k_rope = apply_rope(
-                q_rope, compressed[..., None, latent:], cos, sin, interleaved=True
-            )
-            w_kvb = self.param(
-                "kv_b_proj",
-                nn.with_logical_partitioning(
-                    nn.initializers.normal(cfg.initializer_range), (None, "heads", None)
-                ),
-                (latent, heads, nope + v_dim),
-                cfg.param_jnp_dtype,
-            ).astype(cfg.compute_jnp_dtype)
-
-        scale = cfg.qk_head_dim ** -0.5
-        if cache is not None:
-            row = jnp.concatenate([c_kv, k_rope[:, :, 0]], axis=-1)
-            out, cache = cache.attend_latent(
-                block, q_nope, q_rope, row, w_kvb, segment_ids, scale=scale
-            )
-        else:
-            with jax.named_scope("mla_expand"):
-                kv = jnp.einsum(
-                    "bsl,lhe->bshe", c_kv, w_kvb, preferred_element_type=jnp.float32
-                ).astype(c_kv.dtype)
-            with jax.named_scope("mla_attend"):
-                k = jnp.concatenate(
-                    [kv[..., :nope], jnp.broadcast_to(k_rope, (batch, seq, heads, rope))], axis=-1
-                )
-                # the kernels want one head size: v zero-padded to the keys'
-                v = jnp.pad(kv[..., nope:], ((0, 0),) * 3 + ((0, nope + rope - v_dim),))
-                out = dot_product_attention(
-                    jnp.concatenate([q_nope, q_rope], axis=-1), k, v,
-                    segment_ids=segment_ids, causal=True, scale=scale, impl=cfg.attention_impl,
-                )[..., :v_dim]
-        with jax.named_scope("mla_out"):
-            out = out.astype(hidden.dtype).reshape(batch, seq, heads * v_dim)
-            return _dense(cfg, cfg.hidden_size, ("heads", "embed"), "o_proj", False)(out), cache
 
 
 class LongcatMLP(nn.Module):
@@ -242,12 +175,7 @@ class LongcatMoE(nn.Module):
             out = out + (zero_weight * xc.astype(jnp.float32)).astype(out.dtype)
 
         sel_frac, mean_prob = router_block_stats(topk_idx, scores, width, pad_mask)
-        live = jnp.ones((x.shape[0], 1), bool) if pad_mask is None else pad_mask.reshape(-1, 1)
-        held_here = (topk_idx >= first) & (topk_idx < first + num_held)
-        counts = jnp.stack([
-            jnp.sum(live & held_here), jnp.sum(live & is_zero),
-            jnp.sum(live & ~held_here & ~is_zero),
-        ]).astype(jnp.int32)
+        counts = assignment_counts(topk_idx, first, num_held, pad_mask, is_zero)
         return (
             out.reshape(batch, seq, embed).astype(hidden.dtype),
             (sel_frac, mean_prob, dropped), counts,
@@ -264,7 +192,9 @@ class _SubBlock(nn.Module):
     def __call__(self, x, segment_ids, cos, sin, cache=None, block=None):
         cfg = self.config
         norm = lambda name: RMSNorm(cfg.rms_norm_eps, cfg.param_jnp_dtype, name=name)
-        attn, cache = LongcatMLA(cfg, name="self_attn")(
+        attn, cache = MLAttention(
+            cfg, q_scale=cfg.q_scale, kv_scale=cfg.kv_scale, kv_b_stacked=True, name="self_attn"
+        )(
             norm("input_layernorm")(x), segment_ids, cos, sin, cache, block
         )
         h = x + attn

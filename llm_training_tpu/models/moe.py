@@ -116,6 +116,22 @@ def _gmm_tiling(rows: int, k: int, n: int, itemsize: int) -> tuple[int, int, int
     return tm, tk, tn
 
 
+def assignment_counts(topk_idx, first: int, num_held: int, pad_mask=None, is_zero=None):
+    """`[3]` int32 for a SHARE of the experts (`CausalLMOutput.
+    moe_assignments`): of `topk_idx [T, K]`, the assignments to the experts
+    held here (`first .. first + num_held`), to zero-compute experts
+    (`is_zero [T, K]`; a router without them: 0) and to experts held
+    elsewhere; tokens `pad_mask` marks as padding are left out."""
+    live = jnp.ones((topk_idx.shape[0], 1), bool) if pad_mask is None else pad_mask.reshape(-1, 1)
+    held_here = (topk_idx >= first) & (topk_idx < first + num_held)
+    held = jnp.sum(live & held_here)
+    if is_zero is None:
+        zero, elsewhere = jnp.int32(0), live & ~held_here
+    else:
+        zero, elsewhere = jnp.sum(live & is_zero), live & ~held_here & ~is_zero
+    return jnp.stack([held, zero, jnp.sum(elsewhere)]).astype(jnp.int32)
+
+
 def experts_in_place(stack, local, impl: str, compute_dtype):
     """`(weights, layer)` for a layer's grouped products (`grouped_matmul`).
     `stack = (leaves, layer)` from a decoding layer scan (`models/cache.py:

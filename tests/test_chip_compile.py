@@ -608,6 +608,56 @@ def test_longcat_serve_cell_compiles_for_v5e(v5e, as_on_tpu, program):
     assert not {"kv_page_write", "paged_decode", "paged_prefill"} & set(kernels)
 
 
+# ----------------------------- the latent pool at 128 heads, under `Deepseek`
+#
+# `pangu-serve-longctx8k`: ONE pool (a latent row a token for each of the 5
+# layers' MLA blocks), the looped dense layer's block first and the scanned
+# MoE suffix's four after it; the suffix's held experts go through `gmm` in
+# place. Pinned: both programs fit beside 6.82 GB of weights; the decode step
+# calls `mla_decode` once a block (one call site in the looped layer, one in
+# the scan's body: 5 a step) at 128 heads; the append is the in-place page
+# writer; neither program produces an array of the pool's shape, of the
+# stacked pools', or of one layer's expert matrices; a chunk's temporaries
+# are a trip's [128, 512, 512] float32 scores and the expanded keys and values.
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_pangu_serve_cell_compiles_for_v5e(v5e, as_on_tpu, program):
+    from pathlib import Path
+
+    from benchmarks import common
+
+    lowered, pool, slab = _serve_program(v5e, "pangu-serve-longctx8k", program)
+    assert slab is None
+    config = common.Cell(Path(__file__).resolve().parent.parent, "pangu-serve-longctx8k").config
+    compiled = lowered.compile()  # raises what the chip's compiler would: it fits
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    layers, blocks, *page = pool.shape
+    assert (layers, blocks, page) == (5, 32 * 544 + 1, [1, 16, 640])
+    dims = ",".join(str(d) for d in page)
+    wide, narrow = config["hidden_size"], config["moe_intermediate_size"]
+    patterns = {
+        "pool": rf"bf16\[(?:1,)?{blocks},{dims}\]",
+        "stack": rf"bf16\[(?:{layers},{blocks}|{layers * blocks}),{dims}\]",
+        "experts": rf"bf16\[(?:1,)?8,(?:{wide},{narrow}|{narrow},{wide})\]",
+    }
+    counts = {k: _produced(text, p) for k, p in patterns.items()}
+    print(f"pangu-serve-longctx8k {program}: produced {counts}, "
+          f"temp {memory.temp_size_in_bytes / 1e9:.3f} GB, "
+          f"arguments {memory.argument_size_in_bytes / 1e9:.3f} GB")
+    assert counts == {"pool": 0, "stack": 0, "experts": 0}, counts
+    assert memory.alias_size_in_bytes >= pool.size * 2  # the pool is written in place
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 14e9
+    assert memory.temp_size_in_bytes < {"decode": 0.1, "prefill": 0.5}[program] * 1e9
+
+    # the looped dense layer's call, and one call site in the scan's body: 4 more
+    in_loop = _kernel_calls(text, "mla_decode", 4)
+    assert in_loop == (5 if program == "decode" else 0)
+    kernels = parse_hlo_kernels(text)
+    assert kernels.get("latent_page_write", 0) >= 1 and kernels.get("gmm", 0) >= 3
+    assert not {"kv_page_write", "paged_decode", "paged_prefill"} & set(kernels)
+
+
 # --------------------------------------------- the cell with two page groups
 #
 # `trinity-serve-mixedlen`: 4 layers keep every token of 16 requests of 12,800
