@@ -59,7 +59,9 @@ PLATFORM = "tpu"
 CONFIG_OVERRIDES: list[str] = []
 FLASH_SHAPE = dict(batch=1, seq=2048, q_heads=32, kv_heads=8, head_dim=128)
 PAGED_SHAPE = dict(batch=4, q_heads=32, kv_heads=8, head_dim=128, max_len=512)
-PAGED_CHUNK = 128  # queries a row of the chunk the prefill kernel is checked on
+PAGED_CHUNK = 128  # queries a row of the chunk the prefill kernels are checked on
+# LongCat-Flash's latent attention: 64 heads over rows of 512 + 64 values, stored 640 wide
+LATENT_SHAPE = dict(batch=4, heads=64, latent=512, nope=128, rope=64, v=128, width=640, max_len=512)
 SERVE_PROMPT_LENS = (200, 320, 480, 700, 1024, 1400, 1800, 2000)
 SERVE_NEW_TOKENS = 32
 SERVE_FLAGS = [
@@ -242,6 +244,7 @@ def phase_kernels(chips: int) -> dict:
     import numpy as np
 
     from llm_training_tpu.ops.attention import dot_product_attention
+    from llm_training_tpu.ops.latent_attention import paged_latent_attention
     from llm_training_tpu.ops.paged_attention import paged_cached_attention
     from llm_training_tpu.ops.pallas.flash_attention import flash_attention
 
@@ -355,9 +358,64 @@ def phase_kernels(chips: int) -> dict:
         f"[{describe(device)}]: "
         + ", ".join(f"{n} {e:.2e}" for n, e in paged_errors.items()),
     )
+    # ---- the latent (MLA) pool: a decoded token a row (`mla_decode`, the
+    # absorbed form), then a chunk of queries a row (`mla_prefill`, the
+    # expanded one), each against `attend_rows` in XLA on the same pool
+    shape = LATENT_SHAPE
+    latent, width, page = shape["latent"], shape["width"], 16
+    pages = -(-shape["max_len"] // page)
+    keys = jax.random.split(jax.random.key(latent), 5)
+    rows = lambda key, dims: jax.random.normal(key, dims, jnp.bfloat16).at[
+        ..., latent + shape["rope"]:
+    ].set(0)  # a stored row is `[c_kv | k_r | zeros]`
+    pool = rows(keys[0], (1 + batch * pages, 1, page, width))
+    w_kvb = (
+        jax.random.normal(keys[1], (latent, shape["heads"], shape["nope"] + shape["v"])) * latent**-0.5
+    ).astype(jnp.bfloat16)
+    tables = jnp.asarray(
+        1 + np.random.default_rng(latent).permutation(batch * pages).reshape(batch, pages).astype(np.int32)
+    )
+    latent_errors = {}
+    for seq, kernel in ((1, "mla_decode"), (PAGED_CHUNK, "mla_prefill")):
+        starts = jnp.asarray(np.minimum(lengths, shape["max_len"] - seq))
+        q_nope = jax.random.normal(keys[2], (batch, seq, shape["heads"], shape["nope"]), jnp.bfloat16)
+        q_rope = jax.random.normal(keys[3], (batch, seq, shape["heads"], shape["rope"]), jnp.bfloat16)
+        row = rows(keys[4], (batch, seq, width))
+
+        def attend(impl):
+            return jax.jit(
+                lambda q_nope, q_rope, row, pool: paged_latent_attention(
+                    q_nope, q_rope, row, w_kvb, pool, starts, tables,
+                    scale=(shape["nope"] + shape["rope"]) ** -0.5, impl=impl,
+                )[0]
+            )
+
+        found = kernels_in(attend("auto").lower(q_nope, q_rope, row, pool).compile())
+        say(
+            phase,
+            f"latent: impl='auto' with {seq} quer{'y' if seq == 1 else 'ies'} a row "
+            f"compiles to [{describe(device)}]: {found}",
+        )
+        check(
+            PLATFORM != "tpu" or kernel in found,
+            f"latent: impl='auto' did not put {kernel} in the program: {found}",
+        )
+        latent_errors[kernel] = relative_error(
+            attend("pallas")(q_nope, q_rope, row, pool), attend("xla")(q_nope, q_rope, row, pool)
+        )
+        check(
+            math.isfinite(latent_errors[kernel]) and latent_errors[kernel] <= KERNEL_TOL,
+            f"latent: {kernel} differs from the xla reference by {latent_errors[kernel]:.3e} (> {KERNEL_TOL})",
+        )
+    say(
+        phase,
+        f"latent kernels vs xla at ({shape['heads']} heads, rows of {latent} + {shape['rope']}), "
+        f"ragged lengths {lengths.tolist()}, max error / max |ref| [{describe(device)}]: "
+        + ", ".join(f"{n} {e:.2e}" for n, e in latent_errors.items()),
+    )
     log.report(phase, device)
     return {"device": device, "cache": log.summary(),
-            "flash_errors": errors, "paged_errors": paged_errors}
+            "flash_errors": errors, "paged_errors": paged_errors, "latent_errors": latent_errors}
 
 
 def cli(argv: list[str]) -> int:

@@ -200,9 +200,9 @@ class LayerCache:
         attend `q_nope [B, S, H, nope]`, `q_rope [B, S, H, rope]` against that
         part; `w_kvb [latent, H, nope + v]` is the block's up-projection.
         Returns `(out [B, S, H, v], the cache holding the new buffer)`. One
-        token a row attends in the absorbed form (the paged kernel's), a
-        chunk in the expanded one (`ops/latent_attention.py`). `layer` counts
-        MLA blocks."""
+        token a row attends in the absorbed form (paged: `mla_decode`), a
+        chunk in the expanded one (paged: `mla_prefill`; both kernels on a
+        TPU, `ops/latent_attention.py`). `layer` counts MLA blocks."""
         from llm_training_tpu.ops import latent_attention
 
         row = jnp.pad(row, ((0, 0), (0, 0), (0, self.k.shape[-1] - row.shape[-1])))

@@ -5,21 +5,29 @@ Two forms of the same numbers (docs/inference.md, "How a layer meets its
 cache"), with `W_kvb [latent, H, nope + v]` split a head into `W_uk` and
 `W_uv`:
 
-- expanded: the rows' latents go through `W_kvb` to 64 heads' keys and
+- expanded: the rows' latents go through `W_kvb` to every head's keys and
   values and the chunk attends at the heads' own widths. The projection is
-  paid once a cached row, whatever the number of queries: the prefill route.
+  paid once a cached row, whatever the number of queries: the prefill route,
+  whose kernel is `ops/pallas/mla_prefill.py`.
 - absorbed: `W_uk` is folded into the query (`q~_h = W_uk_h q_nope_h`), the
   score is one dot product over the row, the output the weighted sum of the
   rows' latents taken through `W_uv` afterwards. Nothing of the heads' width
   is made of a cached row: the decode route, whose kernel is
   `ops/pallas/mla_decode.py`.
 
-`attend_rows` runs either over rows brought a TRIP at a time with an online
-softmax, so a paged chunk reads the pages its rows have and not the table's
-width (the trip count is traced), and a dense buffer is one trip.
 `paged_latent_attention` is the paged entry: in-place page append
-(`ops/paged_attention.py:latent_append`), then the kernel for single-token
-decode on a TPU, `attend_rows` over the row's pages otherwise.
+(`ops/paged_attention.py:latent_append`), then, on a TPU, `mla_decode` for
+one token a row and `mla_prefill` for a chunk: the pool stays in HBM, a
+row's pages come by double-buffered trips, and the softmax state (and, for a
+chunk, the expanded keys and values of a trip) never leaves VMEM.
+
+`attend_rows` is the same mathematics in XLA: the CPU path, the dense-buffer
+path (`LayerCache.attend_latent` without pages: one trip) and the oracle both
+kernels are tested against. It runs either form over rows brought a TRIP at a
+time with an online softmax, so a paged chunk reads the pages its rows have
+and not the table's width (the trip count is traced); every trip's float32
+scores `[B, H, S, T]` and statistics are arrays of their own, which is what
+the chunk kernel keeps out of HBM.
 """
 
 from __future__ import annotations
@@ -27,11 +35,17 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from llm_training_tpu.ops.paged_attention import _on_kernels, _over_heads, latent_append
+from llm_training_tpu.ops.paged_attention import (
+    _count_chunk_kernel_layers,
+    _on_kernels,
+    _over_heads,
+    latent_append,
+)
 
 _MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 # cached tokens a trip of the paged XLA path brings: a chunk of 512 queries
-# and 64 heads holds [64, 512, 512] float32 scores a trip
+# and 64 heads holds [64, 512, 512] float32 scores a trip (off the chip only:
+# on a TPU a chunk attends in `mla_prefill`)
 _TRIP_TOKENS = 512
 
 
@@ -132,7 +146,10 @@ def paged_latent_attention(
     paged_attention.py:paged_cached_attention`). `lengths [B]` counts the
     tokens each row holds BEFORE this chunk. `absorbed`: None takes the
     absorbed form for one token a row and the expanded one for a chunk.
-    Returns `(out [B, S, H, v], the pool)`."""
+    impl: 'auto' (the Pallas kernels on a TPU: the page writer, `mla_decode`
+    for one token a row, `mla_prefill` for a chunk; XLA elsewhere) | 'pallas'
+    (kernels forced, interpreted off-TPU) | 'xla'. Returns `(out [B, S, H,
+    v], the pool)`."""
     latent, _, _ = w_kvb.shape
     nope = q_nope.shape[-1]
     batch, seq = q_nope.shape[:2]
@@ -160,6 +177,22 @@ def paged_latent_attention(
                 (q[:, 0], pool, block_tables, lengths + 1), (1, None, None, None), 0,
             )[:, None]
         return project_values(weighted, w_kvb[..., nope:]), pool.reshape(stack_shape)
+    if seq > 1 and not absorbed and _on_kernels(impl):
+        from llm_training_tpu.ops.pallas.mla_prefill import mla_prefill_attention
+
+        with jax.named_scope("mla_attend"):
+            out = _over_heads(
+                lambda q_nope, q_rope, w_kvb, pool, tables, lens: mla_prefill_attention(
+                    q_nope, q_rope, w_kvb, pool, tables, lens, scale=scale
+                ),
+                (q_nope, q_rope, w_kvb, pool, block_tables, lengths),
+                (2, 2, 1, None, None, None), 0,
+            )
+            if segment_ids is not None:
+                # a padded query emits exactly 0, as on the XLA path
+                out = jnp.where((segment_ids > 0)[:, :, None, None], out, 0)
+        _count_chunk_kernel_layers(1 if layer is None else stack_shape[0], "latent")
+        return out, pool.reshape(stack_shape)
 
     num_pages = block_tables.shape[1]
     trip_pages = min(num_pages, max(1, _TRIP_TOKENS // page_size))

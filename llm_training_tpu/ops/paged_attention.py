@@ -60,11 +60,11 @@ from llm_training_tpu.ops.attention import _xla_attention
 from llm_training_tpu.telemetry.registry import get_registry
 
 
-# how many key/value layers' chunk attention the serving programs traced last
-# run in the `paged_prefill` kernel; `serve/engine.py` zeroes it before it
-# builds its programs
+# how many layers' chunk attention the serving programs traced last run in a
+# kernel (`paged_prefill`; `mla_prefill` for latent layers); `serve/engine.py`
+# zeroes it before it builds its programs
 CHUNK_KERNEL_GAUGE = "decode/chunk_attention_kernel_layers"
-_chunk_kernel_layers: dict[bool, int] = {}
+_chunk_kernel_layers: dict[bool | str, int] = {}
 
 
 def reset_chunk_kernel_layers() -> None:
@@ -72,11 +72,11 @@ def reset_chunk_kernel_layers() -> None:
     get_registry().gauge(CHUNK_KERNEL_GAUGE).set(0)
 
 
-def _count_chunk_kernel_layers(layers: int, ring: bool) -> None:
-    """Said when a chunk's attention is traced into the kernel: the call
-    stands for every layer of the stack it addresses, and a stack with two
-    page groups (one behind a ring table) makes such calls for each."""
-    _chunk_kernel_layers[ring] = layers
+def _count_chunk_kernel_layers(layers: int, group: bool | str) -> None:
+    """Said when a chunk's attention is traced into its kernel: the call
+    stands for every layer of the stack it addresses; each of two page groups
+    (`group`: is it behind a ring table) says its own, a latent stack "latent"."""
+    _chunk_kernel_layers[group] = layers
     get_registry().gauge(CHUNK_KERNEL_GAUGE).set(sum(_chunk_kernel_layers.values()))
 
 

@@ -292,9 +292,10 @@ def _serving_section(telemetry: dict) -> list[str]:
     if in_place:
         lines.append(f"expert weights: read in place in {int(in_place)} layers")
     in_kernel = num("decode/chunk_attention_kernel_layers")
-    if in_kernel:
-        lines.append(f"chunk attention: in the paged_prefill kernel in {int(in_kernel)} layers")
     latent = num("decode/latent_pool_bytes")
+    if in_kernel:
+        kernel = "mla_prefill" if latent else "paged_prefill"
+        lines.append(f"chunk attention: in the {kernel} kernel in {int(in_kernel)} layers")
     if latent:
         lines.append(f"latent (MLA) pool: {latent / 2**20:.1f} MiB, one row a token a block")
     held, zero, elsewhere = (num(f"serve/moe_{k}_assignments") for k in ("held", "zero", "elsewhere"))

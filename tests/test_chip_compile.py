@@ -298,6 +298,95 @@ def test_paged_prefill_refuses_untileable_shapes_when_compiled():
         paged_prefill_attention(q, pool, pool, tables, jnp.ones((2,), jnp.int32), interpret=False)
 
 
+@pytest.mark.parametrize("heads,blocks,pages", [
+    # the two latent serve cells as the chunk kernel sees them: one row, 512
+    # queries, every MLA block's pool seen as one, the table's width
+    pytest.param(128, 5 * (32 * 544 + 1), 544, id="pangu-128-heads-8704"),
+    pytest.param(64, 8 * (32 * 352 + 1), 352, id="longcat-64-heads-5632"),
+])
+def test_mla_prefill_compiles_for_v5e(v5e, heads, blocks, pages):
+    """The latent chunk kernel at rows of 640 (512 + 64 and zeros), heads of
+    128 + 64 / 128, pages of 16, bf16: the pool goes to it in place, 16 heads a
+    grid step by the shapes, and no float32 array of a trip's scores, of its
+    statistics or of all heads' accumulators comes out of the program, nor
+    one with more cached tokens than a trip for an axis."""
+    import re
+
+    from llm_training_tpu.ops.pallas.mla_prefill import head_block, mla_prefill_attention
+
+    assert head_block(heads, 512, 512, 128, 128, 128, 2) == 16
+    one = SingleDeviceSharding(v5e.devices[0])
+    shape = lambda dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+    compiled = jax.jit(
+        lambda q_nope, q_rope, w_kvb, pool, tables, lens: mla_prefill_attention(
+            q_nope, q_rope, w_kvb, pool, tables, lens, scale=192 ** -0.5, interpret=False
+        )
+    ).lower(
+        shape((1, 512, heads, 128)), shape((1, 512, heads, 64)), shape((512, heads, 256)),
+        shape((blocks, 1, 16, 640)), shape((1, pages), jnp.int32), shape((1,), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert parse_hlo_kernels(text) == {"mla_prefill": 1}
+    # the queries and the output laid out a block of heads abreast: copies, no more
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.3e6 * heads
+    produced = [
+        line for line in text.splitlines()
+        if " parameter(" not in line and (
+            f"= bf16[{blocks},1,16,640]" in line
+            or re.search(rf"= f32\[(?:1,)?{heads},512(?:,\d+)?\]", line)
+            or re.search(rf"= \w+\[[\d,]*\b{pages * 16}\b[\d,]*\]", line)
+        )
+    ]
+    assert not produced, produced
+
+
+def test_mla_prefill_compiles_on_a_sharded_mesh_for_v5e(v5e, as_on_tpu):
+    """A chunk through `paged_latent_attention` with the heads sharded over
+    `tensor`: the page writer and the chunk kernel, each in its shard_map
+    (`_over_heads`; the pool has no head axis and is replicated), 32 heads a
+    chip of LongCat's 64."""
+    import numpy as np
+
+    from llm_training_tpu.ops.latent_attention import paged_latent_attention
+    from llm_training_tpu.parallel.mesh import MESH_AXIS_NAMES
+
+    mesh = Mesh(np.asarray(v5e.devices).reshape(1, 1, 2, 1, 2, 1), MESH_AXIS_NAMES)
+
+    def shape(dims, dtype, spec):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=NamedSharding(mesh, spec))
+
+    batch, seq, heads, blocks, pages = 2, 512, 64, 512, 128
+    by_head = P(None, None, "tensor", None)
+    with mesh:
+        found = _kernels(
+            lambda q_nope, q_rope, row, w_kvb, pool, lens, tables, seg: paged_latent_attention(
+                q_nope, q_rope, row, w_kvb, pool, lens, tables, segment_ids=seg,
+                scale=192 ** -0.5, impl="auto",
+            )[0],
+            shape((batch, seq, heads, 128), jnp.bfloat16, by_head),
+            shape((batch, seq, heads, 64), jnp.bfloat16, by_head),
+            shape((batch, seq, 640), jnp.bfloat16, P()),
+            shape((512, heads, 256), jnp.bfloat16, P(None, "tensor", None)),
+            shape((blocks, 1, 16, 640), jnp.bfloat16, P()),
+            shape((batch,), jnp.int32, P()), shape((batch, pages), jnp.int32, P()),
+            shape((batch, seq), jnp.int32, P()),
+        )
+    assert found == {"latent_page_write": 1, "mla_prefill": 1}, found
+
+
+def test_mla_prefill_refuses_untileable_shapes_when_compiled():
+    """As the paged kernels: a width Mosaic cannot tile raises on the chip's
+    path, and is not routed to the XLA path."""
+    from llm_training_tpu.ops.pallas.mla_prefill import mla_prefill_attention
+
+    with pytest.raises(ValueError, match="mla_prefill kernel .* tail 64"):
+        mla_prefill_attention(
+            jnp.zeros((1, 16, 4, 128)), jnp.zeros((1, 16, 4, 64)), jnp.zeros((512, 4, 256)),
+            jnp.zeros((3, 1, 16, 576)), jnp.zeros((1, 2), jnp.int32), jnp.zeros((1,), jnp.int32),
+            scale=1.0, interpret=False,
+        )
+
+
 def test_interpret_is_impossible_on_a_tpu_backend(monkeypatch):
     assert resolve_interpret(None) is True  # the CPU test path
     assert resolve_interpret(False) is False  # compiling for a described device
@@ -525,6 +614,8 @@ def _check_serve_program(v5e, cell, program):
     # and a chunk attends in `paged_prefill`, once a pool layer
     assert _kernel_calls(text, "paged_prefill", layers) == (0 if program == "decode" else layers)
     assert parse_hlo_kernels(text).get("kv_page_write", 0) >= 1  # the append's writer
+    # no latent cache here: none of the latent kernels, nor their page writer
+    assert not {"mla_prefill", "mla_decode", "latent_page_write"} & set(parse_hlo_kernels(text))
 
     counts = {k: _produced(text, p) for k, p in patterns.items() if p}
     counts.setdefault("stack", 0)
@@ -570,6 +661,32 @@ def test_rollout_cells_update_the_pool_in_place_for_v5e(v5e, as_on_tpu, cell, pr
 # pools', or of one layer's expert matrices.
 
 
+def _check_chunk_attends_in_mla_prefill(text, program, heads, blocks, repeats):
+    """A chunk program calls `mla_prefill` once a latent block, under
+    `mla_attend`, and the gauge says so; it holds no float32 array of a
+    trip's scores `[heads, 512, 512]`, of their statistics `[heads, 512]` or
+    of all heads' accumulators `[1, heads, 512, 128]`, and nothing under
+    `mla_expand`: a trip's latents are expanded inside the kernel. A decode
+    step calls it never."""
+    import re
+
+    from llm_training_tpu.telemetry import get_registry
+
+    chunk = program == "prefill"
+    assert _kernel_calls(text, "mla_prefill", repeats) == (blocks if chunk else 0)
+    assert _kernel_calls(text, "mla_prefill", repeats, under="mla_attend") == (blocks if chunk else 0)
+    if chunk:
+        # set when the program was traced: every block of the stack
+        assert get_registry().gauge("decode/chunk_attention_kernel_layers").value == blocks
+        assert "mla_expand" not in text  # the expansion runs inside the kernel
+        trips = re.compile(rf"= f32\[(?:1,)?{heads},512(?:,\d+)?\]")
+        made = [
+            line for line in text.splitlines()
+            if " parameter(" not in line and trips.search(line.split(" metadata=")[0])
+        ]
+        assert not made, made[:3]
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill"])
 def test_longcat_serve_cell_compiles_for_v5e(v5e, as_on_tpu, program):
     from pathlib import Path
@@ -597,12 +714,14 @@ def test_longcat_serve_cell_compiles_for_v5e(v5e, as_on_tpu, program):
     assert counts == {"pool": 0, "stack": 0, "experts": 0}, counts
     assert memory.alias_size_in_bytes >= pool.size * 2  # the pool is written in place
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 14e9
-    # a step's temporaries are its rows' activations; a chunk holds a trip's
-    # [64, 512, 512] float32 scores and the expanded keys and values
-    assert memory.temp_size_in_bytes < {"decode": 0.02, "prefill": 0.2}[program] * 1e9
+    # a step's temporaries are its rows' activations, and so are a chunk's since
+    # its attention runs in `mla_prefill` (0.093 GB; 0.2 were allowed until PR 42: a trip's
+    # [64, 512, 512] float32 scores and the expanded keys and values of all heads)
+    assert memory.temp_size_in_bytes < {"decode": 0.02, "prefill": 0.12}[program] * 1e9
 
-    # two call sites in the layer loop's body: 8 calls a step
+    # two call sites in the layer loop's body: 8 calls a step, 8 a chunk
     assert _kernel_calls(text, "mla_decode", layers // 2) == (layers if program == "decode" else 0)
+    _check_chunk_attends_in_mla_prefill(text, program, heads=64, blocks=layers, repeats=layers // 2)
     kernels = parse_hlo_kernels(text)
     assert kernels.get("latent_page_write", 0) >= 1 and kernels.get("gmm", 0) >= 3
     assert not {"kv_page_write", "paged_decode", "paged_prefill"} & set(kernels)
@@ -617,8 +736,8 @@ def test_longcat_serve_cell_compiles_for_v5e(v5e, as_on_tpu, program):
 # calls `mla_decode` once a block (one call site in the looped layer, one in
 # the scan's body: 5 a step) at 128 heads; the append is the in-place page
 # writer; neither program produces an array of the pool's shape, of the
-# stacked pools', or of one layer's expert matrices; a chunk's temporaries
-# are a trip's [128, 512, 512] float32 scores and the expanded keys and values.
+# stacked pools', or of one layer's expert matrices; a chunk attends in
+# `mla_prefill`, 5 calls, and holds no float32 array of a trip's scores.
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
@@ -648,11 +767,13 @@ def test_pangu_serve_cell_compiles_for_v5e(v5e, as_on_tpu, program):
     assert counts == {"pool": 0, "stack": 0, "experts": 0}, counts
     assert memory.alias_size_in_bytes >= pool.size * 2  # the pool is written in place
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 14e9
-    assert memory.temp_size_in_bytes < {"decode": 0.1, "prefill": 0.5}[program] * 1e9
+    # (a chunk 0.087 GB; 0.175 until PR 42, 0.5 allowed: a trip's [128, 512, 512] float32 scores)
+    assert memory.temp_size_in_bytes < {"decode": 0.1, "prefill": 0.12}[program] * 1e9
 
     # the looped dense layer's call, and one call site in the scan's body: 4 more
     in_loop = _kernel_calls(text, "mla_decode", 4)
     assert in_loop == (5 if program == "decode" else 0)
+    _check_chunk_attends_in_mla_prefill(text, program, heads=128, blocks=5, repeats=4)
     kernels = parse_hlo_kernels(text)
     assert kernels.get("latent_page_write", 0) >= 1 and kernels.get("gmm", 0) >= 3
     assert not {"kv_page_write", "paged_decode", "paged_prefill"} & set(kernels)
@@ -715,6 +836,7 @@ def test_trinity_serve_cell_keeps_window_layers_inside_their_budget_for_v5e(v5e,
     assert _kernel_calls(text, mine, 3, under="attn_global") == 4
     kernels = parse_hlo_kernels(text)
     assert kernels.get("kv_page_write", 0) >= 2 and kernels.get("gmm", 0) >= 3
+    assert not {"mla_prefill", "mla_decode", "latent_page_write"} & set(kernels)
 
 
 def test_mla_decode_refuses_a_row_that_is_not_whole_lanes(v5e):

@@ -90,8 +90,8 @@ def run_engine(model, variables, **serve):
     return engine, requests, done
 
 
-@pytest.mark.parametrize("variant", ["dense_experts", "grouped_experts_in_place"])
-def test_chunked_prefill_then_paged_decode_is_the_reference_forward(tiny, variant):
+@pytest.mark.parametrize("variant", ["dense_experts", "grouped_experts_in_place", "latent_kernels"])
+def test_chunked_prefill_then_paged_decode_is_the_reference_forward(tiny, variant, monkeypatch):
     """Prompts of 19, 5, 11, 30 and 3 tokens in chunks of 8 (chunks of unequal
     length, the last one padded), five requests through two slots (the later
     ones join mid-flight into recycled blocks, whose stale latents lie past
@@ -100,9 +100,21 @@ def test_chunked_prefill_then_paged_decode_is_the_reference_forward(tiny, varian
     against the reference's full forward, so a stale latent page, a block of
     the looped prefix and the scanned suffix mixed up, or a wrong rotary
     position fails. Also with the held experts multiplied in place by the
-    grouped product (`moe_impl='ragged'`, the chip's path)."""
+    grouped product (`moe_impl='ragged'`, the chip's path), and with the
+    chip's latent kernels interpreted: a chunk in `mla_prefill`, a token a
+    row in `mla_decode`, the append in `latent_page_write`."""
     _, variables = tiny
-    over = {"dense_experts": {}, "grouped_experts_in_place": {"moe_impl": "ragged"}}[variant]
+    over = {
+        "dense_experts": {}, "grouped_experts_in_place": {"moe_impl": "ragged"}, "latent_kernels": {},
+    }[variant]
+    if variant == "latent_kernels":
+        from llm_training_tpu.ops import latent_attention
+
+        attend = latent_attention.paged_latent_attention
+        monkeypatch.setattr(
+            latent_attention, "paged_latent_attention",
+            lambda *args, **kwargs: attend(*args, **{**kwargs, "impl": "pallas"}),
+        )
     model = Deepseek(DeepseekConfig(**{**TINY, **over}))
     with jax.default_matmul_precision("highest"):
         engine, requests, done = run_engine(model, variables)
@@ -117,6 +129,8 @@ def test_chunked_prefill_then_paged_decode_is_the_reference_forward(tiny, varian
     assert zero == 0 and held > 0 and elsewhere > 0 and (held + elsewhere) % 8 == 0
     if variant == "grouped_experts_in_place":
         assert stats["decode/experts_in_place_layers"] == 2  # the scanned suffix's two
+    # every MLA block's chunk attention in the kernel, or none
+    assert stats["decode/chunk_attention_kernel_layers"] == (3 if variant == "latent_kernels" else 0)
 
 
 @pytest.mark.parametrize("family", ["deepseek_v3_groups", "deepseek_v2_full_rank_q"])

@@ -761,35 +761,41 @@ def test_engine_step_says_where_its_chunk_starts(fresh):
     assert sorted({args["prefill_start"] for args in steps.values()}) == [0, 4]
 
 
-@pytest.mark.parametrize("build,impl,layers", [
-    pytest.param("llama", None, 0, id="xla-off-the-chip"),
-    pytest.param("llama", "pallas", 2, id="kernel"),
-    pytest.param("afmoe", "pallas", 8, id="kernel-two-page-groups"),
+@pytest.mark.parametrize("build,impl,layers,kernel", [
+    pytest.param("llama", None, 0, "paged_prefill", id="xla-off-the-chip"),
+    pytest.param("llama", "pallas", 2, "paged_prefill", id="kernel"),
+    pytest.param("afmoe", "pallas", 8, "paged_prefill", id="kernel-two-page-groups"),
+    pytest.param("longcat", None, 0, "mla_prefill", id="latent-xla-off-the-chip"),
+    pytest.param("longcat", "pallas", 4, "mla_prefill", id="latent-kernel"),
 ])
 def test_gauge_counts_the_layers_whose_chunk_attention_runs_in_the_kernel(
-    fresh, monkeypatch, build, impl, layers
+    fresh, monkeypatch, build, impl, layers, kernel
 ):
     """`decode/chunk_attention_kernel_layers`: set when the prefill program is
     traced, every key/value layer of the stack (both page groups' where it has
-    two) when a chunk attends in `paged_prefill`, 0 on the CPU's gather path;
-    `stats()` holds it and `report` says it."""
-    from llm_training_tpu.ops import paged_attention
+    two) when a chunk attends in `paged_prefill`, every latent (MLA) block
+    when it attends in `mla_prefill`, 0 on the CPU's XLA paths; `stats()`
+    holds it and `report` says it, with the kernel's name."""
+    from llm_training_tpu.ops import latent_attention, paged_attention
     from llm_training_tpu.telemetry.report import _serving_section
 
     if impl is not None:
-        attend = paged_attention.paged_cached_attention
-        monkeypatch.setattr(
-            paged_attention, "paged_cached_attention",
-            lambda *args, **kwargs: attend(*args, **{**kwargs, "impl": impl}),
-        )
+        for module, name in (
+            (paged_attention, "paged_cached_attention"), (latent_attention, "paged_latent_attention"),
+        ):
+            attend = getattr(module, name)
+            monkeypatch.setattr(
+                module, name,
+                lambda *args, attend=attend, **kwargs: attend(*args, **{**kwargs, "impl": impl}),
+            )
     get_registry().gauge("decode/chunk_attention_kernel_layers").set(5)  # another engine's
-    engine = {"llama": _engine, "afmoe": _afmoe_engine}[build]()
+    engine = {"llama": _engine, "afmoe": _afmoe_engine, "longcat": _longcat_engine}[build]()
     assert get_registry().gauge("decode/chunk_attention_kernel_layers").value == 0  # nothing traced yet
     engine.run(_requests(2))
     assert get_registry().gauge("decode/chunk_attention_kernel_layers").value == layers
     stats = engine.stats()
     assert stats["decode/chunk_attention_kernel_layers"] == layers
-    said = f"chunk attention: in the paged_prefill kernel in {layers} layers" in _serving_section(stats)
+    said = f"chunk attention: in the {kernel} kernel in {layers} layers" in _serving_section(stats)
     assert said == bool(layers)
 
 
