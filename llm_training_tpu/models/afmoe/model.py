@@ -50,7 +50,7 @@ from llm_training_tpu.models.base import (
 from llm_training_tpu.models.cache import close_cache, open_cache, scan_layers
 from llm_training_tpu.models.deepseek.model import DeepseekMLP, DeepseekMoE
 from llm_training_tpu.models.llama.model import RMSNorm, _dense
-from llm_training_tpu.models.moe import EXPERT_LEAVES
+from llm_training_tpu.models.moe import EXPERT_LEAVES, decoding_experts
 from llm_training_tpu.models.remat import remat_policy as _remat_policy
 from llm_training_tpu.ops import apply_rope, dot_product_attention
 
@@ -156,7 +156,7 @@ class _Layers(nn.Module):
                 self.first[is_window] + cycle * windows.count(is_window)
                 + windows[:j].count(is_window)
             )
-            experts = None if stack is None or not is_moe else (stack[f"slot{j}"]["mlp"], cycle)
+            experts = decoding_experts(cache, stack, cycle, f"slot{j}", "mlp") if is_moe else None
             hidden, layer_stats, cache = AfmoeDecoderLayer(
                 cfg, is_window, is_moe, name=f"slot{j}"
             )(hidden, segment_ids, cos, sin, cache, index, experts)

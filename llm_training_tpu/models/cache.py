@@ -337,9 +337,9 @@ def scan_layers(body, args: tuple, length: int, hidden, inputs: tuple,
     A body that names some is called with one more argument, `stack=`:
     those leaves whole, `[L, ...]`, nested under the body's module names as
     its params are and closed over like the rest of the cache; None where
-    the stack has no such leaf, or one layer only (a length-1 slice is a
-    view already). The body still owns its slices of them; a layer that
-    reads the stack leaves them unread, and the compiler drops the cut."""
+    the stack has no such leaf. The body still owns its slices of them; a
+    layer that reads the stack leaves them unread, and the compiler drops
+    the cut."""
     decoding = cache is not None
     extra = ((nn.broadcast, 0) + (nn.broadcast,) * bool(whole)) if decoding else ()
     scanned = nn.scan(
@@ -355,8 +355,7 @@ def scan_layers(body, args: tuple, length: int, hidden, inputs: tuple,
         return hidden, ys, None
     stack = ()
     if whole:
-        found = scanned.variables.get("params", {}) if length > 1 else {}
-        stack = (_whole_leaves(found, whole),)
+        stack = (_whole_leaves(scanned.variables.get("params", {}), whole),)
     (hidden, buffers), ys = scanned(
         (hidden, cache.buffers), *inputs, cache.holding((None,) * len(_BUFFERS)),
         jnp.arange(length, dtype=jnp.int32), *stack,

@@ -58,6 +58,7 @@ from llm_training_tpu.models.llama.model import RMSNorm, _dense
 from llm_training_tpu.models.moe import (
     EXPERT_LEAVES,
     assignment_counts,
+    decoding_experts,
     dropless_moe_apply,
     experts_in_place,
     grouped_matmul,
@@ -222,9 +223,10 @@ class DeepseekMoE(nn.Module):
     and the output is their part of the routed sum plus the shared experts
     (`models.moe.dropless_moe_apply(held=...)`); no code stands in for the
     chips that hold the others. `stack = (leaves, layer)` from a decoding
-    layer scan: the experts' `EXPERT_LEAVES` whole, `[L, E, ...]`, read in
-    place (`models/moe.py:experts_in_place`); None, and the grouped products
-    are `jax.lax.ragged_dot` on this layer's own matrices."""
+    layer (`models/moe.py:decoding_experts`): the experts' `EXPERT_LEAVES`
+    whole, `[L, E, ...]`, read in place, or a looped layer's own as a stack
+    of one (`experts_in_place`); None, and the grouped products are
+    `jax.lax.ragged_dot` on this layer's own matrices."""
 
     config: DeepseekConfig
     # a third result, `counts [3]` int32: this call's assignments to experts
@@ -325,7 +327,7 @@ class DeepseekMoE(nn.Module):
             return jnp.einsum("tei,eih->teh", nn.silu(gate) * up, w_down)
 
         weights, layer = experts_in_place(
-            stack, (w_gate, w_up, w_down), cfg.moe_impl, compute_dtype
+            stack, (w_gate, w_up, w_down), cfg.moe_impl, compute_dtype, self.path
         )
 
         def ragged_fn(xs, group_sizes, expert_order, w):
@@ -377,7 +379,7 @@ class DeepseekDecoderLayer(nn.Module):
     static, so the structures are trace-time constants). `layer` is this
     layer's index in the stack, its MLA block's part of the cache; `stack =
     (leaves, index)` the scanned suffix's expert leaves whole, for a
-    decoding layer of it."""
+    decoding layer of it (a looped one hands its block its own)."""
 
     config: DeepseekConfig
     is_moe: bool
@@ -401,10 +403,12 @@ class DeepseekDecoderLayer(nn.Module):
         if self.is_moe:
             pad_mask = None if segment_ids is None else segment_ids > 0
             counts = None
+            # a looped decoding layer: its own experts, a stack of one
+            experts = decoding_experts(cache, None, 0) if stack is None else stack
             if cfg.counts_expert_assignments:
-                mlp_out, stats, counts = DeepseekMoE(cfg, True, name="mlp")(normed, pad_mask, stack)
+                mlp_out, stats, counts = DeepseekMoE(cfg, True, name="mlp")(normed, pad_mask, experts)
             else:
-                mlp_out, stats = DeepseekMoE(cfg, name="mlp")(normed, pad_mask, stack)
+                mlp_out, stats = DeepseekMoE(cfg, name="mlp")(normed, pad_mask, experts)
             ys = (stats, counts)
         else:
             mlp_out = DeepseekMLP(cfg, cfg.intermediate_size, name="mlp")(normed)
@@ -435,7 +439,7 @@ class _MoEScanBody(nn.Module):
         hidden, ys, cache = block(
             hidden, segment_ids, cos, sin, cache.holding(buffers),
             cfg.first_k_dense_replace + layer,
-            None if stack is None else (stack["layer"]["mlp"], layer),
+            decoding_experts(cache, stack, layer, "layer", "mlp"),
         )
         return (hidden, cache.buffers), ys
 

@@ -384,11 +384,11 @@ class LlamaDecoderLayer(nn.Module):
             zero scalar (the ys type is uniform across layers within one
             model — a config is either all-MoE or all-dense)."""
             if cfg.num_experts:
-                from llm_training_tpu.models.moe import MoEMLP
+                from llm_training_tpu.models.moe import MoEMLP, decoding_experts
 
                 pad_mask = None if segment_ids is None else segment_ids > 0
                 return MoEMLP(cfg, name="mlp")(
-                    x, pad_mask, None if stack is None else (stack["mlp"], layer)
+                    x, pad_mask, decoding_experts(cache, stack, layer, "mlp")
                 )
             return LlamaMLP(cfg, name="mlp")(x), jnp.float32(0.0)
 

@@ -52,6 +52,7 @@ from llm_training_tpu.models.longcat_flash.config import LongcatFlashConfig
 from llm_training_tpu.models.moe import (
     EXPERT_LEAVES,
     assignment_counts,
+    decoding_experts,
     dropless_moe_apply,
     experts_in_place,
     grouped_matmul,
@@ -147,7 +148,7 @@ class LongcatMoE(nn.Module):
             return jnp.einsum("tei,eih->teh", nn.silu(gate) * up, w_down)
 
         weights, layer = experts_in_place(
-            stack, (w_gate, w_up, w_down), cfg.moe_impl, compute_dtype
+            stack, (w_gate, w_up, w_down), cfg.moe_impl, compute_dtype, self.path
         )
 
         def ragged_fn(xs, group_sizes, expert_order, w):
@@ -218,7 +219,7 @@ class LongcatDoubleLayer(nn.Module):
         with jax.named_scope("scmoe"):
             pad_mask = None if segment_ids is None else segment_ids > 0
             m, stats, counts = LongcatMoE(cfg, name="mlp")(
-                u, pad_mask, None if stack is None else (stack["mlp"], layer)
+                u, pad_mask, decoding_experts(cache, stack, layer, "mlp")
             )
         h = h + ffn
         h, _, ffn, cache = _SubBlock(cfg, name="sub_1")(h, segment_ids, cos, sin, cache, block(1))

@@ -18,11 +18,12 @@ Qwen2/3-MoE — only through `HFCausalLM`'s torch wrapping,
 - 'dense' impl (parity/debug): run every expert on every token and combine
   with the routing weights — exact, E/K-times the FLOPs; default off-TPU
   where tiny parity tests run.
-- decoding under a layer scan (`grouped_matmul(..., layer=i)`): the ragged
-  path reads layer i's experts INSIDE the layer-stacked parameter
-  [L, E, ...], which reaches the block whole (`MoEMLP(..., stack=)`), so the
-  stack is never cut up by copy (docs/inference.md, "How a layer meets a stacked
-  weight").
+- decoding (`grouped_matmul(..., layer=i)`): the ragged path reads layer i's
+  experts INSIDE the layer-stacked parameter [L, E, ...], which reaches the
+  block whole under a layer scan (`MoEMLP(..., stack=)`), so the stack is
+  never cut up by copy; a looped layer's own matrices are a stack of one.
+  Either way the product skips the experts no row chose and the rows held
+  elsewhere (docs/inference.md, "How a layer meets a stacked weight").
 - optional shared expert + sigmoid gate (Qwen2-MoE).
 - load-balancing auxiliary loss (Switch/Mixtral form): E * sum_e f_e * P_e
   with f_e the fraction of (token, slot) assignments routed to e and P_e
@@ -48,9 +49,11 @@ from llm_training_tpu.telemetry.registry import get_registry
 # what a decoding layer loop hands the block whole (`models/cache.py:
 # scan_layers`, `whole=`)
 EXPERT_LEAVES = ("experts_gate_proj", "experts_up_proj", "experts_down_proj")
-# how many layers' expert weights the serving programs traced last read in
-# place; `serve/engine.py` zeroes it before it builds its programs
+# how many expert layers of the serving programs traced last multiply through
+# the in-place grouped matmul, block by block (a scan body's block stands for
+# every repeat of it); `serve/engine.py` zeroes it before it builds its programs
 IN_PLACE_GAUGE = "decode/experts_in_place_layers"
+_in_place_layers: dict[tuple, int] = {}
 
 
 def router_block_stats(topk_idx, probs, num_experts: int, pad_mask=None):
@@ -98,20 +101,31 @@ def _resolved_impl(impl: str) -> str:
 # Tried on the chip (PR 31) at OLMoE's [9, 64, 2048, 1024], nine layers of
 # three matmuls: 128 rows and the whole 4 MiB matrix a step were the fastest
 # at 256 rows (10.0 ms; 10.0 to 10.9 for 32 to 128 rows and tiles of 1 to 4
-# MiB, up to 12.5 at 256 rows a step) and at 4,096 (13.3 ms; up to 15.9)
+# MiB, up to 12.5 at 256 rows a step) and at 4,096 (13.3 ms; up to 15.9).
+# And (PR 43, `scripts/gmm_sweep.py`) at Solar's held share, 40 experts of
+# [4096, 1280], one product: the whole of K beside 256 columns beat K halved
+# beside 640 at 256 rows (0.322 for 0.370 ms) and at 4,096 (0.672 for 0.792);
+# 32 to 128 rows a step within 3% of each other, 256 slower
 _GMM_ROWS = 128
 _GMM_WEIGHT_TILE = 4 << 20
 
 
 def _gmm_tiling(rows: int, k: int, n: int, itemsize: int) -> tuple[int, int, int]:
-    """(tm, tk, tn) of the in-place grouped matmul. The whole of K where
-    it fits the tile, so a row's sum is ONE float32 accumulation, as
-    `ragged_dot`'s is; N, then K, halved until a weight tile does."""
+    """(tm, tk, tn) of the in-place grouped matmul. The whole of K where a
+    tile holds it, so a row's sum is ONE float32 accumulation, as
+    `ragged_dot`'s is: N halved until a weight tile fits; where that stops
+    at an odd count of 128-lane tiles still too large, N's largest divisor
+    of whole lane tiles that fits beside the whole of K; K halved only
+    when there is none."""
     tm = min(_GMM_ROWS, -(-rows // 16) * 16)
+    fits = lambda tk, tn: tk * tn * itemsize <= _GMM_WEIGHT_TILE
     tk, tn = k, n
-    while tk * tn * itemsize > _GMM_WEIGHT_TILE and tn % 256 == 0:
+    while not fits(tk, tn) and tn % 256 == 0:
         tn //= 2
-    while tk * tn * itemsize > _GMM_WEIGHT_TILE and tk % 256 == 0:
+    if not fits(tk, tn):
+        beside_whole_k = [d for d in range(128, tn, 128) if n % d == 0 and fits(k, d)]
+        tn = max(beside_whole_k, default=tn)
+    while not fits(tk, tn) and tk % 256 == 0:
         tk //= 2
     return tm, tk, tn
 
@@ -132,12 +146,37 @@ def assignment_counts(topk_idx, first: int, num_held: int, pad_mask=None, is_zer
     return jnp.stack([held, zero, jnp.sum(elsewhere)]).astype(jnp.int32)
 
 
-def experts_in_place(stack, local, impl: str, compute_dtype):
+def reset_in_place_layers() -> None:
+    _in_place_layers.clear()
+    get_registry().gauge(IN_PLACE_GAUGE).set(0)
+
+
+def decoding_experts(cache, stack, layer, *path):
+    """What a decoder layer hands its MoE block as `stack`. No cache open
+    (training): None, and the block multiplies its own matrices with
+    `ragged_dot`, whose backward pass wants them. Decoding under a layer
+    scan: `(leaves, layer)`, the scan's stacked `EXPERT_LEAVES` found under
+    `path` in `stack` (`models/cache.py:scan_layers(whole=)`) and this
+    layer's index among them. Decoding in a loop (no `stack`): `(None, 0)`,
+    the block's own three matrices seen as a stack of one."""
+    if cache is None:
+        return None
+    if stack is None:
+        return None, 0
+    for name in path:
+        stack = stack[name]
+    return stack, layer
+
+
+def experts_in_place(stack, local, impl: str, compute_dtype, block: tuple):
     """`(weights, layer)` for a layer's grouped products (`grouped_matmul`).
-    `stack = (leaves, layer)` from a decoding layer scan (`models/cache.py:
-    scan_layers(whole=EXPERT_LEAVES)`): where the products can read layer
-    `layer` inside the stacked leaves `[L, E, ...]`, those and the index;
-    otherwise `local`, this layer's own three matrices, and None."""
+    `stack` is a decoding layer's (`decoding_experts`), `local` this layer's
+    own three matrices `[E, ...]`. Where the products can go through the
+    grouped matmul that reads the stack where it lies (the ragged path, one
+    device, leaves in the compute dtype): the leaves `[L, E, ...]` (`local`
+    as `[1, E, ...]` for a looped layer, a free reshape) and the index;
+    otherwise `local` and None. `block` is the calling module's path: the
+    gauge counts each block's layers once, whichever programs trace it."""
     if (
         stack is not None
         and _resolved_impl(impl) == "ragged"
@@ -145,11 +184,16 @@ def experts_in_place(stack, local, impl: str, compute_dtype):
         # other would have to partition a Mosaic kernel, and cannot
         and (active_mesh() is None or active_mesh().size == 1)
     ):
-        whole = tuple(stack[0][name] for name in EXPERT_LEAVES)
+        leaves, layer = stack
+        whole = (
+            tuple(w[None] for w in local) if leaves is None
+            else tuple(leaves[name] for name in EXPERT_LEAVES)
+        )
         # a cast would copy the whole stack
         if all(w.dtype == compute_dtype for w in whole):
-            get_registry().gauge(IN_PLACE_GAUGE).set(whole[0].shape[0])
-            return whole, stack[1]
+            _in_place_layers[block] = whole[0].shape[0]
+            get_registry().gauge(IN_PLACE_GAUGE).set(sum(_in_place_layers.values()))
+            return whole, layer
     return local, None
 
 
@@ -168,7 +212,8 @@ def grouped_matmul(xs, w, group_sizes, layer=None):
     only: what it fetches is layer i's experts that have rows. The
     arithmetic is `ragged_dot`'s: operands as they come, float32
     accumulation, `xs`'s dtype out. Rows past the last group (a held share
-    leaves such rows) come out zero, as `ragged_dot` leaves them."""
+    leaves such rows) come out zero; `ragged_dot` defines nothing there
+    (on the chip they are not zero), so a caller selects them away."""
     if layer is None:
         return jax.lax.ragged_dot(xs, w, group_sizes)
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
@@ -499,8 +544,8 @@ def dropless_moe_apply(
         token_order = flat_token[order]
         xs, expert_order = x[token_order], flat_expert[order]
     if held is not None:
-        # the rows past the held groups belong to no group: `ragged_dot`
-        # leaves them zero, and the select below holds whatever it leaves
+        # the rows past the held groups belong to no group: whatever the
+        # product leaves there, the select below drops
         group_sizes = group_sizes[:-1]
     with jax.named_scope("moe_experts"):
         ys = ragged_fn(xs, group_sizes, expert_order, weights)
@@ -518,10 +563,11 @@ class MoEMLP(nn.Module):
     __call__(hidden [B, S, H], pad_mask [B, S] bool | None) ->
     (out [B, S, H], (sel_frac [E], mean_prob [E], dropped scalar) fp32
     router stats — `dropped` counts EP capacity-buffer losses, 0 off-EP).
-    `stack = (leaves, layer)`, from a decoding layer scan: this module's
-    `EXPERT_LEAVES` with every layer's experts, `[L, E, ...]`, and which
-    layer this is. The ragged path on one device then multiplies with
-    the stack in place (`grouped_matmul`) and this layer's slices of those
+    `stack = (leaves, layer)`, from a decoding layer (`decoding_experts`):
+    under a scan this module's `EXPERT_LEAVES` with every layer's experts,
+    `[L, E, ...]`, and which layer this is; `(None, 0)` from a looped one.
+    The ragged path on one device then multiplies with the stack in place
+    (`grouped_matmul`) and, under a scan, this layer's slices of those
     three parameters go unread, so the compiler drops the cut.
     The caller pools the per-layer stats across depth and applies the
     Switch/Mixtral formula E * sum(f * P) — pooling BEFORE the product is
@@ -605,7 +651,7 @@ class MoEMLP(nn.Module):
             return jnp.einsum("tei,eih->teh", nn.silu(gate) * up, w_down)
 
         weights, layer = experts_in_place(
-            stack, (w_gate, w_up, w_down), cfg.moe_impl, compute_dtype
+            stack, (w_gate, w_up, w_down), cfg.moe_impl, compute_dtype, self.path
         )
 
         def ragged_fn(xs, group_sizes, expert_order, w):

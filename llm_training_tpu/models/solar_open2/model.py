@@ -45,6 +45,7 @@ from llm_training_tpu.models.base import (
 from llm_training_tpu.models.cache import _slot_rows, close_cache, open_cache, scan_layers
 from llm_training_tpu.models.deepseek.model import DeepseekMoE
 from llm_training_tpu.models.llama.model import RMSNorm, _dense
+from llm_training_tpu.models.moe import EXPERT_LEAVES, decoding_experts
 from llm_training_tpu.models.remat import remat_policy as _remat_policy
 from llm_training_tpu.models.solar_open2.config import SolarOpen2Config
 from llm_training_tpu.models.solar_open2.kda import kda_chunked, kda_step
@@ -174,13 +175,15 @@ class GatedAttention(nn.Module):
 class SolarOpen2DecoderLayer(nn.Module):
     """Returns (hidden, router stats, cache). `layer` is this layer's index
     among the stack's layers of its kind: a GQA layer's part of the cache's
-    pool (or dense buffers), a KDA layer's rows of its slab."""
+    pool (or dense buffers), a KDA layer's rows of its slab. `stack =
+    (leaves, index)`: what a decoding layer's experts are read from
+    (`models/moe.py:decoding_experts`)."""
 
     config: SolarOpen2Config
     is_gqa: bool
 
     @nn.compact
-    def __call__(self, hidden, segment_ids=None, cache=None, layer=None):
+    def __call__(self, hidden, segment_ids=None, cache=None, layer=None, stack=None):
         cfg = self.config
         hidden = nn.with_logical_constraint(hidden, ("batch", "act_seq", "act_embed"))
         norm = lambda name: RMSNorm(cfg.rms_norm_eps, cfg.param_jnp_dtype, name=name)
@@ -200,7 +203,7 @@ class SolarOpen2DecoderLayer(nn.Module):
         hidden = hidden + mixed
         pad_mask = None if segment_ids is None else segment_ids > 0
         mlp_out, stats = DeepseekMoE(cfg, name="mlp")(
-            norm("post_attention_layernorm")(hidden), pad_mask
+            norm("post_attention_layernorm")(hidden), pad_mask, stack
         )
         hidden = hidden + mlp_out
         if rows is not None:
@@ -213,13 +216,14 @@ class _PeriodBody(nn.Module):
     (one period). The carry is `hidden` or, when decoding, `(hidden, the
     cache's buffers)`: the pool with a leading axis over ALL the stack's GQA
     layers, the slab over all its KDA layers, and `cycle` says which period
-    of them this is (`models/cache.py:scan_layers`)."""
+    of them this is (`models/cache.py:scan_layers`); `stack` holds the
+    periods' expert leaves whole (None under the loop)."""
 
     config: SolarOpen2Config
     kinds: tuple[bool, ...]
 
     @nn.compact
-    def __call__(self, carry, segment_ids, cache=None, cycle=None):
+    def __call__(self, carry, segment_ids, cache=None, cycle=None, stack=None):
         cfg = self.config
         hidden = carry
         if cache is not None:
@@ -232,7 +236,8 @@ class _PeriodBody(nn.Module):
                 cycle * self.kinds.count(is_gqa) + self.kinds[:j].count(is_gqa)
             )
             hidden, layer_stats, cache = SolarOpen2DecoderLayer(cfg, is_gqa, name=f"slot{j}")(
-                hidden, segment_ids, cache, index
+                hidden, segment_ids, cache, index,
+                decoding_experts(cache, stack, cycle, f"slot{j}", "mlp"),
             )
             stats.append(layer_stats)
         stats = jax.tree.map(lambda *leaves: jnp.stack(leaves), *stats)
@@ -257,7 +262,7 @@ class SolarOpen2(nn.Module):
         if cfg.scan_period:
             hidden, stats, cache = scan_layers(
                 body, (cfg, tuple(kinds[:period])), cfg.num_hidden_layers // period,
-                hidden, (segment_ids,), cache,
+                hidden, (segment_ids,), cache, whole=EXPERT_LEAVES,
             )
             return hidden, jax.tree.map(lambda x: x.reshape(-1, *x.shape[2:]), stats), cache
         # the loop: the whole stack is one body, under the scan's names

@@ -99,7 +99,7 @@ from llm_training_tpu.infer.sampling import (
     sample_tokens_with_logprob,
 )
 from llm_training_tpu.models.base import PagedDecodeState
-from llm_training_tpu.models.moe import IN_PLACE_GAUGE
+from llm_training_tpu.models.moe import IN_PLACE_GAUGE, reset_in_place_layers
 from llm_training_tpu.ops.paged_attention import CHUNK_KERNEL_GAUGE, reset_chunk_kernel_layers
 from llm_training_tpu.resilience.chaos import get_chaos
 from llm_training_tpu.serve.paged_cache import (
@@ -424,7 +424,7 @@ class ServingEngine:
         model = self.model
         # a sparse MLP that reads its stacked experts in place says in how
         # many layers, when a program below is traced (models/moe.py)
-        get_registry().gauge(IN_PLACE_GAUGE).set(0)
+        reset_in_place_layers()
         # and so does a chunk's attention that runs in the kernel
         # (ops/paged_attention.py)
         reset_chunk_kernel_layers()

@@ -59,6 +59,7 @@ def serve(workload: str, seed: int, steps: int) -> dict:
         "requests": len(tokens), "tokens": sum(len(v) for v in tokens.values()),
         "sha256": hashlib.sha256(json.dumps(tokens, sort_keys=True).encode()).hexdigest(),
         "chunk_attention_kernel_layers": stats.get("decode/chunk_attention_kernel_layers"),
+        "experts_in_place_layers": stats.get("decode/experts_in_place_layers"),
         "served": served,
     }
 

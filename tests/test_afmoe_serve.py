@@ -136,7 +136,9 @@ def test_chunked_prefill_then_paged_decode_is_the_reference_forward(tiny, varian
     assert 5 <= stats["decode/window_peak_blocks_in_use"] <= 7  # more than one row's budget of 4
     assert stats["serve/window_pages_released"] > 0 and stats["serve/window_live_tokens"] > 0
     if variant == "grouped_experts_in_place":
-        assert stats["decode/experts_in_place_layers"] == 2  # the scanned periods' stacks
+        # every expert layer: the looped front's two (each its own stack of
+        # one) and the four of a period in each of the two scanned periods
+        assert stats["decode/experts_in_place_layers"] == 2 + 4 * 2
 
 
 def test_a_page_that_was_given_back_is_never_read(tiny):

@@ -437,6 +437,10 @@ _PRODUCED = {
         "prefill": {"pool": 0, "stack": 0, "state": 3},
     },
 }
+# (layers a loop of the program runs, `gmm` calls a program): every expert
+# layer's three products in the grouped matmul that skips what has no rows,
+# a one-period scan's (Solar's, since PR 43) like a scan of nine layers
+_GMM_CALLS = {"olmoe-serve-rollout": (9, 3 * 9), "solar2-serve-longdoc": (1, 3 * 4)}
 # cells whose decode step updates a slot's state where it lies
 # (`LayerCache.put_recurrent_rows(in_place=True)`): every array of the slab's
 # shape a program produces is a fusion rooted in a dynamic-update-slice of its
@@ -616,6 +620,10 @@ def _check_serve_program(v5e, cell, program):
     assert parse_hlo_kernels(text).get("kv_page_write", 0) >= 1  # the append's writer
     # no latent cache here: none of the latent kernels, nor their page writer
     assert not {"mla_prefill", "mla_decode", "latent_page_write"} & set(parse_hlo_kernels(text))
+
+    if cell in _GMM_CALLS:
+        repeats, calls = _GMM_CALLS[cell]
+        assert _kernel_calls(text, "moe_experts/jit(gmm)", repeats) == calls and "ragged-dot" not in text
 
     counts = {k: _produced(text, p) for k, p in patterns.items() if p}
     counts.setdefault("stack", 0)
@@ -835,7 +843,10 @@ def test_trinity_serve_cell_keeps_window_layers_inside_their_budget_for_v5e(v5e,
     assert _kernel_calls(text, mine, 3, under="attn_window") == 12
     assert _kernel_calls(text, mine, 3, under="attn_global") == 4
     kernels = parse_hlo_kernels(text)
-    assert kernels.get("kv_page_write", 0) >= 2 and kernels.get("gmm", 0) >= 3
+    assert kernels.get("kv_page_write", 0) >= 2
+    # all fourteen expert layers' three products in `gmm`: the looped front's two
+    # (each its own stack of one, since PR 43) and four a period in three periods
+    assert _kernel_calls(text, "moe_experts/jit(gmm)", 3) == 3 * 14 and "ragged-dot" not in text
     assert not {"mla_prefill", "mla_decode", "latent_page_write"} & set(kernels)
 
 

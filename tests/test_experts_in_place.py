@@ -2,7 +2,7 @@
 parameter (docs/inference.md, "How a layer meets a stacked weight"): the
 helper against `jax.lax.ragged_dot` on the layer's slice, the decoding stack
 that engages it against the dense cache and the looped model, the traced
-decode program's structure, and the four fallbacks."""
+decode program's structure at any number of layers, and the fallbacks."""
 
 import re
 
@@ -15,7 +15,7 @@ from jax.interpreters import partial_eval as pe
 
 from llm_training_tpu.infer import GenerateConfig, InferenceEngine
 from llm_training_tpu.models import Llama, LlamaConfig
-from llm_training_tpu.models.moe import EXPERT_LEAVES, grouped_matmul
+from llm_training_tpu.models.moe import EXPERT_LEAVES, _gmm_tiling, grouped_matmul
 from llm_training_tpu.parallel.mesh import MeshConfig, build_mesh
 from llm_training_tpu.serve import ServeConfig, ServingEngine
 from tests.test_serve_spans import (
@@ -35,24 +35,30 @@ EXPERT_SLICE = re.compile(r"dynamic_slice.*-> tensor<1x4x(?:32x16|16x32)xf32>")
     pytest.param(jnp.float32, 1e-5, id="float32"),
     pytest.param(jnp.bfloat16, 2e-2, id="bfloat16"),
 ])
-@pytest.mark.parametrize("sizes", [
-    pytest.param([13, 0, 0, 27], id="empty-groups"),
-    pytest.param([0, 0, ROWS, 0], id="one-group-holds-every-row"),
-    pytest.param([9, 4, 0, 6], id="trailing-rows-in-no-group"),
-    pytest.param([0, 0, 0, 0], id="no-row-in-any-group"),
+@pytest.mark.parametrize("layers,sizes", [
+    pytest.param(LAYERS, [13, 0, 0, 27], id="empty-groups"),
+    pytest.param(LAYERS, [0, 0, ROWS, 0], id="one-group-holds-every-row"),
+    pytest.param(LAYERS, [9, 4, 0, 6], id="trailing-rows-in-no-group"),
+    pytest.param(LAYERS, [0, 0, 0, 0], id="no-row-in-any-group"),
+    # a stack of ONE layer at `layer=0`: a one-period scan's, a looped layer's own
+    pytest.param(1, [10, 10, 10, 10], id="one-layer-every-row-in-a-group"),
+    pytest.param(1, [11, 0, 0, 29], id="one-layer-empty-groups-in-the-middle"),
+    pytest.param(1, [17, 23, 0, 0], id="one-layer-empty-groups-at-the-end"),
+    pytest.param(1, [3, 0, 2, 0], id="one-layer-a-held-shares-stray-rows"),
+    pytest.param(1, [0, 0, 0, 0], id="one-layer-no-row-in-any-group"),
 ])
-def test_in_place_grouped_matmul_equals_ragged_dot_on_the_layers_slice(sizes, dtype, tolerance):
-    """Every layer i of a stack `[L, E, K, N]`: the product with the stack
-    in place is `ragged_dot`'s with layer i cut out, rows past the last
-    group (a held share's) zero as `ragged_dot` leaves them. Off the chip
-    the Pallas kernel runs in the interpreter."""
+def test_in_place_grouped_matmul_equals_ragged_dot_on_the_layers_slice(layers, sizes, dtype, tolerance):
+    """Every layer i of a stack `[L, E, K, N]`, L of one too: the product
+    with the stack in place is `ragged_dot`'s with layer i cut out, rows past
+    the last group (a held share's) zero as `ragged_dot` leaves them. Off
+    the chip the Pallas kernel runs in the interpreter."""
     keys = jax.random.split(jax.random.key(len(sizes) + sum(sizes)), 2)
-    stack = jax.random.normal(keys[0], (LAYERS, EXPERTS, K, N), jnp.float32).astype(dtype)
+    stack = jax.random.normal(keys[0], (layers, EXPERTS, K, N), jnp.float32).astype(dtype)
     xs = jax.random.normal(keys[1], (ROWS, K), jnp.float32).astype(dtype)
     sizes = jnp.asarray(sizes, jnp.int32)
     in_place = jax.jit(lambda layer: grouped_matmul(xs, stack, sizes, layer))
     cut_out = jax.jit(lambda layer: grouped_matmul(xs, stack[layer], sizes))
-    for layer in range(LAYERS):
+    for layer in range(layers):
         got, want = in_place(jnp.int32(layer)), cut_out(layer)
         assert got.dtype == want.dtype == dtype and got.shape == (ROWS, N)
         want = np.asarray(want, np.float32)
@@ -61,6 +67,29 @@ def test_in_place_grouped_matmul_equals_ragged_dot_on_the_layers_slice(sizes, dt
             atol=tolerance * max(1.0, float(np.abs(want).max())),
         )
         assert not np.asarray(got, np.float32)[int(sizes.sum()):].any()
+
+
+@pytest.mark.parametrize("k,n,tiling", [
+    pytest.param(2048, 1024, (2048, 1024), id="olmoe-trinity-gate-up: one expert's whole matrix"),
+    pytest.param(1024, 2048, (1024, 2048), id="olmoe-trinity-down"),
+    pytest.param(6144, 2048, (6144, 256), id="longcat-gate-up"),
+    pytest.param(2048, 6144, (2048, 768), id="longcat-down"),
+    pytest.param(7680, 2048, (7680, 256), id="pangu-gate-up"),
+    # N halved stops at 5 and 15 lane tiles, too large: a divisor of N beside the whole of K
+    pytest.param(4096, 1280, (4096, 256), id="solar-gate-up"),
+    pytest.param(1280, 4096, (1280, 1024), id="solar-down"),
+    pytest.param(2048, 7680, (2048, 768), id="pangu-down"),
+    # no lane tile fits beside the whole of K: K halved
+    pytest.param(65536, 1280, (2048, 640), id="k-too-long-for-any-tile"),
+    pytest.param(32, 48, (32, 48), id="tiny"),
+])
+def test_gmm_tiling_keeps_k_whole_where_a_tile_holds_it(k, n, tiling):
+    """A pure function of the shapes, at every serve cell's widths in
+    bfloat16: a weight tile of at most 4 MiB, the whole of K wherever some
+    divisor of N in whole 128-lane tiles fits beside it."""
+    for rows, tm in ((256, 128), (4096, 128), (40, 48)):
+        assert _gmm_tiling(rows, k, n, 2) == (tm, *tiling)
+    assert tiling[0] * tiling[1] * 2 <= 4 << 20 and k % tiling[0] == 0 and n % tiling[1] == 0
 
 
 # ------------------------------------------- a decoding stack that engages
@@ -86,7 +115,7 @@ def test_paged_decode_in_place_serves_the_dense_caches_and_the_looped_models_tok
     steps of a scanned MoE stack, `moe_impl="ragged"` forced, three layers:
     the paged path, which reads the experts in place, serves what the dense
     `DecodeState` path does and what the looped model (its own per-layer
-    parameters, `ragged_dot`) does through both caches."""
+    parameters, each a stack of one) does through both caches."""
     n = 8
     scanned = Llama(LlamaConfig(**DEEP_MOE))
     looped = Llama(LlamaConfig(**DEEP_MOE, scan_layers=False))
@@ -208,10 +237,21 @@ def test_training_trace_keeps_the_scans_slices():
     assert not [v for v in forward.invars[:consts] if v.aval.shape in STACKS]
 
 
-def test_a_stack_of_one_layer_keeps_its_slice():
-    """A length-1 slice is a view already: the one-layer stack multiplies
-    it with `ragged_dot`, as before."""
-    engine = _engine(dict(DEEP_MOE, num_hidden_layers=1))
-    traced = jax.make_jaxpr(engine._decode_jit)(*_decode_args(engine)).jaxpr
-    primitives = _primitives(traced)
-    assert "ragged_dot_general" in primitives and "pallas_call" not in primitives
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("config", [
+    pytest.param(dict(DEEP_MOE, num_hidden_layers=1), id="a-scan-of-one-layer"),
+    pytest.param(dict(DEEP_MOE, scan_layers=False), id="looped"),
+])
+def test_a_stack_of_one_layer_reads_its_experts_in_place(config, program):
+    """Which grouped product a decoding layer uses does not depend on how
+    many layers its stack has: a scan of one layer (its stack, index 0) and a
+    looped layer (its own leaves seen as `[1, E, ...]`) multiply through the
+    Pallas grouped matmul, which skips the groups with no rows, and no
+    `ragged_dot` is left in either serving program."""
+    engine = _engine(config)
+    jitted, args = (
+        (engine._decode_jit, _decode_args(engine)) if program == "decode"
+        else (engine._prefill_jit, _prefill_args(engine))
+    )
+    primitives = _primitives(jax.make_jaxpr(jitted)(*args).jaxpr)
+    assert "pallas_call" in primitives and "ragged_dot_general" not in primitives

@@ -724,23 +724,26 @@ def test_window_group_reports_its_pool_its_pages_and_what_it_reads(fresh):
     pytest.param(dict(TINY_MOE, num_hidden_layers=3), 3, id="engaged"),
     pytest.param(dict(TINY_MOE, num_hidden_layers=3, moe_impl="dense"), 0, id="dense-impl"),
     pytest.param(dict(TINY_MOE, num_hidden_layers=3, moe_impl="auto"), 0, id="auto-off-the-chip"),
-    pytest.param(dict(TINY_MOE, num_hidden_layers=1), 0, id="one-layer"),
-    pytest.param(dict(TINY_MOE, num_hidden_layers=3, scan_layers=False), 0, id="looped"),
+    pytest.param(dict(TINY_MOE, num_hidden_layers=1), 1, id="one-layer"),
+    pytest.param(dict(TINY_MOE, num_hidden_layers=3, scan_layers=False), 3, id="looped"),
     pytest.param(TINY, 0, id="no-experts"),
 ])
 def test_gauge_counts_the_layers_whose_experts_are_read_in_place(fresh, config, layers):
-    """`decode/experts_in_place_layers`: set when the serving programs are
-    traced, L for a scanned stack on the ragged path, 0 on every fallback;
-    `stats()` holds it and `report` says it."""
+    """`decode/experts_in_place_layers`: counted when the serving programs are
+    traced, every expert layer of a decoding stack on the ragged path once
+    (a scan's body for each of its repeats, a looped layer for itself,
+    however many programs trace it), 0 on every fallback; `stats()` holds it
+    and `report` says it."""
     from llm_training_tpu.telemetry.report import _serving_section
 
+    get_registry().gauge("decode/experts_in_place_layers").set(5)  # another engine's
     engine = _engine(config)
     assert get_registry().gauge("decode/experts_in_place_layers").value == 0  # nothing traced yet
     engine.run(_requests(2))
     assert get_registry().gauge("decode/experts_in_place_layers").value == layers
     stats = engine.stats()
     assert stats["decode/experts_in_place_layers"] == layers
-    said = "expert weights: read in place in 3 layers" in _serving_section(stats)
+    said = f"expert weights: read in place in {layers} layers" in _serving_section(stats)
     assert said == bool(layers)
 
 

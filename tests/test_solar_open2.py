@@ -206,13 +206,18 @@ def test_the_benchmarks_copy_of_the_reference_is_the_same(tiny):
     assert np.abs(np.asarray(got) - np.asarray(want)).max() < 1e-6
 
 
-def test_looped_stack_is_the_scanned_stack(tiny):
-    model, variables = tiny
-    looped = SolarOpen2(SolarOpen2Config(**{**TINY, "scan_layers": False}))
+def looped_variables(variables):
+    """The scanned stack's parameters in the looped stack's layout."""
     stacked = variables["params"]["layers"]
     # layer i of the loop is slot i % 4 of period i // 4
     flat = {f"slot{i}": jax.tree.map(lambda a: a[i // 4], stacked[f"slot{i % 4}"]) for i in range(8)}
-    loop_vars = {"params": {**variables["params"], "layers": flat}}
+    return {"params": {**variables["params"], "layers": flat}}
+
+
+def test_looped_stack_is_the_scanned_stack(tiny):
+    model, variables = tiny
+    looped = SolarOpen2(SolarOpen2Config(**{**TINY, "scan_layers": False}))
+    loop_vars = looped_variables(variables)
     ids, seg = packed_batch()
     with jax.default_matmul_precision("highest"):
         want = jax.jit(lambda v: model.apply(v, input_ids=ids, segment_ids=seg).logits)(variables)
@@ -366,21 +371,42 @@ def run_engine(model, variables, **serve):
     return engine, requests, done
 
 
-def test_chunked_prefill_then_paged_decode_is_the_reference_forward(tiny):
+@pytest.fixture(scope="module")
+def dense_served(tiny):
+    """What the dense expert path serves: `(engine, requests, done)`."""
+    model, variables = tiny
+    with jax.default_matmul_precision("highest"):
+        return run_engine(model, variables)
+
+
+@pytest.mark.parametrize("variant", ["dense_experts", "grouped_experts_in_place", "grouped_experts_in_place_looped"])
+def test_chunked_prefill_then_paged_decode_is_the_reference_forward(tiny, dense_served, variant):
     """Prompts of 19, 5, 11, 30 and 3 tokens in chunks of 8 (so chunks of
     unequal length, the last one padded), five requests through two slots (a
     recycled slot holds its last tenant's state until the first chunk reads
     it as zeros), a pool of 7 blocks (so one request is evicted mid-decode
     and re-prefilled from a zero state with its progress folded in): every
-    served position against the reference's full forward."""
+    served position against the reference's full forward. Also with the held
+    experts multiplied by the grouped product that skips what has no rows
+    (`moe_impl='ragged'`, the chip's path): every expert layer of the scanned
+    periods through its stack, and of the looped stack through its own
+    leaves, serving the dense path's tokens."""
     model, variables = tiny
-    with jax.default_matmul_precision("highest"):
-        engine, requests, done = run_engine(model, variables)
+    if variant == "dense_experts":
+        engine, requests, done = dense_served
+    else:
+        looped = variant.endswith("looped")
+        model = SolarOpen2(SolarOpen2Config(**{**TINY, "moe_impl": "ragged", "scan_layers": not looped}))
+        with jax.default_matmul_precision("highest"):
+            engine, requests, done = run_engine(model, looped_variables(variables) if looped else variables)
+        assert [done[r["id"]]["tokens"] for r in requests] == [dense_served[2][r["id"]]["tokens"] for r in requests]
     assert all(done[r["id"]]["stop_reason"] == "max_tokens" for r in requests)
     assert engine.scheduler.evictions >= 1 and engine.allocator.blocks_in_use == 0
     gap, logprob_gap, _ = served_against_reference(variables, requests, done)
     assert gap < F32_TOL and logprob_gap < F32_TOL
     stats = engine.stats()
+    # the stack's eight expert layers: four a period's body in two periods, or eight looped
+    assert stats["decode/experts_in_place_layers"] == (0 if variant == "dense_experts" else 8)
     assert stats["decode/state_bytes"] == 6 * 2 * (4 * 16 * 16 * 4 + 3 * 192 * 4)
     from llm_training_tpu.telemetry import get_registry
 
