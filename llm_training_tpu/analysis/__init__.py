@@ -6,10 +6,10 @@ chip-hours before surfacing:
 
 - **pallas-kernel-arity**: every `pl.pallas_call` site's implied ref count
   (scalar prefetch + in_specs + outputs + scratch) matches the kernel's
-  positional signature. BENCH_r04 died on a TPU with `_dq_kernel() missing
+  positional signature. Round 4's chip run died with `_dq_kernel() missing
   2 required positional arguments`; this rule makes that a lint failure.
 - **jax-free-import**: declared jax-free modules (supervisor, elastic, the
-  serve package surface, bench.py, serve_loadgen) stay jax-free through
+  serve package surface, chip_smoke.py, serve_loadgen) stay jax-free through
   their *transitive module-level* import graph; lazy function-body imports
   are the sanctioned escape hatch.
 - **host-sync**: `.item()` / `jax.device_get` / `np.asarray` / `print` /
@@ -18,7 +18,7 @@ chip-hours before surfacing:
 - **telemetry-prefix**: every metric name published through the telemetry
   registry matches `callbacks.loggers.TELEMETRY_PREFIXES`/`TELEMETRY_KEYS`,
   so a new subsystem's gauges can never silently miss telemetry.jsonl.
-- **env-doc-drift**: every `LLMT_*`/`FLASH_*`/`BENCH_*`/`PAGED_*` env var
+- **env-doc-drift**: every `LLMT_*`/`FLASH_*`/`PAGED_*` env var
   the code reads appears in the docs env tables.
 - **logical-axis-literal**: every string literal used as logical-axis
   param metadata under models/ appears in the `KNOWN_LOGICAL_AXES`

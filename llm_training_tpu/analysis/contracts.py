@@ -47,21 +47,15 @@ JAX_FREE_CONTRACTS: dict[str, str] = {
         "the router smoke drives the route CLI as a subprocess, exactly "
         "like the loadgen — the children own the backend"
     ),
-    "bench.py": (
-        "the bench parent orchestrates child stages, one after another: a "
-        "parent that touched jax would hold the chip its children need, and "
-        "a hung backend must cost a stage timeout, not the whole bench (the "
-        "r05 failure)"
-    ),
     "chip_smoke.py": (
         "the chip smoke's parent runs each phase as a child, strictly one "
         "at a time: a chip belongs to one process, and a parent that had "
         "touched jax would hold it"
     ),
     "llm_training_tpu/compile_cache.py": (
-        "jax-free parents (bench.py, chip_smoke.py) name the compile cache "
+        "the jax-free parent chip_smoke.py names the compile cache "
         "directory through this module; only configure_compile_cache, "
-        "called in their children, imports jax"
+        "called in its children, imports jax"
     ),
     "scripts/serve_loadgen.py": (
         "the loadgen drives the serve CLI as a subprocess and must keep "
@@ -107,11 +101,6 @@ JAX_FREE_CONTRACTS: dict[str, str] = {
         "subprocesses, exactly like the crash-resume smoke — the "
         "children own the backend"
     ),
-    "llm_training_tpu/telemetry/perf_ledger.py": (
-        "the bench PARENT (itself jax-free) imports the regression ledger; "
-        "the --check-regression gate must run on any machine the repo is "
-        "checked out on, backend or not"
-    ),
     # the lint gate itself: precommit runs it before any backend exists and
     # it must stay millisecond-cheap
     "llm_training_tpu/analysis/__init__.py": (
@@ -137,7 +126,7 @@ TELEMETRY_PUBLISH_METHODS = ("gauge", "counter", "timer")
 
 # ---------------------------------------------------------------- rule 5
 # env-var namespaces this repo owns; every read of one must be documented
-ENV_VAR_PATTERN = r"^(LLMT|FLASH|BENCH|PAGED)_[A-Z0-9]+(?:_[A-Z0-9]+)*$"
+ENV_VAR_PATTERN = r"^(LLMT|FLASH|PAGED)_[A-Z0-9]+(?:_[A-Z0-9]+)*$"
 
 # the docs corpus an env var must appear in (any of these files)
 ENV_DOC_FILES = (

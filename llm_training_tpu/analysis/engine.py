@@ -25,7 +25,7 @@ from typing import Callable, Iterable
 # the default scan set, relative to the repo root: library + entry scripts.
 # tests/ are deliberately excluded (they import jax freely and construct
 # intentionally-broken fixtures); point the CLI at extra paths to widen.
-DEFAULT_SCAN = ("llm_training_tpu", "scripts", "bench.py", "chip_smoke.py")
+DEFAULT_SCAN = ("llm_training_tpu", "scripts", "chip_smoke.py")
 DEFAULT_BASELINE = "config/lint_baseline.json"
 DEFAULT_RACE_BASELINE = "config/race_baseline.json"
 # meta-findings that must never be grandfathered: a baselined reasonless

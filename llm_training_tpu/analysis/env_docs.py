@@ -1,11 +1,10 @@
 """Rule `env-doc-drift`: every repo env var the code reads is documented.
 
-The repo owns four env namespaces — `LLMT_*` (chaos/supervisor/elastic),
-`FLASH_*` (kernel tiles), `BENCH_*` (bench knobs), `PAGED_*` (serving
-tiles) — and the docs carry env tables for them (docs/performance.md,
-docs/resilience.md, docs/serving.md). A knob added in code but not in the
-tables is effectively unshipped: nobody sweeping a bench or debugging a
-resume can find it.
+The repo owns three env namespaces — `LLMT_*` (chaos/supervisor/elastic),
+`FLASH_*` (kernel tiles), `PAGED_*` (serving tiles) — and the docs carry
+env tables for them (docs/performance.md, docs/resilience.md,
+docs/serving.md). A knob added in code but not in the tables is effectively
+unshipped: nobody sweeping tiles or debugging a resume can find it.
 
 The rule collects every string literal matching the env-name pattern from
 non-docstring positions in the scan set (literals, dict values feeding
@@ -86,7 +85,7 @@ def _run(ctx: RepoContext) -> list[Finding]:
 RULE = RuleSpec(
     name="env-doc-drift",
     description=(
-        "every LLMT_*/FLASH_*/BENCH_*/PAGED_* env var read in code must "
+        "every LLMT_*/FLASH_*/PAGED_* env var read in code must "
         "appear in the docs env tables"
     ),
     run=_run,

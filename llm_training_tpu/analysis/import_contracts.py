@@ -1,10 +1,11 @@
 """Rule `jax-free-import`: declared jax-free modules stay jax-free.
 
-The supervisor/elastic/serve-surface/bench/loadgen modules each carry a
+The supervisor/elastic/serve-surface/loadgen modules each carry a
 hand-maintained "never imports jax at module level" invariant (a supervisor
 that owns a backend dies with the child it must restart; the serve package
-surface must be importable host-only; the bench parent must outlive a
-wedged backend). Until now only scattered subprocess tests enforced it.
+surface must be importable host-only; a parent that runs its phases as
+children must leave them the chip). Until now only scattered subprocess
+tests enforced it.
 
 This rule walks the *transitive module-level* import graph from each
 contracted module in `contracts.JAX_FREE_CONTRACTS`: importing
@@ -187,7 +188,7 @@ RULE = RuleSpec(
     name="jax-free-import",
     description=(
         "declared jax-free modules (supervisor, elastic, serve surface, "
-        "bench.py, serve_loadgen) must not reach jax through module-level "
+        "chip_smoke.py, serve_loadgen) must not reach jax through module-level "
         "imports, transitively"
     ),
     run=_run,

@@ -7,7 +7,7 @@ logical-axis metadata, then resolves them through the rule table
 (`parallel/sharding.py`) against a matrix of mesh configurations (the
 full data/pipe/fsdp/expert/tensor/sequence axis space, including the
 multichip-dryrun 8-device shapes). It is the regression gate under which
-the ROADMAP-5 declarative-rule-table refactor can proceed: the refactor
+the declarative rule table (`parallel/sharding.py`) may change: a change
 must keep every family × mesh cell green.
 
 Finding types (all prefixed `shard-`; docs/static-analysis.md#audit):

@@ -11,7 +11,7 @@ schedules the window on the trigger (same budget accounting, artifacts
 inside the run dir by default), and marks the callback `_absorbed` — one
 owner for jax.profiler.start/stop_trace, so a breach-fired capture can
 never nest inside a config-window capture. The standalone path below is
-kept for direct use outside a trainer fit (bench stages, tests).
+kept for direct use outside a trainer fit (scripts, tests).
 """
 
 from __future__ import annotations

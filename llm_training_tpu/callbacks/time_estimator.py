@@ -5,7 +5,7 @@ Capability parity: reference `lightning/callbacks/training_time_estimator.py`
 total-training-time table (`:62-83`), optionally stopping the run
 (`:32-37` disables checkpointing for the dry run; here `stop_after_steps`
 ends the fit). TPU-native addition: tokens/sec/device and **MFU** against
-the chip's peak bf16 FLOP/s — the number BASELINE.md is scored in — using
+the chip's peak bf16 FLOP/s, using
 the standard decoder FLOP model (6·params·tokens + 12·L·H·D·S·tokens for
 attention scores/values).
 """
@@ -21,7 +21,7 @@ from pydantic import BaseModel, ConfigDict, Field
 logger = logging.getLogger(__name__)
 
 # peak dense bf16 FLOP/s per chip by device_kind substring — the ONE peak
-# table (bench.py reads it too). Source: Google Cloud TPU documentation,
+# table. Source: Google Cloud TPU documentation,
 # the per-generation system-architecture pages ("TPU v5e": 197 TFLOP/s
 # bf16, 16 GB HBM at 819 GB/s). A kind that matches nothing has no peak
 # (None): an unknown device is an error to a measurement, never a default.

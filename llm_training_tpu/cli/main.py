@@ -1146,11 +1146,6 @@ def main(argv: list[str] | None = None) -> int:
     report = sub.add_parser("report", help="render a run summary from a run directory")
     report.add_argument("run_dir", help="dir holding metrics.jsonl / telemetry.jsonl")
     report.add_argument(
-        "--bench-dir", default=None,
-        help="dir searched first for the newest BENCH_r*.json / bench*.json "
-        "record (== Perf == section); falls back to run_dir, then cwd",
-    )
-    report.add_argument(
         "--supervisor-log", default=None,
         help="supervisor.jsonl with per-segment topology events "
         "(== Elastic == section); default: <run_dir>/supervisor.jsonl",
@@ -1411,7 +1406,6 @@ def main(argv: list[str] | None = None) -> int:
 
         return report_main(
             args.run_dir,
-            bench_dir=args.bench_dir,
             supervisor_log=args.supervisor_log,
             audit_dir=args.audit_dir,
             format=args.format,

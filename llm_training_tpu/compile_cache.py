@@ -5,7 +5,7 @@ program names no other directory. Where it is not, the cache lives at ONE
 fixed path inside the checkout (`<repo>/.jax_cache`, git-ignored). The
 directory is part of what a cache entry is keyed on, so a path built from a
 temporary name, a pid or the time would never hit: every process of the
-repo — `fit`, `serve`, the bench's stage children, `chip_smoke.py` — must
+repo — `fit`, `serve`, `chip_smoke.py`'s phase children — must
 land in the same place for a second run to compile nothing.
 
 An entry is also keyed on the program's metadata (`jax.named_scope`s, flax
@@ -16,8 +16,8 @@ code no longer has, or not under the ones it has (seen on the chip, PR 25:
 `moe_*` and `sample` missing from every op of a cached decode step). The
 price is that an edit to a traced source file compiles again.
 
-Importing this module does not import jax (bench.py's and chip_smoke.py's
-parents stay off the chip); `configure_compile_cache` does.
+Importing this module does not import jax (chip_smoke.py's parent stays
+off the chip); `configure_compile_cache` does.
 """
 
 from __future__ import annotations

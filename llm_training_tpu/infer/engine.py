@@ -330,7 +330,7 @@ class InferenceEngine:
             "logprobs": logprobs,
             "sequences": sequences,
             # per-row generated length + why each row stopped ("eos" |
-            # "max_tokens") — callers (serve scheduler, evaluate, bench)
+            # "max_tokens") — callers (serve scheduler, evaluate, generate)
             # no longer re-scan the outputs for the eos token
             "lengths": lengths,
             "stop_reasons": stop_reasons,

@@ -140,7 +140,7 @@ class LlamaConfig(BaseModelConfig):
     # tests); 'bucketed' = fixed per-expert capacity buckets + ONE dense
     # batched matmul — trades token drops under imbalance (surfaced by the
     # ep_dropped_rows metric) for fully-dense MXU work where ragged_dot's
-    # lowering underperforms (see BASELINE.md's grouped-matmul sweep)
+    # lowering underperforms (no chip measurement yet: PERF.md section 7)
     moe_impl: Literal["auto", "dense", "ragged", "bucketed"] = "auto"
     # per-rank buffer slack for the expert-parallel dispatch: capacity =
     # ceil(T*K/ep * factor) rows (clamped to T*K); routing beyond it is

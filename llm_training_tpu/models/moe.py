@@ -397,8 +397,8 @@ def _bucketed_apply(
     run ONE batched matmul stack (`bmm_fn`), weighted-scatter back. Rows
     beyond an expert's capacity are DROPPED (classic GShard/Switch
     semantics — counted and returned, cf. the ep path); in exchange every
-    matmul is a dense MXU bmm where `ragged_dot`'s grouped lowering
-    underperforms (BASELINE.md r5 sweep: 0.19 fwd eff at the bench shape).
+    matmul is a dense MXU bmm, for shapes where `ragged_dot`'s grouped
+    lowering underperforms (never measured on the chip: PERF.md section 7).
     """
     n_tokens, top_k = topk_idx.shape
     hidden = x.shape[-1]

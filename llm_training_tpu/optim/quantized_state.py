@@ -1,9 +1,9 @@
 """Block-quantized storage codec for offloaded optimizer state.
 
-The host-offloaded optimizer round trip is transfer-bound: at the 916M-param
-bench proxy the fp32 mu/nu round trip is ~14.7 GB/step against a ~15 GB/s
-host link, and the r5 chip measurement showed the per-leaf "overlapped"
-chains hide none of it (0.3035 vs 0.313 MFU serialized) because the update
+The host-offloaded optimizer round trip is transfer-bound: fp32 mu/nu of a
+916M-param model are ~14.7 GB a step, there and back, over the host link,
+and a builder's r5 chip run (never re-taken by the driver: PERF.md section
+7) found the per-leaf "overlapped" chains hide none of it, because the update
 compute they overlap with is negligible next to the transfers. The lever
 that works is shrinking the bytes: store mu as block-wise int8 and nu as
 block-wise uint8 of sqrt(nu) (8-bit-Adam-style state compression — the

@@ -1,7 +1,7 @@
 """Serving subsystem (docs/serving.md): continuous batching over a paged
 KV cache.
 
-The serving tier ROADMAP item 2 names: a block-pool KV cache with
+The serving tier: a block-pool KV cache with
 per-request block tables (`paged_cache.py` + `models/base.PagedDecodeState`),
 a ragged paged-decode attention path (`ops/paged_attention.py`, Pallas
 kernel in `ops/pallas/paged_attention.py`), a request scheduler with

@@ -35,7 +35,7 @@ from typing import TYPE_CHECKING
 # jax — and everything that drags it in: the infer.cache helpers AND the
 # `llm_training_tpu.ops` package (whose __init__ loads every kernel) —
 # loads lazily inside the pool constructors so the allocator stays
-# importable from jax-free host processes (loadgen / bench parents), the
+# importable from jax-free host processes (loadgen / router parents), the
 # package docstring's contract
 if TYPE_CHECKING:
     import jax.numpy as jnp

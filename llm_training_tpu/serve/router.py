@@ -986,7 +986,7 @@ def require_one_process_per_chip(replicas: int) -> None:
     would share a chip. A chip belongs to one process at a time, and replica
     spawns (here and in scripts/serve_loadgen.py) hand every child this
     process's environment unchanged: none is assigned chips of its own
-    (ROADMAP S3). Wherever JAX's default platform is an accelerator, the
+    (ROADMAP R11). Wherever JAX's default platform is an accelerator, the
     second child to reach the backend fails its start-up or hangs. So more
     than one replica runs only where the environment itself says the CPU
     test path (`JAX_PLATFORMS=cpu`); this jax-free parent cannot ask JAX
@@ -995,7 +995,7 @@ def require_one_process_per_chip(replicas: int) -> None:
         raise SystemExit(
             f"error: {replicas} serve replicas would each initialise the same "
             "accelerator — a chip belongs to one process, and replica spawns "
-            "do not assign chips yet (ROADMAP S3). Run one replica per host, "
+            "do not assign chips yet (ROADMAP R11). Run one replica per host, "
             "or set JAX_PLATFORMS=cpu for the CPU test path."
         )
 

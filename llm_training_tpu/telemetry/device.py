@@ -426,7 +426,7 @@ def compiled_attribution_gauges(
     """`attr/*` gauges from a `jax.stages.Compiled` step: static FLOPs vs
     collective bytes, split per collective family and per mesh axis, plus
     the comm-fraction headline (`collective bytes / bytes accessed`,
-    clamped to [0,1]) that report and bench track round-over-round.
+    clamped to [0,1]) that report prints.
 
     Always publishes the full family set (zeros included) so a mesh with
     no collectives — the single-device CPU smoke — still writes a stable

@@ -63,7 +63,7 @@ _PROM_PREFIX = "llmt_"
 def find_free_port(host: str = "127.0.0.1") -> int:
     """Bind-then-release an OS-assigned ephemeral port — the shared probe
     for callers that must know the port BEFORE the exporter owner starts
-    (bench's exporter stage, the precommit smokes). Inherently racy
+    (the loadgen, the precommit smokes). Inherently racy
     against other port grabbers, but the loser degrades to the exporter's
     logged-warning path, never a crash."""
     import socket
@@ -236,7 +236,7 @@ class MetricsExporter:
         self.extra_fn = extra_fn
         self.status_fn = status_fn
         self.host = host
-        # fleet discovery role (train|serve|bench) stamped on the replica
+        # fleet discovery role (train|serve|router) stamped on the replica
         # card when LLMT_FLEET_DIR is armed (docs/observability.md#fleet)
         self.role = role
         self._clock = clock
@@ -569,7 +569,7 @@ class _Handler(BaseHTTPRequestHandler):
 def start_exporter(port: int | None = None, **sources) -> MetricsExporter | None:
     """Construct + start an exporter when enabled; None when the port is 0
     (`LLMT_METRICS_PORT` unset) or the bind fails. The one-call entry the
-    trainer / serve CLI / bench stages use."""
+    trainer and the serve CLI use."""
     if port is None:
         port = resolve_metrics_port()
     if not port:

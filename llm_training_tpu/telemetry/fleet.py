@@ -9,7 +9,7 @@ what is the total queue depth, did every accepted request complete
 
 - **replica discovery** — every armed `MetricsExporter` drops a
   `replica-<pid>.json` card into the `LLMT_FLEET_DIR` directory (port,
-  role train|serve|bench, supervisor attempt, and a wall↔monotonic start
+  role train|serve|router, supervisor attempt, and a wall↔monotonic start
   anchor) and removes it on clean stop. A SIGKILLed replica cannot remove
   its card, so discovery flags cards whose pid is dead as **stale**
   instead of scraping a corpse forever. Static `--targets host:port,...`
@@ -64,7 +64,7 @@ logger = logging.getLogger(__name__)
 FLEET_DIR_ENV = "LLMT_FLEET_DIR"
 SCRAPE_INTERVAL_ENV = "LLMT_FLEET_SCRAPE_S"
 CARD_SCHEMA = 1
-ROLES = ("train", "serve", "bench", "router")
+ROLES = ("train", "serve", "router")
 
 # serve gauges that roll up as FLEET SUMS (queue depth / in-flight /
 # completed are "how much work, fleet-wide" — the census cross-check and

@@ -73,9 +73,9 @@ class HealthConfig(BaseModel):
     N-th optimizer step runs the instrumented step variant and the trainer
     publishes the host-fetched metrics into the telemetry registry (so
     `telemetry.jsonl`, W&B, and `report` pick them up with no extra wiring).
-    The fetch forces one device sync per health step; `bench.py` tracks the
-    cost as `health_overhead_pct` (sub-1% at every_n_steps >= 10 on the
-    bench shapes — see docs/observability.md for guidance).
+    The fetch forces one device sync per health step (what that costs a
+    step has no chip measurement: PERF.md section 7; see
+    docs/observability.md for guidance).
     """
 
     model_config = ConfigDict(extra="forbid")

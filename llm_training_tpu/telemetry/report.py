@@ -438,8 +438,8 @@ def _newest_json_record(
 ) -> tuple[dict, str] | None:
     """The newest JSON dict matching `patterns` reachable from `dirs`:
     first directory with any match wins the tie; within it, newest mtime
-    then name (BENCH_rNN names sort by round). Unreadable/non-dict files
-    return None — the caller's section degrades or is omitted."""
+    then name. Unreadable/non-dict files return None — the caller's section
+    degrades or is omitted."""
     candidates: list[Path] = []
     for d in dirs:
         if d is None or not d.is_dir():
@@ -458,91 +458,6 @@ def _newest_json_record(
     if not isinstance(record, dict):
         return None
     return record, newest.name
-
-
-def _newest_bench_record(dirs: list[Path]) -> tuple[dict, str] | None:
-    """The newest bench record reachable from `dirs`. Accepts both shapes:
-    a raw bench.py summary record and the driver's wrapper
-    {n, cmd, rc, tail, parsed}."""
-    found = _newest_json_record(dirs, ("BENCH_r*.json", "bench*.json"))
-    if found is None:
-        return None
-    record, name = found
-    if "parsed" in record:  # driver wrapper
-        parsed = record.get("parsed")
-        if not isinstance(parsed, dict):
-            parsed = {"error": f"bench crashed before emitting a record "
-                               f"(rc {record.get('rc')})"}
-        record = parsed
-    return record, name
-
-
-def _perf_section(bench: tuple[dict, str] | None) -> list[str]:
-    """Newest bench record (MFU, vs_baseline, flash blocks used, per-stage
-    status — docs/performance.md). Omitted when no bench record is
-    reachable from the run/bench dir."""
-    if bench is None:
-        return []
-    record, name = bench
-    header = ["", "== Perf ==", f"bench record: {name}"]
-    try:
-        return header + _perf_lines(record)
-    except (KeyError, TypeError, ValueError, AttributeError):
-        # the broad bench*.json glob (and its cwd fallback) can pick up a
-        # foreign or malformed file — that must cost one honest line, not
-        # crash the whole report for a run that never touched bench
-        return header + ["unreadable bench record — malformed fields"]
-
-
-def _perf_lines(record: dict) -> list[str]:
-    lines = []
-    value = record.get("value")
-    if value is not None:
-        line = f"mfu: {float(value):.4f}"
-        if record.get("vs_baseline") is not None:
-            line += f" (vs_baseline {float(record['vs_baseline']):.3f})"
-        lines.append(line)
-        extras = []
-        if record.get("tokens_per_sec_per_chip") is not None:
-            extras.append(f"tokens/sec/chip {float(record['tokens_per_sec_per_chip']):,.1f}")
-        if record.get("sec_per_step") is not None:
-            extras.append(f"sec_per_step {float(record['sec_per_step']):.4f}")
-        if record.get("goodput_pct") is not None:
-            extras.append(f"goodput {float(record['goodput_pct']):.1f}%")
-        if extras:
-            lines.append("  ".join(extras))
-    else:
-        lines.append(f"mfu: unavailable — {record.get('error', 'no value recorded')}")
-    blocks = record.get("blocks") or {}
-    if blocks:
-        parts = [
-            f"{kind} {int(bq)}x{int(bk)}"
-            for kind, (bq, bk) in sorted(blocks.items())
-        ]
-        sources = record.get("block_sources") or {}
-        src = ", ".join(f"{k} x{v}" for k, v in sorted(sources.items()))
-        lines.append("flash blocks: " + "  ".join(parts) + (f"  (resolved: {src})" if src else ""))
-    stages = record.get("stages") or {}
-    if stages:
-        parts = []
-        for stage, info in stages.items():
-            status = info.get("status", "?")
-            part = f"{stage} {status}"
-            if status == "error" and info.get("error"):
-                part += f" ({info['error']})"
-            parts.append(part)
-        lines.append("stages: " + "  ".join(parts))
-    if record.get("health_overhead_pct") is not None:
-        lines.append(f"health_overhead_pct: {float(record['health_overhead_pct']):.2f}")
-    if record.get("trace_overhead_pct") is not None:
-        lines.append(f"trace_overhead_pct: {float(record['trace_overhead_pct']):.2f}")
-    if record.get("decode_tokens_per_sec") is not None:
-        lines.append(
-            f"decode: {float(record['decode_tokens_per_sec']):,.1f} tokens/sec"
-            + (f"  prefill {float(record['prefill_time_s']):.3f}s"
-               if record.get("prefill_time_s") is not None else "")
-        )
-    return lines
 
 
 def _newest_audit_record(dirs: list[Path]) -> tuple[dict, str] | None:
@@ -570,7 +485,7 @@ def _audit_section(
     and reality is visible in one place. A race*.json from the `--races`
     gate adds its one-line summary (docs/static-analysis.md#racecheck).
     Omitted when neither record is reachable; a foreign/malformed record
-    costs one honest line, mirroring `== Perf ==`."""
+    costs one honest line."""
     if audit is None and races is None:
         return []
     lines = ["", "== Audit =="]
@@ -1245,7 +1160,6 @@ def _training_summary(metrics: list[dict]) -> dict | None:
 
 def render_report(
     run_dir: str | Path,
-    bench_dir: str | Path | None = None,
     supervisor_log: str | Path | None = None,
     audit_dir: str | Path | None = None,
 ) -> str:
@@ -1324,9 +1238,6 @@ def render_report(
         lines.append(peak_line)
 
     lines.extend(_health_section(telemetry))
-    lines.extend(_perf_section(_newest_bench_record([
-        Path(bench_dir) if bench_dir else None, run_dir, Path.cwd(),
-    ])))
     lines.extend(_audit_section(
         _newest_audit_record([
             Path(audit_dir) if audit_dir else None, run_dir,
@@ -1358,8 +1269,9 @@ def render_report(
 
 
 # schema_version of the JSON report below: bump on any breaking key change
-# (tests/test_trace.py pins the top-level shape)
-REPORT_SCHEMA_VERSION = 1
+# (tests/test_trace.py pins the top-level shape). 2: the `perf` key went
+# with the bench whose records it read
+REPORT_SCHEMA_VERSION = 2
 
 
 def _numeric_subset(telemetry: dict, prefixes: tuple[str, ...]) -> dict | None:
@@ -1413,7 +1325,6 @@ def _supervisor_segments(events: list[dict] | None) -> list[dict] | None:
 
 def render_report_data(
     run_dir: str | Path,
-    bench_dir: str | Path | None = None,
     supervisor_log: str | Path | None = None,
     audit_dir: str | Path | None = None,
 ) -> dict:
@@ -1427,9 +1338,6 @@ def render_report_data(
     world = _read_world(run_dir)
     training = _training_summary(metrics)
 
-    bench = _newest_bench_record([
-        Path(bench_dir) if bench_dir else None, run_dir, Path.cwd(),
-    ])
     audit = _newest_audit_record([
         Path(audit_dir) if audit_dir else None, run_dir,
     ])
@@ -1479,12 +1387,11 @@ def render_report_data(
         "goodput": _numeric_subset(telemetry, ("goodput/",)),
         "device_memory": device_memory,
         "health": _numeric_subset(telemetry, ("health/", "nan_guard/")),
-        "perf": {"file": bench[1], "data": bench[0]} if bench else None,
         "audit": audit_data,
         "inference": _numeric_subset(telemetry, ("decode/", "eval/")),
         "serving": _numeric_subset(telemetry, ("serve/",)),
         # null when the run never post-trained (no `rl-fit` invocation) —
-        # additive: schema_version stays 1
+        # additive: no schema_version bump
         "rl": _numeric_subset(telemetry, ("rl/",)),
         # null when the run never routed (no `route` invocation)
         "router": _numeric_subset(telemetry, ("router/",)),
@@ -1512,7 +1419,6 @@ def render_report_data(
 
 def report_main(
     run_dir: str,
-    bench_dir: str | None = None,
     supervisor_log: str | None = None,
     audit_dir: str | None = None,
     format: str = "text",
@@ -1521,13 +1427,11 @@ def report_main(
     try:
         if format == "json":
             print(json.dumps(render_report_data(
-                run_dir, bench_dir=bench_dir, supervisor_log=supervisor_log,
-                audit_dir=audit_dir,
+                run_dir, supervisor_log=supervisor_log, audit_dir=audit_dir,
             )))
         else:
             print(render_report(
-                run_dir, bench_dir=bench_dir, supervisor_log=supervisor_log,
-                audit_dir=audit_dir,
+                run_dir, supervisor_log=supervisor_log, audit_dir=audit_dir,
             ))
     except FileNotFoundError as e:
         print(f"report: {e}", file=sys.stderr)

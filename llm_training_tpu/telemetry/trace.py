@@ -151,7 +151,7 @@ class TraceRecorder:
     def attach_sink(self, path: str | Path) -> bool:
         """Open `path` for appending sampled events; False when tracing is
         disabled or a sink is already attached (the first owner keeps it —
-        a fit must not steal the sink a bench stage opened)."""
+        a fit must not steal the sink its caller opened)."""
         if not self.enabled:
             return False
         with self._lock:

@@ -764,8 +764,8 @@ class Trainer:
         # trace sink (docs/observability.md#tracing): lifecycle events land
         # in <run_dir>/trace.jsonl; per-step spans only with
         # LLMT_TRACE_TRAIN=1. Process 0 only — run-dir artifacts follow the
-        # JsonlLogger policy. attach_sink is False when another owner (a
-        # bench stage) already holds the sink — then it keeps it.
+        # JsonlLogger policy. attach_sink is False when another owner
+        # already holds the sink — then it keeps it.
         trace_attached = False
         if run_dir is not None and jax.process_index() == 0:
             trace_attached = get_tracer().attach_sink(run_dir / "trace.jsonl")
@@ -1050,7 +1050,7 @@ class Trainer:
             # compute/comm attribution (docs/observability.md#device-plane):
             # walk the compiled step's HLO for collective payload bytes and
             # split them per mesh axis — the static comm fraction that
-            # report and bench track across rounds
+            # report prints
             for name, value in compiled_attribution_gauges(
                 aot_step, self._mesh_axis_sizes()
             ).items():
@@ -1253,8 +1253,8 @@ class Trainer:
                         # extra wiring, and NaN/spike provenance (nan_guard)
                         # reads the stash. The blocking fetch drains the dispatch
                         # queue, so it bills to step_compute like the log fetch —
-                        # this sync IS the overhead bench.py's
-                        # health_overhead_pct measures.
+                        # this sync IS what the health variant costs a step
+                        # over the plain one.
                         health_keys = [k for k in metrics if k.startswith("health/")]
                         with self.ledger.measure("step_compute"):
                             host = jax.device_get({k: metrics[k] for k in health_keys})

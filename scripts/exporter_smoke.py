@@ -36,8 +36,8 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 # the ONE strict scrape parser (raises ValueError on any malformed line)
-# and ephemeral-port probe, shared with the loadgen / bench exporter
-# stage / unit tests so format drift and probe fixes land once — jax-free
+# and ephemeral-port probe, shared with the loadgen and the unit
+# tests so format drift and probe fixes land once — jax-free
 # by graftlint contract
 from llm_training_tpu.telemetry.exporter import (  # noqa: E402
     find_free_port,

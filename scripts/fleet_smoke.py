@@ -19,7 +19,7 @@ Proves the fleet observability plane end to end on CPU, on every commit:
    interval with `/fleetz` naming the dead replica's stale card; the
    federation `/metrics` must parse as labeled Prometheus text
    throughout. A `fleet --once --out` snapshot then surfaces as report
-   --format json's `fleet` block (schema_version stays 1), and
+   --format json's `fleet` block (additive: no schema_version bump), and
    `fleet --once` against an empty dir exits 2 naming the searched path.
 
 This parent is jax-free (children own any backend) by the same contract
@@ -293,7 +293,7 @@ def main() -> int:
     )
     assert report.returncode == 0, report.stderr
     doc = json.loads(report.stdout)
-    assert doc["schema_version"] == 1, doc.get("schema_version")
+    assert doc["schema_version"] == 2, doc.get("schema_version")
     assert doc["fleet"] and doc["fleet"]["verdict"] == "red", doc.get("fleet")
     assert doc["fleet"]["stale_cards"], doc["fleet"]
 

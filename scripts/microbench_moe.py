@@ -1,17 +1,18 @@
 """Microbenchmark the MoE grouped-matmul primitive on the chip.
 
-The MoE bench proxy reaches 0.330 activated-MFU vs 0.567
-dense, with ~2x of the gap attributed to the `jax.lax.ragged_dot` lowering
-at E=8/width-704. This measures the three-projection expert MLP
+In rounds 4-5 an 8-expert MoE proxy trained well under its dense twin's
+utilisation on builders' chip runs (never re-taken by the driver: PERF.md
+section 7), and much of the gap was put down to the `jax.lax.ragged_dot`
+lowering at E=8/width-704. This measures the three-projection expert MLP
 (gate/up -> silu*mul -> down) as a unit — fwd and fwd+bwd — for:
 
-- `ragged`: jax.lax.ragged_dot (the XLA lowering the r4 bench used)
+- `ragged`: jax.lax.ragged_dot (the XLA lowering training uses)
 - `gmm`: the Pallas megablox grouped-matmul kernel bundled with jax
   (jax.experimental.pallas.ops.tpu.megablox.ops.gmm, custom VJP included)
 
-across expert counts E=8 (bench proxy) and E=64/E=256-class widths
-(DeepSeek-style fine-grained experts), with balanced groups (the bench's
-routing is near-balanced). MXU eff credits 3 * 2*rows*h*w FLOPs (fwd;
+across expert counts E=8 (that proxy) and E=64/E=256-class widths
+(DeepSeek-style fine-grained experts), with balanced groups (a trained
+router's are near-balanced). MXU eff credits 3 * 2*rows*h*w FLOPs (fwd;
 x3 for fwd+bwd) against the nominal v5e peak.
 
 Timing per scripts/microbench_ops.py: chained iterations in one jit,
@@ -41,7 +42,7 @@ _PEAK = 197e12  # v5e nominal bf16
 _RNG = np.random.default_rng(0)
 
 HIDDEN = int(os.environ.get("MOE_HIDDEN", 2048))
-# bench proxy: 2048 seq * 16 batch * top-2. ROWS is overridable so new
+# the proxy's rows: 2048 seq * 16 batch * top-2. ROWS is overridable so new
 # graph shapes (e.g. the bucketed gather/scatter probe) can be validated
 # small first, before a chip call is spent on the full size.
 ROWS = int(os.environ.get("MOE_ROWS", 65536))
@@ -120,7 +121,7 @@ def bench_one(n_experts: int, width: int, impl: str, bwd: bool, mlp=None):
 
 
 def main():
-    # (E, width): 8x704 = bench proxy (total expert params == 697M dense
+    # (E, width): 8x704 = the proxy (total expert params == 697M dense
     # MLP); E-sweeps hold TOTAL params constant so MFU is comparable;
     # 64x2048-class = DeepSeek-V3-like wide-E fine-grained shape at h2048
     cases = [

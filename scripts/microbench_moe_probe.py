@@ -1,10 +1,10 @@
 """Follow-up probes for the MoE grouped-matmul gap (r5).
 
 Reuses the harness from `scripts/microbench_moe.py` (timing discipline,
-input builder, ragged/gmm MLPs). Adds, at the bench proxy shape
+input builder, ragged/gmm MLPs). Adds, at the 8-expert proxy's shape
 (rows 65536, h 2048):
   1. ragged_dot at MXU-aligned width 768 vs the proxy's 704 — how much of
-     the gap is lane misalignment? (measured r5: 0.19 -> 0.21 fwd, minor)
+     the gap is lane misalignment? (a builder's r5 run: minor)
   2. gmm tiling sweep — rejected: non-128-multiple expert widths violate
      the megablox kernel's lowering constraints.
   3. a BUCKETED formulation: balanced groups -> fixed per-expert capacity

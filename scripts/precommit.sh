@@ -1,8 +1,10 @@
 #!/usr/bin/env bash
-# Commit gate: the not-slow test tier plus a bench trace/compile check.
-# Run before EVERY commit — round 4 shipped a broken HEAD because a
-# mid-edit tree was committed without this. A CPU gate: no leg needs or
-# reaches for a chip (the chip check is `python chip_smoke.py`, README).
+# Commit gate: graftlint, racecheck, shardcheck, the not-slow test tier, then
+# the CPU smokes (report, generate/evaluate, serve, serve drain, trace,
+# exporter, profile, fleet, router, rl, forced-NaN, kill-and-resume,
+# durability). Run before EVERY commit — round 4 shipped a broken HEAD
+# because a mid-edit tree was committed without this. A CPU gate: no leg needs
+# or reaches for a chip (the chip check is `python chip_smoke.py`, README).
 #
 # Usage: scripts/precommit.sh [extra pytest args]
 set -euo pipefail
@@ -247,7 +249,7 @@ JAX_PLATFORMS=cpu python -m llm_training_tpu report "${SMOKE_ROOT}/smoke/cpu-smo
 python - "${SMOKE_ROOT}/report.json" <<'EOF'
 import json, sys
 doc = json.load(open(sys.argv[1]))
-assert doc["schema_version"] == 1, doc.get("schema_version")
+assert doc["schema_version"] == 2, doc.get("schema_version")
 for key in ("training", "goodput", "serving", "slo", "trace", "telemetry"):
     assert key in doc, f"report json missing {key!r}"
 assert doc["goodput"]["goodput/total_s"] > 0
@@ -337,57 +339,5 @@ JAX_PLATFORMS=cpu python scripts/crash_resume_smoke.py "${SMOKE_ROOT}/resilience
 # wall
 echo "== precommit: durability smoke (manifests + mirror heal + chaos corruption) =="
 JAX_PLATFORMS=cpu python scripts/durability_smoke.py "${SMOKE_ROOT}/durability"
-
-# bench harness gate (docs/performance.md): the full stage/subprocess/
-# partial-JSON plumbing must work on CPU so bench wiring can't rot unnoticed
-# between chip runs — every stage ok, a summary record with the
-# stage/partial schema whose MFU is null (a CPU has no peak: dry reports
-# counts only), and the report CLI's == Perf == section rendering it. Dry
-# children run with JAX_PLATFORMS=cpu (bench.py child_env); precommit is a
-# CPU gate and never reaches for a chip
-echo "== precommit: bench dry (stage/partial-JSON plumbing) =="
-BENCH_OUT="${SMOKE_ROOT}/bench_dry.json" python bench.py --dry \
-    | tee "${SMOKE_ROOT}/bench_dry.log"
-python - "${SMOKE_ROOT}/bench_dry.log" <<'EOF'
-import json, sys
-records = [json.loads(l) for l in open(sys.argv[1]) if l.strip().startswith("{")]
-partials = [r for r in records if r.get("partial")]
-summary = records[-1]
-assert partials, "no per-stage partial records emitted"
-assert summary["stage"] == "summary" and summary["partial"] is False, summary
-assert summary["value"] is None, f"dry bench claimed an MFU on CPU: {summary}"
-assert summary["n_params"] > 0 and summary["backend"] == "cpu", summary
-bad = {s: i for s, i in summary["stages"].items() if i["status"] != "ok"}
-assert not bad, f"dry bench stages failed: {bad}"
-print("bench dry: OK", {s: i["status"] for s, i in summary["stages"].items()})
-EOF
-JAX_PLATFORMS=cpu python -m llm_training_tpu report "${SMOKE_ROOT}/smoke/cpu-smoke" \
-    --bench-dir "${SMOKE_ROOT}" | tee "${SMOKE_ROOT}/report_perf.log"
-grep -q "== Perf ==" "${SMOKE_ROOT}/report_perf.log"
-grep -q "bench record: bench_dry.json" "${SMOKE_ROOT}/report_perf.log"
-
-# chaos leg: an env-forced wedge in ONE stage must degrade to an error
-# record while the remaining stages still land valid partial JSON and the
-# summary stays parseable (the r04/r05 failure mode, made survivable)
-echo "== precommit: bench chaos wedge (degrade-not-die) =="
-rc=0
-# BENCH_TRACE=0 / BENCH_EXPORTER=0: the short RUN_TIMEOUT that kills the
-# wedged train stage would also fuse the legitimate A/B-fit stages
-BENCH_CHAOS_WEDGE=train BENCH_RUN_TIMEOUT=15 BENCH_HEALTH=0 BENCH_TRACE=0 \
-    BENCH_EXPORTER=0 \
-    python bench.py --dry | tee "${SMOKE_ROOT}/bench_wedge.log" || rc=$?
-test "$rc" -eq 1  # train (the headline) failed -> documented exit 1
-python - "${SMOKE_ROOT}/bench_wedge.log" <<'EOF'
-import json, sys
-records = [json.loads(l) for l in open(sys.argv[1]) if l.strip().startswith("{")]
-summary = records[-1]
-assert summary["stage"] == "summary" and summary["value"] is None, summary
-stages = summary["stages"]
-assert stages["train"]["status"] == "error", stages
-assert "wedged" in stages["train"]["error"], stages["train"]
-assert stages["backend_init"]["status"] == "ok", stages
-assert stages["decode"]["status"] == "ok", stages  # survived the wedge
-print("bench chaos wedge: OK", {s: i["status"] for s, i in stages.items()})
-EOF
 
 echo "== precommit: OK =="

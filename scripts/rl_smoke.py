@@ -19,7 +19,7 @@ Proves the on-policy GRPO loop end to end on CPU, on every commit:
    `--sync-mode host`, so the oracle sync path is exercised in CI too.
 3. **report leg** — the learning run's dir must render an `== RL ==`
    section and an `"rl"` block in `--format json` (additive,
-   schema_version stays 1).
+   no schema_version bump).
 
 This parent is jax-free by contract (analysis/contracts.py) — the
 rl-fit children own the backend.
@@ -171,7 +171,7 @@ def main() -> int:
     )
     assert report_json.returncode == 0, report_json.stderr
     data = json.loads(report_json.stdout)
-    assert data["schema_version"] == 1, data["schema_version"]
+    assert data["schema_version"] == 2, data["schema_version"]
     assert data["rl"] and data["rl"]["rl/rounds"] == 10.0, data.get("rl")
 
     print("rl smoke: OK — reward improved, SIGTERM survived, report renders")

@@ -16,13 +16,13 @@ enumeration, sorted JSON output, no timestamps — re-running on identical
 hardware produces an identical table modulo the measured times. On CPU the
 kernels run in interpreter mode; entries are tagged `cpu-interpret` and are
 plumbing placeholders (real block choice only matters compiled on TPU) —
-re-run on the bench chip to fill in measured entries.
+re-run on the chip to fill in measured entries.
 
 Usage:
   python scripts/tune_flash_blocks.py                    # backend-sized sweep
   python scripts/tune_flash_blocks.py --seqs 8192,32768 --blocks 1024x1024,2048x1024
   python scripts/tune_flash_blocks.py --seed-defaults    # also write the
-      v5e-measured 1024x1024 @ seq-2048/8192 entries (BASELINE/r3-r4 data)
+      1024x1024 @ seq-2048/8192 entries builders chose on a v5e in rounds 3-4
 
 Timing follows scripts/microbench_flash.py: chained
 iterations inside one jit, per-rep salt, completion proven by fetching
@@ -174,10 +174,10 @@ def sweep(args) -> dict:
     return entries
 
 
-# v5e measurements already recorded in-repo (BASELINE.md / the r3-r4 sweep
-# notes that used to live on the import-time constant): 1024x1024 best at
-# seq 2048 and still the 8k bench choice. Written only with --seed-defaults
-# so a CPU placeholder run cannot masquerade as chip data.
+# the tile builders chose on a v5e in rounds 3-4 (their sweep is not in the
+# repo and the driver never re-took it: ROADMAP S6): 1024x1024 at seq 2048
+# and 8192. Written only with --seed-defaults so a CPU placeholder run
+# cannot masquerade as chip data.
 _V5E_SEEDS = {
     tuning.table_key(kind, seq, 128, jnp.bfloat16, True, None): {
         "block_q": 1024, "block_k": 1024, "time_us": None, "backend": "v5e",

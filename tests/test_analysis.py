@@ -23,6 +23,8 @@ import pytest
 from llm_training_tpu.analysis import contracts
 from llm_training_tpu.analysis.engine import (
     DEFAULT_BASELINE,
+    DEFAULT_SCAN,
+    RepoContext,
     all_rules,
     load_baseline,
     main,
@@ -57,7 +59,7 @@ def make_repo(tmp_path: Path, files: dict[str, str]) -> Path:
         "llm_training_tpu/callbacks/loggers.py": _DEFAULT_LOGGERS,
         "llm_training_tpu/parallel/__init__.py": "",
         "llm_training_tpu/parallel/sharding.py": _DEFAULT_SHARDING,
-        "docs/performance.md": "env table: BENCH_DOCUMENTED, FLASH_DOCUMENTED\n",
+        "docs/performance.md": "env table: LLMT_DOCUMENTED, FLASH_DOCUMENTED\n",
     }
     for contract_rel in contracts.JAX_FREE_CONTRACTS:
         base.setdefault(contract_rel, "")
@@ -103,6 +105,37 @@ def test_whole_repo_is_clean_and_fast():
     assert result.findings == [], [f.render() for f in result.findings]
     assert baseline == set(), "baseline must stay empty; fix or suppress inline"
     assert elapsed < 10.0, f"lint gate took {elapsed:.1f}s (budget 10s)"
+
+
+def test_default_scan_names_only_paths_that_exist():
+    """`RepoContext._discover` passes over a path that is not there, so a
+    deleted entry script would leave the scan set in silence: every entry
+    of `DEFAULT_SCAN` is a file or a directory of this tree."""
+    assert [entry for entry in DEFAULT_SCAN if not (REPO_ROOT / entry).exists()] == []
+
+
+def test_env_doc_prefixes_are_the_ones_the_tree_reads():
+    """The env-doc rule demands documents for the namespaces the tree reads
+    and for no other: each prefix of `ENV_VAR_PATTERN` names at least one
+    variable some scanned file reads, and no scanned file reads a variable
+    of the deleted bench's namespace."""
+    import ast
+    import re
+
+    from llm_training_tpu.analysis.env_docs import _docstring_ids
+
+    prefixes = re.match(r"\^\(([A-Z|]+)\)_", contracts.ENV_VAR_PATTERN).group(1).split("|")
+    read: set[str] = set()
+    for parsed in RepoContext(REPO_ROOT).files:
+        docstrings = _docstring_ids(parsed.tree)
+        read.update(
+            node.value for node in ast.walk(parsed.tree)
+            if isinstance(node, ast.Constant) and isinstance(node.value, str)
+            and id(node) not in docstrings and re.fullmatch(r"[A-Z]+(?:_[A-Z0-9]+)+", node.value)
+        )
+    assert sorted(name for name in read if name.startswith("BENCH_")) == []
+    for prefix in prefixes:
+        assert any(name.startswith(prefix + "_") for name in read), prefix
 
 
 def test_analysis_package_never_imports_jax():
@@ -401,15 +434,15 @@ def test_update_baseline_with_narrow_paths_keeps_outside_entries(tmp_path, capsy
     root = make_repo(
         tmp_path,
         {
-            "bench.py": "import jax\n",
+            "chip_smoke.py": "import jax\n",
             "llm_training_tpu/other/__init__.py": "",
         },
     )
     baseline = root / "config/lint_baseline.json"
     assert main(["--root", str(root), "--update-baseline"]) == 0  # full scan
     assert main(["--root", str(root)]) == 0  # grandfathered
-    # a narrow-path update must not drop the bench.py entry it cannot see.
-    # (scanning a path with no contract files would still WALK bench.py via
+    # a narrow-path update must not drop the chip_smoke.py entry it cannot see.
+    # (scanning a path with no contract files would still WALK chip_smoke.py via
     # the contract table, so also restrict to a rule that never leaves the
     # scan set — the hostile case for entry preservation)
     assert main(
@@ -451,7 +484,7 @@ def test_update_baseline_with_narrow_rules_keeps_other_rules_entries(tmp_path, c
     root = make_repo(
         tmp_path,
         {
-            "bench.py": "import jax\n",
+            "chip_smoke.py": "import jax\n",
         },
     )
     baseline = root / "config/lint_baseline.json"
@@ -473,7 +506,7 @@ def test_real_supervisor_contract_holds_and_breaks_when_jax_is_added(tmp_path):
     import shutil
 
     root = tmp_path / "copy"
-    for rel in ("llm_training_tpu", "scripts", "bench.py", "docs", "README.md"):
+    for rel in ("llm_training_tpu", "scripts", "chip_smoke.py", "docs", "README.md"):
         src = REPO_ROOT / rel
         if src.is_dir():
             shutil.copytree(src, root / rel, ignore=shutil.ignore_patterns("__pycache__"))
@@ -635,16 +668,16 @@ def test_env_doc_drift_flags_undocumented_reads(tmp_path):
     src = '''
     import os
 
-    """BENCH_DOCSTRING_ONLY is prose, not a read."""
+    """LLMT_DOCSTRING_ONLY is prose, not a read."""
 
-    KNOB = os.environ.get("BENCH_SECRET_KNOB")
-    OK = os.environ.get("BENCH_DOCUMENTED")
+    KNOB = os.environ.get("LLMT_SECRET_KNOB")
+    OK = os.environ.get("LLMT_DOCUMENTED")
     TABLE = {"block_q": "FLASH_SECRET_TILE"}  # dict values count as reads
     '''
     root = make_repo(tmp_path, {"llm_training_tpu/env.py": src})
     found = findings_for(root, "env-doc-drift")
     names = sorted(f.message.split("`")[1] for f in found)
-    assert names == ["BENCH_SECRET_KNOB", "FLASH_SECRET_TILE"], [
+    assert names == ["FLASH_SECRET_TILE", "LLMT_SECRET_KNOB"], [
         f.render() for f in found
     ]
 
@@ -652,7 +685,7 @@ def test_env_doc_drift_flags_undocumented_reads(tmp_path):
 def test_env_doc_drift_ignores_docstring_mentions(tmp_path):
     src = '''
     def f():
-        """Reads BENCH_PROSE_ONLY from the environment (doc prose)."""
+        """Reads LLMT_PROSE_ONLY from the environment (doc prose)."""
         return None
     '''
     root = make_repo(tmp_path, {"llm_training_tpu/env.py": src})
@@ -816,12 +849,12 @@ def test_parse_errors_from_contract_walk_surface_on_narrow_scans(tmp_path):
     root = make_repo(
         tmp_path,
         {
-            "bench.py": "import jax\ndef broken(:\n",
+            "chip_smoke.py": "import jax\ndef broken(:\n",
             "llm_training_tpu/other/__init__.py": "",
         },
     )
     result = run_analysis(root, paths=["llm_training_tpu/other"])
-    assert any(f.rule == "parse-error" and f.path == "bench.py" for f in result.findings), [
+    assert any(f.rule == "parse-error" and f.path == "chip_smoke.py" for f in result.findings), [
         f.render() for f in result.findings
     ]
 
@@ -845,7 +878,7 @@ def test_contract_suppressions_work_outside_narrow_scan_paths(tmp_path):
     root = make_repo(
         tmp_path,
         {
-            "bench.py": (
+            "chip_smoke.py": (
                 "# lint: allow(jax-free-import): proving suppressions reach "
                 "walked-not-scanned files\nimport jax\n"
             ),

@@ -414,7 +414,7 @@ def test_interpret_is_impossible_on_a_tpu_backend(monkeypatch):
 # (134 MB) and then XLA's in-place update copies it into the carried slab:
 # two instructions a layer where the parent had one and a restack of all
 # three (4 -> 6 by count, the same 805 MB written; a Pallas recurrence that
-# writes in place is ROADMAP R10). A chunk scatters one slot a layer in place
+# writes in place is ROADMAP S13). A chunk scatters one slot a layer in place
 # (7 -> 3).
 _PRODUCED = {
     "phi3m-serve-rollout": {"decode": {"pool": 0, "stack": 0}, "prefill": {"pool": 0, "stack": 0}},

@@ -16,7 +16,7 @@ import pytest
 REPO = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO))
 
-import chip_smoke  # noqa: E402  (jax-free at import, like bench.py)
+import chip_smoke  # noqa: E402  (jax-free at import: a lint contract)
 
 
 def test_chip_smoke_refuses_to_run_without_a_tpu(tmp_path):

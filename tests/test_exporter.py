@@ -1,9 +1,8 @@
-"""Live-telemetry tests: the /metrics//statusz//healthz exporter, the SLO
-burn-rate monitor, and the BENCH perf-regression ledger
-(docs/observability.md#live-telemetry, #slo; docs/performance.md#perf-ledger).
+"""Live-telemetry tests: the /metrics//statusz//healthz exporter and the
+SLO burn-rate monitor (docs/observability.md#live-telemetry, #slo).
 
-Everything here is jax-free host code (the exporter/SLO/ledger trio carry
-graftlint jax-free contracts), so these tests cost milliseconds. HTTP
+Everything here is jax-free host code (the exporter and the SLO monitor
+carry graftlint jax-free contracts), so these tests cost milliseconds. HTTP
 tests bind ephemeral ports on localhost; clock-driven tests inject fake
 clocks — no sleeps.
 """
@@ -27,13 +26,6 @@ from llm_training_tpu.telemetry.exporter import (
     watch_main,
 )
 from llm_training_tpu.telemetry.goodput import GoodputLedger
-from llm_training_tpu.telemetry.perf_ledger import (
-    check_regression,
-    find_comparison,
-    load_history,
-    normalize_record,
-    trend_table,
-)
 from llm_training_tpu.telemetry.registry import TelemetryRegistry
 from llm_training_tpu.telemetry.slo import (
     SLOMonitor,
@@ -499,130 +491,6 @@ def test_slo_config_from_env(monkeypatch):
     assert monitor is not None and len(monitor.specs) == 1
 
 
-# ---------------------------------------------------------- perf ledger
-
-
-def _write_round(tmp_path, n, wrapped=False, **fields):
-    record = {
-        "metric": "llama_clm_train_mfu", "stage": "summary", "partial": False,
-        **fields,
-    }
-    if wrapped:
-        record = {"n": n, "cmd": "python bench.py", "rc": 0, "tail": "",
-                  "parsed": record}
-    (tmp_path / f"BENCH_r{n:02d}.json").write_text(json.dumps(record))
-
-
-def test_perf_ledger_parses_both_shapes_and_sorts(tmp_path):
-    _write_round(tmp_path, 2, wrapped=True, value=0.5, backend="tpu")
-    _write_round(tmp_path, 1, value=0.4, backend="tpu")
-    (tmp_path / "BENCH_r03.json").write_text('{"n": 3, "rc": 1, "parsed": null}')
-    (tmp_path / "not_a_round.json").write_text("{}")
-    history = load_history(tmp_path)
-    assert [r["round"] for r in history] == [1, 2, 3]
-    assert history[1]["value"] == 0.5  # unwrapped
-    assert history[2]["value"] is None and "crashed" in history[2]["error"]
-    table = trend_table(history)
-    assert "r01" in table and "r03" in table and "crashed" in table
-
-
-def test_perf_ledger_same_backend_comparison_only(tmp_path):
-    _write_round(tmp_path, 1, value=0.5, backend="tpu", model="8b-layer")
-    _write_round(tmp_path, 2, value=0.01, backend="cpu", model="8b-layer")
-    # newest is cpu; only tpu history before it -> nothing to compare
-    verdict = check_regression(load_history(tmp_path))
-    assert verdict["status"] == "ok" and "note" in verdict
-
-
-def test_perf_ledger_flags_seeded_regression(tmp_path):
-    _write_round(
-        tmp_path, 1, value=0.5, backend="cpu", model="8b-layer",
-        decode_tokens_per_sec=2000.0, serve_ttft_p50_ms=10.0,
-    )
-    _write_round(
-        tmp_path, 2, value=0.3, backend="cpu", model="8b-layer",
-        decode_tokens_per_sec=1900.0, serve_ttft_p50_ms=20.0,
-    )
-    verdict = check_regression(load_history(tmp_path), tolerance_pct=25.0)
-    assert verdict["status"] == "regression"
-    flagged = {c["metric"] for c in verdict["checked"] if c["regressed"]}
-    # mfu -40%, ttft +100% regress; decode -5% is inside tolerance
-    assert flagged == {"value", "serve_ttft_p50_ms"}
-    assert verdict["baseline"] == "BENCH_r01.json"
-    # widening the tolerance clears it
-    ok = check_regression(load_history(tmp_path), tolerance_pct=200.0)
-    assert ok["status"] == "ok"
-
-
-def test_perf_ledger_crashed_newest_round_fails_the_gate(tmp_path):
-    """The round being committed is the newest by number; one that crashed
-    before reporting MFU must fail --check-regression itself — not slide
-    the comparison back to the two previous healthy rounds."""
-    _write_round(tmp_path, 1, value=0.5, backend="cpu", model="m")
-    _write_round(tmp_path, 2, value=0.5, backend="cpu", model="m")
-    (tmp_path / "BENCH_r03.json").write_text('{"n": 3, "rc": 1, "parsed": null}')
-    verdict = check_regression(load_history(tmp_path))
-    assert verdict["status"] == "regression"
-    assert "no headline value" in verdict["findings"][0]
-    assert verdict["candidate"] == "BENCH_r03.json"
-
-
-def test_perf_ledger_improvements_never_flag(tmp_path):
-    _write_round(tmp_path, 1, value=0.3, backend="cpu", model="m",
-                 serve_ttft_p50_ms=50.0)
-    _write_round(tmp_path, 2, value=0.9, backend="cpu", model="m",
-                 serve_ttft_p50_ms=1.0)
-    assert check_regression(load_history(tmp_path), 10.0)["status"] == "ok"
-
-
-def test_bench_check_regression_cli(tmp_path):
-    """The real `bench.py --check-regression` entry, exit codes included
-    (the acceptance bar: a clean board exits 0, a regressed round exits
-    nonzero). The repo commits no rounds, so the clean board is written
-    here."""
-    import subprocess
-    import sys
-    from pathlib import Path
-
-    repo = Path(__file__).resolve().parent.parent
-    bench = str(repo / "bench.py")
-    clean = tmp_path / "clean"
-    clean.mkdir()
-    _write_round(clean, 1, value=0.5, backend="tpu", model="m")
-    _write_round(clean, 2, wrapped=True, value=0.51, backend="tpu", model="m")
-    result = subprocess.run(
-        [sys.executable, bench, "--check-regression", "--bench-dir", str(clean)],
-        capture_output=True, text=True,
-    )
-    assert result.returncode == 0, result.stdout + result.stderr
-    assert "round" in result.stdout  # the trend table rendered
-    # seeded regression -> exit 3
-    _write_round(tmp_path, 1, value=0.5, backend="cpu", model="m")
-    _write_round(tmp_path, 2, value=0.1, backend="cpu", model="m")
-    result = subprocess.run(
-        [sys.executable, bench, "--check-regression",
-         "--bench-dir", str(tmp_path)],
-        capture_output=True, text=True,
-    )
-    assert result.returncode == 3, result.stdout + result.stderr
-    verdict = json.loads(result.stdout.strip().splitlines()[-1])
-    assert verdict["status"] == "regression"
-    # empty history -> exit 2
-    empty = tmp_path / "empty"
-    empty.mkdir()
-    result = subprocess.run(
-        [sys.executable, bench, "--check-regression", "--bench-dir", str(empty)],
-        capture_output=True, text=True,
-    )
-    assert result.returncode == 2
-
-
-def test_normalize_record_passthrough():
-    assert normalize_record({"value": 1.0}) == {"value": 1.0}
-    assert normalize_record({"parsed": {"value": 2.0}}) == {"value": 2.0}
-    assert find_comparison([]) is None
-
-
 # -------------------------------------------------------- report == SLO ==
 
 
@@ -649,10 +517,9 @@ def _slo_run_dir(tmp_path, with_slo=True):
     return run_dir
 
 
-def test_report_slo_section_renders(tmp_path, monkeypatch):
+def test_report_slo_section_renders(tmp_path):
     from llm_training_tpu.telemetry.report import render_report, render_report_data
 
-    monkeypatch.chdir(tmp_path)  # keep the perf cwd fallback out
     run_dir = _slo_run_dir(tmp_path)
     text = render_report(run_dir)
     assert "== SLO ==" in text
@@ -665,10 +532,9 @@ def test_report_slo_section_renders(tmp_path, monkeypatch):
     assert doc["slo"]["slo/serve/ttft_p99_ms/worst"] == 312.5
 
 
-def test_report_slo_section_omitted_without_config(tmp_path, monkeypatch):
+def test_report_slo_section_omitted_without_config(tmp_path):
     from llm_training_tpu.telemetry.report import render_report, render_report_data
 
-    monkeypatch.chdir(tmp_path)
     run_dir = _slo_run_dir(tmp_path, with_slo=False)
     assert "== SLO ==" not in render_report(run_dir)
     assert render_report_data(run_dir)["slo"] is None

@@ -1,15 +1,13 @@
-"""Flash-attention block-size tuning layer + wedge-proof bench plumbing.
+"""Flash-attention block-size tuning layer.
 
 Covers `ops/pallas/tuning.py` (resolution order: call > env > table >
 default, all read at CALL time — the old import-time FLASH_BLOCK_* read
 made overrides require a re-import), the telemetry gauges recording what
-each compiled step ran with, and the pure parts of `bench.py`'s
-stage/partial-JSON orchestration (summary assembly, stage schema)."""
+each compiled step ran with, and the one table of chip peaks the MFU
+callback divides by."""
 
 import json
-import os
-import subprocess
-import sys
+import re
 from pathlib import Path
 
 import jax
@@ -20,9 +18,6 @@ import pytest
 from llm_training_tpu.ops.pallas import tuning
 from llm_training_tpu.ops.pallas.flash_attention import flash_attention
 from llm_training_tpu.telemetry import TelemetryRegistry, set_registry
-
-sys.path.insert(0, str(Path(__file__).parent.parent))  # repo root: bench.py
-import bench
 
 
 @pytest.fixture(autouse=True)
@@ -427,126 +422,21 @@ def test_resolved_blocks_fit_sequence():
     assert out.shape == q.shape
 
 
-# ------------------------------------------------------------ bench schema
+# ------------------------------------------------ the one table of chip peaks
 
 
-def _ok(stage, **payload):
-    return {"stage": stage, "partial": True, "status": "ok", **payload}
-
-
-def test_bench_summary_all_ok():
-    results = {
-        "backend_init": _ok("backend_init", backend="cpu"),
-        "train": _ok("train", value=0.61, vs_baseline=1.109, sec_per_step=1.5,
-                     blocks={"fwd": [1024, 1024], "bwd": [512, 1024]},
-                     goodput_pct=93.0),
-        "health": _ok("health", sec_per_step_health=1.65),
-        "trace": _ok("trace", sec_per_step_trace=1.515, trace_events_written=60),
-        "decode": _ok("decode", prefill_time_s=0.1, decode_tokens_per_sec=900.0),
-    }
-    summary = bench.summarize(results)
-    assert summary["metric"] == "llama_clm_train_mfu"
-    assert summary["stage"] == "summary" and summary["partial"] is False
-    assert summary["value"] == 0.61 and summary["vs_baseline"] == 1.109
-    assert summary["health_overhead_pct"] == pytest.approx(10.0)
-    assert summary["trace_overhead_pct"] == pytest.approx(1.0)
-    assert summary["blocks"] == {"fwd": [1024, 1024], "bwd": [512, 1024]}
-    assert all(summary["stages"][s]["status"] == "ok" for s in results)
-
-
-def test_bench_summary_degrades_single_stage_to_error():
-    """A wedged stage becomes one error entry; the headline MFU and the
-    other stages' metrics survive."""
-    results = {
-        "backend_init": _ok("backend_init"),
-        "train": _ok("train", value=0.6, vs_baseline=1.09, sec_per_step=1.5),
-        "health": {"stage": "health", "partial": True, "status": "error",
-                   "error": "stage wedged: no completion within 15s (child killed)",
-                   "rc": -9},
-        "decode": _ok("decode", decode_tokens_per_sec=800.0),
-    }
-    summary = bench.summarize(results)
-    assert summary["value"] == 0.6
-    assert summary["health_overhead_pct"] is None
-    assert summary["trace_overhead_pct"] is None
-    assert summary["decode_tokens_per_sec"] == 800.0
-    assert summary["stages"]["health"]["status"] == "error"
-    assert "wedged" in summary["stages"]["health"]["error"]
-
-
-def test_bench_summary_train_failure_keeps_record_valid():
-    results = {
-        "backend_init": _ok("backend_init"),
-        "train": {"stage": "train", "partial": True, "status": "error",
-                  "error": "stage failed (exit 1)", "rc": 1},
-        "decode": _ok("decode", decode_tokens_per_sec=800.0),
-    }
-    summary = bench.summarize(results)
-    assert summary["value"] is None and summary["vs_baseline"] is None
-    assert "error" in summary
-    assert summary["decode_tokens_per_sec"] == 800.0
-    json.dumps(summary)  # the record must stay serializable for the driver
-
-
-def test_report_perf_section_degrades_on_malformed_record():
-    """The broad bench*.json glob (with a cwd fallback) can pick up a
-    foreign or hand-mangled file — the report must render one honest line,
-    not crash with a traceback."""
-    from llm_training_tpu.telemetry.report import _perf_section
-
-    for bad in (
-        {"value": "n/a"},                                  # non-numeric mfu
-        {"value": 0.6, "blocks": {"fwd": [1, 2, 3]}},      # unpackable blocks
-        {"value": 0.6, "stages": {"train": "ok"}},         # stage not a dict
-        {"value": 0.6, "health_overhead_pct": "high"},
-    ):
-        lines = _perf_section((bad, "bench_bad.json"))
-        assert lines[1] == "== Perf ==" and "bench_bad.json" in lines[2]
-        assert any("unreadable bench record" in l for l in lines), (bad, lines)
-    # a well-formed record still renders fully
-    ok = _perf_section(({"value": 0.6, "vs_baseline": 1.09,
-                         "blocks": {"fwd": [1024, 1024]},
-                         "stages": {"train": {"status": "ok"}}}, "b.json"))
-    assert any(l.startswith("mfu: 0.6") for l in ok)
-    assert any("fwd 1024x1024" in l for l in ok)
-
-
-def test_bench_chaos_crash_degrades_stage_not_run():
-    """Real subprocess leg: a chaos-crashed backend_init child yields an
-    error record + a summary line, not a dead bench (fast: the child dies
-    before any jax work)."""
-    proc = subprocess.run(
-        [sys.executable, str(Path(bench.__file__).resolve()), "--dry"],
-        capture_output=True, text=True, timeout=240,
-        env={**os.environ,
-             "BENCH_CHAOS_CRASH": "backend_init", "BENCH_STAGE_RETRIES": "0"},
-    )
-    lines = [json.loads(l) for l in proc.stdout.splitlines() if l.strip().startswith("{")]
-    assert lines, proc.stderr
-    summary = lines[-1]
-    assert summary["stage"] == "summary" and summary["value"] is None
-    assert summary["stages"]["backend_init"]["status"] == "error"
-    # dependent stages skipped, not hung
-    assert summary["stages"]["train"]["status"] == "skipped"
-    assert proc.returncode == 1
-
-
-# ------------------------------------------- bench measures a TPU or nothing
-
-
-def test_bench_refuses_to_measure_without_a_tpu():
-    """Outside --dry a non-TPU backend is an error at backend_init (so no
-    later stage runs); --dry passes and reports what it ran on."""
-    with pytest.raises(SystemExit, match="bench needs a TPU"):
-        bench.stage_backend_init(dry=False)
-    record = bench.stage_backend_init(dry=True)
-    assert record["backend"] == "cpu" and record["n_devices"] >= 1
-
-
-def test_bench_has_one_peak_table_and_no_cpu_peak(monkeypatch):
+def test_there_is_one_peak_table_and_no_cpu_peak(monkeypatch):
+    """MFU divides by `callbacks/time_estimator.py`'s table and by nothing
+    else: no other module of the package spells a chip's peak FLOP/s, and a
+    device the table does not know (this CPU) has none, never a default."""
     from llm_training_tpu.callbacks import time_estimator
 
-    assert not hasattr(bench, "_PEAK_FLOPS") and not hasattr(bench, "_detect_peak")
+    package = Path(time_estimator.__file__).resolve().parents[1]
+    spelled = sorted(
+        str(path.relative_to(package)) for path in package.rglob("*.py")
+        if re.search(r"\b\d+(?:\.\d+)?e1[2-5]\b", path.read_text())
+    )
+    assert spelled == ["callbacks/time_estimator.py"]
     assert time_estimator.peak_flops_per_device() is None  # this CPU: unknown kind
 
     class Device:
@@ -554,14 +444,3 @@ def test_bench_has_one_peak_table_and_no_cpu_peak(monkeypatch):
 
     monkeypatch.setattr(time_estimator.jax, "devices", lambda: [Device()])
     assert time_estimator.peak_flops_per_device() == 197e12
-
-
-def test_bench_dry_shrinks_by_flag_not_by_backend(monkeypatch):
-    for name in ("BENCH_MODEL", "BENCH_SEQ", "BENCH_BATCH", "BENCH_LAYERS",
-                 "BENCH_HIDDEN", "BENCH_STEPS", "BENCH_WARMUP", "BENCH_REMAT"):
-        monkeypatch.delenv(name, raising=False)
-    kwargs, seq, batch, steps, warmup = bench._model_setup(dry=True)
-    assert (kwargs["hidden_size"], seq, batch, steps, warmup) == (128, 2048, 4, 3, 1)
-    kwargs, seq, batch, steps, warmup = bench._model_setup(dry=False)
-    assert (kwargs["hidden_size"], kwargs["intermediate_size"], seq, batch) == (
-        4096, 14336, 8192, 3)

@@ -129,13 +129,13 @@ def test_exporter_start_stop_drops_and_removes_card(tmp_path, monkeypatch):
     fleet_dir = tmp_path / "fleet"
     monkeypatch.setenv("LLMT_FLEET_DIR", str(fleet_dir))
     assert resolve_fleet_dir() == fleet_dir
-    exporter = MetricsExporter(0, registry=TelemetryRegistry(), role="bench")
+    exporter = MetricsExporter(0, registry=TelemetryRegistry(), role="serve")
     assert exporter.start()
     try:
         replicas = discover_replicas(fleet_dir)
         assert len(replicas) == 1
         assert replicas[0]["port"] == exporter.port
-        assert replicas[0]["role"] == "bench"
+        assert replicas[0]["role"] == "serve"
     finally:
         exporter.stop()
     assert discover_replicas(fleet_dir) == []
@@ -393,7 +393,7 @@ def test_report_fleet_block_and_section(tmp_path):
                    "llmt_fleet_replicas": 2.0},
     }))
     doc = render_report_data(run_dir)
-    assert doc["schema_version"] == REPORT_SCHEMA_VERSION == 1
+    assert doc["schema_version"] == REPORT_SCHEMA_VERSION == 2
     fleet = doc["fleet"]
     assert fleet["verdict"] == "red" and fleet["sweeps"] == 9
     assert fleet["stale_cards"] == ["serve-1-22"]

@@ -1,6 +1,6 @@
 """Loss-curve parity vs the torch/CUDA reference semantics.
 
-BASELINE.md's north star is throughput at "loss-curve parity vs the CUDA
+The project's north star (`BASELINE.json`) is throughput at "loss-curve parity vs the CUDA
 FSDP baseline". This harness proves the training *math* matches end to end:
 the same tiny Llama (identical weights via the HF converter), the same token
 stream, and the same optimizer hyperparameters are trained for 20 steps in

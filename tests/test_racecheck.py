@@ -527,7 +527,7 @@ def test_copied_tree_acceptance_seeded_races_exit_1(tmp_path, capsys):
     inversion into a copy of the real tree makes `--races` exit 1, naming
     the attribute, both entry threads, and the lock."""
     root = tmp_path / "copy"
-    for rel in ("llm_training_tpu", "scripts", "bench.py", "config"):
+    for rel in ("llm_training_tpu", "scripts", "chip_smoke.py", "config"):
         src = REPO_ROOT / rel
         if src.is_dir():
             shutil.copytree(

@@ -584,7 +584,7 @@ def test_chaos_router_hooks_parse_and_fire_once(monkeypatch):
     (None, 2, True), ("tpu", 2, True), ("tpu,cpu", 4, True),
 ])
 def test_replica_spawns_never_share_an_accelerator(monkeypatch, platforms, replicas, refused):
-    """Replica spawns assign no chips (ROADMAP S3): more than one serve
+    """Replica spawns assign no chips (ROADMAP R11): more than one serve
     child is refused at once, by name, unless the environment itself states
     the CPU test path — never a hang at the second child's backend init."""
     from llm_training_tpu.serve.router import require_one_process_per_chip

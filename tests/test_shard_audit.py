@@ -164,7 +164,7 @@ def test_trainer_state_shardings_are_strict(devices):
 
 
 def test_audit_matrix_all_families_all_meshes_clean():
-    """THE regression gate for the ROADMAP-5 rule-table refactor: every
+    """THE regression gate for a change of the sharding rule table: every
     registered family × every matrix mesh resolves with zero findings at
     HEAD, well inside the acceptance budget."""
     result = run_audit(REPO_ROOT)

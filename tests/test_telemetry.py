@@ -268,6 +268,34 @@ def test_report_cli_subcommand(tmp_path, capsys):
     assert "== Goodput ==" in capsys.readouterr().out
 
 
+def test_report_reads_no_bench_record(tmp_path, monkeypatch):
+    """The repo's one measurement is the driver's benchmark: a record a
+    deleted bench left in the run directory, or in the directory `report`
+    is called from, is no section of the text form and no key of the JSON
+    form."""
+    from llm_training_tpu.telemetry.report import render_report_data
+
+    run_dir = _write_run_dir(tmp_path)
+    stray = json.dumps({"metric": "llama_clm_train_mfu", "value": 0.61, "stages": {}})
+    (run_dir / "bench_x.json").write_text(stray)
+    (tmp_path / "BENCH_r07.json").write_text(stray)
+    monkeypatch.chdir(tmp_path)
+    text = render_report(run_dir)
+    assert "== Perf ==" not in text and "bench record" not in text
+    assert "MFU (analytic 6N+attention): 0.5500" in text  # the run's own gauge stays
+    assert "perf" not in render_report_data(run_dir)
+
+
+def test_report_cli_refuses_a_bench_dir(tmp_path, capsys):
+    from llm_training_tpu.cli.main import main
+
+    run_dir = _write_run_dir(tmp_path)
+    with pytest.raises(SystemExit) as refused:
+        main(["report", str(run_dir), "--bench-dir", str(tmp_path)])
+    assert refused.value.code == 2
+    assert "unrecognized arguments: --bench-dir" in capsys.readouterr().err
+
+
 # ------------------------------------------------------------ multihost guard
 
 

@@ -317,7 +317,7 @@ def test_report_trace_section_degrades_on_garbage(tmp_path):
     assert "no parseable events" in text
 
 
-def test_report_json_schema(tmp_path, monkeypatch):
+def test_report_json_schema(tmp_path):
     """`report --format json` (CI trend tracking): pin the top-level
     schema — every section key present, absent sections null, numbers
     where CI expects them."""
@@ -326,15 +326,12 @@ def test_report_json_schema(tmp_path, monkeypatch):
         render_report_data,
     )
 
-    # the perf section's cwd fallback would otherwise find whatever bench
-    # summary sits in the directory the tests run from
-    monkeypatch.chdir(tmp_path)
     run_dir = _write_run_dir(tmp_path)
     doc = render_report_data(run_dir)
-    assert doc["schema_version"] == REPORT_SCHEMA_VERSION == 1
+    assert doc["schema_version"] == REPORT_SCHEMA_VERSION == 2
     for key in (
         "run_dir", "world", "training", "goodput", "device_memory",
-        "health", "perf", "audit", "inference", "serving", "slo",
+        "health", "audit", "inference", "serving", "slo",
         "elastic", "trace", "recovery", "flash", "telemetry", "fleet",
     ):
         assert key in doc, key
@@ -349,18 +346,17 @@ def test_report_json_schema(tmp_path, monkeypatch):
     assert doc["goodput"]["goodput/goodput_pct"] == 80.0
     assert doc["serving"] == {"serve/requests_completed": 1.0}
     assert doc["trace"]["events"] == 9
-    assert doc["health"] is None and doc["perf"] is None
+    assert doc["health"] is None
     # the raw record rides along so no numeric key is lost to shaping
     assert doc["telemetry"]["trace/events_recorded"] == 9.0
     json.dumps(doc)  # the whole document must be JSON-serializable
 
 
-def test_report_json_carries_supervisor_segments(tmp_path, monkeypatch):
+def test_report_json_carries_supervisor_segments(tmp_path):
     """`--format json` must not drop the per-segment elastic data text
     mode renders from supervisor.jsonl (review finding)."""
     from llm_training_tpu.telemetry.report import render_report_data
 
-    monkeypatch.chdir(tmp_path)
     run_dir = _write_run_dir(tmp_path, with_trace=False)
     with open(run_dir / "supervisor.jsonl", "w") as f:
         f.write(json.dumps({

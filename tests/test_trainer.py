@@ -207,8 +207,8 @@ def test_blocked_offload_state_structure(devices):
     and counters stay in compute memory. On TPU/GPU that is
     pinned_host/device; a CPU backend addresses only unpinned_host, so
     both kinds collapse and offload degrades to a same-memory placement —
-    the metadata path is identical either way (real-chip execution:
-    `BENCH_OFFLOAD=1 python bench.py`)."""
+    the metadata path is identical either way (execution on a chip has no
+    cell yet: PERF.md section 7)."""
     import flax.linen as nn
     from jax.sharding import PartitionSpec
 
@@ -267,8 +267,8 @@ def test_offload_shardings_map_arrays_to_host(devices):
     Kinds resolve per backend (offload_memory_kinds): pinned_host/device
     on TPU/GPU; a CPU device addresses only unpinned_host, so the kinds
     collapse and the placement is a same-memory no-op — the resolution
-    path is what this pins (the real chip covers execution:
-    `BENCH_OFFLOAD=1 python bench.py`, verify recipes)."""
+    path is what this pins (execution on a chip has no cell yet: PERF.md
+    section 7)."""
     trainer, objective, dm = _make(max_steps=1)
     trainer.config = trainer.config.model_copy(
         update={"offload_optimizer_state": True}
