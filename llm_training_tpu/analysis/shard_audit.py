@@ -269,6 +269,13 @@ FAMILY_REGISTRY: tuple[FamilySpec, ...] = (
              linear_key_head_dim=8, linear_value_head_dim=16,
              max_position_embeddings=128),
     ),
+    FamilySpec(
+        "phi4flash", "llm_training_tpu.models.phi4flash", "Phi4Flash",
+        "llm_training_tpu/models/phi4flash/model.py",
+        dict(vocab_size=128, hidden_size=128, intermediate_size=112,
+             num_hidden_layers=8, num_attention_heads=8, num_key_value_heads=4,
+             sliding_window=16, max_position_embeddings=128),
+    ),
 )
 
 

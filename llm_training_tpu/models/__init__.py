@@ -24,6 +24,7 @@ from llm_training_tpu.models.longcat_flash import LongcatFlash, LongcatFlashConf
 from llm_training_tpu.models.minimax import MiniMax, MiniMaxConfig
 from llm_training_tpu.models.olmo_hybrid import OlmoHybrid, OlmoHybridConfig
 from llm_training_tpu.models.phi3 import Phi3, Phi3Config
+from llm_training_tpu.models.phi4flash import Phi4Flash, Phi4FlashConfig
 from llm_training_tpu.models.qwen3_next import Qwen3Next, Qwen3NextConfig
 from llm_training_tpu.models.solar_open2 import SolarOpen2, SolarOpen2Config
 
@@ -59,6 +60,8 @@ __all__ = [
     "OlmoHybridConfig",
     "Phi3",
     "Phi3Config",
+    "Phi4Flash",
+    "Phi4FlashConfig",
     "Qwen3Next",
     "Qwen3NextConfig",
     "SolarOpen2",

@@ -128,12 +128,18 @@ class KVCacheSpec:
     (`infer/cache.py`). `layers` counts the layers of THIS kind only. `window`
     is how many of a row's newest tokens these layers ever read: None, all of
     them; a number, and the layers are a group of their own whose pages in
-    front of the window go back to their pool (`serve/scheduler.py`)."""
+    front of the window go back to their pool (`serve/scheduler.py`).
+    `readers` counts the layers that READ these pages where that is more
+    than the `layers` that write them: a layer that attends over another
+    layer's keys and values and appends nothing (`LayerCache.attend` without
+    k and v) has no part of the cache, and the serving engine counts what it
+    reads (`serve/shared_kv_reads`). None: every layer reads its own."""
 
     layers: int
     kv_heads: int
     head_dim: int
     window: int | None = None
+    readers: int | None = None
 
 
 @dataclasses.dataclass(frozen=True)

@@ -142,7 +142,11 @@ class LayerCache:
         scan, a Python int in a Python loop. In a stack with two groups
         (`window_k` is there) a layer that attends with a `window` is of the
         window group and `layer` counts that group's layers; any other layer
-        is of the group that keeps everything.
+        is of the group that keeps everything. With `k` and `v` None the layer
+        appends NOTHING and reads part `layer` as it stands: the keys and
+        values another layer of the same call appended there (a stack whose
+        later layers attend over one earlier layer's cache; the declaration
+        counts them, `KVCacheSpec.readers`).
 
         Dense: the chunk goes in at the shared `index`. The causal term of
         the mask (q_offset = index) hides slots written after this chunk and
@@ -173,7 +177,7 @@ class LayerCache:
                 ring=windowed,
             )
             return out, held(ck, cv)
-        ck, cv = (
+        ck, cv = (mine_k, mine_v) if k is None else (
             jax.lax.dynamic_update_slice(
                 cache, new[None].astype(cache.dtype), (layer, 0, self.index, 0, 0)
             )
@@ -181,7 +185,8 @@ class LayerCache:
         )
         mine = lambda cache: jax.lax.dynamic_index_in_dim(cache, layer, keepdims=False)
         out = dot_product_attention(
-            q, mine(ck).astype(k.dtype), mine(cv).astype(v.dtype),
+            q, mine(ck).astype((q if k is None else k).dtype),
+            mine(cv).astype((q if v is None else v).dtype),
             segment_ids=self.kv_segment_ids,
             q_segment_ids=segment_ids,
             causal=True,

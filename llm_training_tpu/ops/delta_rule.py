@@ -55,8 +55,9 @@ def l2norm(x: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
 
 
-def short_conv(mixed, conv_w, tail, segment_ids, valid, parts):
-    """The causal depthwise convolution in front of a delta rule, then SiLU.
+def short_conv(mixed, conv_w, tail, segment_ids, valid, parts, bias=None):
+    """The causal depthwise convolution in front of a delta rule (or of a
+    selective scan, whose convolution has a `bias [C]`), then SiLU.
     mixed `[B, S, C]` (the projections side by side), conv_w `[taps + 1, C]`
     float32, tail `[B, taps, C]` (the inputs before this call's first
     position: a decode slot's, or None for zeros), segment_ids `[B, S]` or
@@ -82,6 +83,8 @@ def short_conv(mixed, conv_w, tail, segment_ids, valid, parts):
         if segment_ids is not None:
             tap = jnp.where((seg_p[:, i:i + seq] == segment_ids)[..., None], tap, 0.0)
         conv = conv + tap
+    if bias is not None:
+        conv = conv + bias
     out = jnp.split(jax.nn.silu(conv), parts, axis=-1)
     end = jnp.max(jnp.where(valid, jnp.arange(1, seq + 1), 0), axis=1)
     new_tail = jax.vmap(

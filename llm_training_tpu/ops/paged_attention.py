@@ -290,8 +290,8 @@ def _over_heads(fn, args, head_axes, like):
 
 def paged_cached_attention(
     q: jnp.ndarray,
-    k: jnp.ndarray,
-    v: jnp.ndarray,
+    k: jnp.ndarray | None,
+    v: jnp.ndarray | None,
     layer_kv: tuple[jnp.ndarray, jnp.ndarray],
     lengths: jnp.ndarray,
     block_tables: jnp.ndarray,
@@ -308,7 +308,9 @@ def paged_cached_attention(
     row against its own cache. q/k/v `[B, S, H*, D]` (S == 1 on the decode
     hot path, S == chunk width during chunked prefill); `lengths [B]` counts
     tokens already in each row's cache BEFORE this chunk. Returns `(out [B,
-    S, Hq, D], new pool pair)`.
+    S, Hq, D], new pool pair)`. With `k` and `v` None nothing is appended:
+    the chunk's keys and values are in the pages already (another layer of
+    the same call put them there) and q attends against them as they are.
 
     `layer_kv` is one layer's pool pair `[N, Hkv, page, D]`, or, with
     `layer`, the pools of the whole stack `[L, N, Hkv, page, D]` as the
@@ -334,7 +336,7 @@ def paged_cached_attention(
         block_tables = block_tables + trash
         pool_k, pool_v = (pool.reshape(-1, *stack_shape[2:]) for pool in layer_kv)
     lengths = lengths.astype(jnp.int32)
-    ck, cv = paged_append(
+    ck, cv = (pool_k, pool_v) if k is None else paged_append(
         pool_k, pool_v, k, v, lengths, block_tables, segment_ids, impl, trash, ring
     )
 

@@ -269,7 +269,12 @@ class ServingEngine:
         # the layers that keep a window, where the stack has such a group:
         # the window, the pages of it a request may hold, the pool's blocks
         # (as many as every row at its budget needs, at most `num_blocks`)
-        window_group = kv_groups(model_config)[1]
+        first_group, window_group = kv_groups(model_config)
+        # layers that read the first group's pages and append nothing
+        # (`KVCacheSpec.readers`): what they read is counted a step
+        self._shared_readers = max(
+            (getattr(first_group, "readers", None) or 0) - first_group.layers, 0
+        )
         self.sliding_window = self.window_pages = None
         window_blocks = 0
         if window_group is not None:
@@ -877,6 +882,9 @@ class ServingEngine:
             counts["state_resets"] = 0
         if self.window_allocator is not None:
             counts["window_live_tokens"] = counts["window_pages_released"] = 0
+        if self._shared_readers:
+            # layer-reads of pages by layers that appended nothing to them
+            counts["shared_kv_reads"] = 0
         # every child span below runs on this thread inside engine_step and
         # carries the step index; nesting by time gives the parent. Children
         # go to the ring and the profiler only (`write=False`): trace.jsonl
@@ -962,7 +970,7 @@ class ServingEngine:
             registry.gauge("decode/state_slots_in_use").set(counts["state_slots_in_use"])
             if counts["state_resets"]:
                 registry.counter("serve/state_resets").inc(counts["state_resets"])
-        for name in ("window_live_tokens", "window_pages_released"):
+        for name in ("window_live_tokens", "window_pages_released", "shared_kv_reads"):
             if counts.get(name):
                 registry.counter(f"serve/{name}").inc(counts[name])
         if counts["prefill_chunks"]:
@@ -1123,6 +1131,10 @@ class ServingEngine:
         fresh = start == 0
         if fresh and self._slab is not None:
             self._step_counts["state_resets"] = 1
+        if self._shared_readers:
+            self._step_counts["shared_kv_reads"] += self._shared_readers * math.ceil(
+                (start + len(chunk)) / self.block_size
+            )
         with tracer.measure(
             "serve", "prefill_chunk", write=request.traced, **ids,
             start=start, tokens=len(chunk), final=final,
@@ -1222,6 +1234,10 @@ class ServingEngine:
             # what the paged kernel reads this call: each row's cache and its
             # new token
             self._step_counts["live_tokens"] = int(lengths.sum()) + len(survivors)
+            if self._shared_readers:
+                self._step_counts["shared_kv_reads"] += self._shared_readers * sum(
+                    r.cache_len // self.block_size + 1 for r in survivors
+                )
             step_slab = {} if self._slab is None else {"slab": self._slab}
             if self._window_pool is not None:
                 step_slab["window_pool"] = self._window_pool
