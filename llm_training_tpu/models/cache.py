@@ -52,6 +52,7 @@ from flax.traverse_util import flatten_dict, unflatten_dict
 
 from llm_training_tpu.models.base import PagedDecodeState
 from llm_training_tpu.ops import dot_product_attention
+from llm_training_tpu.ops.delta_rule import SlabRows, slab_rows
 
 _BUFFERS = ("k", "v", "state", "conv", "window_k", "window_v")
 
@@ -232,13 +233,23 @@ class LayerCache:
         )
         return out, self.replace(k=buffer)
 
-    def recurrent_rows(self, layer, read=_slot_rows, in_place=False):
+    def recurrent_rows(self, layer, read=_slot_rows, in_place=False, *, delta_step=False):
         """`(state [B, ...] float32, conv tail [B, ...])` of recurrent layer
         `layer` for this batch's rows. (`read`: the benchmark's planted fault,
         `benchmarks/tests/test_solar_open2.py`, swaps the slot read by its
         name in the family's module.) `in_place`: the caller's new state is
         an elementwise update of the state it is handed here, and goes back
-        through `put_recurrent_rows(..., in_place=True)`."""
+        through `put_recurrent_rows(..., in_place=True)`. `delta_step`: the
+        caller advances the state by ONE token of a delta rule through
+        `ops/delta_rule.py:one_token_step`; where row i is slot i and the
+        kernel takes the slab, the state handed out is the slab itself and
+        the layer's place in it (`SlabRows`: nothing is read out), and what
+        `one_token_step` gives back goes to `put_recurrent_rows` as ever."""
+        if delta_step:
+            as_they_lie = self.slots is None and self.fresh is None and read is _slot_rows
+            where = slab_rows(self.state, layer, as_they_lie)
+            if where is not None:
+                return where, _layer_rows(self.conv, layer, self.slots, self.fresh, read)
         return (
             _layer_rows(self.state, layer, self.slots, self.fresh, read, in_place),
             _layer_rows(self.conv, layer, self.slots, self.fresh, read),
@@ -250,6 +261,13 @@ class LayerCache:
         carried slab, which reads and writes the slab through one view, so
         the state is written where it lay and no array of a layer's states
         is made (`tests/test_chip_compile.py` holds that for the v5e)."""
+        if isinstance(rows[0], SlabRows):
+            # the kernel wrote the states where they lie: the slab it gave
+            # back is the carried one; the tail is whole before it goes in
+            tail = jax.lax.optimization_barrier(rows[1])
+            return self.replace(
+                state=rows[0].slab, conv=_put_rows(self.conv, layer, self.slots, tail)
+            )
         # the new rows are whole before they go in: fused into the update,
         # their computation reads the slab it writes, and the compiler then
         # copies the whole slab first, once a layer

@@ -49,6 +49,7 @@ from llm_training_tpu.ops.delta_rule import (
     gated_delta_chunked,
     gated_delta_step,
     l2norm,
+    one_token_step,
     pack_heads,
     short_conv,
     unpack_heads,
@@ -132,8 +133,8 @@ class GatedDeltaNet(nn.Module):
         if rows is not None and seq == 1:
             with jax.named_scope("gdn_recurrence"):
                 # on the state as it is stored: nothing of its size is reshaped
-                state, out = gated_delta_step(
-                    rows[0], q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0]
+                state, out = one_token_step(
+                    rows[0], gated_delta_step, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0]
                 )
                 out = out[:, None]
         else:
@@ -214,14 +215,16 @@ class OlmoHybridDecoderLayer(nn.Module):
                 # `_slot_rows` by this module's name: where the benchmark's
                 # tests plant their fault (`LayerCache.recurrent_rows`)
                 # one token a slot: the state is updated where it lies
-                rows = cache.recurrent_rows(layer, _slot_rows, in_place=hidden.shape[1] == 1)
+                one_token = hidden.shape[1] == 1
+                rows = cache.recurrent_rows(
+                    layer, _slot_rows, in_place=one_token, delta_step=one_token
+                )
             mixed, rows = GatedDeltaNet(cfg, name="linear_attn")(hidden, segment_ids, rows)
             if rows is not None:
                 # the write belongs to the recurrence's scope: in a decode step
                 # the state's update fuses INTO it, and a fusion lands in a
                 # trace where its root does (`gdn_decode_roofline_pct` would
                 # otherwise time two of the state's three passes and read 157%)
-                one_token = hidden.shape[1] == 1
                 with jax.named_scope("linear_attn/" + ("gdn_recurrence" if one_token else "gdn_chunk")):
                     cache = cache.put_recurrent_rows(layer, rows, in_place=one_token)
         hidden = hidden + norm("post_attention_layernorm")(mixed)

@@ -50,7 +50,7 @@ from llm_training_tpu.models.remat import remat_policy as _remat_policy
 from llm_training_tpu.models.solar_open2.config import SolarOpen2Config
 from llm_training_tpu.models.solar_open2.kda import kda_chunked, kda_step
 from llm_training_tpu.ops import dot_product_attention
-from llm_training_tpu.ops.delta_rule import l2norm as _l2norm, short_conv
+from llm_training_tpu.ops.delta_rule import l2norm as _l2norm, one_token_step, short_conv
 
 
 class KimiDeltaAttention(nn.Module):
@@ -123,8 +123,8 @@ class KimiDeltaAttention(nn.Module):
         )
         if rows is not None and seq == 1:
             with jax.named_scope("kda_recurrence"):
-                state, out = kda_step(
-                    state, q[:, 0], k[:, 0], v[:, 0], log_alpha[:, 0], beta[:, 0]
+                state, out = one_token_step(
+                    state, kda_step, q[:, 0], k[:, 0], v[:, 0], log_alpha[:, 0], beta[:, 0]
                 )
                 out = out[:, None]
         else:
@@ -196,7 +196,9 @@ class SolarOpen2DecoderLayer(nn.Module):
             if cache is not None:
                 # `_slot_rows` by this module's name: where the benchmark's
                 # tests plant their fault (`LayerCache.recurrent_rows`)
-                rows = cache.recurrent_rows(layer, _slot_rows)
+                # one token a slot: where the kernel takes the slab, the state
+                # is advanced where it lies (`ops/delta_rule.py:one_token_step`)
+                rows = cache.recurrent_rows(layer, _slot_rows, delta_step=hidden.shape[1] == 1)
             mixed, rows = KimiDeltaAttention(cfg, name="linear_attn")(
                 norm("input_layernorm")(hidden), segment_ids, rows
             )

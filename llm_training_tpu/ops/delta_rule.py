@@ -39,12 +39,30 @@ tiles (2 for 192: 384 lanes; 1 for 128), `pack_heads` / `unpack_heads` go
 between `[..., H, dk, dv]` and `[..., H / n, dk, n * dv]`, and
 `gated_delta_step` works on the stored form directly: it never reshapes the
 state, only the token's vectors.
+
+ONE token on a decode slab (`one_token_step`). Where a layer's states can be
+advanced where they lie, the cache hands the layer not its rows but the slab
+and its place in it (`SlabRows`, from `slab_rows`), and the step runs in the
+Pallas kernel that reads a block of stored heads once and writes it back to
+the rows it came from (`ops/pallas/delta_step.py`: KDA's rule and this one,
+by the decay's shape). Everywhere else (off a TPU, a mesh of several devices,
+a state that is not float32 or whose block is not whole 8 x 128 tiles, picked
+or fresh slots, training) the XLA step runs on the rows. The gauges
+`decode/delta_step_calls/{kernel,xla}` say which of the two the layers of a
+slab were traced into.
 """
 
 from __future__ import annotations
 
+import flax.struct
 import jax
 import jax.numpy as jnp
+
+from llm_training_tpu.ops.paged_attention import _on_kernels
+from llm_training_tpu.ops.pallas import resolve_interpret
+from llm_training_tpu.ops.pallas.tuning import delta_step_heads
+from llm_training_tpu.parallel.mesh import active_mesh
+from llm_training_tpu.telemetry.registry import get_registry
 
 # float32 end to end: a TPU otherwise multiplies float32 in bfloat16 passes,
 # and the state is summed into over the whole sequence
@@ -152,6 +170,76 @@ def gated_delta_step(state, q, k, v, g, beta):
     out = alpha_wide * read + per_lane(jnp.sum(k * q, axis=-1)) * write
     state = alpha_wide[:, :, None] * state + k_wide * write[:, :, None]
     return state, out.reshape(batch, heads, dv)
+
+
+# the layers of a decode slab whose one-token step the serving programs traced
+# last run into the Pallas kernel, and into the XLA step: counted where the
+# path is picked (`slab_rows`), in the trace the jit makes anyway.
+# `serve/engine.py` zeroes both before it builds its programs
+DELTA_STEP_GAUGES = {path: f"decode/delta_step_calls/{path}" for path in ("kernel", "xla")}
+
+
+def reset_delta_step_calls() -> None:
+    for gauge in DELTA_STEP_GAUGES.values():
+        get_registry().gauge(gauge).set(0)
+
+
+@flax.struct.dataclass
+class SlabRows:
+    """A recurrent layer's states where they lie: the whole slab `[layers,
+    slots, P, dk, n * dv]` and which layer of it, row i the state of slot i."""
+
+    slab: jnp.ndarray
+    layer: jnp.ndarray | int
+
+
+def slab_rows(slab, layer, as_they_lie: bool) -> SlabRows | None:
+    """Which path a layer's one-token step takes: `SlabRows` where the kernel
+    takes this slab's states, else None (the XLA step, on rows read out of
+    the slab): rows that are not the slab's slots `as_they_lie` (picked or
+    fresh ones), off a TPU, a mesh of several devices (a Mosaic kernel cannot
+    be partitioned), a state that is not float32, a stored head that is not
+    whole 8 x 128 tiles. Nothing here hangs on what a layer has of its own:
+    the answer, and the count it leaves, stand for every layer of the slab."""
+    mesh = active_mesh()
+    kernel = (
+        as_they_lie and _on_kernels("auto") and slab.dtype == jnp.float32
+        and not (mesh is not None and mesh.size > 1)
+        and slab.shape[-2] % 8 == 0 and slab.shape[-1] % 128 == 0
+    )
+    get_registry().gauge(DELTA_STEP_GAUGES["kernel" if kernel else "xla"]).set(slab.shape[0])
+    return SlabRows(slab, layer) if kernel else None
+
+
+def one_token_step(state, xla_step, q, k, v, log_decay, beta):
+    """One token a row of a delta rule: -> `(state, out [B, H, dv])`. `state`
+    is what `LayerCache.recurrent_rows(..., delta_step=True)` handed out: the
+    rows `[B, ...]`, on which `xla_step` (`kda_step`, `gated_delta_step`)
+    runs, or a `SlabRows`, whose layer the kernel advances where it lies
+    (the `SlabRows` that comes back holds the new slab). q, k `[B, H, dk]`; v
+    `[B, H, dv]`; beta `[B, H]`; `log_decay` `[B, H, dk]` (KDA: a key channel)
+    or `[B, H]` (one a head); float32."""
+    if not isinstance(state, SlabRows):
+        return xla_step(state, q, k, v, log_decay, beta)
+    from llm_training_tpu.ops.pallas.delta_step import delta_step
+
+    slab = state.slab
+    # the token's vectors are whole before the kernel lays them out. Laid out
+    # straight from the block's projections, the compiler cuts the product
+    # that feeds KDA's decay loose from the float32 chain behind it and
+    # rounds it to bfloat16 on the way, which in front of the XLA step it
+    # does not: the states then part from the XLA step's by 3e-4 a token
+    # where they are bit for bit the same with this line (chip, PR 46:
+    # PERF.md section 6)
+    q, k, v, log_decay, beta = jax.lax.optimization_barrier((q, k, v, log_decay, beta))
+    # the vectors the kernel turns a stored head: k, q (and a key channel's
+    # decay) of each head abreast
+    turned = (3 if log_decay.ndim == 3 else 2) * (k.shape[1] // slab.shape[2])
+    out, slab = delta_step(
+        slab, jnp.asarray(state.layer, jnp.int32), q, k, v, jnp.exp(log_decay), beta,
+        block=delta_step_heads(*slab.shape[2:], turned), interpret=resolve_interpret(),
+    )
+    return state.replace(slab=slab), out
 
 
 def gated_delta_chunked(q, k, v, g, beta, state, starts=None, chunk_size: int = 64,

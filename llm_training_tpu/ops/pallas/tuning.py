@@ -362,3 +362,32 @@ def resolve_paged_block_size(
     )
     record_block_choice("paged", choice)
     return choice
+
+
+# ---------------------------------------------------------------------------
+# the one-token delta-rule kernel (ops/pallas/delta_step.py)
+
+# stored heads a grid step, by the slab's stored shape (P, dk, n * dv). Tried
+# on a v5e (32 rows, ms a layer and the state's GB/s in and out; PR 46's
+# kernel, a block unrolled, and PR 47's, a loop of trips, read alike). KDA
+# `[64, 128, 128]`, 64 KB a stored head: 1 head a step 1.200 ms (224 GB/s), 2
+# 0.786, 4 0.566, 8 0.474, 16 0.450 (597), 32 0.450; 64 (4 MB, 16 MB
+# double-buffered both ways) is refused: out of VMEM. The gated rule `[15,
+# 96, 384]`, 147 KB a stored pair: 1 pair 0.360 ms (394 GB/s), 3 0.264, 5
+# 0.243 (582), 15 0.241. The XLA steps: 0.831 and 0.336.
+DELTA_STEP_HEADS = {(64, 128, 128): 16, (15, 96, 384): 5}
+# any other shape: the state bytes a step moves each way, under which the
+# step's fixed cost shows and over which nothing was gained above
+_DELTA_STEP_BYTES = 1024 * 1024
+
+
+def delta_step_heads(stored_heads: int, key_dim: int, lanes: int, turned: int) -> int:
+    """Stored heads one grid step of `delta_step` takes: the table's, else
+    the most that divide `stored_heads`, keep the step's states within
+    `_DELTA_STEP_BYTES` and the `turned` key-axis vectors a stored head within
+    the 128 lanes they are turned onto (at least one)."""
+    listed = DELTA_STEP_HEADS.get((stored_heads, key_dim, lanes))
+    if listed is not None:
+        return listed
+    fit = max(1, min(_DELTA_STEP_BYTES // (key_dim * lanes * 4), _LANES // turned))
+    return max(d for d in range(1, fit + 1) if stored_heads % d == 0)

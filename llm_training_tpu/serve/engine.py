@@ -100,6 +100,7 @@ from llm_training_tpu.infer.sampling import (
 )
 from llm_training_tpu.models.base import PagedDecodeState
 from llm_training_tpu.models.moe import IN_PLACE_GAUGE, reset_in_place_layers
+from llm_training_tpu.ops.delta_rule import DELTA_STEP_GAUGES, reset_delta_step_calls
 from llm_training_tpu.ops.paged_attention import CHUNK_KERNEL_GAUGE, reset_chunk_kernel_layers
 from llm_training_tpu.resilience.chaos import get_chaos
 from llm_training_tpu.serve.paged_cache import (
@@ -433,6 +434,9 @@ class ServingEngine:
         # and so does a chunk's attention that runs in the kernel
         # (ops/paged_attention.py)
         reset_chunk_kernel_layers()
+        # and a delta-rule layer's one-token step, which of its two paths it
+        # took (ops/delta_rule.py)
+        reset_delta_step_calls()
         sampling = self.config.sampling
         rope_length = self.config.max_model_len
 
@@ -1455,6 +1459,10 @@ class ServingEngine:
             ),
             IN_PLACE_GAUGE: get_registry().gauge(IN_PLACE_GAUGE).value or 0.0,
             CHUNK_KERNEL_GAUGE: get_registry().gauge(CHUNK_KERNEL_GAUGE).value or 0.0,
+            **{
+                gauge: get_registry().gauge(gauge).value or 0.0
+                for gauge in DELTA_STEP_GAUGES.values()
+            },
             "decode/cache_blocks_total": float(self.allocator.num_blocks - 1),
             "decode/cache_blocks_in_use": float(self.allocator.blocks_in_use),
             "decode/cache_peak_blocks_in_use": float(self.allocator.peak_in_use),

@@ -288,6 +288,15 @@ def _serving_section(telemetry: dict) -> list[str]:
             f"beside {(num('decode/cache_bytes') or 0) / 2**20:.1f} MiB of pages, "
             f"{int(num('serve/state_resets') or 0)} resets"
         )
+    stepped = {path: num(f"decode/delta_step_calls/{path}") for path in ("kernel", "xla")}
+    if any(stepped.values()):
+        lines.append(
+            "one-token delta rule: "
+            + ", ".join(
+                f"{int(layers)} layers in {'the delta_step kernel' if path == 'kernel' else 'XLA'}"
+                for path, layers in stepped.items() if layers
+            )
+        )
     in_place = num("decode/experts_in_place_layers")
     if in_place:
         lines.append(f"expert weights: read in place in {int(in_place)} layers")

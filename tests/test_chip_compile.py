@@ -387,6 +387,49 @@ def test_mla_prefill_refuses_untileable_shapes_when_compiled():
         )
 
 
+@pytest.mark.parametrize("layers,stored,key_dim,heads,value_dim,per_channel", [
+    pytest.param(3, 64, 128, 64, 128, True, id="kda-solar2-serve-longdoc"),
+    pytest.param(6, 15, 96, 30, 192, False, id="gated-olmoh-serve-longgen-two-abreast"),
+])
+def test_delta_step_compiles_for_v5e(v5e, layers, stored, key_dim, heads, value_dim, per_channel):
+    """The one-token delta rules' kernel at the two serve cells' slabs (32
+    slots): it fits VMEM at the table's block, the slab is its own result
+    (nothing of its size is made), and its module holds a trip's stored heads,
+    a loop over the block, not the block's."""
+    import math
+
+    from llm_training_tpu.ops.pallas import delta_step as kernel
+    from llm_training_tpu.ops.pallas.delta_step import delta_step
+    from llm_training_tpu.ops.pallas.tuning import delta_step_heads
+
+    rows, lanes = 32, heads // stored * value_dim
+    one = SingleDeviceSharding(v5e.devices[0])
+    shape = lambda *dims, dtype=jnp.float32: jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+    args = (
+        shape(layers, rows, stored, key_dim, lanes), shape(dtype=jnp.int32),
+        shape(rows, heads, key_dim), shape(rows, heads, key_dim), shape(rows, heads, value_dim),
+        shape(rows, heads, key_dim) if per_channel else shape(rows, heads), shape(rows, heads),
+    )
+    block = delta_step_heads(stored, key_dim, lanes, (3 if per_channel else 2) * (heads // stored))
+    lowered = jax.jit(
+        lambda *a: delta_step(*a, block=block, interpret=False), donate_argnums=0
+    ).lower(*args)
+    compiled = lowered.compile()
+    assert parse_hlo_kernels(compiled.as_text()).get("delta_step") == 1
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == layers * rows * stored * key_dim * lanes * 4
+    assert memory.temp_size_in_bytes < 1e6
+    (module,) = _mosaic_modules(lowered)
+    text = module.operation.get_asm(enable_debug_info=False)
+    # the block's stored heads are a loop of trips of `_HELD` heads (those of
+    # them that divide the block): a stored head's state is written by one
+    # store a head of a TRIP, not one a head of the block
+    held = math.gcd(kernel._HELD, block)
+    assert text.count("scf.for") == (1 if held < block else 0)
+    state = f"memref<1x1x{block}x{key_dim}x{lanes}xf32"
+    assert sum("vector_store" in line and state in line for line in text.splitlines()) == held
+
+
 def test_interpret_is_impossible_on_a_tpu_backend(monkeypatch):
     assert resolve_interpret(None) is True  # the CPU test path
     assert resolve_interpret(False) is False  # compiling for a described device
@@ -410,12 +453,11 @@ def test_interpret_is_impossible_on_a_tpu_backend(monkeypatch):
 # CHANGES.md (PR 28).
 
 # program -> {shape kind: arrays produced}; "state" is a KDA layer's (or all
-# three layers') float32 states. A decode step writes each layer's new state
-# (134 MB) and then XLA's in-place update copies it into the carried slab:
-# two instructions a layer where the parent had one and a restack of all
-# three (4 -> 6 by count, the same 805 MB written; a Pallas recurrence that
-# writes in place is ROADMAP S13). A chunk scatters one slot a layer in place
-# (7 -> 3).
+# three layers') float32 states. A decode step advances them where they lie,
+# in the `delta_step` kernel, whose result IS its operand (`_produced` leaves
+# an aliased custom call out): none since PR 47 (6 until then: each layer's
+# new state, 134 MB, written and then copied into the carried slab). A chunk
+# scatters one slot a layer in place (7 -> 3).
 _PRODUCED = {
     "phi3m-serve-rollout": {"decode": {"pool": 0, "stack": 0}, "prefill": {"pool": 0, "stack": 0}},
     # "experts": one layer's expert matrices `[E, in, out]`. Until PR 31 the
@@ -426,14 +468,15 @@ _PRODUCED = {
         "prefill": {"pool": 0, "stack": 0, "experts": 0},
     },
     "solar2-serve-longdoc": {
-        "decode": {"pool": 0, "stack": 0, "state": 6},
+        "decode": {"pool": 0, "stack": 0, "state": 0},
         "prefill": {"pool": 0, "stack": 0, "state": 3},
     },
     # six delta-rule layers in two periods: a call site a layer of the
-    # period, each an update of the WHOLE carried slab in place (`_IN_PLACE`
-    # below holds that they are, and that no layer's states are made)
+    # period. A chunk's is an update of the WHOLE carried slab in place
+    # (`_IN_PLACE` below holds that they are, and that no layer's states are
+    # made); a decode step's is the kernel's aliased result (3 until PR 47)
     "olmoh-serve-longgen": {
-        "decode": {"pool": 0, "stack": 0, "state": 3},
+        "decode": {"pool": 0, "stack": 0, "state": 0},
         "prefill": {"pool": 0, "stack": 0, "state": 3},
     },
 }
@@ -441,21 +484,32 @@ _PRODUCED = {
 # layer's three products in the grouped matmul that skips what has no rows,
 # a one-period scan's (Solar's, since PR 43) like a scan of nine layers
 _GMM_CALLS = {"olmoe-serve-rollout": (9, 3 * 9), "solar2-serve-longdoc": (1, 3 * 4)}
-# cells whose decode step updates a slot's state where it lies
-# (`LayerCache.put_recurrent_rows(in_place=True)`): every array of the slab's
-# shape a program produces is a fusion rooted in a dynamic-update-slice of its
-# own operand, and none has the shape of one layer's states
-_IN_PLACE = {"olmoh-serve-longgen"}
+# cells (and their programs) that update a slot's state where it lies (the
+# `delta_step` kernel, or `LayerCache.put_recurrent_rows(in_place=True)`):
+# every array of the slab's shape a program produces is the kernel's aliased
+# result or a fusion rooted in a dynamic-update-slice of its own operand, and
+# none has the shape of one layer's states
+_IN_PLACE = {
+    "olmoh-serve-longgen": ("decode", "prefill"),
+    "solar2-serve-longdoc": ("decode",),  # a chunk scatters one picked slot
+}
+# (layers a loop of the program runs, `delta_step` calls a decode step): every
+# delta-rule layer's one-token step in the kernel, under its recurrence's scope
+_DELTA_STEP_CALLS = {
+    "solar2-serve-longdoc": (1, 3, "kda_recurrence"),
+    "olmoh-serve-longgen": (2, 6, "gdn_recurrence"),
+}
 # the programs' temporaries, GB, which hold that Solar's slab updates ARE in
 # place (one more copy of a layer's states is 0.13 GB, of a pool as much).
 # OLMoE's chunk holds its expert activations (0.013 GB; one layer's expert
-# weights cut out of the stack, 0.27 GB, until PR 31), Solar's step and chunk
-# one layer's new state (0.134 GB). No chunk holds attention scores since
+# weights cut out of the stack, 0.27 GB, until PR 31), Solar's chunk one
+# layer's new state (0.134 GB; its step too until PR 47, 0.074 GB since: the
+# kernel writes where the state lies). No chunk holds attention scores since
 # PR 35 (`paged_prefill`; before it Phi-3's held 0.126 GB, Solar's 0.40)
 _TEMP_GB = {
     "phi3m-serve-rollout": {"decode": 0.01, "prefill": 0.02},
     "olmoe-serve-rollout": {"decode": 0.01, "prefill": 0.03},
-    "solar2-serve-longdoc": {"decode": 0.2, "prefill": 0.2},
+    "solar2-serve-longdoc": {"decode": 0.1, "prefill": 0.2},
     # one layer's states are 0.071 GB: the step holds none. The chunk's
     # temporaries are its float32 logits' and the chunked rule's
     "olmoh-serve-longgen": {"decode": 0.03, "prefill": 1.0},
@@ -505,8 +559,9 @@ def _produced(text, pattern):
 
 def _updates_in_place(text, pattern):
     """Whether every array matching `pattern` that the program produces is
-    the result of a fusion whose root is a dynamic-update-slice: an update of
-    its operand's own memory."""
+    the result of a fusion whose root is a dynamic-update-slice, or of a
+    custom call that aliases it to an operand: an update of its operand's own
+    memory."""
     import re
 
     roots = dict(re.findall(r"^%?([\w.\-]+) \([^\n]*\{\n(?:[^\n]*\n)*?\s*ROOT [^\n]*? ([\w\-]+)\(", text, re.M))
@@ -518,6 +573,8 @@ def _updates_in_place(text, pattern):
                 continue
             if not re.search(pattern, rest[: kind.start(1)]):
                 continue
+            if kind.group(1) == "custom-call" and "output_to_operand_aliasing=" in rest:
+                continue  # a kernel's aliased result: the same memory
             called = re.search(r"calls=%?([\w.\-]+)", rest)
             if kind.group(1) != "fusion" or not called or roots.get(called.group(1)) != "dynamic-update-slice":
                 return False
@@ -536,6 +593,32 @@ def _kernel_calls(text, kernel, repeats, under=""):
     }
     bodies = set(re.findall(r"body=%?([\w.\-]+)", text))
     return sum(n * (repeats if name in bodies else 1) for name, n in sites.items())
+
+
+def _mosaic_modules(lowered):
+    """The Mosaic kernels a lowered program's text holds, each parsed: one a
+    `tpu_custom_call` of the text, so a kernel called through one jitted
+    function is there ONCE however many its call sites."""
+    import base64
+    import re
+
+    from jax._src.lib.mlir import ir
+
+    bodies = re.findall(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', lowered.as_text())
+    with ir.Context() as ctx:
+        ctx.allow_unregistered_dialects = True
+        return [ir.Module.parse(base64.b64decode(body)) for body in bodies]
+
+
+def _lowered_bodies(lowered, kernel):
+    """How many Mosaic modules named `kernel` (a kernel's `name=`) a lowered
+    program's text holds."""
+    from jax._src.lib.mlir import ir
+
+    return sum(
+        ir.StringAttr(module.operation.attributes["sym_name"]).value == kernel
+        for module in _mosaic_modules(lowered)
+    )
 
 
 def _serve_program(v5e, cell, program):
@@ -629,7 +712,16 @@ def _check_serve_program(v5e, cell, program):
     counts.setdefault("stack", 0)
     print(f"{cell} {program}: produced {counts}, temp {memory.temp_size_in_bytes / 1e9:.3f} GB")
     assert counts == _PRODUCED[cell][program], counts
-    if cell in _IN_PLACE:
+    if cell in _DELTA_STEP_CALLS:
+        # the one-token steps run in the kernel, each under its recurrence's
+        # scope (where `kda_decode_roofline_pct` / `gdn_decode_roofline_pct`
+        # find it), and the layers share ONE lowered body: what a process
+        # start traces and lowers does not grow with the call sites
+        repeats, calls, scope = _DELTA_STEP_CALLS[cell]
+        calls = calls if program == "decode" else 0
+        assert _kernel_calls(text, "delta_step", repeats, under=f"{scope}/jit(delta_step)") == calls
+        assert _lowered_bodies(lowered, "delta_step") == min(calls, 1)
+    if program in _IN_PLACE.get(cell, ()):
         state = slab[0]
         rest = ",".join(str(d) for d in state.shape[2:])
         assert _produced(text, rf"f32\[(?:1,)?{state.shape[1]},{rest}\]") == 0  # no layer's states
@@ -656,6 +748,40 @@ def test_olmo_hybrid_serve_cell_writes_its_state_in_place_for_v5e(v5e, as_on_tpu
 @pytest.mark.parametrize("cell", ["phi3m-serve-rollout", "olmoe-serve-rollout"])
 def test_rollout_cells_update_the_pool_in_place_for_v5e(v5e, as_on_tpu, cell, program):
     _check_serve_program(v5e, cell, program)
+
+
+# ------------------------- a third slab whose programs the delta rules' kernel
+# must not move: Phi-4-mini-flash's state-space layers read and write theirs
+# through the same `LayerCache.recurrent_rows / put_recurrent_rows` (ROADMAP
+# S13's rest: `ssm_step` stays XLA). Its two lowered programs, every Mosaic
+# kernel's body printed without its source locations (they hold the line
+# numbers of `models/cache.py`'s frames), by digest: the parent's of PR 47,
+# taken from `git archive` of it with this same function. A PR that means to
+# change these programs replaces the digests and says so.
+_PHI4FLASH_PROGRAMS = {
+    "decode": "1665a1a06495a5f54afa8e92a52c8ff20a02d99c8ef36d317eec48c850e2d8bf",
+    "prefill": "4736d805fe459a23caf410afb565e0b6f54cbc7da5c38f9e71f9218a4dac383f",
+}
+
+
+def _program_digest(lowered):
+    import hashlib
+    import re
+
+    bodies = iter(_mosaic_modules(lowered))
+    text = re.sub(
+        r'\\22body\\22: \\22[A-Za-z0-9+/=]+\\22',
+        lambda _: next(bodies).operation.get_asm(enable_debug_info=False),
+        lowered.as_text(),
+    )
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_phi4flash_serve_programs_are_the_text_they_were(v5e, as_on_tpu, program):
+    lowered, _, slab = _serve_program(v5e, "phi4flash-serve-reasoning", program)
+    assert slab is not None and _lowered_bodies(lowered, "delta_step") == 0
+    assert _program_digest(lowered) == _PHI4FLASH_PROGRAMS[program]
 
 
 # ------------------------------------------- the cell with a latent (MLA) pool

@@ -14,15 +14,16 @@ a row's LENGTH is checked against `cache_len` as `_run_decode` found it (what
 the benchmark's harness counts there), and the token a row feeds is checked
 where it can be, against the device's carry once every call has been read."""
 
+import functools
 import random
 import time
 
 import numpy as np
 import pytest
-from test_serve_spans import _afmoe_engine, _engine
+from test_serve_spans import SERVE as SERVE_SPANS, TINY_OLMOH, TINY_SOLAR, _afmoe_engine, _engine
 
 from llm_training_tpu.serve.engine import _split
-from llm_training_tpu.telemetry.registry import TelemetryRegistry, set_registry
+from llm_training_tpu.telemetry.registry import TelemetryRegistry, get_registry, set_registry
 
 SERVE = dict(max_batch=3, max_model_len=48, block_size=8, prefill_chunk=4, num_blocks=9)
 BUILD = {"llama": _engine, "afmoe": _afmoe_engine}
@@ -273,3 +274,112 @@ def test_a_prefilling_slots_pages_shown_to_decode_change_served_tokens(config):
     assert len(sound) == len(faulty) == 36
     assert [t for t in sound if t[0] == "first"] == [t for t in faulty if t[0] == "first"]
     assert [t for t in sound if t[0] == "long"] != [t for t in faulty if t[0] == "long"]
+
+
+# ------------------------------- what a process start traces: the delta rules
+#
+# A delta-rule stack's decode step takes the `delta_step` kernel on a TPU
+# (`ops/delta_rule.py:slab_rows`) and the XLA step elsewhere. Either way each
+# serving program is traced ONCE over an engine's construction and first
+# steps: the path's counter is a side effect of that trace, and nothing beside
+# it lowers or shape-evaluates a step (PR 46 lost `setup_s` to host work the
+# compile cache cannot skip: PERF.md section 6).
+
+PROGRAMS = ("prefill_chunk", "decode_step")
+# state heads that are whole 8 x 128 tiles, as the kernel wants them
+DELTA_STACKS = {
+    "solar_open2": (
+        "SolarOpen2",
+        dict(TINY_SOLAR, linear_head_dim=128, moe_impl="dense"),
+    ),
+    "olmo_hybrid": ("OlmoHybrid", dict(TINY_OLMOH, linear_key_head_dim=8)),
+}
+
+
+@functools.cache
+def _delta_stack_served(family, path):
+    """A tiny engine of `family` whose one-token steps take `path`, through
+    two requests' prefill and decode: (trace events a program, its stats,
+    tokens and logprobs a request)."""
+    import jax
+    from jax._src import monitoring
+
+    from llm_training_tpu import models
+    from llm_training_tpu.ops import delta_rule
+    from llm_training_tpu.serve.engine import ServeConfig, ServingEngine
+
+    name, config = DELTA_STACKS[family]
+    model = getattr(models, name)(getattr(models, f"{name}Config")(**config))
+    variables = jax.jit(model.init)(jax.random.key(0), np.zeros((1, 4), np.int32))
+    traced = dict.fromkeys(PROGRAMS, 0)
+
+    def listen(event, duration, **kwargs):
+        if event == "/jax/core/compile/jaxpr_trace_duration" and kwargs.get("fun_name") in traced:
+            traced[kwargs["fun_name"]] += 1
+
+    previous = set_registry(TelemetryRegistry())
+    on_kernels, delta_rule._on_kernels = delta_rule._on_kernels, lambda impl: path == "kernel"
+    monitoring.register_event_duration_secs_listener(listen)
+    try:
+        engine = ServingEngine(model, variables, ServeConfig(**SERVE_SPANS))
+        events = engine.submit("a", [3, 17, 42, 7, 9, 11], max_new_tokens=6)
+        events += engine.submit("b", [5, 9, 11], max_new_tokens=6)
+        steps = 0
+        decode_steps = get_registry().counter("serve/decode_steps")
+        while decode_steps.value < 2:  # first prefill, first two decode steps
+            events += engine.step()
+            steps += 1
+            assert steps < 20
+        early = dict(traced)
+        while not engine.idle:
+            events += engine.step()
+        stats = engine.stats()
+        engine.close()
+    finally:
+        monitoring.unregister_event_duration_listener(listen)
+        delta_rule._on_kernels = on_kernels
+        set_registry(previous)
+    done = {e["id"]: (e["tokens"], e["logprobs"]) for e in events if e["type"] == "done"}
+    return early, traced, stats, done
+
+
+@pytest.mark.parametrize("path", ["kernel", "xla"])
+@pytest.mark.parametrize("family", sorted(DELTA_STACKS))
+def test_a_delta_rule_engine_traces_each_serving_program_once(family, path):
+    early, traced, stats, done = _delta_stack_served(family, path)
+    assert early == traced == dict.fromkeys(PROGRAMS, 1)
+    # three delta-rule layers of four, all on the one path, and `stats()` says which
+    other = "xla" if path == "kernel" else "kernel"
+    assert stats[f"decode/delta_step_calls/{path}"] == 3 and stats[f"decode/delta_step_calls/{other}"] == 0
+    assert len(done) == 2 and all(len(tokens) == 6 for tokens, _ in done.values())
+
+
+@pytest.mark.parametrize("family", sorted(DELTA_STACKS))
+def test_the_kernel_serves_what_the_xla_step_serves(family):
+    from llm_training_tpu.telemetry.report import _serving_section
+
+    (_, _, stats, kernel), (_, _, _, xla) = (_delta_stack_served(family, path) for path in ("kernel", "xla"))
+    for request in xla:
+        assert kernel[request][0] == xla[request][0]
+        assert np.allclose(kernel[request][1], xla[request][1], atol=1e-5)
+    line = next(line for line in _serving_section(stats) if line.startswith("one-token delta rule:"))
+    assert line == "one-token delta rule: 3 layers in the delta_step kernel"
+
+
+def test_an_engine_zeroes_the_paths_count_and_a_stack_without_a_delta_rule_leaves_it():
+    from llm_training_tpu.ops.delta_rule import DELTA_STEP_GAUGES
+    from llm_training_tpu.telemetry.report import _serving_section
+
+    previous = set_registry(TelemetryRegistry())
+    try:
+        for gauge in DELTA_STEP_GAUGES.values():
+            get_registry().gauge(gauge).set(5)  # another engine's
+        engine = _engine()
+        assert [get_registry().gauge(gauge).value for gauge in DELTA_STEP_GAUGES.values()] == [0, 0]
+        engine.run([{"id": "a", "prompt": [3, 17, 42], "max_new_tokens": 3}])
+        stats = engine.stats()
+        engine.close()
+    finally:
+        set_registry(previous)
+    assert [stats[gauge] for gauge in DELTA_STEP_GAUGES.values()] == [0, 0]
+    assert not [line for line in _serving_section(stats) if line.startswith("one-token delta rule")]
