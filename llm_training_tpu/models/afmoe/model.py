@@ -49,7 +49,7 @@ from llm_training_tpu.models.base import (
 )
 from llm_training_tpu.models.cache import close_cache, open_cache, scan_layers
 from llm_training_tpu.models.deepseek.model import DeepseekMLP, DeepseekMoE
-from llm_training_tpu.models.llama.model import RMSNorm, _dense
+from llm_training_tpu.models.llama.model import RMSNorm, _dense, _plain_rows
 from llm_training_tpu.models.moe import EXPERT_LEAVES, decoding_experts
 from llm_training_tpu.models.remat import remat_policy as _remat_policy
 from llm_training_tpu.ops import apply_rope, dot_product_attention
@@ -72,6 +72,7 @@ class AfmoeAttention(nn.Module):
         q = _dense(cfg, heads * dim, ("embed", "heads"), "q_proj", False)(hidden)
         k = _dense(cfg, kv_heads * dim, ("embed", "kv_heads"), "k_proj", False)(hidden)
         v = _dense(cfg, kv_heads * dim, ("embed", "kv_heads"), "v_proj", False)(hidden)
+        q, k, v = _plain_rows(cache, (q, k, v))
         q = RMSNorm(cfg.rms_norm_eps, cfg.param_jnp_dtype, name="q_norm")(
             q.reshape(batch, seq, heads, dim)
         )
@@ -94,7 +95,9 @@ class AfmoeAttention(nn.Module):
                 )
         out = out.astype(hidden.dtype).reshape(batch, seq, heads * dim)
         with jax.named_scope("attn_gate"):
-            gate = _dense(cfg, heads * dim, ("embed", "heads"), "gate_proj", False)(hidden)
+            gate = _plain_rows(
+                cache, _dense(cfg, heads * dim, ("embed", "heads"), "gate_proj", False)(hidden)
+            )
             out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(out.dtype)
         return _dense(cfg, cfg.hidden_size, ("heads", "embed"), "o_proj", False)(out), cache
 

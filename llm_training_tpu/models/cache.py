@@ -362,7 +362,14 @@ def scan_layers(body, args: tuple, length: int, hidden, inputs: tuple,
     its params are and closed over like the rest of the cache; None where
     the stack has no such leaf. The body still owns its slices of them; a
     layer that reads the stack leaves them unread, and the compiler drops
-    the cut."""
+    the cut.
+
+    Which reads of a stacked leaf are in place (docs/inference.md, "How a
+    layer meets a stacked weight"): a plain product on the slice is; a reader
+    that wants its own buffer is not, hence `whole=`; a product whose result
+    is reshaped to heads is only behind `models/llama/model.py:_plain_rows`
+    (left free, the compiler wants that weight transposed and copies the
+    slice to get it)."""
     decoding = cache is not None
     extra = ((nn.broadcast, 0) + (nn.broadcast,) * bool(whole)) if decoding else ()
     scanned = nn.scan(

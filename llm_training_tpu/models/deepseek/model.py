@@ -54,7 +54,7 @@ from llm_training_tpu.models.base import (
 )
 from llm_training_tpu.models.cache import close_cache, open_cache, scan_layers
 from llm_training_tpu.models.deepseek.config import DeepseekConfig
-from llm_training_tpu.models.llama.model import RMSNorm, _dense
+from llm_training_tpu.models.llama.model import RMSNorm, _dense, _plain_rows
 from llm_training_tpu.models.moe import (
     EXPERT_LEAVES,
     assignment_counts,
@@ -140,6 +140,7 @@ class MLAttention(nn.Module):
                     _dense(cfg, cfg.q_lora_rank, ("embed", None), "q_a_proj", bias)(hidden)
                 )
                 q = _dense(cfg, heads * (nope + rope), (None, "heads"), "q_b_proj", False)(c_q)
+            q = _plain_rows(cache, q)
             if self.q_scale != 1.0:
                 q = q * jnp.asarray(self.q_scale, q.dtype)
             q = q.reshape(batch, seq, heads, nope + rope)

@@ -135,6 +135,21 @@ def _dense(config: LlamaConfig, features: int, logical_axes: tuple[str, str], na
     )
 
 
+def _plain_rows(cache: LayerCache | None, projected):
+    """Projections `[..., rows, out]` on their way to a reshape by heads, as
+    a layer that runs with a `cache` must hand them on: an array (or a tuple
+    of them) the compiler takes as it is. Left free, it folds the reshape and
+    the layout attention likes into the product, which then wants its weight
+    as `[heads, head_dim, in]`; a scan's slice of the stacked leaf cannot give
+    that, so every layer of every step cut the slice out and transposed it
+    (a looped layer: transposed its parameter) before the product read it:
+    two passes over the weight that compute nothing (docs/inference.md, "How
+    a layer meets a stacked weight"). Behind the barrier the product is
+    `[rows, out]` and reads its `[in, out]` slice where it lies, as `o_proj`
+    and the MLP's do. Training (no cache) lowers to what it did."""
+    return projected if cache is None else jax.lax.optimization_barrier(projected)
+
+
 class LlamaAttention(nn.Module):
     """GQA attention (reference `llama_model.py:434-663`).
 
@@ -178,6 +193,7 @@ class LlamaAttention(nn.Module):
                    "k_proj", cfg.attention_bias)(hidden)
         v = _dense(cfg, cfg.num_key_value_heads * head_dim, ("embed", "kv_heads"),
                    "v_proj", cfg.attention_bias)(hidden)
+        q, k, v = _plain_rows(cache, (q, k, v))
 
         if cfg.qk_norm and cfg.qk_norm_scope == "full":
             # OLMo-2/OLMoE: one RMSNorm over the whole projected width, before

@@ -41,7 +41,7 @@ import jax.numpy as jnp
 
 from llm_training_tpu.models.base import CausalLMOutput, DecodeState, PagedDecodeState
 from llm_training_tpu.models.cache import _slot_rows, close_cache, open_cache, scan_layers
-from llm_training_tpu.models.llama.model import LlamaMLP, RMSNorm, _dense
+from llm_training_tpu.models.llama.model import LlamaMLP, RMSNorm, _dense, _plain_rows
 from llm_training_tpu.models.olmo_hybrid.config import OlmoHybridConfig
 from llm_training_tpu.models.remat import remat_policy as _remat_policy
 from llm_training_tpu.ops import dot_product_attention
@@ -179,6 +179,7 @@ class FullAttention(nn.Module):
         q = _dense(cfg, heads * dim, ("embed", "heads"), "q_proj", False)(hidden)
         k = _dense(cfg, kv_heads * dim, ("embed", "kv_heads"), "k_proj", False)(hidden)
         v = _dense(cfg, kv_heads * dim, ("embed", "kv_heads"), "v_proj", False)(hidden)
+        q, k, v = _plain_rows(cache, (q, k, v))
         q = RMSNorm(cfg.rms_norm_eps, cfg.param_jnp_dtype, name="q_norm")(q)
         k = RMSNorm(cfg.rms_norm_eps, cfg.param_jnp_dtype, name="k_norm")(k)
         q = q.reshape(batch, seq, heads, dim)

@@ -57,7 +57,13 @@ import jax.numpy as jnp
 
 from llm_training_tpu.models.base import CausalLMOutput, DecodeState, PagedDecodeState
 from llm_training_tpu.models.cache import close_cache, open_cache, scan_layers
-from llm_training_tpu.models.llama.model import LayerNorm, LlamaMLP, RMSNorm, _dense
+from llm_training_tpu.models.llama.model import (
+    LayerNorm,
+    LlamaMLP,
+    RMSNorm,
+    _dense,
+    _plain_rows,
+)
 from llm_training_tpu.models.olmo_hybrid.model import _dt_bias_init  # Mamba's own: dt log-uniform in [1e-3, 1e-1]
 from llm_training_tpu.models.phi4flash.config import (
     CROSS, FULL, GMU, MAMBA, MEMORY, WINDOW, Phi4FlashConfig,
@@ -178,15 +184,16 @@ class DiffAttention(nn.Module):
         cfg = self.config
         batch, seq, _ = hidden.shape
         heads, kv_heads, dim = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.resolved_head_dim
-        q = _dense(cfg, heads * dim, ("embed", "heads"), "q_proj", True)(hidden)
+        q = _plain_rows(cache, _dense(cfg, heads * dim, ("embed", "heads"), "q_proj", True)(hidden))
         # a query head in its half of a pair's row, zeros in the other
         half = jnp.eye(2, dtype=q.dtype)[:, :, None]
         q = (q.reshape(batch, seq, heads // 2, 2, 1, dim) * half).reshape(batch, seq, heads, 2 * dim)
         k = v = None  # a cross layer under a cache: the keys and values are in the pages
         if self.kind != CROSS:
             k, v = (
-                _dense(cfg, kv_heads * dim, ("embed", "kv_heads"), name, True)(hidden)
-                .reshape(batch, seq, kv_heads // 2, 2 * dim)
+                _plain_rows(
+                    cache, _dense(cfg, kv_heads * dim, ("embed", "kv_heads"), name, True)(hidden)
+                ).reshape(batch, seq, kv_heads // 2, 2 * dim)
                 for name in ("k_proj", "v_proj")
             )
         elif cache is None:

@@ -44,7 +44,7 @@ from llm_training_tpu.models.base import (
 )
 from llm_training_tpu.models.cache import _slot_rows, close_cache, open_cache, scan_layers
 from llm_training_tpu.models.deepseek.model import DeepseekMoE
-from llm_training_tpu.models.llama.model import RMSNorm, _dense
+from llm_training_tpu.models.llama.model import RMSNorm, _dense, _plain_rows
 from llm_training_tpu.models.moe import EXPERT_LEAVES, decoding_experts
 from llm_training_tpu.models.remat import remat_policy as _remat_policy
 from llm_training_tpu.models.solar_open2.config import SolarOpen2Config
@@ -156,6 +156,7 @@ class GatedAttention(nn.Module):
         q = _dense(cfg, heads * dim, ("embed", "heads"), "q_proj", False)(hidden)
         k = _dense(cfg, kv_heads * dim, ("embed", "kv_heads"), "k_proj", False)(hidden)
         v = _dense(cfg, kv_heads * dim, ("embed", "kv_heads"), "v_proj", False)(hidden)
+        q, k, v = _plain_rows(cache, (q, k, v))
         q = q.reshape(batch, seq, heads, dim)
         k = k.reshape(batch, seq, kv_heads, dim)
         v = v.reshape(batch, seq, kv_heads, dim)

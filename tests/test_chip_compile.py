@@ -517,6 +517,10 @@ _TEMP_GB = {
 _NOT_PRODUCED = (
     "parameter", "bitcast", "get-tuple-element", "tuple", "while", "conditional", "call",
 )
+# the compiler's own asynchronous prefetch of a weight ahead of its reader (into
+# memory space `S(1)`, the layout kept; a `ConcatBitcast` joins its pieces): no
+# copy the program asked for (Olmo-Hybrid's `slice-done bf16[1,3840,5760]`)
+_PREFETCH = ("copy-start", "copy-done", "slice-start", "slice-done")
 
 
 def _run_computations(text):
@@ -538,23 +542,68 @@ def _run_computations(text):
     return {k: v for k, v in computations.items() if k not in fused}
 
 
-def _produced(text, pattern):
+def _produced(text, pattern, prefetch=True):
     """Arrays whose shape matches `pattern` that some instruction of the
-    program produces. Left out: views (`_NOT_PRODUCED`), and a custom call
-    whose outputs alias its operands (the page writer: the same memory)."""
+    program produces. Left out: views (`_NOT_PRODUCED`), a custom call whose
+    outputs alias its operands (the page writer: the same memory) and, with
+    `prefetch=False`, the compiler's asynchronous prefetch (`_PREFETCH`)."""
     import re
 
+    skipped = _NOT_PRODUCED if prefetch else _NOT_PRODUCED + _PREFETCH
     total = 0
     for lines in _run_computations(text).values():
         for line in lines:
             _, found, rest = line.partition(" = ")
             kind = re.match(r"(?:\(.*?\)|\S+) ([\w\-]+)\(", rest)
-            if not found or not kind or kind.group(1) in _NOT_PRODUCED:
+            if not found or not kind or kind.group(1) in skipped:
                 continue
             if kind.group(1) == "custom-call" and "output_to_operand_aliasing=" in rest:
                 continue
+            if not prefetch and 'custom_call_target="ConcatBitcast"' in rest:
+                continue
             total += len(re.findall(pattern, rest[: kind.start(1)]))
     return total
+
+
+def _projection_slices(text, config):
+    """Arrays a compiled serving program produces that have the shape of a
+    head projection's slice of the layer stack, `bf16[1, in, out]`, or of a
+    looped layer's own leaf, `bf16[in, out]`: the leaves whose product is
+    reshaped to heads and goes through `models/llama/model.py:_plain_rows`
+    (`q_proj`, `k_proj`, `v_proj`, Trinity's `gate_proj`; `q_b_proj` of a
+    latent stack), widths from the cell's configuration. Each such array was
+    the leaf cut out of the stack or transposed for a product that wanted it
+    `[heads, head_dim, in]`, once a layer a step (PR 48); 0 says every such
+    product reads its weight where it lies."""
+    heads = config["num_attention_heads"]
+    if config.get("q_lora_rank"):
+        wide = heads * (config["qk_nope_head_dim"] + config["qk_rope_head_dim"])
+        leaves = {(config["q_lora_rank"], wide)}
+    else:
+        dim = config.get("head_dim") or config["hidden_size"] // heads
+        leaves = {(config["hidden_size"], n * dim) for n in (heads, config["num_key_value_heads"])}
+    shapes = "|".join(f"{rows},{out}" for rows, out in sorted(leaves))
+    return _produced(text, rf"bf16\[(?:1,)?(?:{shapes})\]", prefetch=False)
+
+
+def _kv_b_slices(text, config):
+    """What PR 48 LEFT in a latent stack's programs: arrays of `kv_b_proj`'s
+    shape, `[latent, heads * (nope + v)]` or LongCat's `[latent, heads, nope +
+    v]`. It is a weight with a head axis, a batch dimension of the absorbed
+    products, which want it heads-major: no product's result to put a barrier
+    on, and the einsum's dimension order does not move it."""
+    latent, heads = config["kv_lora_rank"], config["num_attention_heads"]
+    width = config["qk_nope_head_dim"] + config["v_head_dim"]
+    shapes = rf"bf16\[(?:1,)?{latent},(?:{heads * width}|{heads},{width})\]"
+    return _produced(text, shapes, prefetch=False)
+
+
+def _cell_config(cell):
+    from pathlib import Path
+
+    from benchmarks import common
+
+    return common.Cell(Path(__file__).resolve().parent.parent, cell).config
 
 
 def _updates_in_place(text, pattern):
@@ -662,10 +711,6 @@ def _serve_program(v5e, cell, program):
 
 
 def _check_serve_program(v5e, cell, program):
-    from pathlib import Path
-
-    from benchmarks import common
-
     lowered, pool, slab = _serve_program(v5e, cell, program)
     compiled = lowered.compile()  # raises what the chip's compiler would: it fits
     text, memory = compiled.as_text(), compiled.memory_analysis()
@@ -676,8 +721,8 @@ def _check_serve_program(v5e, cell, program):
         # the stack, as it is declared and as the append sees it (one run of blocks)
         "stack": rf"bf16\[(?:{layers},{blocks}|{layers * blocks}),{dims}\]" if layers > 1 else None,
     }
+    config = _cell_config(cell)
     if "experts" in _PRODUCED[cell][program]:
-        config = common.Cell(Path(__file__).resolve().parent.parent, cell).config
         experts, wide = config["num_experts"], config["hidden_size"]
         narrow = config["program"]["model_kwargs"]["moe_intermediate_size"]
         patterns["experts"] = rf"bf16\[(?:1,)?{experts},(?:{wide},{narrow}|{narrow},{wide})\]"
@@ -712,6 +757,11 @@ def _check_serve_program(v5e, cell, program):
     counts.setdefault("stack", 0)
     print(f"{cell} {program}: produced {counts}, temp {memory.temp_size_in_bytes / 1e9:.3f} GB")
     assert counts == _PRODUCED[cell][program], counts
+    # no head projection's leaf cut out of the stack or transposed for its
+    # product (until PR 48 Phi-3: q_proj and k_proj, slice and transpose, 4 a
+    # program; OLMoE, Olmo-Hybrid: none, a full-width norm stands before the
+    # head reshape; Solar: its one looped GQA layer's q_proj went `[out, in]`)
+    assert _projection_slices(text, config) == 0
     if cell in _DELTA_STEP_CALLS:
         # the one-token steps run in the kernel, each under its recurrence's
         # scope (where `kda_decode_roofline_pct` / `gdn_decode_roofline_pct`
@@ -755,12 +805,14 @@ def test_rollout_cells_update_the_pool_in_place_for_v5e(v5e, as_on_tpu, cell, pr
 # through the same `LayerCache.recurrent_rows / put_recurrent_rows` (ROADMAP
 # S13's rest: `ssm_step` stays XLA). Its two lowered programs, every Mosaic
 # kernel's body printed without its source locations (they hold the line
-# numbers of `models/cache.py`'s frames), by digest: the parent's of PR 47,
-# taken from `git archive` of it with this same function. A PR that means to
+# numbers of `models/cache.py`'s frames), by digest: PR 48's own, which put
+# the attention layers' q, k and v projections behind `_plain_rows`'s barrier
+# (until then the parent's of PR 47, `1665a1a0...` and `4736d805...`, taken
+# from `git archive` of it with this same function). A PR that means to
 # change these programs replaces the digests and says so.
 _PHI4FLASH_PROGRAMS = {
-    "decode": "1665a1a06495a5f54afa8e92a52c8ff20a02d99c8ef36d317eec48c850e2d8bf",
-    "prefill": "4736d805fe459a23caf410afb565e0b6f54cbc7da5c38f9e71f9218a4dac383f",
+    "decode": "12d3ba0df046aaa4a0ef95beabe804613f7328442df3ac607e2e7bd5e005b58b",
+    "prefill": "4bfd1f6fd2824beff51222a3c78e20d58b0b4bce93179491228696a165cb1842",
 }
 
 
@@ -782,6 +834,11 @@ def test_phi4flash_serve_programs_are_the_text_they_were(v5e, as_on_tpu, program
     lowered, _, slab = _serve_program(v5e, "phi4flash-serve-reasoning", program)
     assert slab is not None and _lowered_bodies(lowered, "delta_step") == 0
     assert _program_digest(lowered) == _PHI4FLASH_PROGRAMS[program]
+    # compiled since PR 48 (11 and 14 s): the two scans' and the looped
+    # layers' q_proj, k_proj and v_proj are read where they lie (5 arrays of
+    # a leaf's shape a program until then)
+    text = lowered.compile().as_text()
+    assert _projection_slices(text, _cell_config("phi4flash-serve-reasoning")) == 0
 
 
 # ------------------------------------------- the cell with a latent (MLA) pool
@@ -823,13 +880,9 @@ def _check_chunk_attends_in_mla_prefill(text, program, heads, blocks, repeats):
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
 def test_longcat_serve_cell_compiles_for_v5e(v5e, as_on_tpu, program):
-    from pathlib import Path
-
-    from benchmarks import common
-
     lowered, pool, slab = _serve_program(v5e, "longcat-serve-longctx", program)
     assert slab is None
-    config = common.Cell(Path(__file__).resolve().parent.parent, "longcat-serve-longctx").config
+    config = _cell_config("longcat-serve-longctx")
     compiled = lowered.compile()  # raises what the chip's compiler would: it fits
     text, memory = compiled.as_text(), compiled.memory_analysis()
     layers, blocks, *page = pool.shape
@@ -846,6 +899,11 @@ def test_longcat_serve_cell_compiles_for_v5e(v5e, as_on_tpu, program):
           f"temp {memory.temp_size_in_bytes / 1e9:.3f} GB, "
           f"arguments {memory.argument_size_in_bytes / 1e9:.3f} GB")
     assert counts == {"pool": 0, "stack": 0, "experts": 0}, counts
+    # `q_b_proj` is read where it lies (4 a program until PR 48: two blocks'
+    # slice and transpose); `kv_b_proj [512, 64, 256]` is still cut out and laid
+    # heads-major for the absorbed products, two blocks a loop body
+    assert _projection_slices(text, config) == 0
+    assert _kv_b_slices(text, config) == {"decode": 4, "prefill": 3}[program]
     assert memory.alias_size_in_bytes >= pool.size * 2  # the pool is written in place
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 14e9
     # a step's temporaries are its rows' activations, and so are a chunk's since
@@ -876,13 +934,9 @@ def test_longcat_serve_cell_compiles_for_v5e(v5e, as_on_tpu, program):
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
 def test_pangu_serve_cell_compiles_for_v5e(v5e, as_on_tpu, program):
-    from pathlib import Path
-
-    from benchmarks import common
-
     lowered, pool, slab = _serve_program(v5e, "pangu-serve-longctx8k", program)
     assert slab is None
-    config = common.Cell(Path(__file__).resolve().parent.parent, "pangu-serve-longctx8k").config
+    config = _cell_config("pangu-serve-longctx8k")
     compiled = lowered.compile()  # raises what the chip's compiler would: it fits
     text, memory = compiled.as_text(), compiled.memory_analysis()
     layers, blocks, *page = pool.shape
@@ -899,6 +953,12 @@ def test_pangu_serve_cell_compiles_for_v5e(v5e, as_on_tpu, program):
           f"temp {memory.temp_size_in_bytes / 1e9:.3f} GB, "
           f"arguments {memory.argument_size_in_bytes / 1e9:.3f} GB")
     assert counts == {"pool": 0, "stack": 0, "experts": 0}, counts
+    # `q_b_proj` is read where it lies, the scanned suffix's and the looped
+    # layer's (3 a program until PR 48); `kv_b_proj [512, 32768]` is still cut
+    # out and transposed for the absorbed products (the loop's slice and copy,
+    # the looped layer's copy), and cut out once for a chunk's kernel
+    assert _projection_slices(text, config) == 0
+    assert _kv_b_slices(text, config) == {"decode": 3, "prefill": 1}[program]
     assert memory.alias_size_in_bytes >= pool.size * 2  # the pool is written in place
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 14e9
     # (a chunk 0.087 GB; 0.175 until PR 42, 0.5 allowed: a trip's [128, 512, 512] float32 scores)
@@ -974,6 +1034,9 @@ def test_trinity_serve_cell_keeps_window_layers_inside_their_budget_for_v5e(v5e,
     # (each its own stack of one, since PR 43) and four a period in three periods
     assert _kernel_calls(text, "moe_experts/jit(gmm)", 3) == 3 * 14 and "ragged-dot" not in text
     assert not {"mla_prefill", "mla_decode", "latent_page_write"} & set(kernels)
+    # q_proj, k_proj, v_proj and the gate's `gate_proj` are read where they lie,
+    # the scanned periods' and the looped front's (16 a program until PR 48)
+    assert _projection_slices(text, _cell_config("trinity-serve-mixedlen")) == 0
 
 
 def test_mla_decode_refuses_a_row_that_is_not_whole_lanes(v5e):
