@@ -276,6 +276,17 @@ FAMILY_REGISTRY: tuple[FamilySpec, ...] = (
              num_hidden_layers=8, num_attention_heads=8, num_key_value_heads=4,
              sliding_window=16, max_position_embeddings=128),
     ),
+    FamilySpec(
+        "gigachat3_5", "llm_training_tpu.models.gigachat35", "GigaChat35",
+        "llm_training_tpu/models/gigachat35/model.py",
+        dict(vocab_size=128, hidden_size=64, intermediate_size=112,
+             moe_intermediate_size=32, num_hidden_layers=5, first_k_dense_replace=1,
+             full_attention_layers=[1], num_attention_heads=4, q_lora_rank=32,
+             kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+             n_routed_experts=8, num_experts_per_tok=2, linear_num_key_heads=2,
+             linear_num_value_heads=4, linear_key_head_dim=8, linear_value_head_dim=16,
+             num_nextn_predict_layers=0, rope_scaling=None, max_position_embeddings=128),
+    ),
 )
 
 

@@ -139,18 +139,21 @@ class CLM:
             valid = (segment_ids > 0) & (segment_ids == next_seg)
             labels = jnp.where(valid, labels, cfg.ignore_index)
 
-        # a model with a multi-token-prediction module: position i also
-        # predicts the token at i + 2, where that lies in its own document
-        with_mtp = bool(getattr(model.config, "num_nextn_predict_layers", 0))
-        if with_mtp:
-            mtp_labels = shift_labels(labels, cfg.ignore_index)
+        # a model with multi-token-prediction modules: through module k (the
+        # first is 0) position i also predicts the token at i + k + 2, where
+        # that lies in its own document
+        with_mtp = getattr(model.config, "num_nextn_predict_layers", 0)
+        mtp_labels = []
+        for k in range(with_mtp):
+            ahead_labels = shift_labels(mtp_labels[-1] if mtp_labels else labels, cfg.ignore_index)
             if segment_ids is not None:
                 ahead = jnp.concatenate(
-                    [segment_ids[:, 2:], jnp.zeros_like(segment_ids[:, :2])], axis=1
+                    [segment_ids[:, k + 2:], jnp.zeros_like(segment_ids[:, :k + 2])], axis=1
                 )
-                mtp_labels = jnp.where(
-                    (segment_ids > 0) & (segment_ids == ahead), mtp_labels, cfg.ignore_index
+                ahead_labels = jnp.where(
+                    (segment_ids > 0) & (segment_ids == ahead), ahead_labels, cfg.ignore_index
                 )
+            mtp_labels.append(ahead_labels)
 
         p = params["params"] if "params" in params else params
 
@@ -209,7 +212,13 @@ class CLM:
             # (its ops land under `loss_ce` beside the main loss's: the fused
             # cross entropy is a custom_vjp, whose ops keep no outer scope; the
             # module itself runs under `mtp`, docs/observability.md)
-            mtp_loss, _ = token_loss(out.mtp_hidden_states, mtp_labels)
+            # one module's states, or a tuple of the chained modules': their mean
+            mtp_hidden = out.mtp_hidden_states
+            if not isinstance(mtp_hidden, (tuple, list)):
+                mtp_hidden = (mtp_hidden,)
+            mtp_loss = sum(
+                token_loss(h, targets)[0] for h, targets in zip(mtp_hidden, mtp_labels, strict=True)
+            ) / len(mtp_hidden)
             metrics["mtp_loss"] = mtp_loss
             loss = loss + cfg.mtp_loss_weight * mtp_loss
             metrics["loss"] = loss

@@ -15,6 +15,7 @@ from llm_training_tpu.models.base import BaseModelConfig, CausalLMOutput, Router
 from llm_training_tpu.models.deepseek import Deepseek, DeepseekConfig
 from llm_training_tpu.models.ernie45_moe import Ernie45Moe, Ernie45MoeConfig
 from llm_training_tpu.models.gemma import Gemma, GemmaConfig
+from llm_training_tpu.models.gigachat35 import GigaChat35, GigaChat35Config
 from llm_training_tpu.models.glm4_moe import Glm4Moe, Glm4MoeConfig
 from llm_training_tpu.models.gpt_oss import GptOss, GptOssConfig
 from llm_training_tpu.models.hf_causal_lm import HFCausalLM, HFCausalLMConfig
@@ -42,6 +43,8 @@ __all__ = [
     "Ernie45MoeConfig",
     "Gemma",
     "GemmaConfig",
+    "GigaChat35",
+    "GigaChat35Config",
     "Glm4Moe",
     "Glm4MoeConfig",
     "GptOss",

@@ -19,7 +19,7 @@ block leaves ONE latent row a token, declared by `cache_specs()`.
 
 from __future__ import annotations
 
-from typing import Any, Literal
+from typing import Any, ClassVar, Literal
 
 from pydantic import model_validator
 
@@ -27,6 +27,9 @@ from llm_training_tpu.models.base import BaseModelConfig, LatentCacheSpec
 
 
 class DeepseekConfig(BaseModelConfig):
+    # the multi-token-prediction modules a stack of this config chains at most
+    mtp_modules_max: ClassVar[int] = 1
+
     version: Literal[2, 3] = 3
 
     vocab_size: int = 129280
@@ -118,10 +121,10 @@ class DeepseekConfig(BaseModelConfig):
                 )
         elif self.experts_held is not None:
             raise ValueError("experts_held needs n_routed_experts")
-        if self.num_nextn_predict_layers not in (0, 1):
+        if not 0 <= self.num_nextn_predict_layers <= self.mtp_modules_max:
             raise ValueError(
-                "num_nextn_predict_layers: one multi-token-prediction module is implemented, "
-                f"not {self.num_nextn_predict_layers}"
+                f"num_nextn_predict_layers: {self.mtp_modules_max} multi-token-prediction "
+                f"module(s) are implemented, not {self.num_nextn_predict_layers}"
             )
         self.rope_config  # trigger validation
         return self

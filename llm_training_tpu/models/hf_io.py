@@ -46,6 +46,7 @@ _FAMILIES: dict[str, str] = {
     "AfmoeConfig": "llm_training_tpu.models.afmoe.hf_conversion",
     "OlmoHybridConfig": "llm_training_tpu.models.olmo_hybrid.hf_conversion",
     "Phi4FlashConfig": "llm_training_tpu.models.phi4flash.hf_conversion",
+    "GigaChat35Config": "llm_training_tpu.models.gigachat35.hf_conversion",
     "MiniMaxConfig": "llm_training_tpu.models.minimax.hf_conversion",
     "BambaConfig": "llm_training_tpu.models.bamba.hf_conversion",
     "Glm4MoeConfig": "llm_training_tpu.models.glm4_moe.hf_conversion",
@@ -375,6 +376,7 @@ _ARCH_TO_FAMILY = {
     "afmoe": "llm_training_tpu.models.Afmoe",  # window and full layers in two page groups, config only
     "olmo_hybrid": "llm_training_tpu.models.OlmoHybrid",  # gated delta rule (96 x 192 state) + NoPE MHA, config only
     "phi4flash": "llm_training_tpu.models.Phi4Flash",  # Mamba-1 + differential window / full / cross attention + gated memory units, config only
+    "gigachat3_5": "llm_training_tpu.models.GigaChat35",  # MLA (gated) on one layer in four + gated delta rule, DeepSeek-V3 experts, config only
     "minimax": "llm_training_tpu.models.MiniMax",  # hybrid lightning attention
     "bamba": "llm_training_tpu.models.Bamba",  # Mamba-2 SSD + attention hybrid
     # sparse MoE variants: stacked-expert MoEMLP block (models/moe.py)

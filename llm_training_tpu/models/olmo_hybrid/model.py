@@ -35,6 +35,8 @@ beside `hidden`, whole (`models/cache.py`). Scopes inside `/linear_attn/`:
 
 from __future__ import annotations
 
+from typing import Any
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
@@ -68,48 +70,76 @@ def _dt_bias_init(key, shape, dtype=jnp.float32):
 
 
 class GatedDeltaNet(nn.Module):
-    """`rows` is this layer's `(state [B, *RecurrentCacheSpec.stored] float32,
-    tail [B, taps, channels])` for the batch's rows, or None (training: zero
-    state, zero tail). Returns `(out, new rows)`, the rows None without any."""
+    """The gated-delta-rule mixer of every family that decodes with one
+    (this one, `gigachat35`). `rows` is this layer's `(state [B,
+    *RecurrentCacheSpec.stored] float32, tail [B, taps, channels])` for the
+    batch's rows, or None (training: zero state, zero tail). Returns `(out,
+    new rows)`, the rows None without any.
 
-    config: OlmoHybridConfig
+    `config` gives the sizes (`linear_num_key_heads`, `linear_num_value_heads`,
+    `linear_key_head_dim`, `linear_value_head_dim`, `linear_conv_kernel_dim`,
+    `delta_chunk_size`); what differs between the families is the caller's:
+    `joint`: q, k and v are ONE projection (`qkv_proj`) under ONE convolution
+    (`conv_kernel`), not three of each; `beta_max`: the write strength is
+    `beta_max * sigmoid(.)` (above 1: negative eigenvalues); `out_norm`
+    (`name -> a norm module` over a value head; None: RMSNorm at
+    `rms_norm_eps`) and `out_gate` (what the gate projection goes through):
+    `o_proj(out_norm(o) * out_gate(g_proj x))`. Fewer key heads than value
+    heads: each key head serves `value heads / key heads` neighbouring value
+    heads; q and k are normalised a key head and only the token's vectors are
+    repeated, never a state."""
+
+    config: Any
+    joint: bool = False
+    beta_max: float = 2.0
+    out_norm: Any = None
+    out_gate: Any = jax.nn.silu
 
     @nn.compact
     def __call__(self, hidden, segment_ids=None, rows=None):
         cfg = self.config
         batch, seq, _ = hidden.shape
-        heads = cfg.linear_num_value_heads
+        heads, key_heads = cfg.linear_num_value_heads, cfg.linear_num_key_heads
         dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
-        widths = (heads * dk, heads * dk, heads * dv)
+        widths = (key_heads * dk, key_heads * dk, heads * dv)
         taps = cfg.linear_conv_kernel_dim - 1
         valid = (
             jnp.ones((batch, seq), bool) if segment_ids is None else segment_ids > 0
         )
-        mixed = jnp.concatenate(
-            [_dense(cfg, width, ("embed", "heads"), name, False)(hidden)
-             for name, width in zip(("q_proj", "k_proj", "v_proj"), widths)],
-            axis=-1,
+        conv_param = lambda name, width: self.param(
+            name,
+            nn.with_logical_partitioning(
+                nn.initializers.normal(cfg.initializer_range), (None, "heads")
+            ),
+            (taps + 1, width),
+            cfg.param_jnp_dtype,
         )
+        if self.joint:
+            mixed = _dense(cfg, sum(widths), ("embed", "heads"), "qkv_proj", False)(hidden)
+        else:
+            mixed = jnp.concatenate(
+                [_dense(cfg, width, ("embed", "heads"), name, False)(hidden)
+                 for name, width in zip(("q_proj", "k_proj", "v_proj"), widths)],
+                axis=-1,
+            )
         with jax.named_scope("gdn_conv"):
-            # three convolutions, one a projection; depthwise, so they run as one
-            conv_w = jnp.concatenate([
-                self.param(
-                    f"{name}_conv_kernel",
-                    nn.with_logical_partitioning(
-                        nn.initializers.normal(cfg.initializer_range), (None, "heads")
-                    ),
-                    (taps + 1, width),
-                    cfg.param_jnp_dtype,
-                )
-                for name, width in zip("qkv", widths)
-            ], axis=-1).astype(jnp.float32)
+            if self.joint:
+                conv_w = conv_param("conv_kernel", sum(widths)).astype(jnp.float32)
+            else:
+                # three convolutions, one a projection; depthwise, so they run as one
+                conv_w = jnp.concatenate([
+                    conv_param(f"{name}_conv_kernel", width) for name, width in zip("qkv", widths)
+                ], axis=-1).astype(jnp.float32)
             (q, k, v), new_tail = short_conv(
                 mixed, conv_w, None if rows is None else rows[1], segment_ids, valid,
                 (widths[0], 2 * widths[0]),
             )
 
-        q = l2norm(q.reshape(batch, seq, heads, dk)) * dk ** -0.5
-        k = l2norm(k.reshape(batch, seq, heads, dk))
+        q = l2norm(q.reshape(batch, seq, key_heads, dk)) * dk ** -0.5
+        k = l2norm(k.reshape(batch, seq, key_heads, dk))
+        if key_heads != heads:
+            # a key head's two vectors a token, once a value head it serves
+            q, k = (jnp.repeat(x, heads // key_heads, axis=2) for x in (q, k))
         v = v.reshape(batch, seq, heads, dv)
 
         with jax.named_scope("gdn_gates"):
@@ -125,8 +155,7 @@ class GatedDeltaNet(nn.Module):
                 hidden
             ).astype(jnp.float32)
             g = -jnp.exp(a_log) * jax.nn.softplus(small("a_proj") + dt_bias)
-            strength = 2.0 if cfg.linear_allow_neg_eigval else 1.0
-            beta = strength * jax.nn.sigmoid(small("b_proj"))
+            beta = self.beta_max * jax.nn.sigmoid(small("b_proj"))
             g = jnp.where(valid[..., None], g, 0.0)
             beta = jnp.where(valid[..., None], beta, 0.0)
 
@@ -153,9 +182,12 @@ class GatedDeltaNet(nn.Module):
                 )
                 state = pack_heads(state, abreast)
         with jax.named_scope("gdn_out"):
-            out = RMSNorm(cfg.rms_norm_eps, cfg.param_jnp_dtype, name="o_norm")(out)
+            out_norm = self.out_norm or (
+                lambda name: RMSNorm(cfg.rms_norm_eps, cfg.param_jnp_dtype, name=name)
+            )
+            out = out_norm("o_norm")(out)
             gate = _dense(cfg, heads * dv, ("embed", "heads"), "g_proj", False)(hidden)
-            out = out.reshape(batch, seq, heads * dv) * jax.nn.silu(gate.astype(jnp.float32))
+            out = out.reshape(batch, seq, heads * dv) * self.out_gate(gate.astype(jnp.float32))
             out = _dense(cfg, cfg.hidden_size, ("heads", "embed"), "o_proj", False)(
                 out.astype(hidden.dtype)
             )
@@ -220,7 +252,9 @@ class OlmoHybridDecoderLayer(nn.Module):
                 rows = cache.recurrent_rows(
                     layer, _slot_rows, in_place=one_token, delta_step=one_token
                 )
-            mixed, rows = GatedDeltaNet(cfg, name="linear_attn")(hidden, segment_ids, rows)
+            mixed, rows = GatedDeltaNet(
+                cfg, beta_max=2.0 if cfg.linear_allow_neg_eigval else 1.0, name="linear_attn"
+            )(hidden, segment_ids, rows)
             if rows is not None:
                 # the write belongs to the recurrence's scope: in a decode step
                 # the state's update fuses INTO it, and a fusion lands in a

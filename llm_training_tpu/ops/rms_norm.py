@@ -32,3 +32,14 @@ def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float = 1e-6) -> jnp.ndar
     variance = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
     x_normed = (x32 * jax.lax.rsqrt(variance + eps)).astype(dtype)
     return weight * x_normed
+
+
+def gated_rms_norm(
+    x: jnp.ndarray, weight: jnp.ndarray, eps: float = 1e-6, gating_weight: float = 2.0
+) -> jnp.ndarray:
+    """y = gating_weight * sigmoid(weight) * (x / rms(x)): a zero-centred
+    gated norm. `weight` is learned from 0, where the scale is `gating_weight
+    / 2` (1 at the published 2), and the scale stays inside (0,
+    `gating_weight`). The statistic is `rms_norm`'s, under its scope."""
+    scale = gating_weight * jax.nn.sigmoid(weight.astype(jnp.float32))
+    return rms_norm(x, scale.astype(x.dtype), eps)

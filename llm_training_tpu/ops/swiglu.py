@@ -10,8 +10,13 @@ import jax
 import jax.numpy as jnp
 
 
-def silu_mul(gate: jnp.ndarray, up: jnp.ndarray) -> jnp.ndarray:
-    """silu(gate) * up — the SwiGLU elementwise core."""
+def silu_mul(gate: jnp.ndarray, up: jnp.ndarray, limit: float | None = None) -> jnp.ndarray:
+    """silu(gate) * up — the SwiGLU elementwise core. With a `limit` (a
+    clamped SwiGLU, `swiglu_limit`): the gate is held under it and the linear
+    branch inside +-limit, both before the product."""
+    if limit is not None:
+        gate = jnp.minimum(gate, limit)
+        up = jnp.clip(up, -limit, limit)
     return jax.nn.silu(gate) * up
 
 

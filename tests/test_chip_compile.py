@@ -973,6 +973,56 @@ def test_pangu_serve_cell_compiles_for_v5e(v5e, as_on_tpu, program):
     assert not {"kv_page_write", "paged_decode", "paged_prefill"} & set(kernels)
 
 
+# ------------------------------- a latent pool BESIDE a slab, under `GigaChat35`
+#
+# `gigachat35-serve-longgen-doctail`: ONE MLA layer's latent pool (64 rows of
+# up to 10,240 tokens: 64 x 640 + 1 pages) and four delta-rule layers' slab
+# (`[4, 64, 64, 128, 128]` float32, one head a stored head) in one engine.
+# Pinned: both programs fit beside 9.46 GB of weights; the decode step calls
+# `mla_decode` once (the scanned period's first slot) and `delta_step` four
+# times (the looped dense layer's and three call sites in the period's body)
+# under `gdn_recurrence`, one lowered body; the pool and the slab are written
+# in place, and no program makes an array of a layer's states, of the pool's
+# shape or of a layer's held experts; a chunk attends in `mla_prefill`, once.
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_gigachat35_serve_cell_holds_a_latent_pool_beside_a_slab_for_v5e(v5e, as_on_tpu, program):
+    lowered, pool, slab = _serve_program(v5e, "gigachat35-serve-longgen-doctail", program)
+    config = _cell_config("gigachat35-serve-longgen-doctail")
+    state, tail = slab
+    assert pool.shape == (1, 64 * 640 + 1, 1, 16, 640)
+    assert state.shape == (4, 64, 64, 128, 128) and tail.shape == (4, 64, 3, 16384)
+    compiled = lowered.compile()  # raises what the chip's compiler would: it fits
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    wide, narrow = config["hidden_size"], config["moe_intermediate_size"]
+    patterns = {
+        "pool": r"bf16\[(?:1,)?40961,1,16,640\]",
+        "state": r"f32\[(?:(?:1,)?64|4,64|256),64,128,128\]",
+        "experts": rf"bf16\[(?:1,)?16,(?:{wide},{narrow}|{narrow},{wide})\]",
+    }
+    counts = {k: _produced(text, p) for k, p in patterns.items()}
+    print(f"gigachat35-serve-longgen-doctail {program}: produced {counts}, "
+          f"temp {memory.temp_size_in_bytes / 1e9:.3f} GB, "
+          f"arguments {memory.argument_size_in_bytes / 1e9:.3f} GB")
+    # a chunk scatters ONE picked slot's new state a delta-rule layer (as Solar's chunk does)
+    assert counts == {"pool": 0, "state": {"decode": 0, "prefill": 4}[program], "experts": 0}, counts
+    assert memory.alias_size_in_bytes >= pool.size * 2 + state.size * 4 + tail.size * 2  # written in place
+    assert 11.3e9 < memory.argument_size_in_bytes < 11.5e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 14e9
+    assert memory.temp_size_in_bytes < {"decode": 0.1, "prefill": 0.2}[program] * 1e9
+
+    assert _kernel_calls(text, "mla_decode", 1) == (1 if program == "decode" else 0)
+    _check_chunk_attends_in_mla_prefill(text, program, heads=64, blocks=1, repeats=1)
+    calls = 4 if program == "decode" else 0
+    assert _kernel_calls(text, "delta_step", 1, under="gdn_recurrence/jit(delta_step)") == calls
+    assert _lowered_bodies(lowered, "delta_step") == min(calls, 1)
+    kernels = parse_hlo_kernels(text)
+    assert kernels.get("latent_page_write", 0) >= 1 and kernels.get("gmm", 0) >= 3
+    assert not {"kv_page_write", "paged_decode", "paged_prefill"} & set(kernels)
+    assert _projection_slices(text, config) == 0
+
+
 # --------------------------------------------- the cell with two page groups
 #
 # `trinity-serve-mixedlen`: 4 layers keep every token of 16 requests of 12,800

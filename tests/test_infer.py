@@ -180,7 +180,7 @@ def test_engine_rejects_unthreaded_families():
 
 # what `models/__init__.py` exports, by the shard audit's tiny configs (its
 # registry holds every concrete family; llama three times)
-_DECODING_FAMILIES = {"llama", "llama_moe", "llama_pp", "phi3", "gemma", "solar_open2", "longcat_flash", "afmoe", "olmo_hybrid", "deepseek", "phi4flash"}
+_DECODING_FAMILIES = {"llama", "llama_moe", "llama_pp", "phi3", "gemma", "solar_open2", "longcat_flash", "afmoe", "olmo_hybrid", "deepseek", "phi4flash", "gigachat3_5"}
 
 
 def _family_names():

@@ -219,7 +219,7 @@ def test_mtp_logits_are_the_reference_logits(tiny_mtp):
     with pytest.raises(ValueError, match="num_nextn_predict_layers"):
         Deepseek(DeepseekConfig(**TINY)).apply(
             {"params": {}}, input_ids=ids, return_mtp=True)
-    with pytest.raises(ValueError, match="one multi-token-prediction module"):
+    with pytest.raises(ValueError, match="1 multi-token-prediction module"):
         DeepseekConfig(**{**TINY, "num_nextn_predict_layers": 2})
 
 

@@ -169,7 +169,7 @@ def test_audit_matrix_all_families_all_meshes_clean():
     HEAD, well inside the acceptance budget."""
     result = run_audit(REPO_ROOT)
     assert result.findings == [], [f.render() for f in result.findings]
-    assert len(result.families_run) == 18
+    assert len(result.families_run) == 19
     assert set(result.meshes_run) == set(MESH_MATRIX)
     assert result.elapsed_s < 60.0
     # every cell produced an estimate and fits the default budget
@@ -380,8 +380,8 @@ def test_baseline_keys_are_mesh_selection_stable():
     assert result.meshes_run == ("fsdp8",)
 
 
-def test_registry_covers_eighteen_families():
+def test_registry_covers_nineteen_families():
     names = [f.name for f in FAMILY_REGISTRY]
-    assert len(names) == len(set(names)) == 18
+    assert len(names) == len(set(names)) == 19
     # the registry must exercise scan stacks, MoE, and pipeline layouts
     assert {"llama", "llama_moe", "llama_pp"} <= set(names)
