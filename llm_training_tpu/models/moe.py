@@ -11,7 +11,10 @@ Qwen2/3-MoE — only through `HFCausalLM`'s torch wrapping,
   stacked layout is what makes both impls below a single large MXU op.
 - 'ragged' impl (TPU training path): sort the T*K (token, expert-slot)
   assignments by expert, run the three projections as `jax.lax.ragged_dot`
-  grouped matmuls, scatter-add weighted results back. Static shapes
+  grouped matmuls, gather the rows back into the assignments' order and sum
+  each token's K of them with the router's weights in float32 (`_combine`: a
+  permutation and a reduction, no scatter-add, forward or backward: the chip
+  walks a scatter's rows one after another). Static shapes
   ([T*K, ...] regardless of routing), no token dropping, no capacity factor
   — the modern JAX MoE formulation, vs the GShard one-hot dispatch einsum
   whose [T, E, C] tensors waste HBM at high expert counts.
@@ -389,6 +392,44 @@ def _sorted_dispatch(topk_idx, topk_weights, num_experts):
     return flat_expert, flat_weight, flat_token, order, gs
 
 
+@jax.custom_vjp
+def _unsort(rows, order):
+    """`rows [T*K, H]`, sorted by expert (`rows[j]` is assignment `order[j]`'s),
+    back in the assignments' own order: a gather of rows by the inverse
+    permutation. The transpose of a gather is a scatter-add, which the chip
+    walks a row at a time, so the backward pass is told that a permutation's
+    is a gather too, `g[order]`."""
+    return rows[jnp.argsort(order)]
+
+
+def _unsort_fwd(rows, order):
+    return _unsort(rows, order), order
+
+
+def _unsort_bwd(order, g):
+    return g[order], None
+
+
+_unsort.defvjp(_unsort_fwd, _unsort_bwd)
+
+
+def _combine(ys, order, topk_weights, mine, dtype):
+    """The experts' rows `ys [T*K, H]` (sorted by expert: `order`, of
+    `_sorted_dispatch`) summed a token with the router's `topk_weights [T,
+    K]` -> `[T, H]` in `dtype`. Every token has exactly K rows, so the sum
+    needs no scatter: the rows are gathered back into the assignments' order
+    and reduced over K, ONE float32 sum a token, rounded once. `mine [T, K]`
+    or None: the assignments whose rows count (a held share's); the others
+    are selected to zero before the product (0 x NaN is NaN)."""
+    n_tokens, top_k = topk_weights.shape
+    with jax.named_scope("moe_scatter"):
+        rows = _unsort(ys, order).reshape(n_tokens, top_k, -1)
+        if mine is not None:
+            rows = jnp.where(mine[..., None], rows, 0)
+        weighted = rows.astype(jnp.float32) * topk_weights.astype(jnp.float32)[..., None]
+        return weighted.sum(axis=1).astype(dtype)
+
+
 def _bucketed_apply(
     x, topk_idx, topk_weights, num_experts, bmm_fn, capacity_factor: float
 ):
@@ -469,9 +510,13 @@ def dropless_moe_apply(
 
     Every path names its phases for a device profile (`jax.named_scope`, op
     metadata only): `moe_sort` (argsort + group sizes), `moe_gather`,
-    `moe_experts` (the grouped or batched matmuls), `moe_scatter` (weighted
-    scatter-add); the router's `moe_route` is the calling module's
-    (docs/observability.md#tracing; benchmarks read them by name).
+    `moe_experts` (the grouped or batched matmuls), `moe_scatter` (the
+    weighted combine: on the ragged path of one device a gather of the
+    sorted rows and a float32 sum over each token's K, `_combine`; on the
+    dense path an einsum; a scatter-add into the capacity buffer on the
+    expert-parallel and bucketed paths); the router's `moe_route` is the
+    calling module's (docs/observability.md#tracing; benchmarks read them by
+    name).
 
     Returns (out [T, H], dropped_rows fp32 scalar): dropped_rows counts
     (token, slot) assignments lost to a capacity buffer (expert-parallel
@@ -481,6 +526,7 @@ def dropless_moe_apply(
     n_tokens, top_k = topk_idx.shape
     no_drops = jnp.float32(0.0)
     impl = _resolved_impl(impl)
+    mine = None  # of a held share: the assignments to the experts held here
     if held is not None:
         if impl not in ("dense", "ragged") or _ep_group_size() > 1:
             raise ValueError(
@@ -537,7 +583,7 @@ def dropless_moe_apply(
             x, topk_idx, topk_weights, num_experts, ragged_fn, weights,
             ep, ep_capacity_factor,
         )
-    flat_expert, flat_weight, flat_token, order, group_sizes = _sorted_dispatch(
+    flat_expert, _, flat_token, order, group_sizes = _sorted_dispatch(
         topk_idx, topk_weights, num_experts
     )
     with jax.named_scope("moe_gather"):
@@ -545,16 +591,11 @@ def dropless_moe_apply(
         xs, expert_order = x[token_order], flat_expert[order]
     if held is not None:
         # the rows past the held groups belong to no group: whatever the
-        # product leaves there, the select below drops
+        # product leaves there, the combine's select drops
         group_sizes = group_sizes[:-1]
     with jax.named_scope("moe_experts"):
         ys = ragged_fn(xs, group_sizes, expert_order, weights)
-    with jax.named_scope("moe_scatter"):
-        ys = ys * flat_weight[order][:, None]
-        if held is not None:
-            ys = jnp.where((expert_order < num_experts - 1)[:, None], ys, 0)
-        out = jnp.zeros((n_tokens, x.shape[-1]), x.dtype).at[token_order].add(ys)
-    return out, no_drops
+    return _combine(ys, order, topk_weights, mine, x.dtype), no_drops
 
 
 class MoEMLP(nn.Module):

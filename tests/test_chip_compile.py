@@ -752,6 +752,7 @@ def _check_serve_program(v5e, cell, program):
     if cell in _GMM_CALLS:
         repeats, calls = _GMM_CALLS[cell]
         assert _kernel_calls(text, "moe_experts/jit(gmm)", repeats) == calls and "ragged-dot" not in text
+        _check_combine_gathers(text)
 
     counts = {k: _produced(text, p) for k, p in patterns.items() if p}
     counts.setdefault("stack", 0)
@@ -852,6 +853,17 @@ def test_phi4flash_serve_programs_are_the_text_they_were(v5e, as_on_tpu, program
 # pools', or of one layer's expert matrices.
 
 
+def _check_combine_gathers(text):
+    """The experts' combine (`models/moe.py:_combine`) gathers the sorted rows
+    and sums them: no `scatter` instruction under `moe_scatter` (until PR 50 a
+    `scatter(bf16[512, hidden], s32[4096], bf16[4096, hidden])` a layer, which
+    the chip walks a row at a time), and the scope still holds ops, the gather
+    among them: `moe_dispatch_device_ms` reads it by that name."""
+    under = [line for line in text.splitlines() if "moe_scatter" in line.partition(" metadata=")[2]]
+    assert any(" gather(" in line for line in under)
+    assert not [line for line in under if " scatter(" in line]
+
+
 def _check_chunk_attends_in_mla_prefill(text, program, heads, blocks, repeats):
     """A chunk program calls `mla_prefill` once a latent block, under
     `mla_attend`, and the gauge says so; it holds no float32 array of a
@@ -917,6 +929,7 @@ def test_longcat_serve_cell_compiles_for_v5e(v5e, as_on_tpu, program):
     kernels = parse_hlo_kernels(text)
     assert kernels.get("latent_page_write", 0) >= 1 and kernels.get("gmm", 0) >= 3
     assert not {"kv_page_write", "paged_decode", "paged_prefill"} & set(kernels)
+    _check_combine_gathers(text)
 
 
 # ----------------------------- the latent pool at 128 heads, under `Deepseek`
@@ -971,6 +984,7 @@ def test_pangu_serve_cell_compiles_for_v5e(v5e, as_on_tpu, program):
     kernels = parse_hlo_kernels(text)
     assert kernels.get("latent_page_write", 0) >= 1 and kernels.get("gmm", 0) >= 3
     assert not {"kv_page_write", "paged_decode", "paged_prefill"} & set(kernels)
+    _check_combine_gathers(text)
 
 
 # ------------------------------- a latent pool BESIDE a slab, under `GigaChat35`
@@ -1021,6 +1035,7 @@ def test_gigachat35_serve_cell_holds_a_latent_pool_beside_a_slab_for_v5e(v5e, as
     assert kernels.get("latent_page_write", 0) >= 1 and kernels.get("gmm", 0) >= 3
     assert not {"kv_page_write", "paged_decode", "paged_prefill"} & set(kernels)
     assert _projection_slices(text, config) == 0
+    _check_combine_gathers(text)
 
 
 # --------------------------------------------- the cell with two page groups
@@ -1084,6 +1099,7 @@ def test_trinity_serve_cell_keeps_window_layers_inside_their_budget_for_v5e(v5e,
     # (each its own stack of one, since PR 43) and four a period in three periods
     assert _kernel_calls(text, "moe_experts/jit(gmm)", 3) == 3 * 14 and "ragged-dot" not in text
     assert not {"mla_prefill", "mla_decode", "latent_page_write"} & set(kernels)
+    _check_combine_gathers(text)
     # q_proj, k_proj, v_proj and the gate's `gate_proj` are read where they lie,
     # the scanned periods' and the looped front's (16 a program until PR 48)
     assert _projection_slices(text, _cell_config("trinity-serve-mixedlen")) == 0

@@ -18,6 +18,7 @@ import pytest
 from llm_training_tpu.infer import GenerateConfig, InferenceEngine
 from llm_training_tpu.models.deepseek import Deepseek, DeepseekConfig, reference
 from llm_training_tpu.serve import ServeConfig, ServingEngine
+from llm_training_tpu.telemetry import get_registry
 from tests.test_pangu_ultra_moe import (
     F32_TOL,
     FAR_LEVEL,
@@ -31,6 +32,7 @@ from tests.test_pangu_ultra_moe import (
 # ------------------------------------------------------------------- serving
 
 REQUESTS = [(19, 20), (5, 30), (11, 9), (30, 6), (3, 14)]  # (prompt, new tokens)
+MOE_KINDS = ("held", "zero", "elsewhere")  # `serve/moe_<kind>_assignments`
 SERVE = dict(max_batch=2, max_model_len=64, block_size=8, prefill_chunk=8, num_blocks=7, eos_token_id=None)
 
 
@@ -116,6 +118,8 @@ def test_chunked_prefill_then_paged_decode_is_the_reference_forward(tiny, varian
             lambda *args, **kwargs: attend(*args, **{**kwargs, "impl": "pallas"}),
         )
     model = Deepseek(DeepseekConfig(**{**TINY, **over}))
+    # the counters are the process's: whatever served on this worker before is in them
+    before = [get_registry().counter(f"serve/moe_{k}_assignments").value for k in MOE_KINDS]
     with jax.default_matmul_precision("highest"):
         engine, requests, done = run_engine(model, variables)
     assert all(done[r["id"]]["stop_reason"] == "max_tokens" for r in requests)
@@ -125,7 +129,7 @@ def test_chunked_prefill_then_paged_decode_is_the_reference_forward(tiny, varian
     stats = engine.stats()
     assert stats["decode/latent_pool_bytes"] == stats["decode/cache_bytes"] == 3 * 8 * 8 * 128 * 4
     # a share counts where its rows' choices went: 4 a token in each of the two MoE layers
-    held, zero, elsewhere = (stats[f"serve/moe_{k}_assignments"] for k in ("held", "zero", "elsewhere"))
+    held, zero, elsewhere = (stats[f"serve/moe_{k}_assignments"] - b for k, b in zip(MOE_KINDS, before))
     assert zero == 0 and held > 0 and elsewhere > 0 and (held + elsewhere) % 8 == 0
     if variant == "grouped_experts_in_place":
         assert stats["decode/experts_in_place_layers"] == 2  # the scanned suffix's two

@@ -19,6 +19,7 @@ import pytest
 from llm_training_tpu.infer import GenerateConfig, InferenceEngine
 from llm_training_tpu.models.gigachat35 import GigaChat35, GigaChat35Config, reference
 from llm_training_tpu.serve import ServeConfig, ServingEngine
+from llm_training_tpu.telemetry import get_registry
 from tests.test_gigachat35 import (
     F32_TOL,
     REFERENCE_CFG,
@@ -28,6 +29,7 @@ from tests.test_gigachat35 import (
 )
 
 REQUESTS = [(19, 20), (5, 30), (11, 9), (30, 6), (3, 14)]  # (prompt, new tokens)
+MOE_KINDS = ("held", "zero", "elsewhere")  # `serve/moe_<kind>_assignments`
 SERVE = dict(max_batch=2, max_model_len=64, block_size=8, prefill_chunk=8, num_blocks=7, eos_token_id=None)
 # Read here over 4 draws of the weights, of 79 served tokens: over 0.1 below the reference's best, bfloat16 4, 15, 9
 # and 5 of them (0.05 to 0.19), the fp8 control 49, 49, 44 and 50 (0.56 to 0.63); the limit stands between. The
@@ -117,6 +119,8 @@ def test_chunked_prefill_then_paged_decode_is_the_reference_forward(stack):
     model = GigaChat35(GigaChat35Config(**{**TINY, **over}))
     variables = seeded_variables(model)
     cfg = {**REFERENCE_CFG, "num_hidden_layers": model.config.num_hidden_layers}
+    # the counters are the process's: whatever served on this worker before is in them
+    before = [get_registry().counter(f"serve/moe_{k}_assignments").value for k in MOE_KINDS]
     with jax.default_matmul_precision("highest"):
         engine, requests, done = run_engine(model, variables)
     assert all(done[r["id"]]["stop_reason"] == "max_tokens" for r in requests)
@@ -130,7 +134,7 @@ def test_chunked_prefill_then_paged_decode_is_the_reference_forward(stack):
     assert stats["decode/state_bytes"] == stats["decode/state_logical_bytes"] == state
     assert stats["decode/delta_step_calls/xla"] == slab_layers and stats["decode/delta_step_calls/kernel"] == 0
     # a share counts where its rows' choices went: 4 a token in each layer with experts
-    held, zero, elsewhere = (stats[f"serve/moe_{k}_assignments"] for k in ("held", "zero", "elsewhere"))
+    held, zero, elsewhere = (stats[f"serve/moe_{k}_assignments"] - b for k, b in zip(MOE_KINDS, before))
     assert zero == 0 and held > 0 and elsewhere > 0
     if stack == "one_period_grouped_experts_in_place":
         assert stats["decode/experts_in_place_layers"] == 4  # the scanned period's four

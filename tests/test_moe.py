@@ -7,6 +7,8 @@ dropless ragged_dot grouped-matmul path, so parity against the HF torch
 implementations is the correctness bar.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -500,3 +502,100 @@ def test_granitemoe_state_dict_round_trip():
     assert set(back) == set(sd)
     for key in sd:
         np.testing.assert_array_equal(back[key], sd[key], err_msg=key)
+
+
+# ------------------------------------------------ the ragged path's combine
+#
+# `moe._combine` gathers the experts' sorted rows back into the assignments'
+# order and sums a token's K of them in float32. Held against the formula it
+# replaced, written out: a float32 scatter-add of the weighted rows at
+# `token_order`.
+
+
+def _combine_case(top_k, held, seed=0):
+    """Seeded inputs of one combine: rows sorted by expert, the router's
+    weights, and for a held share the mask of the assignments held here, with
+    NaN planted in the rows of the others (what a grouped product may leave
+    in rows of no group)."""
+    tokens, hidden, experts = 24, 16, 12
+    rng = np.random.default_rng(seed)
+    picks = np.stack([rng.permutation(experts)[:top_k] for _ in range(tokens)]).astype(np.int32)
+    weights = jnp.asarray(rng.random((tokens, top_k)) + 0.1, jnp.float32)
+    order = jnp.argsort(jnp.asarray(picks).reshape(-1))
+    ys = rng.standard_normal((tokens * top_k, hidden)).astype(np.float32)
+    mine = None
+    if held:
+        mine = jnp.asarray(picks < experts - 4)  # the last four experts are held elsewhere
+        ys[~np.asarray(mine).reshape(-1)[np.asarray(order)]] = np.nan
+    return jnp.asarray(ys), order, weights, mine
+
+
+def _scatter_add_combine(ys, order, weights, mine):
+    """What `dropless_moe_apply` did until PR 50, in float32."""
+    tokens, top_k = weights.shape
+    ys = ys * weights.reshape(-1)[order][:, None]
+    if mine is not None:
+        ys = jnp.where(mine.reshape(-1)[order][:, None], ys, 0)
+    token_order = (jnp.arange(tokens * top_k) // top_k)[order]
+    return jnp.zeros((tokens, ys.shape[-1]), jnp.float32).at[token_order].add(ys)
+
+
+def _row_scatter_adds(jaxpr, rows, hidden):
+    """The scatter-adds of a jaxpr (inner ones included) whose updates are
+    `[rows, hidden]`."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scatter-add" and eqn.invars[2].aval.shape == (rows, hidden):
+            found.append(eqn)
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            found += _row_scatter_adds(inner, rows, hidden)
+    return found
+
+
+@pytest.mark.parametrize("held", [False, True])
+@pytest.mark.parametrize("top_k", [1, 2, 8])
+def test_combine_sums_the_gathered_rows_as_the_scatter_add_did(top_k, held):
+    from llm_training_tpu.models.moe import _combine
+
+    ys, order, weights, mine = _combine_case(top_k, held)
+    new = jax.jit(lambda ys, w: _combine(ys, order, w, mine, jnp.float32))
+    out = new(ys, weights)
+    assert out.shape == (weights.shape[0], ys.shape[1]) and out.dtype == jnp.float32
+    assert np.all(np.isfinite(np.asarray(out)))  # selected away, not weighted by 0
+    np.testing.assert_allclose(out, _scatter_add_combine(ys, order, weights, mine), rtol=1e-5, atol=1e-6)
+    # one float32 sum a token, rounded once: bfloat16 rows come out within a
+    # rounding of the float32 sum of those rows
+    low = jnp.nan_to_num(ys).astype(jnp.bfloat16)
+    exact = _scatter_add_combine(low.astype(jnp.float32), order, weights, mine)
+    rounded = jax.jit(lambda ys, w: _combine(ys, order, w, mine, jnp.bfloat16))(low, weights)
+    assert rounded.dtype == jnp.bfloat16
+    np.testing.assert_allclose(rounded.astype(jnp.float32), exact, rtol=2.0**-8, atol=1e-6)
+    assert not _row_scatter_adds(jax.make_jaxpr(new)(ys, weights).jaxpr, *ys.shape)
+
+
+@pytest.mark.parametrize("held", [False, True])
+@pytest.mark.parametrize("top_k", [1, 2, 8])
+def test_combine_gradients_are_the_scatter_adds_and_gather_both_ways(top_k, held):
+    from llm_training_tpu.models.moe import _combine
+
+    ys, order, weights, mine = _combine_case(top_k, held, seed=1)
+    probe = jnp.asarray(np.random.default_rng(2).standard_normal((weights.shape[0], ys.shape[1])), jnp.float32)
+    ys = jnp.nan_to_num(ys)  # a gradient through a NaN row is the select's business: held to zero below
+    loss = lambda combine: lambda ys, w: jnp.sum(combine(ys, order, w, mine) * probe)
+    new = jax.jit(jax.grad(loss(lambda *a: _combine(*a, jnp.float32)), argnums=(0, 1)))
+    d_ys, d_w = new(ys, weights)
+    want_ys, want_w = jax.jit(jax.grad(loss(_scatter_add_combine), argnums=(0, 1)))(ys, weights)
+    np.testing.assert_allclose(d_ys, want_ys, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(d_w, want_w, rtol=1e-5, atol=1e-6)
+    if held:  # nothing flows into the rows held elsewhere
+        elsewhere = ~np.asarray(mine).reshape(-1)[np.asarray(order)]
+        assert elsewhere.any() and not np.asarray(d_ys)[elsewhere].any()
+    # the backward pass did not become the scatter the forward lost, and its
+    # gather stands under the scope the forward's does (a `custom_vjp` keeps
+    # the scope it is called under: `transpose(jvp(moe_scatter))`)
+    assert not _row_scatter_adds(jax.make_jaxpr(new)(ys, weights).jaxpr, *ys.shape)
+    names = re.findall(r'loc\("([^"]*/gather)"', new.lower(ys, weights).as_text(debug_info=True))
+    assert {name.split("/")[-2] for name in names} == {"jvp(moe_scatter)", "transpose(jvp(moe_scatter))"}
+    assert _row_scatter_adds(  # the probe finds one where there is one: a plain gather's transpose
+        jax.make_jaxpr(jax.grad(lambda ys: jnp.sum(ys[jnp.argsort(order)] * 2.0)))(ys).jaxpr, *ys.shape
+    )
