@@ -13,4 +13,10 @@ Phi-3-family models) built TPU-first:
   HF checkpoint round-tripping
 """
 
+import time as _time
+
+# the package's first clock reading (`time.perf_counter()`, the tracer's
+# clock): what the start-up timeline's `setup/ready_s` counts from
+PROCESS_START = _time.perf_counter()
+
 __version__ = "0.1.0"

@@ -24,7 +24,8 @@ TELEMETRY_PREFIXES = (
     "goodput/", "hbm/", "xla/", "data/", "checkpoint/", "perf/",
     "health/", "nan_guard/", "resilience/", "decode/", "eval/", "serve/",
     "elastic/", "flash/", "trace/", "slo/", "exporter/", "attr/",
-    "profile/", "hbm_timeline/", "router/", "rl/", "ckpt/",
+    "profile/", "hbm_timeline/", "router/", "rl/", "ckpt/", "setup/",
+    "compile/",
 )
 TELEMETRY_KEYS = ("compile_time_s",)
 

@@ -290,11 +290,15 @@ def _run_serve(args, config: dict) -> int:
     from llm_training_tpu.serve import ServeConfig, ServingEngine
     from llm_training_tpu.trainer.trainer import LOGICAL_AXIS_RULES
 
-    trainer, objective, _ = _build(config)
+    from llm_training_tpu.telemetry.trace import get_tracer
+
+    with get_tracer().measure("setup", "model_build", pin=True):
+        trainer, objective, _ = _build(config)
     _require_single_model_objective(objective, "serve")
-    state = trainer.restore_for_inference(
-        objective, int(args.ckpt_path) if args.ckpt_path else None
-    )
+    with get_tracer().measure("setup", "weights", pin=True):
+        state = trainer.restore_for_inference(
+            objective, int(args.ckpt_path) if args.ckpt_path else None
+        )
     serve_config = ServeConfig(
         max_batch=args.max_batch,
         max_model_len=args.max_model_len,
@@ -332,7 +336,6 @@ def _run_serve(args, config: dict) -> int:
         uninstall_chaos,
     )
     from llm_training_tpu.serve import RequestJournal, replay_journal
-    from llm_training_tpu.telemetry.trace import get_tracer
 
     log = logging.getLogger(__name__)
     run_dir = _jsonl_run_dir(config)
@@ -1460,19 +1463,30 @@ def main(argv: list[str] | None = None) -> int:
 
         return route_main(args)
 
-    config = load_config(args.config, args.overrides)
-    logging.basicConfig(
-        level=getattr(logging, str(config.get("logging_level", "INFO")).upper()),
-        format="%(asctime)s %(levelname)s %(name)s: %(message)s",
-        stream=sys.stdout,
-    )
-    _seed_everything(int(config.get("seed_everything", 42)))
+    # the start-up timeline (docs/observability.md#tracing): pinned spans,
+    # which the run directory's trace.jsonl takes when its sink is attached
+    from llm_training_tpu.telemetry.trace import get_tracer
 
-    # multi-host rendezvous must precede any jax use
-    from llm_training_tpu.parallel import initialize_distributed
+    tracer = get_tracer()
+    with tracer.measure("setup", "config", pin=True):
+        config = load_config(args.config, args.overrides)
+        logging.basicConfig(
+            level=getattr(logging, str(config.get("logging_level", "INFO")).upper()),
+            format="%(asctime)s %(levelname)s %(name)s: %(message)s",
+            stream=sys.stdout,
+        )
+        _seed_everything(int(config.get("seed_everything", 42)))
 
-    initialize_distributed()
-    _apply_extra_config(config)
+    with tracer.measure("setup", "backend", pin=True):
+        # multi-host rendezvous must precede any jax use
+        from llm_training_tpu.parallel import initialize_distributed
+
+        initialize_distributed()
+        _apply_extra_config(config)
+        import jax
+
+        # the backend's bring-up: here, not inside whoever asks first
+        jax.devices()
 
     if args.command == "generate":
         return _run_generate(args, config)
@@ -1483,7 +1497,8 @@ def main(argv: list[str] | None = None) -> int:
     if args.command == "evaluate":
         return _run_evaluate(args, config)
 
-    trainer, objective, datamodule = _build(config)
+    with tracer.measure("setup", "model_build", pin=True):
+        trainer, objective, datamodule = _build(config)
 
     resume_step = int(args.ckpt_path) if args.ckpt_path else None
     if args.command == "fit":

@@ -39,9 +39,14 @@ def configure_compile_cache() -> str:
     """Point JAX's persistent cache at `compile_cache_dir()`; returns it.
     With the variable set nothing is set in code — JAX already read it.
     JAX's own thresholds stay (programs that compile in under a second are
-    not written). Keys hold the programs' metadata (module docstring)."""
+    not written). Keys hold the programs' metadata (module docstring). The
+    first thing a process of the repo does with jax, so the program starts
+    to hear jax's compile events here (docs/observability.md#tracing)."""
     import jax
 
+    from llm_training_tpu.telemetry.profiling import install_compile_listener
+
+    install_compile_listener()
     jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     if not os.environ.get(ENV_CACHE_DIR):
         jax.config.update("jax_compilation_cache_dir", str(DEFAULT_CACHE_DIR))

@@ -85,6 +85,7 @@ import logging
 import math
 import os
 import time
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass
 from typing import Any, Sequence
 
@@ -119,9 +120,13 @@ from llm_training_tpu.serve.scheduler import (
     ServeRequest,
     WindowGroup,
 )
-from llm_training_tpu.telemetry.profiling import install_trace_annotator
+from llm_training_tpu.telemetry.profiling import (
+    install_compile_listener,
+    install_trace_annotator,
+    mark_setup_ready,
+)
 from llm_training_tpu.telemetry.registry import get_registry
-from llm_training_tpu.telemetry.trace import get_tracer
+from llm_training_tpu.telemetry.trace import get_tracer, startup_lines, startup_summary
 
 logger = logging.getLogger(__name__)
 
@@ -133,6 +138,13 @@ _LIVE_WINDOW = 512
 # terminals that are the engine SHEDDING load to protect its SLO, not
 # request failures: counted as serve/requests_shed, never requests_failed
 _SHED_REASONS = ("deadline", "overloaded")
+
+# a wait for the device this long is a stall worth keeping past the ring's
+# turnover (a step is 10 to 55 ms; the stalls met are 1 to 5 s: ROADMAP S8)
+STALL_SECONDS = 0.5
+
+# every call of a program but its first opens no `setup/first_call` span
+_NO_SPAN = nullcontext()
 
 
 def _split(packed, fields: dict[str, tuple[int, ...]]) -> dict:
@@ -243,6 +255,13 @@ class ServingEngine:
         mesh: Any | None = None,
         rules: Any = (),
     ):
+        # the start-up timeline (docs/observability.md#tracing): jax's compile
+        # events heard from here on, construction as one pinned span
+        install_compile_listener()
+        with get_tracer().measure("setup", "engine_init", pin=True):
+            self._construct(model, variables, config, mesh, rules)
+
+    def _construct(self, model, variables, config, mesh, rules) -> None:
         from llm_training_tpu.infer.engine import supports_decoding
 
         if not supports_decoding(model):
@@ -339,6 +358,13 @@ class ServingEngine:
         # a profiler capture of this process holds step()'s spans beside the
         # device's ops (docs/observability.md#tracing)
         install_trace_annotator()
+        # the programs that have not run yet: the first invocation of each is
+        # a pinned `setup/first_call` span, and the engine is ready (the
+        # pinned instant `setup/ready`) once both have returned
+        self._unrun = {"prefill_chunk", "decode_step"}
+        # the longest wait for the device of the engine's life, as (wall
+        # seconds, the process's CPU seconds across it, the step): ROADMAP S8
+        self._longest_fetch = (0.0, 0.0, 0)
         # the running step's counts: filled where the work is decided, closed
         # into the engine_step span and the serve/* counters by step()
         self._step_counts: dict[str, int] = {}
@@ -545,6 +571,21 @@ class ServingEngine:
         self._decode_jit = jax.jit(
             decode_step, donate_argnums=(2, 3), donate_argnames=("slab", "window_pool")
         )
+
+    def _first_call(self, program: str):
+        """What a program's invocation runs under: nothing, but for the FIRST
+        one, which is the pinned span `setup/first_call` (its trace, lowering,
+        compile or cache read and dispatch; the device's work is not waited
+        for). A flag an engine, no wrapper left around the jitted call."""
+        return self._first_call_span(program) if program in self._unrun else _NO_SPAN
+
+    @contextmanager
+    def _first_call_span(self, program: str):
+        with get_tracer().measure("setup", "first_call", pin=True, program=program):
+            yield
+        self._unrun.discard(program)
+        if not self._unrun:
+            mark_setup_ready(loop="serve")
 
     def _build_tables(self) -> None:
         """What the engine keeps a decode slot for its lifetime and edits
@@ -1052,8 +1093,20 @@ class ServingEngine:
         child = {"step": self._step_index, "write": False}
         del self._in_flight[: len(calls)]
         # the wait for the device, where the newest of them still runs
+        cpu, t = time.process_time(), time.perf_counter()
         with get_tracer().measure("serve", "decode_fetch", **child):
             fetched = jax.device_get([(c.tokens, c.logprobs, c.moe) for c in calls])
+        waited = time.perf_counter() - t
+        if waited > self._longest_fetch[0]:
+            # where a step that reads far off lost its time: a wait that the
+            # process spent on the CPU is the host's, one it slept through is
+            # the device's or the runtime's
+            self._longest_fetch = (waited, time.process_time() - cpu, self._step_index)
+            if waited > STALL_SECONDS:
+                get_tracer().instant(
+                    "serve", "stall", pin=True, step=self._step_index,
+                    fetch_s=waited, cpu_s=self._longest_fetch[1],
+                )
         with get_tracer().measure("serve", "decode_emit", **child):
             for call, (tokens, logprobs, moe) in zip(calls, fetched):
                 if moe is not None:
@@ -1166,11 +1219,12 @@ class ServingEngine:
                 # call returns (the CPU backend aliases an aligned one
                 # outright), and the next chunk fills the buffer while this
                 # one may still be in the device's queue
-                (self._pool_k, self._pool_v, self._last_tokens, logprob, self._slab,
-                 moe) = self._take_window_pool(self._prefill_jit(
-                    self.variables, self._prefill_packed.copy(), self._pool_k, self._pool_v,
-                    self._rng, self._last_tokens, **caches,
-                ))
+                with self._first_call("prefill_chunk"):
+                    (self._pool_k, self._pool_v, self._last_tokens, logprob, self._slab,
+                     moe) = self._take_window_pool(self._prefill_jit(
+                        self.variables, self._prefill_packed.copy(), self._pool_k, self._pool_v,
+                        self._rng, self._last_tokens, **caches,
+                    ))
             request.prefilled += len(chunk)
             request.cache_len += len(chunk)
             self._release_window(request)
@@ -1264,8 +1318,9 @@ class ServingEngine:
         # the enqueue and the books that need no token's value: a step that
         # reads far off shows whether its seconds went here or in the wait
         with tracer.measure("serve", "decode_dispatch", **child):
-            (self._pool_k, self._pool_v, self._last_tokens, logprobs, self._slab,
-             moe) = self._take_window_pool(self._decode_jit(*step_args, **step_slab))
+            with self._first_call("decode_step"):
+                (self._pool_k, self._pool_v, self._last_tokens, logprobs, self._slab,
+                 moe) = self._take_window_pool(self._decode_jit(*step_args, **step_slab))
             for request in survivors:
                 request.cache_len += 1
                 self._release_window(request)
@@ -1466,6 +1521,12 @@ class ServingEngine:
             "decode/cache_blocks_total": float(self.allocator.num_blocks - 1),
             "decode/cache_blocks_in_use": float(self.allocator.blocks_in_use),
             "decode/cache_peak_blocks_in_use": float(self.allocator.peak_in_use),
+            # the longest wait for the device of the engine's life (ROADMAP S8)
+            "serve/longest_fetch_s": self._longest_fetch[0],
+            "serve/longest_fetch_cpu_s": self._longest_fetch[1],
+            "serve/longest_fetch_step": float(self._longest_fetch[2]),
+            # process start to the engine's first useful step (0: not yet)
+            "setup/ready_s": get_registry().gauge("setup/ready_s").value or 0.0,
         }
         if ttft:
             stats["serve/ttft_p50_ms"] = float(np.percentile(ttft, 50))
@@ -1487,6 +1548,8 @@ class ServingEngine:
             "serve/steps", "serve/decode_rows", "serve/table_writes",
             # the step ahead of its fetches (docs/serving.md, "A step ahead")
             "serve/steps_ahead", "serve/pipeline_flushes", "serve/discarded_row_steps",
+            # programs handed to the backend after `setup/ready`: recompiles
+            "compile/after_ready",
         ]
         if self._counts_experts:
             counted += [f"serve/moe_{kind}_assignments" for kind in ("held", "zero", "elsewhere")]
@@ -1504,4 +1567,7 @@ class ServingEngine:
             "serve: %d completed (%d evictions) | %.1f tokens/s (%.1f/chip)",
             len(completed), self.scheduler.evictions, tps, stats["serve/tokens_per_sec_per_chip"],
         )
+        startup = startup_summary(get_tracer().pinned())
+        for line in startup_lines(startup) if startup else ():
+            logger.info(line)
         return stats

@@ -36,8 +36,11 @@ from llm_training_tpu.telemetry.goodput import PHASES, GoodputLedger
 from llm_training_tpu.telemetry.profiling import (
     ProfileTrigger,
     build_profile_trigger,
+    compile_totals,
     get_profile_trigger,
+    install_compile_listener,
     install_trace_annotator,
+    mark_setup_ready,
     set_profile_trigger,
 )
 from llm_training_tpu.telemetry.slo import (
@@ -88,6 +91,7 @@ __all__ = [
     "build_param_groups",
     "build_profile_trigger",
     "build_slo_monitor",
+    "compile_totals",
     "compiled_attribution_gauges",
     "compiled_cost_gauges",
     "dump_anomaly",
@@ -95,7 +99,9 @@ __all__ = [
     "get_registry",
     "get_tracer",
     "hbm_gauges",
+    "install_compile_listener",
     "install_trace_annotator",
+    "mark_setup_ready",
     "set_profile_trigger",
     "layer_health_metrics",
     "moe_router_health",

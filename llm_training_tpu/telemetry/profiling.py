@@ -44,7 +44,9 @@ import threading
 import time
 from pathlib import Path
 
-from llm_training_tpu.telemetry.trace import get_tracer
+from llm_training_tpu import PROCESS_START
+from llm_training_tpu.telemetry.registry import get_registry
+from llm_training_tpu.telemetry.trace import PINNED_CAPACITY, get_tracer
 
 logger = logging.getLogger(__name__)
 
@@ -84,6 +86,222 @@ def install_trace_annotator(tracer=None) -> None:
     (tracer or get_tracer()).set_annotator(
         lambda name, args: jax.profiler.TraceAnnotation(name, **args)
     )
+
+
+# ------------------------------------------------------- start-up timeline
+# (docs/observability.md#tracing, "Start-up timeline")
+
+# jax's own duration events, and the `compile/<kind>` span each is recorded as
+COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    # the backend's compile OR the persistent cache's read in its place
+    "/jax/core/compile/backend_compile_duration": "backend",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_read",
+}
+COMPILE_COUNTERS = {
+    "/jax/compilation_cache/cache_hits": "compile/cache_hits",
+    "/jax/compilation_cache/cache_misses": "compile/cache_misses",
+}
+# a shorter event is counted and not pinned: an inner function's trace lies
+# inside its program's, hundreds of them of a millisecond each
+PIN_SECONDS = 0.1
+# the (kind, fun) rows the table keeps by name; what comes later sums under OTHER_FUN
+_TABLE_ROWS = 1024
+OTHER_FUN = "<other>"
+# the spans that open a loop's own part of start-up: what lies before the
+# first of them is the process's (imports, the backend, the weights)
+LOOP_OPENERS = ("engine_init", "fit_prepare")
+
+
+class _CompileListener:
+    """What `install_compile_listener` registers, once a process: jax's
+    compile events into a per-(kind, fun) table of count and seconds, the
+    long ones into the tracer's pinned store as `compile/<kind>` spans."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.registered = False  # guarded by: _lock
+        self.tracer = None  # guarded by: _lock (None: the process tracer at the event)
+        self._table: dict[tuple[str, str], list] = {}  # guarded by: _lock
+        self._short: dict[str, list] = {}  # guarded by: _lock (events under PIN_SECONDS, a kind)
+        # a cache read is heard inside the backend event that follows it on
+        # the same thread: held here until that event names its program
+        self._cache_reads: dict[int, float] = {}  # guarded by: _lock
+        self._ready = False  # guarded by: _lock
+        self._heard = 0  # guarded by: _lock
+        self._callback_s = 0.0  # guarded by: _lock
+
+    def on_duration(self, event: str, duration: float, **kwargs) -> None:
+        kind = COMPILE_EVENTS.get(event)
+        if kind is None:
+            return
+        t_in = time.perf_counter()
+        fun = str(kwargs.get("fun_name", "")).removeprefix("jit(").removesuffix(")")
+        args = {"fun": fun} if fun else {}
+        with self._lock:
+            thread = threading.get_ident()
+            if kind == "cache_read":
+                self._cache_reads[thread] = duration
+            elif kind == "backend" and thread in self._cache_reads:
+                args["cache_read_s"] = self._cache_reads.pop(thread)
+                self._add_locked("cache_read", fun, args["cache_read_s"])
+            if kind != "cache_read":
+                self._add_locked(kind, fun, duration)
+            if duration < PIN_SECONDS:
+                short = self._short.setdefault(kind, [0, 0.0])
+                short[0] += 1
+                short[1] += duration
+            # a program handed to the backend after `setup/ready` is a
+            # recompile (a new shape): one event a program, which carries the
+            # program's own trace and lowering, whatever their length
+            recompile = self._ready and kind == "backend"
+            if recompile:
+                args["after_ready"] = True
+                for earlier in ("trace", "lower"):
+                    args[f"{earlier}_s"] = self._table.get((earlier, fun), (0, 0.0, 0.0))[2]
+            self._heard += 1
+            if duration < PIN_SECONDS and not recompile:
+                # all there is to nearly every event: one lock, two dictionaries
+                self._callback_s += time.perf_counter() - t_in
+                return
+            tracer = self.tracer
+        tracer = tracer or get_tracer()
+        # a recompile is pinned whatever its length, while the store is under
+        # half full: a process that goes on compiling (a benchmark's reference
+        # after the window) cannot push its own start-up out
+        if duration >= PIN_SECONDS or len(tracer.pinned()) < PINNED_CAPACITY // 2:
+            # the event arrives when the work is over: [now - duration, now)
+            now = tracer.clock()
+            tracer.span("compile", kind, now - duration, now, pin=True, **args)
+        if recompile:
+            get_registry().counter("compile/after_ready").inc()
+        with self._lock:
+            self._callback_s += time.perf_counter() - t_in
+
+    def _add_locked(self, kind: str, fun: str, seconds: float) -> None:
+        """One event into the table, a row of count, seconds and the newest
+        event's seconds. Caller holds `_lock`."""
+        key = (kind, fun)
+        # lint: allow(race-unguarded-shared): _locked-suffix helper — on_duration calls it inside its `with self._lock:` block
+        if key not in self._table and len(self._table) >= _TABLE_ROWS:
+            key = (kind, OTHER_FUN)
+        # lint: allow(race-unguarded-shared): _locked-suffix helper — caller (on_duration) holds _lock across this call
+        row = self._table.setdefault(key, [0, 0.0, 0.0])
+        row[0] += 1
+        row[1] += seconds
+        row[2] = seconds
+
+    def on_event(self, event: str, **_) -> None:
+        name = COMPILE_COUNTERS.get(event)
+        if name is not None:
+            get_registry().counter(name).inc()
+
+    def totals(self) -> dict:
+        with self._lock:
+            kinds: dict[str, dict] = {}
+            by_fun: dict[str, dict] = {}
+            for (kind, fun), (count, seconds, _) in self._table.items():
+                total = kinds.setdefault(kind, {
+                    "count": 0, "seconds": 0.0, "short_count": 0, "short_seconds": 0.0,
+                })
+                total["count"] += count
+                total["seconds"] += seconds
+                by_fun.setdefault(kind, {})[fun] = {"count": count, "seconds": seconds}
+            for kind, (count, seconds) in self._short.items():
+                if kind in kinds:
+                    kinds[kind]["short_count"] = count
+                    kinds[kind]["short_seconds"] = seconds
+            return {
+                "kinds": kinds, "by_fun": by_fun,
+                "heard": self._heard, "callback_s": self._callback_s,
+            }
+
+    def set_ready(self) -> None:
+        with self._lock:
+            self._ready = True
+
+
+_compile_listener = _CompileListener()
+
+
+def install_compile_listener(tracer=None) -> None:
+    """The program hears jax's compile events: from here on every trace,
+    lowering, backend compile and cache read of the process adds to
+    `compile_totals()`, and the ones of `PIN_SECONDS` or more (after
+    `setup/ready`: every backend event) are pinned in the tracer as
+    `compile/<kind>` spans with the program's name as `fun`
+    (`tracer`: where to, from now on; by default the process tracer as it is
+    when the event comes). One registration a process, whoever calls
+    (`jax.monitoring` keeps a listener for good): `configure_compile_cache`,
+    `ServingEngine`, `Trainer.fit`, each where a loop begins to start up, so
+    a call also ends the time "after ready" until `mark_setup_ready` is
+    called again. The listener is a dictionary update an event; inside a
+    steady window jax fires none."""
+    listener = _compile_listener
+    with listener._lock:
+        listener.tracer = tracer
+        # a loop is starting up: what it compiles is no recompile
+        listener._ready = False
+        if listener.registered:
+            return
+        listener.registered = True
+    import jax.monitoring
+
+    jax.monitoring.register_event_duration_secs_listener(listener.on_duration)
+    jax.monitoring.register_event_listener(listener.on_event)
+
+
+def compile_totals() -> dict:
+    """What the listener has heard so far: `kinds` (count and seconds a kind,
+    the events under `PIN_SECONDS` apart as `short_*`), `by_fun` (the same a
+    program or function), and what hearing cost (`heard` events, `callback_s`
+    seconds inside the callbacks)."""
+    return _compile_listener.totals()
+
+
+def _union_s(spans: list[dict]) -> float:
+    """Seconds some span of `spans` covers (an inner function's long trace
+    lies inside its program's and counts once)."""
+    total, end = 0.0, float("-inf")
+    for t0, t1 in sorted((e["ts"], e["ts"] + e["dur"]) for e in spans):
+        total += max(0.0, t1 - max(t0, end))
+        end = max(end, t1)
+    return total
+
+
+def mark_setup_ready(tracer=None, **args) -> None:
+    """A loop has run each of its programs once: the pinned instant
+    `setup/ready`, whose args are the start-up timeline in one line
+    (`ready_s` since the package's first clock reading, `pre_loop_s` of it
+    before the loop's first own span, the seconds the pinned `compile/*`
+    spans cover a kind, and the listener's totals as they stand, the short
+    events' part apart), and the gauge `setup/ready_s`. From the first one on
+    every program handed to the backend counts in `compile/after_ready` and
+    is pinned with its name: a recompile."""
+    tracer = tracer or get_tracer()
+    now = tracer.clock()
+    pinned = [e for e in tracer.pinned() if e["ts"] <= now]
+    loop_start = min(
+        (e["ts"] for e in pinned if e["cat"] == "setup" and e["name"] in LOOP_OPENERS),
+        default=now,
+    )
+    summary = {
+        "ready_s": now - PROCESS_START,
+        "pre_loop_s": max(0.0, loop_start - PROCESS_START),
+    }
+    totals = compile_totals()
+    for kind, total in totals["kinds"].items():
+        spans = [e for e in pinned if e["cat"] == "compile" and e["name"] == kind]
+        # backend events do not nest; trace and lowering events do
+        summary[f"{kind}_pinned_s"] = _union_s(spans)
+        summary[f"{kind}_s"] = total["seconds"]
+        summary[f"{kind}_n"] = total["count"]
+        summary[f"{kind}_short_s"] = total["short_seconds"]
+        summary[f"{kind}_short_n"] = total["short_count"]
+    tracer.instant("setup", "ready", ts=now, pin=True, **args, **summary)
+    get_registry().gauge("setup/ready_s").set(summary["ready_s"])
+    _compile_listener.set_ready()
 
 
 def sanitize_tag(tag: str) -> str:

@@ -15,6 +15,7 @@ import sys
 from pathlib import Path
 
 from llm_training_tpu.telemetry.goodput import PHASES
+from llm_training_tpu.telemetry.trace import startup_lines
 
 _GIB = 1024.0**3
 
@@ -835,6 +836,9 @@ def _trace_section(summary: dict | None) -> list[str]:
             f"requests traced: {int(summary.get('requests_traced', 0))} "
             f"({int(summary.get('requests_completed', 0))} completed)"
         )
+        if summary.get("startup"):
+            # the start-up timeline (docs/observability.md#tracing)
+            lines.extend(startup_lines(summary["startup"]))
         spans = summary.get("spans") or {}
         if spans:
             lines.append(f"{'span':<24} {'count':>6} {'total_s':>10} {'mean_ms':>9}")

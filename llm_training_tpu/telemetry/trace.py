@@ -15,7 +15,13 @@ correlation ids (`request_id` for serving, `step` for training) — into
   *sampled* events (`LLMT_TRACE_SAMPLE`-th serve request; per-step train
   spans only with `LLMT_TRACE_TRAIN=1`), so steady-state overhead stays
   negligible while coarse lifecycle events (compile, checkpoint_save,
-  validation, segment boundaries) are always persisted.
+  validation, segment boundaries) are always persisted; and
+- a small **pinned store** (`pin=True` on `span`/`instant`/`measure`;
+  `pinned()`): the process's start-up timeline, `setup/*` spans from the
+  loops and `compile/*` spans from jax's own compile events
+  (`telemetry/profiling.py:install_compile_listener`), and the stalls worth
+  keeping. Only pinned events enter it, so no engine step evicts them; a
+  flight dump and a sink attached later both lead with it.
 
 Spans opened through `TraceRecorder.measure` can also reach the device
 profiler: a process that holds jax installs an *annotator*
@@ -59,6 +65,11 @@ REQUEST_PHASES = ("queue", "prefill", "decode")
 # device profile: an annotated span is named `llmt/<cat>/<name>`. A contract
 # (test-pinned): benchmarks/span_reduce.py and Perfetto queries match it.
 ANNOTATION_PREFIX = "llmt/"
+
+# the pinned store's size: what a process says of its own start-up (some
+# thirty `setup/*` and `compile/*` events) and the few stalls worth keeping,
+# which no engine step may push out of the ring behind them
+PINNED_CAPACITY = 512
 
 
 def _env_int(name: str, default: int, minimum: int = 1) -> int:
@@ -136,6 +147,12 @@ class TraceRecorder:
         # Set once, by the loop that owns the device, before it steps.
         self._annotator = annotator  # guarded by: _lock
         self._ring: deque[dict] = deque(maxlen=self.capacity)  # guarded by: _lock
+        # events recorded with `pin=True`: in the ring and the sink like any
+        # other AND here, where only pinned events enter (the start-up
+        # timeline, docs/observability.md#tracing)
+        self._pinned: deque[dict] = deque(maxlen=PINNED_CAPACITY)  # guarded by: _lock
+        # of them, how many a sink attached later has still to be handed
+        self._pinned_unsunk = 0  # guarded by: _lock
         self._lock = threading.Lock()
         self._sink = None  # guarded by: _lock
         self._sink_path: Path | None = None  # guarded by: _lock
@@ -172,6 +189,17 @@ class TraceRecorder:
         # attach lock — instant() takes it again
         anchor = clock_anchor(self.clock)
         self.instant("meta", "clock_anchor", ts=anchor["mono_s"], **anchor)
+        # what was pinned while no sink was there (a CLI's start-up runs
+        # before its run directory exists), once, right after the anchor
+        with self._lock:
+            late = list(self._pinned)[len(self._pinned) - self._pinned_unsunk:]
+            self._pinned_unsunk = 0
+            try:
+                for event in late:
+                    self._sink.write(json.dumps(event) + "\n")
+                    self._written += 1
+            except (OSError, TypeError, ValueError):
+                logger.exception("trace sink write failed (pinned events dropped)")
         self.flush()
         return True
 
@@ -200,12 +228,17 @@ class TraceRecorder:
 
     # ------------------------------------------------------------ record
 
-    def _record(self, event: dict, write: bool) -> None:
+    def _record(self, event: dict, write: bool, pin: bool = False) -> None:
         if not self.enabled:
             return
         with self._lock:
             self._ring.append(event)
             self._recorded += 1
+            if pin:
+                self._pinned.append(event)
+                if self._sink is None:
+                    # the store's newest: a sink attached later takes them
+                    self._pinned_unsunk = min(self._pinned_unsunk + 1, len(self._pinned))
             if write and self._sink is not None:
                 try:
                     self._sink.write(json.dumps(event) + "\n")
@@ -223,25 +256,26 @@ class TraceRecorder:
 
     def span(
         self, cat: str, name: str, t0: float, t1: float,
-        write: bool = True, **args,
+        write: bool = True, pin: bool = False, **args,
     ) -> None:
         """One complete span [t0, t1) (Chrome-trace 'X' phase). Timestamps
-        are this recorder's clock (monotonic seconds)."""
+        are this recorder's clock (monotonic seconds). `pin` keeps it in the
+        pinned store too, out of the ring's turnover."""
         event = {"ts": t0, "dur": max(0.0, t1 - t0), "ph": "X",
                  "cat": cat, "name": name}
         if args:
             event["args"] = args
-        self._record(event, write)
+        self._record(event, write, pin)
 
     def instant(
         self, cat: str, name: str, ts: float | None = None,
-        write: bool = True, **args,
+        write: bool = True, pin: bool = False, **args,
     ) -> None:
         event = {"ts": self.clock() if ts is None else ts, "ph": "i",
                  "cat": cat, "name": name}
         if args:
             event["args"] = args
-        self._record(event, write)
+        self._record(event, write, pin)
 
     def set_annotator(self, annotator) -> None:
         """Install (or, with None, remove) the profiler side of `measure`:
@@ -254,13 +288,14 @@ class TraceRecorder:
 
     @contextmanager
     def measure(
-        self, cat: str, name: str, write: bool = True, **args
+        self, cat: str, name: str, write: bool = True, pin: bool = False, **args
     ) -> Iterator[dict]:
         """THE way to open a span: [enter, exit) lands in the ring (and the
-        sink when `write`), and under an installed annotator also in the
-        device profiler as `llmt/<cat>/<name>` with `args` as its keyword
-        arguments. Yields a dict the body may fill with args known only at
-        the end (a step's counts); they join both records."""
+        sink when `write`, the pinned store when `pin`), and under an
+        installed annotator also in the device profiler as
+        `llmt/<cat>/<name>` with `args` as its keyword arguments. Yields a
+        dict the body may fill with args known only at the end (a step's
+        counts); they join both records."""
         late: dict = {}
         annotator = self._annotator if self.enabled else None
         opened = nullcontext() if annotator is None else annotator(
@@ -271,7 +306,7 @@ class TraceRecorder:
             try:
                 yield late
             finally:
-                self.span(cat, name, t0, self.clock(), write=write, **args, **late)
+                self.span(cat, name, t0, self.clock(), write=write, pin=pin, **args, **late)
                 if late and annotation is not None:
                     annotation.set_metadata(**late)
 
@@ -295,13 +330,24 @@ class TraceRecorder:
         with self._lock:
             return list(self._ring)
 
+    def pinned(self) -> list[dict]:
+        """A copy of the pinned store, oldest first: the process's start-up
+        timeline (`setup/*`, `compile/*`) and what else was worth keeping."""
+        with self._lock:
+            return list(self._pinned)
+
     def flight_dump(self, run_dir: str | Path, tag: str) -> Path | None:
-        """Write the ring's last-N events to `trace-flight-<tag>.jsonl` in
-        `run_dir` — the crash flight recorder. Returns the path, or None on
-        failure; never raises (a dump error must not mask the failure being
-        dumped)."""
+        """Write the pinned events and then the ring's last-N to
+        `trace-flight-<tag>.jsonl` in `run_dir` — the crash flight recorder
+        (a hang dump says what compiled and when, and what stalled). A pinned
+        event still in the ring is written once, with the pinned. Returns the
+        path, or None on failure; never raises (a dump error must not mask
+        the failure being dumped)."""
         try:
-            events = self.snapshot()
+            with self._lock:
+                pinned = list(self._pinned)
+                held = {id(event) for event in pinned}
+                events = pinned + [e for e in self._ring if id(e) not in held]
             run_dir = Path(run_dir)
             run_dir.mkdir(parents=True, exist_ok=True)
             path = run_dir / f"trace-flight-{tag}.jsonl"
@@ -598,6 +644,38 @@ def merge_traces(sources: list[str | Path]) -> tuple[dict, dict]:
 # ---------------------------------------------------------------- summary
 
 
+def startup_summary(events: list[dict]) -> dict | None:
+    """The start-up timeline in one record: the args of the first
+    `setup/ready` among `events` (a pinned store, a trace.jsonl; written by
+    `telemetry/profiling.py:mark_setup_ready`) and, as `after_ready`, the
+    `compile/backend` events the listener marked so: programs compiled, or
+    read from the cache, once a loop was running (those it pinned: the
+    counter `compile/after_ready` has them all). None where no loop got ready."""
+    summary = None
+    for event in events:
+        cat, name = event.get("cat"), event.get("name")
+        if summary is None:
+            if cat == "setup" and name == "ready":
+                summary = {**(event.get("args") or {}), "after_ready": 0}
+        elif cat == "compile" and (event.get("args") or {}).get("after_ready"):
+            summary["after_ready"] += 1
+    return summary
+
+
+def startup_lines(summary: dict) -> list[str]:
+    """`report`'s and the serve log's two lines of a `startup_summary`."""
+    seconds = lambda key: float(summary.get(key) or 0.0)  # noqa: E731
+    return [
+        f"start-up: ready after {seconds('ready_s'):.1f} s: "
+        f"before the loop {seconds('pre_loop_s'):.1f}, "
+        f"tracing {seconds('trace_pinned_s'):.1f}, "
+        f"lowering {seconds('lower_pinned_s'):.1f}, "
+        f"compiler or cache {seconds('backend_s'):.1f}",
+        f"recompiled after ready: {int(summary.get('after_ready') or 0)}",
+    ]
+
+
+
 def summarize_trace(events: list[dict], top_k: int = 3) -> dict:
     """Aggregates for `report`'s `== Trace ==` section and the JSON report:
     per-(category, name) span totals, plus the top-k slowest completed
@@ -672,6 +750,7 @@ def summarize_trace(events: list[dict], top_k: int = 3) -> dict:
     slowest = sorted(completed, key=lambda r: -r["wall_s"])[:top_k]
     return {
         "events": len(events),
+        "startup": startup_summary(events),
         "spans": spans,
         "requests_traced": len(requests),
         "requests_completed": len(completed),

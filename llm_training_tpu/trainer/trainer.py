@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import logging
 import time
+from contextlib import ExitStack
 from typing import Any, Callable, Iterator
 
 import flax.linen as nn
@@ -60,8 +61,10 @@ from llm_training_tpu.telemetry import (
     compiled_cost_gauges,
     get_tracer,
     hbm_gauges,
+    install_compile_listener,
     install_trace_annotator,
     layer_health_metrics,
+    mark_setup_ready,
     resolve_run_dir,
     set_profile_trigger,
     set_registry,
@@ -670,6 +673,15 @@ class Trainer:
         state: TrainState | None = None,
     ) -> TrainState:
         cfg = self.config
+        # the start-up timeline (docs/observability.md#tracing): jax's compile
+        # events heard from here on, and everything up to the step's compile
+        # (mesh, optimizer, shardings, the state's placement or restore) as
+        # one pinned span, which `_fit_inner` closes
+        install_compile_listener()
+        self._fit_prepare = ExitStack()
+        self._fit_prepare.enter_context(
+            get_tracer().measure("setup", "fit_prepare", pin=True)
+        )
         devices, mesh_config, plan = self._resolve_topology(resume_step)
         self.mesh = build_mesh(mesh_config, devices)
         self.topology_plan = plan
@@ -773,6 +785,7 @@ class Trainer:
             with self.mesh, nn.logical_axis_rules(LOGICAL_AXIS_RULES):
                 return self._fit_inner(objective, datamodule, resume_step, state)
         finally:
+            self._fit_prepare.close()  # a fit that raised before its compile
             if self._exporter is not None:
                 self._exporter.stop()
                 self._exporter = None
@@ -1033,9 +1046,10 @@ class Trainer:
         plain_step_used = not (
             health_every == 1 and cfg.accumulate_grad_batches == 1
         )
+        self._fit_prepare.close()
         t_compile = time.perf_counter()
         with self.ledger.measure("compile"), \
-                tracer.measure("train", "compile"):
+                tracer.measure("train", "compile", pin=True):
             if plain_step_used:
                 aot_step = train_step.lower(state, sample_batch).compile()
             else:
@@ -1084,6 +1098,19 @@ class Trainer:
         )
 
         skip_list = recovery.skip_list if recovery is not None else None
+        # the start-up timeline's last span: the fit's first executed step
+        # through the first host fetch of what a step made (a log step's, or
+        # the health variant's), after which the fit is ready
+        first_step = ExitStack()
+        first_step_args: dict | None = None
+
+        def first_fetch_done(step: int) -> None:
+            nonlocal first_step_args
+            if first_step_args is not None:
+                first_step_args["step"] = step
+                first_step_args = None
+                first_step.close()
+                mark_setup_ready(tracer, loop="fit", step=step)
 
         def data_stream(from_micro: int):
             # the skip-list keyword only reaches datamodules when recovery
@@ -1103,7 +1130,7 @@ class Trainer:
             the state back, and re-enters with a later-start segment —
             with recovery unset there is exactly one segment and the loop
             below is the whole fit, byte-identical to before."""
-            nonlocal health_compiled, step_fn
+            nonlocal health_compiled, step_fn, first_step_args
             prefetcher = None
             tracer.instant(
                 "train", "segment_start", micro=seg_start,
@@ -1144,6 +1171,10 @@ class Trainer:
                         ),
                     )
                     batches = iter(prefetcher)
+                if self.last_step is None:  # no step of this fit has run yet
+                    first_step_args = first_step.enter_context(
+                        tracer.measure("setup", "first_step", pin=True)
+                    )
                 for micro in range(seg_start, micro_steps):
                     if self._watchdog is not None:
                         self._watchdog.beat("train_loop", step=micro)
@@ -1205,10 +1236,13 @@ class Trainer:
                                     # recompiles per shape like it always did.
                                     # The retry (jit trace + compile) bills to
                                     # the compile phase; LATER new-shape
-                                    # recompiles are invisible inside the jit
-                                    # call and land in step_compute — the
-                                    # warning below is the flag that this is
-                                    # happening
+                                    # recompiles happen inside the jit call
+                                    # and land in step_compute: the warning
+                                    # below says so once, and the compile
+                                    # listener counts each in
+                                    # `compile/after_ready` and pins a
+                                    # `compile/*` span with its program
+                                    # (docs/observability.md#tracing)
                                     if step_fn is train_step:
                                         raise
                                     logger.warning(
@@ -1258,6 +1292,7 @@ class Trainer:
                         health_keys = [k for k in metrics if k.startswith("health/")]
                         with self.ledger.measure("step_compute"):
                             host = jax.device_get({k: metrics[k] for k in health_keys})
+                        first_fetch_done(step)
                         for key in health_keys:
                             del metrics[key]
                         self.last_health = {k: float(v) for k, v in host.items()}
@@ -1281,6 +1316,7 @@ class Trainer:
                             metrics = {
                                 k: np.asarray(v) for k, v in jax.device_get(metrics).items()
                             }
+                        first_fetch_done(step)
                         # divergence injection (chaos nan_step/spike_step):
                         # poison the HOST metrics the guards read — the
                         # device state stays healthy, which is exactly what
@@ -1389,6 +1425,9 @@ class Trainer:
             finally:
                 # a callback that raised must not leave the state pinned
                 self.live_state = None
+                # a segment that ended before any fetch: the span, no `ready`
+                first_step_args = None
+                first_step.close()
                 if prefetcher is not None:
                     prefetcher.close()
 

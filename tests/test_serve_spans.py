@@ -960,3 +960,45 @@ def test_engine_close_frees_the_pool_and_keeps_stats(fresh):
     engine.close()  # idempotent
     assert engine.stats()["decode/cache_bytes"] == cache_bytes
     assert json.dumps(engine.stats())  # still a plain record
+
+
+def test_the_longest_fetch_is_kept_and_a_stall_outlives_the_ring(fresh, monkeypatch):
+    """ROADMAP S8's probe: the longest `serve/decode_fetch` of the engine's
+    life with its wall seconds, the process's CPU seconds across it and its
+    step in `stats()`; one over `STALL_SECONDS` is a pinned `serve/stall`."""
+    import time
+
+    from llm_training_tpu.serve import engine as serve_engine
+
+    engine = _engine()
+    for request in _requests(6):
+        engine.submit(**request)
+    for _ in range(4):
+        engine.step()
+    quiet = engine.stats()
+    assert 0 < quiet["serve/longest_fetch_s"] < serve_engine.STALL_SECONDS
+    assert 1 <= quiet["serve/longest_fetch_step"] <= 4
+    assert "serve/stall" not in [f"{e['cat']}/{e['name']}" for e in fresh.pinned()]
+    device_get = jax.device_get
+
+    def asleep(tree):  # the planted stall: the process sleeps, its CPU does not run
+        time.sleep(serve_engine.STALL_SECONDS + 0.1)
+        return device_get(tree)
+
+    monkeypatch.setattr(jax, "device_get", asleep)
+    engine.step()
+    monkeypatch.setattr(jax, "device_get", device_get)
+    while not engine.idle:
+        engine.step()
+    stats = engine.stats()
+    assert stats["serve/longest_fetch_step"] == 5
+    assert stats["serve/longest_fetch_s"] >= serve_engine.STALL_SECONDS + 0.1
+    assert stats["serve/longest_fetch_cpu_s"] < stats["serve/longest_fetch_s"] / 2
+    for _ in range(fresh.capacity):  # the ring turns over
+        fresh.instant("serve", "submit", write=False)
+    (stall,) = [e for e in fresh.pinned() if (e["cat"], e["name"]) == ("serve", "stall")]
+    assert stall["args"] == {
+        "step": 5, "fetch_s": stats["serve/longest_fetch_s"],
+        "cpu_s": stats["serve/longest_fetch_cpu_s"],
+    }
+    assert "stall" not in [e["name"] for e in fresh.snapshot()]
